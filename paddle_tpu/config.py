@@ -571,10 +571,6 @@ def resolve_matmul_precision():
     p = _flags["matmul_precision"]
     if p is not None:
         return p
-    try:
-        platform = jax.devices()[0].platform
-    except RuntimeError:
-        return None
-    if platform == "tpu":
+    if jax.devices()[0].platform == "tpu":
         return "BF16_BF16_F32"
     return None

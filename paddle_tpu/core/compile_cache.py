@@ -54,13 +54,14 @@ import pickle
 import threading
 
 import jax
+import jaxlib
 
 from ..observability import metrics as _metrics
 from ..utils import log as _log
 
 __all__ = ["PersistentCompileCache", "active_cache", "entry_digest",
            "env_fingerprint", "serialize_compiled",
-           "deserialize_compiled"]
+           "deserialize_compiled", "enable_jax_cache"]
 
 CACHE_HITS = _metrics.REGISTRY.counter(
     "paddle_deploy_cache_hits_total",
@@ -79,6 +80,23 @@ CACHE_EVICTIONS = _metrics.REGISTRY.counter(
     "dir under compile_cache_max_bytes")
 
 
+def enable_jax_cache(default_dir):
+    """Turn on JAX's OWN persistent compilation cache for an entry
+    point (chip_smoke.py, bench.py) — a mechanism apart from the
+    executable cache this module implements, and the only one that
+    covers every jit in the process. It lives at
+    ``$JAX_COMPILATION_CACHE_DIR`` when the environment names a place —
+    and then nowhere else — otherwise at ``default_dir``, which must be
+    a fixed path: a directory that moves from run to run never hits.
+    Every program is cached, however quick its compile. Returns the
+    directory in use."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", default_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir
+
+
 class _CorruptEntry(Exception):
     """Internal: entry present but failed verification/deserialization."""
 
@@ -87,16 +105,11 @@ def env_fingerprint():
     """Everything that silently changes what an XLA executable means:
     a serialized binary deserialized into a different environment is a
     MISS, not a candidate."""
-    try:
-        dev = jax.devices()[0]
-        platform, kind, n = dev.platform, \
-            getattr(dev, "device_kind", ""), len(jax.devices())
-    except RuntimeError:  # no backend yet
-        platform, kind, n = "none", "", 0
-    import jaxlib
+    devs = jax.devices()
+    platform, kind, n = devs[0].platform, devs[0].device_kind, len(devs)
     return {
         "jax": jax.__version__,
-        "jaxlib": getattr(jaxlib, "__version__", ""),
+        "jaxlib": jaxlib.__version__,
         "platform": platform,
         "device_kind": kind,
         "n_devices": n,
@@ -125,19 +138,29 @@ def entry_digest(program, skey_parts):
 
 def serialize_compiled(compiled):
     """One self-contained blob for a ``jax.stages.Compiled``: the PJRT
-    executable payload plus the arg/out pytree defs (which jax's
+    executable payload, the arg/out pytree defs (which jax's
     ``serialize`` hands back separately because pytrees aren't part of
-    its payload). Raises ValueError when the backend's compilation
-    doesn't support serialization."""
+    its payload) and the ids of the devices it was compiled for.
+    Raises ValueError when the backend's compilation doesn't support
+    serialization."""
     from jax.experimental import serialize_executable as _se
     payload, in_tree, out_tree = _se.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree))
+    device_ids = [d.id for d in
+                  compiled.runtime_executable().local_devices()]
+    return pickle.dumps((payload, in_tree, out_tree, device_ids))
 
 
 def deserialize_compiled(blob):
+    """Load a :func:`serialize_compiled` blob onto the devices it was
+    compiled for. Without ``execution_devices`` jax binds the executable
+    to EVERY local device, and a one-device step on a multi-device host
+    then refuses its arguments ("expected 8 shards, got 1")."""
     from jax.experimental import serialize_executable as _se
-    payload, in_tree, out_tree = pickle.loads(blob)
-    return _se.deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return _se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 def sha256_bytes(data):

@@ -354,7 +354,14 @@ class Executor:
     def __init__(self, place=None, strategy=None):
         """strategy: a parallel.DistStrategy — shards feeds/state over a
         device mesh; XLA inserts the collectives (replaces the reference's
-        pserver/NCCL tier, SURVEY §5.8)."""
+        pserver/NCCL tier, SURVEY §5.8).
+
+        place: checked, not steered by — ``TPUPlace()`` on a host with
+        no TPU raises here instead of training on the CPU; arrays then
+        live where JAX puts them (its default device, or the
+        strategy's mesh)."""
+        if place is not None:
+            place.jax_device()
         self.place = place
         self.strategy = strategy
         self._cache = {}

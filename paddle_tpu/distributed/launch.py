@@ -63,21 +63,10 @@ def init_multihost(coordinator_address=None, num_processes=None,
         kwargs["initialization_timeout"] = \
             max(1, math.ceil(float(initialization_timeout_sec)))
     try:
-        try:
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes, process_id=process_id,
-                **kwargs)
-        except TypeError:
-            # older jax without initialization_timeout: retry without
-            # the bound rather than fail bring-up over a tuning kwarg
-            # (still inside the enriching wrapper, so a rendezvous
-            # failure on the retry names the coordinator too)
-            if not kwargs:
-                raise
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes, process_id=process_id)
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes, process_id=process_id,
+            **kwargs)
     except Exception as e:
         raise RuntimeError(
             "jax.distributed.initialize failed for process %d/%d "

@@ -18,8 +18,8 @@ collectives inside the jitted step instead of parameter-server RPC:
   the vocab up to a multiple of :data:`PAD_MULTIPLE` so the same static
   program shape serves any power-of-two shard count (elastic resizes
   re-permute, never reshape — see checkpoint.py).
-* **Lookup** — a two-hop ``all_to_all`` inside ``shard_map``
-  (jax_compat shim): each device hashes its batch's ids to owning
+* **Lookup** — a two-hop ``all_to_all`` inside ``shard_map``:
+  each device hashes its batch's ids to owning
   shards, exchanges id buckets (hop 1, index wire width), gathers rows
   from its local shard, and exchanges the rows back (hop 2). Bucket
   capacity is the device's own id count, so the exchange is static-
@@ -217,10 +217,10 @@ def _a2a_lookup(dim, mesh, axis, n, rps, wire=None):
             jnp.where(valid, idx, m)].set(back, mode="drop")[:m]
         return jnp.zeros_like(out_sorted).at[order].set(out_sorted)
 
-    from ..jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     return shard_map(
-        f, mesh, in_specs=(P(axis, None), P(axis), P(axis)),
+        f, mesh=mesh, in_specs=(P(axis, None), P(axis), P(axis)),
         out_specs=P(axis, None), check_vma=False)
 
 
@@ -361,10 +361,10 @@ def _lookup_table_dist_grad_op(ctx):
     rps = vp // n
     local = _local_rows(flat, n, rps, pad)
     if use_a2a:
-        from ..jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         rows, vals = shard_map(
-            _a2a_grad(dim, axis, n, rps, vp), mesh,
+            _a2a_grad(dim, axis, n, rps, vp), mesh=mesh,
             in_specs=(P(axis, None), P(axis), P(axis)),
             out_specs=(P(axis), P(axis, None)),
             check_vma=False)(g, flat, local)

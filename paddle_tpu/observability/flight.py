@@ -63,7 +63,9 @@ class FlightRecorder:
         self.max_dumps = 8
         self.last_dump_path = None
         self._last_bundle = None
-        self._last_dump_t = 0.0
+        # never dumped: time.monotonic() starts near 0 on a fresh
+        # machine, so 0.0 here would debounce the first dump away
+        self._last_dump_t = float("-inf")
         # RLock, not Lock: the SIGTERM handler calls dump() on
         # whatever thread the signal interrupts — if that frame was
         # already inside one of these critical sections, a plain lock

@@ -40,7 +40,8 @@ def _multihead_attention(ctx):
         return {"Out": out.reshape(b, tq, dm)}
 
     from .. import config as _config
-    if _config.get_flag("flash_attention") and tq == tk:
+    flash = _config.get_flag("flash_attention")
+    if flash and tq == tk:
         from .pallas_attention import flash_attention
         seg = None
         if ctx.has_input("KeyLength"):
@@ -67,7 +68,7 @@ def _multihead_attention(ctx):
         if maxis is not None and nh % sizes.get(maxis, 1) != 0:
             maxis = None
         if daxis is not None or maxis is not None:
-            from ..jax_compat import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as SP
             spec = SP(daxis, maxis, None, None)
 
@@ -95,6 +96,11 @@ def _multihead_attention(ctx):
             return {"Out": out.transpose(0, 2, 1, 3).reshape(b, tq, dm)}
         # no shardable axis applies -> dense path below
 
+    if flash:
+        # armed but not taken (cross attention, or a mesh no axis of
+        # which divides the batch or the heads): the dense O(T^2) path
+        from . import kernel_path
+        kernel_path.record("flash_attention")
     s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh,
                    preferred_element_type=jnp.float32) * (hd ** -0.5)
     neg = jnp.asarray(jnp.finfo(jnp.float32).min, jnp.float32)

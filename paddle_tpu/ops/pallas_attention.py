@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import kernel_path
+
 _NEG = -1e30
 
 
@@ -158,7 +160,9 @@ def _forward(q, k, v, seg, causal, block_q, interpret):
     bq = _block_size(t, block_q, align)
     bk = _block_size(t, 512, align)
     if not bq or not bk:
+        kernel_path.record("flash_attention")
         return _reference(q, k, v, causal, seg)  # ragged: XLA path
+    kernel_path.record("flash_attention", interpret)
     from jax.experimental.pallas import tpu as pltpu
     grid = (bh, t // bq, t // bk)
     kw = dict(scale=d ** -0.5, causal=causal, block_q=bq, block_k=bk,
@@ -343,7 +347,9 @@ def _decode_forward(q, k, v, lengths, interpret):
     bh, c, d = k.shape
     bk = _block_size(c, 512)
     if not bk:
+        kernel_path.record("decode_attention")
         return _decode_reference(q, k, v, lengths)  # ragged: XLA path
+    kernel_path.record("decode_attention", interpret)
     from jax.experimental.pallas import tpu as pltpu
     lens = lengths.reshape(bh).astype(jnp.int32)
 
@@ -500,20 +506,25 @@ def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
     the last live index, so no HBM fetch is issued for them — the
     PR-8 decode kernel's clamp trick, now through a level of
     indirection), and the head picks its head_dim column slice of the
-    pool block. Ragged pool geometry falls back to the dense gather
-    reference — same semantics, so the flag never changes tokens.
-    ``interpret=None`` auto-selects interpreter mode off-TPU."""
+    pool block. Pool geometry Mosaic cannot tile falls back to the
+    dense gather reference — same semantics, so the flag never changes
+    tokens. ``interpret=None`` auto-selects interpreter mode off-TPU."""
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = kernel_path.interpret_mode()
     s, _, dm = q.shape
     nb, bs, _ = k_pool.shape
     mb = tables.shape[1]
     hd = dm // num_heads
-    if not interpret and (bs % 16 != 0 or hd % 16 != 0):
-        # compiled Mosaic wants tileable block rows/lanes; ragged
-        # geometry takes the XLA gather path (identical semantics)
+    if not interpret and (bs % 16 != 0 or
+                          (hd % 128 != 0 and num_heads != 1)):
+        # compiled Mosaic wants a head's (bs, hd) column slice of a
+        # pool block to be whole lane tiles: hd a multiple of 128, or
+        # the slice the whole row (one head). Anything else (e.g.
+        # head_dim 64) takes the XLA gather path (identical semantics)
+        kernel_path.record("decode_attention_paged")
         return _decode_paged_reference(q, k_pool, v_pool, lengths,
                                        tables, num_heads)
+    kernel_path.record("decode_attention_paged", interpret)
     from jax.experimental.pallas import tpu as pltpu
     lens = jnp.asarray(lengths).reshape(s).astype(jnp.int32)
     tab = jnp.asarray(tables).reshape(s * mb).astype(jnp.int32)
@@ -568,7 +579,7 @@ def decode_attention(q, k, v, lengths, interpret=None):
     ``interpret=None`` auto-selects interpreter mode off-TPU; lengths
     of 0 produce garbage (callers gate on active slots)."""
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = kernel_path.interpret_mode()
     b, h, d = q.shape
     c = k.shape[2]
     lens = jnp.asarray(lengths)
@@ -591,7 +602,7 @@ def flash_attention(q, k, v, causal=False, segment_ids=None,
     yield zeros. ``interpret=None`` auto-selects interpreter mode
     off-TPU."""
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = kernel_path.interpret_mode()
     squeeze = q.ndim == 3
     if squeeze:
         q, k, v = q[None], k[None], v[None]

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..observability import metrics as _metrics
+from . import kernel_path
 
 __all__ = ["QUANT_COMPUTE_TYPES", "SCALE_SUFFIX", "scale_var_name",
            "quantize_rows", "quant_matmul_2d", "maybe_quant_compute"]
@@ -112,13 +113,15 @@ def _dequant_matmul_kernel(x_ref, wq_ref, ws_ref, o_ref):
 
 def _pallas_int8_matmul(x2, wq, w_scale, interpret=None):
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = kernel_path.interpret_mode()
     m, k = x2.shape
     n = wq.shape[1]
     if not interpret and (m % 8 or k % 128 or n % 128):
         # compiled Mosaic wants tileable sublanes/lanes; ragged shapes
         # take the dense expression (bit-identical, see kernel doc)
+        kernel_path.record("int8_matmul")
         return _dense_int8_matmul(x2, wq, w_scale)
+    kernel_path.record("int8_matmul", interpret)
     bn = next((b for b in (512, 256, 128) if n % b == 0), n)
     return pl.pallas_call(
         _dequant_matmul_kernel,

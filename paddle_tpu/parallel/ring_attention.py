@@ -15,8 +15,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..jax_compat import shard_map
 
 __all__ = ["ring_attention", "dense_attention"]
 

@@ -1,6 +1,7 @@
 """Places — device identities (reference ``paddle/platform/place.h:24-53``:
-CPUPlace/CUDAPlace variant). TPU-native: TPUPlace is first-class; CUDAPlace
-kept as an API-compat alias that resolves to whatever accelerator JAX has.
+CPUPlace/CUDAPlace variant). TPU-native: TPUPlace is first-class and names
+a real TPU device or raises; CUDAPlace is kept as an API-compat alias of
+it.
 """
 
 import jax
@@ -31,16 +32,22 @@ class TPUPlace(_Place):
 
     def jax_device(self):
         devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        if devs[0].platform != "tpu":
+            raise RuntimeError(
+                "%r needs a TPU, but JAX's default backend is %r (%d "
+                "device(s)); use CPUPlace() to run on the host"
+                % (self, devs[0].platform, len(devs)))
+        if not 0 <= self.device_id < len(devs):
+            raise RuntimeError(
+                "%s(%d): this host has %d TPU device(s)"
+                % (type(self).__name__, self.device_id, len(devs)))
+        return devs[self.device_id]
 
 
 class CUDAPlace(TPUPlace):
     """Compat alias: scripts written against the reference's CUDAPlace run
-    on the default JAX accelerator."""
+    on the TPU."""
 
 
 def is_compiled_with_tpu():
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except RuntimeError:
-        return False
+    return any(d.platform == "tpu" for d in jax.devices())

@@ -29,7 +29,10 @@ import argparse
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# always the CPU, and said so: this child is a protocol double for
+# tests and CPU probes, never a chip member — a chip belongs to one
+# process, and a fleet member on a chip needs a chip of its own
+os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 

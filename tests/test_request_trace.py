@@ -30,7 +30,7 @@ def _reset_tracing():
     flight.RECORDER.clear()
     flight.RECORDER._last_bundle = None
     flight.RECORDER.last_dump_path = None
-    flight.RECORDER._last_dump_t = 0.0
+    flight.RECORDER._last_dump_t = float("-inf")
 
 
 # -- tracer core -----------------------------------------------------------
@@ -214,7 +214,7 @@ class TestFlightRecorder:
         ptpu.config.set_flags(request_tracing=True,
                               flight_dir=str(tmp_path))
         flight.RECORDER.min_interval_sec = 3600.0
-        flight.RECORDER._last_dump_t = 0.0
+        flight.RECORDER._last_dump_t = float("-inf")
         ctx = rtrace.mint("unit")
         rtrace.event(ctx, "sessionFailure", session=0)
         path = flight.RECORDER.trigger("breaker_open", replica="g0:0")

@@ -1,0 +1,141 @@
+"""The main path's Pallas kernels, compiled at real widths for a TPU
+v5e that is described, not attached (guide on-chip-measurement §2,
+rehearsal 3). Nothing runs: a pass means the chip's compiler (Mosaic +
+XLA:TPU, installed with libtpu) accepts the kernel at that geometry and
+that the program holds a ``tpu_custom_call`` — i.e. neither interpret
+mode nor an XLA reference was compiled in its place. Interpret mode
+cannot see tiling or VMEM refusals; this file can, at no chip time.
+
+Skipped as a whole where the topology cannot be described (no libtpu).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.ops import kernel_path, pallas_attention as pa
+from paddle_tpu.ops import pallas_conv_bn, quant_ops
+
+F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """SingleDeviceSharding on one described v5e chip. JAX's persistent
+    compile cache is off for the module: an executable compiled for a
+    described device is written to it but cannot be read back without
+    the chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no libtpu
+        pytest.skip("cannot describe a v5e topology here: %r" % (e,))
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(chip, fn, *shapes):
+    """HLO text of ``fn`` compiled for the described chip under the
+    matmul precision the executor traces TPU steps with
+    (config.resolve_matmul_precision)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    with jax.default_matmul_precision("BF16_BF16_F32"):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# the LM of chip_smoke.py: d_model 2048, 16 heads (head_dim 128),
+# B 8 x T 1024, amp bfloat16
+_QKV = ((8, 16, 1024, 128), BF16)
+
+
+def _flash(q, k, v):
+    return pa.flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def _flash_seg(q, k, v, seg):
+    return pa.flash_attention(q, k, v, causal=True, segment_ids=seg,
+                              interpret=False)
+
+
+def _flash_grad(q, k, v):
+    # the backward is a chunked XLA recompute, no kernel of its own:
+    # keep the forward's value live, as a train step does
+    return jax.value_and_grad(lambda *a: _flash(*a).astype(F32).sum(),
+                              argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("fn,extra", [
+    (_flash, ()),
+    (_flash_seg, (((8, 1024), I32),)),
+    (_flash_grad, ()),
+], ids=["causal", "causal_segment_ids", "custom_vjp_backward"])
+def test_flash_attention_compiles(chip, fn, extra):
+    assert "tpu_custom_call" in _compile(chip, fn, _QKV, _QKV, _QKV,
+                                         *extra)
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_decode_attention_compiles(chip, dtype):
+    def fn(q, k, v, lens):
+        return pa.decode_attention(q, k, v, lens, interpret=False)
+    cache = ((8, 16, 1024, 128), dtype)
+    assert "tpu_custom_call" in _compile(
+        chip, fn, ((8, 16, 128), F32), cache, cache, ((8,), I32))
+
+
+def _paged(chip, num_heads, head_dim, dtype):
+    dm = num_heads * head_dim
+    pool = ((8 * 64, 16, dm), dtype)    # 8 slots x 64 blocks of 16 rows
+
+    def fn(q, kp, vp, lens, tables):
+        return pa.decode_attention_paged(q, kp, vp, lens, tables,
+                                         num_heads, interpret=False)
+    return _compile(chip, fn, ((8, 1, dm), F32), pool, pool,
+                    ((8,), I32), ((8, 64), I32))
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_decode_attention_paged_compiles(chip, dtype):
+    assert "tpu_custom_call" in _paged(chip, 16, 128, dtype)
+
+
+def test_decode_attention_paged_head_dim_64_takes_reference(chip):
+    """d_model 512 / 8 heads: a head's (16, 64) column slice of a pool
+    block is half a lane tile, which Mosaic refuses ("last two
+    dimensions of your block shape ... divisible by 8 and 128"). The
+    gate must hand that geometry to the XLA gather — counted, compiled,
+    and with no kernel in the program — not let it reach the lowering."""
+    before = kernel_path.counts().get(
+        "decode_attention_paged", {}).get("xla", 0)
+    assert "tpu_custom_call" not in _paged(chip, 8, 64, F32)
+    assert kernel_path.counts()["decode_attention_paged"]["xla"] == \
+        before + 1
+
+
+@pytest.mark.parametrize("k,n", [(2048, 8192), (8192, 2048),
+                                 (2048, 32768)])
+def test_int8_matmul_compiles(chip, k, n):
+    def fn(x, wq, ws):
+        return quant_ops._pallas_int8_matmul(x, wq, ws, interpret=False)
+    assert "tpu_custom_call" in _compile(
+        chip, fn, ((8, k), F32), ((k, n), I8), ((n,), F32))
+
+
+@pytest.mark.parametrize("c,hw,o", [(512, 28, 128), (256, 14, 1024)],
+                         ids=["conv3_x_reduce", "conv4_x_expand"])
+def test_conv1x1_bn_compiles(chip, c, hw, o):
+    """ResNet-50 bottleneck 1x1 convs at batch 256, f32 activations."""
+    def fn(x, w):
+        return pallas_conv_bn._pallas_1x1(x, w, interpret=False)
+    assert "tpu_custom_call" in _compile(
+        chip, fn, ((256, c, hw, hw), F32), ((o, c, 1, 1), F32))
