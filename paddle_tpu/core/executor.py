@@ -307,12 +307,16 @@ def _execute_vjp_grad(op, env, block, trace):
 
 
 def run_block(block, env, trace):
-    """Trace every op of ``block`` against ``env`` (name -> traced value)."""
+    """Trace every op of ``block`` against ``env`` (name -> traced value).
+    Each op is traced under ``jax.named_scope(op.type)`` (the analogue of
+    the reference executor's per-op RecordEvent): trace-time only, and an
+    XProf/Perfetto view of a step then groups device ops by Program op."""
     for i, op in enumerate(block.ops):
-        if op.type == "vjp_grad":
-            _execute_vjp_grad(op, env, block, trace)
-        else:
-            _execute_forward_op(op, env, block, trace)
+        with jax.named_scope(op.type):
+            if op.type == "vjp_grad":
+                _execute_vjp_grad(op, env, block, trace)
+            else:
+                _execute_forward_op(op, env, block, trace)
         if trace.nan_guards is not None:
             for name in op.output_names():
                 val = env.get(name)
@@ -605,11 +609,11 @@ class Executor:
         (FLOPs / bytes accessed — the MFU and bandwidth-roofline
         numerators, cf. tools/mfu_probe.py)."""
         t0 = time.perf_counter()
-        with _tracing.span("executorTrace", key=entry.key_id):
+        with _tracing.span("executor:trace", key=entry.key_id):
             lowered = entry.fn.lower(state_rw, state_ro, feed_arrays)
         t1 = time.perf_counter()
         _TRACE_SECONDS.labels(key=entry.key_id).set(t1 - t0)
-        with _tracing.span("executorCompile", key=entry.key_id):
+        with _tracing.span("executor:compile", key=entry.key_id):
             compiled = lowered.compile()
         _COMPILE_SECONDS.labels(key=entry.key_id).set(
             time.perf_counter() - t1)
@@ -635,8 +639,9 @@ class Executor:
         # One thread-local read; no config flag, no cost when off.
         _rt_ctx = _rtrace.current()
         _rt_t0 = time.perf_counter() if _rt_ctx is not None else 0.0
-        entry, state_rw, state_ro, feed_arrays = self._prepare(
-            program, feed, fetch_list, scope, donate_state)
+        with _tracing.span("executor:prepare"):
+            entry, state_rw, state_ro, feed_arrays = self._prepare(
+                program, feed, fetch_list, scope, donate_state)
         from .. import config as _config
         if entry.aot is None and not entry.aot_failed and \
                 self.strategy is None and \
@@ -669,25 +674,32 @@ class Executor:
                 else:
                     if pcache is not None and entry.pkey is not None:
                         pcache.store(entry.pkey, entry.aot)
-        if entry.aot is not None:
-            try:
-                new_state, fetches, guards = entry.aot(
-                    state_rw, state_ro, feed_arrays)
-            except (TypeError, ValueError):
-                # aval drift vs the AOT signature (e.g. a scope var was
-                # replaced with a new shape): jit retraces, AOT can't —
-                # and would flap if recompiled, so stay on jit for good
-                entry.aot = None
-                entry.aot_failed = True
+        # executor:call ends when the step is enqueued (dispatch is
+        # asynchronous); the wait for its result is executor:fetch, or
+        # the caller's own fetch under return_numpy=False
+        with _tracing.span("executor:call", key=entry.key_id):
+            if entry.aot is not None:
+                try:
+                    new_state, fetches, guards = entry.aot(
+                        state_rw, state_ro, feed_arrays)
+                except (TypeError, ValueError):
+                    # aval drift vs the AOT signature (e.g. a scope var
+                    # was replaced with a new shape): jit retraces, AOT
+                    # can't — and would flap if recompiled, so stay on
+                    # jit for good
+                    entry.aot = None
+                    entry.aot_failed = True
+                    new_state, fetches, guards = entry.fn(
+                        state_rw, state_ro, feed_arrays)
+            else:
                 new_state, fetches, guards = entry.fn(
                     state_rw, state_ro, feed_arrays)
-        else:
-            new_state, fetches, guards = entry.fn(
-                state_rw, state_ro, feed_arrays)
-        for n, v in new_state.items():
-            scope.set_var(n, v)
+        with _tracing.span("executor:writeback"):
+            for n, v in new_state.items():
+                scope.set_var(n, v)
         if return_numpy:
-            fetches = [np.asarray(v) for v in fetches]
+            with _tracing.span("executor:fetch"):
+                fetches = [np.asarray(v) for v in fetches]
         if guards:
             # Per-op output scan (reference framework/executor.cc:120-128).
             bad = [k for k, ok in guards.items() if not bool(ok)]

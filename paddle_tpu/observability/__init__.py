@@ -6,9 +6,20 @@ One subsystem feeds three consumers:
   (``metrics.REGISTRY.expose_text()``) and JSON
   (``metrics.REGISTRY.dump_json()``) exposition. The legacy
   ``utils.stat.StatSet`` table is a view over this registry.
-* ``tracing``  — nestable host spans -> Chrome trace-event JSON
-  (``tracing.emit_chrome_trace(path)``), Perfetto-loadable next to the
-  jax.profiler device trace.
+* ``tracing``  — one span primitive, ``tracing.span(name, **args)``,
+  named ``<layer>:<phase>`` (``scheduler:``, ``session:``,
+  ``executor:``). Every span is a ``jax.profiler.TraceAnnotation``: it
+  reaches any ``jax.profiler`` trace without a flag, on the device
+  trace's clock, and costs under a microsecond with no profiler session.
+  Armed (``telemetry`` or ``tracing.start()``) it is also a ring event,
+  exported as Chrome trace-event JSON
+  (``tracing.emit_chrome_trace(path)``) with one
+  ``(perf_counter_ns, time_ns)`` anchor. In Perfetto a decode round
+  reads, on the ``generation-scheduler`` thread, as a
+  ``scheduler:host_turn`` (``scheduler:deliver``, ``scheduler:admit``,
+  ``session:step_prepare``, ``session:step_dispatch`` inside) followed
+  by a ``session:step_wait`` (``GenerationScheduler``'s docstring, "The
+  dispatcher's clock").
 * instrumentation hooks in ``core.executor`` (compile-cache hits/misses,
   per-key compile wall time + XLA FLOPs/bytes), ``trainer`` (step-latency
   histogram, examples/sec, checkpoint time, periodic structured log), and
@@ -16,9 +27,9 @@ One subsystem feeds three consumers:
 
 All hooks are gated by the config flag ``telemetry``
 (``config.set_flags(telemetry=True)``); disabled, the per-step cost is a
-flag check. Setting the flag also arms the span ring buffer, so
-``timer()``/``RecordEvent`` call sites across the codebase record trace
-events with no further setup.
+flag check and an annotation per span. Setting the flag also arms the
+span ring buffer, so ``timer()``/``RecordEvent`` call sites across the
+codebase record trace events with no further setup.
 
 Recovery events are the exception to the gating: the resilience layer's
 counters (``paddle_resilience_*`` from ``resilience/supervisor.py`` —

@@ -65,8 +65,7 @@ __all__ = ["TraceContext", "NO_TRACE", "mint", "adopt", "event",
            "discard", "current", "activate", "trace_events", "span_tree",
            "chrome_trace", "trace_ids", "enabled", "clear",
            "QUEUE_WAIT_MS",
-           "PREFILL_MS", "DECODE_STEP_MS", "REPLAY_RECOVERY_MS",
-           "E2E_MS"]
+           "PREFILL_MS", "DECODE_STEP_MS", "E2E_MS"]
 
 # -- always-on per-stage latency histograms (ms, log-spaced) -----------
 QUEUE_WAIT_MS = _metrics.REGISTRY.histogram(
@@ -81,11 +80,6 @@ PREFILL_MS = _metrics.REGISTRY.histogram(
 DECODE_STEP_MS = _metrics.REGISTRY.histogram(
     "paddle_request_decode_step_ms",
     "One decode step for all of a session's active slots",
-    buckets=_metrics.LATENCY_MS_BUCKETS)
-REPLAY_RECOVERY_MS = _metrics.REGISTRY.histogram(
-    "paddle_request_replay_recovery_ms",
-    "Session failure -> the replayed request decoding again "
-    "(re-queue wait + replay prefill), per failover hop",
     buckets=_metrics.LATENCY_MS_BUCKETS)
 E2E_MS = _metrics.REGISTRY.histogram(
     "paddle_request_e2e_ms",

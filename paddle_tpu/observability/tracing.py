@@ -1,16 +1,25 @@
-"""Host-side trace spans, exported as Chrome trace-event JSON.
+"""Host-side trace spans: on the profiler's clock always, and in a ring
+exported as Chrome trace-event JSON when armed.
 
 The span half of the reference Fluid profiler (``paddle/platform/
-profiler.h:25-131`` RecordEvent + GenProfileReport): nestable host spans
-recorded per thread as complete ("ph":"X") events, written by
-``emit_chrome_trace`` in the Chrome trace-event format — load the file in
-Perfetto/chrome://tracing, side by side with the device trace that
-``utils.profiler.profiler(trace_dir=...)`` captures via jax.profiler.
+profiler.h:25-131`` RecordEvent + GenProfileReport). ``span()`` is the one
+primitive. It always yields a ``jax.profiler.TraceAnnotation``, so every
+span of the program lands in whatever ``jax.profiler`` trace is being
+taken (``utils.profiler.profiler(trace_dir=...)``, the benchmark's traced
+runs, an XProf capture of a live server) on the same clock as the device's
+operations, with no flag; with no profiler session the annotation is a
+flag check in C++. When the tracer is armed (config flag ``telemetry`` or
+``start()``) the span is also recorded per thread as a complete
+("ph":"X") event in a bounded ring, which ``emit_chrome_trace`` writes in
+the Chrome trace-event format for Perfetto/chrome://tracing. The ring
+reads ``time.perf_counter_ns``; the export carries one
+``(perf_counter_ns, time_ns)`` anchor so that the file can be laid beside
+the device trace, whose clock is the wall clock's nanoseconds.
 
-Hot-path discipline: ``span()`` when the tracer is inactive returns the
-preallocated ``NULL_SPAN`` singleton — one attribute check, no
-allocation. Events live in a bounded ring buffer so always-on telemetry
-(config flag ``telemetry``) cannot grow memory without bound.
+Names are ``<layer>:<phase>`` with the layers of PERF.md section 3
+(``scheduler:``, ``session:``, ``executor:``); the trainer's and the
+stateless serving tier's older camel-case names are kept because tests and
+``tools/telemetry_probe.py`` read them.
 
 Nesting is positional, as in chrome://tracing: two "X" events on the same
 pid/tid nest iff one's [ts, ts+dur] window contains the other's.
@@ -23,42 +32,35 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["span", "instant", "start", "stop", "active", "clear",
            "events", "emit_chrome_trace", "chrome_trace_doc",
-           "NULL_SPAN", "MAX_EVENTS"]
+           "MAX_EVENTS"]
 
 MAX_EVENTS = 200_000  # ring-buffer bound for always-on tracing
 
 
-class _NullSpan:
-    """Singleton no-op context manager: the disabled-tracer fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    """An armed span: the annotation plus the ring event, the ring's
+    clock read inside the annotation's so the two agree on duration."""
+
+    __slots__ = ("_tracer", "name", "args", "_annotation", "_t0")
 
     def __init__(self, tracer, name, args):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._annotation = TraceAnnotation(name, **args)
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
         self._tracer._record(self.name, self._t0, t1, self.args)
         return False
 
@@ -70,7 +72,7 @@ class Tracer:
         self._explicit = 0              # nested start()/stop() holds
         self._events = collections.deque(maxlen=MAX_EVENTS)
         self._lock = threading.Lock()
-        self._epoch = time.perf_counter()
+        self._epoch = time.perf_counter_ns()
 
     # -- lifecycle -------------------------------------------------------
     def _sync_enabled(self):
@@ -99,15 +101,11 @@ class Tracer:
             self._events.clear()
 
     # -- recording -------------------------------------------------------
-    def span(self, name, args=None):
-        if not self.enabled:
-            return NULL_SPAN
-        return _Span(self, name, args)
-
     def _record(self, name, t0, t1, args):
+        """One complete event from two ``perf_counter_ns`` readings."""
         ev = {"ph": "X", "name": name, "cat": "host",
-              "ts": (t0 - self._epoch) * 1e6,
-              "dur": (t1 - t0) * 1e6,
+              "ts": (t0 - self._epoch) / 1e3,
+              "dur": (t1 - t0) / 1e3,
               "pid": os.getpid(), "tid": threading.get_ident()}
         if args:
             ev["args"] = dict(args)
@@ -118,7 +116,7 @@ class Tracer:
         if not self.enabled:
             return
         ev = {"ph": "i", "name": name, "cat": "host", "s": "t",
-              "ts": (time.perf_counter() - self._epoch) * 1e6,
+              "ts": self.now_us(),
               "pid": os.getpid(), "tid": threading.get_ident()}
         if args:
             ev["args"] = dict(args)
@@ -128,7 +126,16 @@ class Tracer:
     # -- export ----------------------------------------------------------
     def now_us(self):
         """Current time on the trace clock (same scale as event ts)."""
-        return (time.perf_counter() - self._epoch) * 1e6
+        return (time.perf_counter_ns() - self._epoch) / 1e3
+
+    def clock_anchor(self):
+        """One reading of both clocks, taken together: ``ts_us`` on the
+        trace clock is ``perf_counter_ns`` is ``time_ns``. The profiler's
+        device trace is stamped in wall-clock nanoseconds, so an event at
+        ``ts`` sits at ``time_ns + (ts - ts_us) * 1e3`` there."""
+        pc, wall = time.perf_counter_ns(), time.time_ns()
+        return {"perf_counter_ns": pc, "time_ns": wall,
+                "ts_us": (pc - self._epoch) / 1e3}
 
     def events(self, ts_from=None, ts_to=None):
         with self._lock:
@@ -141,8 +148,10 @@ class Tracer:
 
     def emit_chrome_trace(self, path, ts_from=None, ts_to=None):
         """Write {"traceEvents": [...]} (Perfetto/chrome://tracing);
-        optionally windowed to [ts_from, ts_to] on the trace clock."""
+        optionally windowed to [ts_from, ts_to] on the trace clock. The
+        document's ``metadata`` holds the ``clock_anchor``."""
         doc = chrome_trace_doc(self.events(ts_from, ts_to))
+        doc["metadata"] = {"clock_anchor": self.clock_anchor()}
         with open(path, "w") as f:
             json.dump(doc, f)
         return path
@@ -172,8 +181,12 @@ _TRACER = Tracer()
 
 
 def span(name, **args):
-    """``with span("feed"): ...`` — NULL_SPAN when tracing is off."""
-    return _TRACER.span(name, args or None)
+    """``with span("scheduler:admit", round=7): ...``. Always a
+    ``TraceAnnotation`` (it reaches any running ``jax.profiler`` trace and
+    costs a flag check otherwise); armed, the ring event as well."""
+    if _TRACER.enabled:
+        return _Span(_TRACER, name, args)
+    return TraceAnnotation(name, **args)
 
 
 def instant(name, **args):

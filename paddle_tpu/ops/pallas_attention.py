@@ -185,11 +185,11 @@ def _forward(q, k, v, seg, causal, block_q, interpret):
     )
     if seg is None:
         return pl.pallas_call(
-            functools.partial(_kernel, **kw),
+            functools.partial(_kernel, **kw), name="flash_attention_fwd",
             in_specs=qkv_specs, **common)(q, k, v)
     seg3 = seg.reshape(bh, 1, t)  # (1,1,t) blocks satisfy Mosaic's
     return pl.pallas_call(         # (8,128)-or-whole-dim tiling rule
-        functools.partial(_kernel_seg, **kw),
+        functools.partial(_kernel_seg, **kw), name="flash_attention_fwd_seg",
         in_specs=qkv_specs + [
             pl.BlockSpec((1, 1, t), lambda b, i, j: (b, 0, 0)),
             pl.BlockSpec((1, 1, t), lambda b, i, j: (b, 0, 0)),
@@ -381,7 +381,7 @@ def _decode_forward(q, k, v, lengths, interpret):
                           nk=c // bk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        interpret=interpret)(lens, q, k, v)
+        name="decode_attention", interpret=interpret)(lens, q, k, v)
 
 
 def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
@@ -563,6 +563,7 @@ def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
                           block_k=bs, nk=mb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, 1, dm), q.dtype),
+        name="decode_attention_paged",
         interpret=interpret)(lens, tab, q, k_pool, v_pool)
 
 

@@ -84,8 +84,12 @@ Metrics (always-on, like the serving front door):
 ``paddle_generation_requests_total``, ``_tokens_total``,
 ``_prefills_total``, ``_decode_steps_total``,
 ``_retired_total{reason}``, ``_slot_occupancy``,
-``_ttft_seconds`` (time to first token), ``_inter_token_seconds``,
-``_request_seconds``; recovery: ``_failover_total``,
+``_ttft_seconds`` (time to first token), ``_inter_token_seconds``;
+the dispatcher's clock by phase: ``_host_ms_total{phase}``,
+``_device_wait_ms_total``, and what a step and a prefill worked on:
+``_context_tokens_total``, ``_prompt_tokens_total``,
+``_prefill_padded_tokens_total`` (see ``GenerationScheduler``, "The
+dispatcher's clock"); recovery: ``_failover_total``,
 ``_replayed_tokens_total``, ``_session_rebuilds_total``,
 ``_step_timeouts_total``, ``_failover_recovery_seconds``.
 Shed/deadline events share the serving counters
@@ -111,6 +115,7 @@ new programs — the default dispatcher path is byte-identical.
 """
 
 import collections
+import contextlib
 import itertools
 import queue
 import threading
@@ -166,9 +171,27 @@ _TTFT_SECONDS = _metrics.REGISTRY.histogram(
 _INTER_TOKEN_SECONDS = _metrics.REGISTRY.histogram(
     "paddle_generation_inter_token_seconds",
     "Per-sequence latency between consecutive tokens")
-_REQUEST_SECONDS = _metrics.REGISTRY.histogram(
-    "paddle_generation_request_seconds",
-    "Submit -> Future resolution for completed generations")
+_HOST_MS = _metrics.REGISTRY.counter(
+    "paddle_generation_host_ms_total",
+    "Dispatcher milliseconds in host turns (no decode call of the "
+    "session queued on the device), by phase: deliver, admit (holds the "
+    "prefill's device call), prepare, dispatch, other",
+    labelnames=("phase",))
+_DEVICE_WAIT_MS = _metrics.REGISTRY.counter(
+    "paddle_generation_device_wait_ms_total",
+    "Dispatcher milliseconds blocked on a decode step's result")
+_CONTEXT_TOKENS = _metrics.REGISTRY.counter(
+    "paddle_generation_context_tokens_total",
+    "Cached tokens attended by decode steps: per step, the sum over "
+    "the slots that advanced of their context length, the new token "
+    "included")
+_PROMPT_TOKENS = _metrics.REGISTRY.counter(
+    "paddle_generation_prompt_tokens_total",
+    "Prompt tokens really prefilled (the prompt less its prefix-cache "
+    "hit)")
+_PREFILL_PADDED_TOKENS = _metrics.REGISTRY.counter(
+    "paddle_generation_prefill_padded_tokens_total",
+    "Tokens the prefill programs ran: the bucket width of each prefill")
 _FAILOVERS = _metrics.REGISTRY.counter(
     "paddle_generation_failover_total",
     "Requests re-queued for token-replay after their session failed "
@@ -375,6 +398,9 @@ class GenerationSession:
             if not self.scope.has_var(name):
                 self.scope.set_var(name, jnp.zeros(shape, dtype))
         n = spec.slots
+        # the scheduler's round, stamped on this session's spans (0 when
+        # driven directly): set by the one thread that drives the session
+        self.round = 0
         self.lengths = np.zeros(n, np.int64)     # cached rows per slot
         self.last_token = np.zeros(n, np.int64)  # next token to decode
         self.active = np.zeros(n, bool)
@@ -709,7 +735,8 @@ class GenerationSession:
                 f_pos: np.asarray([n - 1], np.int32),
                 f_slot: np.asarray([slot], np.int32)}
         self._policy_prefill_feed(feed, n, seed, cstate)
-        with _tracing.span("generationPrefill", bucket=bucket):
+        with _tracing.span("session:prefill_call", round=self.round,
+                           bucket=bucket, slot=slot):
             outs = self.exe.run(
                 self.spec.prefill_programs[bucket], feed=feed,
                 fetch_list=[self.spec.prefill_fetch], scope=self.scope)
@@ -720,6 +747,8 @@ class GenerationSession:
         self._policy_admitted(slot, first, seed, cstate)
         self._draft_admit(prompt, slot, first)
         _PREFILLS.labels(bucket=bucket).inc()
+        _PROMPT_TOKENS.inc(n)
+        _PREFILL_PADDED_TOKENS.inc(bucket)
         return slot, first
 
     def _admit_paged(self, prompt, seed=0, cstate=None):
@@ -781,8 +810,8 @@ class GenerationSession:
             # the emitted token's index is the TOTAL length n
             # (= matched + w), prefix sharing included
             self._policy_prefill_feed(feed, n, seed, cstate)
-            with _tracing.span("generationPrefill", bucket=bucket,
-                               hist=matched):
+            with _tracing.span("session:prefill_call", round=self.round,
+                               bucket=bucket, slot=slot, hist=matched):
                 outs = self.exe.run(
                     self.spec.prefill_programs[bucket], feed=feed,
                     fetch_list=[self.spec.prefill_fetch],
@@ -808,6 +837,8 @@ class GenerationSession:
         if len(self.prefill_log) > 4096:     # keep a list (tests
             del self.prefill_log[:2048]      # slice it), bounded
         _PREFILLS.labels(bucket=bucket).inc()
+        _PROMPT_TOKENS.inc(w)
+        _PREFILL_PADDED_TOKENS.inc(bucket)
         return slot, first
 
     def step(self):
@@ -861,26 +892,28 @@ class GenerationSession:
         act = np.flatnonzero(self.active)
         if act.size == 0:
             return None
-        if (self.lengths[act] >= self.max_pos).any():
-            over = [int(s) for s in act
-                    if self.lengths[s] >= self.max_pos]
-            raise RuntimeError(
-                "slots %s are at cache capacity %d — retire before "
-                "stepping" % (over, self.max_pos))
-        if self.speculative:
-            W = self.policy.speculate_k + 1
-            if all(self.capacity_left(int(s)) >= W for s in act):
-                return self._prepare_spec(act)
-            # near capacity: a window write would overrun the cache —
-            # fall back to plain single-token rounds, which finish
-            # these slots (speculation resumes once they retire)
-        if self.paged:
-            return self._prepare_paged(act)
-        f_tok, f_pos = self.spec.decode_feeds[:2]
-        feed = {f_tok: self.last_token.reshape(-1, 1).copy(),
-                f_pos: self.lengths.astype(np.int32)}
-        self._policy_decode_feed(feed)
-        return (act, frozenset(), feed)
+        with _tracing.span("session:step_prepare", round=self.round,
+                           active=int(act.size)):
+            if (self.lengths[act] >= self.max_pos).any():
+                over = [int(s) for s in act
+                        if self.lengths[s] >= self.max_pos]
+                raise RuntimeError(
+                    "slots %s are at cache capacity %d — retire before "
+                    "stepping" % (over, self.max_pos))
+            if self.speculative:
+                W = self.policy.speculate_k + 1
+                if all(self.capacity_left(int(s)) >= W for s in act):
+                    return self._prepare_spec(act)
+                # near capacity: a window write would overrun the cache —
+                # fall back to plain single-token rounds, which finish
+                # these slots (speculation resumes once they retire)
+            if self.paged:
+                return self._prepare_paged(act)
+            f_tok, f_pos = self.spec.decode_feeds[:2]
+            feed = {f_tok: self.last_token.reshape(-1, 1).copy(),
+                    f_pos: self.lengths.astype(np.int32)}
+            self._policy_decode_feed(feed)
+            return (act, frozenset(), feed)
 
     def _policy_decode_feed(self, feed):
         """Append the decode-policy feeds to a decode-step feed dict.
@@ -969,7 +1002,7 @@ class GenerationSession:
             info[s] = (L, tab)
         return {"slots": info, "starved": frozenset(self._starved)}
 
-    def step_run(self, prepared):
+    def step_run(self, prepared, enqueued=None):
         """Phase 2 of a decode step: the device call plus result
         application. Touches no allocator state — safe to execute on
         the scheduler's bounded (leakable) worker thread; the feeds
@@ -977,16 +1010,28 @@ class GenerationSession:
         speculative round is the one exception: it runs drafting,
         verification AND pool rollback here, which is why the
         scheduler refuses step_timeout_ms on speculative sessions —
-        that round only ever executes inline on the dispatcher.)"""
+        that round only ever executes inline on the dispatcher.)
+
+        The call is cut in two where the host stops working and starts
+        waiting: ``session:step_dispatch`` ends with the step on the
+        device's queue, ``session:step_wait`` is the fetch of its
+        tokens. ``enqueued``, when given, is called between the two
+        (the scheduler ends its host turn there). A speculative round
+        interleaves several device calls with host work and is not
+        cut: it never calls ``enqueued``."""
         if isinstance(prepared, dict):
             return self._step_run_spec(prepared)
         act, starved, feed = prepared
-        with _tracing.span("generationStep",
+        with _tracing.span("session:step_dispatch", round=self.round,
                            active=int(act.size)):
             outs = self.exe.run(
                 self.spec.decode_program, feed=feed,
-                fetch_list=[self.spec.decode_fetch], scope=self.scope)
-        nxt = np.asarray(outs[0]).reshape(-1)
+                fetch_list=[self.spec.decode_fetch], scope=self.scope,
+                return_numpy=False)
+        if enqueued is not None:
+            enqueued()
+        with _tracing.span("session:step_wait", round=self.round):
+            nxt = np.asarray(outs[0]).reshape(-1)
         result = {}
         for s in act:
             s = int(s)
@@ -1054,7 +1099,8 @@ class GenerationSession:
             window[0, 1:] = drafts[s]
             pix = np.clip(L + np.arange(W), 0,
                           self.spec.max_len - 1).astype(np.int32)
-            with _tracing.span("generationVerify", window=W):
+            with _tracing.span("session:verify_call", round=self.round,
+                               slot=s, window=W):
                 outs = self.exe.run(
                     self.spec.verify_program,
                     feed={vtok: window,
@@ -1278,6 +1324,32 @@ class GenerationScheduler:
     ``drain()`` stops admission and serves everything accepted;
     ``close()`` is the bounded fast exit. ``swap_weights(params)``
     installs new values between decode steps (see method docs).
+
+    **The dispatcher's clock.** The dispatcher's time is cut, by spans
+    (``observability/tracing.py``: in any ``jax.profiler`` trace, no
+    flag) and by always-on counters read from the same clock readings,
+    into three kinds of stretch. A *host turn*
+    (``scheduler:host_turn``, numbered ``round=``) runs from the moment
+    a decode step's tokens are on the host to the moment the next
+    decode call is on the device's queue: the time the device has
+    nothing of the session queued. Its named children are
+    ``scheduler:deliver`` (tokens to requests, finish, retire),
+    ``scheduler:admit`` (one per admitted request; holds the
+    prefill's device call ``session:prefill_call``),
+    ``session:step_prepare`` and ``session:step_dispatch``; each adds
+    its milliseconds to ``paddle_generation_host_ms_total{phase}``, and
+    ``phase="other"`` takes the turn less its named children (swap,
+    expiry and queue bookkeeping), so the five phases sum to the host
+    turns. A *device wait* (``session:step_wait``,
+    ``paddle_generation_device_wait_ms_total``) is the dispatcher
+    blocked on the step's result; prepare + dispatch + wait is the
+    value observed into ``paddle_request_decode_step_ms``. An *idle
+    wait* (``scheduler:idle_wait``) is the dispatcher blocked on its
+    queue with nothing active. With ``step_timeout_ms`` the call runs
+    on a worker thread, which records the session's spans; the
+    dispatcher's own dispatch phase is then the hand-over and its wait
+    covers the worker's dispatch. A speculative round is not cut: it
+    counts as dispatch, with the device calls as spans inside.
     """
 
     def __init__(self, sessions, max_queue=256, deadline_ms=None,
@@ -1316,6 +1388,14 @@ class GenerationScheduler:
         self._wait_ewma = 0.0
         self._active = {}   # (session_index, slot) -> _GenRequest
         self._sched_id = next(_SCHED_SEQ)
+        # the dispatcher's clock (class docstring): the open host turn's
+        # span, start and named-children seconds; the step in flight's
+        # dispatch start and enqueue time. Owned by whichever thread
+        # drives the loop (the dispatcher, or a dispatcherless drain())
+        self._round = 0
+        self._turn = None
+        self._turn_t0 = self._turn_named = 0.0
+        self._t_dispatch = self._t_enqueued = None
         if deadline_ms is None:
             deadline_ms = _config.get_flag("serving_deadline_ms")
         self.default_deadline_ms = deadline_ms
@@ -1762,8 +1842,61 @@ class GenerationScheduler:
                                   "no healthy generation session for "
                                   "this prompt"))
             return True
-        self._admit_item(item, si)
+        with self._host_phase("scheduler:admit", "admit", session=si):
+            self._admit_item(item, si)
         return True
+
+    # -- the dispatcher's clock (class docstring) -------------------------
+    def _host_ms(self, phase, seconds):
+        self._turn_named += seconds
+        _HOST_MS.labels(phase=phase).inc(seconds * 1e3)
+
+    @contextlib.contextmanager
+    def _host_phase(self, name, phase, **args):
+        """A named child of the open host turn: its span, and its
+        milliseconds on the phase's counter."""
+        t0 = time.perf_counter()
+        try:
+            with _tracing.span(name, round=self._round, **args):
+                yield
+        finally:
+            self._host_ms(phase, time.perf_counter() - t0)
+
+    def _turn_open(self, now):
+        self._round += 1
+        self._turn_t0, self._turn_named = now, 0.0
+        self._turn = _tracing.span("scheduler:host_turn",
+                                   round=self._round)
+        self._turn.__enter__()
+
+    def _turn_close(self, now):
+        if self._turn is None:
+            return
+        self._turn.__exit__(None, None, None)
+        self._turn = None
+        # float rounding can leave the remainder a hair under zero
+        self._host_ms("other", max(
+            0.0, now - self._turn_t0 - self._turn_named))
+
+    def _enqueued(self, now=None):
+        """The decode call is on the device's queue (``step_run`` calls
+        this between dispatch and wait): the host turn ends here."""
+        if now is None:
+            now = time.perf_counter()
+        self._host_ms("dispatch", now - self._t_dispatch)
+        self._turn_close(now)
+        self._t_enqueued = now
+
+    def _idle_wait(self, wait, *args):
+        """Block in ``wait(*args)`` (the queue read, a back-off sleep)
+        as ``scheduler:idle_wait``, outside any host turn; what it
+        returns, if anything, opens the next one."""
+        self._turn_close(time.perf_counter())
+        with _tracing.span("scheduler:idle_wait", round=self._round):
+            got = wait(*args)
+        if got is not None:
+            self._turn_open(time.perf_counter())
+        return got
 
     def _admit_item(self, item, si):
         wait = time.perf_counter() - item.t_queued
@@ -1788,6 +1921,7 @@ class GenerationScheduler:
                     # exactly like the KV cache
                     c = sess.policy.constraint
                     cstate = c.advance_many(c.start, item.tokens)
+                sess.round = self._round
                 slot, first = sess.admit(item.history(),
                                          seed=item.seed, cstate=cstate)
         except ValueError as exc:
@@ -1825,8 +1959,6 @@ class GenerationScheduler:
             # the failover actually cost
             _REPLAYED_TOKENS.inc(len(item.tokens))
             _RECOVERY_SECONDS.observe(now_pc - item.t_queued)
-            _rtrace.REPLAY_RECOVERY_MS.observe(
-                (now_pc - item.t_queued) * 1e3)
         else:
             _REQUESTS.inc()
             _TTFT_SECONDS.observe(now_pc - item.t_submit)
@@ -1939,7 +2071,6 @@ class GenerationScheduler:
                 % len(item.tokens)))
         else:
             e2e = time.perf_counter() - item.t_submit
-            _REQUEST_SECONDS.observe(e2e)
             _rtrace.E2E_MS.observe(e2e * 1e3)
             if item.ctx is not None:
                 _rtrace.event(item.ctx, "resolve", reason=reason,
@@ -1977,7 +2108,7 @@ class GenerationScheduler:
         self._update_occupancy()
         return True
 
-    def _step_session(self, si, sess, prepared=None):
+    def _step_session(self, si, sess, prepared=None, enqueued=None):
         """One session's decode step plus its fault hooks — shared by
         the inline path and the bounded worker, so injected faults
         (including a wedge callback) land inside whatever bounds the
@@ -1988,7 +2119,7 @@ class GenerationScheduler:
         _faults.fire_point("generation_session_wedge", index=si)
         _faults.fire_point("generation_step_fail", index=si)
         if prepared is not None:
-            return sess.step_run(prepared)
+            return sess.step_run(prepared, enqueued)
         return sess.step()
 
     def _step_timed(self, si, sess, prepared):
@@ -2088,6 +2219,8 @@ class GenerationScheduler:
             # event below
             step_ctx = next((it.ctx for _, it in mine
                              if it.ctx is not None), None)
+            sess.round = self._round
+            self._t_dispatch = self._t_enqueued = failure = None
             t_step0 = time.perf_counter()
             try:
                 # step_prepare runs OUTSIDE the activated context on
@@ -2098,24 +2231,53 @@ class GenerationScheduler:
                 prepared = sess.step_prepare()
                 if prepared is None:
                     toks = {}
-                elif self.step_timeout is not None:
-                    toks = self._step_timed(si, sess, prepared)
                 else:
-                    with _rtrace.activate(step_ctx):
-                        toks = self._step_session(si, sess, prepared)
+                    self._t_dispatch = time.perf_counter()
+                    self._host_ms("prepare", self._t_dispatch - t_step0)
+                    if self.step_timeout is not None:
+                        # handed to a worker: from here the dispatcher
+                        # only waits
+                        self._enqueued()
+                        toks = self._step_timed(si, sess, prepared)
+                    else:
+                        with _rtrace.activate(step_ctx):
+                            toks = self._step_session(
+                                si, sess, prepared, self._enqueued)
             except Exception as exc:
-                hang = isinstance(exc, _sres.ServingTimeoutError)
-                self._on_session_failure(si, sess, mine, exc,
+                failure = exc
+            now_pc = time.perf_counter()
+            if self._t_dispatch is not None:
+                # the step reached the device call. One clock reading
+                # per boundary, so prepare + dispatch + wait is the
+                # step's time exactly; a call that never reported its
+                # enqueue (a speculative round, a failed dispatch) is
+                # all dispatch
+                if self._t_enqueued is None:
+                    self._enqueued(now_pc)
+                _DEVICE_WAIT_MS.inc((now_pc - self._t_enqueued) * 1e3)
+                self._turn_open(now_pc)
+            if failure is not None:
+                hang = isinstance(failure, _sres.ServingTimeoutError)
+                self._on_session_failure(si, sess, mine, failure,
                                          hang=hang)
                 continue
             if breaker is not None:
                 breaker.record_success()
                 self._trial_failures[si] = 0
             _STEPS.inc()
-            now_pc = time.perf_counter()
             step_ms = (now_pc - t_step0) * 1e3
             _rtrace.DECODE_STEP_MS.observe(step_ms)
-            advanced = 0
+            # before delivery retires slots and zeroes their lengths
+            _CONTEXT_TOKENS.inc(int(sum(sess.lengths[s] for s in toks)))
+            _TOKENS.inc(self._deliver(si, sess, mine, toks, now_pc,
+                                      step_ms))
+
+    def _deliver(self, si, sess, mine, toks, now_pc, step_ms):
+        """Hand a step's tokens to their requests; finish and retire
+        what ended. Returns the number of tokens delivered."""
+        advanced = 0
+        with self._host_phase("scheduler:deliver", "deliver",
+                              active=len(mine)):
             for slot, it in mine:
                 if slot not in toks:
                     # paged pool exhausted for this sequence (no
@@ -2153,7 +2315,6 @@ class GenerationScheduler:
                     # through pool bytes instead of the position
                     # table
                     _RETIRED.labels(reason="capacity").inc()
-                    _REQUEST_SECONDS.observe(now_pc - it.t_submit)
                     _rtrace.E2E_MS.observe((now_pc - it.t_submit) * 1e3)
                     if it.ctx is not None:
                         _rtrace.event(it.ctx, "resolve",
@@ -2183,7 +2344,7 @@ class GenerationScheduler:
                         # could not know — the rest of the list is
                         # discarded with the slot already retired
                         break
-            _TOKENS.inc(advanced)
+        return advanced
 
     # -- session rebuild -------------------------------------------------
     def _maybe_rebuild(self, si, force=False):
@@ -2495,7 +2656,7 @@ class GenerationScheduler:
                     # a rebuild hand-over or a breaker cooldown trial
                     # will make room in finite time: the parked
                     # request is served then, not failed now
-                    time.sleep(0.02)
+                    self._idle_wait(time.sleep, 0.02)
                     continue
                 # unplaceable with nothing in flight (external slot
                 # holders): resolve rather than spinning forever
@@ -2514,6 +2675,7 @@ class GenerationScheduler:
         retire here too: this epilogue is the one point EVERY
         shutdown shape reaches — including a drain() whose bounded
         join expired and whose caller never calls close()."""
+        self._turn_close(time.perf_counter())
         self._terminal = True
         self._drain_rebuilt()
         self._retire_breaker_gauges()
@@ -2543,7 +2705,7 @@ class GenerationScheduler:
                 # firing meanwhile (gated by _has_deadlines, so a
                 # deadline-free workload pays an attribute check)
                 self._expire_queued()
-                item = self._next_item(block=True)
+                item = self._idle_wait(self._next_item, True)
                 if item is None:
                     if self._closed:
                         return
@@ -2556,7 +2718,7 @@ class GenerationScheduler:
                     # every fitting session's slots are held outside
                     # this scheduler or a rebuild is in flight — back
                     # off instead of spinning
-                    time.sleep(0.02)
+                    self._idle_wait(time.sleep, 0.02)
 
     def _fill_slots(self):
         """Admit waiting requests into free slots without blocking.
@@ -2628,7 +2790,7 @@ class GenerationScheduler:
                 if self._recovery_pending(self._pending[0]):
                     # a rebuild hand-over or cooldown trial serves
                     # the parked items in finite time
-                    time.sleep(0.02)
+                    self._idle_wait(time.sleep, 0.02)
                     continue
                 # unplaceable with nothing in flight (external slot
                 # holders): resolve rather than spinning forever

@@ -29,11 +29,12 @@ _enabled = [False]
 
 def RecordEvent(name):
     """RAII span. Aggregates into the profiler table when profiling is
-    enabled; always records a Chrome-trace event when tracing is armed
-    (telemetry flag or profiler()/tracing.start())."""
+    enabled; always a profiler annotation, and a Chrome-trace event as
+    well when tracing is armed (telemetry flag or
+    profiler()/tracing.start())."""
     if _enabled[0]:
-        return _events.span(name)  # includes the trace event
-    return _tracing.span(name)     # NULL_SPAN when tracing is off
+        return _events.span(name)  # includes the annotation and the event
+    return _tracing.span(name)
 
 
 def enable_profiler():

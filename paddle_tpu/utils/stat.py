@@ -8,10 +8,11 @@ Since the observability PR, a ``StatSet`` is a *view* over the global
 metrics registry (``observability/metrics.py``): each ``add`` observes
 into the ``paddle_stat_span_seconds`` histogram labeled by (set, stat),
 each gauge lands in ``paddle_stat_gauge`` — so the legacy ``report()``
-table and the Prometheus/JSON expositions read the same numbers. Spans
-also record a host trace event when tracing is armed (config flag
-``telemetry`` or an explicit ``tracing.start()``), so every existing
-``timer()`` call site lights up in the Chrome trace for free.
+table and the Prometheus/JSON expositions read the same numbers. Like
+``tracing.span`` a timer span is a profiler annotation always, and a ring
+event as well when tracing is armed (config flag ``telemetry`` or an
+explicit ``tracing.start()``), so every existing ``timer()`` call site
+lights up in both traces for free.
 """
 
 import time
@@ -23,25 +24,25 @@ __all__ = ["timer", "stat_set", "StatSet"]
 
 
 class _SpanCtx:
-    """Timer span: one perf_counter pair, optional trace event, one
-    histogram observe. Cheaper than a contextlib generator on the step
-    hot path."""
+    """Timer span: a ``tracing.span`` (the profiler's annotation, and the
+    ring event when armed) around one perf_counter pair and one histogram
+    observe. Cheaper than a contextlib generator on the step hot path."""
 
-    __slots__ = ("_stat_set", "_key", "_t0")
+    __slots__ = ("_stat_set", "_key", "_span", "_t0")
 
     def __init__(self, stat_set_, key):
         self._stat_set = stat_set_
         self._key = key
+        self._span = _tracing.span(key)
 
     def __enter__(self):
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        tracer = _tracing._TRACER
-        if tracer.enabled:
-            tracer._record(self._key, self._t0, t1, None)
+        self._span.__exit__(*exc)
         self._stat_set.add(self._key, t1 - self._t0)
         return False
 

@@ -10,6 +10,7 @@ Skipped as a whole where the topology cannot be described (no libtpu).
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
 
@@ -53,6 +54,14 @@ def _compile(chip, fn, *shapes):
         return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _has_kernel(hlo, name):
+    """The program holds a Mosaic custom call whose instruction carries
+    the kernel's ``name=``: what a Perfetto/XProf view of the device
+    trace shows it as."""
+    return re.search(r"%%%s(\.\d+)? = [^\n]*tpu_custom_call" % name,
+                     hlo) is not None
+
+
 # the LM of chip_smoke.py: d_model 2048, 16 heads (head_dim 128),
 # B 8 x T 1024, amp bfloat16
 _QKV = ((8, 16, 1024, 128), BF16)
@@ -74,14 +83,14 @@ def _flash_grad(q, k, v):
                               argnums=(0, 1, 2))(q, k, v)
 
 
-@pytest.mark.parametrize("fn,extra", [
-    (_flash, ()),
-    (_flash_seg, (((8, 1024), I32),)),
-    (_flash_grad, ()),
+@pytest.mark.parametrize("fn,extra,name", [
+    (_flash, (), "flash_attention_fwd"),
+    (_flash_seg, (((8, 1024), I32),), "flash_attention_fwd_seg"),
+    # under differentiation XLA names it from "jvp(flash_attention_fwd)"
+    (_flash_grad, (), "jvp_flash_attention_fwd_"),
 ], ids=["causal", "causal_segment_ids", "custom_vjp_backward"])
-def test_flash_attention_compiles(chip, fn, extra):
-    assert "tpu_custom_call" in _compile(chip, fn, _QKV, _QKV, _QKV,
-                                         *extra)
+def test_flash_attention_compiles(chip, fn, extra, name):
+    assert _has_kernel(_compile(chip, fn, _QKV, _QKV, _QKV, *extra), name)
 
 
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
@@ -89,8 +98,9 @@ def test_decode_attention_compiles(chip, dtype):
     def fn(q, k, v, lens):
         return pa.decode_attention(q, k, v, lens, interpret=False)
     cache = ((8, 16, 1024, 128), dtype)
-    assert "tpu_custom_call" in _compile(
-        chip, fn, ((8, 16, 128), F32), cache, cache, ((8,), I32))
+    assert _has_kernel(_compile(
+        chip, fn, ((8, 16, 128), F32), cache, cache, ((8,), I32)),
+        "decode_attention")
 
 
 def _paged(chip, num_heads, head_dim, dtype):
@@ -106,7 +116,8 @@ def _paged(chip, num_heads, head_dim, dtype):
 
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
 def test_decode_attention_paged_compiles(chip, dtype):
-    assert "tpu_custom_call" in _paged(chip, 16, 128, dtype)
+    assert _has_kernel(_paged(chip, 16, 128, dtype),
+                       "decode_attention_paged")
 
 
 def test_decode_attention_paged_head_dim_64_takes_reference(chip):
