@@ -40,10 +40,10 @@ a sequence's logical position p lives at pool row
   (inactive/starved slots can't scribble on blocks they don't own).
 * ``multihead_attention_decode_paged`` / the prefill variant — the
   same masking contract as the dense ops, with K/V gathered through
-  the table: the Pallas block-gather kernel
-  (``decode_attention_paged``) when ``flash_attention`` is on, an XLA
-  gather sharing identical semantics otherwise — the flag never
-  changes tokens.
+  the table: the Pallas kernel (``decode_attention_paged``: one
+  program per slot walks that slot's live pages, all heads at once)
+  when ``flash_attention`` is on, an XLA gather sharing identical
+  semantics otherwise — the flag never changes tokens.
 * ``kv_block_copy`` — one block pool-to-pool (copy-on-write: a
   sequence about to write into a shared block copies it first).
 
@@ -165,8 +165,10 @@ def _multihead_attention_decode_paged(ctx):
     attends its table-gathered cache rows [0, Pos[s]] — the paged
     twin of ``multihead_attention_decode``, same masking/softmax
     contract (token parity with the dense layout is a test
-    invariant). ``flash_attention`` routes to the block-table-gather
-    Pallas kernel; the XLA fallback gathers the same rows densely."""
+    invariant). ``flash_attention`` routes to the Pallas kernel that
+    walks each slot's live pages; the XLA fallback gathers the same
+    rows densely. A slot whose table row is dead gets zeros from the
+    kernel and clamped rows from the gather: nobody reads either."""
     q = ctx.input("Q")
     ck = ctx.input("CacheK")
     cv = ctx.input("CacheV")

@@ -14,6 +14,14 @@ Used by the multihead_attention op when the ``flash_attention`` config
 flag is on (interpret mode on CPU keeps it testable everywhere);
 `/opt`-guide tiling notes: blocks keep the last dim = head_dim and
 block_q rows per grid step.
+
+Decode has two single-query kernels beside it. ``decode_attention``
+reads a dense per-slot cache on a (batch-head, k-block) grid.
+``decode_attention_paged`` reads the block pool of the paged cache
+with one program per slot: the pools stay in HBM, the program walks
+the slot's live pages, fetches each whole (all heads) by hand-issued,
+double-buffered copies and attends all heads at once, so a decode step
+costs what its live context costs.
 """
 
 import functools
@@ -424,145 +432,219 @@ def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
     return out.reshape(s, 1, dm)
 
 
-def _decode_paged_body(lens_ref, tab_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, scale, block_k, nk):
-    """One block step of single-query flash decode THROUGH a block
-    table. Grid (slot, head, block); the block axis is sequential, so
-    the VMEM scratch carries the online softmax per (slot, head). The
-    gather lives in the BlockSpec index maps (scalar-prefetched table
-    entries pick which pool block the next HBM->VMEM copy fetches);
-    this body only predicates dead blocks off and masks the tail —
-    per-step HBM traffic is O(length) pool rows, exactly the live
-    blocks of each sequence."""
-    si, ki = pl.program_id(0), pl.program_id(2)
+# VMEM for the paged kernel's page buffers: K and V, each double-buffered
+_PAGED_BUFFER_BYTES = 2 << 20
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[:] = jnp.full(m_ref.shape, _NEG, m_ref.dtype)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+def _paged_block_pages(block_size, d_model, dtype, max_blocks):
+    """Pages (pool blocks) the paged decode kernel fetches and attends
+    at once: what the four page buffers hold within
+    ``_PAGED_BUFFER_BYTES``, and no more than a table row has. 8 pages
+    (128 rows) for bf16 blocks of 16 x 2048."""
+    page = block_size * d_model * jnp.dtype(dtype).itemsize
+    return int(max(1, min(max_blocks, _PAGED_BUFFER_BYTES // (4 * page))))
+
+
+def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
+                         kbuf, vbuf, sem, base_ref, *, block_size,
+                         max_blocks, num_blocks, pages, num_heads, scale):
+    """One slot of single-query flash decode THROUGH a block table, all
+    heads at once. The pools stay in HBM; the program walks its slot's
+    LIVE pages only, ``pages`` of them a compute block, each page
+    (``[BS, H*D]``, contiguous) copied whole into one of two VMEM
+    buffers while the block before it is attended. The last step of a
+    slot starts the first block of the next one, so consecutive
+    programs overlap too; ``base_ref`` carries which buffer that block
+    went to. A slot whose first table entry is dead (>= NB: inactive or
+    starved) has no pages: it fetches nothing and writes zeros.
+
+    Heads share one pass over a block through a block-diagonal query
+    ``[H, H*D]`` (row h holds head h's lanes): scores are ``[H, rows]``,
+    ``P @ V`` is ``[H, H*D]`` and its diagonal blocks are the outputs.
+    The surplus products are free: the kernel runs at the copies'
+    speed."""
+    from jax.experimental.pallas import tpu as pltpu
+    bs, mb, nb = block_size, max_blocks, num_blocks
+    si, ns = pl.program_id(0), pl.num_programs(0)
+    dm = q_ref.shape[-1]
+    rows = pages * bs
+    mxu = jnp.promote_types(q_ref.dtype, kbuf.dtype)
+
+    def pages_of(slot):
+        n = jnp.minimum((lens_ref[slot] + bs - 1) // bs, mb)
+        return jnp.where(tab_ref[slot * mb] < nb, n, 0)
+
+    def live_pages(n_pages, blk):
+        return jnp.clip(n_pages - blk * pages, 0, pages)
+
+    def for_live_pages(slot, n_pages, blk, buf, act):
+        """``act`` on the K and V copies of block ``blk``'s live pages
+        (a copy is waited for through a descriptor equal to the one
+        that started it)."""
+        def one(i, _):
+            page = jnp.clip(tab_ref[slot * mb + blk * pages + i],
+                            0, nb - 1)
+            dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
+            act(pltpu.make_async_copy(
+                kp_ref.at[page], kbuf.at[buf, dst], sem.at[0, buf]))
+            act(pltpu.make_async_copy(
+                vp_ref.at[page], vbuf.at[buf, dst], sem.at[1, buf]))
+        jax.lax.fori_loop(0, live_pages(n_pages, blk), one, None)
+
+    def start(slot, n_pages, blk, buf):
+        for_live_pages(slot, n_pages, blk, buf, lambda c: c.start())
+
+    @pl.when(si == 0)
+    def _():
+        base_ref[0] = 0
+
+    base = base_ref[0]          # the buffer of this slot's first block
+    n_pages = pages_of(si)
+    n_blocks = (n_pages + pages - 1) // pages
+    started = (si > 0) & (pages_of(jnp.maximum(si - 1, 0)) > 0)
+    nxt = jnp.minimum(si + 1, ns - 1)
+    nxt_pages = jnp.where(si + 1 < ns, pages_of(nxt), 0)
+
+    @pl.when((n_blocks > 0) & jnp.logical_not(started))
+    def _():
+        start(si, n_pages, 0, base)
 
     length = lens_ref[si]
-    live = ki * block_k < length
+    diag = jax.lax.broadcasted_iota(jnp.int32, (num_heads, dm), 0) == \
+        jax.lax.broadcasted_iota(jnp.int32, (num_heads, dm), 1) \
+        // (dm // num_heads)
+    q_bd = jnp.where(
+        diag, jnp.broadcast_to(q_ref[0].astype(jnp.float32),
+                               (num_heads, dm)), 0.0).astype(mxu)
 
-    @pl.when(live)
-    def _step():
-        # narrow (bf16) pools upcast at the contraction, matching the
-        # reference's promotion; identity trace for f32 pools, so the
-        # flag-off program stays byte-identical
-        k_blk = k_ref[0]
-        if k_blk.dtype != jnp.float32:
-            k_blk = k_blk.astype(jnp.float32)
-        v_blk = v_ref[0]
-        if v_blk.dtype != jnp.float32:
-            v_blk = v_blk.astype(jnp.float32)
-        s = jnp.dot(q_ref[0], k_blk.T,
-                    preferred_element_type=jnp.float32,
-                    precision=jax.lax.Precision.DEFAULT) * scale
-        cols = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        mask = cols < length
-        s = jnp.where(mask, s, _NEG)
-        m_prev = m_ref[:]                          # [1, 128]
-        m_new = jnp.maximum(m_prev,
-                            jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        p = jnp.where(mask, p, 0.0)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=-1,
-                                              keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha[:, :1] + jnp.dot(
-            p.astype(v_blk.dtype), v_blk,
+    def step(b, carry):
+        m, l, acc = carry
+        buf = (base + b) % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _():
+            start(si, n_pages, b + 1, 1 - buf)
+
+        @pl.when((b + 1 == n_blocks) & (nxt_pages > 0))
+        def _():
+            start(nxt, nxt_pages, 0, 1 - buf)
+
+        for_live_pages(si, n_pages, b, buf, lambda c: c.wait())
+        # pages of the last block that were not fetched hold whatever
+        # VMEM held. The mask covers their scores; V's rows go to zero
+        # (0 x NaN is NaN)
+        def zero_page(i, _):
+            vbuf[buf, pl.ds(pl.multiple_of(i * bs, bs), bs), :] = \
+                jnp.zeros((bs, dm), vbuf.dtype)
+        jax.lax.fori_loop(live_pages(n_pages, b), pages, zero_page, None)
+        # explicit Precision, as in _body. A query wider than the pool
+        # (f32 on bf16 blocks) upcasts the block, the reference's
+        # promotion; equal dtypes go to the MXU as they are
+        s = jax.lax.dot_general(
+            q_bd, kbuf[buf].astype(mxu), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT)
-        m_ref[:] = m_new
+            precision=jax.lax.Precision.DEFAULT) * scale     # [H, rows]
+        mask = b * rows + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1) < length
+        s = jnp.where(mask, s, _NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        return (m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + jnp.dot(
+                    p.astype(mxu), vbuf[buf].astype(mxu),
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.DEFAULT))
 
-    @pl.when(ki == nk - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
-
-
-def _decode_paged_kernel(lens_ref, tab_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, **kw):
-    _decode_paged_body(lens_ref, tab_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, **kw)
+    m, l, acc = jax.lax.fori_loop(
+        0, n_blocks, step,
+        (jnp.full((num_heads, 1), _NEG, jnp.float32),
+         jnp.zeros((num_heads, 1), jnp.float32),
+         jnp.zeros((num_heads, dm), jnp.float32)))
+    base_ref[0] = (base + n_blocks) % 2
+    out = jnp.where(diag, acc / jnp.maximum(l, 1e-30), 0.0)
+    o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
                            num_heads, interpret=None):
     """Block-table-gather mode of :func:`decode_attention`: single-query
-    flash decode where K/V live in a PAGED pool and scalar-prefetched
-    block indices drive the index maps, so the kernel streams exactly
-    the live blocks of each sequence — never the whole pool, never a
-    gathered dense copy.
+    flash decode where K/V live in a PAGED pool and the kernel streams
+    exactly the live blocks of each sequence — never the whole pool,
+    never a gathered dense copy.
 
     q: [S, 1, D] (one query per slot, D = num_heads * head_dim);
     k_pool/v_pool: [NB, BS, D]; lengths: [S]; tables: [S, MB] int
     block ids (entries >= NB are dead — clamped, masked by length).
-    Returns [S, 1, D]. The k-block size IS the pool's block_size: the
-    grid walks (slot, head, logical block), the index map looks the
-    physical block up in the prefetched table (dead/tail blocks revisit
-    the last live index, so no HBM fetch is issued for them — the
-    PR-8 decode kernel's clamp trick, now through a level of
-    indirection), and the head picks its head_dim column slice of the
-    pool block. Pool geometry Mosaic cannot tile falls back to the
-    dense gather reference — same semantics, so the flag never changes
+    Returns [S, 1, D]. One program per slot (:func:`_decode_paged_kernel`):
+    ``lengths`` and the table are scalar-prefetched, the pools are not
+    blocked, and the program fetches its slot's ``cdiv(length, BS)``
+    live pages by hand, whole and for all heads, so a slot costs what
+    its context costs and a slot whose table row is dead (how the
+    session marks inactive and starved slots) costs nothing and
+    returns zeros, where the reference attends clamped rows nobody
+    reads. Pool geometry Mosaic cannot tile falls back to the dense
+    gather reference — same semantics, so the flag never changes
     tokens. ``interpret=None`` auto-selects interpreter mode off-TPU."""
     if interpret is None:
         interpret = kernel_path.interpret_mode()
-    s, _, dm = q.shape
-    nb, bs, _ = k_pool.shape
-    mb = tables.shape[1]
+    dm, bs = q.shape[-1], k_pool.shape[1]
     hd = dm // num_heads
-    if not interpret and (bs % 16 != 0 or
+    sublanes = 32 // jnp.dtype(k_pool.dtype).itemsize
+    if not interpret and (bs % sublanes != 0 or dm % 128 != 0 or
                           (hd % 128 != 0 and num_heads != 1)):
-        # compiled Mosaic wants a head's (bs, hd) column slice of a
-        # pool block to be whole lane tiles: hd a multiple of 128, or
-        # the slice the whole row (one head). Anything else (e.g.
-        # head_dim 64) takes the XLA gather path (identical semantics)
+        # compiled Mosaic wants a page to land in its buffer on whole
+        # tiles: BS rows a multiple of the dtype's sublane tile (8 for
+        # f32, 16 for bf16), H*D whole lanes. Heads narrower than a
+        # lane tile (e.g. head_dim 64) have not run compiled. Anything
+        # else takes the XLA gather path (identical semantics)
         kernel_path.record("decode_attention_paged")
         return _decode_paged_reference(q, k_pool, v_pool, lengths,
                                        tables, num_heads)
     kernel_path.record("decode_attention_paged", interpret)
+    pages = _paged_block_pages(bs, dm, k_pool.dtype, tables.shape[1])
+    return _decode_paged_call(q, k_pool, v_pool, lengths, tables,
+                              num_heads, pages, interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
+                       pages, interpret):
+    """The kernel call, under a jit of its own: a model's layers share
+    their geometry, so the body is traced once a process and lowered
+    once a program, not once a layer."""
     from jax.experimental.pallas import tpu as pltpu
+    s, _, dm = q.shape
+    nb, bs, _ = k_pool.shape
+    mb = tables.shape[1]
     lens = jnp.asarray(lengths).reshape(s).astype(jnp.int32)
     tab = jnp.asarray(tables).reshape(s * mb).astype(jnp.int32)
 
-    def kv_index(si, hi, j, lens_ref, tab_ref):
-        # logical block j of slot si -> physical pool block. Dead
-        # blocks (past the live prefix) clamp to the last LIVE logical
-        # block before the table lookup: Pallas issues the HBM->VMEM
-        # copy per BlockSpec index, so revisiting a resident index
-        # makes the skip real at the memory level (the body's pl.when
-        # alone only skips compute). The id is also clamped into the
-        # pool, so an inactive slot's dead-marker entries (>= NB)
-        # can't index out of bounds.
-        last = jnp.maximum(lens_ref[si] - 1, 0) // bs
-        blk = tab_ref[si * mb + jnp.minimum(j, last)]
-        return (jnp.clip(blk, 0, nb - 1), 0, hi)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, num_heads, mb),
+        grid=(s,),
         in_specs=[
-            pl.BlockSpec((1, 1, hd),
-                         lambda si, hi, j, lr, tr: (si, 0, hi)),
-            pl.BlockSpec((1, bs, hd), kv_index),
-            pl.BlockSpec((1, bs, hd), kv_index),
+            pl.BlockSpec((1, 1, dm), lambda si, lr, tr: (si, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd),
-                               lambda si, hi, j, lr, tr: (si, 0, hi)),
+        out_specs=pl.BlockSpec((1, 1, dm), lambda si, lr, tr: (si, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, hd), jnp.float32),     # acc
-            pltpu.VMEM((1, 128), jnp.float32),    # running max
-            pltpu.VMEM((1, 128), jnp.float32),    # running sum
+            pltpu.VMEM((2, pages * bs, dm), k_pool.dtype),
+            pltpu.VMEM((2, pages * bs, dm), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),      # (K|V, buffer)
+            pltpu.SMEM((1,), jnp.int32),          # first block's buffer
         ])
     return pl.pallas_call(
-        functools.partial(_decode_paged_kernel, scale=hd ** -0.5,
-                          block_k=bs, nk=mb),
+        functools.partial(_decode_paged_kernel, block_size=bs,
+                          max_blocks=mb, num_blocks=nb, pages=pages,
+                          num_heads=num_heads,
+                          scale=(dm // num_heads) ** -0.5),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, 1, dm), q.dtype),
+        # programs run in order: each hands its successor a block
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         name="decode_attention_paged",
         interpret=interpret)(lens, tab, q, k_pool, v_pool)
 

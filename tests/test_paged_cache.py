@@ -292,42 +292,110 @@ class TestPagedOps:
         np.testing.assert_allclose(got, want)
 
 
+# toy pool geometry of the kernel cases: 2 heads of 8, blocks of 4 rows,
+# table rows of 7 blocks, and a page budget cut so that a compute block
+# holds _P pages (the real budget would hold a whole toy table row)
+_H, _HD, _NB, _BS, _MB, _P = 2, 8, 40, 4, 7, 2
+_DEAD = (_NB, _NB + 1000)       # both mark a dead table entry
+
+
+def _kernel_cases():
+    """(id, lengths per slot, slots with a dead table row, share)."""
+    full = _MB * _BS
+    edge = [1, _BS, _BS + 1, _P * _BS, _P * _BS + 1, full]
+    cases = [("len%d" % n, [n, 2 * _P * _BS + 3, n], (), False)
+             for n in edge]
+    cases += [
+        # the host's inactive slot (length 1) and a starved one (its
+        # length, no blocks), first, last and between live slots
+        ("inactive", [1, 9, full, 1, 13, full - 1], (0, 3, 5), False),
+        ("all_inactive", [1, 1, 5], (0, 1, 2), False),
+        # prefix cache: two slots name the same physical blocks
+        ("shared", [11, 11, 6], (), True),
+        # 7 blocks a row, 2 a compute block: the last holds one page
+        ("odd_max_blocks", [full, full - _BS, full - _BS + 1], (), False),
+    ]
+    return [pytest.param(*c[1:], id=c[0]) for c in cases]
+
+
+def _strict_interpreter():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.InterpretParams(uninitialized_memory="nan",
+                                 out_of_bounds_reads="raise",
+                                 detect_races=True)
+
+
 class TestPagedDecodeKernel:
-    def test_block_gather_kernel_matches_dense_gather(self):
-        """The Pallas block-table-gather kernel streams scattered pool
-        blocks; unreferenced pool blocks are NaN-poisoned so a stray
-        gather (wrong block, dead-block fetch feeding compute) fails
-        loudly instead of averaging in."""
-        from paddle_tpu.ops.pallas_attention import (
-            _decode_paged_reference, decode_attention_paged)
+    @pytest.mark.parametrize("interpret", [True, _strict_interpreter],
+                             ids=["interpreter", "strict"])
+    @pytest.mark.parametrize("pool,query,tol", [
+        ("float32", "float32", 1e-5), ("bfloat16", "float32", 1e-5),
+        # equal dtypes go to the MXU as they are: bf16's own rounding
+        ("bfloat16", "bfloat16", 2e-2)],
+        ids=["f32_pool", "bf16_pool", "bf16_pool_bf16_query"])
+    @pytest.mark.parametrize("lengths,dead,share", _kernel_cases())
+    def test_live_page_walk_matches_dense_gather(
+            self, monkeypatch, lengths, dead, share, pool, query, tol,
+            interpret):
+        """The Pallas kernel walks each slot's live pages through
+        hand-issued copies; unreferenced pool blocks are NaN-poisoned so
+        a stray fetch (wrong block, a dead page feeding compute) fails
+        loudly instead of averaging in. The strict interpreter also
+        poisons VMEM that no copy filled, raises on a read outside the
+        pool and checks that no copy is in flight when its buffer is
+        read."""
+        from paddle_tpu.ops import pallas_attention as pa
+        if interpret is not True:
+            interpret = interpret()
+        D = _H * _HD
+        monkeypatch.setattr(
+            pa, "_PAGED_BUFFER_BYTES",
+            4 * _P * _BS * D * jnp.dtype(pool).itemsize)
+        assert pa._paged_block_pages(_BS, D, pool, _MB) == _P
         rs = np.random.RandomState(0)
-        S, H, HD, NB, BS, MB = 3, 2, 8, 10, 4, 4
-        D = H * HD
-        lengths = np.asarray([1, 9, 16], np.int32)
-        tables = np.full((S, MB), NB, np.int32)
-        pool_k = np.full((NB, BS, D), np.nan, "float32")
-        pool_v = np.full((NB, BS, D), np.nan, "float32")
-        used = iter([7, 0, 3, 2, 9, 5, 1, 4])    # scattered, unordered
+        S = len(lengths)
+        lengths = np.asarray(lengths, np.int32)
+        tables = np.full((S, _MB), _DEAD[0], np.int32)
+        tables[:, 1::2] = _DEAD[1]
+        pool_k = np.full((_NB, _BS, D), np.nan, "float32")
+        pool_v = np.full((_NB, _BS, D), np.nan, "float32")
+        free = iter(rs.permutation(_NB))        # scattered, unordered
         for s in range(S):
-            for j in range(-(-int(lengths[s]) // BS)):
-                b = next(used)
+            if s in dead:
+                continue
+            for j in range(-(-int(lengths[s]) // _BS)):
+                if share and s == 1:
+                    tables[1, j] = tables[0, j]
+                    continue
+                b = next(free)
                 tables[s, j] = b
-                pool_k[b] = rs.randn(BS, D)
-                pool_v[b] = rs.randn(BS, D)
-        q = rs.randn(S, 1, D).astype("float32")
-        out = decode_attention_paged(
-            jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
-            jnp.asarray(lengths), jnp.asarray(tables), H,
-            interpret=True)
-        assert np.isfinite(np.asarray(out)).all()
+                pool_k[b] = rs.randn(_BS, D)
+                pool_v[b] = rs.randn(_BS, D)
+        q = jnp.asarray(rs.randn(S, 1, D), query)
+        out = np.asarray(pa.decode_attention_paged(
+            q, jnp.asarray(pool_k, pool), jnp.asarray(pool_v, pool),
+            jnp.asarray(lengths), jnp.asarray(tables), _H,
+            interpret=interpret), np.float32)
+        assert np.isfinite(out).all()
+        assert (out[list(dead)] == 0).all()
         # reference on pools with the NaNs zeroed (the dense gather
         # touches masked rows; the kernel must match its live math)
-        ref = _decode_paged_reference(
-            jnp.asarray(q), jnp.asarray(np.nan_to_num(pool_k)),
-            jnp.asarray(np.nan_to_num(pool_v)), jnp.asarray(lengths),
-            jnp.asarray(tables), H)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5, rtol=1e-5)
+        ref = pa._decode_paged_reference(
+            q, jnp.asarray(np.nan_to_num(pool_k), pool),
+            jnp.asarray(np.nan_to_num(pool_v), pool),
+            jnp.asarray(lengths), jnp.asarray(tables), _H)
+        np.testing.assert_allclose(out, np.asarray(ref, np.float32),
+                                   atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("dtype,max_blocks,pages", [
+        ("bfloat16", 128, 8), ("float32", 128, 4), ("bfloat16", 5, 5)])
+    def test_pages_per_compute_block_follow_from_static_shapes(
+            self, dtype, max_blocks, pages):
+        """Blocks of 16 x 2048 (the benchmark's serving geometry): 2 MB
+        of page buffers hold 8 bf16 pages or 4 f32 ones, K and V double
+        buffered, and never more than a table row has."""
+        from paddle_tpu.ops.pallas_attention import _paged_block_pages
+        assert _paged_block_pages(16, 2048, dtype, max_blocks) == pages
 
     def test_dense_gather_reference_equals_contiguous_reference(self):
         """_decode_paged_reference over a scattered pool == the PR-8

@@ -103,15 +103,16 @@ def test_decode_attention_compiles(chip, dtype):
         "decode_attention")
 
 
-def _paged(chip, num_heads, head_dim, dtype):
+def _paged(chip, num_heads, head_dim, dtype, query=F32, slots=8,
+           max_blocks=64, pool_blocks=8 * 64):
     dm = num_heads * head_dim
-    pool = ((8 * 64, 16, dm), dtype)    # 8 slots x 64 blocks of 16 rows
+    pool = ((pool_blocks, 16, dm), dtype)       # blocks of 16 rows
 
     def fn(q, kp, vp, lens, tables):
         return pa.decode_attention_paged(q, kp, vp, lens, tables,
                                          num_heads, interpret=False)
-    return _compile(chip, fn, ((8, 1, dm), F32), pool, pool,
-                    ((8,), I32), ((8, 64), I32))
+    return _compile(chip, fn, ((slots, 1, dm), query), pool, pool,
+                    ((slots,), I32), ((slots, max_blocks), I32))
 
 
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
@@ -120,12 +121,21 @@ def test_decode_attention_paged_compiles(chip, dtype):
                        "decode_attention_paged")
 
 
+def test_decode_attention_paged_compiles_at_serving_geometry(chip):
+    """The benchmark's serving cells (benchmarks/workloads/lm-serve-*):
+    32 slots, bf16 query, 2,048 bf16 blocks of 16 x 2048, table rows of
+    128; 8 pages a compute block, 2 MB of page buffers."""
+    assert _has_kernel(
+        _paged(chip, 16, 128, BF16, query=BF16, slots=32,
+               max_blocks=128, pool_blocks=2048),
+        "decode_attention_paged")
+
+
 def test_decode_attention_paged_head_dim_64_takes_reference(chip):
-    """d_model 512 / 8 heads: a head's (16, 64) column slice of a pool
-    block is half a lane tile, which Mosaic refuses ("last two
-    dimensions of your block shape ... divisible by 8 and 128"). The
-    gate must hand that geometry to the XLA gather — counted, compiled,
-    and with no kernel in the program — not let it reach the lowering."""
+    """d_model 512 / 8 heads: two heads share a lane tile, a geometry
+    the kernel has not run compiled. The gate must hand it to the XLA
+    gather — counted, compiled, and with no kernel in the program —
+    not let it reach the lowering."""
     before = kernel_path.counts().get(
         "decode_attention_paged", {}).get("xla", 0)
     assert "tpu_custom_call" not in _paged(chip, 8, 64, F32)
