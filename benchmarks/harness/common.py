@@ -5,6 +5,7 @@ sub-window, and the facts a run hands to the per-layer readers."""
 import contextlib
 import os
 import shutil
+import time
 
 import numpy as np
 
@@ -120,10 +121,22 @@ class Env:
             out_root or os.path.join(CHECKOUT, ".bench_out"), workload)
         os.makedirs(self.out_dir, exist_ok=True)
         self.trace_dir = os.path.join(self.out_dir, "trace")
+        # seconds of set-up by phase, for the notes: what is left of
+        # ``setup_s`` is the driver's own (the traffic's draw and lead-in)
+        self.phases = {"imports_and_devices":
+                       time.perf_counter() - t_process}
 
     def setup_seconds(self, t_window):
         """Process start to window start."""
         return t_window - self.t_process
+
+    @contextlib.contextmanager
+    def phase(self, name):
+        """A phase of set-up: a ``bench:`` span, and its seconds kept."""
+        t0 = time.perf_counter()
+        with span(name):
+            yield
+        self.phases[name] = time.perf_counter() - t0
 
     @contextlib.contextmanager
     def traced(self):
@@ -166,6 +179,7 @@ class Facts:
         self.trace = None       # trace_reduce.reduce_trace(), traced runs
         self.correct = True
         self.problems = []      # why ``correct`` is false
+        self.compared = {}      # {name: {"value", "limit"}}: what decided it
         self.attempted = 0
         self.failed = 0
         self.notes = {}         # printed on an earlier line
@@ -174,6 +188,18 @@ class Facts:
     def fail(self, msg, *args):
         self.correct = False
         self.problems.append(msg % args)
+
+    def compare(self, name, value, limit, msg, *args):
+        """Hold a number to its limit: kept beside the limit for the result
+        line, and the run is not correct where it is over it or no number."""
+        value = float(value)
+        ok = value <= limit     # False for NaN
+        self.compared[name] = {
+            "value": value if np.isfinite(value) else repr(value),
+            "limit": limit}
+        if not ok:
+            self.fail(msg, *args)
+        return ok
 
 
 def midmean(values):

@@ -11,6 +11,7 @@ import importlib
 import os
 
 from . import flops, lm, peaks
+from .. import architectures
 
 
 def hist_mean(facts, histogram):
@@ -50,8 +51,9 @@ def mfu(facts):
     if rate is None:
         return None
     peak = peaks.peaks_for(facts.device_kind)["bf16_flops"]
-    return 100.0 * flops.mfu(facts.cfg, facts.observed["seq_len"], rate,
-                             facts.chips, peak)
+    per_token = architectures.load(facts.cfg).train_flops_per_token(
+        facts.cfg, facts.observed["seq_len"])
+    return 100.0 * flops.mfu(per_token, rate, facts.chips, peak)
 
 
 KINDS = {"hist_mean": hist_mean, "counter_ratio": counter_ratio,

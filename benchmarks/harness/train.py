@@ -18,12 +18,12 @@ seconds where six runs in one call had read 0.003%).
 """
 
 import collections
-import importlib
 import time
 
 import numpy as np
 
 from . import common, lm
+from .. import architectures
 from .common import check
 
 # Program vs reference loss on the check step. The program multiplies in
@@ -43,11 +43,6 @@ def _scalar(fetched):
     return float(np.asarray(fetched).reshape(-1)[0])
 
 
-def _batch(rs, vocab, batch, seq_len):
-    ids = rs.randint(2, vocab, (batch, seq_len)).astype(np.int32)
-    return {"toks": ids, "lbls": np.roll(ids, -1, axis=1)}
-
-
 def run(cell, cfg, seed, seconds, env):
     """One run of a training cell; returns the facts."""
     import jax
@@ -55,23 +50,25 @@ def run(cell, cfg, seed, seconds, env):
     import paddle_tpu as ptpu
     from paddle_tpu.ops import kernel_path
     facts = common.Facts(cell, cfg, env.devices, seconds)
+    arch, ref = architectures.load(cfg), architectures.reference(cfg)
     traffic = cell["traffic"]
     batch, seq_len = int(traffic["batch"]), int(traffic["seq_len"])
     fetch_every = int(traffic["fetch_loss_every"])
-    vocab = cfg["vocab_size"]
-    check(seq_len <= cfg["n_positions"], "seq_len %d exceeds n_positions %d",
-          seq_len, cfg["n_positions"])
+    check(seq_len <= arch.max_positions(cfg),
+          "seq_len %d exceeds the configuration's %d positions", seq_len,
+          arch.max_positions(cfg))
     mesh = cell.get("mesh")
-    strategy = lm.make_strategy(mesh, env.devices) if mesh else None
-    ref = importlib.import_module("benchmarks.reference." + cfg["architecture"])
+    strategy = arch.strategy(cfg, mesh, env.devices) if mesh else None
     rs = np.random.RandomState(seed)
+
+    def step_feed():
+        return arch.train_feed(rs, cfg, traffic)["feed"]
+
     kernels0 = kernel_path.counts()
     with lm.flags(**cfg["flags"]), ptpu.scope_guard(ptpu.Scope()), \
             ptpu.unique_name.guard():
-        with common.span("init_weights"):
-            main, startup, loss = lm.lm_program(
-                cfg, seq_len, seed, train=True,
-                learning_rate=float(traffic["learning_rate"]))
+        with env.phase("init_weights"):
+            main, startup, loss = arch.train_program(cfg, traffic, seed)
             exe = ptpu.Executor(strategy=strategy)
             exe.run(startup)
         scope = ptpu.global_scope()
@@ -79,27 +76,27 @@ def run(cell, cfg, seed, seconds, env):
         # -- the check step: one seeded sequence in every row of the batch,
         # so the program's mean loss is that sequence's loss; the reference
         # reads the initial weights before the step donates them.
-        with common.span("check_and_warm"):
-            one = _batch(rs, vocab, 1, seq_len)
-            weights = ref.gather_weights(scope.find_var, cfg["n_layer"])
-            want = float(jax.jit(lambda w, t, l: ref.loss(
-                w, t, l, cfg["n_layer"], cfg["n_head"],
-                cfg["layer_norm_epsilon"]))(
-                    weights, jnp.asarray(one["toks"][0]),
-                    jnp.asarray(one["lbls"][0])))
+        with env.phase("check_and_warm"):
+            one = arch.train_feed(rs, cfg, dict(traffic, batch=1))
+            weights = ref.gather_weights(scope.find_var, cfg)
+            want = float(jax.jit(lambda w, *row: ref.loss(w, *row, cfg))(
+                weights, *(jnp.asarray(r[0]) for r in one["reference_rows"])))
             del weights
-            feed = {k: np.repeat(v, batch, axis=0) for k, v in one.items()}
+            feed = {k: np.repeat(v, batch, axis=0)
+                    for k, v in one["feed"].items()}
+            units = one["units_per_step"] * batch    # tokens, for an LM
             got = _scalar(exe.run(main, feed=feed, fetch_list=[loss])[0])
-            check(np.isfinite(got) and abs(got - want) <= LOSS_ATOL,
-                  "first step's loss %.6f differs from the reference's %.6f "
-                  "by more than %g", got, want, LOSS_ATOL)
+            facts.compare(
+                "check_loss_abs_diff", abs(got - want), LOSS_ATOL,
+                "first step's loss %.6f differs from the reference's %.6f "
+                "by more than %g", got, want, LOSS_ATOL)
             warm = []
             for _ in range(int(traffic.get("warm_steps", 2))):
                 warm.append(_scalar(exe.run(
-                    main, feed=_batch(rs, vocab, batch, seq_len),
-                    fetch_list=[loss])[0]))
+                    main, feed=step_feed(), fetch_list=[loss])[0]))
         paths = common.kernel_paths_since(kernels0)
-        common.check_kernel_compiled("flash_attention", paths, env.on_tpu)
+        for kernel in arch.kernels("train"):
+            common.check_kernel_compiled(kernel, paths, env.on_tpu)
 
         # -- the window
         stamps, losses, count = [], [], [0]
@@ -113,7 +110,7 @@ def run(cell, cfg, seed, seconds, env):
             handed = time.perf_counter()
             for _ in range(fetch_every):
                 with common.span("feed"):
-                    out = exe.run(main, feed=_batch(rs, vocab, batch, seq_len),
+                    out = exe.run(main, feed=step_feed(),
                                   fetch_list=[loss], return_numpy=False)[0]
                 count[0] += 1
             pending.append((count[0], out, handed))
@@ -168,10 +165,11 @@ def run(cell, cfg, seed, seconds, env):
         compiles = env.meter.delta(env.meter.snapshot(), comp0)
 
     window = t1 - t0
-    tokens = n * batch * seq_len
+    tokens = n * units
     facts.attempted, facts.failed = n, 0
-    if not all(np.isfinite(losses)):
-        facts.fail("loss not finite inside the window: %r", losses[:8])
+    facts.compare("window_losses_not_finite",
+                  sum(1 for x in losses if not np.isfinite(x)), 0,
+                  "loss not finite inside the window: %r", losses[:8])
     facts.compiles = compiles
     # host clock between neighbouring fetched losses over the steps between
     # them: kept for the notes (how far single blocks lie from the median)
@@ -181,7 +179,7 @@ def run(cell, cfg, seed, seconds, env):
     obs["setup_s"] = env.setup_seconds(t0)
     step_s = common.median_slope(stamps)
     obs["train_step_p50_ms"] = step_s * 1e3
-    obs["train_tokens_per_s"] = batch * seq_len / step_s
+    obs["train_tokens_per_s"] = units / step_s
     obs["seq_len"] = seq_len
     facts.samples = {"stamps": [[k, t - t0] for k, t in stamps]}
     facts.notes = {

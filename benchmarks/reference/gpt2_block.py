@@ -1,9 +1,12 @@
 """Plain reference of the GPT-2 block LM (Radford et al. 2019; the block of
 Cerebras-GPT, arXiv:2304.03208): pre-norm, LayerNorm, learned positions,
 full multi-head causal attention, a 4x GELU feed-forward, a final LayerNorm
-and an LM head. Straight ``jax.numpy`` in float32 under
-``jax.default_matmul_precision("highest")``: no kernels, no cache, no
-batching, one sequence at a time.
+and an LM head. Like every reference it takes the configuration dict
+(``gather_weights(find_var, cfg)``, ``logits_at(w, tokens, positions,
+cfg)``, ``loss(w, tokens, labels, cfg)``) and reads its own sizes from it:
+the depth, the heads and the LayerNorm epsilon. Straight ``jax.numpy`` in
+float32 under ``jax.default_matmul_precision("highest")``: no kernels, no
+cache, no batching, one sequence at a time.
 
 It is fed the program's own weights by the names the program gives them
 (``weight_names``), so it checks the program's arithmetic and not its
@@ -41,10 +44,10 @@ def weight_names(n_layer):
     return names
 
 
-def gather_weights(find_var, n_layer):
+def gather_weights(find_var, cfg):
     """{reference name: array} from the program's scope (``find_var`` is
     ``scope.find_var``). No copy: the arrays are the program's own."""
-    return {k: find_var(v) for k, v in weight_names(n_layer).items()}
+    return {k: find_var(v) for k, v in weight_names(cfg["n_layer"]).items()}
 
 
 def _layer_norm(x, g, b, eps):
@@ -53,8 +56,10 @@ def _layer_norm(x, g, b, eps):
     return (x - mean) / jnp.sqrt(var + eps) * g + b
 
 
-def hidden(w, tokens, n_layer, n_head, eps=1e-5):
+def hidden(w, tokens, cfg):
     """tokens [T] -> final hidden states [T, d], after the last LayerNorm."""
+    n_layer, n_head = cfg["n_layer"], cfg["n_head"]
+    eps = cfg["layer_norm_epsilon"]
     t = tokens.shape[0]
     x = w["tok"][tokens] + w["pos"][:t]
     d = x.shape[-1]
@@ -76,17 +81,17 @@ def hidden(w, tokens, n_layer, n_head, eps=1e-5):
     return _layer_norm(x, w["ln_f.g"], w["ln_f.b"], eps)
 
 
-def logits_at(w, tokens, positions, n_layer, n_head, eps=1e-5):
+def logits_at(w, tokens, positions, cfg):
     """Logits [len(positions), V] of one sequence at the given positions."""
     with jax.default_matmul_precision("highest"):
         w = {k: jnp.asarray(v, jnp.float32) for k, v in w.items()}
-        return hidden(w, tokens, n_layer, n_head, eps)[positions] @ w["head"]
+        return hidden(w, tokens, cfg)[positions] @ w["head"]
 
 
-def loss(w, tokens, labels, n_layer, n_head, eps=1e-5):
+def loss(w, tokens, labels, cfg):
     """Mean next-token cross-entropy of one sequence (labels [T])."""
     with jax.default_matmul_precision("highest"):
         w = {k: jnp.asarray(v, jnp.float32) for k, v in w.items()}
-        logits = hidden(w, tokens, n_layer, n_head, eps) @ w["head"]
+        logits = hidden(w, tokens, cfg) @ w["head"]
         logp = jax.nn.log_softmax(logits, axis=-1)
         return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))

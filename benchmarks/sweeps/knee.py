@@ -39,6 +39,10 @@ def sweep(workload, rates, seconds, seed, require_tpu=True):
     env = common.Env(T_PROCESS, "knee-" + workload, cell["chips"], False,
                      require_tpu=require_tpu)
     dep = serve.Deployment(cell, cfg, seed, env)
+    report = dep.check_report
+    common.check(max(report["worst_rel_err"],
+                     report["worst_first_token_rel_gap"]) <= report["rtol"],
+                 "the deployment differs from its reference: %r", report)
     rows = []
     try:
         dep.open()
@@ -47,7 +51,7 @@ def sweep(workload, rates, seconds, seed, require_tpu=True):
             got = serve.offer(dep, traffic, seed, seconds)
             w0, w1 = got["w0"], got["w1"]
             nums = serve.client_numbers(got["requests"], w0, w1,
-                                        cfg["vocab_size"])
+                                        dep.arch.vocab(cfg))
             mid = (w0 + w1) / 2
             sent = [r for r in got["requests"] if r.sent is not None]
             half = [[], []]

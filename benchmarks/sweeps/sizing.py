@@ -4,7 +4,7 @@ step ``Executor.run`` would execute, for a *described* ``v5e:2x2`` topology,
 and prints the compiler's ``memory_analysis()`` bytes per device.
 
     JAX_PLATFORMS=cpu python benchmarks/sweeps/sizing.py train \
-        --config cerebras-gpt-1.3b-l12 --layers 11 --batch 4 --seq 2048
+        --config cerebras-gpt-1.3b-l10 --set <depth key>=11 --batch 4 --seq 2048
     JAX_PLATFORMS=cpu python benchmarks/sweeps/sizing.py train \
         --config cerebras-gpt-1.3b --mesh data=2,model=2 --batch 8 --seq 2048
     JAX_PLATFORMS=cpu python benchmarks/sweeps/sizing.py serve \
@@ -68,11 +68,10 @@ class _ShapeScope:
         raise RuntimeError("sizing scope is read-only (%s)" % name)
 
 
-def _shape_strategy(mesh_axes, devices):
+def _shape_strategy(arch, cfg, mesh_axes, devices):
     """The cell's DistStrategy, placing shapes in place of arrays."""
     import jax
-    from benchmarks.harness import lm
-    base = lm.make_strategy(mesh_axes, devices)
+    base = arch.strategy(cfg, mesh_axes, devices)
 
     def shard_feed(name, array):
         return jax.ShapeDtypeStruct(
@@ -129,22 +128,26 @@ def _steer_like_tpu():
 def size_train(cfg, mesh_axes, batch, seq):
     import jax
     import paddle_tpu as ptpu
+    from benchmarks import architectures
     from benchmarks.harness import lm
+    arch = architectures.load(cfg)
     devices = _described_devices()
     one_chip = jax.sharding.SingleDeviceSharding(devices[0])
-    strategy = _shape_strategy(mesh_axes, devices) if mesh_axes else None
+    strategy = _shape_strategy(arch, cfg, mesh_axes, devices) \
+        if mesh_axes else None
+    traffic = {"batch": batch, "seq_len": seq, "learning_rate": 1e-4}
     with lm.flags(**cfg["flags"]), ptpu.unique_name.guard():
-        main, startup, loss = lm.lm_program(cfg, seq, 0, train=True)
+        main, startup, loss = arch.train_program(cfg, traffic, 0)
         exe = ptpu.Executor(strategy=strategy)
-        feed = {"toks": np.zeros((batch, seq), "int32"),
-                "lbls": np.zeros((batch, seq), "int32")}
-        mem, hlo = _compile(exe, main, feed, [loss],
+        step = arch.train_feed(np.random.RandomState(0), cfg, traffic)
+        mem, hlo = _compile(exe, main, step["feed"], [loss],
                             _ShapeScope([main, startup]), one_chip)
     n_params = sum(int(np.prod(p.shape))
                    for p in main.global_block().all_parameters())
     return {"what": "train", "config": cfg["name"],
-            "n_layer": cfg["n_layer"], "mesh": mesh_axes or None,
-            "batch": batch, "seq": seq, "tokens_per_step": batch * seq,
+            "reducible": {k: cfg[k] for k in arch.published(cfg)["reducible"]},
+            "mesh": mesh_axes or None, "batch": batch, "seq": seq,
+            "units_per_step": step["units_per_step"],
             "n_params": n_params, "per_device": mem,
             "tpu_custom_calls": hlo.count("tpu_custom_call"),
             "all_reduce": hlo.count(" all-reduce("),
@@ -155,7 +158,9 @@ def size_train(cfg, mesh_axes, batch, seq):
 def size_serve(cfg, buckets):
     import jax
     import paddle_tpu as ptpu
+    from benchmarks import architectures
     from benchmarks.harness import lm
+    arch = architectures.load(cfg)
     geometry = cfg["deployment"]["serving"]
     one_chip = jax.sharding.SingleDeviceSharding(_described_devices()[0])
     out = {"what": "serve", "config": cfg["name"], "geometry": geometry,
@@ -163,11 +168,10 @@ def size_serve(cfg, buckets):
     with lm.flags(generation_paged_kv=True,
                   generation_kv_dtype=geometry["kv_dtype"], **cfg["flags"]):
         with ptpu.unique_name.guard():
-            main, startup, _ = lm.lm_program(cfg, cfg["n_positions"], 0,
-                                             train=False)
-        spec = lm.serve_spec(cfg, geometry, buckets)
+            startup = arch.serve_startup(cfg, 0)
+        spec = arch.serve_spec(cfg, geometry, buckets)
 
-        scope = _ShapeScope([main, startup], more=spec.cache_vars)
+        scope = _ShapeScope([startup], more=spec.cache_vars)
         exe = ptpu.Executor()
         S, MB = spec.slots, spec.max_blocks
         dfeed = {"gen.dtok": np.zeros((S, 1), "int64"),
@@ -194,16 +198,22 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("what", choices=("train", "serve"))
     ap.add_argument("--config", required=True)
-    ap.add_argument("--layers", type=int, help="override n_layer")
+    ap.add_argument("--set", action="append", default=[], metavar="KEY=INT",
+                    help="override a key the configuration may reduce")
     ap.add_argument("--mesh", default="", help="e.g. data=2,model=2")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=2048)
     ap.add_argument("--buckets", default="128")
     args = ap.parse_args(argv)
+    from benchmarks import architectures
     from benchmarks.harness import lm
     cfg = lm.load_config(args.config)
-    if args.layers:
-        cfg["n_layer"] = args.layers
+    reducible = architectures.load(cfg).published(cfg)["reducible"]
+    for key, value in (kv.split("=") for kv in args.set):
+        if key not in reducible:
+            ap.error("%s may reduce %s, not %r" % (
+                cfg["architecture"], sorted(reducible), key))
+        cfg[key] = int(value)
     _steer_like_tpu()
     if args.what == "train":
         mesh = {k: int(v) for k, v in

@@ -10,25 +10,24 @@ import shutil
 
 import pytest
 
-from benchmarks import run as bench_run
+from benchmarks import architectures, run as bench_run
 from benchmarks.harness import lm, readers
 
 ROOT = lm.CHECKOUT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-# what may never be cut: a width is the model
-WIDTHS = ("n_embd", "n_head", "n_inner", "n_positions", "vocab_size")
-PUBLISHED = dict(n_embd=2048, n_head=16, n_inner=8192, n_positions=2048,
-                 vocab_size=50257)
+
+
+def _bench():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
 
 
 @pytest.fixture(scope="module")
 def bench():
-    path = os.path.join(ROOT, "BENCHMARK.json")
-    assert os.path.getsize(path) <= 64 * 1024
-    with open(path) as f:
-        return json.load(f)
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
+    return _bench()
 
 
 def test_top_level_keys_and_limits(bench):
@@ -74,24 +73,35 @@ def test_entries_have_just_their_keys(bench):
                                           "layer", "moves"}
 
 
-def test_configurations_keep_the_published_widths(bench):
-    files = set()
-    for c in bench["configs"]:
-        assert c["file"].startswith("benchmarks/configs/")
-        assert c["file"] not in files
-        files.add(c["file"])
-        with open(os.path.join(ROOT, c["file"])) as f:
-            cfg = json.load(f)
-        assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
-        assert cfg["reduced"] == c["reduced"]
-        for key in WIDTHS:
-            assert cfg[key] == PUBLISHED[key], (c["name"], key)
-            assert key not in c["reduced"]
-        assert (cfg["n_layer"] != 24) == ("n_layer" in c["reduced"])
-        assert cfg["n_embd"] // cfg["n_head"] == 128
-        assert isinstance(cfg["departures"], list) and cfg["departures"]
-        assert "assumed" in cfg and "sizing" in cfg
-        assert any(w["config"] == c["name"] for w in bench["workloads"])
+def test_configurations_have_files_of_their_own(bench):
+    files = [c["file"] for c in bench["configs"]]
+    assert len(files) == len(set(files))
+    assert all(f.startswith("benchmarks/configs/") for f in files)
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in _bench()["configs"]])
+def test_configuration_keeps_the_published_widths(bench, name):
+    """What may never be cut (a width is the model) and what ``reduced``
+    may list come from the architecture's module."""
+    c = next(c for c in bench["configs"] if c["name"] == name)
+    with open(os.path.join(ROOT, c["file"])) as f:
+        cfg = json.load(f)
+    assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
+    assert cfg["reduced"] == c["reduced"]
+    published = architectures.load(cfg).published(cfg)
+    for key, value in published["widths"].items():
+        assert cfg[key] == value, (name, key)
+        assert key not in c["reduced"]
+    for key, value in published["reducible"].items():
+        assert (cfg[key] != value) == (key in c["reduced"]), (name, key)
+    assert set(c["reduced"]) <= set(published["reducible"])
+    for what, (built, value) in published["as_built"].items():
+        assert built == value, (name, what)
+    assert os.path.exists(os.path.join(
+        lm.BENCH_DIR, "reference", cfg["architecture"] + ".py"))
+    assert isinstance(cfg["departures"], list) and cfg["departures"]
+    assert "assumed" in cfg and "sizing" in cfg
+    assert any(w["config"] == name for w in bench["workloads"])
 
 
 def test_cells_have_their_files_and_chips(bench):

@@ -6,11 +6,12 @@ brings ``layer_metrics/decode_step_roofline_share.py``."""
 import numpy as np
 import pytest
 
-from benchmarks import run as bench_run
+from benchmarks import architectures, run as bench_run
 from benchmarks.harness import common, flops, lm, peaks, readers
 from test_rehearsal import BENCH, tiny_bench  # noqa: F401 — the fixture
 
 CFG = lm.load_config("cerebras-gpt-1.3b")
+ARCH = architectures.load(CFG)
 HOST = "paddle_generation_host_ms_total{phase=%s}"
 STEPS = "paddle_generation_decode_steps_total"
 WAIT = "paddle_generation_device_wait_ms_total"
@@ -73,8 +74,8 @@ def test_roofline_share_of_one_step_by_hand():
     weights once, bf16 keys and values of 14,400 tokens, at 819 GB/s."""
     facts = _facts({STEPS: 1.0, TOKENS: 32.0, CONTEXT: 32 * 450.0},
                    {STEP_MS: (1, 277.0)})
-    weights = 4 * flops.matmul_params(CFG)
-    kv = 32 * 450 * 2 * CFG["n_embd"] * CFG["n_layer"] * 2
+    weights = 4 * ARCH.matmul_params(CFG)
+    kv = 32 * 450 * 2 * 2048 * 24 * 2      # tokens x (k, v) x d x L x bf16
     least_ms = (weights + kv) / 819e9 * 1e3
     assert 9.0 < least_ms < 11.0        # PERF.md's "9.9 ms" step
     assert _read("decode_step_roofline_share", facts) == pytest.approx(
@@ -96,9 +97,9 @@ def test_roofline_share_cannot_pass_100_on_a_chip_at_its_roofline(seed):
     slack = rs.choice([0.0, 0.5])       # at the roofline, or half over it
     for _ in range(steps):
         lens = rs.randint(1, 2049, size=rs.randint(1, 33)).tolist()
-        least, _ = flops.roofline_seconds(
-            flops.decode_step_flops(CFG, lens),
-            flops.decode_step_bytes(CFG, lens), pk)
+        least, _ = flops.roofline_seconds(*ARCH.decode_ops_and_bytes(
+            CFG, {STEPS: 1.0, TOKENS: float(len(lens)),
+                  CONTEXT: float(sum(lens))}, weight_bytes=4, kv_bytes=2), pk)
         took_s += least * (1.0 + slack)
         tokens += len(lens)
         context += sum(lens)

@@ -12,16 +12,13 @@ import sys
 
 import pytest
 
-from benchmarks import run as bench_run
+from benchmarks import architectures, run as bench_run
 from benchmarks.harness import lm, peaks, serve, train
 
 ROOT = lm.CHECKOUT
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
 CELLS = [w["name"] for w in BENCH["workloads"]]
-
-# head size 128 is the only geometry the paged decode kernel takes
-TINY = dict(n_embd=256, n_head=2, n_inner=512, n_positions=64, vocab_size=128)
 
 
 @pytest.fixture(scope="module")
@@ -35,10 +32,7 @@ def tiny_bench(tmp_path_factory):
     os.makedirs(tmp / "workloads")
     for c in BENCH["configs"]:
         cfg = lm.load_json("configs", c["name"] + ".json")
-        cfg.update(TINY, n_layer=2)
-        if "serving" in cfg.get("deployment", {}):
-            cfg["deployment"]["serving"].update(
-                slots=4, cache_len=64, num_blocks=16)
+        cfg = architectures.load(cfg).tiny(cfg)
         with open(tmp / "configs" / (c["name"] + ".json"), "w") as f:
             json.dump(cfg, f)
     for name in CELLS:
@@ -80,9 +74,12 @@ def test_cell_rehearsal(tiny_bench, name, trace):
         BENCH, name, seed=3, seconds=3.0, trace=bool(trace),
         require_tpu=False, out_root=str(tiny_bench / "out"))
     assert json.loads(json.dumps(result)) == result     # plain JSON
-    want = {"correct", "attempted", "failed", "metrics", "device"}
+    want = {"correct", "attempted", "failed", "metrics", "device", "compared"}
     assert set(result) == want | ({"breakdown"} if trace else set())
     assert result["correct"] is True, notes["problems"]
+    # each number compared beside its limit, last on the line
+    assert list(result)[-1] == "compared" and len(result["compared"]) >= 2
+    assert all(c["value"] <= c["limit"] for c in result["compared"].values())
     assert result["attempted"] > 0 and result["failed"] == 0
     entry = next(w for w in BENCH["workloads"] if w["name"] == name)
     dev = result["device"]
@@ -107,7 +104,13 @@ def test_cell_rehearsal(tiny_bench, name, trace):
         assert set(got) == {m["name"] for m in e2e}
         assert "setup_s" in got and len(got) >= 2
         assert all(v["value"] > 0 for v in got.values())
-    if "train" in name:
+        # the notes say where set-up went
+        phases = notes["setup_phases_s"]
+        assert set(phases) == {"imports_and_devices", "init_weights",
+                               "check_and_warm"}
+        assert 0 < sum(phases.values()) <= got["setup_s"]["value"]
+    cell = lm.load_json("workloads", name + ".json")
+    if cell["kind"] == "train":
         assert notes["steps"] == result["attempted"]
         assert notes["tokens"] == notes["steps"] * 4 * 64
         # whole blocks of ``fetch_loss_every`` steps, nothing left in flight
@@ -127,11 +130,12 @@ def test_cell_rehearsal(tiny_bench, name, trace):
             assert "delivered_tokens_per_s" in got
         else:
             # the open loop's rate is its offered load: recorded, not judged
-            assert ("output_tokens_per_s" in got) == ("offline" in name)
+            assert ("output_tokens_per_s" in got) == \
+                (cell["traffic"]["loop"] == "closed")
         assert notes["reference_check"]["worst_rel_err"] <= \
             notes["reference_check"]["rtol"]
         assert len(notes["reference_check"]["per_bucket"]) == \
-            (2 if "online" in name else 1)
+            len(cell["prompt_buckets"])
 
 
 def test_a_run_that_ends_its_process_parks_the_dispatcher(tiny_bench,
@@ -147,6 +151,49 @@ def test_a_run_that_ends_its_process_parks_the_dispatcher(tiny_bench,
     assert result["failed"] == 0
     assert 0 < notes["requests_resolved"] < notes["requests_sent"]
     assert result["metrics"]["output_tokens_per_s"]["value"] > 0
+
+
+def _wrong_labels(arch, monkeypatch):
+    """The step trains on other labels than the reference is shown."""
+    real = arch.train_feed
+
+    def train_feed(rs, cfg, traffic):
+        step = real(rs, cfg, traffic)
+        step["feed"]["lbls"] = step["feed"]["lbls"][:, ::-1].copy()
+        return step
+    monkeypatch.setattr(arch, "train_feed", train_feed)
+    return "check_loss_abs_diff"
+
+
+def _wrong_first_token(arch, monkeypatch):
+    """A prefill hands back another token than the one it computed."""
+    from paddle_tpu.serving.generation import GenerationSession
+    real = GenerationSession.admit
+
+    def admit(self, prompt, *args, **kw):
+        slot, first = real(self, prompt, *args, **kw)
+        return slot, (int(first) + 1) % 128
+    monkeypatch.setattr(GenerationSession, "admit", admit)
+    return "prefill_token_rel_gap"
+
+
+@pytest.mark.parametrize("name,break_it", [
+    ("lm-train-1chip", _wrong_labels), ("lm-serve-offline", _wrong_first_token)])
+def test_a_broken_timed_path_is_not_correct(tiny_bench, monkeypatch, name,
+                                            break_it):
+    """The rest of a run over a path broken underneath: the run reaches its
+    end, prints its line, and ``correct`` is false with the number that
+    caught it over its limit."""
+    cfg = lm.load_config(lm.load_json("workloads", name + ".json")["config"])
+    caught_by = break_it(architectures.load(cfg), monkeypatch)
+    result, notes, _ = bench_run.run_cell(
+        BENCH, name, seed=3, seconds=2.0, trace=False, require_tpu=False,
+        out_root=str(tiny_bench / "out"))
+    assert result["correct"] is False and notes["problems"]
+    c = result["compared"][caught_by]
+    assert c["value"] > 3 * c["limit"]
+    assert set(result["metrics"]) == {
+        m["name"] for m in bench_run.cell_metrics(BENCH, name)[0]}
 
 
 def test_command_line_refuses_to_run_without_a_tpu():

@@ -12,6 +12,7 @@ import copy
 
 import pytest
 
+from benchmarks import architectures
 from benchmarks.harness import lm
 
 
@@ -43,7 +44,8 @@ def sizing():
 @pytest.fixture(scope="module")
 def cfg():
     c = copy.deepcopy(lm.load_config("cerebras-gpt-1.3b"))
-    c["n_layer"] = 2
+    for key in architectures.load(c).published(c)["reducible"]:
+        c[key] = 2      # a two-layer cut at the published widths
     return c
 
 
