@@ -1,0 +1,108 @@
+"""Layers of the sparse-expert decoder block (ops/moe_ops.py): RMSNorm,
+rotary positions, a bias-free projection held in a dtype of its own,
+SwiGLU and the expert feed-forward."""
+
+from ..layer_helper import LayerHelper
+from ..initializer import ConstantInitializer, NormalInitializer
+from . import nn as _nn
+from . import ops as _ops
+
+__all__ = ["rms_norm", "rotary_embedding", "linear", "swiglu", "moe_ffn"]
+
+
+def rms_norm(x, epsilon=1e-5, group_size=0, param_attr=None, name=None,
+             **kwargs):
+    """RMSNorm over the last axis of ``x``, or with ``group_size`` over
+    each group of that many lanes (one weight vector of ``group_size``
+    shared by all groups: a per-head norm of a [.., H*D] projection).
+    Float32 out, float32 weight."""
+    helper = LayerHelper("rms_norm", name=name, **kwargs)
+    w = helper.create_parameter(
+        param_attr, shape=[group_size or x.shape[-1]], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_tmp_variable("float32")
+    helper.append_op(type="rms_norm",
+                     inputs={"X": [x.name], "Scale": [w.name]},
+                     outputs={"Y": [out.name]},
+                     attrs={"epsilon": epsilon, "group_size": group_size})
+    return out
+
+
+def rotary_embedding(x, head_dim, theta=10000.0, pos=None, per_row=False,
+                     name=None, **kwargs):
+    """Rotary positions on x [B, T, H*D]: ``pos`` [T] along the time axis
+    (absent: 0..T-1), or with ``per_row`` [B], one per batch row."""
+    helper = LayerHelper("rotary_embedding", name=name, **kwargs)
+    inputs = {"X": [x.name]}
+    if pos is not None:
+        inputs["Pos"] = [pos.name]
+    out = helper.create_tmp_variable(x.dtype)
+    helper.append_op(type="rotary_embedding", inputs=inputs,
+                     outputs={"Out": [out.name]},
+                     attrs={"head_dim": head_dim, "theta": theta,
+                            "per_row": per_row})
+    return out
+
+
+def linear(x, size, param_attr, dtype=None, std=0.02, **kwargs):
+    """``x @ W`` over the last axis with no bias, W [in, size] created and
+    held in ``dtype`` (default: x's). The product is exact and float32
+    whatever W is held in (ops/moe_ops.py ``linear``)."""
+    helper = LayerHelper("linear", **kwargs)
+    w = helper.create_parameter(
+        param_attr, shape=[x.shape[-1], size], dtype=dtype or x.dtype,
+        default_initializer=NormalInitializer(0.0, std))
+    out = helper.create_tmp_variable("float32")
+    helper.append_op(type="linear", inputs={"X": [x.name], "W": [w.name]},
+                     outputs={"Out": [out.name]})
+    return out
+
+
+def swiglu(x, d_ff, prefix, dtype=None, **kwargs):
+    """``(silu(x Wg) * (x Wu)) Wd``: parameters ``<prefix>.gate.w``,
+    ``.up.w`` [d, d_ff] and ``.down.w`` [d_ff, d]."""
+    gate = linear(x, d_ff, prefix + ".gate.w", dtype, **kwargs)
+    up = linear(x, d_ff, prefix + ".up.w", dtype, **kwargs)
+    inner = _nn.elementwise_mul(_ops.silu(gate, **kwargs), up, **kwargs)
+    return linear(inner, x.shape[-1], prefix + ".down.w", dtype, **kwargs)
+
+
+def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
+            route_scale=1.0, expert_offset=0, experts_held=None,
+            dtype=None, std=0.02, **kwargs):
+    """The routed experts of a sparse feed-forward over x [.., d]
+    (ops/moe_ops.py ``moe_ffn``): the router ``<prefix>.router.w`` [d, E]
+    and the selection bias ``<prefix>.expert_bias`` [E] in float32, the
+    held experts ``[expert_offset, expert_offset + experts_held)`` stacked
+    as ``<prefix>.experts.gate.w``, ``.up.w`` [E_held, d, d_ff] and
+    ``.down.w`` [E_held, d_ff, d] in ``dtype``. Returns (out float32,
+    counts [E_held] int32)."""
+    helper = LayerHelper("moe_ffn", **kwargs)
+    d = x.shape[-1]
+    held = num_experts if experts_held is None else experts_held
+    dtype = dtype or x.dtype
+    normal = NormalInitializer(0.0, std)
+    router = helper.create_parameter(
+        prefix + ".router.w", shape=[d, num_experts], dtype="float32",
+        default_initializer=normal)
+    bias = helper.create_parameter(
+        prefix + ".expert_bias", shape=[num_experts], dtype="float32",
+        default_initializer=ConstantInitializer(0.0))
+    stacks = [helper.create_parameter(
+        "%s.experts.%s.w" % (prefix, which), shape=shape, dtype=dtype,
+        default_initializer=normal)
+        for which, shape in (("gate", [held, d, d_ff]),
+                             ("up", [held, d, d_ff]),
+                             ("down", [held, d_ff, d]))]
+    out = helper.create_tmp_variable("float32")
+    counts = helper.create_tmp_variable("int32", stop_gradient=True)
+    helper.append_op(
+        type="moe_ffn",
+        inputs={"X": [x.name], "RouterW": [router.name],
+                "ExpertBias": [bias.name], "WGate": [stacks[0].name],
+                "WUp": [stacks[1].name], "WDown": [stacks[2].name]},
+        outputs={"Out": [out.name], "Counts": [counts.name]},
+        attrs={"num_experts": num_experts, "top_k": top_k,
+               "route_norm": route_norm, "route_scale": route_scale,
+               "expert_offset": expert_offset})
+    return out, counts

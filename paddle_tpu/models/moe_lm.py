@@ -1,0 +1,227 @@
+"""A decoder LM driven by a per-layer spec: grouped-query attention that is
+full or windowed layer by layer, RMSNorm before and after each half,
+rotary positions in the window layers, a gated attention output, and a
+feed-forward that is dense in the leading layers and sparse experts with a
+shared expert in the rest (the ``afmoe`` family; the equations are in
+``benchmarks/reference/afmoe.py``).
+
+:func:`moe_lm` is the whole-sequence forward (its startup program makes the
+weights); :func:`moe_lm_session` builds the paged prefill and decode
+programs through ``transformer.lm_session``, with one kind of layer cache
+for the full layers and one, which frees blocks behind the window, for the
+window layers. Matmul weights, the embedding and the head are created and
+held in ``param_dtype``; norms, router and expert bias are float32, and so
+is every activation: the products are exact (ops/moe_ops.py says why), so
+the layer caches should be float32 too.
+"""
+
+from .. import layers
+from ..layer_helper import LayerHelper
+from ..initializer import NormalInitializer
+from ..param_attr import ParamAttr
+from .transformer import lm_session
+
+__all__ = ["moe_lm", "moe_lm_session", "MoeLM"]
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+class MoeLM:
+    """The model as ``lm_session`` takes one, and as :func:`moe_lm` runs it
+    over whole sequences. Parameter names are fixed (``moe_lm.l3.attn.q.w``)
+    so that every program built from the same sizes shares the scope's
+    weights."""
+
+    def __init__(self, vocab_size, d_model, num_heads, num_kv_heads, head_dim,
+                 d_ff, moe_d_ff, num_experts, top_k, layer_types,
+                 num_dense_layers, sliding_window, rope_theta=10000.0,
+                 rms_eps=1e-5, route_norm=True, route_scale=1.0,
+                 embed_scale=1.0, param_dtype="float32", expert_offset=0,
+                 experts_held=None, init_std=0.02):
+        unknown = set(layer_types) - {SLIDING, FULL}
+        if unknown:
+            raise ValueError("layer_types holds %s: a layer is %r or %r"
+                             % (sorted(unknown), SLIDING, FULL))
+        if num_heads % num_kv_heads:
+            raise ValueError("%d query heads on %d KV heads"
+                             % (num_heads, num_kv_heads))
+        self.vocab_size = vocab_size
+        self.d, self.nh, self.nkv, self.hd = (d_model, num_heads,
+                                               num_kv_heads, head_dim)
+        self.d_ff, self.moe_d_ff = d_ff, moe_d_ff
+        self.num_experts, self.top_k = num_experts, top_k
+        self.layer_types = tuple(layer_types)
+        self.num_dense_layers = num_dense_layers
+        self.window = sliding_window
+        self.theta, self.eps = rope_theta, rms_eps
+        self.route_norm, self.route_scale = route_norm, route_scale
+        self.embed_scale, self.dtype = embed_scale, param_dtype
+        self.expert_offset, self.experts_held = expert_offset, experts_held
+        self.std = init_std
+        # the kinds of layer cache, full first where the model has both;
+        # per layer the width of a cached row and its kind
+        present = [t for t in (FULL, SLIDING) if t in self.layer_types]
+        self.kinds = tuple(("full", None) if t == FULL else
+                           ("window", sliding_window) for t in present)
+        self.cache_layers = [(num_kv_heads * head_dim, present.index(t))
+                             for t in self.layer_types]
+
+    # -- the block ---------------------------------------------------------
+    def _norm(self, x, name, group_size=0):
+        return layers.rms_norm(x, epsilon=self.eps, group_size=group_size,
+                               param_attr="moe_lm.%s.w" % name)
+
+    def _linear(self, x, size, name):
+        return layers.linear(x, size, "moe_lm.%s.w" % name, self.dtype,
+                             self.std)
+
+    def _attention(self, a, i, ctx):
+        """a [B, T, d] -> the gated attention output [B, T, H*D], through
+        the layer's paged cache where ``ctx`` has one."""
+        p = "l%d.attn." % i
+        windowed = self.layer_types[i] == SLIDING
+        q = self._linear(a, self.nh * self.hd, p + "q")
+        k = self._linear(a, self.nkv * self.hd, p + "k")
+        v = self._linear(a, self.nkv * self.hd, p + "v")
+        gate = self._linear(a, self.nh * self.hd, p + "gate")
+        q = self._norm(q, p + "q_norm", self.hd)
+        k = self._norm(k, p + "k_norm", self.hd)
+        if windowed:
+            # positions only where the window bounds what they span
+            rope = dict(head_dim=self.hd, theta=self.theta)
+            if ctx is not None:
+                decode = ctx["mode"] == "decode"
+                rope.update(pos=ctx["pos"] if decode else ctx["pos_idx"],
+                            per_row=decode)
+            q = layers.rotary_embedding(q, **rope)
+            k = layers.rotary_embedding(k, **rope)
+        helper = LayerHelper("moe_lm_attention")
+        out = helper.create_tmp_variable(v.dtype)
+        attrs = {"num_heads": self.nh, "num_kv_heads": self.nkv}
+        if windowed:
+            attrs["window"] = self.window
+        if ctx is None:
+            helper.append_op(
+                type="multihead_attention",
+                inputs={"Q": [q.name], "K": [k.name], "V": [v.name]},
+                outputs={"Out": [out.name]},
+                attrs=dict(attrs, causal=True, ring_axis=None))
+        else:
+            ck, cv = ctx["caches"][i]
+            table = ctx["tables"][self.cache_layers[i][1]]
+            if ctx["mode"] == "prefill":
+                write, attend = ("kv_cache_write_paged",
+                                 "multihead_attention_prefill_paged")
+                where = {"Table": [table.name], "Hist": [ctx["hist"].name],
+                         "Len": [ctx["key_length"].name]}
+                attrs["block_rows"] = 512
+            else:
+                write, attend = ("kv_cache_append_paged",
+                                 "multihead_attention_decode_paged")
+                where = {"Pos": [ctx["pos"].name], "Table": [table.name]}
+            for cvar, new in ((ck, k), (cv, v)):
+                helper.append_op(type=write,
+                                 inputs=dict(where, Cache=[cvar.name],
+                                             New=[new.name]),
+                                 outputs={"Out": [cvar.name]})
+            helper.append_op(type=attend,
+                             inputs=dict(where, Q=[q.name],
+                                         CacheK=[ck.name],
+                                         CacheV=[cv.name]),
+                             outputs={"Out": [out.name]}, attrs=attrs)
+        return layers.elementwise_mul(out, layers.sigmoid(gate))
+
+    def _feed_forward(self, m, i):
+        """-> (f, the experts' pair counts or None)."""
+        if i < self.num_dense_layers:
+            return layers.swiglu(m, self.d_ff, "moe_lm.l%d.mlp" % i,
+                                 self.dtype), None
+        p = "moe_lm.l%d.moe" % i
+        shared = layers.swiglu(m, self.moe_d_ff, p + ".shared", self.dtype)
+        routed, counts = layers.moe_ffn(
+            m, self.num_experts, self.top_k, self.moe_d_ff, p,
+            route_norm=self.route_norm, route_scale=self.route_scale,
+            expert_offset=self.expert_offset,
+            experts_held=self.experts_held, dtype=self.dtype, std=self.std)
+        return layers.elementwise_add(routed, shared), counts
+
+    def hidden(self, tokens, ctx=None):
+        """tokens [B, T] -> (h [B, T, d] float32 before the final norm,
+        [counts] of the expert layers in order)."""
+        emb = layers.embedding(
+            tokens, size=[self.vocab_size, self.d], dtype=self.dtype,
+            param_attr=ParamAttr(
+                name="moe_lm.embed.w",
+                initializer=NormalInitializer(0.0, self.std)),
+            keep_dims=True)
+        h = layers.scale(layers.cast(emb, "float32"), self.embed_scale)
+        all_counts = []
+        for i in range(len(self.layer_types)):
+            a = self._norm(h, "l%d.norm_in" % i)
+            o = self._linear(self._attention(a, i, ctx), self.d,
+                             "l%d.attn.o" % i)
+            h = layers.elementwise_add(
+                h, self._norm(o, "l%d.norm_post_attn" % i))
+            f, counts = self._feed_forward(
+                self._norm(h, "l%d.norm_pre_mlp" % i), i)
+            h = layers.elementwise_add(
+                h, self._norm(f, "l%d.norm_post_mlp" % i))
+            if counts is not None:
+                all_counts.append(counts)
+        return h, all_counts
+
+    def _head(self, h):
+        return self._linear(self._norm(h, "norm_final"), self.vocab_size,
+                            "lm_head")
+
+    # -- what lm_session calls ----------------------------------------------
+    def logits(self, tokens, cache_ctx=None):
+        return self._head(self.hidden(tokens, cache_ctx)[0])
+
+    def prefill_row(self, tokens, last_pos, cache_ctx):
+        # the last real row before the head: [1,P,d] -> [P,1,d] -> [1,1,d];
+        # the head over every row of the bucket would be P x V logits
+        h, _ = self.hidden(tokens, cache_ctx)
+        at = layers.gather(layers.transpose(h, [1, 0, 2]), last_pos)
+        return layers.reshape(self._head(at), [1, self.vocab_size])
+
+    def decode_row(self, tokens, cache_ctx):
+        h, counts = self.hidden(tokens, cache_ctx)
+        row = layers.reshape(self._head(h),
+                             [tokens.shape[0], self.vocab_size])
+        return row, (layers.stack(counts, axis=0) if counts else None)
+
+    def draft(self, overrides):
+        raise ValueError("moe_lm has no speculative draft (and a window "
+                         "kind of layer cache takes no speculation)")
+
+
+def moe_lm(tokens, labels, **sizes):
+    """tokens/labels: [B, T] ids (labels = tokens shifted); ``sizes`` are
+    :class:`MoeLM`'s. Returns (loss, logits)."""
+    model = MoeLM(**sizes)
+    logits = layers.cast(model.logits(tokens), "float32")
+    t = tokens.shape[1]
+    tok_loss = layers.softmax_with_cross_entropy(
+        layers.reshape(logits, [-1, model.vocab_size]),
+        layers.reshape(labels, [-1, 1]))
+    return layers.mean(layers.reshape(tok_loss, [-1, t])), logits
+
+
+def moe_lm_session(slots, cache_len, prompt_buckets, block_size, num_blocks,
+                   window_num_blocks=None, kv_dtype="float32", bos_id=0,
+                   eos_id=1, cache_ns=None, **sizes):
+    """The paged prefill and decode programs of :func:`moe_lm` (a
+    ``GenerationSpec``): ``num_blocks`` sizes the first kind of layer
+    cache (the full layers', where the model has any), and
+    ``window_num_blocks`` the window layers' where it has both. Greedy;
+    positions are rotary, so a sequence is bounded by ``cache_len`` alone."""
+    model = MoeLM(**sizes)
+    return lm_session(
+        model, max_len=cache_len, slots=slots, cache_len=cache_len,
+        prompt_buckets=prompt_buckets, bos_id=bos_id, eos_id=eos_id,
+        cache_ns=cache_ns, dtype=kv_dtype, paged=True,
+        block_size=block_size, num_blocks=num_blocks, prefix_cache=False,
+        decode_policy=None,
+        kind_blocks={"window": window_num_blocks}
+        if len(model.kinds) > 1 else None)

@@ -10,7 +10,7 @@ from ..layers.attention import (transformer_encoder_layer,
                                 positional_encoding,
                                 positional_encoding_window)
 
-__all__ = ["transformer_lm", "transformer_lm_generate",
+__all__ = ["transformer_lm", "transformer_lm_generate", "lm_session",
            "transformer_lm_session", "transformer_tp_rules"]
 
 
@@ -248,6 +248,81 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
     Returns a :class:`paddle_tpu.serving.generation.GenerationSpec`
     consumed by ``GenerationSession`` / ``GenerationScheduler``.
     """
+    return lm_session(
+        _GptBlockLM(vocab_size, d_model, num_heads, d_ff, num_layers),
+        max_len=max_len, slots=slots, cache_len=cache_len,
+        prompt_buckets=prompt_buckets, bos_id=bos_id, eos_id=eos_id,
+        cache_ns=cache_ns, dtype=dtype, paged=paged, block_size=block_size,
+        num_blocks=num_blocks, prefix_cache=prefix_cache,
+        decode_policy=decode_policy)
+
+
+class _GptBlockLM:
+    """The GPT-2 block LM as :func:`lm_session` takes a model: what its
+    layers cache and the logits its programs end in."""
+
+    kinds = (("full", None),)
+    theta = None                # learned positions: bounded by the table
+
+    def __init__(self, vocab_size, d_model, num_heads, d_ff, num_layers):
+        self.vocab_size = vocab_size
+        self.dims = dict(d_model=d_model, num_heads=num_heads, d_ff=d_ff,
+                         num_layers=num_layers)
+        # per layer: (width of a cached K or V row, index of its kind)
+        self.cache_layers = [(d_model, 0)] * num_layers
+
+    def logits(self, tokens, cache_ctx):
+        return _lm_backbone(tokens, self.vocab_size, is_test=True,
+                            cache_ctx=cache_ctx, **self.dims)
+
+    def prefill_row(self, tokens, last_pos, cache_ctx):
+        # logits at the last REAL prompt position (ppos = len-1):
+        # [1,P,V] -> [P,1,V] -> [1,1,V] -> [1,V]
+        by_time = layers.transpose(self.logits(tokens, cache_ctx),
+                                   [1, 0, 2])
+        at = layers.gather(by_time, last_pos)
+        return layers.reshape(at, [1, self.vocab_size])
+
+    def decode_row(self, tokens, cache_ctx):
+        """-> (logits [slots, V], the step's expert counts or None)."""
+        return layers.reshape(self.logits(tokens, cache_ctx),
+                              [tokens.shape[0], self.vocab_size]), None
+
+    def draft(self, overrides):
+        """The speculative draft: by default a 1-layer truncation of the
+        target — identical parameter names for the layers it keeps, so
+        running it over the TARGET's scope shares embedding/head/layer-0
+        weights, a free self-draft. ``decode_draft_model`` overrides
+        the dims (then give the session a separate draft scope)."""
+        dkw = dict(self.dims, num_layers=1)
+        if overrides:
+            unknown = set(overrides) - set(dkw)
+            if unknown:
+                raise ValueError("decode_draft_model keys %r not in "
+                                 "%r" % (sorted(unknown),
+                                         sorted(dkw)))
+            dkw.update(overrides)
+        return _GptBlockLM(self.vocab_size, **dkw)
+
+
+def lm_session(model, max_len=16, slots=None, cache_len=None,
+               prompt_buckets=None, bos_id=0, eos_id=1, cache_ns=None,
+               dtype="float32", paged=None, block_size=None,
+               num_blocks=None, prefix_cache=None, decode_policy="flags",
+               kind_blocks=None):
+    """The KV-cached generation programs of a causal LM, whatever its
+    block: :func:`transformer_lm_session` (whose docstring describes the
+    programs and every argument) with the model behind an object.
+    ``model`` offers ``vocab_size``; ``kinds``, its kinds of layer cache
+    as (name, window) pairs, the first the one ``num_blocks`` sizes;
+    ``cache_layers``, per layer the width of a cached row and the index
+    of its kind; ``logits(tokens, cache_ctx)`` -> [B, T, V];
+    ``prefill_row(tokens, last_pos, cache_ctx)`` -> [1, V];
+    ``decode_row(tokens, cache_ctx)`` -> ([slots, V], expert counts or
+    None); ``draft(overrides)`` -> the speculative draft's model.
+    ``kind_blocks`` sizes the pools of the kinds after the first, by
+    name; their table feeds are ``gen.ptab.<name>`` / ``gen.dtab.<name>``
+    and reach the model as ``cache_ctx["tables"]``, one per kind."""
     from .. import config as _config
     from ..core import unique_name as _un
     from ..core.framework import Program, program_guard
@@ -257,6 +332,8 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
     if decode_policy == "flags":
         decode_policy = DecodePolicy.from_flags()
     policy = decode_policy
+    vocab_size = model.vocab_size
+    kinds = tuple(model.kinds)
     sampled = policy is not None and policy.sampled
     constraint = None if policy is None else policy.constraint
     spec_k = 0 if policy is None else policy.speculate_k
@@ -310,12 +387,19 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
         if prefix_cache is None:
             prefix_cache = bool(_config.get_flag(
                 "generation_prefix_cache"))
-        cache_shape = (num_blocks, block_size, d_model)
+        rows = [num_blocks] + [int((kind_blocks or {})[name])
+                               for name, _ in kinds[1:]]
+        cache_shapes = [(rows[k], block_size, width)
+                        for width, k in model.cache_layers]
     else:
+        if len(kinds) > 1 or kinds[0][1]:
+            raise ValueError("a model with a window or several kinds of "
+                             "layer cache needs the paged KV layout")
         block_size = 0
         num_blocks = 0
         prefix_cache = False
-        cache_shape = (slots, cache_len, d_model)
+        cache_shapes = [(slots, cache_len, width)
+                        for width, _ in model.cache_layers]
     if spec_k and not paged:
         raise ValueError("decode_speculate_k needs the paged KV "
                          "layout (generation_paged_kv / paged=True): "
@@ -325,7 +409,7 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
     def make_cache_vars(program):
         block = program.global_block()
         caches = []
-        for i in range(num_layers):
+        for i, cache_shape in enumerate(cache_shapes):
             ck = block.create_var(name="%s.l%d.k" % (cache_ns, i),
                                   shape=cache_shape, dtype=dtype,
                                   persistable=True, stop_gradient=True)
@@ -334,6 +418,13 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
                                   persistable=True, stop_gradient=True)
             caches.append((ck, cv))
         return caches
+
+    def more_tables(prefix, shape):
+        """The table feeds of the kinds after the first, with the first
+        kind's feed in front: one per kind, as the model indexes them."""
+        return [layers.data("%s.%s" % (prefix, name), shape=shape,
+                            dtype="int32", append_batch_size=False)
+                for name, _ in kinds[1:]]
 
     def _policy_epilogue(row, seed=None, step=None, mask=None):
         """row [n, V] -> next token [n] under the resolved policy.
@@ -395,6 +486,8 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
                              "caches": None, "table": ptab,
                              "hist": phist, "pos_idx": ppix,
                              "key_length": plen, "max_len": max_len}
+                cache_ctx["tables"] = [ptab] + more_tables(
+                    "gen.ptab", [max_blocks])
             else:
                 slot = layers.data("gen.slot", shape=[1],
                                    dtype="int32",
@@ -405,14 +498,8 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
             pseed, pstep, pmask, prefill_extra = _policy_feeds(
                 "gen.p", 1)
             cache_ctx["caches"] = make_cache_vars(prog)
-            logits = _lm_backbone(
-                toks, vocab_size, d_model, num_heads, d_ff, num_layers,
-                is_test=True, cache_ctx=cache_ctx)
-            # logits at the last REAL prompt position (ppos = len-1):
-            # [1,P,V] -> [P,1,V] -> [1,1,V] -> [1,V] -> next [1]
-            by_time = layers.transpose(logits, [1, 0, 2])
-            at = layers.gather(by_time, ppos)
-            row = layers.reshape(at, [1, vocab_size])
+            # the row at the last REAL prompt position (ppos = len-1)
+            row = model.prefill_row(toks, ppos, cache_ctx)
             nxt = _policy_epilogue(row, seed=pseed, step=pstep,
                                    mask=pmask)
         prefill_programs[P] = prog
@@ -430,16 +517,15 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
             cache_ctx = {"mode": "decode", "layout": "paged",
                          "caches": None, "table": dtab, "pos": dpos,
                          "max_len": max_len}
+            cache_ctx["tables"] = [dtab] + more_tables(
+                "gen.dtab", [slots, max_blocks])
         else:
             cache_ctx = {"mode": "decode", "caches": None, "pos": dpos,
                          "max_len": max_len}
         dseed, dstep, dmask, decode_extra = _policy_feeds(
             "gen.d", slots)
         cache_ctx["caches"] = make_cache_vars(decode_program)
-        logits = _lm_backbone(
-            toks, vocab_size, d_model, num_heads, d_ff, num_layers,
-            is_test=True, cache_ctx=cache_ctx)
-        row = layers.reshape(logits, [slots, vocab_size])
+        row, stats = model.decode_row(toks, cache_ctx)
         nxt = _policy_epilogue(row, seed=dseed, step=dstep, mask=dmask)
     decode_fetch = nxt.name
 
@@ -455,8 +541,10 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
             cdst = layers.data("gen.cdst", shape=[1], dtype="int32",
                                append_batch_size=False)
             cblock = copy_program.global_block()
-            for ck, cv in make_cache_vars(copy_program):
-                for cvar in (ck, cv):
+            # the first kind's layers: only its blocks are ever shared
+            for (ck, cv), (_, k) in zip(make_cache_vars(copy_program),
+                                        model.cache_layers):
+                for cvar in (ck, cv) if k == 0 else ():
                     cblock.append_op(
                         type="kv_block_copy",
                         inputs={"Cache": [cvar.name],
@@ -496,9 +584,7 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
                          "caches": make_cache_vars(verify_program),
                          "table": vtab, "hist": vhist, "pos_idx": vpix,
                          "key_length": vlen, "max_len": max_len}
-            logits = _lm_backbone(
-                vtok, vocab_size, d_model, num_heads, d_ff, num_layers,
-                is_test=True, cache_ctx=cache_ctx)
+            logits = model.logits(vtok, cache_ctx)
             vtoks, vaccept = layers.decode_verify(
                 logits, vtok, vseed, vhist, kind=policy.kind,
                 temperature=policy.temperature, top_k=policy.top_k,
@@ -510,47 +596,43 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
         # rows are overwritten in place on rollback — no pool), plain
         # greedy policy (a deterministic draft collapses modified
         # rejection sampling to prefix matching; see decoding_ops).
-        # Default is a 1-layer truncation of the target: identical
-        # parameter names for the layers it keeps, so running it over
-        # the TARGET's scope shares embedding/head/layer-0 weights —
-        # a free self-draft. decode_draft_model overrides the dims
-        # (then give the session a separate draft scope).
-        dkw = dict(d_model=d_model, num_heads=num_heads, d_ff=d_ff,
-                   num_layers=1)
-        if policy.draft:
-            unknown = set(policy.draft) - set(dkw)
-            if unknown:
-                raise ValueError("decode_draft_model keys %r not in "
-                                 "%r" % (sorted(unknown),
-                                         sorted(dkw)))
-            dkw.update(policy.draft)
-        draft_spec = transformer_lm_session(
-            vocab_size, max_len=max_len, slots=slots,
+        draft_spec = lm_session(
+            model.draft(policy.draft), max_len=max_len, slots=slots,
             cache_len=cache_len, prompt_buckets=prompt_buckets,
             bos_id=bos_id, eos_id=eos_id, cache_ns=None, dtype=dtype,
-            paged=False, decode_policy=None, **dkw)
+            paged=False, decode_policy=None)
 
     def _rebuild():
         # the session-rebuild factory (serving.generation): identical
         # programs/parameters, but cache_ns=None forces a FRESH cache
         # namespace — a wedged step leaked from the torn-down session
         # can only ever write to the old, orphaned names
-        return transformer_lm_session(
-            vocab_size, d_model=d_model, num_heads=num_heads,
-            d_ff=d_ff, num_layers=num_layers, max_len=max_len,
-            slots=slots, cache_len=cache_len,
+        return lm_session(
+            model, max_len=max_len, slots=slots, cache_len=cache_len,
             prompt_buckets=prompt_buckets, bos_id=bos_id,
             eos_id=eos_id, cache_ns=None, dtype=dtype, paged=paged,
             block_size=block_size or None,
             num_blocks=num_blocks or None,
-            prefix_cache=prefix_cache, decode_policy=policy)
+            prefix_cache=prefix_cache, decode_policy=policy,
+            kind_blocks=kind_blocks)
+
+    cache_kinds = None
+    if paged and (len(kinds) > 1 or kinds[0][1]):
+        from ..serving.paged_cache import CacheKind
+        cache_kinds = tuple(
+            CacheKind(name, window, rows[k],
+                      sum(1 for _, lk in model.cache_layers if lk == k),
+                      "gen.ptab.%s" % name if k else "gen.ptab",
+                      "gen.dtab.%s" % name if k else "gen.dtab")
+            for k, (name, window) in enumerate(kinds))
 
     return GenerationSpec(
         slots=slots, cache_len=cache_len, max_len=max_len,
         prompt_buckets=prompt_buckets, bos_id=bos_id, eos_id=eos_id,
         cache_vars=tuple(("%s.l%d.%s" % (cache_ns, i, kv), cache_shape,
                           dtype)
-                         for i in range(num_layers) for kv in ("k", "v")),
+                         for i, cache_shape in enumerate(cache_shapes)
+                         for kv in ("k", "v")),
         prefill_programs=prefill_programs,
         prefill_feeds=((("gen.ptok", "gen.plen", "gen.ppos",
                          "gen.phist", "gen.ppix", "gen.ptab") if paged
@@ -569,4 +651,6 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
         copy_feeds=("gen.csrc", "gen.cdst") if paged else None,
         vocab_size=vocab_size, policy=policy,
         verify_program=verify_program, verify_feeds=verify_feeds,
-        verify_fetch=verify_fetch, draft_spec=draft_spec)
+        verify_fetch=verify_fetch, draft_spec=draft_spec,
+        cache_kinds=cache_kinds,
+        stats_fetch=None if stats is None else stats.name)

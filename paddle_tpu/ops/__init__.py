@@ -19,6 +19,7 @@ from . import (  # noqa: F401
     control_flow_ops,
     attention_ops,
     generation_ops,
+    moe_ops,
     decoding_ops,
     crf_ctc_ops,
     beam_search_ops,

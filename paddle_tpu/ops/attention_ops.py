@@ -16,16 +16,43 @@ from ..core.registry import register_op
 from .. import parallel
 
 
+def _grouped_window_attention(q, k, v, nh, nkv, causal, window):
+    """Dense attention of Q [B, T, H*D] on K, V [B, T, Hkv*D]: query head
+    h on KV head ``h // (H / Hkv)``; with a ``window``, key j is visible
+    to query i iff ``i - j < window``. The whole-sequence form of what the
+    paged cache ops do a window or a step at a time."""
+    b, tq, dm = q.shape
+    tk, hd = k.shape[1], dm // nh
+    qh = q.reshape(b, tq, nkv, nh // nkv, hd)
+    kh = k.reshape(b, tk, nkv, hd)
+    vh = v.reshape(b, tk, nkv, hd)
+    s = jnp.einsum("bqkgd,bckd->bkgqc", qh, kh,
+                   preferred_element_type=jnp.float32) * (hd ** -0.5)
+    rows, cols = jnp.arange(tq)[:, None], jnp.arange(tk)[None, :]
+    mask = cols <= rows if causal else jnp.ones((tq, tk), bool)
+    if window:
+        mask = mask & (rows - cols < window)
+    s = jnp.where(mask, s, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bkgqc,bckd->bqkgd", p, vh).reshape(b, tq, dm)
+
+
 @register_op("multihead_attention")
 def _multihead_attention(ctx):
     """Q,K,V: [B, T, H*D] packed; attrs num_heads, causal; optional
-    KeyLength [B] masking padded keys. Out: [B, T, H*D]."""
+    KeyLength [B] masking padded keys. Out: [B, T, H*D]. With attrs
+    num_kv_heads (K, V are [B, T, Hkv*D]) or window: the dense grouped,
+    windowed form (no KeyLength, no kernel)."""
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
     nh = ctx.attr("num_heads")
     causal = ctx.attr("causal", False)
     b, tq, dm = q.shape
     tk = k.shape[1]
     hd = dm // nh
+    if ctx.attr("num_kv_heads") or ctx.attr("window"):
+        return {"Out": _grouped_window_attention(
+            q, k, v, nh, ctx.attr("num_kv_heads") or nh, causal,
+            ctx.attr("window"))}
     qh = q.reshape(b, tq, nh, hd)
     kh = k.reshape(b, tk, nh, hd)
     vh = v.reshape(b, tk, nh, hd)

@@ -159,10 +159,13 @@ def _kv_block_copy(ctx):
 
 @register_op("multihead_attention_decode_paged")
 def _multihead_attention_decode_paged(ctx):
-    """Q [S, 1, H*D], CacheK/CacheV [NB, BS, H*D] pools, Pos [S] int
+    """Q [S, 1, H*D], CacheK/CacheV [NB, BS, Hkv*D] pools, Pos [S] int
     (the row each slot's new token was just written to), Table [S, MB]
-    int; attr num_heads. Out [S, 1, H*D]: each slot's single query
-    attends its table-gathered cache rows [0, Pos[s]] — the paged
+    int; attrs num_heads, and where the model has them num_kv_heads
+    (heads the pools hold; absent: num_heads) and window (absent: none).
+    Out [S, 1, H*D]: each slot's single query
+    attends its table-gathered cache rows [0, Pos[s]], with a window the
+    last ``window`` of them — the paged
     twin of ``multihead_attention_decode``, same masking/softmax
     contract (token parity with the dense layout is a test
     invariant). ``flash_attention`` routes to the Pallas kernel that
@@ -175,41 +178,29 @@ def _multihead_attention_decode_paged(ctx):
     length = ctx.input("Pos").reshape(-1).astype(jnp.int32) + 1
     table = ctx.input("Table")
     nh = ctx.attr("num_heads")
+    nkv, window = ctx.attr("num_kv_heads"), ctx.attr("window")
 
     from .. import config as _config
     if _config.get_flag("flash_attention"):
         from .pallas_attention import decode_attention_paged
-        return {"Out": decode_attention_paged(q, ck, cv, length,
-                                              table, nh)}
+        return {"Out": decode_attention_paged(
+            q, ck, cv, length, table, nh, num_kv_heads=nkv,
+            window=window)}
     from .pallas_attention import _decode_paged_reference
     return {"Out": _decode_paged_reference(q, ck, cv, length, table,
-                                           nh)}
+                                           nh, nkv, window)}
 
 
-@register_op("multihead_attention_prefill_paged")
-def _multihead_attention_prefill_paged(ctx):
-    """Q [1, P, H*D] (a prompt-suffix window whose K/V rows were just
-    written through the table), CacheK/CacheV [NB, BS, H*D] pools,
-    Table [MB] int, Hist [1] int, Len [1] int; attr num_heads.
-    Out [1, P, H*D]: window row i (logical position Hist+i) attends
-    table-gathered cache rows [0, Hist+i] — causal over the cached
-    prefix PLUS the window itself, which is what lets a shared-prefix
-    admission prefill only its unshared suffix. Rows at or past Len
-    are padding: they compute garbage that is neither fetched nor
-    written (the paged write op drops their K/V), and real rows never
-    attend them (their positions are beyond every real row's mask).
-    Dense XLA only — this runs once per admission, not per step; the
-    per-step Pallas path is the decode op."""
-    q = ctx.input("Q")
-    ck = ctx.input("CacheK")
-    cv = ctx.input("CacheV")
-    table = ctx.input("Table").reshape(-1).astype(jnp.int32)
-    hist = ctx.input("Hist").reshape(-1)[0].astype(jnp.int32)
-    nh = ctx.attr("num_heads")
+def _largest_divisor(n, cap):
+    return next(r for r in range(min(n, cap), 0, -1) if n % r == 0)
+
+
+def _prefill_paged_dense(q, ck, cv, table, hist, nh):
+    """Every row against the whole table at once: float32 scores
+    ``[H, P, MB*BS]``."""
     _, p, dm = q.shape
     nb, bs, _ = ck.shape
-    mb = table.shape[0]
-    c = mb * bs
+    c = table.shape[0] * bs
     hd = dm // nh
     tbl = jnp.clip(table, 0, nb - 1)
     k = ck[tbl].reshape(c, dm)
@@ -226,7 +217,111 @@ def _multihead_attention_prefill_paged(ctx):
     s = jnp.where(mask, s, -1e30)
     prob = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     out = jnp.einsum("hqk,hkd->hqd", prob, vh)
-    return {"Out": out.transpose(1, 0, 2).reshape(1, p, dm)}
+    return out.transpose(1, 0, 2).reshape(1, p, dm)
+
+
+def _prefill_paged_blocked(q, ck, cv, table, hist, nh, nkv, window, r):
+    """``r`` rows at a time, one block after the other, each against the
+    chunks of ``r`` cached rows it can see and no others: from the chunk
+    that holds the first row inside the window (or row 0) to the chunk
+    that holds the block's last row, with a running softmax over them
+    (float32 maximum, sum and accumulator, as the kernels keep them). The
+    scores are ``[H, r, r]`` whatever the prompt and the cache. On a
+    float32 cache the products are exact and the weights stay float32."""
+    from .pallas_attention import cache_precision
+    prec = cache_precision(ck.dtype)
+    _, p, dm = q.shape
+    nb, bs, _ = ck.shape
+    hd, group = dm // nh, nh // nkv
+    pages = -(-r // bs)                     # pages a chunk
+    c = pages * bs
+    mb = table.shape[0]
+    n_chunks = -(-mb // pages)
+    # dead entries, and the padding to whole chunks, gather block 0: their
+    # rows lie behind the window or beyond every row's own position
+    tbl = jnp.zeros(n_chunks * pages, jnp.int32).at[:mb].set(
+        jnp.where(table < nb, table, 0))
+    # [blocks, Hkv, G*r, hd]: a KV head's query heads side by side
+    qh = q.reshape(p // r, r, nkv, group, hd).transpose(0, 2, 3, 1, 4) \
+        .reshape(p // r, nkv, group * r, hd)
+    offs = jnp.tile(jnp.arange(r, dtype=jnp.int32), group)
+
+    def block(args):
+        b, qb = args
+        rows = hist + b * r + offs                          # [G*r]
+        lo = 0 if window is None else \
+            jnp.maximum(hist + b * r - window + 1, 0) // c
+        hi = (hist + b * r + r - 1) // c + 1
+
+        def chunk(j, carry):
+            m, l, acc = carry
+            ids = jax.lax.dynamic_slice(tbl, (j * pages,), (pages,))
+            kh = ck[ids].reshape(c, nkv, hd).transpose(1, 0, 2)
+            vh = cv[ids].reshape(c, nkv, hd).transpose(1, 0, 2)
+            s = jnp.einsum("hqd,hkd->hqk", qb, kh, precision=prec,
+                           preferred_element_type=jnp.float32)
+            s = s * (hd ** -0.5)
+            cols = j * c + jnp.arange(c, dtype=jnp.int32)
+            mask = cols[None, :] <= rows[:, None]
+            if window is not None:
+                mask = mask & (rows[:, None] - cols[None, :] < window)
+            s = jnp.where(mask, s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            prob = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            pv = jnp.einsum("hqk,hkd->hqd", prob.astype(q.dtype), vh,
+                            precision=prec,
+                            preferred_element_type=jnp.float32)
+            return (m_new, alpha * l + jnp.sum(prob, -1, keepdims=True),
+                    alpha * acc + pv)
+
+        _, l, acc = jax.lax.fori_loop(
+            lo, jnp.minimum(hi, n_chunks), chunk,
+            (jnp.full((nkv, group * r, 1), -1e30, jnp.float32),
+             jnp.zeros((nkv, group * r, 1), jnp.float32),
+             jnp.zeros((nkv, group * r, hd), jnp.float32)))
+        out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
+        return out.reshape(nkv, group, r, hd).transpose(2, 0, 1, 3) \
+            .reshape(r, dm)
+
+    out = jax.lax.map(block, (jnp.arange(p // r, dtype=jnp.int32), qh))
+    return out.reshape(1, p, dm)
+
+
+@register_op("multihead_attention_prefill_paged")
+def _multihead_attention_prefill_paged(ctx):
+    """Q [1, P, H*D] (a prompt-suffix window whose K/V rows were just
+    written through the table), CacheK/CacheV [NB, BS, Hkv*D] pools,
+    Table [MB] int, Hist [1] int, Len [1] int; attrs num_heads, and
+    where the model has them num_kv_heads (absent: num_heads), window
+    (absent: none) and block_rows (absent: every row at once).
+    Out [1, P, H*D]: window row i (logical position Hist+i) attends
+    table-gathered cache rows [0, Hist+i], with a window the last
+    ``window`` of them — causal over the cached
+    prefix PLUS the window itself, which is what lets a shared-prefix
+    admission prefill only its unshared suffix. Rows at or past Len
+    are padding: they compute garbage that is neither fetched nor
+    written (the paged write op drops their K/V), and real rows never
+    attend them (their positions are beyond every real row's mask).
+    With ``block_rows`` the rows are taken that many at a time against
+    the chunks of the cache they can see (``_prefill_paged_blocked``), so
+    that neither the temporaries nor the work grow as P x cache.
+    Dense XLA only — this runs once per admission, not per step; the
+    per-step Pallas path is the decode op."""
+    q = ctx.input("Q")
+    ck = ctx.input("CacheK")
+    cv = ctx.input("CacheV")
+    table = ctx.input("Table").reshape(-1).astype(jnp.int32)
+    hist = ctx.input("Hist").reshape(-1)[0].astype(jnp.int32)
+    nh = ctx.attr("num_heads")
+    if not ctx.attr("block_rows"):
+        if ctx.attr("num_kv_heads") or ctx.attr("window"):
+            raise ValueError("grouped queries and a window need block_rows")
+        return {"Out": _prefill_paged_dense(q, ck, cv, table, hist, nh)}
+    return {"Out": _prefill_paged_blocked(
+        q, ck, cv, table, hist, nh, ctx.attr("num_kv_heads") or nh,
+        ctx.attr("window"),
+        _largest_divisor(q.shape[1], ctx.attr("block_rows")))}
 
 
 @register_op("multihead_attention_decode")

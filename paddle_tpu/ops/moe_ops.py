@@ -1,0 +1,196 @@
+"""Ops of the sparse-expert decoder block: RMSNorm, rotary positions, the
+projection and the expert feed-forward.
+
+**Every product of this block is exact.** Which eight experts a token goes
+to is a discontinuous function of its activations: an error of a bfloat16
+ulp anywhere upstream of a router moves the 8th and 9th scores past each
+other for a few tokens in a hundred, and such a token's logits then differ
+from the model's by a tenth to a third of the largest (PERF.md section 6,
+PR 27). So activations are float32, and a float32 activation is multiplied
+with a weight held in bfloat16 without rounding it: it is taken apart into
+three bfloat16 pieces that add up to it exactly (:func:`_pieces`), the
+pieces go through the MXU as three times the rows in ONE pass over the
+weight, and the three partial results are added in float32. A decode step,
+which is bound by the bytes of the weights, pays little for it; a prefill
+pays three times the products.
+
+* ``linear`` - ``x @ W`` with no bias, exact, float32 out.
+
+* ``rms_norm`` — ``x * w / sqrt(mean(x^2) + eps)`` over the last axis (or
+  over each group of ``group_size`` lanes of it: one head's lanes of a
+  ``[.., H*D]`` projection), computed and returned in float32 whatever
+  flows in.
+* ``rotary_embedding`` — rotary positions on ``[B, T, H*D]`` in the
+  half-split pairing (lane ``i`` of a head turns with lane ``i + D/2``),
+  positions along the time axis (a prompt window, a training batch) or one
+  per batch row (a decode step).
+* ``moe_ffn`` — route, sort by expert, grouped matmul (exact), weighted
+  combine.
+  Every token-expert pair is computed whatever the imbalance: there is no
+  capacity and no padding to a per-expert size. The op routes over all
+  ``num_experts`` and returns the part of the result that the experts it
+  holds, ``[expert_offset, expert_offset + E_held)``, give; the parts of
+  disjoint holders add up to the whole layer. Beside the result it returns
+  how many pairs each held expert took.
+"""
+
+import jax
+import jax.numpy as jnp
+
+from ..core.registry import register_op
+
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _pieces(x):
+    """x float32 -> three bfloat16 arrays that add up to x exactly (8 + 8
+    + 8 bits of significand). ``reduce_precision`` and not a cast there
+    and back: the compiler may drop such a pair and leave nothing to
+    subtract."""
+    out, rest = [], x.astype(jnp.float32)
+    for _ in range(3):
+        top = jax.lax.reduce_precision(rest, exponent_bits=8,
+                                       mantissa_bits=7)
+        out.append(top.astype(jnp.bfloat16))
+        rest = rest - top
+    return out
+
+
+def _add_pieces(y):
+    """The partial results [3, ..], smallest first."""
+    return (y[2] + y[1]) + y[0]
+
+
+def exact_dot(x, w):
+    """x [n, d] float32 @ w [d, f], every product exact and the sums in
+    float32: a bfloat16 ``w`` meets x's three pieces as [3n, d] in one
+    pass; any other is multiplied at the highest precision."""
+    if w.dtype != jnp.bfloat16:
+        return jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                       precision=_HIGHEST)
+    y = jnp.dot(jnp.concatenate(_pieces(x), axis=0), w,
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT)
+    return _add_pieces(y.reshape((3, x.shape[0], w.shape[1])))
+
+
+def exact_ragged_dot(xs, w, counts):
+    """:func:`exact_dot` for rows sorted by group: xs [n, d] float32, w
+    [G, d, f], ``counts`` [G] rows a group. A row's three pieces lie one
+    after the other, so every group is three times as long."""
+    if w.dtype != jnp.bfloat16:
+        return jax.lax.ragged_dot(
+            xs.astype(jnp.float32), w.astype(jnp.float32), counts,
+            precision=_HIGHEST, preferred_element_type=jnp.float32)
+    n, d = xs.shape
+    y = jax.lax.ragged_dot(
+        jnp.stack(_pieces(xs), axis=1).reshape(3 * n, d), w, 3 * counts,
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.DEFAULT)
+    return _add_pieces(jnp.moveaxis(y.reshape(n, 3, w.shape[2]), 1, 0))
+
+
+@register_op("linear")
+def _linear(ctx):
+    """X [.., d], W [d, f] held in any dtype; Out float32 [.., f] =
+    ``X @ W``, exact (:func:`exact_dot`)."""
+    x, w = ctx.input("X"), ctx.input("W")
+    y = exact_dot(x.reshape(-1, x.shape[-1]), w)
+    return {"Out": y.reshape(x.shape[:-1] + (w.shape[1],))}
+
+
+@register_op("rms_norm")
+def _rms_norm(ctx):
+    """X [.., d], Scale [d] or [group_size]; attrs epsilon, group_size (0:
+    the whole last axis). Y float32, X's shape."""
+    x = ctx.input("X").astype(jnp.float32)
+    w = ctx.input("Scale").astype(jnp.float32)
+    eps = ctx.attr("epsilon", 1e-5)
+    group = ctx.attr("group_size", 0)
+    shape = x.shape
+    if group:
+        x = x.reshape(shape[:-1] + (shape[-1] // group, group))
+    y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
+    return {"Y": y.reshape(shape)}
+
+
+@register_op("rotary_embedding")
+def _rotary_embedding(ctx):
+    """X [B, T, H*D], Pos int (optional): [T] positions along the time
+    axis, or with attr ``per_row`` [B], one position per batch row; absent,
+    the positions are 0..T-1. attrs head_dim, theta. Out: X's shape and
+    dtype, the angles taken in float32."""
+    x = ctx.input("X")
+    hd = ctx.attr("head_dim")
+    theta = ctx.attr("theta", 10000.0)
+    b, t, dm = x.shape
+    if ctx.has_input("Pos"):
+        pos = ctx.input("Pos").reshape(-1).astype(jnp.float32)
+        pos = pos.reshape((b, 1) if ctx.attr("per_row", False) else (1, t))
+    else:
+        pos = jnp.arange(t, dtype=jnp.float32).reshape(1, t)
+    half = hd // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / hd)
+    ang = pos[..., None, None] * freq                   # [b|1, 1|t, 1, half]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xh = x.astype(jnp.float32).reshape(b, t, dm // hd, hd)
+    x1, x2 = xh[..., :half], xh[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return {"Out": out.reshape(b, t, dm).astype(x.dtype)}
+
+
+def route(x, router_w, bias, top_k, route_norm, route_scale):
+    """The router of ``moe_ffn``: x [n, d] -> (sel [n, k] expert ids,
+    w [n, k] float32 weights). Scores are ``sigmoid`` of float32 logits
+    taken at the highest precision; ``bias`` moves the selection only."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, sel, axis=1)
+    if route_norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return sel, w * route_scale
+
+
+@register_op("moe_ffn")
+def _moe_ffn(ctx):
+    """X [.., d]; RouterW [d, E] and ExpertBias [E] (float32); WGate, WUp
+    [E_held, d, f] and WDown [E_held, f, d], the held experts stacked;
+    attrs num_experts, top_k, route_norm, route_scale, expert_offset.
+    Out float32, X's shape: sum over the token's selected experts that
+    are held of ``w * (silu(x WGate) * (x WUp)) WDown``. Counts [E_held]
+    int32: the pairs each held expert took in this call."""
+    x = ctx.input("X")
+    wg, wu, wd = ctx.input("WGate"), ctx.input("WUp"), ctx.input("WDown")
+    k = ctx.attr("top_k")
+    offset = ctx.attr("expert_offset", 0)
+    held, d = wg.shape[0], x.shape[-1]
+    x2 = x.reshape(-1, d)
+    n = x2.shape[0]
+    router_w = ctx.input("RouterW")
+    if router_w.shape[1] != ctx.attr("num_experts"):
+        raise ValueError("moe_ffn routes over %d experts, RouterW has %d"
+                         % (ctx.attr("num_experts"), router_w.shape[1]))
+    sel, w = route(x2, router_w, ctx.input("ExpertBias"), k,
+                   ctx.attr("route_norm", True),
+                   ctx.attr("route_scale", 1.0))
+    # the n*k pairs sorted by held expert; pairs of experts held elsewhere
+    # sort last, fall in no group and weigh nothing
+    local = sel.reshape(-1) - offset
+    mine = (local >= 0) & (local < held)
+    key = jnp.where(mine, local, held)
+    order = jnp.argsort(key)                            # stable
+    counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    xs = x2[order // k]
+    inner = jax.nn.silu(exact_ragged_dot(xs, wg, counts)) * \
+        exact_ragged_dot(xs, wu, counts)
+    ys = exact_ragged_dot(inner, wd, counts)            # [n*k, d] float32
+    # rows past the last group are nobody's: whatever they hold, they add 0
+    ys = jnp.where((jnp.arange(n * k) < jnp.sum(counts))[:, None], ys, 0.0)
+    # back to the pairs' own order, then the weighted sum over a token's k
+    pair_w = jnp.where(mine, w.reshape(-1), 0.0)
+    y = ys[jnp.argsort(order)] * pair_w[:, None]
+    return {"Out": jnp.sum(y.reshape(n, k, d), axis=1).reshape(x.shape),
+            "Counts": counts}

@@ -400,36 +400,60 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
 
 # -- paged decode: block-table gather over a block-pool cache ------------
 
+def cache_precision(cache_dtype):
+    """How the paged attention ops multiply: a bfloat16 cache in one MXU
+    pass as before; a float32 cache, which a model asks for when a
+    rounded key would change what it computes (models/moe_lm.py), at the
+    highest precision, products exact."""
+    return jax.lax.Precision.HIGHEST if cache_dtype == jnp.float32 \
+        else jax.lax.Precision.DEFAULT
+
+
 def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
-                            num_heads):
-    """Dense XLA single-query attention over a PAGED cache: q [S, 1, D]
-    (one query token per slot, D = heads*head_dim), k/v pools
-    [NB, BS, D], lengths [S] (live rows per slot), tables [S, MB]
-    block ids mapping slot s's logical rows [j*BS, (j+1)*BS) to pool
-    block tables[s, j]. Table entries >= NB mark dead/unallocated
-    rows (clipped for the gather; the length mask keeps them
-    unattendable). The flag-off fallback AND the numeric contract the
-    paged kernel must match: after the gather this is exactly
-    :func:`_decode_reference` on the logical [S, MB*BS] cache, so the
-    paged and dense layouts are token-identical by construction."""
+                            num_heads, num_kv_heads=None, window=None):
+    """Dense XLA single-query attention over a PAGED cache: q [S, 1, H*D]
+    (one query token per slot), k/v pools [NB, BS, Hkv*D], lengths [S]
+    (live rows per slot), tables [S, MB] block ids mapping slot s's
+    logical rows [j*BS, (j+1)*BS) to pool block tables[s, j]. Table
+    entries >= NB mark dead/unallocated rows (clipped for the gather;
+    the length mask keeps them unattendable). ``num_kv_heads`` (default:
+    ``num_heads``) is the number of heads the pools hold: query head h
+    attends KV head ``h // (H / Hkv)``. With ``window``, row j is
+    attendable iff ``length - window <= j < length``: the query sits at
+    ``length - 1`` and sees itself and the ``window - 1`` rows before it;
+    rows behind the window may sit in blocks the table no longer names.
+    The flag-off fallback AND the numeric contract the paged kernel must
+    match: after the gather this is exactly :func:`_decode_reference` on
+    the logical [S, MB*BS] cache, so the paged and dense layouts are
+    token-identical by construction."""
     s, _, dm = q.shape
-    nb, bs, _ = k_pool.shape
+    nb, bs, dkv = k_pool.shape
+    nkv = num_kv_heads or num_heads
     mb = tables.shape[1]
     c = mb * bs
     hd = dm // num_heads
     tbl = jnp.clip(tables.astype(jnp.int32), 0, nb - 1)
-    k = k_pool[tbl].reshape(s, c, dm)
-    v = v_pool[tbl].reshape(s, c, dm)
-    qh = q.reshape(s, num_heads, hd)
-    kh = k.reshape(s, c, num_heads, hd).transpose(0, 2, 1, 3)
-    vh = v.reshape(s, c, num_heads, hd).transpose(0, 2, 1, 3)
+    # [S, C, Hkv, hd] -> every query head beside its KV head's rows
+    kh = jnp.repeat(k_pool[tbl].reshape(s, c, nkv, hd), num_heads // nkv,
+                    axis=2).transpose(0, 2, 1, 3)
+    vh = jnp.repeat(v_pool[tbl].reshape(s, c, nkv, hd), num_heads // nkv,
+                    axis=2).transpose(0, 2, 1, 3)
+    qh = q.reshape(s * num_heads, 1, hd)
+    kh = kh.reshape(s * num_heads, c, hd)
+    vh = vh.reshape(s * num_heads, c, hd)
     lens = jnp.broadcast_to(
-        jnp.asarray(lengths).reshape(s, 1), (s, num_heads))
-    out = _decode_reference(qh.reshape(s * num_heads, 1, hd),
-                            kh.reshape(s * num_heads, c, hd),
-                            vh.reshape(s * num_heads, c, hd),
-                            lens.reshape(s * num_heads))
-    return out.reshape(s, 1, dm)
+        jnp.asarray(lengths).reshape(s, 1), (s, num_heads)).reshape(-1)
+    if window is None:
+        return _decode_reference(qh, kh, vh, lens).reshape(s, 1, dm)
+    prec = cache_precision(k_pool.dtype)
+    sc = jnp.einsum("bqd,bkd->bqk", qh, kh, precision=prec,
+                    preferred_element_type=jnp.float32) * (hd ** -0.5)
+    cols = jnp.arange(c)[None, None, :]
+    mask = (cols < lens[:, None, None]) & \
+        (cols >= lens[:, None, None] - window)
+    p = jax.nn.softmax(jnp.where(mask, sc, _NEG), axis=-1).astype(q.dtype)
+    return jnp.einsum("bqk,bkd->bqd", p, vh,
+                      precision=prec).reshape(s, 1, dm)
 
 
 # VMEM for the paged kernel's page buffers: K and V, each double-buffered
@@ -447,43 +471,60 @@ def _paged_block_pages(block_size, d_model, dtype, max_blocks):
 
 def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
                          kbuf, vbuf, sem, base_ref, *, block_size,
-                         max_blocks, num_blocks, pages, num_heads, scale):
+                         max_blocks, num_blocks, pages, num_heads,
+                         num_kv_heads, window, scale):
     """One slot of single-query flash decode THROUGH a block table, all
     heads at once. The pools stay in HBM; the program walks its slot's
     LIVE pages only, ``pages`` of them a compute block, each page
-    (``[BS, H*D]``, contiguous) copied whole into one of two VMEM
+    (``[BS, Hkv*D]``, contiguous) copied whole into one of two VMEM
     buffers while the block before it is attended. The last step of a
     slot starts the first block of the next one, so consecutive
     programs overlap too; ``base_ref`` carries which buffer that block
-    went to. A slot whose first table entry is dead (>= NB: inactive or
-    starved) has no pages: it fetches nothing and writes zeros.
+    went to. With a ``window`` the walk starts at the page that holds row
+    ``length - window`` and masks that page's rows behind it: pages
+    before it are never read (the session has freed them). A slot whose
+    first live table entry is dead (>= NB: inactive or starved) has no
+    pages: it fetches nothing and writes zeros.
 
     Heads share one pass over a block through a block-diagonal query
-    ``[H, H*D]`` (row h holds head h's lanes): scores are ``[H, rows]``,
-    ``P @ V`` is ``[H, H*D]`` and its diagonal blocks are the outputs.
+    ``[H, Hkv*D]``: row h holds head h's lanes in the columns of KV head
+    ``h // (H / Hkv)``, so scores are ``[H, rows]``, ``P @ V`` is
+    ``[H, Hkv*D]`` and head h's output is its KV head's block of row h.
+    The query block is ``[1, H*D]`` where every head has a KV head of its
+    own and ``[H, D]`` where heads share one: the layout each model has.
     The surplus products are free: the kernel runs at the copies'
     speed."""
     from jax.experimental.pallas import tpu as pltpu
     bs, mb, nb = block_size, max_blocks, num_blocks
     si, ns = pl.program_id(0), pl.num_programs(0)
-    dm = q_ref.shape[-1]
+    dkv = kbuf.shape[-1]
+    hd = dkv // num_kv_heads
+    group = num_heads // num_kv_heads
     rows = pages * bs
     mxu = jnp.promote_types(q_ref.dtype, kbuf.dtype)
+    prec = cache_precision(kbuf.dtype)
 
-    def pages_of(slot):
-        n = jnp.minimum((lens_ref[slot] + bs - 1) // bs, mb)
-        return jnp.where(tab_ref[slot * mb] < nb, n, 0)
+    def first_of(slot):
+        """The first page a slot's query can see."""
+        if window is None:
+            return 0
+        return jnp.minimum(jnp.maximum(lens_ref[slot] - window, 0) // bs,
+                           mb - 1)
+
+    def pages_of(slot, first):
+        n = jnp.minimum((lens_ref[slot] + bs - 1) // bs, mb) - first
+        return jnp.where(tab_ref[slot * mb + first] < nb, n, 0)
 
     def live_pages(n_pages, blk):
         return jnp.clip(n_pages - blk * pages, 0, pages)
 
-    def for_live_pages(slot, n_pages, blk, buf, act):
+    def for_live_pages(slot, first, n_pages, blk, buf, act):
         """``act`` on the K and V copies of block ``blk``'s live pages
         (a copy is waited for through a descriptor equal to the one
         that started it)."""
         def one(i, _):
-            page = jnp.clip(tab_ref[slot * mb + blk * pages + i],
-                            0, nb - 1)
+            page = jnp.clip(
+                tab_ref[slot * mb + first + blk * pages + i], 0, nb - 1)
             dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
             act(pltpu.make_async_copy(
                 kp_ref.at[page], kbuf.at[buf, dst], sem.at[0, buf]))
@@ -491,31 +532,40 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
                 vp_ref.at[page], vbuf.at[buf, dst], sem.at[1, buf]))
         jax.lax.fori_loop(0, live_pages(n_pages, blk), one, None)
 
-    def start(slot, n_pages, blk, buf):
-        for_live_pages(slot, n_pages, blk, buf, lambda c: c.start())
+    def start(slot, first, n_pages, blk, buf):
+        for_live_pages(slot, first, n_pages, blk, buf,
+                       lambda c: c.start())
 
     @pl.when(si == 0)
     def _():
         base_ref[0] = 0
 
     base = base_ref[0]          # the buffer of this slot's first block
-    n_pages = pages_of(si)
+    first = first_of(si)
+    n_pages = pages_of(si, first)
     n_blocks = (n_pages + pages - 1) // pages
-    started = (si > 0) & (pages_of(jnp.maximum(si - 1, 0)) > 0)
+    prev = jnp.maximum(si - 1, 0)
+    started = (si > 0) & (pages_of(prev, first_of(prev)) > 0)
     nxt = jnp.minimum(si + 1, ns - 1)
-    nxt_pages = jnp.where(si + 1 < ns, pages_of(nxt), 0)
+    nxt_first = first_of(nxt)
+    nxt_pages = jnp.where(si + 1 < ns, pages_of(nxt, nxt_first), 0)
 
     @pl.when((n_blocks > 0) & jnp.logical_not(started))
     def _():
-        start(si, n_pages, 0, base)
+        start(si, first, n_pages, 0, base)
 
     length = lens_ref[si]
-    diag = jax.lax.broadcasted_iota(jnp.int32, (num_heads, dm), 0) == \
-        jax.lax.broadcasted_iota(jnp.int32, (num_heads, dm), 1) \
-        // (dm // num_heads)
-    q_bd = jnp.where(
-        diag, jnp.broadcast_to(q_ref[0].astype(jnp.float32),
-                               (num_heads, dm)), 0.0).astype(mxu)
+    # query head h against KV head h // group: its lanes in that head's
+    # columns, zeros elsewhere
+    diag = jax.lax.broadcasted_iota(jnp.int32, (num_heads, dkv), 0) \
+        // group == \
+        jax.lax.broadcasted_iota(jnp.int32, (num_heads, dkv), 1) // hd
+    q = q_ref[0].astype(jnp.float32)
+    if group == 1:      # q [1, H*D]: every row is every head's lanes
+        q = jnp.broadcast_to(q, (num_heads, dkv))
+    else:               # q [H, D]: a head's lanes under each KV head
+        q = jnp.concatenate([q] * num_kv_heads, axis=1)
+    q_bd = jnp.where(diag, q, 0.0).astype(mxu)
 
     def step(b, carry):
         m, l, acc = carry
@@ -523,29 +573,33 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
 
         @pl.when(b + 1 < n_blocks)
         def _():
-            start(si, n_pages, b + 1, 1 - buf)
+            start(si, first, n_pages, b + 1, 1 - buf)
 
         @pl.when((b + 1 == n_blocks) & (nxt_pages > 0))
         def _():
-            start(nxt, nxt_pages, 0, 1 - buf)
+            start(nxt, nxt_first, nxt_pages, 0, 1 - buf)
 
-        for_live_pages(si, n_pages, b, buf, lambda c: c.wait())
+        for_live_pages(si, first, n_pages, b, buf, lambda c: c.wait())
         # pages of the last block that were not fetched hold whatever
         # VMEM held. The mask covers their scores; V's rows go to zero
         # (0 x NaN is NaN)
         def zero_page(i, _):
             vbuf[buf, pl.ds(pl.multiple_of(i * bs, bs), bs), :] = \
-                jnp.zeros((bs, dm), vbuf.dtype)
+                jnp.zeros((bs, dkv), vbuf.dtype)
         jax.lax.fori_loop(live_pages(n_pages, b), pages, zero_page, None)
         # explicit Precision, as in _body. A query wider than the pool
         # (f32 on bf16 blocks) upcasts the block, the reference's
-        # promotion; equal dtypes go to the MXU as they are
+        # promotion; equal dtypes go to the MXU as they are, float32
+        # blocks at the highest precision
         s = jax.lax.dot_general(
             q_bd, kbuf[buf].astype(mxu), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT) * scale     # [H, rows]
-        mask = b * rows + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1) < length
+            precision=prec) * scale                          # [H, rows]
+        row = (first + b * pages) * bs + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        mask = row < length
+        if window is not None:
+            mask = mask & (row >= length - window)
         s = jnp.where(mask, s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -554,99 +608,117 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
                 acc * alpha + jnp.dot(
                     p.astype(mxu), vbuf[buf].astype(mxu),
                     preferred_element_type=jnp.float32,
-                    precision=jax.lax.Precision.DEFAULT))
+                    precision=prec))
 
     m, l, acc = jax.lax.fori_loop(
         0, n_blocks, step,
         (jnp.full((num_heads, 1), _NEG, jnp.float32),
          jnp.zeros((num_heads, 1), jnp.float32),
-         jnp.zeros((num_heads, dm), jnp.float32)))
+         jnp.zeros((num_heads, dkv), jnp.float32)))
     base_ref[0] = (base + n_blocks) % 2
     out = jnp.where(diag, acc / jnp.maximum(l, 1e-30), 0.0)
-    o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    # row h is zero outside its KV head's block: with a head each, the rows
+    # add up to [1, H*D]; with grouped queries, the blocks to [H, D]
+    if group == 1:
+        out = jnp.sum(out, axis=0, keepdims=True)
+    else:
+        out = sum(out[:, g * hd:(g + 1) * hd] for g in range(num_kv_heads))
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
-                           num_heads, interpret=None):
+                           num_heads, interpret=None, num_kv_heads=None,
+                           window=None):
     """Block-table-gather mode of :func:`decode_attention`: single-query
     flash decode where K/V live in a PAGED pool and the kernel streams
     exactly the live blocks of each sequence — never the whole pool,
     never a gathered dense copy.
 
-    q: [S, 1, D] (one query per slot, D = num_heads * head_dim);
-    k_pool/v_pool: [NB, BS, D]; lengths: [S]; tables: [S, MB] int
-    block ids (entries >= NB are dead — clamped, masked by length).
-    Returns [S, 1, D]. One program per slot (:func:`_decode_paged_kernel`):
-    ``lengths`` and the table are scalar-prefetched, the pools are not
-    blocked, and the program fetches its slot's ``cdiv(length, BS)``
-    live pages by hand, whole and for all heads, so a slot costs what
-    its context costs and a slot whose table row is dead (how the
-    session marks inactive and starved slots) costs nothing and
-    returns zeros, where the reference attends clamped rows nobody
-    reads. Pool geometry Mosaic cannot tile falls back to the dense
-    gather reference — same semantics, so the flag never changes
+    q: [S, 1, H*D] (one query per slot); k_pool/v_pool: [NB, BS, Hkv*D]
+    with ``num_kv_heads`` heads (default ``num_heads``; fewer: grouped
+    queries, head h on KV head ``h // (H / Hkv)``); lengths: [S]; tables:
+    [S, MB] int block ids (entries >= NB are dead — clamped, masked by
+    length); ``window``: attend rows ``[length - window, length)`` only.
+    Returns [S, 1, H*D]. One program per slot
+    (:func:`_decode_paged_kernel`): ``lengths`` and the table are
+    scalar-prefetched, the pools are not blocked, and the program
+    fetches its slot's live pages by hand — ``cdiv(length, BS)`` of
+    them, less those wholly behind the window — whole and for all heads,
+    so a slot costs what its context costs and a slot whose table row is
+    dead (how the session marks inactive and starved slots) costs
+    nothing and returns zeros, where the reference attends clamped rows
+    nobody reads. Pool geometry Mosaic cannot tile falls back to the
+    dense gather reference — same semantics, so the flag never changes
     tokens. ``interpret=None`` auto-selects interpreter mode off-TPU."""
     if interpret is None:
         interpret = kernel_path.interpret_mode()
-    dm, bs = q.shape[-1], k_pool.shape[1]
-    hd = dm // num_heads
+    nkv = num_kv_heads or num_heads
+    bs, dkv = k_pool.shape[1], k_pool.shape[2]
+    hd = q.shape[-1] // num_heads
     sublanes = 32 // jnp.dtype(k_pool.dtype).itemsize
-    if not interpret and (bs % sublanes != 0 or dm % 128 != 0 or
+    if not interpret and (bs % sublanes != 0 or dkv % 128 != 0 or
                           (hd % 128 != 0 and num_heads != 1)):
         # compiled Mosaic wants a page to land in its buffer on whole
         # tiles: BS rows a multiple of the dtype's sublane tile (8 for
-        # f32, 16 for bf16), H*D whole lanes. Heads narrower than a
+        # f32, 16 for bf16), Hkv*D whole lanes. Heads narrower than a
         # lane tile (e.g. head_dim 64) have not run compiled. Anything
         # else takes the XLA gather path (identical semantics)
         kernel_path.record("decode_attention_paged")
         return _decode_paged_reference(q, k_pool, v_pool, lengths,
-                                       tables, num_heads)
+                                       tables, num_heads, nkv, window)
     kernel_path.record("decode_attention_paged", interpret)
-    pages = _paged_block_pages(bs, dm, k_pool.dtype, tables.shape[1])
+    pages = _paged_block_pages(bs, dkv, k_pool.dtype, tables.shape[1])
     return _decode_paged_call(q, k_pool, v_pool, lengths, tables,
-                              num_heads, pages, interpret)
+                              num_heads, pages, interpret, nkv, window)
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
 def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
-                       pages, interpret):
+                       pages, interpret, num_kv_heads, window):
     """The kernel call, under a jit of its own: a model's layers share
     their geometry, so the body is traced once a process and lowered
     once a program, not once a layer."""
     from jax.experimental.pallas import tpu as pltpu
     s, _, dm = q.shape
-    nb, bs, _ = k_pool.shape
+    nb, bs, dkv = k_pool.shape
+    hd = dm // num_heads
     mb = tables.shape[1]
     lens = jnp.asarray(lengths).reshape(s).astype(jnp.int32)
     tab = jnp.asarray(tables).reshape(s * mb).astype(jnp.int32)
 
+    # a slot's query and output: one row of all heads' lanes where every
+    # head has its own KV head (the layout the model hands over), a row a
+    # head where query heads share one
+    rows = (1, dm) if num_kv_heads == num_heads else (num_heads, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s,),
         in_specs=[
-            pl.BlockSpec((1, 1, dm), lambda si, lr, tr: (si, 0, 0)),
+            pl.BlockSpec((1,) + rows, lambda si, lr, tr: (si, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, dm), lambda si, lr, tr: (si, 0, 0)),
+        out_specs=pl.BlockSpec((1,) + rows, lambda si, lr, tr: (si, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, pages * bs, dm), k_pool.dtype),
-            pltpu.VMEM((2, pages * bs, dm), v_pool.dtype),
+            pltpu.VMEM((2, pages * bs, dkv), k_pool.dtype),
+            pltpu.VMEM((2, pages * bs, dkv), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),      # (K|V, buffer)
             pltpu.SMEM((1,), jnp.int32),          # first block's buffer
         ])
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_decode_paged_kernel, block_size=bs,
                           max_blocks=mb, num_blocks=nb, pages=pages,
-                          num_heads=num_heads,
-                          scale=(dm // num_heads) ** -0.5),
+                          num_heads=num_heads, num_kv_heads=num_kv_heads,
+                          window=window, scale=hd ** -0.5),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, 1, dm), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s,) + rows, q.dtype),
         # programs run in order: each hands its successor a block
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="decode_attention_paged",
-        interpret=interpret)(lens, tab, q, k_pool, v_pool)
+        interpret=interpret)(lens, tab, q.reshape((s,) + rows),
+                             k_pool, v_pool)
+    return out.reshape(s, 1, dm)
 
 
 def decode_attention(q, k, v, lengths, interpret=None):

@@ -185,6 +185,24 @@ _CONTEXT_TOKENS = _metrics.REGISTRY.counter(
     "Cached tokens attended by decode steps: per step, the sum over "
     "the slots that advanced of their context length, the new token "
     "included")
+_WINDOW_CONTEXT_TOKENS = _metrics.REGISTRY.counter(
+    "paddle_generation_window_context_tokens_total",
+    "Cached tokens attended by the window layers of decode steps: per "
+    "step, the sum over the slots that advanced and over the window "
+    "layers of min(context length, window)")
+_MOE_LAYER_STEPS = _metrics.REGISTRY.counter(
+    "paddle_generation_moe_layer_steps_total",
+    "Expert layers run by decode steps (steps x expert layers)")
+_EXPERTS_TOUCHED = _metrics.REGISTRY.counter(
+    "paddle_generation_experts_touched_total",
+    "Per decode step and expert layer, the experts that took at least "
+    "one token of the step's batch, every slot's row counted")
+_EXPERT_ASSIGNMENTS = _metrics.REGISTRY.counter(
+    "paddle_generation_expert_assignments_total",
+    "Token-expert pairs computed by the expert layers of decode steps")
+_EXPERT_MAX_LOAD = _metrics.REGISTRY.counter(
+    "paddle_generation_expert_max_load_total",
+    "Per decode step and expert layer, the pairs of its busiest expert")
 _PROMPT_TOKENS = _metrics.REGISTRY.counter(
     "paddle_generation_prompt_tokens_total",
     "Prompt tokens really prefilled (the prompt less its prefix-cache "
@@ -302,6 +320,18 @@ class GenerationSpec:
     block-copy program, ``max_blocks`` is the per-sequence table
     width (ceil(cache_len / block_size)), and ``prefix_cache`` arms
     the content-hashed prompt-block index (serving/paged_cache.py).
+
+    ``cache_kinds`` (paged, optional): the kinds of layer cache the
+    model has, a tuple of ``paged_cache.CacheKind``. Layers of one kind
+    keep the same rows and share a block table: a kind without a
+    ``window`` keeps every block, a window kind frees the blocks that
+    fall wholly behind its window. Each kind has its own pool of
+    ``num_blocks`` and names its own table feeds; the first kind's are
+    ``num_blocks`` and the table feeds of ``prefill_feeds`` /
+    ``decode_feeds``. Absent, the spec has the one kind those fields
+    describe. ``stats_fetch`` (optional) names a small int array
+    ``[expert layers, experts]`` of the decode program, the pairs each
+    expert took in the step, fetched with the step's tokens.
     """
 
     __slots__ = ("slots", "cache_len", "max_len", "prompt_buckets",
@@ -311,7 +341,8 @@ class GenerationSpec:
                  "block_size", "num_blocks", "max_blocks",
                  "prefix_cache", "copy_program", "copy_feeds",
                  "vocab_size", "policy", "verify_program",
-                 "verify_feeds", "verify_fetch", "draft_spec")
+                 "verify_feeds", "verify_fetch", "draft_spec",
+                 "cache_kinds", "stats_fetch")
 
     def __init__(self, **kwargs):
         kwargs.setdefault("rebuild", None)
@@ -331,6 +362,8 @@ class GenerationSpec:
         kwargs.setdefault("verify_feeds", None)
         kwargs.setdefault("verify_fetch", None)
         kwargs.setdefault("draft_spec", None)
+        kwargs.setdefault("cache_kinds", None)
+        kwargs.setdefault("stats_fetch", None)
         for name in self.__slots__:
             setattr(self, name, kwargs.pop(name))
         if kwargs:
@@ -411,14 +444,37 @@ class GenerationSession:
         self.paged = bool(getattr(spec, "paged", False))
         self.pool = None
         self.prefix = None
+        # one entry per kind of layer cache (serving/paged_cache.py
+        # LayerCache); a spec that names none has the one kind its own
+        # fields describe, whose pool and tables are ``self.pool`` and
+        # ``self.tables``. What a step does for the other kinds, and for
+        # kinds with a window, it does in loops over these two lists:
+        # both are empty for a one-kind spec
+        self.kinds = []
+        self._more_kinds = self._window_kinds = ()
         if self.paged:
-            from .paged_cache import BlockPool, PrefixIndex
-            self.pool = BlockPool(spec.num_blocks, spec.block_size)
+            from .paged_cache import CacheKind, LayerCache, PrefixIndex
+            kinds = getattr(spec, "cache_kinds", None) or (CacheKind(
+                "full", None, spec.num_blocks, len(spec.cache_vars) // 2,
+                None, None),)
+            policy = getattr(spec, "policy", None)
+            if (len(kinds) > 1 or kinds[0].window) and (
+                    spec.prefix_cache or
+                    (policy is not None and policy.speculate_k > 0)):
+                raise ValueError(
+                    "a spec with a window kind of layer cache (or more "
+                    "than one kind) takes neither prefix_cache nor "
+                    "speculate_k: blocks shared or rolled back behind a "
+                    "window are not implemented")
+            self.kinds = [LayerCache(k, spec.block_size, n) for k in kinds]
+            self._more_kinds = tuple(self.kinds[1:])
+            self._window_kinds = tuple(k for k in self.kinds if k.window)
+            self.pool = self.kinds[0].pool
             if spec.prefix_cache:
                 self.prefix = PrefixIndex(self.pool)
             # host-side block table per slot: physical block ids
             # backing logical rows [0, lengths[slot])
-            self.tables = [[] for _ in range(n)]
+            self.tables = self.kinds[0].tables
             # slots whose next write found no allocatable block this
             # step — excluded from step() results; the scheduler (or
             # generate()) finishes them at their current length
@@ -443,6 +499,11 @@ class GenerationSession:
         if self.constrained:
             self._mask_table = policy.constraint.mask_table(
                 spec.vocab_size)
+        # the step's fetches: its tokens, and where the spec names them the
+        # expert layers' pair counts beside them
+        self._decode_fetches = [spec.decode_fetch]
+        if getattr(spec, "stats_fetch", None) is not None:
+            self._decode_fetches.append(spec.stats_fetch)
         self.draft = None
         if self.speculative:
             # the draft mirrors the target slot-for-slot: admitted,
@@ -509,7 +570,10 @@ class GenerationSession:
             need = min(need + 1, self.pool.num_blocks)
             if avail < need:
                 avail += self.prefix.evictable_count()
-        return avail >= need
+        # the other kinds hold the whole history until the prefill has
+        # run, then what their window keeps
+        return avail >= need and all(
+            k.pool.free_count() >= need for k in self._more_kinds)
 
     def storable(self, n_tokens):
         """Static bound: could this session's storage EVER hold an
@@ -519,8 +583,20 @@ class GenerationSession:
         pool that can never satisfy it, however much retires free."""
         if not self.paged:
             return True
-        return -(-int(n_tokens) // self.spec.block_size) <= \
-            self.pool.num_blocks
+        blocks = -(-int(n_tokens) // self.spec.block_size)
+        return all(self._most_blocks(k, blocks) <= k.pool.num_blocks
+                   for k in self.kinds)
+
+    def _most_blocks(self, kind, blocks):
+        """The most blocks a sequence of ``blocks`` holds in a kind at one
+        time: all of them, or with a window those of the longest prompt (a
+        prefill writes all its rows before the window is trimmed) or of
+        the window with the block being written."""
+        if not kind.window:
+            return blocks
+        bs = self.spec.block_size
+        return min(blocks, max(-(-self.spec.prompt_buckets[-1] // bs),
+                               kind.window // bs + 2))
 
     def window_fits(self, history):
         """Placement probe for a history whose FULL length fits no
@@ -547,11 +623,13 @@ class GenerationSession:
             return None
         itemsize = np.dtype(self.spec.cache_vars[0][2]).itemsize
         d_model = self.spec.cache_vars[0][1][2]
+        # of the first kind: a block id names a K and a V block in each
+        # of its layers
         return {"blocks_in_use": self.pool.used_count(),
                 "num_blocks": self.pool.num_blocks,
                 "block_size": self.spec.block_size,
                 "bytes_per_block": self.spec.block_size * d_model
-                * itemsize * len(self.spec.cache_vars)}
+                * itemsize * 2 * self.kinds[0].kind.layers}
 
     def prefix_stats(self):
         """Prefix-cache hit counters (zeros when not armed)."""
@@ -565,10 +643,9 @@ class GenerationSession:
         table and index pin (serving/paged_cache.py) — the
         pool-accounting invariant tests assert after retire / close /
         failover so a leaked block fails loudly. No-op on dense."""
-        if self.paged:
-            self.pool.check_invariant(
-                (self.tables[s] for s in range(self.spec.slots)),
-                self.prefix)
+        for kind in self.kinds:
+            kind.check_invariant(
+                self.prefix if kind.pool is self.pool else None)
 
     def _alloc_block(self):
         """One fresh block, reclaiming cold prefix-cache entries under
@@ -582,9 +659,8 @@ class GenerationSession:
                     raise
 
     def _release_table(self, slot):
-        for block in self.tables[slot]:
-            self.pool.decref(block)
-        self.tables[slot] = []
+        for kind in self.kinds:
+            kind.release(slot)
 
     def _copy_block(self, src, dst):
         """Run the block-copy program: block ``src`` -> ``dst`` in
@@ -639,9 +715,12 @@ class GenerationSession:
             if self.prefix is not None:
                 self.prefix.clear()
             self.check_pool_invariant()
-            assert self.pool.used_count() == 0, \
-                "closed session leaked %d blocks" % self.pool.used_count()
-            self.pool.close()
+            for kind in self.kinds:
+                assert kind.pool.used_count() == 0, \
+                    "closed session leaked %d blocks of kind %s" % (
+                        kind.pool.used_count(), kind.kind.name)
+                kind.pool.close()
+            self.kinds, self._more_kinds, self._window_kinds = [], (), ()
             self.pool = None
             self.prefix = None
             self.paged = False
@@ -784,6 +863,7 @@ class GenerationSession:
         table = list(shared)
         for block in shared:
             self.pool.incref(block)
+        more = [[] for _ in self._more_kinds]
         try:
             if matched % bs:
                 # the matched prefix ends MID-block: the suffix writes
@@ -791,6 +871,9 @@ class GenerationSession:
                 self._ensure_writable(table, len(table) - 1)
             while len(table) * bs < n:
                 table.append(self._alloc_block())
+            for kind, tbl in zip(self._more_kinds, more):
+                while len(tbl) * bs < n:
+                    tbl.append(kind.pool.alloc())
             w = suffix.size
             padded = np.full((1, bucket), self.spec.eos_id, np.int64)
             padded[0, :w] = suffix
@@ -807,6 +890,11 @@ class GenerationSession:
                     f_hist: np.asarray([matched], np.int32),
                     f_pix: pix,
                     f_tab: tab}
+            for kind, tbl in zip(self._more_kinds, more):
+                row = np.full(self.spec.max_blocks, kind.pool.num_blocks,
+                              np.int32)
+                row[:len(tbl)] = tbl
+                feed[kind.kind.prefill_table] = row
             # the emitted token's index is the TOTAL length n
             # (= matched + w), prefix sharing included
             self._policy_prefill_feed(feed, n, seed, cstate)
@@ -819,6 +907,9 @@ class GenerationSession:
         except BaseException:
             for block in table:
                 self.pool.decref(block)
+            for kind, tbl in zip(self._more_kinds, more):
+                for block in tbl:
+                    kind.pool.decref(block)
             raise
         first = int(np.asarray(outs[0]).reshape(-1)[0])
         if self.prefix is not None:
@@ -827,7 +918,10 @@ class GenerationSession:
             # PR-9 token replay of it, prefills only its suffix
             self.prefix.register(prompt, table)
         self.tables[slot] = table
+        for kind, tbl in zip(self._more_kinds, more):
+            kind.tables[slot] = tbl
         self.lengths[slot] = n
+        self._trim_windows((slot,))
         self.last_token[slot] = first
         self.active[slot] = True
         self._policy_admitted(slot, first, seed, cstate)
@@ -940,6 +1034,9 @@ class GenerationSession:
         from .paged_cache import PoolExhausted
         bs = self.spec.block_size
         self._starved.clear()   # a retire may have freed blocks since
+        if self._window_kinds:
+            with _tracing.span("session:window_trim", round=self.round):
+                self._trim_windows(act)
         for s in act:
             s = int(s)
             pos = int(self.lengths[s])
@@ -951,6 +1048,9 @@ class GenerationSession:
                     # writing into a block a sharer or the prefix
                     # index also holds: diverge onto a private copy
                     self._ensure_writable(tbl, pos // bs)
+                for kind in self._more_kinds:
+                    if pos // bs == len(kind.tables[s]):
+                        kind.tables[s].append(kind.pool.alloc())
             except PoolExhausted:
                 self._starved.add(s)
         nb = self.pool.num_blocks
@@ -966,8 +1066,29 @@ class GenerationSession:
         feed = {f_tok: self.last_token.reshape(-1, 1).copy(),
                 f_pos: self.lengths.astype(np.int32),
                 f_tab: tab}
+        for kind in self._more_kinds:
+            tab = np.full((self.spec.slots, self.spec.max_blocks),
+                          kind.pool.num_blocks, np.int32)
+            for s in act:
+                if int(s) not in self._starved:
+                    kind.feed_row(tab[s], int(s))
+            feed[kind.kind.decode_table] = tab
         self._policy_decode_feed(feed)
         return (act, frozenset(self._starved), feed)
+
+    def _trim_windows(self, slots):
+        """Return to their pools the blocks of ``slots`` that the next
+        row's query cannot see (``LayerCache.first_seen``)."""
+        from .paged_cache import WINDOW_BLOCKS_FREED
+        freed = 0
+        slots = np.asarray(slots, np.int64)
+        for kind in self._window_kinds:
+            first = kind.first_seen(self.lengths[slots])
+            moved = first > kind.first[slots]
+            for s, f in zip(slots[moved], first[moved]):
+                freed += kind.trim(int(s), f)
+        if freed:
+            WINDOW_BLOCKS_FREED.inc(freed)
 
     def _prepare_spec(self, act):
         """Speculative phase 1: extend each active slot's block table
@@ -1026,12 +1147,16 @@ class GenerationSession:
                            active=int(act.size)):
             outs = self.exe.run(
                 self.spec.decode_program, feed=feed,
-                fetch_list=[self.spec.decode_fetch], scope=self.scope,
+                fetch_list=self._decode_fetches, scope=self.scope,
                 return_numpy=False)
         if enqueued is not None:
             enqueued()
         with _tracing.span("session:step_wait", round=self.round):
+            for extra in outs[1:]:
+                extra.copy_to_host_async()  # beside the tokens, not after
             nxt = np.asarray(outs[0]).reshape(-1)
+            if len(outs) > 1:
+                self._count_experts(np.asarray(outs[1]))
         result = {}
         for s in act:
             s = int(s)
@@ -1043,9 +1168,23 @@ class GenerationSession:
             if self.constrained:
                 self.cstate[s] = self.policy.constraint.advance(
                     self.cstate[s], int(nxt[s]))
+        if self._window_kinds and result:
+            lens = self.lengths[list(result)]
+            _WINDOW_CONTEXT_TOKENS.inc(int(sum(
+                k.kind.layers * np.minimum(lens, k.window).sum()
+                for k in self._window_kinds)))
         if self.draft is not None and result:
             self._draft_mirror_plain(result)
         return result
+
+    @staticmethod
+    def _count_experts(counts):
+        """The routing counters from a step's ``[expert layers, experts]``
+        pair counts."""
+        _MOE_LAYER_STEPS.inc(counts.shape[0])
+        _EXPERTS_TOUCHED.inc(int((counts > 0).sum()))
+        _EXPERT_ASSIGNMENTS.inc(int(counts.sum()))
+        _EXPERT_MAX_LOAD.inc(int(counts.max(axis=1).sum()))
 
     def _draft_mirror_plain(self, result):
         """A plain single-token round under a speculative session (the

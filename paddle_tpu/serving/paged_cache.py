@@ -50,7 +50,8 @@ import numpy as np
 from ..observability import metrics as _metrics
 from ..observability import request_trace as _rtrace
 
-__all__ = ["BlockPool", "PrefixIndex", "PoolExhausted"]
+__all__ = ["BlockPool", "PrefixIndex", "PoolExhausted", "CacheKind",
+           "LayerCache"]
 
 PREFIX_HITS = _metrics.REGISTRY.counter(
     "paddle_generation_prefix_hits_total",
@@ -80,7 +81,20 @@ SPEC_ROLLBACKS = _metrics.REGISTRY.counter(
     "Blocks returned by speculative-decoding rollbacks (window rows "
     "past the accepted draft prefix)")
 
+WINDOW_BLOCKS_FREED = _metrics.REGISTRY.counter(
+    "paddle_generation_kv_window_blocks_freed_total",
+    "Blocks a window kind of layer cache returned because every row of "
+    "them lay behind the window")
+
 _POOL_SEQ = itertools.count()
+
+# One kind of layer cache of a generation spec: the layers that share a
+# block table because they keep the same rows. ``window`` None keeps every
+# row; a window keeps the last ``window`` rows of a sequence and frees the
+# blocks behind them. Each kind has its own pool of ``num_blocks`` and its
+# own table feeds; ``layers`` is how many layers are of the kind.
+CacheKind = collections.namedtuple(
+    "CacheKind", "name window num_blocks layers prefill_table decode_table")
 
 
 class PoolExhausted(RuntimeError):
@@ -204,6 +218,56 @@ class BlockPool:
                 % (block, self._ref[block], block in free))
         assert len(free) + sum(1 for r in self._ref if r > 0) == \
             self.num_blocks
+
+
+class LayerCache:
+    """The host books of one :class:`CacheKind` in one session: its pool
+    and a block table per slot, indexed by logical block as ever. A window
+    kind marks the entries it has freed dead (the pool's ``num_blocks``,
+    what the table feeds hold for rows nobody owns) and remembers per slot
+    the first live one, so that a feed row copies the live entries only."""
+
+    def __init__(self, kind, block_size, slots):
+        self.kind = kind
+        self.window = kind.window
+        self.pool = BlockPool(kind.num_blocks, block_size)
+        self.tables = [[] for _ in range(slots)]
+        self.first = np.zeros(slots, np.int64)
+
+    def release(self, slot):
+        """Return every block the slot still holds."""
+        for block in self.tables[slot][self.first[slot]:]:
+            self.pool.decref(block)
+        self.tables[slot] = []
+        self.first[slot] = 0
+
+    def first_seen(self, lengths):
+        """The first block the query at position ``lengths`` (the next
+        row written) can see: a window keeps the rows from
+        ``length + 1 - window`` on, the decode kernel's first live page."""
+        return np.maximum(np.asarray(lengths) + 1 - self.window, 0) \
+            // self.pool.block_size
+
+    def trim(self, slot, first):
+        """Free the blocks of ``slot`` before block ``first``: those that
+        lie wholly behind its window. Returns how many were freed."""
+        table, old = self.tables[slot], int(self.first[slot])
+        first = min(int(first), len(table))
+        for j in range(old, first):
+            self.pool.decref(table[j])
+            table[j] = self.pool.num_blocks
+        self.first[slot] = max(old, first)
+        return max(0, first - old)
+
+    def feed_row(self, row, slot):
+        """Write the slot's live entries into a table-feed row that is
+        dead everywhere else."""
+        table, first = self.tables[slot], int(self.first[slot])
+        row[first:len(table)] = table[first:]
+
+    def check_invariant(self, index=None):
+        self.pool.check_invariant(
+            (t[f:] for t, f in zip(self.tables, self.first)), index)
 
 
 def _chain_digest(parent, chunk):

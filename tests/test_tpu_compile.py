@@ -104,13 +104,17 @@ def test_decode_attention_compiles(chip, dtype):
 
 
 def _paged(chip, num_heads, head_dim, dtype, query=F32, slots=8,
-           max_blocks=64, pool_blocks=8 * 64):
+           max_blocks=64, pool_blocks=8 * 64, num_kv_heads=None,
+           window=None):
     dm = num_heads * head_dim
-    pool = ((pool_blocks, 16, dm), dtype)       # blocks of 16 rows
+    # blocks of 16 rows, as wide as the heads the pool holds
+    pool = ((pool_blocks, 16, (num_kv_heads or num_heads) * head_dim),
+            dtype)
 
     def fn(q, kp, vp, lens, tables):
-        return pa.decode_attention_paged(q, kp, vp, lens, tables,
-                                         num_heads, interpret=False)
+        return pa.decode_attention_paged(
+            q, kp, vp, lens, tables, num_heads, interpret=False,
+            num_kv_heads=num_kv_heads, window=window)
     return _compile(chip, fn, ((slots, 1, dm), query), pool, pool,
                     ((slots,), I32), ((slots, max_blocks), I32))
 
@@ -129,6 +133,34 @@ def test_decode_attention_paged_compiles_at_serving_geometry(chip):
         _paged(chip, 16, 128, BF16, query=BF16, slots=32,
                max_blocks=128, pool_blocks=2048),
         "decode_attention_paged")
+
+
+@pytest.mark.parametrize("window", [None, 2048], ids=["full", "window"])
+def test_decode_attention_paged_compiles_grouped_queries(chip, window):
+    """trinity-serve-offline's two kinds of layer: 64 slots, 32 query
+    heads on 4 KV heads of 128, float32 query and pools 512 wide (products
+    at the highest precision), table rows of 512; the same kernel, with
+    and without the window in its page walk."""
+    assert _has_kernel(
+        _paged(chip, 32, 128, F32, query=F32, slots=64,
+               max_blocks=512, pool_blocks=8704, num_kv_heads=4,
+               window=window),
+        "decode_attention_paged")
+
+
+def test_exact_products_keep_their_three_pieces(chip):
+    """ops/moe_ops.py exact_dot on the chip's compiler: the float32
+    activation reaches the bfloat16 weight as 3 x the rows in one
+    bfloat16 product (the compiler has not folded the pieces away), and
+    the grouped form runs in kernels of the compiler's own."""
+    from paddle_tpu.ops import moe_ops
+    hlo = _compile(chip, moe_ops.exact_dot, ((64, 2048), F32),
+                   ((2048, 4096), BF16))
+    assert "reduce-precision" in hlo
+    assert re.search(r"bf16\[192,2048\]", hlo), hlo[-3000:]
+    hlo = _compile(chip, moe_ops.exact_ragged_dot, ((512, 2048), F32),
+                   ((128, 2048, 1024), BF16), ((128,), I32))
+    assert "reduce-precision" in hlo and "bf16[1536,2048]" in hlo
 
 
 def test_decode_attention_paged_head_dim_64_takes_reference(chip):
