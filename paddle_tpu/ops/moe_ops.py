@@ -25,7 +25,12 @@ pays three times the products.
   positions along the time axis (a prompt window, a training batch) or one
   per batch row (a decode step).
 * ``moe_ffn`` — route, sort by expert, grouped matmul (exact), weighted
-  combine.
+  combine. The grouped matmuls run in ``pallas_moe``'s kernels, which
+  stream each touched expert's weights once, where the call's shapes pass
+  ``pallas_moe.admits`` (weights held in bfloat16, whole lane tiles, no
+  more pairs an expert than were measured), else in ``exact_ragged_dot``:
+  the same products either way, and the path is counted
+  (``kernel_path``, ``moe_grouped_matmul``).
   Every token-expert pair is computed whatever the imbalance: there is no
   capacity and no padding to a per-expert size. The op routes over all
   ``num_experts`` and returns the part of the result that the experts it
@@ -38,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
+from . import kernel_path, pallas_moe
 
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -184,9 +190,15 @@ def _moe_ffn(ctx):
     order = jnp.argsort(key)                            # stable
     counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
     xs = x2[order // k]
-    inner = jax.nn.silu(exact_ragged_dot(xs, wg, counts)) * \
-        exact_ragged_dot(xs, wu, counts)
-    ys = exact_ragged_dot(inner, wd, counts)            # [n*k, d] float32
+    if pallas_moe.admits(n * k, wg):
+        interpret = kernel_path.interpret_mode()
+        kernel_path.record("moe_grouped_matmul", interpret)
+        ys = pallas_moe.expert_ffn(xs, wg, wu, wd, counts, interpret)
+    else:
+        kernel_path.record("moe_grouped_matmul")
+        inner = jax.nn.silu(exact_ragged_dot(xs, wg, counts)) * \
+            exact_ragged_dot(xs, wu, counts)
+        ys = exact_ragged_dot(inner, wd, counts)        # [n*k, d] float32
     # rows past the last group are nobody's: whatever they hold, they add 0
     ys = jnp.where((jnp.arange(n * k) < jnp.sum(counts))[:, None], ys, 0.0)
     # back to the pairs' own order, then the weighted sum over a token's k

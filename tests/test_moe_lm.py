@@ -325,6 +325,150 @@ def test_moe_ffn_refuses_a_router_of_another_width():
         _run(build, {"x": np.zeros((4, D), np.float32)})
 
 
+# -- the experts' matmuls as a weight stream (ops/pallas_moe.py) -------------
+
+GE, GK, GD, GF = 8, 2, 256, 128         # a geometry that passes the gate
+
+
+def _ffn_line(x, wg, wu, wd, counts):
+    """float64 products of the three pieces, group by group; the inner
+    activation held in float32 as the kernels hold it."""
+    from paddle_tpu.ops.moe_ops import _pieces
+    f64 = lambda a: np.asarray(a.astype(jnp.float32)).astype(  # noqa: E731
+        np.float64)
+    x64 = lambda a: sum(f64(p) for p in _pieces(jnp.asarray(a)))  # noqa: E731
+    out, row = np.zeros((x.shape[0], wd.shape[2])), 0
+    for g, c in enumerate(counts):
+        rows = slice(row, row + c)
+        a = x64(x[rows]) @ f64(wg[g])
+        inner = a / (1 + np.exp(-a)) * (x64(x[rows]) @ f64(wu[g]))
+        out[rows] = x64(inner.astype(np.float32)) @ f64(wd[g])
+        row += c
+    return out
+
+
+@pytest.mark.parametrize("counts,n", [
+    ([3, 0, 5, 17, 0, 1, 9, 2], 37),
+    ([0, 0, 0, 150, 0, 0, 0, 0], 150),
+    ([7, 13, 1, 3, 11, 5, 9, 2], 51),
+    ([10, 0, 25, 5, 0, 0, 0, 0], 96),
+    ([64, 64, 0, 0, 0, 0, 0, 64], 192),
+    ([0] * 8, 16),
+], ids=["experts_that_take_no_row", "one_expert_takes_every_row_of_three_tiles",
+        "counts_off_the_sublane_tile", "rows_of_experts_held_elsewhere_last",
+        "tiles_filled_to_the_row", "no_row_at_all"])
+def test_streamed_expert_matmuls_against_ragged_dot_and_float64(counts, n):
+    """The kernels in the interpreter: the rows that are some expert's
+    equal ``exact_ragged_dot``'s and the float64 line to float32's
+    sum-order noise; the rows past the last group are nobody's."""
+    from paddle_tpu.ops import pallas_moe
+    rs = np.random.RandomState(sum(counts) + n)
+    x = (rs.randn(n, GD) * np.exp(rs.randn(n, GD))).astype(np.float32)
+    wg, wu = (jnp.asarray(rs.randn(GE, GD, GF) * 0.05, jnp.bfloat16)
+              for _ in range(2))
+    wd = jnp.asarray(rs.randn(GE, GF, GD) * 0.05, jnp.bfloat16)
+    c = jnp.asarray(counts, jnp.int32)
+    got = np.asarray(pallas_moe.expert_ffn(jnp.asarray(x), wg, wu, wd, c,
+                                           True))
+    assert got.shape == (n, GD) and got.dtype == np.float32
+    total = sum(counts)
+    inner = jax.nn.silu(moe_ops.exact_ragged_dot(x, wg, c)) * \
+        moe_ops.exact_ragged_dot(x, wu, c)
+    ragged = np.asarray(moe_ops.exact_ragged_dot(inner, wd, c))[:total]
+    line = _ffn_line(x, wg, wu, wd, counts)[:total]
+    largest = np.abs(line).max() if total else 1.0
+    assert np.abs(got[:total] - ragged).max(initial=0.0) < 1e-6 * largest
+    assert np.abs(got[:total] - line).max(initial=0.0) < 1e-6 * largest
+
+
+def test_the_kernels_pieces_are_the_ops_pieces_bit_for_bit():
+    from paddle_tpu.ops import pallas_moe
+    rs = np.random.RandomState(12)
+    x = rs.randn(64, 256) * np.exp(rs.randn(64, 256) * 8)
+    x[0, :4] = [0.0, -0.0, 1.0 + 2.0 ** -8, 1.0 + 3 * 2.0 ** -9]   # ties
+    x = jnp.asarray(x, jnp.float32)
+    for mine, theirs in zip(pallas_moe._pieces(x), moe_ops._pieces(x)):
+        assert mine.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(mine.astype(jnp.float32)),
+            np.asarray(theirs.astype(jnp.float32)))
+
+
+def _moe_stream(x, dtype="bfloat16", d_ff=GF, offset=0, held=None):
+    """``moe_ffn`` over GE experts on x [n, d] with seeded weights held in
+    ``dtype``: -> (out, counts)."""
+    def build():
+        xv = layers.data("x", shape=list(x.shape), dtype="float32",
+                         append_batch_size=False)
+        return layers.moe_ffn(xv, GE, GK, d_ff, "m", route_scale=2.0,
+                              expert_offset=offset, experts_held=held,
+                              dtype=dtype, std=0.3)
+    return _run(build, {"x": x})[0]
+
+
+def _paths():
+    from paddle_tpu.ops import kernel_path
+    return dict(kernel_path.counts().get("moe_grouped_matmul", {}))
+
+
+_GATE_CASES = [
+    ("held_in_bfloat16", {}, "interpret"),
+    ("on_a_chip", None, "compiled"),
+    ("held_in_float32", {"dtype": "float32"}, "xla"),
+    ("f_no_whole_lane_tile", {"d_ff": 64}, "xla"),
+    ("more_pairs_an_expert_than_measured", {}, "xla"),
+]
+
+
+@pytest.mark.parametrize("case,how,path", _GATE_CASES,
+                         ids=[c[0] for c in _GATE_CASES])
+def test_moe_ffn_counts_the_path_its_shapes_chose(case, how, path,
+                                                  monkeypatch):
+    """The gate is a test of the call's shapes; each outcome is counted
+    once a traced call in ``paddle_kernel_lowerings_total``."""
+    from paddle_tpu.ops import kernel_path, pallas_moe
+    x = np.random.RandomState(13).randn(24, GD).astype(np.float32)
+    before = _paths()
+    if case == "more_pairs_an_expert_than_measured":
+        # 24 x 2 pairs on 8 experts are 6 each
+        monkeypatch.setattr(pallas_moe, "MAX_PAIRS_PER_EXPERT", 5)
+    if case == "on_a_chip":
+        # building the program infers the op's shapes, which traces it and
+        # counts; nothing is lowered
+        monkeypatch.setattr(kernel_path, "interpret_mode", lambda: False)
+        with ptpu.unique_name.guard(), ptpu.program_guard(
+                ptpu.Program(), ptpu.Program()):
+            xv = layers.data("x", shape=list(x.shape), dtype="float32",
+                             append_batch_size=False)
+            layers.moe_ffn(xv, GE, GK, GF, "m", dtype="bfloat16")
+    else:
+        _moe_stream(x, **how)
+    after = _paths()
+    grew = {p for p in after if after[p] != before.get(p, 0)}
+    assert grew == {path}, (before, after)
+
+
+@pytest.mark.parametrize("offset,held", [(0, None), (4, 4), (0, 2)],
+                         ids=["all_held", "the_upper_half", "two_of_eight"])
+def test_moe_ffn_through_the_stream_is_moe_ffn_through_ragged_dot(
+        offset, held, monkeypatch):
+    """The same op on the same weights with the gate open and shut: the
+    same ``Counts``, and ``Out`` to float32's sum-order noise; the rows
+    of experts held elsewhere add 0 either way."""
+    from paddle_tpu.ops import pallas_moe
+    x = np.random.RandomState(14).randn(40, GD).astype(np.float32)
+    before = _paths()
+    got, counts = _moe_stream(x, offset=offset, held=held)
+    assert _paths().get("interpret", 0) > before.get("interpret", 0)
+    monkeypatch.setattr(pallas_moe, "admits", lambda *a: False)
+    want, want_counts = _moe_stream(x, offset=offset, held=held)
+    np.testing.assert_array_equal(counts, want_counts)
+    assert counts.sum() <= 40 * GK and (held is None) == (
+        counts.sum() == 40 * GK)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() < 1e-6 * np.abs(want).max()
+
+
 # -- grouped queries and a window in the paged decode kernel -----------------
 
 S, H, HKV, HD, BS, MB, NB, WINDOW = 5, 8, 2, 16, 4, 8, 40, 10

@@ -163,6 +163,61 @@ def test_exact_products_keep_their_three_pieces(chip):
     assert "reduce-precision" in hlo and "bf16[1536,2048]" in hlo
 
 
+def _moe_ffn(monkeypatch, chip, tokens):
+    """HLO of the ``moe_ffn`` op at Trinity-Mini's widths (top-8 of 128
+    experts of 2048 x 1024 held in bfloat16) for ``tokens`` tokens, and the
+    paths it counted meanwhile. The op asks ``interpret_mode()``: answered
+    as a chip would."""
+    from types import SimpleNamespace
+    from paddle_tpu.core.registry import ExecContext
+    from paddle_tpu.ops import moe_ops
+    monkeypatch.setattr(kernel_path, "interpret_mode", lambda: False)
+    op = SimpleNamespace(attrs={"num_experts": 128, "top_k": 8})
+    slots = ("X", "RouterW", "ExpertBias", "WGate", "WUp", "WDown")
+
+    def fn(*values):
+        return moe_ops._moe_ffn(ExecContext(
+            op, {slot: [v] for slot, v in zip(slots, values)}))
+    before = dict(kernel_path.counts().get("moe_grouped_matmul", {}))
+    hlo = _compile(chip, fn, ((tokens, 2048), F32), ((2048, 128), F32),
+                   ((128,), F32), ((128, 2048, 1024), BF16),
+                   ((128, 2048, 1024), BF16), ((128, 1024, 2048), BF16))
+    after = kernel_path.counts()["moe_grouped_matmul"]
+    return hlo, {p: n - before.get(p, 0) for p, n in after.items()
+                 if n != before.get(p, 0)}
+
+
+@pytest.mark.parametrize("tokens", [64, 4096], ids=["decode_step",
+                                                    "largest_prefill"])
+def test_expert_matmuls_compile_as_a_weight_stream(chip, monkeypatch,
+                                                   tokens):
+    """trinity-serve-offline's decode step (512 pairs, 4 an expert) and its
+    4,096-token prefill (256 an expert, the most the gate admits): two
+    Mosaic calls named ``moe_grouped_matmul``, gate and up in one and down
+    in the other, none of the compiler's own ``ragged-dot`` kernels, and
+    the three pieces taken in the kernel (no ``reduce-precision`` and no
+    tripled rows outside it)."""
+    hlo, paths = _moe_ffn(monkeypatch, chip, tokens)
+    assert paths == {"compiled": 1}
+    calls = re.findall(r"%moe_grouped_matmul(?:\.\d+)? = [^\n]*"
+                       r"tpu_custom_call", hlo)
+    assert len(calls) == 2, hlo[-3000:]
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert "ragged" not in hlo and "reduce-precision" not in hlo
+    assert "bf16[%d,2048]" % (3 * tokens * 8) not in hlo
+
+
+def test_expert_matmuls_past_the_measured_sizes_take_ragged_dot(
+        chip, monkeypatch):
+    """8,192 tokens are 512 pairs an expert, twice the most measured: the
+    gate hands them to ``exact_ragged_dot``, counted ``xla``, three pieces
+    and the compiler's own kernels as before."""
+    hlo, paths = _moe_ffn(monkeypatch, chip, 8192)
+    assert paths == {"xla": 1}
+    assert not _has_kernel(hlo, "moe_grouped_matmul")
+    assert "reduce-precision" in hlo and "bf16[196608,2048]" in hlo
+
+
 def test_decode_attention_paged_head_dim_64_takes_reference(chip):
     """d_model 512 / 8 heads: two heads share a lane tile, a geometry
     the kernel has not run compiled. The gate must hand it to the XLA
