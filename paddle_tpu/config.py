@@ -168,30 +168,32 @@ generation_step_timeout_ms: per-session decode-step timeout for the
   step() can no longer freeze every other session and the deadline
   sweeps. Read only at scheduler construction.
 
-generation_paged_kv / generation_block_size / generation_pool_blocks /
-generation_prefix_cache: paged-KV-cache defaults for
+generation_block_size / generation_pool_blocks /
+generation_prefix_cache: the K/V cache's defaults for
   ``transformer_lm_session`` (models/transformer.py +
-  serving/paged_cache.py). With ``generation_paged_kv`` False (the
-  default) a session owns dense per-slot [slots, cache_len, d_model]
-  K/V buffers — the PR-8/9 layout, byte-identical behavior. True
-  rebuilds per-layer K/V storage as ONE [num_blocks, block_size,
-  d_model] block pool: each sequence owns a host-side block table,
-  cache writes become block-granular in-place updates through the
-  table (same donation contract), and HBM pinned per sequence is
-  proportional to its LIVE length instead of the worst-case bucket —
-  concurrency becomes "pool bytes / live tokens", not "slots x
-  worst-case bucket". ``generation_block_size`` is the rows-per-block
+  serving/paged_cache.py). A session keeps each layer's K/V as ONE
+  [num_blocks, block_size, d_model] block pool: each sequence owns a
+  host-side block table, cache writes are block-granular in-place
+  updates through the table (under the executor's donation), and HBM
+  pinned per sequence is proportional to its LIVE length — concurrency
+  is "pool bytes / live tokens", not "slots x worst-case bucket".
+  ``generation_block_size`` is the rows-per-block
   granularity (small = less fragmentation waste per sequence, large =
   fewer gather indices and better prefix-sharing amortization);
-  ``generation_pool_blocks`` sizes the pool (0 = auto: byte parity
-  with the dense layout, slots x ceil(cache_len/block_size) blocks);
+  ``generation_pool_blocks`` sizes the pool (0 = auto: a whole table
+  for every slot, slots x ceil(cache_len/block_size) blocks);
   ``generation_prefix_cache`` additionally content-hashes prefill
   blocks at block granularity and shares full blocks read-only across
   sequences via refcounts (copy-on-write when a sequence writes into
   a shared block), so a shared system prompt prefills ONCE and a
   PR-9 token replay re-prefills only its unshared suffix. All read
   only at session construction — generation unused costs zero flag
-  checks anywhere, and the dense decode path consults none of them.
+  checks anywhere.
+
+generation_paged_kv: the constant True. The dense per-slot layout it
+  once switched off went in PR 29; the name stays because
+  benchmarks/harness/lm.py::flags reads, sets and restores it, and
+  setting it to anything false raises.
 
 decode_policy / decode_temperature / decode_top_k / decode_top_p /
 decode_speculate_k / decode_draft_model / decode_constraint: the
@@ -408,10 +410,10 @@ quant_pallas: route the quantized DECODE matmul through the fused
   program (construction); stored on the program tag, so the trace
   itself reads no flags.
 
-generation_kv_dtype: dtype of the generation K/V cache storage —
-  dense rows and paged block pools both. "bfloat16": cache writes
+generation_kv_dtype: dtype of the generation K/V block pools.
+  "bfloat16": cache writes
   round to bf16 and attention reads upcast to f32 (halves
-  kv_cache_bytes_per_token, doubling fixed-budget paged
+  kv_cache_bytes_per_token, doubling fixed-budget
   concurrency). None (default): caches stay f32, byte-identical.
   Read only inside ``transformer_lm_session`` at spec construction
   (and only when the caller left ``dtype`` at its default);
@@ -471,10 +473,10 @@ _flags = {
     "generation_replay_attempts": 0,
     "generation_rebuild_limit": 0,
     "generation_step_timeout_ms": 0,
-    # paged KV cache + prefix reuse (serving/paged_cache.py; read only
-    # at session construction — defaults keep the dense PR-8/9 cache
-    # layout byte-identical)
-    "generation_paged_kv": False,
+    # the KV cache's block pool + prefix reuse (serving/paged_cache.py;
+    # read only at session construction). generation_paged_kv is a
+    # constant: benchmarks/harness/lm.py::flags still sets it
+    "generation_paged_kv": True,
     "generation_block_size": 16,
     "generation_pool_blocks": 0,
     "generation_prefix_cache": False,
@@ -553,10 +555,18 @@ _flags = {
 _on_change = []
 
 
+_DENSE_KV_REMOVED = (
+    "the dense per-slot KV layout went in PR 29: the paged block pool is "
+    "the only cache a generation session has (generation_paged_kv is the "
+    "constant True, and paged= takes None or True)")
+
+
 def set_flags(**kwargs):
     for k, v in kwargs.items():
         if k not in _flags:
             raise KeyError("unknown flag %r (have %s)" % (k, sorted(_flags)))
+        if k == "generation_paged_kv" and not v:
+            raise ValueError(_DENSE_KV_REMOVED)
         _flags[k] = v
     for cb in list(_on_change):
         cb(_flags)

@@ -56,27 +56,20 @@ def multi_head_attention_cached(x, cache, d_model, num_heads,
     with K/V routed through persistable per-layer cache variables
     (ops/generation_ops.py) instead of being recomputed from history.
 
-    ``cache``: dict with ``k``/``v`` ([slots, cache_len, d_model]
-    persistable Variables) and ``mode``:
+    ``cache``: dict with ``k``/``v`` ([num_blocks, block_size, d_model]
+    persistable block POOLS), ``table`` (the block table the ops route
+    through; ops/generation_ops.py) and ``mode``:
 
-    * ``"prefill"`` — x is one prompt [1, P, D]; the prompt's K/V rows
-      are written into cache slot ``cache["slot"]`` at positions
-      [0, P) and attention runs causally within the prompt window
-      (``key_length`` masks right-padding).
+    * ``"prefill"`` — a suffix-window prefill: x [1, P, D] is the
+      UNSHARED tail of one prompt, ``cache["hist"]`` rows are already
+      cached (shared prefix blocks); the window's K/V rows are written
+      at positions [hist, hist + key_length) through the table
+      (``key_length`` masks right-padding) and the window attends the
+      cached prefix plus itself causally.
     * ``"decode"`` — x is one token per slot [S, 1, D]; K/V rows are
-      appended at per-slot positions ``cache["pos"]`` and the single
-      query attends cache rows [0, pos] per slot (its own row
-      included).
-
-    With ``cache["layout"] == "paged"`` the k/v Variables are
-    [num_blocks, block_size, d_model] block POOLS and the ops route
-    through a block table (``cache["table"]``; ops/generation_ops.py
-    paged variants): prefill becomes a suffix-window prefill — x is
-    the UNSHARED tail of the prompt, ``cache["hist"]`` rows are
-    already cached (shared prefix blocks) and the window attends the
-    cached prefix plus itself causally — and decode gathers each
-    slot's K/V through its table row. Same masking/softmax contracts
-    as the dense layout; token parity is a test invariant.
+      appended at per-slot positions ``cache["pos"]`` through each
+      slot's table row and the single query attends cache rows
+      [0, pos] per slot (its own row included).
 
     Because the q/k/v/o parameter names match the uncached layer
     (same ``unique_name`` sequence), programs built under the same
@@ -98,83 +91,43 @@ def multi_head_attention_cached(x, cache, d_model, num_heads,
                param_attr=attr("qkv_v"), **kwargs)
     ck, cv = cache["k"], cache["v"]
     ctx_out = helper.create_tmp_variable(x.dtype)
-    if cache.get("layout") == "paged":
-        table = cache["table"]
-        if cache["mode"] == "prefill":
-            hist = cache["hist"]
-            # window rows land at positions [hist, hist+key_length)
-            # through the block table; padding rows drop
-            for cvar, proj in ((ck, k), (cv, v)):
-                helper.append_op(type="kv_cache_write_paged",
-                                 inputs={"Cache": [cvar.name],
-                                         "New": [proj.name],
-                                         "Table": [table.name],
-                                         "Hist": [hist.name],
-                                         "Len": [key_length.name]},
-                                 outputs={"Out": [cvar.name]})
-            helper.append_op(type="multihead_attention_prefill_paged",
-                             inputs={"Q": [q.name], "CacheK": [ck.name],
-                                     "CacheV": [cv.name],
+    table = cache["table"]
+    if cache["mode"] == "prefill":
+        hist = cache["hist"]
+        # window rows land at positions [hist, hist+key_length)
+        # through the block table; padding rows drop. Cache writes
+        # alias the cache variable name: the executor marks it written
+        # (state_rw) and donates it, so the update is in place in HBM
+        for cvar, proj in ((ck, k), (cv, v)):
+            helper.append_op(type="kv_cache_write_paged",
+                             inputs={"Cache": [cvar.name],
+                                     "New": [proj.name],
                                      "Table": [table.name],
                                      "Hist": [hist.name],
                                      "Len": [key_length.name]},
-                             outputs={"Out": [ctx_out.name]},
-                             attrs={"num_heads": num_heads})
-        elif cache["mode"] == "decode":
-            pos = cache["pos"]
-            for cvar, proj in ((ck, k), (cv, v)):
-                helper.append_op(type="kv_cache_append_paged",
-                                 inputs={"Cache": [cvar.name],
-                                         "New": [proj.name],
-                                         "Pos": [pos.name],
-                                         "Table": [table.name]},
-                                 outputs={"Out": [cvar.name]})
-            helper.append_op(type="multihead_attention_decode_paged",
-                             inputs={"Q": [q.name], "CacheK": [ck.name],
-                                     "CacheV": [cv.name],
-                                     "Pos": [pos.name],
-                                     "Table": [table.name]},
-                             outputs={"Out": [ctx_out.name]},
-                             attrs={"num_heads": num_heads})
-        else:
-            raise ValueError("cache mode must be 'prefill' or "
-                             "'decode', got %r" % (cache["mode"],))
-        return _nn.fc(ctx_out, d_model, num_flatten_dims=2,
-                      bias_attr=False, param_attr=attr("o"), **kwargs)
-    if cache["mode"] == "prefill":
-        slot = cache["slot"]
-        # cache writes alias the cache variable name: the executor
-        # marks it written (state_rw) and donates it, so the update is
-        # in place in HBM
-        helper.append_op(type="kv_cache_write_slot",
-                         inputs={"Cache": [ck.name], "New": [k.name],
-                                 "Slot": [slot.name]},
-                         outputs={"Out": [ck.name]})
-        helper.append_op(type="kv_cache_write_slot",
-                         inputs={"Cache": [cv.name], "New": [v.name],
-                                 "Slot": [slot.name]},
-                         outputs={"Out": [cv.name]})
-        inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
-        if key_length is not None:
-            inputs["KeyLength"] = [key_length.name]
-        helper.append_op(type="multihead_attention", inputs=inputs,
-                         outputs={"Out": [ctx_out.name]},
-                         attrs={"num_heads": num_heads, "causal": True,
-                                "ring_axis": None})
-    elif cache["mode"] == "decode":
-        pos = cache["pos"]
-        helper.append_op(type="kv_cache_append",
-                         inputs={"Cache": [ck.name], "New": [k.name],
-                                 "Pos": [pos.name]},
-                         outputs={"Out": [ck.name]})
-        helper.append_op(type="kv_cache_append",
-                         inputs={"Cache": [cv.name], "New": [v.name],
-                                 "Pos": [pos.name]},
-                         outputs={"Out": [cv.name]})
-        helper.append_op(type="multihead_attention_decode",
+                             outputs={"Out": [cvar.name]})
+        helper.append_op(type="multihead_attention_prefill_paged",
                          inputs={"Q": [q.name], "CacheK": [ck.name],
                                  "CacheV": [cv.name],
-                                 "Pos": [pos.name]},
+                                 "Table": [table.name],
+                                 "Hist": [hist.name],
+                                 "Len": [key_length.name]},
+                         outputs={"Out": [ctx_out.name]},
+                         attrs={"num_heads": num_heads})
+    elif cache["mode"] == "decode":
+        pos = cache["pos"]
+        for cvar, proj in ((ck, k), (cv, v)):
+            helper.append_op(type="kv_cache_append_paged",
+                             inputs={"Cache": [cvar.name],
+                                     "New": [proj.name],
+                                     "Pos": [pos.name],
+                                     "Table": [table.name]},
+                             outputs={"Out": [cvar.name]})
+        helper.append_op(type="multihead_attention_decode_paged",
+                         inputs={"Q": [q.name], "CacheK": [ck.name],
+                                 "CacheV": [cv.name],
+                                 "Pos": [pos.name],
+                                 "Table": [table.name]},
                          outputs={"Out": [ctx_out.name]},
                          attrs={"num_heads": num_heads})
     else:

@@ -220,7 +220,7 @@ def moe_lm_session(slots, cache_len, prompt_buckets, block_size, num_blocks,
     return lm_session(
         model, max_len=cache_len, slots=slots, cache_len=cache_len,
         prompt_buckets=prompt_buckets, bos_id=bos_id, eos_id=eos_id,
-        cache_ns=cache_ns, dtype=kv_dtype, paged=True,
+        cache_ns=cache_ns, dtype=kv_dtype,
         block_size=block_size, num_blocks=num_blocks, prefix_cache=False,
         decode_policy=None,
         kind_blocks={"window": window_num_blocks}

@@ -22,14 +22,13 @@ def _lm_backbone(tokens, vocab_size, d_model, num_heads, d_ff, num_layers,
     weights through the scope.
 
     ``cache_ctx`` (KV-cached generation, transformer_lm_session): dict
-    with ``mode`` ('prefill'|'decode'), ``caches`` ([(k, v) Variable
-    pairs per layer]), ``max_len`` (position-table length — must equal
-    the table length of the program whose weights are served), and the
-    mode's index feeds (``slot``/``key_length`` for prefill,
-    ``pos``/``length`` for decode). With ``layout='paged'`` the caches
-    are block pools and the dict carries ``table`` (block-table feed)
-    plus, for prefill, ``hist`` (cached-prefix depth) and ``pos_idx``
-    (per-window-row position indices, hist + arange(P)). Every
+    with ``mode`` ('prefill'|'decode'), ``caches`` ([(k, v) block-pool
+    Variable pairs per layer]), ``max_len`` (position-table length —
+    must equal the table length of the program whose weights are
+    served), ``table`` (the block-table feed) and the mode's index
+    feeds: for prefill ``key_length``, ``hist`` (cached-prefix depth)
+    and ``pos_idx`` (per-window-row position indices, hist +
+    arange(P)); for decode ``pos``. Every
     parameter name is identical to the uncached build — cached
     programs serve a scope trained by the plain ones."""
     emb = layers.embedding(tokens, size=[vocab_size, d_model],
@@ -38,7 +37,7 @@ def _lm_backbone(tokens, vocab_size, d_model, num_heads, d_ff, num_layers,
     if cache_ctx is None:
         x = positional_encoding(emb)
     elif cache_ctx.get("pos_idx") is not None:
-        # paged suffix prefill: the window starts at cached depth
+        # suffix prefill: the window starts at cached depth
         # hist, so its position rows are gathered, not sliced from 0
         x = positional_encoding_window(emb, cache_ctx["max_len"],
                                        pos=cache_ctx["pos_idx"],
@@ -52,9 +51,7 @@ def _lm_backbone(tokens, vocab_size, d_model, num_heads, d_ff, num_layers,
         if cache_ctx is not None:
             ck, cv = cache_ctx["caches"][i]
             cache = {"k": ck, "v": cv, "mode": cache_ctx["mode"],
-                     "slot": cache_ctx.get("slot"),
                      "pos": cache_ctx.get("pos"),
-                     "layout": cache_ctx.get("layout"),
                      "table": cache_ctx.get("table"),
                      "hist": cache_ctx.get("hist")}
             key_length = cache_ctx.get("key_length")
@@ -176,18 +173,28 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
     O(L)-per-token production decode path (the O(L^2) reference is
     :func:`transformer_lm_generate`).
 
-    Two program families, all parameter names identical to
+    Three program families, all parameter names identical to
     :func:`transformer_lm` / the reference generate path (build each
-    under ``unique_name.guard()`` to share a trained scope):
+    under ``unique_name.guard()`` to share a trained scope). Each
+    layer's K/V storage is ONE [num_blocks, block_size, d_model] block
+    pool, and the programs route writes and attention through a
+    per-sequence block table feed (ops/generation_ops.py):
 
-    * **prefill** (one per prompt bucket P): tokens [1, P] + prompt
-      length + slot index -> the prompt's K/V rows written into that
-      slot of every layer's [slots, cache_len, d_model] cache, and the
-      greedy next token at the last prompt position.
+    * **prefill** (one per prompt bucket P), a suffix-WINDOW prefill:
+      tokens [1, P] plus a ``hist`` feed — the first ``hist`` positions
+      are already cached (prefix blocks shared from an earlier
+      admission), the window's K/V rows are written through the table
+      and its queries attend the cached prefix plus themselves
+      causally; the next token at the last prompt position comes back.
+      ``hist=0`` is a plain prefill; the shape set stays one program
+      per prompt bucket regardless of hist.
     * **decode** (exactly one per (slots, cache_len) shape): one token
-      per slot + per-slot positions -> K/V appended in place, one
-      single-query attention per layer against the live cache prefix,
-      greedy next token per slot.
+      per slot + per-slot positions + a [slots, max_blocks] table feed
+      -> K/V appended in place, one single-query attention per layer
+      over each slot's live blocks (the ``flash_attention`` flag arms
+      the block-table-gather Pallas kernel; dense XLA shares the gather
+      semantics), next token per slot.
+    * a tiny **block-copy program** (one compile) backs copy-on-write.
 
     Cache variables are persistable (named under ``cache_ns``, unique
     per session so several sessions can share one scope/params) and
@@ -198,33 +205,14 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
     ``generation_slots`` / ``generation_cache_buckets`` /
     ``generation_prompt_buckets`` config flags (read only here — with
     no session built, generation costs nothing anywhere).
-
-    **Paged mode** (``paged=True``, default: the
-    ``generation_paged_kv`` flag): per-layer K/V storage becomes ONE
-    [num_blocks, block_size, d_model] block pool instead of dense
-    per-slot rows, and the programs route writes/attention through a
-    per-sequence block table feed (ops/generation_ops.py paged ops):
-
-    * **prefill** becomes a suffix-WINDOW prefill: tokens [1, P] plus
-      a ``hist`` feed — the first ``hist`` positions are already
-      cached (prefix blocks shared from an earlier admission), the
-      window's K/V rows are written through the table and its queries
-      attend the cached prefix plus themselves causally. ``hist=0``
-      is a plain prefill; the shape set stays one program per prompt
-      bucket regardless of hist.
-    * **decode** carries a [slots, max_blocks] table feed; the
-      attention gathers each slot's live blocks (the
-      ``flash_attention`` flag arms the block-table-gather Pallas
-      kernel; dense XLA shares the gather semantics).
-    * a tiny **block-copy program** (one compile) backs copy-on-write.
-
     ``block_size`` / ``num_blocks`` / ``prefix_cache`` default to the
     ``generation_block_size`` / ``generation_pool_blocks`` /
-    ``generation_prefix_cache`` flags; ``num_blocks=0`` auto-sizes to
-    byte parity with the dense layout (slots x ceil(cache_len /
-    block_size)). Slots and pool bytes are DECOUPLED: a paged session
-    can run more decode lanes than the dense layout could afford,
-    because a lane pins only its live blocks, not a worst-case row.
+    ``generation_prefix_cache`` flags; ``num_blocks=0`` gives every
+    slot a whole table (slots x ceil(cache_len / block_size) blocks).
+    Slots and pool bytes are DECOUPLED: a session can run more decode
+    lanes than whole tables would afford, because a lane pins only its
+    live blocks, not a worst-case row. ``paged`` takes None or True
+    (benchmarks/architectures/gpt2_block.py:123 passes it).
 
     **Decode policy** (``decode_policy``, default ``"flags"``: resolve
     the ``decode_*`` config flags via ``DecodePolicy.from_flags`` —
@@ -232,18 +220,18 @@ def transformer_lm_session(vocab_size, d_model=128, num_heads=4,
     stop being a hardcoded argmax. Sampling adds per-request
     seed/position feeds and ends in the counter-keyed
     ``decode_sample`` op; a constraint adds an additive logit-mask
-    feed; ``speculate_k > 0`` (paged only) additionally builds a
+    feed; ``speculate_k > 0`` additionally builds a
     **verify program** — a suffix-window prefill at window W = k+1
     whose epilogue (``decode_verify``) re-decides every window
     position with the target's own logits and counts the accepted
-    draft prefix — plus a nested dense greedy **draft spec** (same
+    draft prefix — plus a nested greedy **draft spec** (same
     machinery, fresh cache namespace, by default a 1-layer truncation
     of this model so it shares weights through the same scope; pass
     ``decode_draft_model`` overrides and a separate draft scope for
     an independently trained draft). ``decode_policy=None`` forces
     plain greedy regardless of flags. The all-defaults flags resolve
-    to None: spec.policy is None and every program is byte-identical
-    to the PR-8..16 build.
+    to None: spec.policy is None and every program ends in the plain
+    argmax.
 
     Returns a :class:`paddle_tpu.serving.generation.GenerationSpec`
     consumed by ``GenerationSession`` / ``GenerationScheduler``.
@@ -368,43 +356,24 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         kvd = _config.get_flag("generation_kv_dtype")
         if kvd:
             dtype = str(kvd)
-    if paged is None:
-        paged = bool(_config.get_flag("generation_paged_kv"))
-    max_blocks = 0
-    if paged:
-        if block_size is None:
-            block_size = int(_config.get_flag("generation_block_size"))
-        block_size = max(1, int(block_size))
-        max_blocks = -(-cache_len // block_size)   # ceil
-        if num_blocks is None:
-            num_blocks = int(_config.get_flag(
-                "generation_pool_blocks"))
-        if not num_blocks:
-            # byte parity with the dense layout by default — the win
-            # then comes purely from sharing + not pinning dead rows
-            num_blocks = slots * max_blocks
-        num_blocks = int(num_blocks)
-        if prefix_cache is None:
-            prefix_cache = bool(_config.get_flag(
-                "generation_prefix_cache"))
-        rows = [num_blocks] + [int((kind_blocks or {})[name])
-                               for name, _ in kinds[1:]]
-        cache_shapes = [(rows[k], block_size, width)
-                        for width, k in model.cache_layers]
-    else:
-        if len(kinds) > 1 or kinds[0][1]:
-            raise ValueError("a model with a window or several kinds of "
-                             "layer cache needs the paged KV layout")
-        block_size = 0
-        num_blocks = 0
-        prefix_cache = False
-        cache_shapes = [(slots, cache_len, width)
-                        for width, _ in model.cache_layers]
-    if spec_k and not paged:
-        raise ValueError("decode_speculate_k needs the paged KV "
-                         "layout (generation_paged_kv / paged=True): "
-                         "the verify pass is a suffix-window prefill "
-                         "and rollback is block decref")
+    if paged is not None and not paged:
+        # the keyword survives for benchmarks/architectures/gpt2_block.py:123,
+        # which passes True
+        raise ValueError(_config._DENSE_KV_REMOVED)
+    if block_size is None:
+        block_size = int(_config.get_flag("generation_block_size"))
+    block_size = max(1, int(block_size))
+    max_blocks = -(-cache_len // block_size)   # ceil
+    if num_blocks is None:
+        num_blocks = int(_config.get_flag("generation_pool_blocks"))
+    # unsized: a whole table for every slot, so that no sequence starves
+    num_blocks = int(num_blocks) or slots * max_blocks
+    if prefix_cache is None:
+        prefix_cache = bool(_config.get_flag("generation_prefix_cache"))
+    rows = [num_blocks] + [int((kind_blocks or {})[name])
+                           for name, _ in kinds[1:]]
+    cache_shapes = [(rows[k], block_size, width)
+                    for width, k in model.cache_layers]
 
     def make_cache_vars(program):
         block = program.global_block()
@@ -472,29 +441,17 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
                                append_batch_size=False)
             ppos = layers.data("gen.ppos", shape=[1], dtype="int32",
                                append_batch_size=False)
-            if paged:
-                phist = layers.data("gen.phist", shape=[1],
-                                    dtype="int32",
-                                    append_batch_size=False)
-                ppix = layers.data("gen.ppix", shape=[P],
-                                   dtype="int32",
-                                   append_batch_size=False)
-                ptab = layers.data("gen.ptab", shape=[max_blocks],
-                                   dtype="int32",
-                                   append_batch_size=False)
-                cache_ctx = {"mode": "prefill", "layout": "paged",
-                             "caches": None, "table": ptab,
-                             "hist": phist, "pos_idx": ppix,
-                             "key_length": plen, "max_len": max_len}
-                cache_ctx["tables"] = [ptab] + more_tables(
-                    "gen.ptab", [max_blocks])
-            else:
-                slot = layers.data("gen.slot", shape=[1],
-                                   dtype="int32",
-                                   append_batch_size=False)
-                cache_ctx = {"mode": "prefill", "caches": None,
-                             "slot": slot, "key_length": plen,
-                             "max_len": max_len}
+            phist = layers.data("gen.phist", shape=[1], dtype="int32",
+                                append_batch_size=False)
+            ppix = layers.data("gen.ppix", shape=[P], dtype="int32",
+                               append_batch_size=False)
+            ptab = layers.data("gen.ptab", shape=[max_blocks],
+                               dtype="int32", append_batch_size=False)
+            cache_ctx = {"mode": "prefill", "caches": None, "table": ptab,
+                         "tables": [ptab] + more_tables("gen.ptab",
+                                                        [max_blocks]),
+                         "hist": phist, "pos_idx": ppix,
+                         "key_length": plen, "max_len": max_len}
             pseed, pstep, pmask, prefill_extra = _policy_feeds(
                 "gen.p", 1)
             cache_ctx["caches"] = make_cache_vars(prog)
@@ -511,17 +468,12 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
                            append_batch_size=False)
         dpos = layers.data("gen.dpos", shape=[slots], dtype="int32",
                            append_batch_size=False)
-        if paged:
-            dtab = layers.data("gen.dtab", shape=[slots, max_blocks],
-                               dtype="int32", append_batch_size=False)
-            cache_ctx = {"mode": "decode", "layout": "paged",
-                         "caches": None, "table": dtab, "pos": dpos,
-                         "max_len": max_len}
-            cache_ctx["tables"] = [dtab] + more_tables(
-                "gen.dtab", [slots, max_blocks])
-        else:
-            cache_ctx = {"mode": "decode", "caches": None, "pos": dpos,
-                         "max_len": max_len}
+        dtab = layers.data("gen.dtab", shape=[slots, max_blocks],
+                           dtype="int32", append_batch_size=False)
+        cache_ctx = {"mode": "decode", "caches": None, "table": dtab,
+                     "tables": [dtab] + more_tables(
+                         "gen.dtab", [slots, max_blocks]),
+                     "pos": dpos, "max_len": max_len}
         dseed, dstep, dmask, decode_extra = _policy_feeds(
             "gen.d", slots)
         cache_ctx["caches"] = make_cache_vars(decode_program)
@@ -529,28 +481,26 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         nxt = _policy_epilogue(row, seed=dseed, step=dstep, mask=dmask)
     decode_fetch = nxt.name
 
-    copy_program = None
-    if paged:
-        # copy-on-write primitive: block Src -> block Dst in EVERY
-        # layer's K and V pool (one block id addresses the same row
-        # range of all of them). One program, one compile, feeds only.
-        copy_program = Program()
-        with _un.guard(), program_guard(copy_program, Program()):
-            csrc = layers.data("gen.csrc", shape=[1], dtype="int32",
-                               append_batch_size=False)
-            cdst = layers.data("gen.cdst", shape=[1], dtype="int32",
-                               append_batch_size=False)
-            cblock = copy_program.global_block()
-            # the first kind's layers: only its blocks are ever shared
-            for (ck, cv), (_, k) in zip(make_cache_vars(copy_program),
-                                        model.cache_layers):
-                for cvar in (ck, cv) if k == 0 else ():
-                    cblock.append_op(
-                        type="kv_block_copy",
-                        inputs={"Cache": [cvar.name],
-                                "Src": [csrc.name],
-                                "Dst": [cdst.name]},
-                        outputs={"Out": [cvar.name]})
+    # copy-on-write primitive: block Src -> block Dst in EVERY
+    # layer's K and V pool (one block id addresses the same row
+    # range of all of them). One program, one compile, feeds only.
+    copy_program = Program()
+    with _un.guard(), program_guard(copy_program, Program()):
+        csrc = layers.data("gen.csrc", shape=[1], dtype="int32",
+                           append_batch_size=False)
+        cdst = layers.data("gen.cdst", shape=[1], dtype="int32",
+                           append_batch_size=False)
+        cblock = copy_program.global_block()
+        # the first kind's layers: only its blocks are ever shared
+        for (ck, cv), (_, k) in zip(make_cache_vars(copy_program),
+                                    model.cache_layers):
+            for cvar in (ck, cv) if k == 0 else ():
+                cblock.append_op(
+                    type="kv_block_copy",
+                    inputs={"Cache": [cvar.name],
+                            "Src": [csrc.name],
+                            "Dst": [cdst.name]},
+                    outputs={"Out": [cvar.name]})
 
     verify_program = None
     verify_fetch = None
@@ -562,7 +512,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         # re-decides every window position with the TARGET's logits
         # under the counter keys and counts the accepted draft prefix.
         # Scoring row i sits at live length hist + i, so this is
-        # exactly the PR-10 paged window-prefill shape — batch 1, run
+        # exactly the window-prefill shape — batch 1, run
         # per speculating slot (the low-batch latency regime
         # speculation exists for).
         W = spec_k + 1
@@ -580,7 +530,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
                                dtype="int32", append_batch_size=False)
             vseed = layers.data("gen.vseed", shape=[1], dtype="int64",
                                 append_batch_size=False)
-            cache_ctx = {"mode": "prefill", "layout": "paged",
+            cache_ctx = {"mode": "prefill",
                          "caches": make_cache_vars(verify_program),
                          "table": vtab, "hist": vhist, "pos_idx": vpix,
                          "key_length": vlen, "max_len": max_len}
@@ -592,15 +542,19 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         verify_feeds = ("gen.vtok", "gen.vlen", "gen.vhist",
                         "gen.vpix", "gen.vtab", "gen.vseed")
         verify_fetch = (vtoks.name, vaccept.name)
-        # the draft: same session machinery, DENSE layout (its k/v
-        # rows are overwritten in place on rollback — no pool), plain
-        # greedy policy (a deterministic draft collapses modified
-        # rejection sampling to prefix matching; see decoding_ops).
+        # the draft: the same call, a pool with a whole table for every
+        # slot (it runs ahead of the target and must never starve), no
+        # prefix index, plain greedy policy (a deterministic draft
+        # collapses modified rejection sampling to prefix matching; see
+        # decoding_ops). Its rollback is a truncation of the session's
+        # ``lengths``: rejected rows are overwritten in place, inside
+        # blocks its table already holds.
         draft_spec = lm_session(
             model.draft(policy.draft), max_len=max_len, slots=slots,
             cache_len=cache_len, prompt_buckets=prompt_buckets,
             bos_id=bos_id, eos_id=eos_id, cache_ns=None, dtype=dtype,
-            paged=False, decode_policy=None)
+            block_size=block_size, num_blocks=slots * max_blocks,
+            prefix_cache=False, decode_policy=None)
 
     def _rebuild():
         # the session-rebuild factory (serving.generation): identical
@@ -610,14 +564,13 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         return lm_session(
             model, max_len=max_len, slots=slots, cache_len=cache_len,
             prompt_buckets=prompt_buckets, bos_id=bos_id,
-            eos_id=eos_id, cache_ns=None, dtype=dtype, paged=paged,
-            block_size=block_size or None,
-            num_blocks=num_blocks or None,
+            eos_id=eos_id, cache_ns=None, dtype=dtype,
+            block_size=block_size, num_blocks=num_blocks,
             prefix_cache=prefix_cache, decode_policy=policy,
             kind_blocks=kind_blocks)
 
     cache_kinds = None
-    if paged and (len(kinds) > 1 or kinds[0][1]):
+    if len(kinds) > 1 or kinds[0][1]:
         from ..serving.paged_cache import CacheKind
         cache_kinds = tuple(
             CacheKind(name, window, rows[k],
@@ -634,21 +587,16 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
                          for i, cache_shape in enumerate(cache_shapes)
                          for kv in ("k", "v")),
         prefill_programs=prefill_programs,
-        prefill_feeds=((("gen.ptok", "gen.plen", "gen.ppos",
-                         "gen.phist", "gen.ppix", "gen.ptab") if paged
-                        else ("gen.ptok", "gen.plen", "gen.ppos",
-                              "gen.slot")) + prefill_extra),
+        prefill_feeds=("gen.ptok", "gen.plen", "gen.ppos", "gen.phist",
+                       "gen.ppix", "gen.ptab") + prefill_extra,
         prefill_fetch=prefill_fetch,
         decode_program=decode_program,
-        decode_feeds=((("gen.dtok", "gen.dpos", "gen.dtab") if paged
-                       else ("gen.dtok", "gen.dpos")) + decode_extra),
+        decode_feeds=("gen.dtok", "gen.dpos", "gen.dtab") + decode_extra,
         decode_fetch=decode_fetch,
         rebuild=_rebuild,
-        paged=bool(paged), block_size=block_size,
-        num_blocks=num_blocks, max_blocks=max_blocks,
-        prefix_cache=bool(prefix_cache),
-        copy_program=copy_program,
-        copy_feeds=("gen.csrc", "gen.cdst") if paged else None,
+        block_size=block_size, num_blocks=num_blocks,
+        max_blocks=max_blocks, prefix_cache=bool(prefix_cache),
+        copy_program=copy_program, copy_feeds=("gen.csrc", "gen.cdst"),
         vocab_size=vocab_size, policy=policy,
         verify_program=verify_program, verify_feeds=verify_feeds,
         verify_fetch=verify_fetch, draft_spec=draft_spec,

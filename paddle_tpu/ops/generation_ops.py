@@ -1,33 +1,15 @@
-"""Autoregressive-generation ops: on-device KV cache + single-query
-decode attention.
+"""Autoregressive-generation ops: the on-device KV cache, a pool of
+blocks, + single-query decode attention.
 
 The reference generated through RecurrentGradientMachine's per-step
 kernel dispatch; the fluid-era answer (and transformer_lm_generate's
 reference path) re-encodes the full token history every step — O(L^2)
 per sequence. These ops are the state-layout change that makes decode
 O(L): per-layer K/V caches live in the Scope as persistable
-[slots, cache_len, d_model] buckets, each step writes one row per
-sequence in place (``dynamic_update_slice`` under executor donation, so
-the update never copies the cache in HBM) and attends a single query
-row against the live prefix.
-
-* ``kv_cache_write_slot`` — prefill: write a whole prompt's K/V rows
-  into ONE slot of the cache (positions [0, T)).
-* ``kv_cache_append``     — decode: write one new K/V row per slot at
-  that slot's own position (per-row ``dynamic_update_slice``).
-* ``multihead_attention_decode`` — one query token per slot against the
-  cache with a per-slot length mask; the Pallas decode kernel
-  (ops/pallas_attention.py ``decode_attention``) when the
-  ``flash_attention`` flag is on, dense XLA otherwise — both share the
-  same masking contract, so flipping the flag never changes tokens.
-
-All shapes here are static (slots and cache_len are compile-time
-bucket sizes): the executor compile cache sees exactly one decode
-entry per (slot-bucket, cache-bucket) pair.
-
-Paged mode (``generation_paged_kv``): per-layer K/V storage is ONE
-[num_blocks, block_size, d_model] pool instead of dense per-slot rows;
-a sequence's logical position p lives at pool row
+[num_blocks, block_size, d_model] pools, each step writes one row per
+sequence in place (a scatter under executor donation, so the update
+never copies the cache in HBM) and attends a single query row against
+the live prefix. A sequence's logical position p lives at pool row
 ``table[p // block_size] * block_size + p % block_size`` where
 ``table`` is its host-side block table (serving/paged_cache.py).
 
@@ -38,8 +20,9 @@ a sequence's logical position p lives at pool row
 * ``kv_cache_append_paged`` — decode: one row per slot through its
   own table row; dead table entries (>= num_blocks) DROP the write
   (inactive/starved slots can't scribble on blocks they don't own).
-* ``multihead_attention_decode_paged`` / the prefill variant — the
-  same masking contract as the dense ops, with K/V gathered through
+* ``multihead_attention_decode_paged`` / the prefill variant — one
+  query token per slot (or a window's rows) against the cache under a
+  per-slot length mask, with K/V gathered through
   the table: the Pallas kernel (``decode_attention_paged``: one
   program per slot walks that slot's live pages, all heads at once)
   when ``flash_attention`` is on, an XLA gather sharing identical
@@ -50,46 +33,17 @@ a sequence's logical position p lives at pool row
 All writes keep the donation contract: Out aliases the pool variable
 name, the scatter/dynamic_update_slice lands in place in HBM.
 
-Shapes stay static here too (block tables are fixed-width feeds padded
-with dead entries): paged mode adds exactly one decode entry and one
-prefill entry per bucket to the compile cache, plus one block-copy
-program — the shape set stays closed.
+All shapes here are static (slots and cache_len are compile-time
+bucket sizes; block tables are fixed-width feeds padded with dead
+entries): the executor compile cache sees exactly one decode entry per
+(slot-bucket, cache-bucket) pair and one prefill entry per prompt
+bucket, plus one block-copy program — the shape set stays closed.
 """
 
 import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
-
-
-@register_op("kv_cache_write_slot")
-def _kv_cache_write_slot(ctx):
-    """Cache [S, C, D], New [1, T, D] (T <= C), Slot [1] int ->
-    Out = Cache with rows [0, T) of slot written. Out aliases the
-    Cache variable name, so the executor's donated state update keeps
-    the write in place."""
-    cache = ctx.input("Cache")
-    new = ctx.input("New")
-    slot = ctx.input("Slot").reshape(-1)[0].astype(jnp.int32)
-    zero = jnp.int32(0)
-    return {"Out": jax.lax.dynamic_update_slice(
-        cache, new.astype(cache.dtype), (slot, zero, zero))}
-
-
-@register_op("kv_cache_append")
-def _kv_cache_append(ctx):
-    """Cache [S, C, D], New [S, 1, D], Pos [S] int -> Out = Cache with
-    row Pos[s] of every slot s overwritten by New[s]. Positions are
-    per-slot (continuous batching: co-resident sequences sit at
-    different depths); out-of-range positions clamp (callers guard)."""
-    cache = ctx.input("Cache")
-    new = ctx.input("New").astype(cache.dtype)
-    pos = ctx.input("Pos").reshape(-1).astype(jnp.int32)
-
-    def upd(c, n, p):
-        return jax.lax.dynamic_update_slice(c, n, (p, jnp.int32(0)))
-
-    return {"Out": jax.vmap(upd)(cache, new, pos)}
 
 
 @register_op("kv_cache_write_paged")
@@ -165,10 +119,8 @@ def _multihead_attention_decode_paged(ctx):
     (heads the pools hold; absent: num_heads) and window (absent: none).
     Out [S, 1, H*D]: each slot's single query
     attends its table-gathered cache rows [0, Pos[s]], with a window the
-    last ``window`` of them — the paged
-    twin of ``multihead_attention_decode``, same masking/softmax
-    contract (token parity with the dense layout is a test
-    invariant). ``flash_attention`` routes to the Pallas kernel that
+    last ``window`` of them (token parity with the O(L^2) reference
+    path is a test invariant). ``flash_attention`` routes to the Pallas kernel that
     walks each slot's live pages; the XLA fallback gathers the same
     rows densely. A slot whose table row is dead gets zeros from the
     kernel and clamped rows from the gather: nobody reads either."""
@@ -322,38 +274,3 @@ def _multihead_attention_prefill_paged(ctx):
         q, ck, cv, table, hist, nh, ctx.attr("num_kv_heads") or nh,
         ctx.attr("window"),
         _largest_divisor(q.shape[1], ctx.attr("block_rows")))}
-
-
-@register_op("multihead_attention_decode")
-def _multihead_attention_decode(ctx):
-    """Q [S, 1, H*D], CacheK/CacheV [S, C, H*D], Pos [S] int (the row
-    each slot's new token was just written to); attr num_heads.
-    Out [S, 1, H*D]: each slot's single query attends cache rows
-    [0, Pos[s]] — its own token included. Same softmax/masking
-    numerics as the dense multihead_attention row it replaces
-    (token-parity with the O(L^2) reference path is a test
-    invariant)."""
-    q = ctx.input("Q")
-    ck = ctx.input("CacheK")
-    cv = ctx.input("CacheV")
-    length = ctx.input("Pos").reshape(-1).astype(jnp.int32) + 1
-    nh = ctx.attr("num_heads")
-    s, _, dm = q.shape
-    c = ck.shape[1]
-    hd = dm // nh
-    qh = q.reshape(s, nh, hd)
-    kh = ck.reshape(s, c, nh, hd).transpose(0, 2, 1, 3)
-    vh = cv.reshape(s, c, nh, hd).transpose(0, 2, 1, 3)
-
-    from .. import config as _config
-    if _config.get_flag("flash_attention"):
-        from .pallas_attention import decode_attention
-        out = decode_attention(qh, kh, vh, length)
-        return {"Out": out.reshape(s, 1, dm)}
-
-    from .pallas_attention import _decode_reference
-    lens = jnp.broadcast_to(length[:, None], (s, nh)).reshape(s * nh)
-    out = _decode_reference(qh.reshape(s * nh, 1, hd),
-                            kh.reshape(s * nh, c, hd),
-                            vh.reshape(s * nh, c, hd), lens)
-    return {"Out": out.reshape(s, 1, dm)}

@@ -15,9 +15,8 @@ flag is on (interpret mode on CPU keeps it testable everywhere);
 `/opt`-guide tiling notes: blocks keep the last dim = head_dim and
 block_q rows per grid step.
 
-Decode has two single-query kernels beside it. ``decode_attention``
-reads a dense per-slot cache on a (batch-head, k-block) grid.
-``decode_attention_paged`` reads the block pool of the paged cache
+Decode has a single-query kernel beside it.
+``decode_attention_paged`` reads the block pool of the KV cache
 with one program per slot: the pools stay in HBM, the program walks
 the slot's live pages, fetches each whole (all heads) by hand-issued,
 double-buffered copies and attends all heads at once, so a decode step
@@ -277,10 +276,11 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # -- decode mode: one query row against a KV cache -----------------------
 
 def _decode_reference(q, k, v, lengths):
-    """Dense XLA single-query attention over a cache: q [BH, 1, D],
-    k/v [BH, C, D], lengths [BH] (valid cache rows per batch-head).
-    The flag-off fallback AND the numeric contract the kernel must
-    match: a cache row is attendable iff its index < length."""
+    """Dense XLA single-query attention over a contiguous cache:
+    q [BH, 1, D], k/v [BH, C, D], lengths [BH] (valid cache rows per
+    batch-head). What :func:`_decode_paged_reference` is after its
+    gather, and so the numeric contract the kernel must match: a cache
+    row is attendable iff its index < length."""
     s = jnp.einsum("bqd,bkd->bqk", q, k,
                    preferred_element_type=jnp.float32)
     s = s * (q.shape[-1] ** -0.5)
@@ -289,113 +289,6 @@ def _decode_reference(q, k, v, lengths):
     s = jnp.where(mask, s, _NEG)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bqk,bkd->bqd", p, v)
-
-
-def _decode_body(lens_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                 l_ref, *, scale, block_k, nk):
-    """One k-block step of single-query flash decode. The k axis is the
-    sequential grid dim; VMEM scratch (acc, running max, running sum)
-    carries the online softmax across k blocks. Blocks past the cache
-    length are skipped at BOTH levels: the scalar-prefetched length
-    clamps the K/V BlockSpec index maps (a dead block revisits the
-    already-resident index, so no HBM fetch is issued for it) and this
-    body predicates the compute away — decode streams only the live
-    prefix of the cache, which is the whole point of the kernel (the
-    dense path reads all C rows per step regardless of length)."""
-    bi, ki = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[:] = jnp.full(m_ref.shape, _NEG, m_ref.dtype)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    length = lens_ref[bi]
-    live = ki * block_k < length
-
-    @pl.when(live)
-    def _step():
-        # narrow (bf16) pools upcast at the contraction, matching the
-        # reference's promotion; identity trace for f32 pools, so the
-        # flag-off program stays byte-identical
-        k_blk = k_ref[0]
-        if k_blk.dtype != jnp.float32:
-            k_blk = k_blk.astype(jnp.float32)
-        v_blk = v_ref[0]
-        if v_blk.dtype != jnp.float32:
-            v_blk = v_blk.astype(jnp.float32)
-        s = jnp.dot(q_ref[0], k_blk.T,
-                    preferred_element_type=jnp.float32,
-                    precision=jax.lax.Precision.DEFAULT) * scale
-        cols = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        mask = cols < length
-        s = jnp.where(mask, s, _NEG)
-        m_prev = m_ref[:]                          # [1, 128]
-        m_new = jnp.maximum(m_prev,
-                            jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        p = jnp.where(mask, p, 0.0)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=-1,
-                                              keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha[:, :1] + jnp.dot(
-            p.astype(v_blk.dtype), v_blk,
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT)
-        m_ref[:] = m_new
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
-
-
-def _decode_forward(q, k, v, lengths, interpret):
-    bh, c, d = k.shape
-    bk = _block_size(c, 512)
-    if not bk:
-        kernel_path.record("decode_attention")
-        return _decode_reference(q, k, v, lengths)  # ragged: XLA path
-    kernel_path.record("decode_attention", interpret)
-    from jax.experimental.pallas import tpu as pltpu
-    lens = lengths.reshape(bh).astype(jnp.int32)
-
-    def kv_index(b, j, lens_ref):
-        # clamp dead block indices to the last LIVE block: Pallas
-        # issues the HBM->VMEM copy per BlockSpec index, so revisiting
-        # a resident index makes the skip real at the memory level
-        # (pl.when alone only skips the compute) — per-step traffic is
-        # O(length), not O(cache_len)
-        last = jnp.maximum(lens_ref[b] - 1, 0) // bk
-        return (b, jnp.minimum(j, last), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bh, c // bk),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda b, j, lr: (b, 0, 0)),
-            pl.BlockSpec((1, bk, d), kv_index),
-            pl.BlockSpec((1, bk, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, j, lr: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),      # acc
-            pltpu.VMEM((1, 128), jnp.float32),    # running max
-            pltpu.VMEM((1, 128), jnp.float32),    # running sum
-        ])
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, scale=d ** -0.5, block_k=bk,
-                          nk=c // bk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        name="decode_attention", interpret=interpret)(lens, q, k, v)
-
-
-def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                   m_ref, l_ref, **kw):
-    _decode_body(lens_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                 l_ref, **kw)
 
 
 # -- paged decode: block-table gather over a block-pool cache ------------
@@ -424,8 +317,7 @@ def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
     rows behind the window may sit in blocks the table no longer names.
     The flag-off fallback AND the numeric contract the paged kernel must
     match: after the gather this is exactly :func:`_decode_reference` on
-    the logical [S, MB*BS] cache, so the paged and dense layouts are
-    token-identical by construction."""
+    the logical [S, MB*BS] cache."""
     s, _, dm = q.shape
     nb, bs, dkv = k_pool.shape
     nkv = num_kv_heads or num_heads
@@ -629,10 +521,10 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
 def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
                            num_heads, interpret=None, num_kv_heads=None,
                            window=None):
-    """Block-table-gather mode of :func:`decode_attention`: single-query
-    flash decode where K/V live in a PAGED pool and the kernel streams
-    exactly the live blocks of each sequence — never the whole pool,
-    never a gathered dense copy.
+    """Single-query flash decode (inference only, no vjp: generation
+    never differentiates through the cache) where K/V live in a PAGED
+    pool and the kernel streams exactly the live blocks of each
+    sequence — never the whole pool, never a gathered dense copy.
 
     q: [S, 1, H*D] (one query per slot); k_pool/v_pool: [NB, BS, Hkv*D]
     with ``num_kv_heads`` heads (default ``num_heads``; fewer: grouped
@@ -719,32 +611,6 @@ def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
         interpret=interpret)(lens, tab, q.reshape((s,) + rows),
                              k_pool, v_pool)
     return out.reshape(s, 1, dm)
-
-
-def decode_attention(q, k, v, lengths, interpret=None):
-    """Single-query flash attention against an on-device KV cache —
-    the decode-mode variant of :func:`flash_attention` (inference only,
-    no vjp: generation never differentiates through the cache).
-
-    q: [B, H, D] (ONE query per sequence); k, v: [B, H, C, D] cache
-    buckets; lengths: [B] or [B, H] int — row c of the cache is
-    attendable iff c < length. Streams K/V blocks against the single
-    query row with an online softmax, skipping blocks past the length,
-    so HBM traffic per step is O(length), not O(C). Returns [B, H, D].
-    ``interpret=None`` auto-selects interpreter mode off-TPU; lengths
-    of 0 produce garbage (callers gate on active slots)."""
-    if interpret is None:
-        interpret = kernel_path.interpret_mode()
-    b, h, d = q.shape
-    c = k.shape[2]
-    lens = jnp.asarray(lengths)
-    if lens.ndim == 1:
-        lens = jnp.broadcast_to(lens[:, None], (b, h))
-    out = _decode_forward(q.reshape(b * h, 1, d),
-                          k.reshape(b * h, c, d),
-                          v.reshape(b * h, c, d),
-                          lens.reshape(b * h), interpret)
-    return out.reshape(b, h, d)
 
 
 def flash_attention(q, k, v, causal=False, segment_ids=None,

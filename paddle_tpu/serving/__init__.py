@@ -31,8 +31,8 @@ Four cooperating pieces:
   ``submit(prompt) -> Future`` with deadlines/backpressure/shedding,
   mid-flight slot-level admit/retire, per-session breakers, drain,
   and between-step weight swap).
-* :mod:`paged_cache` — the paged-KV memory tier behind
-  ``generation_paged_kv``: :class:`BlockPool` (fixed-size block
+* :mod:`paged_cache` — the memory tier of a generation session's
+  K/V cache: :class:`BlockPool` (fixed-size block
   allocator with refcounts over the per-layer K/V pools) and
   :class:`PrefixIndex` (content-hashed prompt caching: shared prefix
   blocks, copy-on-write divergence, LRU eviction under pressure).

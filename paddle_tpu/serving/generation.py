@@ -9,9 +9,11 @@ between steps. This module adds that stateful tier on top of the same
 machinery:
 
 * :class:`GenerationSession` — owns one decode batch: ``slots``
-  sequences, each with a per-layer [slots, cache_len, d_model] K/V
-  cache resident in a Scope as persistable variables. ``admit()`` runs
-  a prompt-bucket prefill program that fills ONE slot's cache rows and
+  sequences over per-layer [num_blocks, block_size, d_model] K/V block
+  pools resident in a Scope as persistable variables, each sequence
+  with a host-side block table (serving/paged_cache.py). ``admit()``
+  runs a prompt-bucket prefill program that writes ONE sequence's rows
+  through its table and
   returns the first greedy token; ``step()`` runs the single decode
   program — one token per slot, per-slot positions — so sequences at
   different depths decode together. Both programs are compiled exactly
@@ -19,7 +21,7 @@ machinery:
   one decode entry per (slot-bucket, cache-bucket), one prefill entry
   per prompt bucket — asserted via ``Executor.compile_stats()``), and
   the caches ride the executor's donated state update: every step is
-  an in-place ``dynamic_update_slice`` in HBM, never a cache copy.
+  an in-place scatter in HBM, never a cache copy.
 
 * :class:`GenerationScheduler` — continuous batching:
   ``submit(prompt) -> Future`` with the MicroBatcher's admission
@@ -107,7 +109,7 @@ on device under counter-based keys (``decoding_key(seed, position)``
 — the seed is minted per request at the front door, carried in the
 replay journal, and re-fed on every replay, so SAMPLED output is as
 bit-replayable as greedy), optionally speculates with a draft
-session (k drafts verified in ONE paged suffix-window forward,
+session (k drafts verified in ONE suffix-window forward,
 rejected rows rolled back via the COW block machinery), and
 optionally constrains output with host-compiled additive logit
 masks. All of it is construction-gated: no policy, no new feeds, no
@@ -138,6 +140,8 @@ from ..utils import log as _log
 from . import resilience as _sres
 from .batcher import ServingOverloadError, _resolve, _WAIT_ALPHA
 from .decoding.policy import GREEDY_FINGERPRINT, mint_seed
+from .paged_cache import (BLOCK_COWS, SPEC_ROLLBACKS, WINDOW_BLOCKS_FREED,
+                          CacheKind, LayerCache, PoolExhausted, PrefixIndex)
 from .resilience import (ReplicaBreaker, ServingDeadlineError,
                          ServingUnavailableError)
 
@@ -296,14 +300,21 @@ class GenerationSpec:
     ``models.transformer.transformer_lm_session``) and the generic
     session/scheduler: programs plus the feed/fetch naming.
 
-    * ``prefill_programs``: {prompt_bucket P: Program} — tokens
-      [1, P] -> first greedy token [1], writing cache slot rows [0, P).
-      ``prefill_feeds`` names (tokens, prompt_len, last_pos, slot).
+    * ``prefill_programs``: {prompt_bucket P: Program} — a window of
+      tokens [1, P] behind ``hist`` cached rows -> first greedy token
+      [1], writing the window's rows through the block table.
+      ``prefill_feeds`` names (tokens, len, last_pos, hist, pos_idx,
+      table).
     * ``decode_program``: one step for ALL slots — tokens [slots, 1] +
-      positions [slots] -> next token per slot. ``decode_feeds`` names
-      (tokens, positions).
-    * ``cache_vars``: ((name, shape, dtype), ...) persistable cache
-      variables a session materializes as device zeros in its scope.
+      positions [slots] + tables [slots, max_blocks] -> next token per
+      slot. ``decode_feeds`` names (tokens, positions, tables).
+    * ``cache_vars``: ((name, shape, dtype), ...) persistable
+      [num_blocks, block_size, d_model] block POOLS a session
+      materializes as device zeros in its scope.
+    * ``copy_program``/``copy_feeds``: the copy-on-write block-copy
+      program; ``max_blocks`` is the per-sequence table width
+      (ceil(cache_len / block_size)), and ``prefix_cache`` arms the
+      content-hashed prompt-block index (serving/paged_cache.py).
     * ``rebuild`` (optional): zero-arg factory returning an equivalent
       fresh spec under a NEW cache namespace — what session rebuild
       constructs the replacement from. A fresh namespace is
@@ -312,16 +323,7 @@ class GenerationSpec:
       cache names into the scope; under a new namespace those writes
       land on orphaned variables, never on the replacement's state.
 
-    Paged mode (``paged=True``): ``cache_vars`` are
-    [num_blocks, block_size, d_model] block POOLS, the programs carry
-    block-table feeds (``prefill_feeds`` = (tokens, len, last_pos,
-    hist, pos_idx, table); ``decode_feeds`` = (tokens, positions,
-    tables)), ``copy_program``/``copy_feeds`` name the copy-on-write
-    block-copy program, ``max_blocks`` is the per-sequence table
-    width (ceil(cache_len / block_size)), and ``prefix_cache`` arms
-    the content-hashed prompt-block index (serving/paged_cache.py).
-
-    ``cache_kinds`` (paged, optional): the kinds of layer cache the
+    ``cache_kinds`` (optional): the kinds of layer cache the
     model has, a tuple of ``paged_cache.CacheKind``. Layers of one kind
     keep the same rows and share a block table: a kind without a
     ``window`` keeps every block, a window kind frees the blocks that
@@ -337,22 +339,19 @@ class GenerationSpec:
     __slots__ = ("slots", "cache_len", "max_len", "prompt_buckets",
                  "bos_id", "eos_id", "cache_vars", "prefill_programs",
                  "prefill_feeds", "prefill_fetch", "decode_program",
-                 "decode_feeds", "decode_fetch", "rebuild", "paged",
+                 "decode_feeds", "decode_fetch", "rebuild",
                  "block_size", "num_blocks", "max_blocks",
                  "prefix_cache", "copy_program", "copy_feeds",
                  "vocab_size", "policy", "verify_program",
                  "verify_feeds", "verify_fetch", "draft_spec",
                  "cache_kinds", "stats_fetch")
 
+    # a constant, kept because benchmarks/harness/serve.py:71 checks it
+    paged = True
+
     def __init__(self, **kwargs):
         kwargs.setdefault("rebuild", None)
-        kwargs.setdefault("paged", False)
-        kwargs.setdefault("block_size", 0)
-        kwargs.setdefault("num_blocks", 0)
-        kwargs.setdefault("max_blocks", 0)
         kwargs.setdefault("prefix_cache", False)
-        kwargs.setdefault("copy_program", None)
-        kwargs.setdefault("copy_feeds", None)
         # decode-policy surface (serving/decoding): all None/0 when
         # the decode_* flags sit at their defaults, so every PR-8..16
         # spec construction and pickle stays valid unchanged
@@ -440,52 +439,43 @@ class GenerationSession:
         # the deepest position any sequence may WRITE: bounded by the
         # cache bucket and by the learned position table
         self.max_pos = min(spec.cache_len, spec.max_len)
-        # -- paged block-pool state (spec.paged; serving/paged_cache) --
-        self.paged = bool(getattr(spec, "paged", False))
-        self.pool = None
-        self.prefix = None
+        # -- block-pool state (serving/paged_cache) ----------------------
         # one entry per kind of layer cache (serving/paged_cache.py
         # LayerCache); a spec that names none has the one kind its own
         # fields describe, whose pool and tables are ``self.pool`` and
         # ``self.tables``. What a step does for the other kinds, and for
         # kinds with a window, it does in loops over these two lists:
         # both are empty for a one-kind spec
-        self.kinds = []
-        self._more_kinds = self._window_kinds = ()
-        if self.paged:
-            from .paged_cache import CacheKind, LayerCache, PrefixIndex
-            kinds = getattr(spec, "cache_kinds", None) or (CacheKind(
-                "full", None, spec.num_blocks, len(spec.cache_vars) // 2,
-                None, None),)
-            policy = getattr(spec, "policy", None)
-            if (len(kinds) > 1 or kinds[0].window) and (
-                    spec.prefix_cache or
-                    (policy is not None and policy.speculate_k > 0)):
-                raise ValueError(
-                    "a spec with a window kind of layer cache (or more "
-                    "than one kind) takes neither prefix_cache nor "
-                    "speculate_k: blocks shared or rolled back behind a "
-                    "window are not implemented")
-            self.kinds = [LayerCache(k, spec.block_size, n) for k in kinds]
-            self._more_kinds = tuple(self.kinds[1:])
-            self._window_kinds = tuple(k for k in self.kinds if k.window)
-            self.pool = self.kinds[0].pool
-            if spec.prefix_cache:
-                self.prefix = PrefixIndex(self.pool)
-            # host-side block table per slot: physical block ids
-            # backing logical rows [0, lengths[slot])
-            self.tables = self.kinds[0].tables
-            # slots whose next write found no allocatable block this
-            # step — excluded from step() results; the scheduler (or
-            # generate()) finishes them at their current length
-            self._starved = set()
-            # (bucket, hist, window_len) per prefill — the probe/test
-            # surface proving a shared prefix was NOT re-prefilled;
-            # bounded (see _admit_paged) so a long-lived session
-            # doesn't accumulate host memory per admission
-            self.prefill_log = []
-        # -- decode-policy state (spec.policy; serving/decoding) -------
+        kinds = getattr(spec, "cache_kinds", None) or (CacheKind(
+            "full", None, spec.num_blocks, len(spec.cache_vars) // 2,
+            None, None),)
         policy = getattr(spec, "policy", None)
+        if (len(kinds) > 1 or kinds[0].window) and (
+                spec.prefix_cache or
+                (policy is not None and policy.speculate_k > 0)):
+            raise ValueError(
+                "a spec with a window kind of layer cache (or more "
+                "than one kind) takes neither prefix_cache nor "
+                "speculate_k: blocks shared or rolled back behind a "
+                "window are not implemented")
+        self.kinds = [LayerCache(k, spec.block_size, n) for k in kinds]
+        self._more_kinds = tuple(self.kinds[1:])
+        self._window_kinds = tuple(k for k in self.kinds if k.window)
+        self.pool = self.kinds[0].pool
+        self.prefix = PrefixIndex(self.pool) if spec.prefix_cache else None
+        # host-side block table per slot: physical block ids
+        # backing logical rows [0, lengths[slot])
+        self.tables = self.kinds[0].tables
+        # slots whose next write found no allocatable block this
+        # step — excluded from step() results; the scheduler (or
+        # generate()) finishes them at their current length
+        self._starved = set()
+        # (bucket, hist, window_len) per prefill — the probe/test
+        # surface proving a shared prefix was NOT re-prefilled;
+        # bounded (see admit) so a long-lived session
+        # doesn't accumulate host memory per admission
+        self.prefill_log = []
+        # -- decode-policy state (spec.policy; serving/decoding) -------
         self.policy = policy
         self.sampled = policy is not None and policy.sampled
         self.constrained = policy is not None and \
@@ -540,11 +530,10 @@ class GenerationSession:
     def compile_stats(self):
         return self.exe.compile_stats()
 
-    # -- paged-pool surface (no-ops / trivial on the dense layout) -------
+    # -- the block pools' surface ----------------------------------------
     def admit_ok(self, n_tokens):
         """Can an ``n_tokens``-history admission get storage RIGHT NOW?
-        Dense: always (storage is the slot itself — ``free_slots`` is
-        the gate). Paged: enough free-or-evictable blocks to cover the
+        Enough free-or-evictable blocks to cover the
         whole history PLUS one copy-on-write block when the prefix
         cache is armed. The accounting is sharing-independent: if the
         admission matches m cached blocks it needs m fewer fresh ones
@@ -554,8 +543,6 @@ class GenerationSession:
         consults this during placement so pool pressure parks a
         request instead of turning into an admit exception that would
         charge a healthy session's breaker."""
-        if not self.paged:
-            return True
         need = -(-min(int(n_tokens), self.max_pos)
                  // self.spec.block_size)
         avail = self.pool.free_count()
@@ -577,12 +564,9 @@ class GenerationSession:
 
     def storable(self, n_tokens):
         """Static bound: could this session's storage EVER hold an
-        ``n_tokens`` history? Dense storage is the slot row itself
-        (``max_pos`` covers it); a paged pool must have enough blocks
+        ``n_tokens`` history? A pool must have enough blocks
         IN TOTAL — placement must not park a request forever on a
         pool that can never satisfy it, however much retires free."""
-        if not self.paged:
-            return True
         blocks = -(-int(n_tokens) // self.spec.block_size)
         return all(self._most_blocks(k, blocks) <= k.pool.num_blocks
                    for k in self.kinds)
@@ -605,9 +589,9 @@ class GenerationSession:
         journal that outgrew every bucket is still admissible here
         when its prompt prefix is cached, so failover composes with
         prefix reuse instead of dying on bucket promotion. Entirely
-        side-effect-free (``PrefixIndex.peek``); dense sessions and
-        prefix-off pools return False, preserving the old verdict."""
-        if not self.paged or self.prefix is None:
+        side-effect-free (``PrefixIndex.peek``); a session without a
+        prefix index returns False."""
+        if self.prefix is None:
             return False
         history = np.asarray(history, np.int64).reshape(-1)
         n = history.size
@@ -618,9 +602,7 @@ class GenerationSession:
 
     def pool_stats(self):
         """{blocks_in_use, num_blocks, block_size, bytes_per_block}
-        for the paged layout (None on dense) — probe/bench surface."""
-        if not self.paged:
-            return None
+        — probe/bench surface."""
         itemsize = np.dtype(self.spec.cache_vars[0][2]).itemsize
         d_model = self.spec.cache_vars[0][1][2]
         # of the first kind: a block id names a K and a V block in each
@@ -642,7 +624,7 @@ class GenerationSession:
         """Assert the block-pool books balance against every live
         table and index pin (serving/paged_cache.py) — the
         pool-accounting invariant tests assert after retire / close /
-        failover so a leaked block fails loudly. No-op on dense."""
+        failover so a leaked block fails loudly."""
         for kind in self.kinds:
             kind.check_invariant(
                 self.prefix if kind.pool is self.pool else None)
@@ -650,7 +632,6 @@ class GenerationSession:
     def _alloc_block(self):
         """One fresh block, reclaiming cold prefix-cache entries under
         pressure (LRU, pin-only) before giving up."""
-        from .paged_cache import PoolExhausted
         while True:
             try:
                 return self.pool.alloc()
@@ -679,7 +660,6 @@ class GenerationSession:
         into a fresh block and swap that into the table — the writer
         diverges onto its own copy, sharers keep the original
         untouched. Raises PoolExhausted when no block is allocatable."""
-        from .paged_cache import BLOCK_COWS
         old = table[idx]
         if self.pool.refcount(old) <= 1:
             return
@@ -700,7 +680,7 @@ class GenerationSession:
     def close(self):
         """Release this session's cache-variable claim (and drop the
         cache arrays from the scope), so a later session may reuse the
-        names. Paged: every block reference — slot tables AND prefix
+        names. Every block reference — slot tables AND prefix
         pins — is returned to the pool first, and the accounting
         invariant is re-checked so a teardown (including the PR-9
         rebuild path, which closes the old session on hand-over) can
@@ -709,7 +689,7 @@ class GenerationSession:
         if self.draft is not None:
             self.draft.close()
             self.draft = None
-        if self.paged and self.pool is not None:
+        if self.pool is not None:
             for slot in range(self.spec.slots):
                 self._release_table(slot)
             if self.prefix is not None:
@@ -723,7 +703,6 @@ class GenerationSession:
             self.kinds, self._more_kinds, self._window_kinds = [], (), ()
             self.pool = None
             self.prefix = None
-            self.paged = False
         claimed = _CACHE_CLAIMS.get(self.scope)
         if claimed is not None:
             claimed -= self._claimed
@@ -784,59 +763,18 @@ class GenerationSession:
         Raises RuntimeError when no slot is free and ValueError when
         the prompt fits no bucket.
 
-        Paged layout: storage comes from the block pool through a
+        Storage comes from the block pool through a
         fresh block table; with the prefix cache armed, the longest
         content-hash-matched prefix is SHARED (its blocks referenced,
         not recomputed) and only the unshared suffix is prefilled —
         capped at len-1, because logits need the last prompt token's
-        hidden state, which only a forward pass produces."""
+        hidden state, which only a forward pass produces. The prompt's
+        blocks are then registered in the prefix index. All block
+        references taken here are rolled back if anything below fails —
+        the pool can't leak on an admission error."""
         prompt = np.asarray(prompt, np.int64).reshape(-1)
-        n = prompt.size
-        if n < 1:
+        if prompt.size < 1:
             raise ValueError("empty prompt")
-        if self.paged:
-            return self._admit_paged(prompt, seed, cstate)
-        bucket = self.prompt_bucket(n)
-        if bucket is None:
-            raise ValueError(
-                "prompt length %d exceeds the largest prompt bucket %d"
-                % (n, self.spec.prompt_buckets[-1]))
-        free = self.free_slots()
-        if not free:
-            raise RuntimeError("no free cache slot (%d active)"
-                               % self.spec.slots)
-        slot = free[0]
-        padded = np.full((1, bucket), self.spec.eos_id, np.int64)
-        padded[0, :n] = prompt
-        f_tok, f_len, f_pos, f_slot = self.spec.prefill_feeds[:4]
-        feed = {f_tok: padded,
-                f_len: np.asarray([n], np.int32),
-                f_pos: np.asarray([n - 1], np.int32),
-                f_slot: np.asarray([slot], np.int32)}
-        self._policy_prefill_feed(feed, n, seed, cstate)
-        with _tracing.span("session:prefill_call", round=self.round,
-                           bucket=bucket, slot=slot):
-            outs = self.exe.run(
-                self.spec.prefill_programs[bucket], feed=feed,
-                fetch_list=[self.spec.prefill_fetch], scope=self.scope)
-        first = int(np.asarray(outs[0]).reshape(-1)[0])
-        self.lengths[slot] = n
-        self.last_token[slot] = first
-        self.active[slot] = True
-        self._policy_admitted(slot, first, seed, cstate)
-        self._draft_admit(prompt, slot, first)
-        _PREFILLS.labels(bucket=bucket).inc()
-        _PROMPT_TOKENS.inc(n)
-        _PREFILL_PADDED_TOKENS.inc(bucket)
-        return slot, first
-
-    def _admit_paged(self, prompt, seed=0, cstate=None):
-        """Paged admission: match the cached prefix, reference its
-        blocks, allocate fresh ones for the rest, prefill ONLY the
-        unshared suffix window, then register the prompt's blocks in
-        the prefix index. All block references taken here are rolled
-        back if anything below fails — the pool can't leak on an
-        admission error."""
         n = prompt.size
         bs = self.spec.block_size
         if n > self.max_pos:
@@ -944,12 +882,11 @@ class GenerationSession:
         overwrites). Raises RuntimeError when an active slot is out of
         cache capacity — retire it first.
 
-        Paged layout: a slot whose next write needs a block the pool
+        A slot whose next write needs a block the pool
         cannot supply (even after evicting cold prefix entries) is
         EXCLUDED from the result — it neither advances nor writes
         (its table feed row is dead, so the device write drops) and
-        the caller finishes it at its current length. Dense sessions
-        never exclude a slot.
+        the caller finishes it at its current length.
 
         Internally two phases — :meth:`step_prepare` (ALL host-side
         pool/table mutation) then :meth:`step_run` (the device call) —
@@ -962,16 +899,16 @@ class GenerationSession:
 
     def step_prepare(self):
         """Phase 1 of a decode step: the active-slot snapshot, the
-        capacity check, and — on the paged layout — EVERY host-side
+        capacity check, and EVERY host-side
         pool mutation (block growth, copy-on-write, the table feed)
         plus snapshotted feeds. Returns an opaque handle for
         :meth:`step_run`, or None with nothing active.
 
         The split is a thread-safety contract, not a convenience: the
         scheduler's step-timeout path runs the device call on a
-        worker thread it may LEAK past the timeout. The dense layout
-        tolerates that (a leaked step touches only device state and
-        per-slot numpy scalars), but allocator refcounts would not —
+        worker thread it may LEAK past the timeout. A leaked step
+        touches only device state and per-slot numpy scalars, which
+        tolerate that; allocator refcounts would not —
         so they are only ever touched here, on the caller/dispatcher
         thread, and a wedged worker can never race retire()/close()
         on the pool books.
@@ -1001,13 +938,53 @@ class GenerationSession:
                 # near capacity: a window write would overrun the cache —
                 # fall back to plain single-token rounds, which finish
                 # these slots (speculation resumes once they retire)
-            if self.paged:
-                return self._prepare_paged(act)
-            f_tok, f_pos = self.spec.decode_feeds[:2]
+            # a plain round: grow/copy-on-write each active slot's write
+            # block and build the table feed. Inactive and pool-starved
+            # slots get all-dead table rows, so their device writes DROP —
+            # a slot can never scribble on blocks it does not own
+            bs = self.spec.block_size
+            self._starved.clear()   # a retire may have freed blocks since
+            if self._window_kinds:
+                with _tracing.span("session:window_trim", round=self.round):
+                    self._trim_windows(act)
+            for s in act:
+                s = int(s)
+                pos = int(self.lengths[s])
+                tbl = self.tables[s]
+                try:
+                    if pos // bs == len(tbl):
+                        tbl.append(self._alloc_block())
+                    else:
+                        # writing into a block a sharer or the prefix
+                        # index also holds: diverge onto a private copy
+                        self._ensure_writable(tbl, pos // bs)
+                    for kind in self._more_kinds:
+                        if pos // bs == len(kind.tables[s]):
+                            kind.tables[s].append(kind.pool.alloc())
+                except PoolExhausted:
+                    self._starved.add(s)
+            nb = self.pool.num_blocks
+            tab = np.full((self.spec.slots, self.spec.max_blocks), nb,
+                          np.int32)
+            for s in act:
+                s = int(s)
+                if s in self._starved:
+                    continue
+                tbl = self.tables[s]
+                tab[s, :len(tbl)] = tbl
+            f_tok, f_pos, f_tab = self.spec.decode_feeds[:3]
             feed = {f_tok: self.last_token.reshape(-1, 1).copy(),
-                    f_pos: self.lengths.astype(np.int32)}
+                    f_pos: self.lengths.astype(np.int32),
+                    f_tab: tab}
+            for kind in self._more_kinds:
+                tab = np.full((self.spec.slots, self.spec.max_blocks),
+                              kind.pool.num_blocks, np.int32)
+                for s in act:
+                    if int(s) not in self._starved:
+                        kind.feed_row(tab[s], int(s))
+                feed[kind.kind.decode_table] = tab
             self._policy_decode_feed(feed)
-            return (act, frozenset(), feed)
+            return (act, frozenset(self._starved), feed)
 
     def _policy_decode_feed(self, feed):
         """Append the decode-policy feeds to a decode-step feed dict.
@@ -1026,60 +1003,9 @@ class GenerationSession:
                     mask[int(s)] = self._mask_table[c.state_index(state)]
             feed["gen.dmask"] = mask
 
-    def _prepare_paged(self, act):
-        """Paged phase 1: grow/copy-on-write each active slot's write
-        block and build the table feed. Inactive and pool-starved
-        slots get all-dead table rows, so their device writes DROP —
-        a slot can never scribble on blocks it does not own."""
-        from .paged_cache import PoolExhausted
-        bs = self.spec.block_size
-        self._starved.clear()   # a retire may have freed blocks since
-        if self._window_kinds:
-            with _tracing.span("session:window_trim", round=self.round):
-                self._trim_windows(act)
-        for s in act:
-            s = int(s)
-            pos = int(self.lengths[s])
-            tbl = self.tables[s]
-            try:
-                if pos // bs == len(tbl):
-                    tbl.append(self._alloc_block())
-                else:
-                    # writing into a block a sharer or the prefix
-                    # index also holds: diverge onto a private copy
-                    self._ensure_writable(tbl, pos // bs)
-                for kind in self._more_kinds:
-                    if pos // bs == len(kind.tables[s]):
-                        kind.tables[s].append(kind.pool.alloc())
-            except PoolExhausted:
-                self._starved.add(s)
-        nb = self.pool.num_blocks
-        tab = np.full((self.spec.slots, self.spec.max_blocks), nb,
-                      np.int32)
-        for s in act:
-            s = int(s)
-            if s in self._starved:
-                continue
-            tbl = self.tables[s]
-            tab[s, :len(tbl)] = tbl
-        f_tok, f_pos, f_tab = self.spec.decode_feeds[:3]
-        feed = {f_tok: self.last_token.reshape(-1, 1).copy(),
-                f_pos: self.lengths.astype(np.int32),
-                f_tab: tab}
-        for kind in self._more_kinds:
-            tab = np.full((self.spec.slots, self.spec.max_blocks),
-                          kind.pool.num_blocks, np.int32)
-            for s in act:
-                if int(s) not in self._starved:
-                    kind.feed_row(tab[s], int(s))
-            feed[kind.kind.decode_table] = tab
-        self._policy_decode_feed(feed)
-        return (act, frozenset(self._starved), feed)
-
     def _trim_windows(self, slots):
         """Return to their pools the blocks of ``slots`` that the next
         row's query cannot see (``LayerCache.first_seen``)."""
-        from .paged_cache import WINDOW_BLOCKS_FREED
         freed = 0
         slots = np.asarray(slots, np.int64)
         for kind in self._window_kinds:
@@ -1095,9 +1021,8 @@ class GenerationSession:
         to cover the verify-window rows [L, L+W) — block growth and
         copy-on-write only, on the dispatcher thread (step_prepare's
         allocator contract). A slot the pool cannot cover is starved
-        out of the round exactly like plain paged starvation, its
+        out of the round exactly like plain starvation, its
         this-round growth returned."""
-        from .paged_cache import PoolExhausted
         bs = self.spec.block_size
         W = self.policy.speculate_k + 1
         self._starved.clear()
@@ -1209,7 +1134,6 @@ class GenerationSession:
         {slot: [token, ...]} — each list is the accepted draft prefix
         plus the target's correction/bonus token, so it is exactly
         the tokens plain rounds would have emitted one at a time."""
-        from .paged_cache import SPEC_ROLLBACKS
         info = prepared["slots"]
         starved = prepared["starved"]
         k = self.policy.speculate_k
@@ -1271,8 +1195,9 @@ class GenerationSession:
                 self.tables[s], (new_len - 1) // bs + 1)
             if freed:
                 SPEC_ROLLBACKS.inc(freed)
-            # draft rollback is a length truncation: its rows live at
-            # fixed positions, so rejected rows are simply overwritten
+            # draft rollback is a length truncation: rejected rows are
+            # overwritten in place, inside blocks its table keeps (the
+            # draft's pool has a whole table for every slot)
             self.draft.lengths[s] = new_len
             self.draft.last_token[s] = emitted[-1]
             result[s] = emitted
@@ -1284,7 +1209,7 @@ class GenerationSession:
     def retire(self, slot):
         """Free a slot mid-flight. The cache rows are left as-is — the
         next prefill into this slot overwrites them, and the per-slot
-        length mask keeps them unattendable meanwhile. Paged: every
+        length mask keeps them unattendable meanwhile. Every
         block reference the slot's table held is returned to the pool
         (a block shared with the prefix index survives as cached
         prompt state; exclusive blocks free immediately)."""
@@ -1295,9 +1220,8 @@ class GenerationSession:
         self.cstate[slot] = None
         if self.draft is not None:
             self.draft.retire(slot)
-        if self.paged:
-            self._release_table(slot)
-            self._starved.discard(slot)
+        self._release_table(slot)
+        self._starved.discard(slot)
 
     def generate(self, prompt, max_new_tokens=None, eos_id=None,
                  seed=0):
@@ -1317,7 +1241,7 @@ class GenerationSession:
             while tokens[-1] != eos and len(tokens) < limit:
                 nxt = self.step()
                 if slot not in nxt:
-                    break  # paged pool exhausted: finish at length
+                    break  # pool exhausted: finish at length
                 got = nxt[slot]
                 # speculative rounds emit a LIST per slot; tokens past
                 # EOS or the budget are discarded (the round could not
@@ -1773,11 +1697,10 @@ class GenerationScheduler:
         admission used — and its REMAINING budget are what must fit.
         For a fresh item both reduce to the original check.
 
-        Paged sessions with the prefix cache armed get one more
+        Sessions with the prefix cache armed get one more
         chance: when the FULL journal outgrew every bucket, a cached
         prefix may shrink the actual prefill window back under one
-        (``window_fits``, side-effect-free) — dense sessions return
-        the exact old verdict through the same short-circuit."""
+        (``window_fits``, side-effect-free)."""
         n = item.prompt.size + len(item.tokens)
         need = max(1, item.max_new - len(item.tokens)) \
             if item.explicit_budget else 1
@@ -2081,11 +2004,8 @@ class GenerationScheduler:
         _rtrace.PREFILL_MS.observe((now_pc - t_admit0) * 1e3)
         if item.ctx is not None:
             # hist = prefix-cache hit length: tokens served from
-            # shared blocks instead of re-prefilled (0 on the dense
-            # layout and on a prefix miss)
-            hist = sess.prefill_log[-1][1] \
-                if getattr(sess, "paged", False) and sess.prefill_log \
-                else 0
+            # shared blocks instead of re-prefilled (0 on a prefix miss)
+            hist = sess.prefill_log[-1][1] if sess.prefill_log else 0
             _rtrace.event(item.ctx,
                           "replayAdmit" if replay else "prefill",
                           dur_ms=(now_pc - t_admit0) * 1e3,
@@ -2270,8 +2190,8 @@ class GenerationScheduler:
         can't stack blocked threads behind a dead device call.
 
         ``prepared`` is the session's step_prepare() handle, produced
-        by _step_all on the dispatcher thread — which on the paged
-        layout is where ALL block-pool mutation happens: a worker
+        by _step_all on the dispatcher thread — which
+        is where ALL block-pool mutation happens: a worker
         leaked past its timeout only ever executes the device call
         plus per-slot scalar advances, never allocator mutation, so
         it cannot race the dispatcher's retire()/close() on the pool
@@ -2363,7 +2283,7 @@ class GenerationScheduler:
             t_step0 = time.perf_counter()
             try:
                 # step_prepare runs OUTSIDE the activated context on
-                # both paths: its paged pool mutations (grow, COW,
+                # both paths: its pool mutations (grow, COW,
                 # eviction pressure) are batch-level — slot B's COW
                 # must not land in request A's span tree, so those
                 # global events reach only the flight ring
@@ -2419,10 +2339,9 @@ class GenerationScheduler:
                               active=len(mine)):
             for slot, it in mine:
                 if slot not in toks:
-                    # paged pool exhausted for this sequence (no
+                    # pool exhausted for this sequence (no
                     # allocatable block even after eviction): it
-                    # cannot grow HERE. Dense sessions never omit an
-                    # active slot, so this branch costs them nothing.
+                    # cannot grow HERE.
                     sess.retire(slot)
                     del self._active[(si, slot)]
                     self._update_occupancy()
@@ -2436,7 +2355,6 @@ class GenerationScheduler:
                         # placement prefers via failed_on. Only an
                         # exhausted replay budget falls through to
                         # the capacity finish below.
-                        from .paged_cache import PoolExhausted
                         it.failed_on.add(si)
                         _RETIRED.labels(reason="preempted").inc()
                         if it.ctx is not None:
@@ -2567,10 +2485,9 @@ class GenerationScheduler:
                     slot, _ = new.admit([spec.bos_id])
                     new.step()
                     new.retire(slot)
-                    if new.paged and spec.copy_program is not None:
-                        # the COW program too (block 0 onto itself is
-                        # a harmless identity copy)
-                        new._copy_block(0, 0)
+                    # the COW program too (block 0 onto itself is
+                    # a harmless identity copy)
+                    new._copy_block(0, 0)
                 finally:
                     new.prefix = prefix
             except BaseException:

@@ -1,9 +1,9 @@
-"""Paged KV-cache bookkeeping: the block-pool allocator and the
-content-hashed prefix index behind ``generation_paged_kv``.
+"""KV-cache bookkeeping: the block-pool allocator and the
+content-hashed prefix index of a generation session.
 
-The dense PR-8 layout gives every sequence a full worst-case cache row
-([slots, cache_len, d_model] per layer), so a 64-token chat pins the
-same HBM as a 2048-token document and concurrency is capped by the most
+A cache row for every slot ([slots, cache_len, d_model] per layer)
+would pin the same HBM for a 64-token chat as for a 2048-token document
+and cap concurrency by the most
 pessimistic bucket. The paged layout (the PagedAttention insight)
 stores each layer's K/V as ONE [num_blocks, block_size, d_model] pool;
 a sequence owns a host-side *block table* — the list of physical block

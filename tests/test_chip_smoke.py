@@ -53,6 +53,10 @@ def test_jax_cache_dir_is_placed_from_outside(env_dir):
 
 
 def test_phase_train_tiny():
+    # a path this process took before the phase (here: another test's call
+    # that its gate handed to XLA) is not one the phase took
+    from paddle_tpu.ops import kernel_path
+    kernel_path.record("flash_attention")
     line = chip_smoke.phase_train(batch=2, seq_len=32, steps=3, **TINY_LM)
     assert line["ok"] and line["loss"][-1] < line["loss"][0]
     assert set(line["kernels"]["flash_attention"]) == {"interpret"}

@@ -494,12 +494,65 @@ class TestSpeculativeDecoding:
         assert _counter(
             "paddle_generation_kv_spec_rollback_blocks_total") > r0
 
-    def test_speculative_requires_paged(self, lm_scope):
-        with pytest.raises(ValueError, match="paged"):
-            transformer_lm_session(
-                V, max_len=MAXLEN, slots=2, prompt_buckets=(4, 8),
-                decode_policy=DecodePolicy(kind="greedy",
-                                           speculate_k=2), **KW)
+    def test_the_drafts_pool_holds_what_its_live_slots_need(
+            self, lm_scope):
+        """The draft is a session over a block pool like its target, with
+        a whole table for every slot and no prefix index. Under forced
+        full rejections (slot 0) beside full acceptances (slot 1) its books
+        balance after every round: each block in use is in one live
+        slot's table, each table covers its slot's length (a rollback is
+        a truncation of ``lengths``, the blocks stay), and the draft
+        stands where its target does."""
+        sess = _session(lm_scope,
+                        DecodePolicy(kind="greedy", speculate_k=3,
+                                     draft=dict(
+                                         num_layers=KW["num_layers"])),
+                        paged=True)
+        draft, spec = sess.draft, sess.draft.spec
+        assert spec.num_blocks == spec.slots * spec.max_blocks
+        assert draft.prefix is None and draft.draft is None
+        assert all(shape[:2] == (spec.num_blocks, 4)
+                   for _, shape, _ in spec.cache_vars)
+        faults.arm("decode_draft_mismatch", at=0, times=None)
+        try:
+            slots = [sess.admit(p)[0] for p in ([BOS, 5, 7],
+                                                [2, 3, 4, 5, 6])]
+            for _ in range(4):
+                sess.step()
+                draft.check_pool_invariant()
+                held = [b for s in slots for b in draft.tables[s]]
+                assert draft.pool.used_count() == len(held) \
+                    == len(set(held))
+                for s in slots:
+                    assert draft.lengths[s] == sess.lengths[s]
+                    assert len(draft.tables[s]) * 4 >= draft.lengths[s]
+            # slot 0 gained one token a round; slot 1, whose draft is
+            # its target, all four
+            assert sess.lengths[slots[0]] == 3 + 4
+            assert sess.lengths[slots[1]] == 5 + 4 * 4
+        finally:
+            faults.disarm("decode_draft_mismatch")
+            sess.close()
+
+    def test_retiring_every_slot_empties_the_drafts_pool(self, lm_scope):
+        sess = _session(lm_scope,
+                        DecodePolicy(kind="greedy", speculate_k=3),
+                        paged=True)
+        draft = sess.draft
+        try:
+            slots = [sess.admit(p)[0] for p in ([BOS, 5, 7], [2, 3])]
+            for _ in range(3):
+                sess.step()
+            assert draft.pool.used_count() > 0
+            sess.retire(slots[0])
+            draft.check_pool_invariant()
+            assert draft.tables[slots[0]] == [] and draft.tables[slots[1]]
+            sess.retire(slots[1])
+            assert draft.pool.used_count() == 0
+            assert sess.pool.used_count() == 0
+            draft.check_pool_invariant()
+        finally:
+            sess.close()
 
     def test_speculative_rejects_step_timeout(self, lm_scope):
         sess = _session(lm_scope,

@@ -1,13 +1,13 @@
 """Autoregressive generation serving: KV-cache decode parity with the
-O(L^2) re-encode reference, closed compile-shape contract, single-query
-Pallas decode kernel, and the continuous-batching scheduler."""
+O(L^2) re-encode reference, closed compile-shape contract, and the
+continuous-batching scheduler. (The cache's ops and its single-query
+Pallas kernel: tests/test_paged_cache.py.)"""
 
 import time
 
 import numpy as np
 import pytest
 
-import jax.numpy as jnp
 
 import paddle_tpu as ptpu
 from paddle_tpu import layers
@@ -28,7 +28,7 @@ BOS, EOS = 0, 1
 
 @pytest.fixture(autouse=True)
 def _no_flash():
-    """Every test starts from the default (dense) path; flash tests
+    """Every test starts from the default (XLA gather) path; flash tests
     arm the flag themselves."""
     prev = ptpu.config.get_flag("flash_attention")
     ptpu.config.set_flags(flash_attention=False)
@@ -89,115 +89,6 @@ def _session(scope, slots=3, cache_len=16, prompt_buckets=(4, 8)):
                                   prompt_buckets=prompt_buckets,
                                   bos_id=BOS, eos_id=EOS, **KW)
     return GenerationSession(spec, scope=scope)
-
-
-# -- kv-cache ops ----------------------------------------------------------
-
-class TestKVCacheOps:
-    def test_write_slot_and_append(self):
-        S, C, D = 3, 8, 4
-        main, startup = ptpu.Program(), ptpu.Program()
-        with ptpu.program_guard(main, startup):
-            block = main.global_block()
-            cache = block.create_var(name="cache", shape=(S, C, D),
-                                     persistable=True,
-                                     stop_gradient=True)
-            new = layers.data("new", shape=[1, 2, D],
-                              append_batch_size=False)
-            slot = layers.data("slot", shape=[1], dtype="int32",
-                               append_batch_size=False)
-            block.append_op(type="kv_cache_write_slot",
-                            inputs={"Cache": ["cache"],
-                                    "New": [new.name],
-                                    "Slot": [slot.name]},
-                            outputs={"Out": ["cache"]})
-            one = layers.data("one", shape=[S, 1, D],
-                              append_batch_size=False)
-            pos = layers.data("pos", shape=[S], dtype="int32",
-                              append_batch_size=False)
-            block.append_op(type="kv_cache_append",
-                            inputs={"Cache": ["cache"],
-                                    "New": [one.name],
-                                    "Pos": [pos.name]},
-                            outputs={"Out": ["cache"]})
-        scope = ptpu.Scope()
-        scope.set_var("cache", jnp.zeros((S, C, D), jnp.float32))
-        exe = ptpu.Executor()
-        rs = np.random.RandomState(0)
-        newv = rs.randn(1, 2, D).astype("float32")
-        onev = rs.randn(S, 1, D).astype("float32")
-        posv = np.array([5, 0, 3], np.int32)
-        exe.run(main, feed={"new": newv, "slot": np.array([1], "int32"),
-                            "one": onev, "pos": posv},
-                fetch_list=[], scope=scope)
-        got = np.asarray(scope.find_var("cache"))
-        want = np.zeros((S, C, D), "float32")
-        want[1, 0:2] = newv[0]          # write_slot into slot 1
-        for s in range(S):              # then per-slot appends
-            want[s, posv[s]] = onev[s, 0]
-        np.testing.assert_allclose(got, want)
-
-
-# -- single-query pallas kernel --------------------------------------------
-
-class TestDecodeKernel:
-    def test_kernel_matches_dense_reference(self):
-        from paddle_tpu.ops.pallas_attention import (_block_size,
-                                                     _decode_reference,
-                                                     decode_attention)
-        rs = np.random.RandomState(0)
-        B, H, C, D = 3, 2, 64, 16
-        assert _block_size(C, 512)  # the kernel path really engages
-        q = jnp.asarray(rs.randn(B, H, D).astype("float32"))
-        k = jnp.asarray(rs.randn(B, H, C, D).astype("float32"))
-        v = jnp.asarray(rs.randn(B, H, C, D).astype("float32"))
-        lens = jnp.asarray([1, 17, C], jnp.int32)
-        out = decode_attention(q, k, v, lens, interpret=True)
-        ref = _decode_reference(
-            q.reshape(B * H, 1, D), k.reshape(B * H, C, D),
-            v.reshape(B * H, C, D),
-            jnp.repeat(lens, H)).reshape(B, H, D)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5, rtol=1e-5)
-
-    def test_multi_block_online_softmax_carry(self):
-        """cache_len > 512 forces nk > 1: the cross-block carry (alpha
-        rescale of acc/l, running-max handoff) must match the dense
-        reference — the numerically hardest branch must not live
-        untested."""
-        from paddle_tpu.ops.pallas_attention import (_block_size,
-                                                     _decode_reference,
-                                                     decode_attention)
-        C = 1024
-        assert C // _block_size(C, 512) > 1  # really multi-block
-        rs = np.random.RandomState(2)
-        B, H, D = 2, 2, 8
-        q = jnp.asarray(rs.randn(B, H, D).astype("float32"))
-        k = jnp.asarray(rs.randn(B, H, C, D).astype("float32"))
-        v = jnp.asarray(rs.randn(B, H, C, D).astype("float32"))
-        # lengths straddling the block boundary: dead-block clamp,
-        # partial second block, and full-cache accumulation
-        lens = jnp.asarray([513, C], jnp.int32)
-        out = decode_attention(q, k, v, lens, interpret=True)
-        ref = _decode_reference(
-            q.reshape(B * H, 1, D), k.reshape(B * H, C, D),
-            v.reshape(B * H, C, D),
-            jnp.repeat(lens, H)).reshape(B, H, D)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5, rtol=1e-5)
-
-    def test_ragged_cache_falls_back_dense(self):
-        from paddle_tpu.ops.pallas_attention import (_block_size,
-                                                     decode_attention)
-        assert _block_size(100, 512) == 0
-        rs = np.random.RandomState(1)
-        q = jnp.asarray(rs.randn(2, 2, 8).astype("float32"))
-        k = jnp.asarray(rs.randn(2, 2, 100, 8).astype("float32"))
-        v = jnp.asarray(rs.randn(2, 2, 100, 8).astype("float32"))
-        out = decode_attention(q, k, v, jnp.asarray([3, 100]),
-                               interpret=True)
-        assert out.shape == (2, 2, 8)
-        assert np.isfinite(np.asarray(out)).all()
 
 
 # -- greedy parity vs the O(L^2) reference ---------------------------------

@@ -833,11 +833,14 @@ def test_unknown_layer_type_is_refused():
 
 def _digest(spec):
     """Every op, variable and attribute of a spec's programs, its feeds
-    and its cache variables."""
+    and its cache variables; where the spec has them, its verify program
+    and its kinds of layer cache too."""
     out = []
     progs = [("decode", spec.decode_program), ("copy", spec.copy_program)] + \
         [("prefill%d" % b, p)
          for b, p in sorted(spec.prefill_programs.items())]
+    if spec.verify_program is not None:
+        progs.append(("verify", spec.verify_program))
     for tag, prog in progs:
         for blk in prog.blocks:
             for name in sorted(blk.vars):
@@ -852,6 +855,10 @@ def _digest(spec):
     out.append((spec.cache_vars, spec.prefill_feeds, spec.decode_feeds,
                 spec.prefill_fetch, spec.decode_fetch, spec.num_blocks,
                 spec.max_blocks, spec.copy_feeds))
+    more = (spec.verify_feeds, spec.verify_fetch, spec.cache_kinds,
+            spec.stats_fetch)
+    if any(m is not None for m in more):
+        out.append(more)
     return hashlib.sha256(repr(out).encode()).hexdigest()
 
 
@@ -872,6 +879,41 @@ def test_a_one_kind_specs_programs_are_the_parents_byte_for_byte():
         and not sess._window_kinds
     assert sess._decode_fetches == [spec.decode_fetch]
     sess.close()
+
+
+def test_a_two_kind_specs_programs_are_the_parents_byte_for_byte():
+    """This file's model, full and window layers, digested at 119a506,
+    before the dense per-slot layout left ``lm_session``."""
+    spec = moe_lm_session(slots=3, cache_len=32, prompt_buckets=(8, 16),
+                          block_size=4, num_blocks=24, window_num_blocks=20,
+                          cache_ns="kv", **SIZES)
+    assert _digest(spec) == ("9c9464669cfd22f073cd9c8a1d4243ef"
+                             "4658e61c65d5d0af4eca990686b1059d")
+
+
+@pytest.mark.parametrize("policy,digest", [
+    (dict(kind="greedy"), "4437c28b911221139a86c01a0d50008e"
+                          "be87399dd894dca9800185eda2435424"),
+    (dict(kind="sample", temperature=0.8, top_k=5),
+     "9d0804ce2a4fddca976f5e6876b0eb6e2e3c2e6aa54de3595a06b23e6d5daaf1")],
+    ids=["greedy", "sampled"])
+def test_a_speculative_targets_programs_are_the_parents_byte_for_byte(
+        policy, digest):
+    """The GPT-2 block's decode, copy, prefill and verify programs under
+    ``speculate_k=2`` with the prefix index armed, digested at 119a506.
+    The draft's programs are not in it: they are the ones that changed,
+    from dense rows to a pool."""
+    from paddle_tpu.serving.decoding import DecodePolicy
+    spec = transformer_lm_session(
+        29, d_model=16, num_heads=2, d_ff=32, num_layers=2, max_len=24,
+        slots=3, cache_len=24, prompt_buckets=(4, 8), bos_id=0, eos_id=1,
+        paged=True, block_size=4, num_blocks=24, prefix_cache=True,
+        cache_ns="kv", decode_policy=DecodePolicy(speculate_k=2, **policy))
+    assert _digest(spec) == digest
+    draft = spec.draft_spec
+    assert (draft.block_size, draft.num_blocks, draft.prefix_cache) == \
+        (4, 3 * 6, False)
+    assert draft.policy is None and draft.draft_spec is None
 
 
 # -- counters and spans ------------------------------------------------------

@@ -1,5 +1,5 @@
 """Paged KV cache with prefix reuse: block-pool allocator accounting,
-paged op/kernel correctness, paged-vs-dense greedy token parity,
+paged op/kernel correctness, greedy token parity with the re-encode oracle,
 copy-on-write divergence isolation, shared-prefix suffix-only prefill,
 pool-exhaustion capacity retirement, PR-9 failover over the paged
 pool, and the fixed-budget concurrency win."""
@@ -83,13 +83,6 @@ def _paged_session(scope, slots=3, cache_len=16, prompt_buckets=(4, 8),
         prompt_buckets=prompt_buckets, bos_id=BOS, eos_id=EOS,
         paged=True, block_size=block_size, num_blocks=num_blocks,
         prefix_cache=prefix_cache, **KW)
-    return GenerationSession(spec, scope=scope)
-
-
-def _dense_session(scope, slots=3, cache_len=16, prompt_buckets=(4, 8)):
-    spec = transformer_lm_session(
-        V, max_len=MAXLEN, slots=slots, cache_len=cache_len,
-        prompt_buckets=prompt_buckets, bos_id=BOS, eos_id=EOS, **KW)
     return GenerationSession(spec, scope=scope)
 
 
@@ -397,11 +390,47 @@ class TestPagedDecodeKernel:
         from paddle_tpu.ops.pallas_attention import _paged_block_pages
         assert _paged_block_pages(16, 2048, dtype, max_blocks) == pages
 
+    @pytest.mark.parametrize("cache_len,lengths", [
+        (64, [1, 17, 64]),          # one compute block holds the cache
+        # several compute blocks: the running maximum, sum and accumulator
+        # carried across them; lengths on both sides of a block's edge
+        (1024, [513, 1024]),
+        (100, [3, 100, 52])],       # a cache that is no whole compute block
+        ids=["one_block", "carry_across_blocks", "ragged"])
+    def test_contiguous_cache_under_an_identity_table(self, monkeypatch,
+                                                      cache_len, lengths):
+        """A contiguous [S, C, H*D] cache cut into blocks of 4 rows under
+        the table that names them in order: the kernel against
+        ``_decode_reference`` on the cache itself."""
+        from paddle_tpu.ops import pallas_attention as pa
+        H, HD, BS = 2, 8, 4
+        D, S, MB = H * HD, len(lengths), cache_len // BS
+        # 16 pages (64 rows) a compute block
+        monkeypatch.setattr(pa, "_PAGED_BUFFER_BYTES", 4 * 16 * BS * D * 4)
+        assert pa._paged_block_pages(BS, D, "float32", MB) == 16
+        rs = np.random.RandomState(2)
+        k = rs.randn(S, cache_len, D).astype("float32")
+        v = rs.randn(S, cache_len, D).astype("float32")
+        q = rs.randn(S, 1, D).astype("float32")
+        lens = np.asarray(lengths, np.int32)
+        tables = np.arange(S * MB, dtype=np.int32).reshape(S, MB)
+        out = pa.decode_attention_paged(
+            jnp.asarray(q), jnp.asarray(k.reshape(S * MB, BS, D)),
+            jnp.asarray(v.reshape(S * MB, BS, D)), jnp.asarray(lens),
+            jnp.asarray(tables), H, interpret=True)
+
+        def heads(x):       # [S, C, H*D] -> [S*H, C, D]
+            return jnp.asarray(x.reshape(S, -1, H, HD).transpose(
+                0, 2, 1, 3).reshape(S * H, -1, HD))
+        ref = pa._decode_reference(heads(q), heads(k), heads(v),
+                                   jnp.asarray(np.repeat(lens, H)))
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref).reshape(S, 1, D),
+            atol=1e-5, rtol=1e-5)
+
     def test_dense_gather_reference_equals_contiguous_reference(self):
-        """_decode_paged_reference over a scattered pool == the PR-8
-        _decode_reference over the hand-gathered contiguous cache —
-        the shared-semantics contract that makes paged vs dense
-        token-identical."""
+        """_decode_paged_reference over a scattered pool ==
+        _decode_reference over the hand-gathered contiguous cache."""
         from paddle_tpu.ops.pallas_attention import (
             _decode_paged_reference, _decode_reference)
         rs = np.random.RandomState(3)
@@ -430,28 +459,25 @@ class TestPagedDecodeKernel:
                                    atol=1e-6, rtol=1e-6)
 
 
-# -- paged-vs-dense greedy parity ------------------------------------------
+# -- greedy parity with the oracle -----------------------------------------
 
 class TestPagedParity:
     @pytest.mark.parametrize("flash", [False, True])
     def test_token_identical_to_dense_and_oracle(self, flash):
-        """Acceptance: greedy output token-identical to the dense
-        layout in ALL paths (dense XLA and Pallas), over ragged prompt
+        """Acceptance: greedy output token-identical to the re-encode
+        oracle in ALL paths (XLA gather and Pallas), over ragged prompt
         lengths crossing block boundaries (block_size 4; prompts of
         1/3/4/5/7 tokens end before, at, and past block edges)."""
         ptpu.config.set_flags(flash_attention=flash)
         scope, exe, main, logits = _lm_scope()
-        dense = _dense_session(scope)
         paged = _paged_session(scope)      # prefix sharing armed
         prompts = ([BOS], [BOS, 5, 7], [2, 3, 4, 5], [2, 3, 4, 5, 6],
                    [2, 3, 4, 5, 6, 7, 8])
         seqs = []
         for prompt in prompts:
             want = _reencode_greedy(exe, main, logits, scope, prompt)
-            got_d = [int(t) for t in dense.generate(prompt)]
             got_p = [int(t) for t in paged.generate(prompt)]
-            assert got_d == want, ("dense", prompt)
-            assert got_p == want, ("paged", prompt)
+            assert got_p == want, prompt
             seqs.append(tuple(want))
         assert len(set(seqs)) > 1          # prompt-dependent outputs
         paged.check_pool_invariant()
@@ -844,35 +870,28 @@ class TestPagedRebuild:
 
 class TestConcurrencyAtFixedBudget:
     def test_paged_sustains_2x_dense_sequences(self):
-        """Acceptance: at the SAME cache-byte budget, the paged pool
-        holds >= 2x the concurrent sequences of the dense layout on a
+        """Acceptance: at the budget of 3 whole rows of ``cache_len``
+        (what 3 sequences would pin, each given its worst case), the pool
+        holds >= 2x as many concurrent sequences on a
         mixed-length workload, token-identical throughout."""
         scope, exe, main, logits = _lm_scope()
-        # dense: 3 slots x 16 rows = 48 rows of budget, 3 sequences max
-        dense = _dense_session(scope, slots=3, cache_len=16)
-        # paged: SAME 48 rows (12 blocks x 4), but 8 decode lanes
+        # 3 rows x cache_len 16 = 48 rows of budget: 3 sequences a row each
+        admitted_d = 3
+        # the SAME 48 rows (12 blocks x 4), but 8 decode lanes
         paged = _paged_session(scope, slots=8, cache_len=16,
                                block_size=4, num_blocks=12,
                                prefix_cache=False)
         rs = np.random.RandomState(0)
         prompts = [list(rs.randint(2, V, int(n)))
                    for n in (1, 2, 3, 1, 2, 3, 2, 1)]   # mixed, short
-        # dense admits exactly its slot count
-        admitted_d = 0
-        for p in prompts:
-            try:
-                dense.admit(p)
-                admitted_d += 1
-            except RuntimeError:
-                break
-        # paged admits while blocks last
+        # admits while blocks last
         admitted_p, slots_p = 0, []
         for p in prompts:
             if not (paged.free_slots() and paged.admit_ok(len(p))):
                 break
             slots_p.append(paged.admit(p)[0])
             admitted_p += 1
-        assert admitted_d == 3
+        assert paged.pool.num_blocks * 4 == admitted_d * 16
         assert admitted_p >= 2 * admitted_d, (admitted_p, admitted_d)
         # all paged sequences decode together, matching their solos
         toks = {s: [] for s in slots_p}
@@ -890,32 +909,70 @@ class TestConcurrencyAtFixedBudget:
         paged.close()
 
 
-# -- off-by-default guarantee ----------------------------------------------
+# -- one layout --------------------------------------------------------------
 
-class TestPagedDefaultOff:
+class TestOneLayout:
     def test_flags_exist_with_defaults(self):
-        assert ptpu.config.get_flag("generation_paged_kv") is False
+        assert ptpu.config.get_flag("generation_paged_kv") is True
         assert ptpu.config.get_flag("generation_block_size") == 16
         assert ptpu.config.get_flag("generation_pool_blocks") == 0
         assert ptpu.config.get_flag("generation_prefix_cache") is False
 
-    def test_default_spec_is_dense_pr8_layout(self):
+    def test_default_spec_is_a_pool_with_a_table_for_every_slot(self):
+        """No cache arguments: blocks of 16, ``slots x ceil(cache_len /
+        16)`` of them, no prefix index; the session reports the pool."""
         spec = transformer_lm_session(V, max_len=MAXLEN, slots=2,
-                                      cache_len=16,
+                                      cache_len=40,
                                       prompt_buckets=(4,), **KW)
-        assert spec.paged is False
-        assert spec.copy_program is None
-        name, shape, _ = spec.cache_vars[0]
-        assert shape == (2, 16, KW["d_model"])       # dense per-slot
-        assert spec.prefill_feeds == ("gen.ptok", "gen.plen",
-                                      "gen.ppos", "gen.slot")
-        assert spec.decode_feeds == ("gen.dtok", "gen.dpos")
+        assert spec.paged is True
+        assert (spec.block_size, spec.max_blocks, spec.num_blocks) == \
+            (16, 3, 6)
+        assert not spec.prefix_cache and spec.copy_program is not None
+        assert all(shape == (6, 16, KW["d_model"])
+                   for _, shape, _ in spec.cache_vars)
+        assert spec.prefill_feeds == ("gen.ptok", "gen.plen", "gen.ppos",
+                                      "gen.phist", "gen.ppix", "gen.ptab")
+        assert spec.decode_feeds == ("gen.dtok", "gen.dpos", "gen.dtab")
+        sess = GenerationSession(spec, scope=_lm_scope()[0])
+        assert sess.prefix is None
+        assert sess.pool_stats() == {
+            "blocks_in_use": 0, "num_blocks": 6, "block_size": 16,
+            "bytes_per_block": 16 * KW["d_model"] * 4 * 2
+            * KW["num_layers"]}
+        sess.close()
 
-    def test_dense_hot_path_consults_no_paged_flag(self, monkeypatch):
-        """The dense session's admit/step never read a paged flag —
-        paged mode costs nothing until a paged spec is built."""
+    def test_the_dense_layout_is_refused_by_name(self):
+        with pytest.raises(ValueError, match="PR 29"):
+            transformer_lm_session(V, max_len=MAXLEN, slots=2,
+                                   prompt_buckets=(4,), paged=False, **KW)
+        spec = transformer_lm_session(V, max_len=MAXLEN, slots=2,
+                                      prompt_buckets=(4,), paged=True, **KW)
+        assert spec.paged is True
+
+    def test_the_flag_is_a_constant(self):
+        with pytest.raises(ValueError, match="PR 29"):
+            ptpu.config.set_flags(generation_paged_kv=False)
+        assert ptpu.config.get_flag("generation_paged_kv") is True
+        ptpu.config.set_flags(generation_paged_kv=True)     # harness/lm.py
+        assert ptpu.config.get_flag("generation_paged_kv") is True
+        assert len(ptpu.config._flags) == 64
+
+    def test_the_deleted_path_is_not_left_behind(self):
+        from paddle_tpu.core import registry
+        from paddle_tpu.ops import pallas_attention
+        for op in ("kv_cache_write_slot", "kv_cache_append",
+                   "multihead_attention_decode"):
+            with pytest.raises(NotImplementedError, match=op):
+                registry.get_op_def(op)
+        registry.get_op_def("kv_cache_append_paged")
+        assert not hasattr(pallas_attention, "decode_attention")
+        assert hasattr(pallas_attention, "_decode_reference")
+
+    def test_hot_path_consults_no_cache_flag(self, monkeypatch):
+        """A session's admit/step never read a cache flag: they are read
+        once, where the spec is built."""
         scope, _, _, _ = _lm_scope()
-        sess = _dense_session(scope, slots=2, prompt_buckets=(4,))
+        sess = _paged_session(scope, slots=2, prompt_buckets=(4,))
         sess.generate([BOS], max_new_tokens=2)       # warm compiles
         calls = []
         orig = ptpu.config.get_flag
@@ -933,6 +990,7 @@ class TestPagedDefaultOff:
                     or c in ("generation_block_size",
                              "generation_pool_blocks",
                              "generation_prefix_cache")], calls
+        sess.close()
 
     def test_rebuild_factory_preserves_paged_geometry(self):
         spec = transformer_lm_session(

@@ -93,16 +93,6 @@ def test_flash_attention_compiles(chip, fn, extra, name):
     assert _has_kernel(_compile(chip, fn, _QKV, _QKV, _QKV, *extra), name)
 
 
-@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
-def test_decode_attention_compiles(chip, dtype):
-    def fn(q, k, v, lens):
-        return pa.decode_attention(q, k, v, lens, interpret=False)
-    cache = ((8, 16, 1024, 128), dtype)
-    assert _has_kernel(_compile(
-        chip, fn, ((8, 16, 128), F32), cache, cache, ((8,), I32)),
-        "decode_attention")
-
-
 def _paged(chip, num_heads, head_dim, dtype, query=F32, slots=8,
            max_blocks=64, pool_blocks=8 * 64, num_kv_heads=None,
            window=None):

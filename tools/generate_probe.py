@@ -188,50 +188,6 @@ def main():
                       "compiles_added_by_scheduler_run":
                           stats2["compiles"] - stats["compiles"]}))
 
-    print("== max concurrent sessions at a fixed cache-byte budget: "
-          "paged vs dense ==")
-    # same budget: dense SLOTS x max_len rows == a paged pool with the
-    # identical row count; the paged session also gets 4x the decode
-    # lanes, because a lane no longer pins a worst-case cache row (the
-    # tools/paged_cache_probe.py workload, summarized here)
-    bsz = 8
-    dense_spec = transformer_lm_session(
-        VOCAB, max_len=max_len, slots=SLOTS, cache_len=max_len,
-        prompt_buckets=(8,), bos_id=BOS, eos_id=EOS, **KW)
-    dense_s = GenerationSession(dense_spec, scope=scope)
-    paged_spec = transformer_lm_session(
-        VOCAB, max_len=max_len, slots=4 * SLOTS, cache_len=max_len,
-        prompt_buckets=(8,), bos_id=BOS, eos_id=EOS, paged=True,
-        block_size=bsz, num_blocks=SLOTS * max_len // bsz,
-        prefix_cache=False, **KW)
-    paged_s = GenerationSession(paged_spec, scope=scope)
-    mixed = [list(rs.randint(2, VOCAB, int(n)))
-             for n in rs.randint(2, 8, 64)]
-    dense_n = 0
-    for p in mixed:
-        try:
-            dense_s.admit(p)
-            dense_n += 1
-        except RuntimeError:
-            break
-    paged_n = 0
-    for p in mixed:
-        if not (paged_s.free_slots() and paged_s.admit_ok(len(p))):
-            break
-        paged_s.admit(p)
-        paged_n += 1
-    paged_s.step()                     # all lanes decode together
-    print(json.dumps({
-        "cache_budget_rows": SLOTS * max_len,
-        "dense_max_concurrent": dense_n,
-        "paged_max_concurrent": paged_n,
-        "concurrency_gain": round(paged_n / float(dense_n), 2)}))
-    for s in list(paged_s.active_slots()):
-        paged_s.retire(s)
-    paged_s.check_pool_invariant()
-    paged_s.close()
-    dense_s.close()
-
     print("== generation metric families ==")
     for line in metrics.REGISTRY.expose_text().splitlines():
         if "generation" in line and not line.startswith("#"):

@@ -9,13 +9,10 @@ through a prefix-cache-armed paged ``GenerationSession`` and a
    prefill log (bucket, hist, window) proving the common prefix
    prefilled EXACTLY once: every later admission re-prefills only its
    unshared suffix through the small prompt bucket.
-2. **memory** — blocks in use vs the dense layout's equivalent bytes
-   at the same moment (slots x worst-case cache rows), i.e. what the
+2. **memory** — blocks in use vs the bytes of a worst-case cache row
+   for every slot at the same moment, i.e. what the
    block pool actually buys per live token.
-3. **fixed-budget concurrency** — at the SAME cache-byte budget, how
-   many mixed-length sequences the paged pool sustains concurrently vs
-   the dense layout (the acceptance criterion: >= 2x).
-4. **closed shape set** — executor compile counters across the whole
+3. **closed shape set** — executor compile counters across the whole
    run (prompt buckets + one decode + one block-copy program, however
    many admissions, hits, and COWs flow), plus the pool-accounting
    invariant re-checked at the end.
@@ -85,7 +82,7 @@ def main():
           "common prefix ==" % (args.requests, len(system)))
     spec = transformer_lm_session(
         VOCAB, max_len=max_len, slots=slots, cache_len=max_len,
-        prompt_buckets=(8, 16), bos_id=BOS, eos_id=EOS, paged=True,
+        prompt_buckets=(8, 16), bos_id=BOS, eos_id=EOS,
         block_size=BLOCK_SIZE, prefix_cache=True, **KW)
     sess = GenerationSession(spec, scope=scope)
     sched = GenerationScheduler(sess)
@@ -117,14 +114,14 @@ def main():
     print("prefill log (bucket, hist, window): %s"
           % sess.prefill_log[:args.requests])
 
-    print("== memory: blocks in use vs dense-equivalent bytes ==")
+    print("== memory: blocks in use vs a worst-case row a slot ==")
     # prompt blocks are still cached (index-pinned) post-drain
     print(json.dumps({
         "blocks_in_use": pstats["blocks_in_use"],
         "num_blocks": pstats["num_blocks"],
         "paged_cache_bytes": int(pstats["blocks_in_use"]
                                  * pstats["bytes_per_block"]),
-        "dense_equiv_bytes": int(slots * max_len * row_bytes),
+        "row_a_slot_bytes": int(slots * max_len * row_bytes),
         "block_size": BLOCK_SIZE,
     }))
 
@@ -137,51 +134,6 @@ def main():
     assert stats["compiles"] <= 4, stats
     sess.check_pool_invariant()
     sess.close()
-
-    print("== fixed-budget concurrency: paged vs dense ==")
-    # same cache-byte budget: dense 4 slots x 64 rows == paged pool of
-    # 32 x 8-row blocks; paged also gets more decode lanes since a
-    # lane no longer pins a worst-case row
-    dense_slots = 4
-    budget_rows = dense_slots * max_len
-    dense_spec = transformer_lm_session(
-        VOCAB, max_len=max_len, slots=dense_slots, cache_len=max_len,
-        prompt_buckets=(8,), bos_id=BOS, eos_id=EOS, **KW)
-    dense = GenerationSession(dense_spec, scope=scope)
-    paged_spec = transformer_lm_session(
-        VOCAB, max_len=max_len, slots=4 * dense_slots,
-        cache_len=max_len, prompt_buckets=(8,), bos_id=BOS, eos_id=EOS,
-        paged=True, block_size=BLOCK_SIZE,
-        num_blocks=budget_rows // BLOCK_SIZE, prefix_cache=False, **KW)
-    paged = GenerationSession(paged_spec, scope=scope)
-    mixed = [list(rs.randint(2, VOCAB, int(n)))
-             for n in rs.randint(2, 8, 64)]
-    dense_n = 0
-    for p in mixed:
-        try:
-            dense.admit(p)
-            dense_n += 1
-        except RuntimeError:
-            break
-    paged_n = 0
-    for p in mixed:
-        if not (paged.free_slots() and paged.admit_ok(len(p))):
-            break
-        paged.admit(p)
-        paged_n += 1
-    paged.step()        # everyone decodes together once
-    print(json.dumps({
-        "cache_budget_rows": budget_rows,
-        "dense_concurrent_sequences": dense_n,
-        "paged_concurrent_sequences": paged_n,
-        "concurrency_gain": round(paged_n / float(dense_n), 2),
-    }))
-    assert paged_n >= 2 * dense_n, (paged_n, dense_n)
-    for s in list(paged.active_slots()):
-        paged.retire(s)
-    paged.check_pool_invariant()
-    paged.close()
-    dense.close()
 
     print("== paged-cache metric families ==")
     for line in metrics.REGISTRY.expose_text().splitlines():
