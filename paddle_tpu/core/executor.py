@@ -429,9 +429,11 @@ class Executor:
             feed = _ingest.explode_sparse(feed)
             for name, value in feed.items():
                 var = block.var_or_none(name)
-                spec = _ingest_spec(var, getattr(value, "dtype",
-                                                 np.asarray(value).dtype),
-                                    name)
+                # an array (a device array above all: np.asarray would
+                # wait for it and fetch it) says its dtype itself
+                spec = _ingest_spec(var, value.dtype
+                                    if hasattr(value, "dtype")
+                                    else np.asarray(value).dtype, name)
                 if spec is not None:
                     ingest_specs.append(spec)
                     arr = jnp.asarray(value)  # stays in wire dtype

@@ -17,9 +17,10 @@ One subsystem feeds three consumers:
   ``(perf_counter_ns, time_ns)`` anchor. In Perfetto a decode round
   reads, on the ``generation-scheduler`` thread, as a
   ``scheduler:host_turn`` (``scheduler:deliver``, ``scheduler:admit``,
-  ``session:step_prepare``, ``session:step_dispatch`` inside) followed
-  by a ``session:step_wait`` (``GenerationScheduler``'s docstring, "The
-  dispatcher's clock").
+  ``scheduler:first_token``, ``session:step_prepare``,
+  ``session:step_dispatch`` inside) followed by a ``session:step_wait``
+  for the step launched a turn earlier (``GenerationScheduler``'s
+  docstring, "One step ahead" and "The dispatcher's clock").
 * instrumentation hooks in ``core.executor`` (compile-cache hits/misses,
   per-key compile wall time + XLA FLOPs/bytes), ``trainer`` (step-latency
   histogram, examples/sec, checkpoint time, periodic structured log), and

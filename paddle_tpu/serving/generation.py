@@ -85,6 +85,8 @@ worker threads, failures resolve exceptionally as before.
 Metrics (always-on, like the serving front door):
 ``paddle_generation_requests_total``, ``_tokens_total``,
 ``_prefills_total``, ``_decode_steps_total``,
+``_decode_steps_ahead_total`` (of them, the steps launched while the
+one before was uncollected),
 ``_retired_total{reason}``, ``_slot_occupancy``,
 ``_ttft_seconds`` (time to first token), ``_inter_token_seconds``;
 the dispatcher's clock by phase: ``_host_ms_total{phase}``,
@@ -175,11 +177,17 @@ _TTFT_SECONDS = _metrics.REGISTRY.histogram(
 _INTER_TOKEN_SECONDS = _metrics.REGISTRY.histogram(
     "paddle_generation_inter_token_seconds",
     "Per-sequence latency between consecutive tokens")
+_STEPS_AHEAD = _metrics.REGISTRY.counter(
+    "paddle_generation_decode_steps_ahead_total",
+    "Decode steps put on the device's queue while the session's "
+    "previous step was still uncollected (over _decode_steps_total: "
+    "the share of steps launched one step ahead)")
 _HOST_MS = _metrics.REGISTRY.counter(
     "paddle_generation_host_ms_total",
-    "Dispatcher milliseconds in host turns (no decode call of the "
-    "session queued on the device), by phase: deliver, admit (holds the "
-    "prefill's device call), prepare, dispatch, other",
+    "Dispatcher milliseconds in host turns (a step's tokens on the "
+    "host to the next decode call on the device's queue), by phase: "
+    "deliver, admit (holds the prefill's device call), prepare, "
+    "dispatch, other",
     labelnames=("phase",))
 _DEVICE_WAIT_MS = _metrics.REGISTRY.counter(
     "paddle_generation_device_wait_ms_total",
@@ -370,6 +378,21 @@ class GenerationSpec:
                             % sorted(kwargs))
 
 
+class _Flight:
+    """A decode step on the device's queue, between ``step_launch`` and
+    ``step_collect``: the slots it advances with their retirement counts
+    at the launch, the device arrays it will fetch, and the cached tokens
+    it attends. A speculative round, which is over when it is launched,
+    carries what it emitted instead."""
+
+    __slots__ = ("advanced", "retires", "outs", "context", "emitted")
+
+    def __init__(self, advanced=None, retires=None, outs=None, context=0,
+                 emitted=None):
+        self.advanced, self.retires, self.outs = advanced, retires, outs
+        self.context, self.emitted = context, emitted
+
+
 class GenerationSession:
     """One decode batch: ``spec.slots`` cache slots over one scope.
 
@@ -494,6 +517,23 @@ class GenerationSession:
         self._decode_fetches = [spec.decode_fetch]
         if getattr(spec, "stats_fetch", None) is not None:
             self._decode_fetches.append(spec.stats_fetch)
+        # -- steps launched and not yet collected (step_launch) ----------
+        # oldest first. A step is prepared with at most one of them
+        # uncollected: its tokens are then the next feed on the device
+        # (``_merge_tokens``), and only a slot that did not advance in it
+        # is fed from the host's ``last_token``. ``_retires`` counts a
+        # slot's retirements, so a result that arrives for a sequence
+        # retired since its launch is told from its successor's
+        self._flights = collections.deque()
+        self._retires = np.zeros(n, np.int64)
+        # the next feed needs no token on the host: what a scheduler
+        # reads to work one step ahead (GenerationScheduler, "One step
+        # ahead"). A constraint's mask comes from the token itself and a
+        # speculative round interleaves host and device
+        self.lookahead = not (self.constrained or self.speculative)
+        self._merge_tokens = self._token_dtype = None
+        if self.lookahead:
+            self._compile_token_merge()
         self.draft = None
         if self.speculative:
             # the draft mirrors the target slot-for-slot: admitted,
@@ -709,6 +749,7 @@ class GenerationSession:
         for name in self._claimed:
             self.scope.erase(name)
         self._claimed = set()
+        self._flights.clear()
         self.active[:] = False
 
     # -- decode-policy plumbing ------------------------------------------
@@ -771,7 +812,19 @@ class GenerationSession:
         hidden state, which only a forward pass produces. The prompt's
         blocks are then registered in the prefix index. All block
         references taken here are rolled back if anything below fails —
-        the pool can't leak on an admission error."""
+        the pool can't leak on an admission error.
+
+        Two phases, like a decode step: :meth:`admit_launch` ends with
+        the prefill on the device's queue, :meth:`admit_collect` is the
+        wait for its first token and the slot's books. A scheduler that
+        has a decode step queued ahead of the prefill collects that step
+        between the two."""
+        return self.admit_collect(self.admit_launch(prompt, seed, cstate))
+
+    def admit_launch(self, prompt, seed=0, cstate=None):
+        """Phase 1 of an admission: the slot, its blocks and the prefill
+        call, not waited for. Returns the handle for
+        :meth:`admit_collect`; the slot is not active until then."""
         prompt = np.asarray(prompt, np.int64).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -841,15 +894,39 @@ class GenerationSession:
                 outs = self.exe.run(
                     self.spec.prefill_programs[bucket], feed=feed,
                     fetch_list=[self.spec.prefill_fetch],
-                    scope=self.scope)
+                    scope=self.scope, return_numpy=False)
         except BaseException:
-            for block in table:
-                self.pool.decref(block)
-            for kind, tbl in zip(self._more_kinds, more):
-                for block in tbl:
-                    kind.pool.decref(block)
+            self._admit_rollback(table, more)
             raise
-        first = int(np.asarray(outs[0]).reshape(-1)[0])
+        return (prompt, slot, table, more, bucket, matched, outs,
+                seed, cstate)
+
+    def _admit_rollback(self, table, more):
+        for block in table:
+            self.pool.decref(block)
+        for kind, tbl in zip(self._more_kinds, more):
+            for block in tbl:
+                kind.pool.decref(block)
+
+    def admit_abandon(self, launched):
+        """Give back what :meth:`admit_launch` took, for a prefill whose
+        first token nobody will wait for."""
+        self._admit_rollback(launched[2], launched[3])
+
+    def admit_collect(self, launched):
+        """Phase 2 of an admission: wait for the prefill's first token
+        (``session:prefill_wait``) and enter the sequence in the slot's
+        books. Returns ``(slot, token)``."""
+        prompt, slot, table, more, bucket, matched, outs, seed, cstate = \
+            launched
+        try:
+            with _tracing.span("session:prefill_wait", round=self.round,
+                               slot=slot):
+                first = int(np.asarray(outs[0]).reshape(-1)[0])
+        except BaseException:
+            self._admit_rollback(table, more)
+            raise
+        n = prompt.size
         if self.prefix is not None:
             # publish the prompt's blocks (full chunks + partial
             # tail) — the next admission sharing this prefix, or a
@@ -865,11 +942,11 @@ class GenerationSession:
         self._policy_admitted(slot, first, seed, cstate)
         self._draft_admit(prompt, slot, first)
         self._starved.discard(slot)
-        self.prefill_log.append((bucket, matched, w))
+        self.prefill_log.append((bucket, matched, n - matched))
         if len(self.prefill_log) > 4096:     # keep a list (tests
             del self.prefill_log[:2048]      # slice it), bounded
         _PREFILLS.labels(bucket=bucket).inc()
-        _PROMPT_TOKENS.inc(w)
+        _PROMPT_TOKENS.inc(n - matched)
         _PREFILL_PADDED_TOKENS.inc(bucket)
         return slot, first
 
@@ -897,12 +974,20 @@ class GenerationSession:
             return {}
         return self.step_run(prepared)
 
-    def step_prepare(self):
+    def step_prepare(self, hold=()):
         """Phase 1 of a decode step: the active-slot snapshot, the
         capacity check, and EVERY host-side
         pool mutation (block growth, copy-on-write, the table feed)
         plus snapshotted feeds. Returns an opaque handle for
-        :meth:`step_run`, or None with nothing active.
+        :meth:`step_run`, or None with nothing to step.
+
+        ``hold`` names active slots that sit this step out: they
+        neither write nor advance, like a free slot. A scheduler working
+        one step ahead holds the slots whose sequence ends with the step
+        still uncollected. With such a step uncollected
+        (:meth:`step_launch`) the token feed is built on the device from
+        that step's tokens; a slot that did not advance in it is fed the
+        host's ``last_token``.
 
         The split is a thread-safety contract, not a convenience: the
         scheduler's step-timeout path runs the device call on a
@@ -920,9 +1005,17 @@ class GenerationSession:
         ``admit()``'s exposure, not ``step()``'s: like every prefill,
         it runs unbounded on the dispatcher (the step timeout has
         always bounded only the per-token decode call)."""
-        act = np.flatnonzero(self.active)
+        active = self.active
+        if len(hold):
+            active = active.copy()
+            active[list(hold)] = False
+        act = np.flatnonzero(active)
         if act.size == 0:
             return None
+        if len(self._flights) > 1:
+            raise RuntimeError(
+                "%d decode steps are uncollected — a step is prepared at "
+                "most one step ahead" % len(self._flights))
         with _tracing.span("session:step_prepare", round=self.round,
                            active=int(act.size)):
             if (self.lengths[act] >= self.max_pos).any():
@@ -973,7 +1066,7 @@ class GenerationSession:
                 tbl = self.tables[s]
                 tab[s, :len(tbl)] = tbl
             f_tok, f_pos, f_tab = self.spec.decode_feeds[:3]
-            feed = {f_tok: self.last_token.reshape(-1, 1).copy(),
+            feed = {f_tok: self._token_feed(),
                     f_pos: self.lengths.astype(np.int32),
                     f_tab: tab}
             for kind in self._more_kinds:
@@ -985,6 +1078,44 @@ class GenerationSession:
                 feed[kind.kind.decode_table] = tab
             self._policy_decode_feed(feed)
             return (act, frozenset(self._starved), feed)
+
+    def _compile_token_merge(self):
+        """The one device computation a step ahead adds: the uncollected
+        step's tokens, with the host's token where ``from_host`` says so,
+        in the token feed's shape and dtype. Compiled here, with the
+        session, so that no step compiles it."""
+        import jax
+        import jax.numpy as jnp
+        from ..core.framework import convert_dtype
+        block = self.spec.decode_program.global_block()
+        feed = block.var(self.spec.decode_feeds[0])
+        out = block.var(self.spec.decode_fetch)
+        shape, dtype = tuple(feed.shape), convert_dtype(feed.dtype)
+        n = self.spec.slots
+
+        def merge(tokens, host, from_host):
+            return jnp.where(from_host, host,
+                             tokens.reshape(n).astype(dtype)).reshape(shape)
+        self._token_dtype = dtype
+        self._merge_tokens = jax.jit(merge).lower(
+            jax.ShapeDtypeStruct(tuple(out.shape), convert_dtype(out.dtype)),
+            jax.ShapeDtypeStruct((n,), dtype),
+            jax.ShapeDtypeStruct((n,), np.bool_)).compile()
+
+    def _token_feed(self):
+        """The step's token feed: the host's ``last_token``, or with a
+        step uncollected that step's tokens merged on the device with the
+        host's for the slots that did not advance in it (admitted since,
+        starved or held in it)."""
+        if not self._flights:
+            return self.last_token.reshape(-1, 1).copy()
+        ahead = self._flights[-1]
+        from_host = np.ones(self.spec.slots, bool)
+        from_host[ahead.advanced[
+            ahead.retires == self._retires[ahead.advanced]]] = False
+        return self._merge_tokens(
+            ahead.outs[0], self.last_token.astype(self._token_dtype),
+            from_host)
 
     def _policy_decode_feed(self, feed):
         """Append the decode-policy feeds to a decode-step feed dict.
@@ -1050,7 +1181,8 @@ class GenerationSession:
 
     def step_run(self, prepared, enqueued=None):
         """Phase 2 of a decode step: the device call plus result
-        application. Touches no allocator state — safe to execute on
+        application, :meth:`step_launch` then :meth:`step_collect`.
+        Touches no allocator state — safe to execute on
         the scheduler's bounded (leakable) worker thread; the feeds
         and starved-set were snapshotted at prepare time. (The
         speculative round is the one exception: it runs drafting,
@@ -1058,15 +1190,32 @@ class GenerationSession:
         scheduler refuses step_timeout_ms on speculative sessions —
         that round only ever executes inline on the dispatcher.)
 
-        The call is cut in two where the host stops working and starts
-        waiting: ``session:step_dispatch`` ends with the step on the
-        device's queue, ``session:step_wait`` is the fetch of its
-        tokens. ``enqueued``, when given, is called between the two
-        (the scheduler ends its host turn there). A speculative round
-        interleaves several device calls with host work and is not
-        cut: it never calls ``enqueued``."""
+        ``enqueued``, when given, is called between the two, where the
+        host stops working and starts waiting (the scheduler ends its
+        host turn there). A speculative round interleaves several device
+        calls with host work and is not cut: it is over when
+        ``enqueued`` is called."""
+        flight = self.step_launch(prepared)
+        if enqueued is not None:
+            enqueued()
+        return self.step_collect(flight)
+
+    def step_launch(self, prepared):
+        """Put a prepared step on the device's queue
+        (``session:step_dispatch``) and advance the books by what does not
+        depend on its tokens: the lengths of the slots it advances. The
+        tokens stay on the device until :meth:`step_collect`, in launch
+        order; the next step can be prepared and launched before that
+        (one step ahead, where ``lookahead`` is true), fed by them there.
+
+        A speculative round is not one asynchronous call: it runs whole
+        here, and its collect hands over what it emitted."""
         if isinstance(prepared, dict):
-            return self._step_run_spec(prepared)
+            emitted = self._step_run_spec(prepared)
+            flight = _Flight(emitted=emitted, context=int(sum(
+                self.lengths[s] for s in emitted)))
+            self._flights.append(flight)
+            return flight
         act, starved, feed = prepared
         with _tracing.span("session:step_dispatch", round=self.round,
                            active=int(act.size)):
@@ -1074,8 +1223,37 @@ class GenerationSession:
                 self.spec.decode_program, feed=feed,
                 fetch_list=self._decode_fetches, scope=self.scope,
                 return_numpy=False)
-        if enqueued is not None:
-            enqueued()
+        advanced = act if not starved else np.asarray(
+            [s for s in act if int(s) not in starved], np.int64)
+        self.lengths[advanced] += 1
+        lens = self.lengths[advanced]
+        if self._window_kinds and advanced.size:
+            _WINDOW_CONTEXT_TOKENS.inc(int(sum(
+                k.kind.layers * np.minimum(lens, k.window).sum()
+                for k in self._window_kinds)))
+        flight = _Flight(advanced, self._retires[advanced].copy(), outs,
+                         int(lens.sum()))
+        self._flights.append(flight)
+        return flight
+
+    def step_collect(self, flight):
+        """Wait for a launched step's tokens (``session:step_wait``) and
+        apply them: ``{slot: token}`` for the slots it advanced. A slot
+        retired since the launch is left out: its sequence ended (or its
+        session failed) while the step was queued, and the row it wrote
+        lies in blocks the slot owned then; whatever reuses them is a
+        later call on the device's queue."""
+        if self._flights and self._flights[0] is flight:
+            self._flights.popleft()
+        elif any(f is flight for f in self._flights):
+            raise RuntimeError("decode steps are collected in the order "
+                               "they were launched")
+        # else dropped (drop_flights) while a bounded worker was still in
+        # this call: every slot it advanced was retired with it
+        if flight.emitted is not None:
+            return flight.emitted
+        advanced, retires, outs = flight.advanced, flight.retires, \
+            flight.outs
         with _tracing.span("session:step_wait", round=self.round):
             for extra in outs[1:]:
                 extra.copy_to_host_async()  # beside the tokens, not after
@@ -1083,24 +1261,21 @@ class GenerationSession:
             if len(outs) > 1:
                 self._count_experts(np.asarray(outs[1]))
         result = {}
-        for s in act:
+        for s in advanced[retires == self._retires[advanced]]:
             s = int(s)
-            if s in starved:
-                continue
-            self.lengths[s] += 1
             self.last_token[s] = int(nxt[s])
             result[s] = int(nxt[s])
             if self.constrained:
                 self.cstate[s] = self.policy.constraint.advance(
                     self.cstate[s], int(nxt[s]))
-        if self._window_kinds and result:
-            lens = self.lengths[list(result)]
-            _WINDOW_CONTEXT_TOKENS.inc(int(sum(
-                k.kind.layers * np.minimum(lens, k.window).sum()
-                for k in self._window_kinds)))
         if self.draft is not None and result:
             self._draft_mirror_plain(result)
         return result
+
+    def drop_flights(self):
+        """Forget the steps launched and not collected: their session
+        failed, and what they hold is re-made from the journals."""
+        self._flights.clear()
 
     @staticmethod
     def _count_experts(counts):
@@ -1214,6 +1389,7 @@ class GenerationSession:
         (a block shared with the prefix index survives as cached
         prompt state; exclusive blocks free immediately)."""
         self.active[slot] = False
+        self._retires[slot] += 1
         self.lengths[slot] = 0
         self.last_token[slot] = 0
         self.seeds[slot] = 0
@@ -1262,7 +1438,7 @@ class _GenRequest:
                  "future", "deadline", "t_submit", "tokens", "slot",
                  "session_index", "t_last", "t_queued", "replays",
                  "charged", "failed_on", "last_exc", "ctx",
-                 "on_token", "seed", "tenant")
+                 "on_token", "seed", "tenant", "ahead")
 
     def __init__(self, prompt, max_new, explicit_budget, eos_id,
                  deadline, on_token=None, seed=0, tenant=None):
@@ -1294,6 +1470,9 @@ class _GenRequest:
         # using t_submit — replay spends the caller's budget
         self.t_queued = self.t_submit
         self.tokens = []
+        # decode steps launched for this request whose tokens are not
+        # delivered yet (the dispatcher works one step ahead)
+        self.ahead = 0
         self.slot = None
         self.session_index = None
         self.t_last = None
@@ -1349,6 +1528,20 @@ class _GenRequest:
             [self.prompt, np.asarray(self.tokens, np.int64)])
 
 
+class _Launched:
+    """A session's decode step between the dispatcher's launch and its
+    collect: the requests it steps, by slot, as they stood at the launch
+    (a slot may have been retired since), the slots the pool starved out
+    of it, ``wait()`` that blocks for ``{slot: token}``, and the cached
+    tokens it attends where the launch knows them."""
+
+    __slots__ = ("mine", "starved", "wait", "context")
+
+    def __init__(self, mine, starved):
+        self.mine, self.starved = mine, starved
+        self.wait = self.context = None
+
+
 class GenerationScheduler:
     """Continuous-batching front door over one or more
     :class:`GenerationSession` replicas.
@@ -1388,31 +1581,81 @@ class GenerationScheduler:
     ``close()`` is the bounded fast exit. ``swap_weights(params)``
     installs new values between decode steps (see method docs).
 
+    **One step ahead.** Nothing a decode step needs from the host
+    depends on the tokens of the step before it: lengths advance by
+    one, block tables, window trims, copy-on-write, sampling counters
+    and token budgets follow from counts, and the tokens themselves are
+    fed to the next step on the device
+    (``GenerationSession.step_launch``). So an iteration of the
+    dispatcher on a session is: prepare and launch step n+1, *then*
+    collect step n's tokens, deliver them, retire, admit. The device
+    has step n+1 queued while the host does its turn. How far ahead is
+    read off the session, set by nobody (``_depth``): one step where
+    ``session.lookahead`` is true (greedy and sampled policies), none,
+    which is launch then collect of the same step, where the next feed
+    needs the token on the host or the step is not one asynchronous
+    call: constrained decoding, speculative rounds, and
+    ``step_timeout_ms`` (the bounded worker).
+    ``paddle_generation_decode_steps_ahead_total`` counts the steps
+    launched with their predecessor uncollected. What follows from it:
+
+    * A request that the uncollected step ends *by count* (token
+      budget, cache capacity) sits the next step out (``hold``); its
+      Future resolves when the tokens arrive. What ends a request *by
+      value* (EOS, a constraint, a deadline read at delivery) is seen
+      one step late: the slot's one extra step wrote into blocks it
+      owned, and ``_deliver`` discards its result.
+    * Whatever acted "between two steps" first collects and delivers
+      what is launched (``_settle``): the wait for a prefill's first
+      token, ``swap_weights``, the end of ``drain``/``close``/serving
+      out; a rebuild's hand-over and a session failure drop it instead
+      (``drop_flights``): a failed step n surfaces at its collect, the
+      step launched behind it goes with it, and the journals, which
+      hold delivered tokens only, replay bit-identically. An admission
+      into a session always settles that session first, so a launched
+      step never delivers into a slot's next tenant.
+    * Blocks freed by a retire or a window trim may be reused at once:
+      every reuse is a later call on the device's queue than every
+      read or write of them.
+
     **The dispatcher's clock.** The dispatcher's time is cut, by spans
     (``observability/tracing.py``: in any ``jax.profiler`` trace, no
     flag) and by always-on counters read from the same clock readings,
     into three kinds of stretch. A *host turn*
     (``scheduler:host_turn``, numbered ``round=``) runs from the moment
     a decode step's tokens are on the host to the moment the next
-    decode call is on the device's queue: the time the device has
-    nothing of the session queued. Its named children are
-    ``scheduler:deliver`` (tokens to requests, finish, retire),
-    ``scheduler:admit`` (one per admitted request; holds the
-    prefill's device call ``session:prefill_call``),
-    ``session:step_prepare`` and ``session:step_dispatch``; each adds
-    its milliseconds to ``paddle_generation_host_ms_total{phase}``, and
-    ``phase="other"`` takes the turn less its named children (swap,
-    expiry and queue bookkeeping), so the five phases sum to the host
-    turns. A *device wait* (``session:step_wait``,
+    decode call is on the device's queue. Working a step ahead, that is
+    no longer time the device has nothing of the session queued: the
+    step launched in the turn before runs beside it, and the turn
+    shows in a token gap only by what it exceeds that step. At depth 0,
+    and in the one turn after each prefill (which is waited for with
+    nothing behind it), the device still idles through it. Its named
+    children are ``scheduler:deliver`` (tokens to requests, finish,
+    retire), ``scheduler:admit`` (one per admitted request; ends with
+    the prefill's device call ``session:prefill_call`` on the queue)
+    and ``scheduler:first_token`` (the wait for it,
+    ``session:prefill_wait``, and the request's entry into the books;
+    both count as ``phase="admit"``), ``session:step_prepare`` and
+    ``session:step_dispatch``; each adds its milliseconds to
+    ``paddle_generation_host_ms_total{phase}``, and ``phase="other"``
+    takes the turn less its named children (swap, expiry and queue
+    bookkeeping), so the five phases sum to the host turns. A *device
+    wait* (``session:step_wait``,
     ``paddle_generation_device_wait_ms_total``) is the dispatcher
-    blocked on the step's result; prepare + dispatch + wait is the
-    value observed into ``paddle_request_decode_step_ms``. An *idle
-    wait* (``scheduler:idle_wait``) is the dispatcher blocked on its
-    queue with nothing active. With ``step_timeout_ms`` the call runs
-    on a worker thread, which records the session's spans; the
-    dispatcher's own dispatch phase is then the hand-over and its wait
-    covers the worker's dispatch. A speculative round is not cut: it
-    counts as dispatch, with the device calls as spans inside.
+    blocked on the step it collects, booked to that step: a decode step
+    queued ahead of a prefill is collected between ``scheduler:admit``
+    and ``scheduler:first_token``, outside the turn, so its wait is not
+    read as prefill time. ``paddle_request_decode_step_ms`` observes,
+    at each collect, that wait plus the prepare and dispatch seconds
+    spent since the last observation (one step ahead: the *next* step's
+    prepare and dispatch), so the observations do not overlap and sum
+    to prepare + dispatch + wait. An *idle wait*
+    (``scheduler:idle_wait``) is the dispatcher blocked on its queue
+    with nothing active. With ``step_timeout_ms`` the call runs on a
+    worker thread, which records the session's spans; the dispatcher's
+    own dispatch phase is then the hand-over and its wait covers the
+    worker's dispatch. A speculative round is not cut: it counts as
+    dispatch, with the device calls as spans inside.
     """
 
     def __init__(self, sessions, max_queue=256, deadline_ms=None,
@@ -1459,6 +1702,12 @@ class GenerationScheduler:
         self._turn = None
         self._turn_t0 = self._turn_named = 0.0
         self._t_dispatch = self._t_enqueued = None
+        # prepare + dispatch seconds no decode-step observation holds yet
+        self._unobserved = 0.0
+        # per session, the step launched and not collected (_Launched):
+        # only a session that works a step ahead leaves one here between
+        # two dispatcher iterations ("One step ahead", class docstring)
+        self._inflight = [None] * len(self.sessions)
         if deadline_ms is None:
             deadline_ms = _config.get_flag("serving_deadline_ms")
         self.default_deadline_ms = deadline_ms
@@ -1904,8 +2153,7 @@ class GenerationScheduler:
                                   "no healthy generation session for "
                                   "this prompt"))
             return True
-        with self._host_phase("scheduler:admit", "admit", session=si):
-            self._admit_item(item, si)
+        self._admit_item(item, si)
         return True
 
     # -- the dispatcher's clock (class docstring) -------------------------
@@ -1946,6 +2194,7 @@ class GenerationScheduler:
         if now is None:
             now = time.perf_counter()
         self._host_ms("dispatch", now - self._t_dispatch)
+        self._unobserved += now - self._t_dispatch
         self._turn_close(now)
         self._t_enqueued = now
 
@@ -1961,78 +2210,114 @@ class GenerationScheduler:
         return got
 
     def _admit_item(self, item, si):
-        wait = time.perf_counter() - item.t_queued
-        self._wait_ewma += _WAIT_ALPHA * (wait - self._wait_ewma)
-        _rtrace.QUEUE_WAIT_MS.observe(wait * 1e3)
+        """Admit ``item`` into session ``si``, in the two phases of
+        ``GenerationSession.admit``: ``scheduler:admit`` ends with the
+        prefill on the device's queue, ``scheduler:first_token`` holds
+        the wait for its first token and the request's entry into the
+        books. A decode step of the session that is queued ahead of the
+        prefill is collected and delivered between the two, so its wait
+        is booked to it and its tokens do not wait out the prefill."""
         sess = self.sessions[si]
         replay = bool(item.tokens)
-        if item.ctx is not None:
-            _rtrace.event(item.ctx, "queueWait", dur_ms=wait * 1e3,
-                          replay=replay)
         t_admit0 = time.perf_counter()
+        with self._host_phase("scheduler:admit", "admit", session=si):
+            wait = t_admit0 - item.t_queued
+            self._wait_ewma += _WAIT_ALPHA * (wait - self._wait_ewma)
+            _rtrace.QUEUE_WAIT_MS.observe(wait * 1e3)
+            if item.ctx is not None:
+                _rtrace.event(item.ctx, "queueWait", dur_ms=wait * 1e3,
+                              replay=replay)
+            launched = self._admit_guarded(
+                item, si, lambda: self._prefill_launch(item, si, sess))
+        if launched is None:
+            return
+        prefill_s = time.perf_counter() - t_admit0
+        if self._inflight[si] is not None and not self._settle(si):
+            # the step queued ahead of the prefill failed, and the
+            # session's requests went to replay: this one goes back to
+            # the head of the line as it came
+            sess.admit_abandon(launched)
+            item.failed_on.add(si)
+            self._pending.appendleft(item)
+            return
+        t_resume = time.perf_counter()
+        with self._host_phase("scheduler:first_token", "admit",
+                              session=si):
+            got = self._admit_guarded(
+                item, si, lambda: sess.admit_collect(launched))
+            if got is None:
+                return
+            slot, first = got
+            # breaker success is recorded by a surviving STEP, not here:
+            # a persistently step-broken session would otherwise launder
+            # itself closed through every trial admission it then fails
+            if item.eos_id is None:
+                item.eos_id = sess.spec.eos_id
+            now_pc = time.perf_counter()
+            prefill_ms = (prefill_s + now_pc - t_resume) * 1e3
+            _rtrace.PREFILL_MS.observe(prefill_ms)
+            if item.ctx is not None:
+                # hist = prefix-cache hit length: tokens served from
+                # shared blocks instead of re-prefilled (0 on a prefix
+                # miss)
+                hist = sess.prefill_log[-1][1] if sess.prefill_log else 0
+                _rtrace.event(item.ctx,
+                              "replayAdmit" if replay else "prefill",
+                              dur_ms=prefill_ms,
+                              session=si, slot=slot,
+                              journal_len=int(item.prompt.size)
+                              + len(item.tokens), hist=int(hist))
+            if replay:
+                # the same logical request, resumed — requests_total
+                # must not double-count it; the re-prefilled history is
+                # what the failover actually cost
+                _REPLAYED_TOKENS.inc(len(item.tokens))
+                _RECOVERY_SECONDS.observe(now_pc - item.t_queued)
+            else:
+                _REQUESTS.inc()
+                _TTFT_SECONDS.observe(now_pc - item.t_submit)
+            _TOKENS.inc()  # the prefill produced one NEW token either way
+            item.t_last = now_pc
+            item.slot, item.ahead = slot, 0
+            item.session_index = si
+            item.tokens.append(first)
+            item.notify_token(first)
+            self._active[(si, slot)] = item
+            self._update_occupancy()
+            # EOS/budget can end it at token 1; a surviving constrained
+            # request may already be in a dead automaton state
+            if not self._finish_if_done(item):
+                self._check_dead_end(sess, item)
+
+    def _prefill_launch(self, item, si, sess):
+        _faults.fire_point("generation_admit_fail", index=si)
+        cstate = None
+        if sess.constrained:
+            # replay state folds the journal through the
+            # automaton — the host state is journal-derived,
+            # exactly like the KV cache
+            c = sess.policy.constraint
+            cstate = c.advance_many(c.start, item.tokens)
+        sess.round = self._round
+        return sess.admit_launch(item.history(), seed=item.seed,
+                                 cstate=cstate)
+
+    def _admit_guarded(self, item, si, call):
+        """``call()`` under the request's activated context (it follows
+        the admission into the fault hook and the prefill's
+        executor.run: deviceCall spans land on this request's trace),
+        or None with the failure handled."""
         try:
-            # the activated context follows the admission into the
-            # fault hook and the prefill's executor.run (deviceCall
-            # spans land on this request's trace)
             with _rtrace.activate(item.ctx):
-                _faults.fire_point("generation_admit_fail", index=si)
-                cstate = None
-                if sess.constrained:
-                    # replay state folds the journal through the
-                    # automaton — the host state is journal-derived,
-                    # exactly like the KV cache
-                    c = sess.policy.constraint
-                    cstate = c.advance_many(c.start, item.tokens)
-                sess.round = self._round
-                slot, first = sess.admit(item.history(),
-                                         seed=item.seed, cstate=cstate)
+                return call()
         except ValueError as exc:
             # a client-shaped prompt (bucket/length) is the request's
             # fault, not the session's — it must not charge the
             # breaker and quarantine a healthy session
             self._resolve_err(item, exc)
-            return
         except Exception as exc:
             self._on_admit_failure(item, si, exc)
-            return
-        # breaker success is recorded by a surviving STEP, not here: a
-        # persistently step-broken session would otherwise launder
-        # itself closed through every trial admission it then fails
-        if item.eos_id is None:
-            item.eos_id = sess.spec.eos_id
-        now_pc = time.perf_counter()
-        _rtrace.PREFILL_MS.observe((now_pc - t_admit0) * 1e3)
-        if item.ctx is not None:
-            # hist = prefix-cache hit length: tokens served from
-            # shared blocks instead of re-prefilled (0 on a prefix miss)
-            hist = sess.prefill_log[-1][1] if sess.prefill_log else 0
-            _rtrace.event(item.ctx,
-                          "replayAdmit" if replay else "prefill",
-                          dur_ms=(now_pc - t_admit0) * 1e3,
-                          session=si, slot=slot,
-                          journal_len=int(item.prompt.size)
-                          + len(item.tokens), hist=int(hist))
-        if replay:
-            # the same logical request, resumed — requests_total must
-            # not double-count it; the re-prefilled history is what
-            # the failover actually cost
-            _REPLAYED_TOKENS.inc(len(item.tokens))
-            _RECOVERY_SECONDS.observe(now_pc - item.t_queued)
-        else:
-            _REQUESTS.inc()
-            _TTFT_SECONDS.observe(now_pc - item.t_submit)
-        _TOKENS.inc()  # the prefill produced one NEW token either way
-        item.t_last = now_pc
-        item.slot = slot
-        item.session_index = si
-        item.tokens.append(first)
-        item.notify_token(first)
-        self._active[(si, slot)] = item
-        self._update_occupancy()
-        # EOS/budget can end it at token 1; a surviving constrained
-        # request may already be in a dead automaton state
-        if not self._finish_if_done(item):
-            self._check_dead_end(sess, item)
+        return None
 
     def _on_admit_failure(self, item, si, exc):
         """A session failed this request's (re-)admission: charge its
@@ -2109,7 +2394,9 @@ class GenerationScheduler:
             reason = "eos"
         elif len(item.tokens) >= item.max_new:
             reason = "max_tokens"
-        elif sess.capacity_left(item.slot) <= 0:
+        elif sess.capacity_left(item.slot) + item.ahead <= 0:
+            # ``ahead``: the lengths already hold the step launched and
+            # not delivered
             reason = "capacity"
         elif item.deadline is not None and \
                 time.monotonic() >= item.deadline:
@@ -2167,19 +2454,20 @@ class GenerationScheduler:
         self._update_occupancy()
         return True
 
-    def _step_session(self, si, sess, prepared=None, enqueued=None):
-        """One session's decode step plus its fault hooks — shared by
-        the inline path and the bounded worker, so injected faults
-        (including a wedge callback) land inside whatever bounds the
-        step. ``prepared`` carries a host-side step_prepare() handle
-        when the caller already ran phase 1 — _step_all does on both
-        paths, keeping pool mutation on the dispatcher thread and
-        outside any request's activated trace context."""
+    def _step_session(self, si, sess, prepared):
+        """One session's decode step plus its fault hooks, on the
+        bounded worker — so injected faults (including a wedge callback)
+        land inside whatever bounds the step. ``prepared`` is the
+        step_prepare() handle ``_launch`` made on the dispatcher thread,
+        which keeps pool mutation there and outside any request's
+        activated trace context."""
+        self._step_faults(si)
+        return sess.step_run(prepared)
+
+    @staticmethod
+    def _step_faults(si):
         _faults.fire_point("generation_session_wedge", index=si)
         _faults.fire_point("generation_step_fail", index=si)
-        if prepared is not None:
-            return sess.step_run(prepared, enqueued)
-        return sess.step()
 
     def _step_timed(self, si, sess, prepared):
         """Step bounded by ``self.step_timeout`` on a worker thread
@@ -2190,7 +2478,7 @@ class GenerationScheduler:
         can't stack blocked threads behind a dead device call.
 
         ``prepared`` is the session's step_prepare() handle, produced
-        by _step_all on the dispatcher thread — which
+        by _launch on the dispatcher thread — which
         is where ALL block-pool mutation happens: a worker
         leaked past its timeout only ever executes the device call
         plus per-slot scalar advances, never allocator mutation, so
@@ -2208,14 +2496,23 @@ class GenerationScheduler:
             _STEP_TIMEOUTS.inc()
             raise
 
-    def _on_session_failure(self, si, sess, mine, exc, hang=False):
+    def _on_session_failure(self, si, sess, exc):
         """A session's step failed (or hung): free its slots, charge
         its breaker once for the event, and replay the affected
         requests into healthy sessions (default-off: they resolve
         exceptionally, the pre-replay contract). The cache state died
         with the session, but each request's prompt+tokens journal is
         a complete deterministic transcript — re-prefilling it
-        elsewhere resumes the generation with identical output."""
+        elsewhere resumes the generation with identical output.
+
+        A step launched behind the failed one is dropped with it,
+        uncollected: the journals hold delivered tokens only, so the
+        replay re-makes what both would have given."""
+        hang = isinstance(exc, _sres.ServingTimeoutError)
+        self._inflight[si] = None
+        sess.drop_flights()
+        self._unobserved = 0.0
+        mine = self._on_session(si)
         breaker = self._breakers[si] if self._breakers else None
         if breaker is not None:
             # one breaker charge per failure EVENT (the step is the
@@ -2261,75 +2558,167 @@ class GenerationScheduler:
         # rebuild is armed it goes straight to reconstruction
         self._maybe_rebuild(si, force=hang)
 
+    def _on_session(self, si):
+        """The requests active on session ``si``, as (slot, request)."""
+        return [(slot, it) for (s_i, slot), it
+                in list(self._active.items()) if s_i == si]
+
+    def _busy(self):
+        """Something is active or a launched step is uncollected."""
+        return bool(self._active) or any(
+            rec is not None for rec in self._inflight)
+
+    def _depth(self, sess):
+        """How many decode steps of ``sess`` the dispatcher keeps on the
+        device's queue beyond the one it waits for: read off the
+        session ("One step ahead", class docstring)."""
+        return 1 if sess.lookahead and self.step_timeout is None else 0
+
+    def _ends_in_flight(self, sess, it):
+        """The steps launched for ``it`` and not delivered end it by
+        count (token budget, cache capacity): it needs no further one."""
+        return it.ahead and (len(it.tokens) + it.ahead >= it.max_new or
+                             sess.capacity_left(it.slot) <= 0)
+
     def _step_all(self):
+        """One dispatcher iteration on every session: launch its next
+        decode step, then collect and deliver the one launched an
+        iteration earlier — or, at depth 0, the one just launched."""
         for si, sess in enumerate(self.sessions):
             if si in self._rebuilding:
                 continue  # down for reconstruction; nothing is active
-            mine = [(slot, it) for (s_i, slot), it
-                    in list(self._active.items()) if s_i == si]
-            if not mine:
+            ahead_of = self._inflight[si]
+            mine, hold = self._on_session(si), ()
+            if ahead_of is not None:
+                hold = [slot for slot, it in mine
+                        if self._ends_in_flight(sess, it)]
+                mine = [(slot, it) for slot, it in mine
+                        if slot not in hold]
+            self._t_dispatch = self._t_enqueued = None
+            new = None
+            if mine:
+                try:
+                    new = self._launch(si, sess, mine, hold)
+                except Exception as exc:
+                    now = time.perf_counter()
+                    if self._t_dispatch is not None and \
+                            self._t_enqueued is None:
+                        self._enqueued(now)  # a failed dispatch is all
+                    if self._turn is None:   # dispatch
+                        self._turn_open(now)
+                    self._on_session_failure(si, sess, exc)
+                    continue
+            if new is not None and ahead_of is None and self._depth(sess):
+                # nothing older to collect: this step is collected an
+                # iteration later, behind the next one's launch
+                self._inflight[si] = new
+                self._turn_open(self._t_enqueued)
+                self._t_enqueued = None
                 continue
-            breaker = self._breakers[si] if self._breakers else None
-            # one decode program serves every co-resident request:
-            # the step's deviceCall span is carried by the FIRST
-            # sampled request's context (the inline path; a
-            # worker-bounded step loses it by design), each sampled
-            # request then gets its own slot-annotated decodeStep
-            # event below
-            step_ctx = next((it.ctx for _, it in mine
-                             if it.ctx is not None), None)
-            sess.round = self._round
-            self._t_dispatch = self._t_enqueued = failure = None
-            t_step0 = time.perf_counter()
-            try:
-                # step_prepare runs OUTSIDE the activated context on
-                # both paths: its pool mutations (grow, COW,
-                # eviction pressure) are batch-level — slot B's COW
-                # must not land in request A's span tree, so those
-                # global events reach only the flight ring
-                prepared = sess.step_prepare()
-                if prepared is None:
-                    toks = {}
-                else:
-                    self._t_dispatch = time.perf_counter()
-                    self._host_ms("prepare", self._t_dispatch - t_step0)
-                    if self.step_timeout is not None:
-                        # handed to a worker: from here the dispatcher
-                        # only waits
-                        self._enqueued()
-                        toks = self._step_timed(si, sess, prepared)
-                    else:
-                        with _rtrace.activate(step_ctx):
-                            toks = self._step_session(
-                                si, sess, prepared, self._enqueued)
-            except Exception as exc:
-                failure = exc
-            now_pc = time.perf_counter()
-            if self._t_dispatch is not None:
-                # the step reached the device call. One clock reading
-                # per boundary, so prepare + dispatch + wait is the
-                # step's time exactly; a call that never reported its
-                # enqueue (a speculative round, a failed dispatch) is
-                # all dispatch
-                if self._t_enqueued is None:
-                    self._enqueued(now_pc)
-                _DEVICE_WAIT_MS.inc((now_pc - self._t_enqueued) * 1e3)
-                self._turn_open(now_pc)
-            if failure is not None:
-                hang = isinstance(failure, _sres.ServingTimeoutError)
-                self._on_session_failure(si, sess, mine, failure,
-                                         hang=hang)
-                continue
-            if breaker is not None:
-                breaker.record_success()
-                self._trial_failures[si] = 0
-            _STEPS.inc()
-            step_ms = (now_pc - t_step0) * 1e3
-            _rtrace.DECODE_STEP_MS.observe(step_ms)
-            # before delivery retires slots and zeroes their lengths
-            _CONTEXT_TOKENS.inc(int(sum(sess.lengths[s] for s in toks)))
-            _TOKENS.inc(self._deliver(si, sess, mine, toks, now_pc,
-                                      step_ms))
+            if ahead_of is None:
+                ahead_of, new = new, None
+            elif new is not None:
+                _STEPS_AHEAD.inc()
+            self._inflight[si] = new
+            if ahead_of is not None:
+                self._collect(si, sess, ahead_of)
+
+    def _launch(self, si, sess, mine, hold):
+        """Prepare a decode step of ``sess`` for the requests ``mine``
+        and put it on the device's queue: the host turn ends there.
+        Returns the ``_Launched``, or None with nothing to step."""
+        # one decode program serves every co-resident request:
+        # the step's deviceCall span is carried by the FIRST
+        # sampled request's context (the inline path; a
+        # worker-bounded step loses it by design), each sampled
+        # request then gets its own slot-annotated decodeStep
+        # event at delivery
+        step_ctx = next((it.ctx for _, it in mine
+                         if it.ctx is not None), None)
+        sess.round = self._round
+        t_step0 = time.perf_counter()
+        # step_prepare runs OUTSIDE the activated context on
+        # both paths: its pool mutations (grow, COW,
+        # eviction pressure) are batch-level — slot B's COW
+        # must not land in request A's span tree, so those
+        # global events reach only the flight ring
+        prepared = sess.step_prepare(hold)
+        if prepared is None:
+            return None
+        self._t_dispatch = time.perf_counter()
+        self._host_ms("prepare", self._t_dispatch - t_step0)
+        self._unobserved += self._t_dispatch - t_step0
+        rec = _Launched(mine, prepared["starved"] if isinstance(
+            prepared, dict) else prepared[1])
+        if self.step_timeout is not None:
+            # handed to a worker: from here the dispatcher only waits
+            self._enqueued()
+            rec.wait = lambda: self._step_timed(si, sess, prepared)
+        else:
+            with _rtrace.activate(step_ctx):
+                self._step_faults(si)
+                flight = sess.step_launch(prepared)
+            self._enqueued()
+            rec.context = flight.context
+            rec.wait = lambda: sess.step_collect(flight)
+        for slot, it in mine:
+            if slot not in rec.starved:
+                it.ahead += 1
+        return rec
+
+    def _collect(self, si, sess, rec):
+        """Block on a launched step's tokens (the device wait), then
+        deliver them. One clock reading per boundary: the prepare and
+        dispatch seconds no observation holds yet plus this wait are the
+        step's time in ``paddle_request_decode_step_ms``, so the
+        observations neither overlap nor leave any of the three out.
+        Returns False when the step failed."""
+        if self._t_enqueued is None:
+            # nothing was launched in this iteration: the host turn ends
+            # where the wait starts
+            self._t_enqueued = time.perf_counter()
+            self._turn_close(self._t_enqueued)
+        failure = None
+        try:
+            toks = rec.wait()
+        except Exception as exc:
+            failure = exc
+        now_pc = time.perf_counter()
+        waited, self._t_enqueued = now_pc - self._t_enqueued, None
+        _DEVICE_WAIT_MS.inc(waited * 1e3)
+        self._turn_open(now_pc)
+        if failure is not None:
+            self._on_session_failure(si, sess, failure)
+            return False
+        for slot, it in rec.mine:
+            if slot not in rec.starved and \
+                    self._active.get((si, slot)) is it:
+                it.ahead -= 1
+        breaker = self._breakers[si] if self._breakers else None
+        if breaker is not None:
+            breaker.record_success()
+            self._trial_failures[si] = 0
+        _STEPS.inc()
+        step_ms = (self._unobserved + waited) * 1e3
+        self._unobserved = 0.0
+        _rtrace.DECODE_STEP_MS.observe(step_ms)
+        # a bounded worker's step knows its lengths only now, and before
+        # delivery retires slots and zeroes them
+        _CONTEXT_TOKENS.inc(
+            int(sum(sess.lengths[s] for s in toks))
+            if rec.context is None else rec.context)
+        _TOKENS.inc(self._deliver(si, sess, rec.mine, toks, now_pc,
+                                  step_ms))
+        return True
+
+    def _settle(self, si):
+        """Collect and deliver the step of session ``si`` that is
+        launched and uncollected, if there is one: what everything that
+        acts between two steps does first (a prefill's wait, a weight
+        swap, a rebuild's hand-over, the end of serving). Returns False
+        when that step failed."""
+        rec, self._inflight[si] = self._inflight[si], None
+        return rec is None or self._collect(si, self.sessions[si], rec)
 
     def _deliver(self, si, sess, mine, toks, now_pc, step_ms):
         """Hand a step's tokens to their requests; finish and retire
@@ -2338,6 +2727,12 @@ class GenerationScheduler:
         with self._host_phase("scheduler:deliver", "deliver",
                               active=len(mine)):
             for slot, it in mine:
+                if self._active.get((si, slot)) is not it:
+                    # ended since the launch, by what the tokens of the
+                    # step before said (EOS, a deadline): the one step
+                    # it ran beyond that is discarded, as a speculative
+                    # round's tokens past the end are below
+                    continue
                 if slot not in toks:
                     # pool exhausted for this sequence (no
                     # allocatable block even after eviction): it
@@ -2421,6 +2816,10 @@ class GenerationScheduler:
             return
         if any(s_i == si for (s_i, _) in self._active):
             return  # live requests still decoding there; next event
+        # a step still uncollected there stepped requests that have all
+        # ended since: nobody waits for it
+        self._inflight[si] = None
+        sess.drop_flights()
         self._rebuilding.add(si)
         self._rebuilds[si] += 1
         # a rebuild is incident-grade (quarantine became repair):
@@ -2603,6 +3002,11 @@ class GenerationScheduler:
         if pending is None:
             return
         params, future = pending
+        # what is launched was launched on the old weights: its tokens
+        # are delivered before the flip, so every token handed over after
+        # swap_weights() returns was computed on the new ones
+        for si in range(len(self.sessions)):
+            self._settle(si)
         try:
             scopes = []
             for sess in self.sessions:
@@ -2655,8 +3059,10 @@ class GenerationScheduler:
         """Install new parameter values (``{name: array}``) on every
         session's scope BETWEEN decode steps — the hot-swap story for
         stateful serving. The flip lands on a step boundary (the
-        dispatcher applies it before its next admit/step), so no
-        forward pass mixes versions; sequences already mid-generation
+        dispatcher applies it before its next admit/step, after it has
+        collected and delivered the step it had launched ahead), so no
+        forward pass mixes versions and no token delivered after this
+        returns comes from the old ones; sequences already mid-generation
         continue on the new weights, which is the documented semantic
         for session state (their KV cache keeps the old weights'
         values — retire-and-retry callers who need strict isolation).
@@ -2699,7 +3105,7 @@ class GenerationScheduler:
         while True:
             self._apply_pending_swap()
             self._absorb_rebuilds()
-            if self._active:
+            if self._busy():
                 self._step_all()
                 continue
             item = self._next_item(block=False)
@@ -2731,6 +3137,8 @@ class GenerationScheduler:
         retire here too: this epilogue is the one point EVERY
         shutdown shape reaches — including a drain() whose bounded
         join expired and whose caller never calls close()."""
+        for si in range(len(self.sessions)):
+            self._settle(si)    # a dispatcherless close(): see drain()
         self._turn_close(time.perf_counter())
         self._terminal = True
         self._drain_rebuilt()
@@ -2748,7 +3156,7 @@ class GenerationScheduler:
         while True:
             self._apply_pending_swap()
             self._absorb_rebuilds()
-            if self._active:
+            if self._busy():
                 self._expire_queued()
                 got_stop = self._fill_slots()
                 self._step_all()
@@ -2833,14 +3241,14 @@ class GenerationScheduler:
         # one-at-a-time would run each generation solo and forfeit
         # the batching this layer exists for)
         self._pending.extend(leftovers)
-        while self._pending or self._active:
+        while self._pending or self._busy():
             self._absorb_rebuilds()
             progressed = False
             while self._pending:
                 if not self._place(self._pending.popleft()):
                     break  # head parked again: a step must free slots
                 progressed = True
-            if self._active:
+            if self._busy():
                 self._step_all()
             elif not progressed and self._pending:
                 if self._recovery_pending(self._pending[0]):

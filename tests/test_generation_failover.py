@@ -187,8 +187,9 @@ class TestReplayFailover:
         try:
             fut = sched.submit([2, 3], max_new_tokens=9, eos_id=-1)
             assert sched._place(sched._next_item(block=False))
-            for _ in range(4):
-                sched._step_all()       # 5 tokens generated
+            for _ in range(5):
+                sched._step_all()       # 5 tokens delivered, a step ahead
+            assert len(next(iter(sched._active.values())).tokens) == 5
             p0 = _counter("paddle_generation_prefills_total",
                           bucket="8")
             faults.arm("generation_step_fail", times=1)

@@ -250,6 +250,8 @@ def test_requests_got_what_they_asked_for(decode_run):
     ("session:step_prepare", "scheduler:host_turn"),
     ("session:step_dispatch", "scheduler:host_turn"),
     ("session:prefill_call", "scheduler:admit"),
+    ("scheduler:first_token", "scheduler:host_turn"),
+    ("session:prefill_wait", "scheduler:first_token"),
 ])
 def test_span_nests_in_its_parent_of_the_same_round(decode_run, child,
                                                     parent):
@@ -268,11 +270,12 @@ def test_span_nests_in_its_parent_of_the_same_round(decode_run, child,
       "executor:fetch": 0}),
     ("session:prefill_call",
      {"executor:prepare": 1, "executor:call": 1, "executor:writeback": 1,
-      "executor:fetch": 1}),
+      "executor:fetch": 0}),
 ])
 def test_device_call_holds_the_executors_spans(decode_run, call, holds):
     """A decode call is dispatched and not fetched (``session:step_wait``
-    is the fetch); a prefill fetches its first token itself."""
+    is the fetch), and so is a prefill (``session:prefill_wait``): a
+    decode step queued ahead of it is collected between the two."""
     calls = _named(decode_run, call)
     assert calls
     for c in calls:
@@ -382,5 +385,6 @@ def test_dispatcher_spans_stay_on_the_dispatchers_thread(decode_run):
     assert {"scheduler:host_turn", "scheduler:deliver", "scheduler:admit",
             "scheduler:idle_wait", "session:step_prepare",
             "session:step_dispatch", "session:step_wait",
-            "session:prefill_call", "executor:prepare", "executor:call",
-            "executor:writeback", "executor:fetch"} <= names
+            "session:prefill_call", "session:prefill_wait",
+            "scheduler:first_token", "executor:prepare", "executor:call",
+            "executor:writeback"} <= names
