@@ -7,7 +7,8 @@ from ..initializer import ConstantInitializer, NormalInitializer
 from . import nn as _nn
 from . import ops as _ops
 
-__all__ = ["rms_norm", "rotary_embedding", "linear", "swiglu", "moe_ffn"]
+__all__ = ["rms_norm", "rotary_embedding", "linear", "swiglu", "moe_ffn",
+           "mla_attention"]
 
 
 def rms_norm(x, epsilon=1e-5, group_size=0, param_attr=None, name=None,
@@ -29,18 +30,23 @@ def rms_norm(x, epsilon=1e-5, group_size=0, param_attr=None, name=None,
 
 
 def rotary_embedding(x, head_dim, theta=10000.0, pos=None, per_row=False,
-                     name=None, **kwargs):
+                     lanes=None, yarn=None, name=None, **kwargs):
     """Rotary positions on x [B, T, H*D]: ``pos`` [T] along the time axis
-    (absent: 0..T-1), or with ``per_row`` [B], one per batch row."""
+    (absent: 0..T-1), or with ``per_row`` [B], one per batch row. ``lanes``
+    (lo, hi): only these lanes of each head turn; ``yarn``: the YaRN
+    scaling of the frequencies (ops/moe_ops.py ``rotary_frequencies``)."""
     helper = LayerHelper("rotary_embedding", name=name, **kwargs)
     inputs = {"X": [x.name]}
     if pos is not None:
         inputs["Pos"] = [pos.name]
+    attrs = {"head_dim": head_dim, "theta": theta, "per_row": per_row}
+    if lanes is not None:
+        attrs["lanes"] = tuple(lanes)
+    if yarn:
+        attrs["yarn"] = dict(yarn)
     out = helper.create_tmp_variable(x.dtype)
     helper.append_op(type="rotary_embedding", inputs=inputs,
-                     outputs={"Out": [out.name]},
-                     attrs={"head_dim": head_dim, "theta": theta,
-                            "per_row": per_row})
+                     outputs={"Out": [out.name]}, attrs=attrs)
     return out
 
 
@@ -106,3 +112,36 @@ def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
                "route_norm": route_norm, "route_scale": route_scale,
                "expert_offset": expert_offset})
     return out, counts
+
+
+def mla_attention(q, c, k_rope, num_heads, nope_dim, rope_dim, v_dim, scale,
+                  param_attr, dtype=None, std=0.02, block_rows=None,
+                  cache=None, pos=None, table=None, **kwargs):
+    """Latent attention (ops/mla_ops.py) of rotated queries q
+    [B, T, H*(nope + rope)] over the latents c [B, T, kv_rank] (normed) and
+    k_rope [B, T, rope] (rotated), through the up-projection ``param_attr``
+    [kv_rank, H*(nope + v)] held in ``dtype``: over the sequence's own
+    rows, expanded, ``block_rows`` at a time; or with ``cache`` (the paged
+    latent pool the rows were written to), ``pos`` and ``table``, one
+    query a slot over its cached rows, absorbed. -> [B, T, H*v] float32."""
+    helper = LayerHelper("mla_attention", **kwargs)
+    w = helper.create_parameter(
+        param_attr, shape=[c.shape[-1], num_heads * (nope_dim + v_dim)],
+        dtype=dtype or q.dtype, default_initializer=NormalInitializer(0.0, std))
+    attrs = {"num_heads": num_heads, "nope_dim": nope_dim,
+             "rope_dim": rope_dim, "v_dim": v_dim, "scale": scale}
+    out = helper.create_tmp_variable("float32")
+    if cache is None:
+        helper.append_op(
+            type="mla_attention",
+            inputs={"Q": [q.name], "C": [c.name], "KRope": [k_rope.name],
+                    "WUKV": [w.name]},
+            outputs={"Out": [out.name]},
+            attrs=dict(attrs, block_rows=block_rows))
+    else:
+        helper.append_op(
+            type="mla_attention_decode_paged",
+            inputs={"Q": [q.name], "Cache": [cache.name], "Pos": [pos.name],
+                    "Table": [table.name], "WUKV": [w.name]},
+            outputs={"Out": [out.name]}, attrs=attrs)
+    return out

@@ -1,19 +1,29 @@
-"""A decoder LM driven by a per-layer spec: grouped-query attention that is
-full or windowed layer by layer, RMSNorm before and after each half,
-rotary positions in the window layers, a gated attention output, and a
-feed-forward that is dense in the leading layers and sparse experts with a
-shared expert in the rest (the ``afmoe`` family; the equations are in
-``benchmarks/reference/afmoe.py``).
+"""A decoder LM driven by a per-layer spec and the shape of its block, given
+as data: a feed-forward that is dense in the leading layers and sparse
+experts with a shared expert in the rest, behind an attention that is
+
+* ``gqa``: grouped queries, full or windowed layer by layer, rotary
+  positions in the window layers, RMSNorm before and after each half, a
+  gated attention output and a scaled embedding (the ``afmoe`` family; the
+  equations are in ``benchmarks/reference/afmoe.py``); or
+* ``latent``: MLA (``ops/mla_ops.py``) with low-rank queries, YaRN rotary
+  positions on the rope lanes, RMSNorm before each half only, no gate and
+  no embedding scale (the ``kimi_k2`` / DeepSeek-V3 family;
+  ``benchmarks/reference/kimi_k2.py``).
 
 :func:`moe_lm` is the whole-sequence forward (its startup program makes the
 weights); :func:`moe_lm_session` builds the paged prefill and decode
 programs through ``transformer.lm_session``, with one kind of layer cache
 for the full layers and one, which frees blocks behind the window, for the
-window layers. Matmul weights, the embedding and the head are created and
+window layers, or for latent attention the one **latent kind**: one pool a
+layer whose row is a token's ``(c, k_r)`` padded to whole lane tiles.
+Matmul weights, the embedding and the head are created and
 held in ``param_dtype``; norms, router and expert bias are float32, and so
 is every activation: the products are exact (ops/moe_ops.py says why), so
 the layer caches should be float32 too.
 """
+
+import math
 
 from .. import layers
 from ..layer_helper import LayerHelper
@@ -37,12 +47,16 @@ class MoeLM:
                  num_dense_layers, sliding_window, rope_theta=10000.0,
                  rms_eps=1e-5, route_norm=True, route_scale=1.0,
                  embed_scale=1.0, param_dtype="float32", expert_offset=0,
-                 experts_held=None, init_std=0.02):
+                 experts_held=None, init_std=0.02, attention="gqa",
+                 post_norms=True, latent=None, rope_scaling=None):
         unknown = set(layer_types) - {SLIDING, FULL}
         if unknown:
             raise ValueError("layer_types holds %s: a layer is %r or %r"
                              % (sorted(unknown), SLIDING, FULL))
-        if num_heads % num_kv_heads:
+        if attention not in ("gqa", "latent"):
+            raise ValueError("attention is 'gqa' or 'latent', not %r"
+                             % (attention,))
+        if attention == "gqa" and num_heads % num_kv_heads:
             raise ValueError("%d query heads on %d KV heads"
                              % (num_heads, num_kv_heads))
         self.vocab_size = vocab_size
@@ -58,6 +72,14 @@ class MoeLM:
         self.embed_scale, self.dtype = embed_scale, param_dtype
         self.expert_offset, self.experts_held = expert_offset, experts_held
         self.std = init_std
+        self.attention, self.post_norms = attention, post_norms
+        self.yarn = rope_scaling
+        # expert pairs a row of a step routes, held here or not
+        self.pairs_per_row = top_k * (len(self.layer_types)
+                                      - num_dense_layers)
+        if attention == "latent":
+            self._latent_sizes(**latent)
+            return
         # the kinds of layer cache, full first where the model has both;
         # per layer the width of a cached row and its kind
         present = [t for t in (FULL, SLIDING) if t in self.layer_types]
@@ -65,6 +87,30 @@ class MoeLM:
                            ("window", sliding_window) for t in present)
         self.cache_layers = [(num_kv_heads * head_dim, present.index(t))
                              for t in self.layer_types]
+
+    def _latent_sizes(self, q_rank, kv_rank, nope_dim, rope_dim, v_dim):
+        """Latent attention's widths, its one kind of layer cache and the
+        scale of its scores: ``(nope + rope)^-1/2``, times the square of
+        YaRN's ``0.1 mscale_all_dim ln(factor) + 1`` where the positions
+        are scaled."""
+        if set(self.layer_types) != {FULL}:
+            raise ValueError("latent attention has no window layers")
+        self.q_rank, self.kv_rank = q_rank, kv_rank
+        self.nope, self.rope, self.v_dim = nope_dim, rope_dim, v_dim
+        m = 1.0
+        if self.yarn and self.yarn.get("mscale_all_dim"):
+            m = 0.1 * self.yarn["mscale_all_dim"] * \
+                math.log(self.yarn["factor"]) + 1.0
+        self.attn_scale = (nope_dim + rope_dim) ** -0.5 * m * m
+        # a cached row is (c, k_r) and zeros up to whole lane tiles: one
+        # pool a layer, read once a page as key and value
+        self.row_width = -(-(kv_rank + rope_dim) // 128) * 128
+        self.kinds = (("latent", None),)
+        self.cache_pools = ("c",)
+        self.cache_layers = [(self.row_width, 0)] * len(self.layer_types)
+        # a prompt's rows attend the prompt's own latents: nothing cached
+        # before them is seen
+        self.prefill_sees_history = False
 
     # -- the block ---------------------------------------------------------
     def _norm(self, x, name, group_size=0):
@@ -75,9 +121,61 @@ class MoeLM:
         return layers.linear(x, size, "moe_lm.%s.w" % name, self.dtype,
                              self.std)
 
+    def _positions(self, ctx):
+        """The rotary layer's position arguments for a program's mode."""
+        if ctx is None:
+            return {}
+        decode = ctx["mode"] == "decode"
+        return dict(pos=ctx["pos"] if decode else ctx["pos_idx"],
+                    per_row=decode)
+
+    def _latent_attention(self, a, i, ctx):
+        """a [B, T, d] -> latent attention's output [B, T, H*v]: expanded
+        over the rows' own latents (whole sequences, a prefill), absorbed
+        over the layer's paged latent pool (a decode step)."""
+        p = "l%d.attn." % i
+        nh, nope, rope = self.nh, self.nope, self.rope
+        c_q = self._norm(self._linear(a, self.q_rank, p + "q_a"),
+                         p + "q_a_norm")
+        q = self._linear(c_q, nh * (nope + rope), p + "q_b")
+        ckr = self._linear(a, self.kv_rank + rope, p + "kv_a")
+        c = self._norm(layers.slice(ckr, [2], [0], [self.kv_rank]),
+                       p + "kv_a_norm")
+        k_r = layers.slice(ckr, [2], [self.kv_rank], [self.kv_rank + rope])
+        turn = dict(self._positions(ctx), theta=self.theta, yarn=self.yarn)
+        q = layers.rotary_embedding(q, nope + rope,
+                                    lanes=(nope, nope + rope), **turn)
+        k_r = layers.rotary_embedding(k_r, rope, **turn)
+        attend = dict(num_heads=nh, nope_dim=nope, rope_dim=rope,
+                      v_dim=self.v_dim, scale=self.attn_scale,
+                      param_attr="moe_lm.%skv_b.w" % p, dtype=self.dtype,
+                      std=self.std)
+        if ctx is not None:
+            # the token's row into the layer's pool: (c, k_r, zeros)
+            pool, = ctx["caches"][i]
+            row = layers.pad(layers.concat([c, k_r], axis=2),
+                             [0, 0, 0, 0, 0,
+                              self.row_width - self.kv_rank - rope])
+            decode = ctx["mode"] == "decode"
+            where = {"Pos": [ctx["pos"].name]} if decode else \
+                {"Hist": [ctx["hist"].name], "Len": [ctx["key_length"].name]}
+            LayerHelper("moe_lm_attention").append_op(
+                type="kv_cache_append_paged" if decode
+                else "kv_cache_write_paged",
+                inputs=dict(where, Cache=[pool.name], New=[row.name],
+                            Table=[ctx["table"].name]),
+                outputs={"Out": [pool.name]})
+            if decode:
+                return layers.mla_attention(
+                    q, c, k_r, cache=pool, pos=ctx["pos"],
+                    table=ctx["table"], **attend)
+        return layers.mla_attention(q, c, k_r, block_rows=512, **attend)
+
     def _attention(self, a, i, ctx):
         """a [B, T, d] -> the gated attention output [B, T, H*D], through
         the layer's paged cache where ``ctx`` has one."""
+        if self.attention == "latent":
+            return self._latent_attention(a, i, ctx)
         p = "l%d.attn." % i
         windowed = self.layer_types[i] == SLIDING
         q = self._linear(a, self.nh * self.hd, p + "q")
@@ -88,11 +186,8 @@ class MoeLM:
         k = self._norm(k, p + "k_norm", self.hd)
         if windowed:
             # positions only where the window bounds what they span
-            rope = dict(head_dim=self.hd, theta=self.theta)
-            if ctx is not None:
-                decode = ctx["mode"] == "decode"
-                rope.update(pos=ctx["pos"] if decode else ctx["pos_idx"],
-                            per_row=decode)
+            rope = dict(self._positions(ctx), head_dim=self.hd,
+                        theta=self.theta)
             q = layers.rotary_embedding(q, **rope)
             k = layers.rotary_embedding(k, **rope)
         helper = LayerHelper("moe_lm_attention")
@@ -154,18 +249,22 @@ class MoeLM:
                 name="moe_lm.embed.w",
                 initializer=NormalInitializer(0.0, self.std)),
             keep_dims=True)
-        h = layers.scale(layers.cast(emb, "float32"), self.embed_scale)
+        h = layers.cast(emb, "float32")
+        if self.embed_scale is not None:
+            h = layers.scale(h, self.embed_scale)
         all_counts = []
         for i in range(len(self.layer_types)):
             a = self._norm(h, "l%d.norm_in" % i)
             o = self._linear(self._attention(a, i, ctx), self.d,
                              "l%d.attn.o" % i)
-            h = layers.elementwise_add(
-                h, self._norm(o, "l%d.norm_post_attn" % i))
+            if self.post_norms:
+                o = self._norm(o, "l%d.norm_post_attn" % i)
+            h = layers.elementwise_add(h, o)
             f, counts = self._feed_forward(
                 self._norm(h, "l%d.norm_pre_mlp" % i), i)
-            h = layers.elementwise_add(
-                h, self._norm(f, "l%d.norm_post_mlp" % i))
+            if self.post_norms:
+                f = self._norm(f, "l%d.norm_post_mlp" % i)
+            h = layers.elementwise_add(h, f)
             if counts is not None:
                 all_counts.append(counts)
         return h, all_counts

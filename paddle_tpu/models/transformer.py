@@ -304,10 +304,17 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     ``model`` offers ``vocab_size``; ``kinds``, its kinds of layer cache
     as (name, window) pairs, the first the one ``num_blocks`` sizes;
     ``cache_layers``, per layer the width of a cached row and the index
-    of its kind; ``logits(tokens, cache_ctx)`` -> [B, T, V];
+    of its kind; optionally ``cache_pools``, the pools a layer has (default
+    ``("k", "v")``; a latent kind has one, ``("c",)``, whose row is key and
+    value at once) and ``prefill_sees_history`` (False: a prefill attends
+    its window's own rows only, so neither a shared prefix nor a
+    speculative verify can be built on it);
+    ``logits(tokens, cache_ctx)`` -> [B, T, V];
     ``prefill_row(tokens, last_pos, cache_ctx)`` -> [1, V];
     ``decode_row(tokens, cache_ctx)`` -> ([slots, V], expert counts or
-    None); ``draft(overrides)`` -> the speculative draft's model.
+    None; with counts the model offers ``pairs_per_row``, the expert pairs
+    a row routes in a step, held here or not); ``draft(overrides)`` ->
+    the speculative draft's model.
     ``kind_blocks`` sizes the pools of the kinds after the first, by
     name; their table feeds are ``gen.ptab.<name>`` / ``gen.dtab.<name>``
     and reach the model as ``cache_ctx["tables"]``, one per kind."""
@@ -325,6 +332,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     sampled = policy is not None and policy.sampled
     constraint = None if policy is None else policy.constraint
     spec_k = 0 if policy is None else policy.speculate_k
+    pools = tuple(getattr(model, "cache_pools", ("k", "v")))
 
     if slots is None:
         slots = int(_config.get_flag("generation_slots"))
@@ -370,6 +378,11 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     num_blocks = int(num_blocks) or slots * max_blocks
     if prefix_cache is None:
         prefix_cache = bool(_config.get_flag("generation_prefix_cache"))
+    if not getattr(model, "prefill_sees_history", True) and (
+            spec_k or prefix_cache):
+        raise ValueError(
+            "this model's prefill attends the window's own rows, not the "
+            "cache: it takes neither prefix_cache nor speculate_k")
     rows = [num_blocks] + [int((kind_blocks or {})[name])
                            for name, _ in kinds[1:]]
     cache_shapes = [(rows[k], block_size, width)
@@ -377,16 +390,10 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
 
     def make_cache_vars(program):
         block = program.global_block()
-        caches = []
-        for i, cache_shape in enumerate(cache_shapes):
-            ck = block.create_var(name="%s.l%d.k" % (cache_ns, i),
-                                  shape=cache_shape, dtype=dtype,
-                                  persistable=True, stop_gradient=True)
-            cv = block.create_var(name="%s.l%d.v" % (cache_ns, i),
-                                  shape=cache_shape, dtype=dtype,
-                                  persistable=True, stop_gradient=True)
-            caches.append((ck, cv))
-        return caches
+        return [tuple(block.create_var(
+            name="%s.l%d.%s" % (cache_ns, i, pool), shape=cache_shape,
+            dtype=dtype, persistable=True, stop_gradient=True)
+            for pool in pools) for i, cache_shape in enumerate(cache_shapes)]
 
     def more_tables(prefix, shape):
         """The table feeds of the kinds after the first, with the first
@@ -492,9 +499,9 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
                            append_batch_size=False)
         cblock = copy_program.global_block()
         # the first kind's layers: only its blocks are ever shared
-        for (ck, cv), (_, k) in zip(make_cache_vars(copy_program),
-                                    model.cache_layers):
-            for cvar in (ck, cv) if k == 0 else ():
+        for cvars, (_, k) in zip(make_cache_vars(copy_program),
+                                 model.cache_layers):
+            for cvar in cvars if k == 0 else ():
                 cblock.append_op(
                     type="kv_block_copy",
                     inputs={"Cache": [cvar.name],
@@ -570,7 +577,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
             kind_blocks=kind_blocks)
 
     cache_kinds = None
-    if len(kinds) > 1 or kinds[0][1]:
+    if len(kinds) > 1 or kinds[0][1] or kinds[0][0] != "full":
         from ..serving.paged_cache import CacheKind
         cache_kinds = tuple(
             CacheKind(name, window, rows[k],
@@ -585,7 +592,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         cache_vars=tuple(("%s.l%d.%s" % (cache_ns, i, kv), cache_shape,
                           dtype)
                          for i, cache_shape in enumerate(cache_shapes)
-                         for kv in ("k", "v")),
+                         for kv in pools),
         prefill_programs=prefill_programs,
         prefill_feeds=("gen.ptok", "gen.plen", "gen.ppos", "gen.phist",
                        "gen.ppix", "gen.ptab") + prefill_extra,
@@ -601,4 +608,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         verify_program=verify_program, verify_feeds=verify_feeds,
         verify_fetch=verify_fetch, draft_spec=draft_spec,
         cache_kinds=cache_kinds,
-        stats_fetch=None if stats is None else stats.name)
+        stats_fetch=None if stats is None else stats.name,
+        routed_pairs=None if stats is None else slots * model.pairs_per_row,
+        latent_layers=sum(kinds[k][0] == "latent"
+                          for _, k in model.cache_layers))

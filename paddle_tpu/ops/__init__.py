@@ -20,6 +20,7 @@ from . import (  # noqa: F401
     attention_ops,
     generation_ops,
     moe_ops,
+    mla_ops,
     decoding_ops,
     crf_ctc_ops,
     beam_search_ops,

@@ -27,6 +27,9 @@ the live prefix. A sequence's logical position p lives at pool row
   program per slot walks that slot's live pages, all heads at once)
   when ``flash_attention`` is on, an XLA gather sharing identical
   semantics otherwise — the flag never changes tokens.
+  Latent attention's two paths over a latent pool (one row a token, key
+  and value at once) are in ``mla_ops``; its rows are written by the two
+  write ops above, a width being all that differs.
 * ``kv_block_copy`` — one block pool-to-pool (copy-on-write: a
   sequence about to write into a shared block copies it first).
 

@@ -23,7 +23,8 @@ pays three times the products.
 * ``rotary_embedding`` — rotary positions on ``[B, T, H*D]`` in the
   half-split pairing (lane ``i`` of a head turns with lane ``i + D/2``),
   positions along the time axis (a prompt window, a training batch) or one
-  per batch row (a decode step).
+  per batch row (a decode step); optionally over a range of each head's
+  lanes only, with YaRN-blended frequencies (:func:`rotary_frequencies`).
 * ``moe_ffn`` — route, sort by expert, grouped matmul (exact), weighted
   combine. The grouped matmuls run in ``pallas_moe``'s kernels, which
   stream each touched expert's weights once, where the call's shapes pass
@@ -36,8 +37,14 @@ pays three times the products.
   ``num_experts`` and returns the part of the result that the experts it
   holds, ``[expert_offset, expert_offset + E_held)``, give; the parts of
   disjoint holders add up to the whole layer. Beside the result it returns
-  how many pairs each held expert took.
+  how many pairs each held expert took. A holder of every expert takes all
+  ``n * top_k`` pairs in one pass; **a holder of a share** takes the pairs
+  that fall on its experts, which lie first in the sorted order, in passes
+  of at most ``SHARE_ROWS`` rows until none is left: its work and its
+  temporaries follow the held pairs, and still no pair is dropped.
 """
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -121,28 +128,56 @@ def _rms_norm(ctx):
     return {"Y": y.reshape(shape)}
 
 
+def rotary_frequencies(rot, theta, yarn=None):
+    """The ``rot // 2`` angular frequencies of a rotary turn over ``rot``
+    lanes: ``theta^(-2i/rot)``, and with ``yarn`` (``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``)
+    the YaRN blend: pair ``i`` keeps its frequency below ``lo``, is
+    slowed by ``factor`` above ``hi`` and is between the two in between,
+    where ``lo`` / ``hi`` are the pairs that turn ``beta_fast`` /
+    ``beta_slow`` times over the original context."""
+    half = rot // 2
+    i = jnp.arange(half, dtype=jnp.float32)
+    freq = theta ** (-i * 2.0 / rot)
+    if not yarn:
+        return freq
+
+    def corr(turns):
+        return rot * math.log(yarn["original_max_position_embeddings"]
+                              / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+    lo = max(math.floor(corr(yarn["beta_fast"])), 0)
+    hi = min(math.ceil(corr(yarn["beta_slow"])), rot - 1)
+    ramp = jnp.clip((i - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return freq * (1.0 - ramp) + freq / yarn["factor"] * ramp
+
+
 @register_op("rotary_embedding")
 def _rotary_embedding(ctx):
     """X [B, T, H*D], Pos int (optional): [T] positions along the time
     axis, or with attr ``per_row`` [B], one position per batch row; absent,
-    the positions are 0..T-1. attrs head_dim, theta. Out: X's shape and
-    dtype, the angles taken in float32."""
+    the positions are 0..T-1. attrs head_dim, theta; where the model has
+    them ``lanes`` (lo, hi): the lanes of each head that turn (absent:
+    all; the others pass), and ``yarn`` (:func:`rotary_frequencies`).
+    Out: X's shape and dtype, the angles taken in float32."""
     x = ctx.input("X")
     hd = ctx.attr("head_dim")
     theta = ctx.attr("theta", 10000.0)
+    lo, hi = ctx.attr("lanes") or (0, hd)
     b, t, dm = x.shape
     if ctx.has_input("Pos"):
         pos = ctx.input("Pos").reshape(-1).astype(jnp.float32)
         pos = pos.reshape((b, 1) if ctx.attr("per_row", False) else (1, t))
     else:
         pos = jnp.arange(t, dtype=jnp.float32).reshape(1, t)
-    half = hd // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / hd)
+    half = (hi - lo) // 2
+    freq = rotary_frequencies(hi - lo, theta, ctx.attr("yarn"))
     ang = pos[..., None, None] * freq                   # [b|1, 1|t, 1, half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xh = x.astype(jnp.float32).reshape(b, t, dm // hd, hd)
-    x1, x2 = xh[..., :half], xh[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    x1, x2 = xh[..., lo:lo + half], xh[..., lo + half:hi]
+    out = jnp.concatenate([xh[..., :lo], x1 * cos - x2 * sin,
+                           x2 * cos + x1 * sin, xh[..., hi:]], -1)
     return {"Out": out.reshape(b, t, dm).astype(x.dtype)}
 
 
@@ -158,6 +193,31 @@ def route(x, router_w, bias, top_k, route_norm, route_scale):
     if route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return sel, w * route_scale
+
+
+# Rows of one pass over the held experts where the op holds a share of
+# them: what the 4,096-token prefill of a holder of 12 of 384 experts at
+# top-8 expects twice over (4096 * 8 * 12 / 384 = 1,024 pairs)
+SHARE_ROWS = 2048
+
+
+def _expert_rows(xs, wg, wu, wd, counts):
+    """``(silu(xs WGate) * (xs WUp)) WDown`` of rows sorted by expert,
+    ``counts`` [E_held] rows an expert from row 0: xs [r, d] -> [r, d]
+    float32, zeros in the rows past the last expert's. Through
+    ``pallas_moe``'s kernels where they admit the shapes."""
+    if pallas_moe.admits(xs.shape[0], wg):
+        interpret = kernel_path.interpret_mode()
+        kernel_path.record("moe_grouped_matmul", interpret)
+        ys = pallas_moe.expert_ffn(xs, wg, wu, wd, counts, interpret)
+    else:
+        kernel_path.record("moe_grouped_matmul")
+        inner = jax.nn.silu(exact_ragged_dot(xs, wg, counts)) * \
+            exact_ragged_dot(xs, wu, counts)
+        ys = exact_ragged_dot(inner, wd, counts)        # [r, d] float32
+    # rows past the last group are nobody's: whatever they hold, they add 0
+    return jnp.where((jnp.arange(xs.shape[0]) < jnp.sum(counts))[:, None],
+                     ys, 0.0)
 
 
 @register_op("moe_ffn")
@@ -189,20 +249,37 @@ def _moe_ffn(ctx):
     key = jnp.where(mine, local, held)
     order = jnp.argsort(key)                            # stable
     counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-    xs = x2[order // k]
-    if pallas_moe.admits(n * k, wg):
-        interpret = kernel_path.interpret_mode()
-        kernel_path.record("moe_grouped_matmul", interpret)
-        ys = pallas_moe.expert_ffn(xs, wg, wu, wd, counts, interpret)
-    else:
-        kernel_path.record("moe_grouped_matmul")
-        inner = jax.nn.silu(exact_ragged_dot(xs, wg, counts)) * \
-            exact_ragged_dot(xs, wu, counts)
-        ys = exact_ragged_dot(inner, wd, counts)        # [n*k, d] float32
-    # rows past the last group are nobody's: whatever they hold, they add 0
-    ys = jnp.where((jnp.arange(n * k) < jnp.sum(counts))[:, None], ys, 0.0)
-    # back to the pairs' own order, then the weighted sum over a token's k
     pair_w = jnp.where(mine, w.reshape(-1), 0.0)
-    y = ys[jnp.argsort(order)] * pair_w[:, None]
-    return {"Out": jnp.sum(y.reshape(n, k, d), axis=1).reshape(x.shape),
-            "Counts": counts}
+    # a share's pass is never longer than the kernels were measured for
+    rows = n * k if held == ctx.attr("num_experts") else \
+        min(n * k, SHARE_ROWS, pallas_moe.MAX_PAIRS_PER_EXPERT * held)
+    if rows == n * k:
+        # every pair in one pass
+        ys = _expert_rows(x2[order // k], wg, wu, wd, counts)
+        # back to the pairs' own order, then the weighted sum over a
+        # token's k
+        y = ys[jnp.argsort(order)] * pair_w[:, None]
+        out = jnp.sum(y.reshape(n, k, d), axis=1)
+    else:
+        # a share of the experts takes a share of the pairs: the held
+        # pairs lie first in the sorted order, and they are taken ``rows``
+        # at a time until none is left. However many there are, none is
+        # dropped, and the work and the temporaries are a pass's
+        total = jnp.sum(counts)
+        end = jnp.cumsum(counts)
+        start = end - counts
+        order = jnp.pad(order, (0, rows))
+
+        def one_pass(i, out):
+            first = i * rows
+            pairs = jax.lax.dynamic_slice(order, (first,), (rows,))
+            here = jnp.clip(jnp.minimum(end, first + rows)
+                            - jnp.maximum(start, first), 0, rows)
+            ys = _expert_rows(x2[pairs // k], wg, wu, wd, here)
+            ys = jnp.where((first + jnp.arange(rows) < total)[:, None],
+                           ys * pair_w[pairs][:, None], 0.0)
+            return out.at[pairs // k].add(ys)
+
+        out = jax.lax.fori_loop(0, (total + rows - 1) // rows, one_pass,
+                                jnp.zeros((n, d), jnp.float32))
+    return {"Out": out.reshape(x.shape), "Counts": counts}

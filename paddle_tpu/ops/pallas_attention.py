@@ -303,7 +303,8 @@ def cache_precision(cache_dtype):
 
 
 def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
-                            num_heads, num_kv_heads=None, window=None):
+                            num_heads, num_kv_heads=None, window=None,
+                            v_width=None, scale=None):
     """Dense XLA single-query attention over a PAGED cache: q [S, 1, H*D]
     (one query token per slot), k/v pools [NB, BS, Hkv*D], lengths [S]
     (live rows per slot), tables [S, MB] block ids mapping slot s's
@@ -315,6 +316,10 @@ def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
     attendable iff ``length - window <= j < length``: the query sits at
     ``length - 1`` and sees itself and the ``window - 1`` rows before it;
     rows behind the window may sit in blocks the table no longer names.
+    With ``v_width`` there is no V pool (``v_pool`` None): the pool holds
+    one head whose value is the leading ``v_width`` lanes of its key's
+    own row, and the result is [S, 1, H*v_width]. ``scale`` multiplies
+    the scores (default ``D ** -0.5``).
     The flag-off fallback AND the numeric contract the paged kernel must
     match: after the gather this is exactly :func:`_decode_reference` on
     the logical [S, MB*BS] cache."""
@@ -325,46 +330,51 @@ def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
     c = mb * bs
     hd = dm // num_heads
     tbl = jnp.clip(tables.astype(jnp.int32), 0, nb - 1)
+    rows = k_pool[tbl]
+    values = rows[..., :v_width] if v_width else v_pool[tbl]
     # [S, C, Hkv, hd] -> every query head beside its KV head's rows
-    kh = jnp.repeat(k_pool[tbl].reshape(s, c, nkv, hd), num_heads // nkv,
+    kh = jnp.repeat(rows.reshape(s, c, nkv, hd), num_heads // nkv,
                     axis=2).transpose(0, 2, 1, 3)
-    vh = jnp.repeat(v_pool[tbl].reshape(s, c, nkv, hd), num_heads // nkv,
+    vh = jnp.repeat(values.reshape(s, c, nkv, -1), num_heads // nkv,
                     axis=2).transpose(0, 2, 1, 3)
     qh = q.reshape(s * num_heads, 1, hd)
     kh = kh.reshape(s * num_heads, c, hd)
-    vh = vh.reshape(s * num_heads, c, hd)
+    vh = vh.reshape(s * num_heads, c, vh.shape[-1])
     lens = jnp.broadcast_to(
         jnp.asarray(lengths).reshape(s, 1), (s, num_heads)).reshape(-1)
-    if window is None:
+    if window is None and not v_width and scale is None:
         return _decode_reference(qh, kh, vh, lens).reshape(s, 1, dm)
     prec = cache_precision(k_pool.dtype)
     sc = jnp.einsum("bqd,bkd->bqk", qh, kh, precision=prec,
-                    preferred_element_type=jnp.float32) * (hd ** -0.5)
+                    preferred_element_type=jnp.float32) \
+        * (hd ** -0.5 if scale is None else scale)
     cols = jnp.arange(c)[None, None, :]
-    mask = (cols < lens[:, None, None]) & \
-        (cols >= lens[:, None, None] - window)
+    mask = cols < lens[:, None, None]
+    if window is not None:
+        mask = mask & (cols >= lens[:, None, None] - window)
     p = jax.nn.softmax(jnp.where(mask, sc, _NEG), axis=-1).astype(q.dtype)
     return jnp.einsum("bqk,bkd->bqd", p, vh,
-                      precision=prec).reshape(s, 1, dm)
+                      precision=prec).reshape(s, 1, -1)
 
 
 # VMEM for the paged kernel's page buffers: K and V, each double-buffered
 _PAGED_BUFFER_BYTES = 2 << 20
 
 
-def _paged_block_pages(block_size, d_model, dtype, max_blocks):
+def _paged_block_pages(block_size, d_model, dtype, max_blocks, buffers=4):
     """Pages (pool blocks) the paged decode kernel fetches and attends
-    at once: what the four page buffers hold within
-    ``_PAGED_BUFFER_BYTES``, and no more than a table row has. 8 pages
-    (128 rows) for bf16 blocks of 16 x 2048."""
+    at once: what the page buffers (K and V twice over, or with one pool
+    for both its rows twice over) hold within ``_PAGED_BUFFER_BYTES``,
+    and no more than a table row has. 8 pages (128 rows) for bf16 blocks
+    of 16 x 2048."""
     page = block_size * d_model * jnp.dtype(dtype).itemsize
-    return int(max(1, min(max_blocks, _PAGED_BUFFER_BYTES // (4 * page))))
+    return int(max(1, min(max_blocks,
+                          _PAGED_BUFFER_BYTES // (buffers * page))))
 
 
-def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
-                         kbuf, vbuf, sem, base_ref, *, block_size,
-                         max_blocks, num_blocks, pages, num_heads,
-                         num_kv_heads, window, scale):
+def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
+                         block_size, max_blocks, num_blocks, pages,
+                         num_heads, num_kv_heads, window, scale, v_width):
     """One slot of single-query flash decode THROUGH a block table, all
     heads at once. The pools stay in HBM; the program walks its slot's
     LIVE pages only, ``pages`` of them a compute block, each page
@@ -378,6 +388,11 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
     first live table entry is dead (>= NB: inactive or starved) has no
     pages: it fetches nothing and writes zeros.
 
+    With ``v_width`` the pool holds ONE head whose value is the leading
+    ``v_width`` lanes of its key's own row (a latent cache): there is no
+    V pool and no V buffer, a page is fetched once and used as both, and
+    the output is ``[H, v_width]``.
+
     Heads share one pass over a block through a block-diagonal query
     ``[H, Hkv*D]``: row h holds head h's lanes in the columns of KV head
     ``h // (H / Hkv)``, so scores are ``[H, rows]``, ``P @ V`` is
@@ -387,9 +402,15 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
     The surplus products are free: the kernel runs at the copies'
     speed."""
     from jax.experimental.pallas import tpu as pltpu
+    if v_width:
+        o_ref, kbuf, sem, base_ref = refs
+        vp_ref, vbuf = None, kbuf
+    else:
+        vp_ref, o_ref, kbuf, vbuf, sem, base_ref = refs
     bs, mb, nb = block_size, max_blocks, num_blocks
     si, ns = pl.program_id(0), pl.num_programs(0)
     dkv = kbuf.shape[-1]
+    dv = v_width or dkv
     hd = dkv // num_kv_heads
     group = num_heads // num_kv_heads
     rows = pages * bs
@@ -420,8 +441,9 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
             dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
             act(pltpu.make_async_copy(
                 kp_ref.at[page], kbuf.at[buf, dst], sem.at[0, buf]))
-            act(pltpu.make_async_copy(
-                vp_ref.at[page], vbuf.at[buf, dst], sem.at[1, buf]))
+            if vp_ref is not None:
+                act(pltpu.make_async_copy(
+                    vp_ref.at[page], vbuf.at[buf, dst], sem.at[1, buf]))
         jax.lax.fori_loop(0, live_pages(n_pages, blk), one, None)
 
     def start(slot, first, n_pages, blk, buf):
@@ -498,7 +520,7 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         return (m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True),
                 acc * alpha + jnp.dot(
-                    p.astype(mxu), vbuf[buf].astype(mxu),
+                    p.astype(mxu), vbuf[buf][:, :dv].astype(mxu),
                     preferred_element_type=jnp.float32,
                     precision=prec))
 
@@ -506,21 +528,24 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, vp_ref, o_ref,
         0, n_blocks, step,
         (jnp.full((num_heads, 1), _NEG, jnp.float32),
          jnp.zeros((num_heads, 1), jnp.float32),
-         jnp.zeros((num_heads, dkv), jnp.float32)))
+         jnp.zeros((num_heads, dv), jnp.float32)))
     base_ref[0] = (base + n_blocks) % 2
-    out = jnp.where(diag, acc / jnp.maximum(l, 1e-30), 0.0)
-    # row h is zero outside its KV head's block: with a head each, the rows
-    # add up to [1, H*D]; with grouped queries, the blocks to [H, D]
-    if group == 1:
-        out = jnp.sum(out, axis=0, keepdims=True)
-    else:
-        out = sum(out[:, g * hd:(g + 1) * hd] for g in range(num_kv_heads))
+    out = acc / jnp.maximum(l, 1e-30)    # one head's rows with v_width
+    if not v_width:
+        # row h is zero outside its KV head's block: with a head each, the
+        # rows add up to [1, H*D]; with grouped queries, the blocks to [H, D]
+        out = jnp.where(diag, out, 0.0)
+        if group == 1:
+            out = jnp.sum(out, axis=0, keepdims=True)
+        else:
+            out = sum(out[:, g * hd:(g + 1) * hd]
+                      for g in range(num_kv_heads))
     o_ref[0] = out.astype(o_ref.dtype)
 
 
 def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
                            num_heads, interpret=None, num_kv_heads=None,
-                           window=None):
+                           window=None, v_width=None, scale=None):
     """Single-query flash decode (inference only, no vjp: generation
     never differentiates through the cache) where K/V live in a PAGED
     pool and the kernel streams exactly the live blocks of each
@@ -531,6 +556,11 @@ def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
     queries, head h on KV head ``h // (H / Hkv)``); lengths: [S]; tables:
     [S, MB] int block ids (entries >= NB are dead — clamped, masked by
     length); ``window``: attend rows ``[length - window, length)`` only.
+    ``v_width``: the pool holds one head whose value is the leading
+    ``v_width`` lanes of its key's row (a latent cache: ``v_pool`` is
+    None, ``num_kv_heads`` 1); a page is read once for both and the
+    result is [S, 1, H*v_width]. ``scale`` multiplies the scores
+    (default ``D ** -0.5``).
     Returns [S, 1, H*D]. One program per slot
     (:func:`_decode_paged_kernel`): ``lengths`` and the table are
     scalar-prefetched, the pools are not blocked, and the program
@@ -545,10 +575,14 @@ def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
     if interpret is None:
         interpret = kernel_path.interpret_mode()
     nkv = num_kv_heads or num_heads
+    if v_width and (v_pool is not None or nkv != 1):
+        raise ValueError("a pool whose value is the head of its key's row "
+                         "holds one head and has no V pool")
     bs, dkv = k_pool.shape[1], k_pool.shape[2]
     hd = q.shape[-1] // num_heads
     sublanes = 32 // jnp.dtype(k_pool.dtype).itemsize
     if not interpret and (bs % sublanes != 0 or dkv % 128 != 0 or
+                          (v_width or 0) % 128 != 0 or
                           (hd % 128 != 0 and num_heads != 1)):
         # compiled Mosaic wants a page to land in its buffer on whole
         # tiles: BS rows a multiple of the dtype's sublane tile (8 for
@@ -557,16 +591,21 @@ def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
         # else takes the XLA gather path (identical semantics)
         kernel_path.record("decode_attention_paged")
         return _decode_paged_reference(q, k_pool, v_pool, lengths,
-                                       tables, num_heads, nkv, window)
+                                       tables, num_heads, nkv, window,
+                                       v_width, scale)
     kernel_path.record("decode_attention_paged", interpret)
-    pages = _paged_block_pages(bs, dkv, k_pool.dtype, tables.shape[1])
+    pages = _paged_block_pages(bs, dkv, k_pool.dtype, tables.shape[1],
+                               2 if v_width else 4)
     return _decode_paged_call(q, k_pool, v_pool, lengths, tables,
-                              num_heads, pages, interpret, nkv, window)
+                              num_heads, pages, interpret, nkv, window,
+                              v_width, hd ** -0.5 if scale is None
+                              else float(scale))
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
-                       pages, interpret, num_kv_heads, window):
+                       pages, interpret, num_kv_heads, window, v_width,
+                       scale):
     """The kernel call, under a jit of its own: a model's layers share
     their geometry, so the body is traced once a process and lowered
     once a program, not once a layer."""
@@ -582,18 +621,17 @@ def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
     # head has its own KV head (the layout the model hands over), a row a
     # head where query heads share one
     rows = (1, dm) if num_kv_heads == num_heads else (num_heads, hd)
+    out_rows = (num_heads, v_width) if v_width else rows
+    pools = (k_pool,) if v_width else (k_pool, v_pool)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s,),
-        in_specs=[
-            pl.BlockSpec((1,) + rows, lambda si, lr, tr: (si, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1,) + rows, lambda si, lr, tr: (si, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, pages * bs, dkv), k_pool.dtype),
-            pltpu.VMEM((2, pages * bs, dkv), v_pool.dtype),
+        in_specs=[pl.BlockSpec((1,) + rows, lambda si, lr, tr: (si, 0, 0))]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=pl.BlockSpec((1,) + out_rows,
+                               lambda si, lr, tr: (si, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, pages * bs, dkv), pool.dtype)
+                        for pool in pools] + [
             pltpu.SemaphoreType.DMA((2, 2)),      # (K|V, buffer)
             pltpu.SMEM((1,), jnp.int32),          # first block's buffer
         ])
@@ -601,16 +639,15 @@ def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
         functools.partial(_decode_paged_kernel, block_size=bs,
                           max_blocks=mb, num_blocks=nb, pages=pages,
                           num_heads=num_heads, num_kv_heads=num_kv_heads,
-                          window=window, scale=hd ** -0.5),
+                          window=window, scale=scale, v_width=v_width),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s,) + rows, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s,) + out_rows, q.dtype),
         # programs run in order: each hands its successor a block
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="decode_attention_paged",
-        interpret=interpret)(lens, tab, q.reshape((s,) + rows),
-                             k_pool, v_pool)
-    return out.reshape(s, 1, dm)
+        interpret=interpret)(lens, tab, q.reshape((s,) + rows), *pools)
+    return out.reshape(s, 1, -1)
 
 
 def flash_attention(q, k, v, causal=False, segment_ids=None,

@@ -202,6 +202,16 @@ _WINDOW_CONTEXT_TOKENS = _metrics.REGISTRY.counter(
     "Cached tokens attended by the window layers of decode steps: per "
     "step, the sum over the slots that advanced and over the window "
     "layers of min(context length, window)")
+_LATENT_ROWS_ATTENDED = _metrics.REGISTRY.counter(
+    "paddle_generation_latent_rows_attended_total",
+    "Cached latent rows attended by decode steps: per step, the sum over "
+    "the slots that advanced and over the layers of a latent kind of "
+    "their context length, the new token included")
+_ROUTED_PAIRS = _metrics.REGISTRY.counter(
+    "paddle_generation_routed_pairs_total",
+    "Token-expert pairs routed by the expert layers of decode steps "
+    "(rows x top-k), whether the expert is held here or not: over it, "
+    "_expert_assignments_total is the share that fell on held experts")
 _MOE_LAYER_STEPS = _metrics.REGISTRY.counter(
     "paddle_generation_moe_layer_steps_total",
     "Expert layers run by decode steps (steps x expert layers)")
@@ -340,8 +350,15 @@ class GenerationSpec:
     ``num_blocks`` and the table feeds of ``prefill_feeds`` /
     ``decode_feeds``. Absent, the spec has the one kind those fields
     describe. ``stats_fetch`` (optional) names a small int array
-    ``[expert layers, experts]`` of the decode program, the pairs each
-    expert took in the step, fetched with the step's tokens.
+    ``[expert layers, held experts]`` of the decode program, the pairs
+    each held expert took in the step, fetched with the step's tokens;
+    ``routed_pairs`` is then how many pairs a step routes, held here or
+    not (absent: every expert is held and the counts add up to it).
+
+    ``latent_layers`` counts the layers whose cache is a latent kind's:
+    one pool a layer, whose row is key and value at once (``cache_vars``
+    names one variable a layer, not a K and a V); its books are the full
+    kind's.
     """
 
     __slots__ = ("slots", "cache_len", "max_len", "prompt_buckets",
@@ -352,7 +369,8 @@ class GenerationSpec:
                  "prefix_cache", "copy_program", "copy_feeds",
                  "vocab_size", "policy", "verify_program",
                  "verify_feeds", "verify_fetch", "draft_spec",
-                 "cache_kinds", "stats_fetch")
+                 "cache_kinds", "stats_fetch", "routed_pairs",
+                 "latent_layers")
 
     # a constant, kept because benchmarks/harness/serve.py:71 checks it
     paged = True
@@ -371,6 +389,8 @@ class GenerationSpec:
         kwargs.setdefault("draft_spec", None)
         kwargs.setdefault("cache_kinds", None)
         kwargs.setdefault("stats_fetch", None)
+        kwargs.setdefault("routed_pairs", None)
+        kwargs.setdefault("latent_layers", 0)
         for name in self.__slots__:
             setattr(self, name, kwargs.pop(name))
         if kwargs:
@@ -484,6 +504,7 @@ class GenerationSession:
         self.kinds = [LayerCache(k, spec.block_size, n) for k in kinds]
         self._more_kinds = tuple(self.kinds[1:])
         self._window_kinds = tuple(k for k in self.kinds if k.window)
+        self._latent_layers = getattr(spec, "latent_layers", 0)
         self.pool = self.kinds[0].pool
         self.prefix = PrefixIndex(self.pool) if spec.prefix_cache else None
         # host-side block table per slot: physical block ids
@@ -645,13 +666,15 @@ class GenerationSession:
         — probe/bench surface."""
         itemsize = np.dtype(self.spec.cache_vars[0][2]).itemsize
         d_model = self.spec.cache_vars[0][1][2]
-        # of the first kind: a block id names a K and a V block in each
-        # of its layers
+        # of the first kind: a block id names a block of each pool (a K
+        # and a V, or a latent kind's one) in each of its layers
+        pools = len(self.spec.cache_vars) // sum(
+            k.kind.layers for k in self.kinds)
         return {"blocks_in_use": self.pool.used_count(),
                 "num_blocks": self.pool.num_blocks,
                 "block_size": self.spec.block_size,
                 "bytes_per_block": self.spec.block_size * d_model
-                * itemsize * 2 * self.kinds[0].kind.layers}
+                * itemsize * pools * self.kinds[0].kind.layers}
 
     def prefix_stats(self):
         """Prefix-cache hit counters (zeros when not armed)."""
@@ -1231,6 +1254,8 @@ class GenerationSession:
             _WINDOW_CONTEXT_TOKENS.inc(int(sum(
                 k.kind.layers * np.minimum(lens, k.window).sum()
                 for k in self._window_kinds)))
+        if self._latent_layers and advanced.size:
+            _LATENT_ROWS_ATTENDED.inc(self._latent_layers * int(lens.sum()))
         flight = _Flight(advanced, self._retires[advanced].copy(), outs,
                          int(lens.sum()))
         self._flights.append(flight)
@@ -1277,13 +1302,14 @@ class GenerationSession:
         failed, and what they hold is re-made from the journals."""
         self._flights.clear()
 
-    @staticmethod
-    def _count_experts(counts):
-        """The routing counters from a step's ``[expert layers, experts]``
-        pair counts."""
+    def _count_experts(self, counts):
+        """The routing counters from a step's ``[expert layers, held
+        experts]`` pair counts."""
+        held = int(counts.sum())
         _MOE_LAYER_STEPS.inc(counts.shape[0])
         _EXPERTS_TOUCHED.inc(int((counts > 0).sum()))
-        _EXPERT_ASSIGNMENTS.inc(int(counts.sum()))
+        _EXPERT_ASSIGNMENTS.inc(held)
+        _ROUTED_PAIRS.inc(getattr(self.spec, "routed_pairs", None) or held)
         _EXPERT_MAX_LOAD.inc(int(counts.max(axis=1).sum()))
 
     def _draft_mirror_plain(self, result):

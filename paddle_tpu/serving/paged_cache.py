@@ -92,7 +92,10 @@ _POOL_SEQ = itertools.count()
 # block table because they keep the same rows. ``window`` None keeps every
 # row; a window keeps the last ``window`` rows of a sequence and frees the
 # blocks behind them. Each kind has its own pool of ``num_blocks`` and its
-# own table feeds; ``layers`` is how many layers are of the kind.
+# own table feeds; ``layers`` is how many layers are of the kind. A kind
+# named ``latent`` keeps every row like ``full``; what differs is on the
+# device: one pool a layer, whose row is a token's latent, key and value at
+# once (ops/mla_ops.py).
 CacheKind = collections.namedtuple(
     "CacheKind", "name window num_blocks layers prefill_table decode_table")
 
@@ -115,7 +118,7 @@ class BlockPool:
     the free list exactly when its count reaches zero.
     """
 
-    def __init__(self, num_blocks, block_size):
+    def __init__(self, num_blocks, block_size, kind=None):
         if num_blocks < 1 or block_size < 1:
             raise ValueError("need num_blocks >= 1 and block_size >= 1,"
                              " got %r / %r" % (num_blocks, block_size))
@@ -123,7 +126,11 @@ class BlockPool:
         self.block_size = int(block_size)
         self._free = collections.deque(range(self.num_blocks))
         self._ref = [0] * self.num_blocks
+        # a gauge child of its own: the pool's serial number, behind the
+        # name of its kind of layer cache where it has one ("latent.p7")
         self._label = "p%d" % next(_POOL_SEQ)
+        if kind is not None:
+            self._label = "%s.%s" % (kind, self._label)
         self._gauge = BLOCKS_IN_USE.labels(pool=self._label)
         self._gauge.set(0)
 
@@ -230,7 +237,7 @@ class LayerCache:
     def __init__(self, kind, block_size, slots):
         self.kind = kind
         self.window = kind.window
-        self.pool = BlockPool(kind.num_blocks, block_size)
+        self.pool = BlockPool(kind.num_blocks, block_size, kind.name)
         self.tables = [[] for _ in range(slots)]
         self.first = np.zeros(slots, np.int64)
 

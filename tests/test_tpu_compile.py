@@ -208,6 +208,74 @@ def test_expert_matmuls_past_the_measured_sizes_take_ragged_dot(
     assert "reduce-precision" in hlo and "bf16[196608,2048]" in hlo
 
 
+@pytest.mark.parametrize("block_size", [32, 16])
+def test_decode_attention_paged_compiles_on_a_latent_pool(chip, block_size):
+    """kimi-serve-offline's layer cache: 32 slots, 64 query heads on ONE KV
+    head whose value is the leading 512 lanes of its key's 640-lane
+    float32 row; one pool, fetched once a page; the output [64, 512] a
+    slot."""
+    max_blocks = 8192 // block_size
+
+    def fn(q, pool, lens, tables):
+        return pa.decode_attention_paged(
+            q, pool, None, lens, tables, 64, interpret=False,
+            num_kv_heads=1, v_width=512, scale=0.1447)
+    hlo = _compile(chip, fn, ((32, 1, 64 * 640), F32),
+                   ((32 * max_blocks, block_size, 640), F32), ((32,), I32),
+                   ((32, max_blocks), I32))
+    assert _has_kernel(hlo, "decode_attention_paged")
+    assert re.search(r"f32\[32,64,512\]", hlo), hlo[-2000:]
+
+
+def test_a_576_wide_latent_pool_takes_the_reference(chip):
+    """Four and a half lane tiles: the gate hands it to the XLA gather."""
+    before = kernel_path.counts().get("decode_attention_paged", {})
+    hlo = _compile(
+        chip, lambda q, pool, lens, tables: pa.decode_attention_paged(
+            q, pool, None, lens, tables, 64, interpret=False,
+            num_kv_heads=1, v_width=512),
+        ((4, 1, 64 * 576), F32), ((64, 32, 576), F32), ((4,), I32),
+        ((4, 16), I32))
+    after = kernel_path.counts()["decode_attention_paged"]
+    assert not _has_kernel(hlo, "decode_attention_paged")
+    assert after.get("xla", 0) == before.get("xla", 0) + 1
+
+
+@pytest.mark.parametrize("tokens", [32, 4096], ids=["decode_step",
+                                                    "largest_prefill"])
+def test_a_shares_expert_matmuls_compile_as_a_weight_stream(
+        chip, monkeypatch, tokens):
+    """kimi-serve-offline: 12 of 384 experts of 7168 x 2048 held in
+    bfloat16 (29 MB an expert: the column-tiled path), top-8. The decode
+    step's 256 pairs in one pass; the 4,096-token prefill's 32,768 in
+    passes of 2,048 rows inside a loop. Both in ``moe_grouped_matmul``,
+    none in ``ragged_dot``."""
+    from types import SimpleNamespace
+    from paddle_tpu.core.registry import ExecContext
+    from paddle_tpu.ops import moe_ops
+    monkeypatch.setattr(kernel_path, "interpret_mode", lambda: False)
+    op = SimpleNamespace(attrs={"num_experts": 384, "top_k": 8,
+                                "route_scale": 2.827})
+    slots = ("X", "RouterW", "ExpertBias", "WGate", "WUp", "WDown")
+
+    def fn(*values):
+        return moe_ops._moe_ffn(ExecContext(
+            op, {slot: [v] for slot, v in zip(slots, values)}))
+    before = dict(kernel_path.counts().get("moe_grouped_matmul", {}))
+    hlo = _compile(chip, fn, ((tokens, 7168), F32), ((7168, 384), F32),
+                   ((384,), F32), ((12, 7168, 2048), BF16),
+                   ((12, 7168, 2048), BF16), ((12, 2048, 7168), BF16))
+    after = kernel_path.counts()["moe_grouped_matmul"]
+    assert {p: n - before.get(p, 0) for p, n in after.items()
+            if n != before.get(p, 0)} == {"compiled": 1}
+    assert len(re.findall(r"%moe_grouped_matmul(?:\.\d+)? = [^\n]*"
+                          r"tpu_custom_call", hlo)) == 2, hlo[-3000:]
+    assert "ragged" not in hlo and "reduce-precision" not in hlo
+    # the rows gathered are a pass's, not every pair's
+    if tokens == 4096:
+        assert "f32[32768,7168]" not in hlo and "f32[2048,7168]" in hlo
+
+
 def test_decode_attention_paged_head_dim_64_takes_reference(chip):
     """d_model 512 / 8 heads: two heads share a lane tile, a geometry
     the kernel has not run compiled. The gate must hand it to the XLA
