@@ -271,7 +271,7 @@ def _lm_train_steps(sizes, batch, seq_len, steps, strategy=None):
 def phase_train(vocab, d_model, num_heads, d_ff, num_layers, batch,
                 seq_len, steps):
     """LM + Adam through ``Executor.run``; loss finite and falling; the
-    flash kernel compiled into the step."""
+    flash kernels, forward and backward, compiled into the step."""
     sizes = dict(vocab=vocab, d_model=d_model, num_heads=num_heads,
                  d_ff=d_ff, num_layers=num_layers)
     with _phase("train", batch=batch, seq_len=seq_len, **sizes) \
@@ -284,8 +284,8 @@ def phase_train(vocab, d_model, num_heads, d_ff, num_layers, batch,
                     first_step_s=round(step_s[0], 2),
                     step_s=round(float(np.median(step_s[1:])), 4))
         _falling(losses, "LM train")
-        line["kernels"] = {"flash_attention": _kernel_taken(
-            "flash_attention", k0, hlo)}
+        line["kernels"] = {k: _kernel_taken(k, k0, hlo) for k in
+                           ("flash_attention", "flash_attention_bwd")}
     return line
 
 
@@ -466,8 +466,8 @@ def phase_cross_chip(chips, lm, lm_batch, lm_seq_len, lm_steps, wd_vocab,
                "feed is not batch-sharded over %d devices", chips)
         _check("all-reduce" in hlo, "no all-reduce in the sharded LM "
                "step's HLO")
-        kernels = {"flash_attention": _kernel_taken(
-            "flash_attention", k0, hlo)}
+        kernels = {k: _kernel_taken(k, k0, hlo) for k in
+                   ("flash_attention", "flash_attention_bwd")}
         del scope, main, toks
         gc.collect()
         losses_1, step_1, _, _, _ = _lm_train_steps(

@@ -5,10 +5,14 @@ The fused kernel streams K/V from VMEM against one Q block at a time:
 scores, causal mask, softmax, and the P@V contraction all happen
 on-chip, so the [T, T] probability matrix never exists in HBM (the XLA
 fallback in ops/attention_ops.py writes it out between the two
-einsums). Forward is the Pallas kernel; backward is a flash-style
-CHUNKED recompute under jax.custom_vjp — probabilities are rebuilt one
-q-chunk at a time (peak O(block_q * T) per batch-head), so training at
-long T stays in-memory too; residuals are just q, k, v.
+einsums). Forward and backward are Pallas kernels under
+jax.custom_vjp: the forward under differentiation also keeps the
+log-sum-exp of every query row ([BH, 1, T] float32), and the backward
+(``flash_attention_bwd``) rebuilds the probabilities from it a
+(k-block, q-block) tile at a time in VMEM, so no [.., T] array is
+written in either direction; residuals are q, k, v, the output and the
+row statistics. A length that is not whole lane tiles (T % 128) keeps
+q, k, v alone and differentiates the XLA reference.
 
 Used by the multihead_attention op when the ``flash_attention`` config
 flag is on (interpret mode on CPU keeps it testable everywhere);
@@ -37,7 +41,7 @@ _NEG = -1e30
 
 
 def _reference(q, k, v, causal, seg=None):
-    """Plain jnp attention over [BH, T, D] (the backward path).
+    """Plain jnp attention over [BH, T, D] (ragged lengths, both ways).
     seg: [BH, T] int32 segment ids, 0 = padding — a key is attendable
     by a query iff their ids match and the key's id is nonzero (covers
     both padding masks and packed-sequence masks, SURVEY §5.7)."""
@@ -58,14 +62,71 @@ def _reference(q, k, v, causal, seg=None):
     return jnp.einsum("bqk,bkd->bqd", p, v)
 
 
-def _body(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, acc_ref, m_ref,
-          l_ref, *, scale, causal, block_q, block_k, nk):
+def _mxu(a, b, contract):
+    """One MXU product inside a kernel, float32 out. ``contract``: the
+    contracted dim of each operand. The explicit Precision: the
+    executor's ambient default_matmul_precision('BF16_BF16_F32') is a
+    DotAlgorithmPreset that Mosaic's dot lowering rejects; inside the
+    kernel the MXU path is already bf16-multiply/f32-acc."""
+    return jax.lax.dot_general(
+        a, b, ((contract[:1], contract[1:]), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.DEFAULT)
+
+
+_NT, _NN, _TN = (1, 1), (1, 0), (0, 0)     # a @ b.T, a @ b, a.T @ b
+
+
+def _tile_mask(qi, ki, block_q, block_k, causal, sq_ref, sk_ref,
+               keys_first=False):
+    """Which (query, key) pairs of tile (qi, ki) attend: bool
+    ``[bq, bk]`` (``[bk, bq]`` with ``keys_first``), or None where every
+    pair does. sq_ref/sk_ref (optional) carry the FULL [1, 1, T] int32
+    segment-id row, 0 = padding (Mosaic needs block dims divisible by
+    (8,128) or whole-array; a (1,bq) block is neither) — the window is
+    sliced in-kernel; key attendable iff ids match and nonzero."""
+    shape = (block_k, block_q) if keys_first else (block_q, block_k)
+    qdim = 1 if keys_first else 0
+    mask = None
+    if causal:
+        rows = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, shape, qdim)
+        cols = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1 - qdim)
+        mask = rows >= cols
+    if sq_ref is not None:
+        sq = sq_ref[0, :, pl.ds(qi * block_q, block_q)]  # [1, bq]
+        sk = sk_ref[0, :, pl.ds(ki * block_k, block_k)]  # [1, bk]
+        if keys_first:
+            sk = sk.reshape(block_k, 1)
+        else:
+            sq = sq.reshape(block_q, 1)
+        seg_mask = (sq == sk) & (sk != 0)
+        mask = seg_mask if mask is None else (mask & seg_mask)
+    return mask
+
+
+def _live(qi, ki, block_q, block_k, causal):
+    """False for a causal tile wholly above the diagonal: its last query
+    row lies before its first key."""
+    return (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+
+
+def _body(q_ref, k_ref, v_ref, *refs, segmented, with_lse, scale, causal,
+          block_q, block_k, nk):
     """One (q-block, k-block) step of flash attention with online
     softmax. The k axis is the innermost (sequential) grid dim, so the
     VMEM scratch (acc, running max m, running sum l) carries across
-    k blocks of the same q block. sq_ref/sk_ref (optional, [1, bq] /
-    [1, bk] int32 segment ids, 0 = padding) add the padding /
-    packed-sequence mask: key attendable iff ids match and nonzero."""
+    k blocks of the same q block. ``refs``: where ``segmented`` the
+    segment-id rows sq_ref, sk_ref (the padding / packed-sequence mask,
+    :func:`_tile_mask`); o_ref; ``with_lse`` (the forward under
+    differentiation) lse_ref, the whole [1, 1, T] float32 row of a
+    batch-head: the log-sum-exp of every query row, ``m + log l``, which
+    the backward kernel rebuilds the probabilities from; the scratch."""
+    sq_ref, sk_ref = refs[:2] if segmented else (None, None)
+    o_ref = refs[2 * segmented]
+    lse_ref = refs[2 * segmented + 1] if with_lse else None
+    acc_ref, m_ref, l_ref = refs[-3:]
     qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -74,16 +135,8 @@ def _body(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, acc_ref, m_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # causal: skip k blocks strictly above this q block's last row
-    live = (qi * block_q + block_q - 1 >= ki * block_k) \
-        if causal else True
-
-    @pl.when(live)
+    @pl.when(_live(qi, ki, block_q, block_k, causal))
     def _step():
-        # explicit Precision: the executor's ambient
-        # default_matmul_precision('BF16_BF16_F32') is a
-        # DotAlgorithmPreset that Mosaic's dot lowering rejects; inside
-        # the kernel the MXU path is already bf16-multiply/f32-acc
         # narrow (bf16) pools upcast at the contraction, matching the
         # reference's promotion; identity trace for f32 pools, so the
         # flag-off program stays byte-identical
@@ -96,21 +149,7 @@ def _body(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, acc_ref, m_ref,
         s = jnp.dot(q_ref[0], k_blk.T,
                     preferred_element_type=jnp.float32,
                     precision=jax.lax.Precision.DEFAULT) * scale
-        mask = None
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            mask = rows >= cols
-        if sq_ref is not None:
-            # sq_ref/sk_ref carry the FULL [1, 1, T] id row (Mosaic
-            # needs block dims divisible by (8,128) or whole-array; a
-            # (1,bq) block is neither) — slice the window in-kernel
-            sq = sq_ref[0, :, pl.ds(qi * block_q, block_q)]  # [1, bq]
-            sk = sk_ref[0, :, pl.ds(ki * block_k, block_k)]  # [1, bk]
-            seg_mask = (sq.reshape(block_q, 1) == sk) & (sk != 0)
-            mask = seg_mask if mask is None else (mask & seg_mask)
+        mask = _tile_mask(qi, ki, block_q, block_k, causal, sq_ref, sk_ref)
         if mask is not None:
             s = jnp.where(mask, s, _NEG)
         m_prev = m_ref[:]                          # [bq, 128]
@@ -132,17 +171,10 @@ def _body(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, acc_ref, m_ref,
     def _finish():
         denom = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
-
-
-def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, **kw):
-    _body(q_ref, k_ref, v_ref, None, None, o_ref, acc_ref, m_ref,
-          l_ref, **kw)
-
-
-def _kernel_seg(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, acc_ref,
-                m_ref, l_ref, **kw):
-    _body(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, acc_ref, m_ref,
-          l_ref, **kw)
+        if lse_ref is not None:
+            # a column of row statistics [bq, 128] -> lanes [1, bq]
+            lse = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
+            lse_ref[0, :, pl.ds(qi * block_q, block_q)] = lse.T[:1]
 
 
 def _block_size(t, cap, align=16):
@@ -161,113 +193,222 @@ def _block_size(t, cap, align=16):
     return 0
 
 
-def _forward(q, k, v, seg, causal, block_q, interpret):
-    bh, t, d = q.shape
-    align = 128 if seg is not None else 16
-    bq = _block_size(t, block_q, align)
-    bk = _block_size(t, 512, align)
-    if not bq or not bk:
-        kernel_path.record("flash_attention")
-        return _reference(q, k, v, causal, seg)  # ragged: XLA path
-    kernel_path.record("flash_attention", interpret)
+def _row_spec(t):
+    """A batch-head's whole [1, 1, T] row of per-position numbers (segment
+    ids, row statistics), resident across its grid steps: whole rows
+    satisfy Mosaic's (8,128)-or-whole-dim tiling rule where a (1, bq)
+    block does not, and are sliced in-kernel."""
+    return pl.BlockSpec((1, 1, t), lambda b, i, j: (b, 0, 0))
+
+
+def _seg_rows(seg):
+    """[BH, T] segment ids as the kernels take them, twice (the queries'
+    and the keys'), and their specs."""
+    if seg is None:
+        return [], []
+    bh, t = seg.shape
+    return [seg.reshape(bh, 1, t)] * 2, [_row_spec(t)] * 2
+
+
+def _forward(q, k, v, seg, causal, bq, bk, interpret, with_lse=False):
+    """The forward kernel on blocks of ``bq`` query and ``bk`` key rows;
+    ``with_lse``: also the [BH, 1, T] float32 log-sum-exp of every query
+    row (``bq`` whole lane tiles then)."""
     from jax.experimental.pallas import tpu as pltpu
-    grid = (bh, t // bq, t // bk)
-    kw = dict(scale=d ** -0.5, causal=causal, block_q=bq, block_k=bk,
-              nk=t // bk)
-    qkv_specs = [
-        pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-    ]
-    common = dict(
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        grid=grid,
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+    bh, t, d = q.shape
+    segs, seg_specs = _seg_rows(seg)
+    out_shape = jax.ShapeDtypeStruct((bh, t, d), q.dtype)
+    out_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
+    if with_lse:
+        out_shape = (out_shape,
+                     jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
+        out_spec = (out_spec, _row_spec(t))
+    return pl.pallas_call(
+        functools.partial(_body, segmented=bool(segs), with_lse=with_lse,
+                          scale=d ** -0.5, causal=causal, block_q=bq,
+                          block_k=bk, nk=t // bk),
+        name="flash_attention_fwd" + ("_seg" if segs else ""),
+        grid=(bh, t // bq, t // bk),
+        in_specs=[
+            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+        ] + seg_specs,
+        out_shape=out_shape, out_specs=out_spec,
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),     # acc
             pltpu.VMEM((bq, 128), jnp.float32),   # running max
             pltpu.VMEM((bq, 128), jnp.float32),   # running sum
         ],
-        interpret=interpret,
-    )
-    if seg is None:
-        return pl.pallas_call(
-            functools.partial(_kernel, **kw), name="flash_attention_fwd",
-            in_specs=qkv_specs, **common)(q, k, v)
-    seg3 = seg.reshape(bh, 1, t)  # (1,1,t) blocks satisfy Mosaic's
-    return pl.pallas_call(         # (8,128)-or-whole-dim tiling rule
-        functools.partial(_kernel_seg, **kw), name="flash_attention_fwd_seg",
-        in_specs=qkv_specs + [
-            pl.BlockSpec((1, 1, t), lambda b, i, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, t), lambda b, i, j: (b, 0, 0)),
-        ], **common)(q, k, v, seg3, seg3)
+        interpret=interpret)(q, k, v, *segs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, seg, causal, block_q, interpret):
-    return _forward(q, k, v, seg, causal, block_q, interpret)
+# -- the backward: one kernel over the saved row statistics ---------------
+
+def _bwd_body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+              segmented, scale, causal, block_q, block_k, nq, nk):
+    """One (k-block, q-block) tile of the backward: the probabilities
+    ``p = exp(s - lse)`` and ``ds = p * (dp - delta)`` are rebuilt in
+    VMEM, keys first (``[bk, bq]``), so the row statistics apply as the
+    lane rows ``[1, bq]`` they are stored as and dV, dK are plain
+    products. The q axis is the innermost grid dim: dK and dV of the k
+    block carry across it in ``dk_acc``/``dv_acc``; dQ, which sums over k
+    blocks, is kept for the whole batch-head in ``dq_acc`` ``[T, d]`` and
+    written out a q block at a time under the last k block. A masked
+    pair, and every pair of a fully masked row (whose lse is -1e30),
+    gets p = 0 by the select, whatever exp gave. ``refs``: where
+    ``segmented`` the segment-id rows, then the three outputs and the
+    three sums."""
+    sq_ref, sk_ref = refs[:2] if segmented else (None, None)
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[-6:]
+    ki, qi = pl.program_id(1), pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+
+    @pl.when(qi == 0)
+    def _init_k_block():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(ki == 0)
+    def _init_q_block():
+        dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
+
+    @pl.when(_live(qi, ki, block_q, block_k, causal))
+    def _step():
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        p = jnp.exp(_mxu(k, q, _NT) * scale - lse_ref[0, :, rows])
+        mask = _tile_mask(qi, ki, block_q, block_k, causal, sq_ref, sk_ref,
+                          keys_first=True)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        ds = (p * (_mxu(v_ref[0], do, _NT) - delta_ref[0, :, rows])
+              ).astype(q.dtype)
+        dv_acc[:] += _mxu(p.astype(do.dtype), do, _NN)
+        dk_acc[:] += _mxu(ds, q, _NN)
+        dq_acc[rows, :] += _mxu(ds, k, _TN)
+
+    @pl.when(qi == nq - 1)
+    def _finish_k_block():
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(ki == nk - 1)
+    def _finish_q_block():
+        dq_ref[0] = (dq_acc[rows, :] * scale).astype(dq_ref.dtype)
+
+
+# rows of q and of k a backward tile takes, at most (my chip runs, PR 32:
+# PERF.md section 7), and the largest [T, d] float32 sum of dQ a
+# batch-head may keep in VMEM
+_BWD_BLOCK = 512
+_BWD_DQ_BYTES = 64 << 20
+
+
+def _backward(q, k, v, o, lse, seg, do, causal, interpret):
+    """(dq, dk, dv) of flash attention from the forward's output and row
+    statistics: ``delta = rowsum(dO * O)`` once (XLA), then one kernel on
+    the grid (BH, T/bk, T/bq) that writes no [.., T] array. Causal tiles
+    wholly above the diagonal do nothing, and the index map holds their q
+    blocks at the k block's first live one, so nothing is fetched for
+    them."""
+    from jax.experimental.pallas import tpu as pltpu
+    bh, t, d = q.shape
+    mxu = jnp.promote_types(q.dtype, k.dtype)   # one operand dtype
+    q, k, v, do = (x.astype(mxu) for x in (q, k, v, do))
+    bq = _block_size(t, _BWD_BLOCK, 128)
+    bk = _block_size(t, _BWD_BLOCK, 128 if seg is not None else 16)
+    nq, nk = t // bq, t // bk
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
+                    axis=-1)[:, None, :]
+    segs, seg_specs = _seg_rows(seg)
+
+    def q_of(b, j, i):
+        return (b, jnp.maximum(i, (j * bk) // bq) if causal else i, 0)
+
+    q_spec = pl.BlockSpec((1, bq, d), q_of)
+    k_spec = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
+    row = _row_spec(t)
+    # the scoped VMEM a kernel gets by default holds a dQ sum of 8 MiB
+    # beside the tiles; a longer one asks for its room
+    dq_bytes = t * d * 4
+    params = pltpu.CompilerParams(
+        vmem_limit_bytes=dq_bytes + (16 << 20)) \
+        if dq_bytes > (8 << 20) else None
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_body, segmented=bool(segs),
+                          scale=d ** -0.5, causal=causal, block_q=bq,
+                          block_k=bk, nq=nq, nk=nk),
+        name="flash_attention_bwd" + ("_seg" if segs else ""),
+        grid=(bh, nk, nq),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row, row] + seg_specs,
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        # dq's block is written under the last k block alone: until then
+        # the map stays on block 0, which is not written back before the
+        # kernel has filled it
+        out_specs=(pl.BlockSpec(
+            (1, bq, d),
+            lambda b, j, i: (b, jnp.where(j == nk - 1, i, 0), 0)),
+            k_spec, k_spec),
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret)(q, k, v, do, lse, delta, *segs)
+    return dq, dk, dv
+
+
+def _tiles(t, block_q, segmented, lanes=False):
+    """(bq, bk) the forward kernel tiles a length with, or None for a
+    ragged one. ``lanes``: q blocks of whole lane tiles, which the row
+    statistics need (they are stored and sliced along lanes)."""
+    align = 128 if segmented else 16
+    bq = _block_size(t, block_q, 128 if lanes else align)
+    bk = _block_size(t, 512, align)
+    return (bq, bk) if bq and bk else None
+
+
+def _primal(q, k, v, seg, causal, block_q, interpret):
+    tiles = _tiles(q.shape[1], block_q, seg is not None)
+    if tiles is None:
+        kernel_path.record("flash_attention")
+        return _reference(q, k, v, causal, seg)  # ragged: XLA path
+    kernel_path.record("flash_attention", interpret)
+    return _forward(q, k, v, seg, causal, *tiles, interpret)
+
+
+_flash = jax.custom_vjp(_primal, nondiff_argnums=(4, 5, 6))
 
 
 def _flash_fwd(q, k, v, seg, causal, block_q, interpret):
-    return _forward(q, k, v, seg, causal, block_q, interpret), \
-        (q, k, v, seg)
+    """Where the length is whole lane tiles (and its dQ sum fits VMEM)
+    the forward keeps its row statistics and the backward is the kernel;
+    any other length keeps (q, k, v) alone and differentiates the
+    reference."""
+    _, t, d = q.shape
+    tiles = _tiles(t, block_q, seg is not None, lanes=True)
+    if tiles is None or t * d * 4 > _BWD_DQ_BYTES:
+        return _primal(q, k, v, seg, causal, block_q, interpret), \
+            (q, k, v, None, None, seg)
+    kernel_path.record("flash_attention", interpret)
+    o, lse = _forward(q, k, v, seg, causal, *tiles, interpret,
+                      with_lse=True)
+    return o, (q, k, v, o, lse, seg)
 
 
 def _flash_bwd(causal, block_q, interpret, res, g):
-    """Flash-style chunked backward: recompute probabilities one
-    q-chunk at a time, so peak memory is O(bq * T) per batch-head —
-    never the full [T, T] score matrix (training at T=8192 stays
-    in-memory where the dense backward OOMs)."""
-    q, k, v, seg = res
-    bh, t, d = q.shape
-    scale = d ** -0.5
-    bq = _block_size(t, block_q)
+    q, k, v, o, lse, seg = res
     seg_ct = (None if seg is None else
               np.zeros(seg.shape, jax.dtypes.float0))
-    if not bq:
+    if lse is None:
+        kernel_path.record("flash_attention_bwd")
         _, vjp = jax.vjp(
             lambda q_, k_, v_: _reference(q_, k_, v_, causal, seg),
             q, k, v)
         return vjp(g) + (seg_ct,)
-    nb = t // bq
-    qc = q.reshape(bh, nb, bq, d)
-    gc = g.reshape(bh, nb, bq, d)
-    segc = None if seg is None else seg.reshape(bh, nb, bq)
-    cols = jnp.arange(t)
-
-    def chunk(carry, idx):
-        dk, dv = carry
-        qb = qc[:, idx]                    # [bh, bq, d]
-        gb = gc[:, idx]
-        s = jnp.einsum("bqd,bkd->bqk", qb, k,
-                       preferred_element_type=jnp.float32) * scale
-        mask = None
-        if causal:
-            rows = idx * bq + jnp.arange(bq)
-            mask = rows[None, :, None] >= cols[None, None, :]
-        if segc is not None:
-            sb = segc[:, idx]              # [bh, bq]
-            sm = (sb[:, :, None] == seg[:, None, :]) & \
-                (seg[:, None, :] != 0)
-            mask = sm if mask is None else (mask & sm)
-        if mask is not None:
-            s = jnp.where(mask, s, _NEG)
-        p = jax.nn.softmax(s, axis=-1)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)  # fully-masked rows -> 0
-        dp = jnp.einsum("bqd,bkd->bqk", gb, v,
-                        preferred_element_type=jnp.float32)
-        ds = (dp - jnp.sum(dp * p, axis=-1, keepdims=True)) * p
-        dqb = jnp.einsum("bqk,bkd->bqd", ds, k) * scale
-        dk = dk + jnp.einsum("bqk,bqd->bkd", ds, qb) * scale
-        dv = dv + jnp.einsum("bqk,bqd->bkd", p, gb)
-        return (dk, dv), dqb.astype(q.dtype)
-
-    (dk, dv), dqs = jax.lax.scan(
-        chunk, (jnp.zeros(k.shape, jnp.float32),
-                jnp.zeros(v.shape, jnp.float32)), jnp.arange(nb))
-    dq = jnp.moveaxis(dqs, 0, 1).reshape(bh, t, d)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype), seg_ct
+    kernel_path.record("flash_attention_bwd", interpret)
+    return _backward(q, k, v, o, lse, seg, g, causal, interpret) + (seg_ct,)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -653,7 +794,7 @@ def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
 def flash_attention(q, k, v, causal=False, segment_ids=None,
                     block_q=256, interpret=None):
     """q, k, v: [B, H, T, D] (or [BH, T, D]) -> same-shape output.
-    Fused Pallas forward + recompute backward. ``segment_ids``:
+    Fused Pallas forward and backward. ``segment_ids``:
     [B, T] int32, 0 = padding — a key is attendable iff its id matches
     the query's and is nonzero (one mask covering the padded-batch
     convention AND packed sequences, SURVEY §5.7). Padded query rows

@@ -57,9 +57,11 @@ def test_phase_train_tiny():
     # that its gate handed to XLA) is not one the phase took
     from paddle_tpu.ops import kernel_path
     kernel_path.record("flash_attention")
-    line = chip_smoke.phase_train(batch=2, seq_len=32, steps=3, **TINY_LM)
+    # a length of whole lane tiles: shorter ones take the backward's gate
+    line = chip_smoke.phase_train(batch=2, seq_len=128, steps=3, **TINY_LM)
     assert line["ok"] and line["loss"][-1] < line["loss"][0]
     assert set(line["kernels"]["flash_attention"]) == {"interpret"}
+    assert set(line["kernels"]["flash_attention_bwd"]) == {"interpret"}
 
 
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "float32"])
@@ -82,7 +84,7 @@ def test_phase_resnet_tiny():
 
 def test_phase_cross_chip_on_four_virtual_devices():
     line = chip_smoke.phase_cross_chip(
-        4, TINY_LM, lm_batch=8, lm_seq_len=32, lm_steps=3, wd_vocab=4096,
+        4, TINY_LM, lm_batch=8, lm_seq_len=128, lm_steps=3, wd_vocab=4096,
         wd_slots=4, wd_emb_dim=8, wd_batch=64, wd_steps=3, loss_rtol=2e-2)
     assert line["ok"]
 

@@ -1,21 +1,69 @@
 """Pallas flash attention (ops/pallas_attention.py): K-blocked online-
 softmax kernel vs the dense reference. Runs in interpreter mode on CPU,
 which emulates TPU MXU semantics (bf16 multiply passes for f32 dots) —
-tolerances are set for that, and gradients are exact because the
-backward recomputes through the jnp reference."""
+tolerances are set for that. The backward is a kernel too
+(``flash_attention_bwd``): its gradients are held to the float32
+reference vjp at the forward's tolerance, and at 1e-5 to a jnp backward
+that rounds where the kernel rounds (``_rounded_backward``)."""
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 import paddle_tpu as ptpu
 from paddle_tpu import layers
+from paddle_tpu.ops import kernel_path
 from paddle_tpu.ops.pallas_attention import flash_attention, _reference
 
 # MXU-emulation tolerance (bf16 multiply passes inside the kernel dots)
 TOL = dict(rtol=2e-2, atol=2e-2)
+
+# the TPU interpreter with memory that was never written reading NaN and
+# its race detector on: a skipped tile that is read all the same, or an
+# accumulator that is not zeroed, fails the comparison
+STRICT = pltpu.InterpretParams(uninitialized_memory="nan",
+                               detect_races=True)
+
+
+def _rounded_backward(q, k, v, do, causal, seg=None):
+    """(dq, dk, dv) of attention over [BH, T, D] by the kernel's own
+    mathematics in plain jnp: float32 scores and statistics, ``p`` and
+    ``ds`` rounded to the operand dtype where they enter a product, the
+    output rounded before ``delta = rowsum(dO * O)``, ``scale`` on the
+    float32 sums."""
+    dt, f32 = q.dtype, jnp.float32
+    t, scale = q.shape[1], q.shape[-1] ** -0.5
+    q, k, v, do = (x.astype(f32) for x in (q, k, v, do))
+    mask = jnp.ones((1, t, t), bool)
+    if causal:
+        mask = mask & jnp.tril(jnp.ones((t, t), bool))[None]
+    if seg is not None:
+        mask = mask & (seg[:, :, None] == seg[:, None, :]) & \
+            (seg[:, None, :] != 0)
+    s = jnp.where(mask, jnp.einsum("bqd,bkd->bqk", q, k) * scale, -1e30)
+    lse = jax.nn.logsumexp(s, axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+    p_mxu = p.astype(dt).astype(f32)
+    o = jnp.einsum("bqk,bkd->bqd", p_mxu, v).astype(dt).astype(f32)
+    delta = jnp.sum(o * do, axis=-1, keepdims=True)
+    ds = (p * (jnp.einsum("bqd,bkd->bqk", do, v) - delta)
+          ).astype(dt).astype(f32)
+    return tuple(x.astype(dt) for x in (
+        jnp.einsum("bqk,bkd->bqd", ds, k) * scale,
+        jnp.einsum("bqk,bqd->bkd", ds, q) * scale,
+        jnp.einsum("bqk,bqd->bkd", p_mxu, do)))
+
+
+def _bwd_paths(since=None):
+    """Traced ``flash_attention_bwd`` sites by path, less those of
+    ``since`` (an earlier reading: other tests of the process count
+    too)."""
+    now = kernel_path.counts().get("flash_attention_bwd", {})
+    return {p: n - (since or {}).get(p, 0) for p, n in now.items()
+            if n != (since or {}).get(p, 0)}
 
 
 class TestFlashKernel:
@@ -36,7 +84,10 @@ class TestFlashKernel:
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_gradients_match_reference_exactly(self, causal):
+        """The backward kernel against the float32 reference vjp (one
+        tile: T 512 is one block of q and of k)."""
         q, k, v = self._data(t=512)
+        before = _bwd_paths()
 
         def f(q, k, v):
             return flash_attention(q, k, v, causal=causal,
@@ -49,10 +100,62 @@ class TestFlashKernel:
 
         gf = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(r, argnums=(0, 1, 2))(q, k, v)
+        assert _bwd_paths(before) == {"interpret": 1}
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a),
                                        np.asarray(b).reshape(a.shape),
-                                       rtol=1e-6, atol=1e-6)
+                                       **TOL)
+
+    @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                           ("bfloat16", 1e-2)])
+    @pytest.mark.parametrize("t,causal,seg", [
+        (128, True, None),        # one tile
+        (512, False, None),
+        (768, True, None),        # divisor blocks: 2 x 384 both ways
+        (1024, True, None),       # 2 x 2 tiles, one skipped
+        (1280, False, None),      # bq 256 != bk 320
+        (1280, True, None),       # ... and tiles the diagonal cuts askew
+        (1024, True, "padding"),
+        (1024, False, "padding"),
+        (1280, True, "packed"),   # bq = bk = 256 (whole lane tiles)
+        (1024, False, "packed"),
+    ])
+    def test_backward_kernel_matches_rounded_backward(self, t, causal, seg,
+                                                      dtype, tol):
+        """The kernel's gradients against the same mathematics in jnp,
+        rounding where the kernel rounds: 1e-5 of the largest gradient
+        in float32, half an ulp's worth in bfloat16. Segment cases hold
+        fully masked rows (padding), whose gradients are exactly zero."""
+        bh, d = 2, 32
+        rs = np.random.RandomState(t + causal)
+        q, k, v, do = (jnp.asarray(rs.randn(bh, t, d), dtype)
+                       for _ in range(4))
+        ids = None
+        if seg == "padding":
+            ids = (jnp.arange(t)[None, :] <
+                   jnp.asarray([t - 200, t])[:, None]).astype(jnp.int32)
+        elif seg == "packed":      # two sequences and a padded tail
+            ids = jnp.broadcast_to(jnp.concatenate([
+                jnp.full((t // 2 - 72,), 1), jnp.full((t // 2,), 2),
+                jnp.zeros((72,))]).astype(jnp.int32), (bh, t))
+        before = _bwd_paths()
+        # [B, H, T, D] with a batch row a batch-head: ids differ by row
+        _, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q[:, None], k[:, None], v[:, None], causal=causal,
+                segment_ids=ids, interpret=STRICT)[:, 0], q, k, v)
+        got = vjp(do)
+        assert _bwd_paths(before) == {"interpret": 1}
+        want = _rounded_backward(q, k, v, do, causal, ids)
+        for a, b in zip(got, want):
+            a, b = (np.asarray(x, np.float32) for x in (a, b))
+            assert np.isfinite(a).all()
+            assert np.abs(a - b).max() <= tol * np.abs(b).max()
+        if ids is not None:
+            pad = np.asarray(ids) == 0
+            assert pad.any()
+            for g in got:
+                assert not np.asarray(g, np.float32)[pad].any()
 
     def test_ragged_length_falls_back_to_reference(self):
         q, k, v = self._data(t=100)  # 100 % 512 != 0
@@ -124,12 +227,14 @@ class TestBlockSelection:
                                    **TOL)
 
     def test_chunked_backward_matches_dense_grads(self):
-        """The O(bq*T) chunked backward == dense reference grads."""
+        """The tiled backward kernel == dense reference grads (T=768:
+        two blocks of 384 rows each way, one tile skipped)."""
         from paddle_tpu.ops import pallas_attention as pa
         rs = np.random.RandomState(1)
         q = jnp.asarray(rs.randn(1, 2, 768, 32).astype("float32"))
         k = jnp.asarray(rs.randn(1, 2, 768, 32).astype("float32"))
         v = jnp.asarray(rs.randn(1, 2, 768, 32).astype("float32"))
+        before = _bwd_paths()
 
         def f(q, k, v):
             return (pa.flash_attention(q, k, v, causal=True) *
@@ -143,9 +248,11 @@ class TestBlockSelection:
 
         gf = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(r, argnums=(0, 1, 2))(q, k, v)
+        assert _bwd_paths(before) == {"interpret": 1}
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-5)
+                                       rtol=TOL["rtol"],
+                                       atol=TOL["atol"] * 32)
 
 
 def test_flash_flag_is_part_of_the_compile_cache_key():
@@ -247,6 +354,25 @@ def test_genuinely_ragged_length_uses_dense_fallback(monkeypatch):
     want = ref(q[0], q[0], q[0], True).reshape(out.shape)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("t", [100, 64, 288])
+def test_lengths_off_the_lane_tiles_differentiate_the_reference(t):
+    """The backward kernel takes lengths of whole lane tiles. T=100 is
+    ragged both ways; 64 (one block) and 288 (two of 144) run the forward
+    kernel, keep no statistics, and their gradients are the reference's
+    vjp, counted ``xla``."""
+    rs = np.random.RandomState(t)
+    q, k, v = (jnp.asarray(rs.randn(2, t, 32).astype("float32"))
+               for _ in range(3))
+    before = _bwd_paths()
+    gf = jax.grad(lambda *a: (flash_attention(*a, causal=True) ** 2).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    assert _bwd_paths(before) == {"xla": 1}
+    gr = jax.grad(lambda *a: (_reference(*a, True) ** 2).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL)
 
 
 def test_flash_under_distributed_strategy_contract():
@@ -414,7 +540,10 @@ class TestSegmentMasks:
         gr = jax.grad(r, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-6, atol=1e-6)
+                                       **TOL)
+        # padded rows (ids 0 from row 320 on): no gradient at all
+        for g in gf:
+            assert not np.asarray(g)[:, :, 320:].any()
 
     def test_multihead_op_keylength_on_flash_matches_dense(self):
         """The op-level path: KeyLength + flash flag == KeyLength dense

@@ -76,11 +76,12 @@ def _flash_seg(q, k, v, seg):
                               interpret=False)
 
 
-def _flash_grad(q, k, v):
-    # the backward is a chunked XLA recompute, no kernel of its own:
+def _flash_grad(q, k, v, *seg):
     # keep the forward's value live, as a train step does
-    return jax.value_and_grad(lambda *a: _flash(*a).astype(F32).sum(),
-                              argnums=(0, 1, 2))(q, k, v)
+    fn = _flash_seg if seg else _flash
+    return jax.value_and_grad(
+        lambda *a: fn(*a, *seg).astype(F32).sum(), argnums=(0, 1, 2))(
+            q, k, v)
 
 
 @pytest.mark.parametrize("fn,extra,name", [
@@ -88,9 +89,54 @@ def _flash_grad(q, k, v):
     (_flash_seg, (((8, 1024), I32),), "flash_attention_fwd_seg"),
     # under differentiation XLA names it from "jvp(flash_attention_fwd)"
     (_flash_grad, (), "jvp_flash_attention_fwd_"),
-], ids=["causal", "causal_segment_ids", "custom_vjp_backward"])
+], ids=["causal", "causal_segment_ids", "custom_vjp_forward"])
 def test_flash_attention_compiles(chip, fn, extra, name):
     assert _has_kernel(_compile(chip, fn, _QKV, _QKV, _QKV, *extra), name)
+
+
+def _bwd_paths(since=None):
+    """Traced ``flash_attention_bwd`` sites by path, less those of
+    ``since`` (an earlier reading)."""
+    now = kernel_path.counts().get("flash_attention_bwd", {})
+    return {p: n - (since or {}).get(p, 0) for p, n in now.items()
+            if n != (since or {}).get(p, 0)}
+
+
+@pytest.mark.parametrize("qkv,extra,name", [
+    # the benchmark's training cells: [64, 2048, 128] bf16 a chip
+    (((4, 16, 2048, 128), BF16), (), "flash_attention_bwd_"),
+    (_QKV, (((8, 1024), I32),), "flash_attention_bwd_seg_"),
+    # two heads a lane tile: the blocks are whole in their last dim
+    (((8, 8, 1024, 64), BF16), (), "flash_attention_bwd_"),
+    (((2, 4, 1280, 128), F32), (), "flash_attention_bwd_"),
+], ids=["training_cell", "segment_ids", "head_dim_64", "f32_divisor_blocks"])
+def test_flash_attention_backward_compiles(chip, qkv, extra, name):
+    """Under ``jax.grad`` the program holds the forward kernel (with its
+    row statistics) and ``flash_attention_bwd``, both counted compiled,
+    and nothing of a chunked XLA backward: no float32 array of a chunk's
+    scores against every key."""
+    before = _bwd_paths()
+    hlo = _compile(chip, _flash_grad, qkv, qkv, qkv, *extra)
+    assert _bwd_paths(before) == {"compiled": 1}
+    # XLA names it from "transpose(jvp(flash_attention_bwd))"
+    assert _has_kernel(hlo, "transpose_jvp_" + name + "_"), hlo[-3000:]
+    assert _has_kernel(hlo, "jvp_" + name.replace("bwd", "fwd"))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    t = qkv[0][2]
+    # (the row statistics are [BH, 1, T]: one row, not a chunk's)
+    assert not re.search(r"f32\[\d+,(?!1,)\d+,%d\]" % t, hlo), \
+        "a float32 [.., T] array of scores in the program"
+
+
+def test_flash_attention_backward_off_the_lane_tiles_takes_reference(chip):
+    """T = 288 = 2 x 144 rows: the forward kernel tiles it, the row
+    statistics (sliced along lanes a q block at a time) do not. The gate
+    hands the gradient to the reference's vjp, counted ``xla``."""
+    before = _bwd_paths()
+    qkv = ((2, 4, 288, 128), BF16)
+    hlo = _compile(chip, _flash_grad, qkv, qkv, qkv)
+    assert _bwd_paths(before) == {"xla": 1}
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
 
 
 def _paged(chip, num_heads, head_dim, dtype, query=F32, slots=8,

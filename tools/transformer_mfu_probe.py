@@ -5,8 +5,8 @@ bench family.
 
 Usage (on the TPU chip):
   python tools/transformer_mfu_probe.py --mode step [--batch 8 --seqlen 1024]
-  python tools/transformer_mfu_probe.py --mode kernel
-  python tools/transformer_mfu_probe.py --mode sweep
+  python tools/transformer_mfu_probe.py --mode kernel   # fwd and bwd alone
+  python tools/transformer_mfu_probe.py --mode sweep    # fwd block sizes
 """
 
 import argparse
@@ -95,102 +95,77 @@ def bench_step(batch, seqlen, d=2048, L=12, H=16, vocab=32768,
         return out
 
 
-def bench_kernel(block_q, block_k, b=8, h=16, t=1024, dd=128,
-                 causal=True, n_iter=8, bwd=True):
-    """Flash kernel fwd(+bwd) at the bench attention shape, chained
-    in-jit; block_k is applied by monkey-patching the cap in _forward
-    (it is a fixed 512 today)."""
+def _live_share(t, bq, bk, causal):
+    """Share of the (q-block, k-block) tiles a causal mask leaves."""
+    if not causal:
+        return 1.0
+    nq, nk = t // bq, t // bk
+    live = sum(1 for i in range(nq) for j in range(nk)
+               if i * bq + bq - 1 >= j * bk)
+    return live / (nq * nk)
+
+
+def _time(fn, *args, n_iter):
+    import jax
+    jax.block_until_ready(fn(*args))          # compile and warm
+    t0 = time.perf_counter()
+    for _ in range(n_iter):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / n_iter * 1e3
+
+
+def bench_kernel(block_q, block_k, b=4, h=16, t=2048, dd=128,
+                 causal=True, n_iter=20, bwd=True):
+    """The flash kernels alone at the training cells' attention shape
+    ([64, 2048, 128] bf16 a chip): the forward under differentiation
+    (``pa._forward`` on blocks of ``block_q`` x ``block_k``, row statistics
+    kept) and, with ``bwd``, the backward kernel (``pa._backward``, its own
+    blocks) on that forward's output. Each is a jitted call on device
+    arrays, timed over ``n_iter`` calls. ``peak_share`` counts every
+    tile's products (2 forward, 5 backward, each 2*T*T*D a batch-head),
+    ``peak_share_live`` only the tiles the causal mask leaves."""
     import jax
     import jax.numpy as jnp
-    from functools import partial
     from paddle_tpu.ops import pallas_attention as pa
 
+    dev = jax.devices()[0]
+    peak = _PEAK[dev.device_kind]
     rs = np.random.RandomState(0)
-    q = jnp.asarray(rs.randn(b, h, t, dd), jnp.bfloat16)
-    k = jnp.asarray(rs.randn(b, h, t, dd), jnp.bfloat16)
-    v = jnp.asarray(rs.randn(b, h, t, dd), jnp.bfloat16)
-
-    orig_forward = pa._forward
-
-    def patched(q_, k_, v_, seg, causal_, bq_, interpret):
-        bh, t_, d_ = q_.shape
-        bq = pa._block_size(t_, block_q)
-        bk = pa._block_size(t_, block_k)
-        if not bq or not bk:
-            return pa._reference(q_, k_, v_, causal_, seg)
-        import functools as ft
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-        grid = (bh, t_ // bq, t_ // bk)
-        kw = dict(scale=d_ ** -0.5, causal=causal_, block_q=bq,
-                  block_k=bk, nk=t_ // bk)
-        return pl.pallas_call(
-            ft.partial(pa._kernel, **kw),
-            in_specs=[
-                pl.BlockSpec((1, bq, d_), lambda b2, i, j: (b2, i, 0)),
-                pl.BlockSpec((1, bk, d_), lambda b2, i, j: (b2, j, 0)),
-                pl.BlockSpec((1, bk, d_), lambda b2, i, j: (b2, j, 0))],
-            out_shape=jax.ShapeDtypeStruct((bh, t_, d_), q_.dtype),
-            grid=grid,
-            out_specs=pl.BlockSpec((1, bq, d_),
-                                   lambda b2, i, j: (b2, i, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((bq, d_), jnp.float32),
-                pltpu.VMEM((bq, 128), jnp.float32),
-                pltpu.VMEM((bq, 128), jnp.float32)],
-            interpret=interpret)(q_, k_, v_)
-
-    pa._forward = patched
+    q, k, v, do = (jnp.asarray(rs.randn(b * h, t, dd), jnp.bfloat16)
+                   for _ in range(4))
+    product = 2.0 * t * t * dd * b * h
+    out = {"shape": [b * h, t, dd], "causal": causal,
+           "device": dev.device_kind}
     try:
-        # the chain must CONSUME every output (a *0 or dead gk/gv lets
-        # XLA DCE the work) and re-inject a scalar so iterations
-        # serialize without changing the values materially
+        fwd = jax.jit(lambda *a: pa._forward(
+            *a, None, causal, block_q, block_k, False, with_lse=True))
+        ms = _time(fwd, q, k, v, n_iter=n_iter)
+        rows = [dict(out, kernel="flash_attention_fwd", block_q=block_q,
+                     block_k=block_k, ms=round(ms, 3),
+                     peak_share=round(2 * product / ms / 1e-3 / peak, 4),
+                     peak_share_live=round(
+                         2 * product * _live_share(t, block_q, block_k,
+                                                   causal)
+                         / ms / 1e-3 / peak, 4))]
         if bwd:
-            def loss_fn(q_, k_, v_):
-                o = pa.flash_attention(q_, k_, v_, causal=causal)
-                return jnp.sum(o.astype(jnp.float32) ** 2)
-
-            g = jax.grad(loss_fn, argnums=(0, 1, 2))
-
-            @jax.jit
-            def chain(q_, k_, v_):
-                def body(c, _):
-                    gq, gk, gv = g(q_ + c.astype(q_.dtype), k_, v_)
-                    s = (jnp.sum(gq.astype(jnp.float32)) +
-                         jnp.sum(gk.astype(jnp.float32)) +
-                         jnp.sum(gv.astype(jnp.float32)))
-                    return s * 1e-30, None
-                c, _ = jax.lax.scan(body, jnp.float32(0), None,
-                                    length=n_iter)
-                return c
-            _sync(chain(q, k, v))
-            t0 = time.perf_counter()
-            _sync(chain(q, k, v))
-            ms = (time.perf_counter() - t0) / n_iter * 1e3
-        else:
-            @jax.jit
-            def chain_f(q_, k_, v_):
-                def body(c, _):
-                    o = pa.flash_attention(q_ + c.astype(q_.dtype),
-                                           k_, v_, causal=causal)
-                    return jnp.sum(o.astype(jnp.float32)) * 1e-30, None
-                c, _ = jax.lax.scan(body, jnp.float32(0), None,
-                                    length=n_iter)
-                return c
-            _sync(chain_f(q, k, v))
-            t0 = time.perf_counter()
-            _sync(chain_f(q, k, v))
-            ms = (time.perf_counter() - t0) / n_iter * 1e3
+            o, lse = fwd(q, k, v)
+            ms = _time(jax.jit(lambda *a: pa._backward(
+                *a[:5], None, a[5], causal, False)), q, k, v, o, lse, do,
+                n_iter=n_iter)
+            bq = pa._block_size(t, pa._BWD_BLOCK, 128)
+            bk = pa._block_size(t, pa._BWD_BLOCK)
+            rows.append(dict(
+                out, kernel="flash_attention_bwd", block_q=bq, block_k=bk,
+                ms=round(ms, 3),
+                peak_share=round(5 * product / ms / 1e-3 / peak, 4),
+                peak_share_live=round(
+                    5 * product * _live_share(t, bq, bk, causal)
+                    / ms / 1e-3 / peak, 4)))
+        return rows
     except Exception as e:
-        pa._forward = orig_forward
-        return {"block_q": block_q, "block_k": block_k,
-                "err": str(e)[:160]}
-    finally:
-        pa._forward = orig_forward
-    # causal useful flops: ~half the full T^2 (counted full both ways
-    # in MFU conventions; report raw time, that's what matters)
-    return {"block_q": block_q, "block_k": block_k, "bwd": bwd,
-            "ms": round(ms, 2)}
+        return [dict(out, block_q=block_q, block_k=block_k,
+                     err=str(e)[:160])]
 
 
 def main():
@@ -211,13 +186,12 @@ def main():
                      (12, 1024)]:
             print(json.dumps(bench_step(b, t)), flush=True)
     elif args.mode == "kernel":
-        for bwd in (False, True):
-            print(json.dumps(bench_kernel(256, 512, bwd=bwd)),
-                  flush=True)
+        for row in bench_kernel(256, 512):
+            print(json.dumps(row), flush=True)
     elif args.mode == "sweep":
         for bq in (256, 512, 1024):
             for bk in (256, 512, 1024):
-                print(json.dumps(bench_kernel(bq, bk, bwd=False)),
+                print(json.dumps(bench_kernel(bq, bk, bwd=False)[0]),
                       flush=True)
 
 
