@@ -50,17 +50,23 @@ def rotary_embedding(x, head_dim, theta=10000.0, pos=None, per_row=False,
     return out
 
 
-def linear(x, size, param_attr, dtype=None, std=0.02, **kwargs):
+def linear(x, size, param_attr, dtype=None, std=0.02, transpose_w=False,
+           **kwargs):
     """``x @ W`` over the last axis with no bias, W [in, size] created and
     held in ``dtype`` (default: x's). The product is exact and float32
-    whatever W is held in (ops/moe_ops.py ``linear``)."""
+    whatever W is held in (ops/moe_ops.py ``linear``). ``transpose_w``: W
+    is [size, in] and read as it lies: a head over the embedding's own
+    parameter."""
     helper = LayerHelper("linear", **kwargs)
+    shape = [x.shape[-1], size]
     w = helper.create_parameter(
-        param_attr, shape=[x.shape[-1], size], dtype=dtype or x.dtype,
+        param_attr, shape=shape[::-1] if transpose_w else shape,
+        dtype=dtype or x.dtype,
         default_initializer=NormalInitializer(0.0, std))
     out = helper.create_tmp_variable("float32")
     helper.append_op(type="linear", inputs={"X": [x.name], "W": [w.name]},
-                     outputs={"Out": [out.name]})
+                     outputs={"Out": [out.name]},
+                     attrs={"transpose_w": True} if transpose_w else {})
     return out
 
 
@@ -75,14 +81,15 @@ def swiglu(x, d_ff, prefix, dtype=None, **kwargs):
 
 def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
             route_scale=1.0, expert_offset=0, experts_held=None,
-            dtype=None, std=0.02, **kwargs):
+            dtype=None, std=0.02, scoring="sigmoid", **kwargs):
     """The routed experts of a sparse feed-forward over x [.., d]
     (ops/moe_ops.py ``moe_ffn``): the router ``<prefix>.router.w`` [d, E]
     and the selection bias ``<prefix>.expert_bias`` [E] in float32, the
     held experts ``[expert_offset, expert_offset + experts_held)`` stacked
     as ``<prefix>.experts.gate.w``, ``.up.w`` [E_held, d, d_ff] and
-    ``.down.w`` [E_held, d_ff, d] in ``dtype``. Returns (out float32,
-    counts [E_held] int32)."""
+    ``.down.w`` [E_held, d_ff, d] in ``dtype``. ``scoring`` is the
+    router's (``sigmoid``, or ``softmax_topk``: a softmax over the chosen
+    logits). Returns (out float32, counts [E_held] int32)."""
     helper = LayerHelper("moe_ffn", **kwargs)
     d = x.shape[-1]
     held = num_experts if experts_held is None else experts_held
@@ -102,15 +109,18 @@ def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
                              ("down", [held, d_ff, d]))]
     out = helper.create_tmp_variable("float32")
     counts = helper.create_tmp_variable("int32", stop_gradient=True)
+    attrs = {"num_experts": num_experts, "top_k": top_k,
+             "route_norm": route_norm, "route_scale": route_scale,
+             "expert_offset": expert_offset}
+    if scoring != "sigmoid":
+        attrs["scoring"] = scoring
     helper.append_op(
         type="moe_ffn",
         inputs={"X": [x.name], "RouterW": [router.name],
                 "ExpertBias": [bias.name], "WGate": [stacks[0].name],
                 "WUp": [stacks[1].name], "WDown": [stacks[2].name]},
         outputs={"Out": [out.name], "Counts": [counts.name]},
-        attrs={"num_experts": num_experts, "top_k": top_k,
-               "route_norm": route_norm, "route_scale": route_scale,
-               "expert_offset": expert_offset})
+        attrs=attrs)
     return out, counts
 
 

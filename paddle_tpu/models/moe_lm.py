@@ -1,11 +1,17 @@
 """A decoder LM driven by a per-layer spec and the shape of its block, given
-as data: a feed-forward that is dense in the leading layers and sparse
-experts with a shared expert in the rest, behind an attention that is
+as data: a feed-forward that is dense in the leading layers (where there
+are any) and sparse experts with a shared expert in the rest, behind a
+mixer that is layer by layer a Mamba-2 state-space mixer (``"mamba"``,
+``ops/ssm_ops.py``) or an attention that is
 
 * ``gqa``: grouped queries, full or windowed layer by layer, rotary
   positions in the window layers, RMSNorm before and after each half, a
   gated attention output and a scaled embedding (the ``afmoe`` family; the
-  equations are in ``benchmarks/reference/afmoe.py``); or
+  equations are in ``benchmarks/reference/afmoe.py``), each of which is
+  data: without norms on q and k, the gate and positions, with a softmax
+  scale, residual, embedding and logit multipliers of its own and the head
+  tied to the embedding it is the ``granitemoehybrid`` family's attention
+  layer (``benchmarks/reference/granitemoehybrid.py``); or
 * ``latent``: MLA (``ops/mla_ops.py``) with low-rank queries, YaRN rotary
   positions on the rope lanes, RMSNorm before each half only, no gate and
   no embedding scale (the ``kimi_k2`` / DeepSeek-V3 family;
@@ -16,7 +22,12 @@ weights); :func:`moe_lm_session` builds the paged prefill and decode
 programs through ``transformer.lm_session``, with one kind of layer cache
 for the full layers and one, which frees blocks behind the window, for the
 window layers, or for latent attention the one **latent kind**: one pool a
-layer whose row is a token's ``(c, k_r)`` padded to whole lane tiles.
+layer whose row is a token's ``(c, k_r)`` padded to whole lane tiles. A
+model with state-space layers has beside its paged kind a **state kind**:
+a pool of one fixed-size float32 row a slot in each such layer (the scan's
+state, the convolution's last inputs and the tokens absorbed), rewritten
+whole every step; its prefill starts from a zero state, so it takes
+neither a shared prefix nor speculation.
 Matmul weights, the embedding and the head are created and
 held in ``param_dtype``; norms, router and expert bias are float32, and so
 is every activation: the products are exact (ops/moe_ops.py says why), so
@@ -33,7 +44,7 @@ from .transformer import lm_session
 
 __all__ = ["moe_lm", "moe_lm_session", "MoeLM"]
 
-SLIDING, FULL = "sliding_attention", "full_attention"
+SLIDING, FULL, MAMBA = "sliding_attention", "full_attention", "mamba"
 
 
 class MoeLM:
@@ -48,11 +59,14 @@ class MoeLM:
                  rms_eps=1e-5, route_norm=True, route_scale=1.0,
                  embed_scale=1.0, param_dtype="float32", expert_offset=0,
                  experts_held=None, init_std=0.02, attention="gqa",
-                 post_norms=True, latent=None, rope_scaling=None):
-        unknown = set(layer_types) - {SLIDING, FULL}
+                 post_norms=True, latent=None, rope_scaling=None,
+                 scoring="sigmoid", qk_norm=True, attn_gate=True,
+                 attn_scale=None, residual_scale=None, logit_scale=None,
+                 tie_embeddings=False, mamba=None, shared_d_ff=None):
+        unknown = set(layer_types) - {SLIDING, FULL, MAMBA}
         if unknown:
-            raise ValueError("layer_types holds %s: a layer is %r or %r"
-                             % (sorted(unknown), SLIDING, FULL))
+            raise ValueError("layer_types holds %s: a layer is %r, %r or %r"
+                             % (sorted(unknown), SLIDING, FULL, MAMBA))
         if attention not in ("gqa", "latent"):
             raise ValueError("attention is 'gqa' or 'latent', not %r"
                              % (attention,))
@@ -63,6 +77,7 @@ class MoeLM:
         self.d, self.nh, self.nkv, self.hd = (d_model, num_heads,
                                                num_kv_heads, head_dim)
         self.d_ff, self.moe_d_ff = d_ff, moe_d_ff
+        self.shared_d_ff = shared_d_ff or moe_d_ff
         self.num_experts, self.top_k = num_experts, top_k
         self.layer_types = tuple(layer_types)
         self.num_dense_layers = num_dense_layers
@@ -74,6 +89,11 @@ class MoeLM:
         self.std = init_std
         self.attention, self.post_norms = attention, post_norms
         self.yarn = rope_scaling
+        self.scoring, self.qk_norm, self.attn_gate = (scoring, qk_norm,
+                                                      attn_gate)
+        self.attn_scale = attn_scale
+        self.residual_scale, self.logit_scale = residual_scale, logit_scale
+        self.tie_embeddings = tie_embeddings
         # expert pairs a row of a step routes, held here or not
         self.pairs_per_row = top_k * (len(self.layer_types)
                                       - num_dense_layers)
@@ -85,8 +105,25 @@ class MoeLM:
         present = [t for t in (FULL, SLIDING) if t in self.layer_types]
         self.kinds = tuple(("full", None) if t == FULL else
                            ("window", sliding_window) for t in present)
-        self.cache_layers = [(num_kv_heads * head_dim, present.index(t))
-                             for t in self.layer_types]
+        state_row = None
+        if MAMBA in self.layer_types:
+            if not present:
+                raise ValueError("a model of state-space layers alone has "
+                                 "no paged kind for the session to size")
+            # (num_heads, head_dim, state_dim, conv_width, chunk); a slot's
+            # row in a layer: the scan's state and the convolution's inputs
+            self.mamba = dict(mamba)
+            lanes = mamba["num_heads"] * mamba["head_dim"] \
+                + 2 * mamba["state_dim"]
+            state_row = ((mamba["num_heads"], mamba["head_dim"],
+                          mamba["state_dim"]), (mamba["conv_width"], lanes))
+            self.kinds += (("state", None),)
+            # a prompt's rows start from a zero state
+            self.prefill_sees_history = False
+        self.cache_layers = [
+            (state_row, len(present)) if t == MAMBA else
+            (num_kv_heads * head_dim, present.index(t))
+            for t in self.layer_types]
 
     def _latent_sizes(self, q_rank, kv_rank, nope_dim, rope_dim, v_dim):
         """Latent attention's widths, its one kind of layer cache and the
@@ -171,8 +208,24 @@ class MoeLM:
                     table=ctx["table"], **attend)
         return layers.mla_attention(q, c, k_r, block_rows=512, **attend)
 
+    def _mixer(self, a, i, ctx):
+        """a [B, T, d] -> the Mamba-2 mixer's output [B, T, d]: whole
+        sequences, a prefill into the slot's state row, or a decode step
+        over the layer's state pool."""
+        where = {}
+        if ctx is not None:
+            where = dict(state=ctx["caches"][i],
+                         table=ctx["tables"][self.cache_layers[i][1]])
+            if ctx["mode"] == "decode":
+                where["pos"] = ctx["pos"]
+            else:
+                where["length"] = ctx["key_length"]
+        return layers.mamba2_mixer(
+            a, prefix="moe_lm.l%d.mamba" % i, epsilon=self.eps,
+            dtype=self.dtype, std=self.std, **dict(self.mamba, **where))
+
     def _attention(self, a, i, ctx):
-        """a [B, T, d] -> the gated attention output [B, T, H*D], through
+        """a [B, T, d] -> the (gated) attention output [B, T, H*D], through
         the layer's paged cache where ``ctx`` has one."""
         if self.attention == "latent":
             return self._latent_attention(a, i, ctx)
@@ -181,9 +234,11 @@ class MoeLM:
         q = self._linear(a, self.nh * self.hd, p + "q")
         k = self._linear(a, self.nkv * self.hd, p + "k")
         v = self._linear(a, self.nkv * self.hd, p + "v")
-        gate = self._linear(a, self.nh * self.hd, p + "gate")
-        q = self._norm(q, p + "q_norm", self.hd)
-        k = self._norm(k, p + "k_norm", self.hd)
+        if self.attn_gate:
+            gate = self._linear(a, self.nh * self.hd, p + "gate")
+        if self.qk_norm:
+            q = self._norm(q, p + "q_norm", self.hd)
+            k = self._norm(k, p + "k_norm", self.hd)
         if windowed:
             # positions only where the window bounds what they span
             rope = dict(self._positions(ctx), head_dim=self.hd,
@@ -195,6 +250,8 @@ class MoeLM:
         attrs = {"num_heads": self.nh, "num_kv_heads": self.nkv}
         if windowed:
             attrs["window"] = self.window
+        if self.attn_scale is not None:
+            attrs["scale"] = self.attn_scale
         if ctx is None:
             helper.append_op(
                 type="multihead_attention",
@@ -224,6 +281,8 @@ class MoeLM:
                                          CacheK=[ck.name],
                                          CacheV=[cv.name]),
                              outputs={"Out": [out.name]}, attrs=attrs)
+        if not self.attn_gate:
+            return out
         return layers.elementwise_mul(out, layers.sigmoid(gate))
 
     def _feed_forward(self, m, i):
@@ -232,13 +291,20 @@ class MoeLM:
             return layers.swiglu(m, self.d_ff, "moe_lm.l%d.mlp" % i,
                                  self.dtype), None
         p = "moe_lm.l%d.moe" % i
-        shared = layers.swiglu(m, self.moe_d_ff, p + ".shared", self.dtype)
+        shared = layers.swiglu(m, self.shared_d_ff, p + ".shared",
+                               self.dtype)
         routed, counts = layers.moe_ffn(
             m, self.num_experts, self.top_k, self.moe_d_ff, p,
             route_norm=self.route_norm, route_scale=self.route_scale,
             expert_offset=self.expert_offset,
-            experts_held=self.experts_held, dtype=self.dtype, std=self.std)
+            experts_held=self.experts_held, dtype=self.dtype, std=self.std,
+            scoring=self.scoring)
         return layers.elementwise_add(routed, shared), counts
+
+    def _residual(self, h, o):
+        if self.residual_scale is not None:
+            o = layers.scale(o, self.residual_scale)
+        return layers.elementwise_add(h, o)
 
     def hidden(self, tokens, ctx=None):
         """tokens [B, T] -> (h [B, T, d] float32 before the final norm,
@@ -255,23 +321,34 @@ class MoeLM:
         all_counts = []
         for i in range(len(self.layer_types)):
             a = self._norm(h, "l%d.norm_in" % i)
-            o = self._linear(self._attention(a, i, ctx), self.d,
-                             "l%d.attn.o" % i)
+            if self.layer_types[i] == MAMBA:
+                o = self._mixer(a, i, ctx)
+            else:
+                o = self._linear(self._attention(a, i, ctx), self.d,
+                                 "l%d.attn.o" % i)
             if self.post_norms:
                 o = self._norm(o, "l%d.norm_post_attn" % i)
-            h = layers.elementwise_add(h, o)
+            h = self._residual(h, o)
             f, counts = self._feed_forward(
                 self._norm(h, "l%d.norm_pre_mlp" % i), i)
             if self.post_norms:
                 f = self._norm(f, "l%d.norm_post_mlp" % i)
-            h = layers.elementwise_add(h, f)
+            h = self._residual(h, f)
             if counts is not None:
                 all_counts.append(counts)
         return h, all_counts
 
     def _head(self, h):
-        return self._linear(self._norm(h, "norm_final"), self.vocab_size,
-                            "lm_head")
+        h = self._norm(h, "norm_final")
+        if self.tie_embeddings:
+            # the embedding's own rows, read as they lie
+            logits = layers.linear(h, self.vocab_size, "moe_lm.embed.w",
+                                   self.dtype, self.std, transpose_w=True)
+        else:
+            logits = self._linear(h, self.vocab_size, "lm_head")
+        if self.logit_scale is not None:
+            logits = layers.scale(logits, self.logit_scale)
+        return logits
 
     # -- what lm_session calls ----------------------------------------------
     def logits(self, tokens, cache_ctx=None):
@@ -291,8 +368,9 @@ class MoeLM:
         return row, (layers.stack(counts, axis=0) if counts else None)
 
     def draft(self, overrides):
-        raise ValueError("moe_lm has no speculative draft (and a window "
-                         "kind of layer cache takes no speculation)")
+        raise ValueError("moe_lm has no speculative draft (and neither a "
+                         "window nor a state kind of layer cache takes "
+                         "speculation)")
 
 
 def moe_lm(tokens, labels, **sizes):
@@ -313,8 +391,9 @@ def moe_lm_session(slots, cache_len, prompt_buckets, block_size, num_blocks,
     """The paged prefill and decode programs of :func:`moe_lm` (a
     ``GenerationSpec``): ``num_blocks`` sizes the first kind of layer
     cache (the full layers', where the model has any), and
-    ``window_num_blocks`` the window layers' where it has both. Greedy;
-    positions are rotary, so a sequence is bounded by ``cache_len`` alone."""
+    ``window_num_blocks`` the window layers' where it has both; a state
+    kind has one row a slot. Greedy; positions are rotary or none, so a
+    sequence is bounded by ``cache_len`` alone."""
     model = MoeLM(**sizes)
     return lm_session(
         model, max_len=cache_len, slots=slots, cache_len=cache_len,
@@ -322,5 +401,5 @@ def moe_lm_session(slots, cache_len, prompt_buckets, block_size, num_blocks,
         cache_ns=cache_ns, dtype=kv_dtype,
         block_size=block_size, num_blocks=num_blocks, prefix_cache=False,
         decode_policy=None,
-        kind_blocks={"window": window_num_blocks}
+        kind_blocks={"window": window_num_blocks, "state": slots}
         if len(model.kinds) > 1 else None)

@@ -317,7 +317,13 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     the speculative draft's model.
     ``kind_blocks`` sizes the pools of the kinds after the first, by
     name; their table feeds are ``gen.ptab.<name>`` / ``gen.dtab.<name>``
-    and reach the model as ``cache_ctx["tables"]``, one per kind."""
+    and reach the model as ``cache_ctx["tables"]``, one per kind.
+    A kind named ``state`` is no rows of keys and values: a layer of it
+    names in ``cache_layers`` the shapes of a slot's row, ``(ssm, conv)``,
+    and has the pools ``ssm`` and ``conv`` (float32 whatever ``dtype``) and
+    ``at`` (int32, the tokens a row has absorbed), one row a slot; its
+    table feeds hold one entry a sequence."""
+    import numpy as np
     from .. import config as _config
     from ..core import unique_name as _un
     from ..core.framework import Program, program_guard
@@ -378,6 +384,14 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     num_blocks = int(num_blocks) or slots * max_blocks
     if prefix_cache is None:
         prefix_cache = bool(_config.get_flag("generation_prefix_cache"))
+    state_kind = any(name == "state" for name, _ in kinds)
+    if state_kind and (spec_k or prefix_cache):
+        raise ValueError(
+            "this model has a state kind of layer cache: a slot's state is "
+            "one row rewritten whole every step, which no prefix can share "
+            "(nothing keeps it as it was at a block's edge) and no "
+            "rejected draft can be rolled back from; it takes neither "
+            "prefix_cache nor speculate_k")
     if not getattr(model, "prefill_sees_history", True) and (
             spec_k or prefix_cache):
         raise ValueError(
@@ -385,22 +399,36 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
             "cache: it takes neither prefix_cache nor speculate_k")
     rows = [num_blocks] + [int((kind_blocks or {})[name])
                            for name, _ in kinds[1:]]
-    cache_shapes = [(rows[k], block_size, width)
-                    for width, k in model.cache_layers]
+
+    def layer_pools(width, k):
+        """(pool, shape, dtype) of a layer's cache variables."""
+        if kinds[k][0] == "state":
+            ssm, conv = width
+            return (("ssm", (rows[k],) + tuple(ssm), "float32"),
+                    ("conv", (rows[k],) + tuple(conv), "float32"),
+                    ("at", (rows[k],), "int32"))
+        return tuple((pool, (rows[k], block_size, width), dtype)
+                     for pool in pools)
+
+    # (name, shape, dtype) layer by layer, as the spec lists them
+    cache_vars = [tuple(("%s.l%d.%s" % (cache_ns, i, pool), shape, held)
+                        for pool, shape, held in layer_pools(width, k))
+                  for i, (width, k) in enumerate(model.cache_layers)]
 
     def make_cache_vars(program):
         block = program.global_block()
         return [tuple(block.create_var(
-            name="%s.l%d.%s" % (cache_ns, i, pool), shape=cache_shape,
-            dtype=dtype, persistable=True, stop_gradient=True)
-            for pool in pools) for i, cache_shape in enumerate(cache_shapes)]
+            name=name, shape=shape, dtype=held, persistable=True,
+            stop_gradient=True) for name, shape, held in layer)
+            for layer in cache_vars]
 
-    def more_tables(prefix, shape):
+    def more_tables(prefix, lead):
         """The table feeds of the kinds after the first, with the first
-        kind's feed in front: one per kind, as the model indexes them."""
-        return [layers.data("%s.%s" % (prefix, name), shape=shape,
-                            dtype="int32", append_batch_size=False)
-                for name, _ in kinds[1:]]
+        kind's feed in front: one per kind, as the model indexes them. A
+        sequence's table is ``max_blocks`` wide, a state kind's one."""
+        return [layers.data("%s.%s" % (prefix, name), shape=lead + [
+            1 if name == "state" else max_blocks], dtype="int32",
+            append_batch_size=False) for name, _ in kinds[1:]]
 
     def _policy_epilogue(row, seed=None, step=None, mask=None):
         """row [n, V] -> next token [n] under the resolved policy.
@@ -455,8 +483,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
             ptab = layers.data("gen.ptab", shape=[max_blocks],
                                dtype="int32", append_batch_size=False)
             cache_ctx = {"mode": "prefill", "caches": None, "table": ptab,
-                         "tables": [ptab] + more_tables("gen.ptab",
-                                                        [max_blocks]),
+                         "tables": [ptab] + more_tables("gen.ptab", []),
                          "hist": phist, "pos_idx": ppix,
                          "key_length": plen, "max_len": max_len}
             pseed, pstep, pmask, prefill_extra = _policy_feeds(
@@ -478,8 +505,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         dtab = layers.data("gen.dtab", shape=[slots, max_blocks],
                            dtype="int32", append_batch_size=False)
         cache_ctx = {"mode": "decode", "caches": None, "table": dtab,
-                     "tables": [dtab] + more_tables(
-                         "gen.dtab", [slots, max_blocks]),
+                     "tables": [dtab] + more_tables("gen.dtab", [slots]),
                      "pos": dpos, "max_len": max_len}
         dseed, dstep, dmask, decode_extra = _policy_feeds(
             "gen.d", slots)
@@ -585,14 +611,17 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
                       "gen.ptab.%s" % name if k else "gen.ptab",
                       "gen.dtab.%s" % name if k else "gen.dtab")
             for k, (name, window) in enumerate(kinds))
+    # what a block (a state kind: a row) holds over each kind's layers
+    kind_block_bytes = tuple(
+        sum(int(np.prod(shape[1:])) * np.dtype(held).itemsize
+            for layer, (_, lk) in zip(cache_vars, model.cache_layers)
+            if lk == k for _, shape, held in layer)
+        for k in range(len(kinds)))
 
     return GenerationSpec(
         slots=slots, cache_len=cache_len, max_len=max_len,
         prompt_buckets=prompt_buckets, bos_id=bos_id, eos_id=eos_id,
-        cache_vars=tuple(("%s.l%d.%s" % (cache_ns, i, kv), cache_shape,
-                          dtype)
-                         for i, cache_shape in enumerate(cache_shapes)
-                         for kv in pools),
+        cache_vars=tuple(var for layer in cache_vars for var in layer),
         prefill_programs=prefill_programs,
         prefill_feeds=("gen.ptok", "gen.plen", "gen.ppos", "gen.phist",
                        "gen.ppix", "gen.ptab") + prefill_extra,
@@ -611,4 +640,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         stats_fetch=None if stats is None else stats.name,
         routed_pairs=None if stats is None else slots * model.pairs_per_row,
         latent_layers=sum(kinds[k][0] == "latent"
-                          for _, k in model.cache_layers))
+                          for _, k in model.cache_layers),
+        state_layers=sum(kinds[k][0] == "state"
+                         for _, k in model.cache_layers),
+        kind_block_bytes=kind_block_bytes)

@@ -16,7 +16,7 @@ from ..core.registry import register_op
 from .. import parallel
 
 
-def _grouped_window_attention(q, k, v, nh, nkv, causal, window):
+def _grouped_window_attention(q, k, v, nh, nkv, causal, window, scale=None):
     """Dense attention of Q [B, T, H*D] on K, V [B, T, Hkv*D]: query head
     h on KV head ``h // (H / Hkv)``; with a ``window``, key j is visible
     to query i iff ``i - j < window``. The whole-sequence form of what the
@@ -27,7 +27,8 @@ def _grouped_window_attention(q, k, v, nh, nkv, causal, window):
     kh = k.reshape(b, tk, nkv, hd)
     vh = v.reshape(b, tk, nkv, hd)
     s = jnp.einsum("bqkgd,bckd->bkgqc", qh, kh,
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
+                   preferred_element_type=jnp.float32) * \
+        (hd ** -0.5 if scale is None else scale)
     rows, cols = jnp.arange(tq)[:, None], jnp.arange(tk)[None, :]
     mask = cols <= rows if causal else jnp.ones((tq, tk), bool)
     if window:
@@ -42,7 +43,8 @@ def _multihead_attention(ctx):
     """Q,K,V: [B, T, H*D] packed; attrs num_heads, causal; optional
     KeyLength [B] masking padded keys. Out: [B, T, H*D]. With attrs
     num_kv_heads (K, V are [B, T, Hkv*D]) or window: the dense grouped,
-    windowed form (no KeyLength, no kernel)."""
+    windowed form (no KeyLength, no kernel), whose scores take attr
+    ``scale`` where the model has one (absent: ``D^-1/2``)."""
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
     nh = ctx.attr("num_heads")
     causal = ctx.attr("causal", False)
@@ -52,7 +54,7 @@ def _multihead_attention(ctx):
     if ctx.attr("num_kv_heads") or ctx.attr("window"):
         return {"Out": _grouped_window_attention(
             q, k, v, nh, ctx.attr("num_kv_heads") or nh, causal,
-            ctx.attr("window"))}
+            ctx.attr("window"), ctx.attr("scale"))}
     qh = q.reshape(b, tq, nh, hd)
     kh = k.reshape(b, tk, nh, hd)
     vh = v.reshape(b, tk, nh, hd)

@@ -119,7 +119,8 @@ def _multihead_attention_decode_paged(ctx):
     """Q [S, 1, H*D], CacheK/CacheV [NB, BS, Hkv*D] pools, Pos [S] int
     (the row each slot's new token was just written to), Table [S, MB]
     int; attrs num_heads, and where the model has them num_kv_heads
-    (heads the pools hold; absent: num_heads) and window (absent: none).
+    (heads the pools hold; absent: num_heads), window (absent: none) and
+    scale (the scores' factor; absent: ``D^-1/2``).
     Out [S, 1, H*D]: each slot's single query
     attends its table-gathered cache rows [0, Pos[s]], with a window the
     last ``window`` of them (token parity with the O(L^2) reference
@@ -140,10 +141,11 @@ def _multihead_attention_decode_paged(ctx):
         from .pallas_attention import decode_attention_paged
         return {"Out": decode_attention_paged(
             q, ck, cv, length, table, nh, num_kv_heads=nkv,
-            window=window)}
+            window=window, scale=ctx.attr("scale"))}
     from .pallas_attention import _decode_paged_reference
     return {"Out": _decode_paged_reference(q, ck, cv, length, table,
-                                           nh, nkv, window)}
+                                           nh, nkv, window,
+                                           scale=ctx.attr("scale"))}
 
 
 def _largest_divisor(n, cap):
@@ -175,7 +177,8 @@ def _prefill_paged_dense(q, ck, cv, table, hist, nh):
     return out.transpose(1, 0, 2).reshape(1, p, dm)
 
 
-def _prefill_paged_blocked(q, ck, cv, table, hist, nh, nkv, window, r):
+def _prefill_paged_blocked(q, ck, cv, table, hist, nh, nkv, window, r,
+                           scale=None):
     """``r`` rows at a time, one block after the other, each against the
     chunks of ``r`` cached rows it can see and no others: from the chunk
     that holds the first row inside the window (or row 0) to the chunk
@@ -188,6 +191,7 @@ def _prefill_paged_blocked(q, ck, cv, table, hist, nh, nkv, window, r):
     _, p, dm = q.shape
     nb, bs, _ = ck.shape
     hd, group = dm // nh, nh // nkv
+    scale = hd ** -0.5 if scale is None else scale
     pages = -(-r // bs)                     # pages a chunk
     c = pages * bs
     mb = table.shape[0]
@@ -215,7 +219,7 @@ def _prefill_paged_blocked(q, ck, cv, table, hist, nh, nkv, window, r):
             vh = cv[ids].reshape(c, nkv, hd).transpose(1, 0, 2)
             s = jnp.einsum("hqd,hkd->hqk", qb, kh, precision=prec,
                            preferred_element_type=jnp.float32)
-            s = s * (hd ** -0.5)
+            s = s * scale
             cols = j * c + jnp.arange(c, dtype=jnp.int32)
             mask = cols[None, :] <= rows[:, None]
             if window is not None:
@@ -249,7 +253,8 @@ def _multihead_attention_prefill_paged(ctx):
     written through the table), CacheK/CacheV [NB, BS, Hkv*D] pools,
     Table [MB] int, Hist [1] int, Len [1] int; attrs num_heads, and
     where the model has them num_kv_heads (absent: num_heads), window
-    (absent: none) and block_rows (absent: every row at once).
+    (absent: none), block_rows (absent: every row at once) and, with
+    block_rows, scale (the scores' factor; absent: ``D^-1/2``).
     Out [1, P, H*D]: window row i (logical position Hist+i) attends
     table-gathered cache rows [0, Hist+i], with a window the last
     ``window`` of them — causal over the cached
@@ -276,4 +281,5 @@ def _multihead_attention_prefill_paged(ctx):
     return {"Out": _prefill_paged_blocked(
         q, ck, cv, table, hist, nh, ctx.attr("num_kv_heads") or nh,
         ctx.attr("window"),
-        _largest_divisor(q.shape[1], ctx.attr("block_rows")))}
+        _largest_divisor(q.shape[1], ctx.attr("block_rows")),
+        ctx.attr("scale"))}

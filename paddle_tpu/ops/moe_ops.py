@@ -75,17 +75,20 @@ def _add_pieces(y):
     return (y[2] + y[1]) + y[0]
 
 
-def exact_dot(x, w):
-    """x [n, d] float32 @ w [d, f], every product exact and the sums in
-    float32: a bfloat16 ``w`` meets x's three pieces as [3n, d] in one
+def exact_dot(x, w, transposed=False):
+    """x [n, d] float32 @ w [d, f] (``transposed``: w [f, d], contracted
+    over its second axis where it lies), every product exact and the sums
+    in float32: a bfloat16 ``w`` meets x's three pieces as [3n, d] in one
     pass; any other is multiplied at the highest precision."""
+    dims = (((1,), (1 if transposed else 0,)), ((), ()))
     if w.dtype != jnp.bfloat16:
-        return jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
-                       precision=_HIGHEST)
-    y = jnp.dot(jnp.concatenate(_pieces(x), axis=0), w,
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.DEFAULT)
-    return _add_pieces(y.reshape((3, x.shape[0], w.shape[1])))
+        return jax.lax.dot_general(x.astype(jnp.float32),
+                                   w.astype(jnp.float32), dims,
+                                   precision=_HIGHEST)
+    y = jax.lax.dot_general(jnp.concatenate(_pieces(x), axis=0), w, dims,
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.DEFAULT)
+    return _add_pieces(y.reshape((3, x.shape[0], y.shape[1])))
 
 
 def exact_ragged_dot(xs, w, counts):
@@ -107,10 +110,12 @@ def exact_ragged_dot(xs, w, counts):
 @register_op("linear")
 def _linear(ctx):
     """X [.., d], W [d, f] held in any dtype; Out float32 [.., f] =
-    ``X @ W``, exact (:func:`exact_dot`)."""
+    ``X @ W``, exact (:func:`exact_dot`). With attr ``transpose_w`` W is
+    [f, d] and read as it lies (a head tied to the embedding)."""
     x, w = ctx.input("X"), ctx.input("W")
-    y = exact_dot(x.reshape(-1, x.shape[-1]), w)
-    return {"Out": y.reshape(x.shape[:-1] + (w.shape[1],))}
+    tied = bool(ctx.attr("transpose_w"))
+    y = exact_dot(x.reshape(-1, x.shape[-1]), w, tied)
+    return {"Out": y.reshape(x.shape[:-1] + (w.shape[0 if tied else 1],))}
 
 
 @register_op("rms_norm")
@@ -181,12 +186,21 @@ def _rotary_embedding(ctx):
     return {"Out": out.reshape(b, t, dm).astype(x.dtype)}
 
 
-def route(x, router_w, bias, top_k, route_norm, route_scale):
+def route(x, router_w, bias, top_k, route_norm, route_scale,
+          scoring="sigmoid"):
     """The router of ``moe_ffn``: x [n, d] -> (sel [n, k] expert ids,
-    w [n, k] float32 weights). Scores are ``sigmoid`` of float32 logits
-    taken at the highest precision; ``bias`` moves the selection only."""
+    w [n, k] float32 weights), from float32 logits taken at the highest
+    precision. ``scoring`` ``sigmoid``: scores are the logits' sigmoid,
+    ``bias`` moves the selection only; ``softmax_topk``: the top k of the
+    logits themselves, weighed by a softmax over the chosen k."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax_topk":
+        top, sel = jax.lax.top_k(logits, top_k)
+        return sel, jax.nn.softmax(top, axis=-1) * route_scale
+    if scoring != "sigmoid":
+        raise ValueError("scoring is 'sigmoid' or 'softmax_topk', not %r"
+                         % (scoring,))
     s = jax.nn.sigmoid(logits)
     _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, sel, axis=1)
@@ -224,7 +238,8 @@ def _expert_rows(xs, wg, wu, wd, counts):
 def _moe_ffn(ctx):
     """X [.., d]; RouterW [d, E] and ExpertBias [E] (float32); WGate, WUp
     [E_held, d, f] and WDown [E_held, f, d], the held experts stacked;
-    attrs num_experts, top_k, route_norm, route_scale, expert_offset.
+    attrs num_experts, top_k, route_norm, route_scale, expert_offset, and
+    where the router is no sigmoid ``scoring`` (:func:`route`).
     Out float32, X's shape: sum over the token's selected experts that
     are held of ``w * (silu(x WGate) * (x WUp)) WDown``. Counts [E_held]
     int32: the pairs each held expert took in this call."""
@@ -241,7 +256,8 @@ def _moe_ffn(ctx):
                          % (ctx.attr("num_experts"), router_w.shape[1]))
     sel, w = route(x2, router_w, ctx.input("ExpertBias"), k,
                    ctx.attr("route_norm", True),
-                   ctx.attr("route_scale", 1.0))
+                   ctx.attr("route_scale", 1.0),
+                   ctx.attr("scoring") or "sigmoid")
     # the n*k pairs sorted by held expert; pairs of experts held elsewhere
     # sort last, fall in no group and weigh nothing
     local = sel.reshape(-1) - offset

@@ -91,7 +91,8 @@ one before was uncollected),
 ``_ttft_seconds`` (time to first token), ``_inter_token_seconds``;
 the dispatcher's clock by phase: ``_host_ms_total{phase}``,
 ``_device_wait_ms_total``, and what a step and a prefill worked on:
-``_context_tokens_total``, ``_prompt_tokens_total``,
+``_context_tokens_total``, ``_state_rows_updated_total`` (a state
+kind's rows a step rewrites), ``_prompt_tokens_total``,
 ``_prefill_padded_tokens_total`` (see ``GenerationScheduler``, "The
 dispatcher's clock"); recovery: ``_failover_total``,
 ``_replayed_tokens_total``, ``_session_rebuilds_total``,
@@ -207,6 +208,11 @@ _LATENT_ROWS_ATTENDED = _metrics.REGISTRY.counter(
     "Cached latent rows attended by decode steps: per step, the sum over "
     "the slots that advanced and over the layers of a latent kind of "
     "their context length, the new token included")
+_STATE_ROWS_UPDATED = _metrics.REGISTRY.counter(
+    "paddle_generation_state_rows_updated_total",
+    "State rows advanced by decode steps: per step, the layers of a state "
+    "kind times the slots that advanced (each such row is read and "
+    "written whole)")
 _ROUTED_PAIRS = _metrics.REGISTRY.counter(
     "paddle_generation_routed_pairs_total",
     "Token-expert pairs routed by the expert layers of decode steps "
@@ -358,7 +364,10 @@ class GenerationSpec:
     ``latent_layers`` counts the layers whose cache is a latent kind's:
     one pool a layer, whose row is key and value at once (``cache_vars``
     names one variable a layer, not a K and a V); its books are the full
-    kind's.
+    kind's. ``state_layers`` counts the layers of a state kind
+    (``paged_cache.CacheKind``): three variables a layer, one row a slot.
+    ``kind_block_bytes`` says kind by kind what one block (of a state
+    kind: one row) holds over the kind's layers.
     """
 
     __slots__ = ("slots", "cache_len", "max_len", "prompt_buckets",
@@ -370,7 +379,7 @@ class GenerationSpec:
                  "vocab_size", "policy", "verify_program",
                  "verify_feeds", "verify_fetch", "draft_spec",
                  "cache_kinds", "stats_fetch", "routed_pairs",
-                 "latent_layers")
+                 "latent_layers", "state_layers", "kind_block_bytes")
 
     # a constant, kept because benchmarks/harness/serve.py:71 checks it
     paged = True
@@ -391,6 +400,7 @@ class GenerationSpec:
         kwargs.setdefault("stats_fetch", None)
         kwargs.setdefault("routed_pairs", None)
         kwargs.setdefault("latent_layers", 0)
+        kwargs.setdefault("state_layers", 0)
         for name in self.__slots__:
             setattr(self, name, kwargs.pop(name))
         if kwargs:
@@ -493,6 +503,14 @@ class GenerationSession:
             "full", None, spec.num_blocks, len(spec.cache_vars) // 2,
             None, None),)
         policy = getattr(spec, "policy", None)
+        if any(k.name == "state" for k in kinds) and (
+                spec.prefix_cache or
+                (policy is not None and policy.speculate_k > 0)):
+            raise ValueError(
+                "a spec with a state kind of layer cache takes neither "
+                "prefix_cache nor speculate_k: a slot's state is one row "
+                "rewritten whole every step, which no prefix can share and "
+                "no rejected draft can be rolled back from")
         if (len(kinds) > 1 or kinds[0].window) and (
                 spec.prefix_cache or
                 (policy is not None and policy.speculate_k > 0)):
@@ -501,10 +519,12 @@ class GenerationSession:
                 "than one kind) takes neither prefix_cache nor "
                 "speculate_k: blocks shared or rolled back behind a "
                 "window are not implemented")
-        self.kinds = [LayerCache(k, spec.block_size, n) for k in kinds]
+        self.kinds = [LayerCache(k, spec.block_size, n, spec.max_blocks)
+                      for k in kinds]
         self._more_kinds = tuple(self.kinds[1:])
         self._window_kinds = tuple(k for k in self.kinds if k.window)
         self._latent_layers = getattr(spec, "latent_layers", 0)
+        self._state_layers = getattr(spec, "state_layers", 0)
         self.pool = self.kinds[0].pool
         self.prefix = PrefixIndex(self.pool) if spec.prefix_cache else None
         # host-side block table per slot: physical block ids
@@ -621,7 +641,8 @@ class GenerationSession:
         # the other kinds hold the whole history until the prefill has
         # run, then what their window keeps
         return avail >= need and all(
-            k.pool.free_count() >= need for k in self._more_kinds)
+            k.pool.free_count() >= (1 if k.state else need)
+            for k in self._more_kinds)
 
     def storable(self, n_tokens):
         """Static bound: could this session's storage EVER hold an
@@ -634,9 +655,11 @@ class GenerationSession:
 
     def _most_blocks(self, kind, blocks):
         """The most blocks a sequence of ``blocks`` holds in a kind at one
-        time: all of them, or with a window those of the longest prompt (a
-        prefill writes all its rows before the window is trimmed) or of
-        the window with the block being written."""
+        time: all of them, a state kind's one row, or with a window those
+        of the longest prompt (a prefill writes all its rows before the
+        window is trimmed) or of the window with the block being written."""
+        if kind.state:
+            return 1
         if not kind.window:
             return blocks
         bs = self.spec.block_size
@@ -662,19 +685,21 @@ class GenerationSession:
         return self.prompt_bucket(n - matched) is not None
 
     def pool_stats(self):
-        """{blocks_in_use, num_blocks, block_size, bytes_per_block}
-        — probe/bench surface."""
-        itemsize = np.dtype(self.spec.cache_vars[0][2]).itemsize
-        d_model = self.spec.cache_vars[0][1][2]
-        # of the first kind: a block id names a block of each pool (a K
-        # and a V, or a latent kind's one) in each of its layers
-        pools = len(self.spec.cache_vars) // sum(
-            k.kind.layers for k in self.kinds)
-        return {"blocks_in_use": self.pool.used_count(),
-                "num_blocks": self.pool.num_blocks,
-                "block_size": self.spec.block_size,
-                "bytes_per_block": self.spec.block_size * d_model
-                * itemsize * pools * self.kinds[0].kind.layers}
+        """{blocks_in_use, num_blocks, block_size, bytes_per_block} of the
+        first kind — probe/bench surface; with more kinds than one,
+        ``kinds`` has the same of each by name (a state kind's block is a
+        slot's row over its layers)."""
+        def stats(kind, size):
+            return {"blocks_in_use": kind.pool.used_count(),
+                    "num_blocks": kind.pool.num_blocks,
+                    "block_size": kind.pool.block_size,
+                    "bytes_per_block": size}
+        sizes = self.spec.kind_block_bytes
+        out = stats(self.kinds[0], sizes[0])
+        if len(self.kinds) > 1:
+            out["kinds"] = {k.kind.name: stats(k, size)
+                            for k, size in zip(self.kinds, sizes)}
+        return out
 
     def prefix_stats(self):
         """Prefix-cache hit counters (zeros when not armed)."""
@@ -702,9 +727,18 @@ class GenerationSession:
                 if self.prefix is None or not self.prefix.evict_one():
                     raise
 
+    def _state_bind(self, kind, slot, bound):
+        """The span around a state kind's row being bound to ``slot`` or
+        returned by it; nothing around a paged kind's blocks."""
+        if not kind.state or not (bound or kind.tables[slot]):
+            return contextlib.nullcontext()
+        return _tracing.span("session:state_bind", round=self.round,
+                             slot=slot, bound=bound)
+
     def _release_table(self, slot):
         for kind in self.kinds:
-            kind.release(slot)
+            with self._state_bind(kind, slot, False):
+                kind.release(slot)
 
     def _copy_block(self, src, dst):
         """Run the block-copy program: block ``src`` -> ``dst`` in
@@ -886,8 +920,8 @@ class GenerationSession:
             while len(table) * bs < n:
                 table.append(self._alloc_block())
             for kind, tbl in zip(self._more_kinds, more):
-                while len(tbl) * bs < n:
-                    tbl.append(kind.pool.alloc())
+                with self._state_bind(kind, slot, True):
+                    kind.extend(tbl, n, slot)
             w = suffix.size
             padded = np.full((1, bucket), self.spec.eos_id, np.int64)
             padded[0, :w] = suffix
@@ -905,8 +939,7 @@ class GenerationSession:
                     f_pix: pix,
                     f_tab: tab}
             for kind, tbl in zip(self._more_kinds, more):
-                row = np.full(self.spec.max_blocks, kind.pool.num_blocks,
-                              np.int32)
+                row = np.full(kind.width, kind.pool.num_blocks, np.int32)
                 row[:len(tbl)] = tbl
                 feed[kind.kind.prefill_table] = row
             # the emitted token's index is the TOTAL length n
@@ -1075,8 +1108,7 @@ class GenerationSession:
                         # index also holds: diverge onto a private copy
                         self._ensure_writable(tbl, pos // bs)
                     for kind in self._more_kinds:
-                        if pos // bs == len(kind.tables[s]):
-                            kind.tables[s].append(kind.pool.alloc())
+                        kind.extend(kind.tables[s], pos + 1, s)
                 except PoolExhausted:
                     self._starved.add(s)
             nb = self.pool.num_blocks
@@ -1093,7 +1125,7 @@ class GenerationSession:
                     f_pos: self.lengths.astype(np.int32),
                     f_tab: tab}
             for kind in self._more_kinds:
-                tab = np.full((self.spec.slots, self.spec.max_blocks),
+                tab = np.full((self.spec.slots, kind.width),
                               kind.pool.num_blocks, np.int32)
                 for s in act:
                     if int(s) not in self._starved:
@@ -1256,6 +1288,8 @@ class GenerationSession:
                 for k in self._window_kinds)))
         if self._latent_layers and advanced.size:
             _LATENT_ROWS_ATTENDED.inc(self._latent_layers * int(lens.sum()))
+        if self._state_layers and advanced.size:
+            _STATE_ROWS_UPDATED.inc(self._state_layers * int(advanced.size))
         flight = _Flight(advanced, self._retires[advanced].copy(), outs,
                          int(lens.sum()))
         self._flights.append(flight)

@@ -95,7 +95,11 @@ _POOL_SEQ = itertools.count()
 # own table feeds; ``layers`` is how many layers are of the kind. A kind
 # named ``latent`` keeps every row like ``full``; what differs is on the
 # device: one pool a layer, whose row is a token's latent, key and value at
-# once (ops/mla_ops.py).
+# once (ops/mla_ops.py). A kind named ``state`` keeps no rows of tokens: its
+# pool is one fixed-size row a slot in each of its layers (a state-space
+# layer's state, ops/ssm_ops.py), a sequence's table is the one entry it is
+# bound to from admission to retirement, and the row of a slot is the row of
+# its own index, so that a step updates the pool where it lies.
 CacheKind = collections.namedtuple(
     "CacheKind", "name window num_blocks layers prefill_table decode_table")
 
@@ -160,6 +164,16 @@ class BlockPool:
                 "all %d blocks referenced (%d-row blocks)"
                 % (self.num_blocks, self.block_size))
         block = self._free.popleft()
+        self._ref[block] = 1
+        self._update_gauge()
+        return block
+
+    def take(self, block):
+        """The free block ``block`` itself, with refcount 1: a state kind
+        binds a slot to the row of its own index."""
+        if self._ref[block]:
+            raise RuntimeError("block %d is taken" % block)
+        self._free.remove(block)
         self._ref[block] = 1
         self._update_gauge()
         return block
@@ -232,14 +246,33 @@ class LayerCache:
     and a block table per slot, indexed by logical block as ever. A window
     kind marks the entries it has freed dead (the pool's ``num_blocks``,
     what the table feeds hold for rows nobody owns) and remembers per slot
-    the first live one, so that a feed row copies the live entries only."""
+    the first live one, so that a feed row copies the live entries only. A
+    state kind's table is the one row the slot is bound to: its own."""
 
-    def __init__(self, kind, block_size, slots):
+    def __init__(self, kind, block_size, slots, max_blocks=None):
         self.kind = kind
         self.window = kind.window
-        self.pool = BlockPool(kind.num_blocks, block_size, kind.name)
+        self.state = kind.name == "state"
+        if self.state and kind.num_blocks != slots:
+            raise ValueError("a state kind has one row a slot: %d rows, %d "
+                             "slots" % (kind.num_blocks, slots))
+        self.pool = BlockPool(kind.num_blocks, 1 if self.state
+                              else block_size, kind.name)
+        # entries of a table feed's row
+        self.width = 1 if self.state else max_blocks
         self.tables = [[] for _ in range(slots)]
         self.first = np.zeros(slots, np.int64)
+
+    def extend(self, table, n_tokens, slot):
+        """Append to ``table`` what a sequence of ``n_tokens`` in ``slot``
+        still lacks: fresh blocks, or a state kind's one row, the slot's
+        own. Raises PoolExhausted with the table as far as it got."""
+        if self.state:
+            if not table:
+                table.append(self.pool.take(slot))
+            return
+        while len(table) * self.pool.block_size < n_tokens:
+            table.append(self.pool.alloc())
 
     def release(self, slot):
         """Return every block the slot still holds."""

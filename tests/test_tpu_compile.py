@@ -351,3 +351,97 @@ def test_conv1x1_bn_compiles(chip, c, hw, o):
         return pallas_conv_bn._pallas_1x1(x, w, interpret=False)
     assert "tpu_custom_call" in _compile(
         chip, fn, ((256, c, hw, hw), F32), ((o, c, 1, 1), F32))
+
+
+@pytest.fixture(scope="module")
+def granite_programs(chip):
+    """granite-serve-offline's programs as the executor compiles them, for
+    the described chip: the configuration at its published widths, its
+    deployment's 96 slots and 24,576 blocks, cut to one state-space layer
+    and the attention layer (every layer of a kind compiles alike).
+    -> {"decode" | 2048: (memory, HLO)}, and the kernel paths counted."""
+    import numpy as np
+    import paddle_tpu as ptpu
+    from benchmarks import architectures
+    from benchmarks.harness import lm
+    from benchmarks.sweeps import sizing
+    cfg = lm.load_config("granite-4.0-h-small-l10")
+    cfg.update(num_hidden_layers=2, layer_types=["mamba", "attention"])
+    arch = architectures.load(cfg)
+    geometry = cfg["deployment"]["serving"]
+    patch = pytest.MonkeyPatch()
+    patch.setattr(kernel_path, "interpret_mode", lambda: False)
+    before = {k: dict(v) for k, v in kernel_path.counts().items()}
+    out = {}
+    try:
+        with lm.flags(generation_kv_dtype=geometry["kv_dtype"],
+                      matmul_precision="BF16_BF16_F32", **cfg["flags"]):
+            with ptpu.unique_name.guard():
+                startup = arch.serve_startup(cfg, 0)
+            spec = arch.serve_spec(cfg, geometry, (2048,))
+            scope = sizing._ShapeScope([startup], more=spec.cache_vars)
+            exe = ptpu.Executor()
+            s, mb = spec.slots, spec.max_blocks
+            feed = {"gen.dtok": np.zeros((s, 1), "int64"),
+                    "gen.dpos": np.zeros((s,), "int32"),
+                    "gen.dtab": np.zeros((s, mb), "int32"),
+                    "gen.dtab.state": np.zeros((s, 1), "int32")}
+            out["decode"] = sizing._compile(
+                exe, spec.decode_program, feed,
+                [spec.decode_fetch, spec.stats_fetch], scope, chip)
+            feed = {"gen.ptok": np.zeros((1, 2048), "int64"),
+                    "gen.plen": np.ones((1,), "int32"),
+                    "gen.ppos": np.zeros((1,), "int32"),
+                    "gen.phist": np.zeros((1,), "int32"),
+                    "gen.ppix": np.zeros((2048,), "int32"),
+                    "gen.ptab": np.zeros((mb,), "int32"),
+                    "gen.ptab.state": np.zeros((1,), "int32")}
+            out[2048] = sizing._compile(
+                exe, spec.prefill_programs[2048], feed, [spec.prefill_fetch],
+                scope, chip)
+    finally:
+        patch.undo()
+    after = kernel_path.counts()
+    paths = {k: {p: n - before.get(k, {}).get(p, 0) for p, n in v.items()
+                 if n != before.get(k, {}).get(p, 0)}
+             for k, v in after.items()}
+    return out, {k: v for k, v in paths.items() if v}
+
+
+def test_granite_decode_step_updates_the_state_where_it_lies(
+        granite_programs):
+    """96 slots: the state pool of the layer (403 MB) is an argument that
+    the step's output aliases, and the temporaries hold no second copy of
+    it; the attention layer's paged decode and the held experts' grouped
+    matmuls are Mosaic calls; the tied head reads the embedding
+    [25088, 4096] as it lies: no transposed copy of its 205 MB."""
+    (mem, hlo), paths = granite_programs[0]["decode"], granite_programs[1]
+    state = 96 * 4 * (128 * 64 * 128 + 4 * 8448)
+    assert mem["alias_bytes"] > state
+    assert mem["temp_bytes"] < state // 4, mem
+    assert mem["temp_bytes"] < 25088 * 4096 * 2, mem
+    assert _has_kernel(hlo, "decode_attention_paged")
+    assert len(re.findall(r"%moe_grouped_matmul(?:\.\d+)? = [^\n]*"
+                          r"tpu_custom_call", hlo)) == 2 * 2
+    assert "f32[96,128,64,128]" in hlo
+    assert not re.search(r"bf16\[4096,25088\]", hlo)
+    assert "ragged" not in hlo
+    assert set(paths["moe_grouped_matmul"]) == {"compiled"}
+    assert set(paths["decode_attention_paged"]) == {"compiled"}
+
+
+def test_granite_prefill_compiles_with_its_chunks_and_its_passes(
+        granite_programs):
+    """The 2,048 bucket: 8 chunks of 256 rows under 128 heads (the scores
+    ``[8, 128, 256, 256]``), the state written into the slot's row of the
+    pool in place, and the held experts' 5,120 expected pairs taken in
+    passes of ``SHARE_ROWS`` 2,048 rows (three in balance) by the kernel
+    inside a loop."""
+    mem, hlo = granite_programs[0][2048]
+    state = 96 * 4 * (128 * 64 * 128 + 4 * 8448)
+    assert mem["alias_bytes"] > state
+    assert mem["temp_bytes"] < 1.2e9, mem
+    assert "f32[8,128,256,256]" in hlo
+    assert _has_kernel(hlo, "moe_grouped_matmul")
+    assert "f32[20480,4096]" not in hlo and "f32[2048,4096]" in hlo
+    assert "ragged" not in hlo
