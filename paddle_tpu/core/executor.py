@@ -67,6 +67,14 @@ _STEP_BYTES = _metrics.REGISTRY.gauge(
     "XLA cost-analysis bytes accessed of the cached step "
     "(bandwidth-roofline numerator)",
     labelnames=("key",))
+# trace-time only, like paddle_kernel_lowerings_total: counts when a step
+# is traced (every compile), never on the steady-state path
+_FENCED_UPDATES = _metrics.REGISTRY.counter(
+    "paddle_executor_fenced_updates_total",
+    "Optimizer update ops traced with their dense gradient behind an "
+    "optimization barrier, so that XLA compiles the update apart from "
+    "the op that produced the gradient",
+    labelnames=("op",))
 
 
 # Global key_id source: labels must not alias across Executors or
@@ -221,9 +229,44 @@ def _write_outputs(op, env, norm_result):
                 env[name] = vals[i]
 
 
+def _fence_update_grad(op, values):
+    """Put the gradient of an optimizer update behind an optimization
+    barrier. An update is told by its slots (``Param`` and ``Grad`` in,
+    ``ParamOut`` out), not by a list of names.
+
+    Why: left alone, XLA fuses the update into the epilogue of the matmul
+    that makes a weight's gradient, and on the v5e that fusion runs the
+    product at 44-60% of the MXU's peak where the product alone reaches
+    89-93% (PERF.md, PR 34); behind the barrier the product compiles
+    alone and the update as one elementwise pass over the parameter and
+    its accumulators. The barrier is the identity on values.
+
+    Left as they were, by what the trace can see: gradients of rank < 2
+    (biases, norm gains: they come from reductions, not matmuls); a
+    ``Rows`` gradient (a merge and a scatter stand before its update);
+    and every gradient of a step whose batch is sharded over chips
+    (``data_shards() > 1``): there the all-reduce already stands between
+    the product and the update, and a fence after it would only force
+    the summed bfloat16 gradient's float32 copy through HBM (measured:
+    5 ms of a 300 ms step)."""
+    if "ParamOut" not in op.outputs or "Rows" in op.inputs or \
+            not {"Param", "Grad"} <= op.inputs.keys():
+        return
+    grad = values["Grad"][0]
+    if getattr(grad, "ndim", 0) < 2:
+        return
+    from .. import parallel as _parallel
+    strategy = _parallel.current_strategy()
+    if strategy is not None and strategy.data_shards() > 1:
+        return
+    values["Grad"] = [jax.lax.optimization_barrier(grad)]
+    _FENCED_UPDATES.labels(op=op.type).inc()
+
+
 def _execute_forward_op(op, env, block, trace):
     opdef = registry.get_op_def(op.type)
     values = _gather_inputs(op, env, block)
+    _fence_update_grad(op, values)
     rng_key = None
     if opdef.needs_rng:
         env[RNG_STATE_VAR], rng_key = jax.random.split(env[RNG_STATE_VAR])

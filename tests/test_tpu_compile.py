@@ -445,3 +445,44 @@ def test_granite_prefill_compiles_with_its_chunks_and_its_passes(
     assert _has_kernel(hlo, "moe_grouped_matmul")
     assert "f32[20480,4096]" not in hlo and "f32[2048,4096]" in hlo
     assert "ragged" not in hlo
+
+
+def _update_fusions(chip):
+    """The FFN's down projection at the training cells' shape (8,192 rows
+    of 8,192 -> 2,048, amp bfloat16) with its ``vjp_grad`` and ``adam``,
+    compiled through the executor's own trace -> the kinds of the fusions
+    whose result tuple is ParamOut and both moments."""
+    import numpy as np
+    import paddle_tpu as ptpu
+    from paddle_tpu import layers
+    from benchmarks.harness import lm
+    from benchmarks.sweeps import sizing
+    main, startup = ptpu.Program(), ptpu.Program()
+    with ptpu.unique_name.guard(), ptpu.program_guard(main, startup):
+        x = layers.data("x", shape=[8192])
+        y = layers.fc(x, 2048, bias_attr=False)
+        loss = layers.reduce_mean(layers.square(y))
+        ptpu.optimizer.Adam(learning_rate=1e-4).minimize(
+            loss, startup_program=startup)
+    assert sum(op.type == "adam" for op in main.global_block().ops) == 1
+    with lm.flags(amp="bfloat16", matmul_precision="BF16_BF16_F32"):
+        _, hlo = sizing._compile(
+            ptpu.Executor(), main, {"x": np.zeros((8192, 8192), "float32")},
+            [loss], sizing._ShapeScope([main, startup]), chip)
+    w = r"f32\[8192,2048\]"
+    return re.findall(r"= \(%s, %s, %s\) fusion\([^\n]*kind=(k\w+)"
+                      % (w, w, w), re.sub(r"\{[^}]*\}", "", hlo))
+
+
+def test_adam_compiles_apart_from_the_weight_gradient_matmul(
+        chip, monkeypatch):
+    """PR 34: with the gradient fenced, the weight-gradient product is a
+    fusion of its own and Adam an elementwise (``kLoop``) pass over the
+    parameter and its moments; unfenced, XLA makes the update the
+    product's epilogue (``kOutput``), which the chip runs at 44-60% of
+    the MXU's peak (PERF.md §6)."""
+    from paddle_tpu.core import executor
+    assert _update_fusions(chip) == ["kLoop"]
+    monkeypatch.setattr(executor, "_fence_update_grad",
+                        lambda op, values: None)
+    assert _update_fusions(chip) == ["kOutput"]
