@@ -11,6 +11,10 @@ re-designed for JAX/XLA:
   pserver tier and NCCL ops.
 """
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()    # this import, top to bottom
+
 from .core.framework import (  # noqa: F401
     Program, Variable, Parameter, default_main_program,
     default_startup_program, program_guard)
@@ -43,3 +47,9 @@ from .param_attr import ParamAttr  # noqa: F401
 from .place import CPUPlace, TPUPlace, CUDAPlace, is_compiled_with_tpu  # noqa: F401
 
 __version__ = "0.1.0"
+
+observability.metrics.REGISTRY.gauge(
+    "paddle_process_import_seconds",
+    "Seconds the import of paddle_tpu took in this process, top to bottom "
+    "(jax and the op library included when nothing imported them before)"
+).set(_time.perf_counter() - _T_IMPORT)

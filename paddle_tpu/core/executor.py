@@ -33,6 +33,7 @@ from . import registry
 from .framework import (Program, Variable, default_main_program,
                         convert_dtype, RNG_STATE_VAR)
 from .scope import global_scope
+from ..observability import compile_ledger as _ledger
 from ..observability import metrics as _metrics
 from ..observability import request_trace as _rtrace
 from ..observability import tracing as _tracing
@@ -49,15 +50,6 @@ _CACHE_HITS = _metrics.REGISTRY.counter(
 _CACHE_MISSES = _metrics.REGISTRY.counter(
     "paddle_executor_cache_misses_total",
     "Executor.run compile-cache misses (trace + XLA compile)")
-_TRACE_SECONDS = _metrics.REGISTRY.gauge(
-    "paddle_executor_trace_seconds",
-    "Python block trace + StableHLO lowering wall time per "
-    "compile-cache key",
-    labelnames=("key",))
-_COMPILE_SECONDS = _metrics.REGISTRY.gauge(
-    "paddle_executor_compile_seconds",
-    "XLA compile wall time per compile-cache key",
-    labelnames=("key",))
 _STEP_FLOPS = _metrics.REGISTRY.gauge(
     "paddle_executor_step_flops",
     "XLA cost-analysis FLOPs of the cached step (MFU numerator)",
@@ -75,6 +67,39 @@ _FENCED_UPDATES = _metrics.REGISTRY.counter(
     "optimization barrier, so that XLA compiles the update apart from "
     "the op that produced the gradient",
     labelnames=("op",))
+# The four spans of a run on counters, from the spans' own clock readings
+# (always on). A compiled step goes by its role, the name of its Program.
+_RUNS = _metrics.REGISTRY.counter(
+    "paddle_executor_runs_total",
+    "Executor.run calls by the role of the compiled step (Program.name)",
+    labelnames=("role",))
+_FIRST_CALLS = _metrics.REGISTRY.counter(
+    "paddle_executor_first_calls_total",
+    "Executor.run calls that were the first of a compiled step, by role: "
+    "paddle_executor_runs_total less these are the steady runs",
+    labelnames=("role",))
+_HOST_MS = _metrics.REGISTRY.counter(
+    "paddle_executor_host_ms_total",
+    "Host milliseconds of Executor.run by role and phase: prepare, call "
+    "(the step enqueued), writeback, fetch (the wait for the results) as "
+    "the executor: spans of those names. The first run of a compiled "
+    "step is booked whole under first_call, never under prepare, call or "
+    "writeback: its prepare (the step's function built, state placed), "
+    "the span executor:first_call (trace, lowering, compile or cache "
+    "read, then the call) and its writeback",
+    labelnames=("role", "phase"))
+_PHASES = ("prepare", "call", "writeback", "fetch", "first_call")
+_METRICS_LOCK = _metrics.REGISTRY._lock     # _CacheEntry.book says why
+
+# every second of compile-side work in the process is booked from here on
+_ledger.install()
+
+DEFAULT_ROLE = "program"      # a Program nobody named
+
+
+def _role_of(program):
+    """The role a program's compiled steps go by: its name."""
+    return program.name or DEFAULT_ROLE
 
 
 # Global key_id source: labels must not alias across Executors or
@@ -123,12 +148,20 @@ class _CacheEntry:
     ``skey_parts`` is the in-memory cache key minus its process-local
     head (program uid/version) — the stable half of the persistent
     cache digest (core/compile_cache.py); ``pkey`` memoizes that digest
-    once computed."""
+    once computed.
+
+    ``role`` is the name of the entry's Program, which ``fn`` carries too
+    (the HLO module is ``jit_<role>``); two entries of one program keep
+    one role and differ by ``key_id``. ``called`` turns true with the
+    first call, the one that traces, lowers and compiles (or reads JAX's
+    cache): it runs inside the span ``executor:first_call``, with the
+    compile ledger booking to ``role``."""
 
     __slots__ = ("fn", "read", "written", "needs_rng", "key_id", "aot",
-                 "aot_failed", "skey_parts", "pkey")
+                 "aot_failed", "skey_parts", "pkey", "role", "called",
+                 "_meters", "_generation")
 
-    def __init__(self, fn, read, written, needs_rng, key_id):
+    def __init__(self, fn, read, written, needs_rng, key_id, role):
         self.fn = fn
         self.read = read
         self.written = written
@@ -138,6 +171,42 @@ class _CacheEntry:
         self.aot_failed = False
         self.skey_parts = None
         self.pkey = None
+        self.role = role
+        self.called = False
+        self._generation = None
+
+    def book(self, first, t0, t1, t2, t3, t4):
+        """One run on the counters, from the clock readings around its
+        spans: prepare ``[t0, t1]``, the call ``[t1, t2]``, writeback
+        ``[t2, t3]``, fetch ``[t3, t4]`` (``t4`` None: nothing was fetched
+        to the host). The entry's ``first`` run is booked whole, up to
+        the fetch, under ``first_call``: what the steady phases hold is
+        the steady runs' alone. The children are held, not resolved run
+        by run; a registry reset drops them."""
+        if self._generation != _metrics.REGISTRY.generation:
+            self._generation = _metrics.REGISTRY.generation
+            self._meters = (_RUNS.labels(role=self.role),) + tuple(
+                _HOST_MS.labels(role=self.role, phase=p) for p in _PHASES)
+        if first:
+            runs, _, _, _, fetch, first_call = self._meters
+            runs.inc()
+            first_call.inc((t3 - t0) * 1e3)
+            fetch.inc(0.0 if t4 is None else (t4 - t3) * 1e3)
+            _FIRST_CALLS.labels(role=self.role).inc()
+        else:
+            # every run pays this: the five children moved under ONE
+            # hold of the registry's lock (what Counter.inc takes once a
+            # child), so that a run's bookkeeping stays near a
+            # microsecond and a snapshot sees all of a run or none. The
+            # clock only goes forward: no amount is negative
+            runs, prepare, call, writeback, fetch, _ = self._meters
+            with _METRICS_LOCK:
+                runs._value += 1.0
+                prepare._value += (t1 - t0) * 1e3
+                call._value += (t2 - t1) * 1e3
+                writeback._value += (t3 - t2) * 1e3
+                if t4 is not None:
+                    fetch._value += (t4 - t3) * 1e3
 
 
 def _lookup(env, name, op, block):
@@ -528,7 +597,8 @@ class Executor:
                                 donate_state, check_nan_inf, amp,
                                 nonfinite_guard, ingest_specs, packed_sig,
                                 quant)
-            entry = _CacheEntry(*built, key_id="k%d" % next(_KEY_IDS))
+            entry = _CacheEntry(*built, key_id="k%d" % next(_KEY_IDS),
+                                role=_role_of(program))
             # the process-stable half of the persistent-cache digest
             # (key[2:] drops program uid/version, which the program's
             # serialized content replaces)
@@ -599,7 +669,8 @@ class Executor:
         entry, state_rw, state_ro, feed_arrays = self._prepare(
             program, feed, fetch_list, scope, donate_state,
             count_cache=False)
-        return entry.fn.lower(state_rw, state_ro, feed_arrays)
+        with _ledger.attribute(entry.role):
+            return entry.fn.lower(state_rw, state_ro, feed_arrays)
 
     def cache_digest(self, program, feed=None, fetch_list=None, scope=None,
                      donate_state=True):
@@ -649,19 +720,16 @@ class Executor:
     def _aot_compile(self, entry, state_rw, state_ro, feed_arrays):
         """Telemetry path for a compile-cache miss: AOT-compile the step
         (the jit call path would compile the same module again — the AOT
-        executable is kept and used for every subsequent run), record
-        per-key trace and compile wall time plus the XLA cost analysis
-        (FLOPs / bytes accessed — the MFU and bandwidth-roofline
-        numerators, cf. tools/mfu_probe.py)."""
-        t0 = time.perf_counter()
+        executable is kept and used for every subsequent run) and record
+        the XLA cost analysis (FLOPs / bytes accessed — the MFU and
+        bandwidth-roofline numerators, cf. tools/mfu_probe.py). Its
+        seconds go where the jit path's go: it runs inside the first
+        call, so the compile ledger books its trace, lowering and compile
+        to the entry's role."""
         with _tracing.span("executor:trace", key=entry.key_id):
             lowered = entry.fn.lower(state_rw, state_ro, feed_arrays)
-        t1 = time.perf_counter()
-        _TRACE_SECONDS.labels(key=entry.key_id).set(t1 - t0)
         with _tracing.span("executor:compile", key=entry.key_id):
             compiled = lowered.compile()
-        _COMPILE_SECONDS.labels(key=entry.key_id).set(
-            time.perf_counter() - t1)
         try:
             ca = compiled.cost_analysis()
             if isinstance(ca, list):
@@ -674,77 +742,111 @@ class Executor:
             pass  # cost analysis is best-effort (backend-dependent)
         entry.aot = compiled
 
+    def _wants_aot(self, entry):
+        """Whether the step is still to be had as a ``jax.stages.Compiled``:
+        the repo's persistent executable cache is armed (``entry.pkey``,
+        set in _prepare only when compile_cache_dir is on, so the
+        all-defaults path pays one telemetry flag check a run) or
+        telemetry is, and no attempt failed."""
+        from .. import config as _config
+        return entry.aot is None and not entry.aot_failed and \
+            self.strategy is None and \
+            (entry.pkey is not None or bool(_config.get_flag("telemetry")))
+
+    def _make_aot(self, entry, state_rw, state_ro, feed_arrays):
+        """The step as a ``jax.stages.Compiled``, deserialized from the
+        repo's persistent cache or compiled here."""
+        pcache = _compile_cache.active_cache() \
+            if entry.pkey is not None else None
+        if pcache is not None:
+            # restart fast path: deserialize the executable a past
+            # process compiled for this exact digest. load() never
+            # raises — a corrupt entry is quarantined and reported
+            # as a miss, and we fall through to a normal compile.
+            entry.aot = pcache.load(entry.pkey)
+        if entry.aot is None:
+            # telemetry on (cost-analysis compile, reused for
+            # execution) or persistent cache armed (compile once,
+            # publish for the next process): AOT-compile the step
+            # so the executed step and the artifact share ONE XLA
+            # compilation
+            try:
+                self._aot_compile(entry, state_rw, state_ro, feed_arrays)
+            except Exception:
+                entry.aot = None
+                entry.aot_failed = True  # jit call path from here on
+            else:
+                if pcache is not None:
+                    pcache.store(entry.pkey, entry.aot)
+
+    def _call(self, entry, state_rw, state_ro, feed_arrays):
+        if entry.aot is not None:
+            try:
+                return entry.aot(state_rw, state_ro, feed_arrays)
+            except (TypeError, ValueError):
+                # aval drift vs the AOT signature (e.g. a scope var
+                # was replaced with a new shape): jit retraces, AOT
+                # can't — and would flap if recompiled, so stay on
+                # jit for good
+                entry.aot = None
+                entry.aot_failed = True
+        return entry.fn(state_rw, state_ro, feed_arrays)
+
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
             return_numpy=True, donate_state=True):
         if scope is None:
             scope = global_scope()
+        if program is None:
+            program = default_main_program()
+        role = _role_of(program)
         # request-scoped tracing: a serving layer above may have
         # activated a request's TraceContext on this thread — the
         # device call then lands as a span on that request's trace.
         # One thread-local read; no config flag, no cost when off.
         _rt_ctx = _rtrace.current()
-        _rt_t0 = time.perf_counter() if _rt_ctx is not None else 0.0
-        with _tracing.span("executor:prepare"):
+        # five clock readings around the four spans: the counters
+        # (entry.book) read the spans' own seconds
+        t0 = time.perf_counter()
+        with _tracing.span("executor:prepare", role=role):
             entry, state_rw, state_ro, feed_arrays = self._prepare(
                 program, feed, fetch_list, scope, donate_state)
-        from .. import config as _config
-        if entry.aot is None and not entry.aot_failed and \
-                self.strategy is None and \
-                (entry.pkey is not None or _config.get_flag("telemetry")):
-            # entry.pkey doubles as the "persistent cache armed" gate
-            # (set in _prepare only when compile_cache_dir is on), so
-            # the all-defaults path pays exactly the one telemetry
-            # flag check it always did — no active_cache() call.
-            pcache = _compile_cache.active_cache() \
-                if entry.pkey is not None else None
-            if pcache is not None:
-                # restart fast path: deserialize the executable a past
-                # process compiled for this exact digest. load() never
-                # raises — a corrupt entry is quarantined and reported
-                # as a miss, and we fall through to a normal compile.
-                entry.aot = pcache.load(entry.pkey)
-            if entry.aot is None and \
-                    (pcache is not None or _config.get_flag("telemetry")):
-                # telemetry on (cost-analysis compile, reused for
-                # execution) or persistent cache armed (compile once,
-                # publish for the next process): AOT-compile the step
-                # so the executed step and the artifact share ONE XLA
-                # compilation
-                try:
-                    self._aot_compile(entry, state_rw, state_ro,
-                                      feed_arrays)
-                except Exception:
-                    entry.aot = None
-                    entry.aot_failed = True  # jit call path from here on
-                else:
-                    if pcache is not None and entry.pkey is not None:
-                        pcache.store(entry.pkey, entry.aot)
-        # executor:call ends when the step is enqueued (dispatch is
-        # asynchronous); the wait for its result is executor:fetch, or
-        # the caller's own fetch under return_numpy=False
-        with _tracing.span("executor:call", key=entry.key_id):
-            if entry.aot is not None:
-                try:
-                    new_state, fetches, guards = entry.aot(
-                        state_rw, state_ro, feed_arrays)
-                except (TypeError, ValueError):
-                    # aval drift vs the AOT signature (e.g. a scope var
-                    # was replaced with a new shape): jit retraces, AOT
-                    # can't — and would flap if recompiled, so stay on
-                    # jit for good
-                    entry.aot = None
-                    entry.aot_failed = True
-                    new_state, fetches, guards = entry.fn(
-                        state_rw, state_ro, feed_arrays)
-            else:
-                new_state, fetches, guards = entry.fn(
-                    state_rw, state_ro, feed_arrays)
+        t1 = time.perf_counter()
+        wants_aot = self._wants_aot(entry)
+        first = wants_aot or not entry.called
+        if first:
+            # the entry's first call: whatever makes the step runnable
+            # (an AOT compile or a deserialized executable; else the jit
+            # call's own trace, lowering and compile or read of JAX's
+            # cache) and the call, booked to the entry's role. A step
+            # that telemetry, armed later, compiles ahead of time after
+            # all is a first call again
+            with _tracing.span("executor:first_call", role=entry.role,
+                               key=entry.key_id), \
+                    _ledger.attribute(entry.role):
+                if wants_aot:
+                    self._make_aot(entry, state_rw, state_ro, feed_arrays)
+                new_state, fetches, guards = self._call(
+                    entry, state_rw, state_ro, feed_arrays)
+            entry.called = True
+        else:
+            # executor:call ends when the step is enqueued (dispatch is
+            # asynchronous); the wait for its result is executor:fetch,
+            # or the caller's own fetch under return_numpy=False
+            with _tracing.span("executor:call", role=entry.role,
+                               key=entry.key_id):
+                new_state, fetches, guards = self._call(
+                    entry, state_rw, state_ro, feed_arrays)
+        t2 = time.perf_counter()
         with _tracing.span("executor:writeback"):
             for n, v in new_state.items():
                 scope.set_var(n, v)
+        t3 = time.perf_counter()
+        t4 = None
         if return_numpy:
             with _tracing.span("executor:fetch"):
                 fetches = [np.asarray(v) for v in fetches]
+            t4 = time.perf_counter()
+        entry.book(first, t0, t1, t2, t3, t4)
         if guards:
             # Per-op output scan (reference framework/executor.cc:120-128).
             bad = [k for k, ok in guards.items() if not bool(ok)]
@@ -754,7 +856,7 @@ class Executor:
         if _rt_ctx is not None:
             _rtrace.event(
                 _rt_ctx, "deviceCall", key=entry.key_id,
-                dur_ms=(time.perf_counter() - _rt_t0) * 1e3)
+                dur_ms=(time.perf_counter() - t0) * 1e3)
         return fetches
 
     def as_jax_function(self, program, feed_templates, fetch_list,
@@ -889,4 +991,7 @@ class Executor:
         if packed_sig is not None:
             donate.append(2)
         jit_kwargs = {"donate_argnums": tuple(donate)} if donate else {}
+        # the step goes by its program's role: the HLO module is
+        # jit_<role>, the profiler's host trace shows PjitFunction(<role>)
+        fn.__name__ = fn.__qualname__ = _role_of(program)
         return (jax.jit(fn, **jit_kwargs), read_t, written_t, needs_rng)

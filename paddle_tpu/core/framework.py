@@ -253,9 +253,17 @@ _program_uid_counter = [0]
 
 
 class Program:
-    """A list of Blocks; block 0 is global (reference framework.py:789)."""
+    """A list of Blocks; block 0 is global (reference framework.py:789).
 
-    def __init__(self):
+    ``name`` is the program's role, plain data set by who builds it
+    (``startup``, ``train``, ``prefill_128``, ``decode``): the executor
+    gives the step it compiles from the program that name, so that the HLO
+    module, the profiler's host trace, the ``executor:`` spans and the
+    compile ledger all say which step they are about. ``None`` until
+    somebody names it; the executor then says ``program``."""
+
+    def __init__(self, name=None):
+        self.name = name
         self.blocks = [Block(self, 0)]
         self.current_block_idx = 0
         self._version = 0  # bumped on mutation; part of the executor jit key
@@ -299,7 +307,7 @@ class Program:
 
 
 _main_program = Program()
-_startup_program = Program()
+_startup_program = Program(name="startup")
 
 
 def default_main_program():
@@ -317,14 +325,20 @@ def switch_main_program(program):
 
 
 def switch_startup_program(program):
+    """Install ``program`` as the startup program; one nobody named is
+    named by the role it takes."""
     global _startup_program
+    if program.name is None:
+        program.name = "startup"
     prev, _startup_program = _startup_program, program
     return prev
 
 
 @contextlib.contextmanager
 def program_guard(main_program, startup_program=None):
-    """Route layer construction into the given programs (reference parity)."""
+    """Route layer construction into the given programs (reference parity).
+    A startup program nobody named is named by its role
+    (``switch_startup_program``)."""
     prev_main = switch_main_program(main_program)
     prev_startup = None
     if startup_program is not None:

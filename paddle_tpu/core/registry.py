@@ -14,12 +14,28 @@ Capability parity with the reference OpRegistry/OpInfoMap
   (``paddle/framework/grad_op_desc_maker.h``) without per-op grad code.
 """
 
+import time
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from .framework import convert_dtype
+from ..observability import compile_ledger as _ledger
+from ..observability import metrics as _metrics
+
+# The first of a step's traces: every op is traced once here, when the
+# program is built, and again whenever the executor traces its block. The
+# compile ledger leaves these traces out: they are booked here.
+_INFER_SECONDS = _metrics.REGISTRY.counter(
+    "paddle_program_infer_shape_seconds_total",
+    "Seconds of op shape inference at program build time: compute() "
+    "traced under jax.eval_shape, one call an appended op")
+_INFER_OPS = _metrics.REGISTRY.counter(
+    "paddle_program_infer_shape_ops_total",
+    "Ops whose output shapes were inferred by tracing compute() under "
+    "jax.eval_shape at program build time")
 
 # Build-time stand-in for unknown (-1) dimensions during eval_shape.
 _DIM_PLACEHOLDER = 8191
@@ -183,11 +199,16 @@ def infer_shape(op, block):
         _current_leaves[:] = leaves
         return abstract_fn()
 
+    t0 = time.perf_counter()
     try:
-        out_structs = jax.eval_shape(wrapped, *leaf_specs)
+        with _ledger.quiet():
+            out_structs = jax.eval_shape(wrapped, *leaf_specs)
     except Exception as e:  # surface op name for debuggability
         raise type(e)("shape inference failed for op %r: %s" % (op.type, e)) \
             from e
+    finally:
+        _INFER_SECONDS.inc(time.perf_counter() - t0)
+        _INFER_OPS.inc()
 
     for (slot, i), struct in zip(_out_slots, out_structs):
         names = op.outputs.get(slot, [])

@@ -468,7 +468,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     prefill_fetch = None
     prefill_extra = ()
     for P in prompt_buckets:
-        prog = Program()
+        prog = Program(name="prefill_%d" % P)
         with _un.guard(), program_guard(prog, Program()):
             toks = layers.data("gen.ptok", shape=[1, P], dtype="int64",
                                append_batch_size=False)
@@ -496,7 +496,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         prefill_programs[P] = prog
         prefill_fetch = nxt.name
 
-    decode_program = Program()
+    decode_program = Program(name="decode")
     with _un.guard(), program_guard(decode_program, Program()):
         toks = layers.data("gen.dtok", shape=[slots, 1], dtype="int64",
                            append_batch_size=False)
@@ -517,7 +517,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     # copy-on-write primitive: block Src -> block Dst in EVERY
     # layer's K and V pool (one block id addresses the same row
     # range of all of them). One program, one compile, feeds only.
-    copy_program = Program()
+    copy_program = Program(name="copy")
     with _un.guard(), program_guard(copy_program, Program()):
         csrc = layers.data("gen.csrc", shape=[1], dtype="int32",
                            append_batch_size=False)
@@ -549,7 +549,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         # per speculating slot (the low-batch latency regime
         # speculation exists for).
         W = spec_k + 1
-        verify_program = Program()
+        verify_program = Program(name="verify")
         with _un.guard(), program_guard(verify_program, Program()):
             vtok = layers.data("gen.vtok", shape=[1, W], dtype="int64",
                                append_batch_size=False)

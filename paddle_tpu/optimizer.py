@@ -102,6 +102,11 @@ class Optimizer:
                  no_grad_set=None):
         main = self._get_main(loss)
         startup = startup_program or default_startup_program()
+        # the roles the executor's compiled steps go by (Program.name)
+        if main.name is None:
+            main.name = "train"
+        if startup.name is None:
+            startup.name = "startup"
         params_grads = append_backward(loss, parameter_list, no_grad_set)
         params_grads = append_gradient_clip_ops(params_grads)
         params_grads = append_regularization_ops(params_grads,

@@ -406,6 +406,15 @@ class GenerationSpec:
         if kwargs:
             raise TypeError("unknown GenerationSpec fields: %s"
                             % sorted(kwargs))
+        # a draft's programs go by roles of their own (Program.name):
+        # draft_prefill_<bucket>, draft_decode, draft_copy
+        draft = self.draft_spec
+        if draft is not None:
+            for prog in (*draft.prefill_programs.values(),
+                         draft.decode_program, draft.copy_program):
+                if prog is not None and prog.name and \
+                        not prog.name.startswith("draft_"):
+                    prog.name = "draft_" + prog.name
 
 
 class _Flight:

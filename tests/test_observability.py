@@ -230,6 +230,12 @@ def _misses():
         "paddle_executor_cache_misses_total").value
 
 
+def _compile_seconds(role, stage):
+    return metrics.REGISTRY.counter(
+        "paddle_compile_seconds_total",
+        labelnames=("role", "stage")).labels(role=role, stage=stage).value
+
+
 def test_executor_cache_hit_miss_counts(telemetry):
     main, startup = ptpu.Program(), ptpu.Program()
     with ptpu.program_guard(main, startup):
@@ -237,6 +243,9 @@ def test_executor_cache_hit_miss_counts(telemetry):
         y = layers.scale(x, scale=2.0)
     exe = ptpu.Executor()
     h0, m0 = _hits(), _misses()
+    # nobody named the program: its steps go by the default role
+    before = {st: _compile_seconds("program", st)
+              for st in ("trace", "lower", "compile")}
     feed8 = {"x": np.ones((8, 4), "float32")}
     exe.run(main, feed=feed8, fetch_list=[y])      # miss (new key)
     exe.run(main, feed=feed8, fetch_list=[y])      # hit
@@ -250,8 +259,13 @@ def test_executor_cache_hit_miss_counts(telemetry):
     flops = d["paddle_executor_step_flops"]["samples"]
     assert len(flops) >= 2
     assert all(s["value"] >= 0 for s in flops)
-    compile_s = d["paddle_executor_compile_seconds"]["samples"]
-    assert all(s["value"] > 0 for s in compile_s)
+    # the AOT compile's seconds are on the compile ledger, by role and
+    # stage (the per-key gauges paddle_executor_trace_seconds and
+    # paddle_executor_compile_seconds are gone)
+    assert all(_compile_seconds("program", st) > before[st]
+               for st in before)
+    assert "paddle_executor_compile_seconds" not in d
+    assert "paddle_executor_trace_seconds" not in d
 
 
 def test_lower_neither_counts_cache_nor_blocks_aot_telemetry(telemetry):

@@ -61,7 +61,9 @@ def main():
 
     print("== prometheus exposition (excerpt) " + "=" * 31)
     for line in metrics.REGISTRY.expose_text().splitlines():
-        if line.startswith(("paddle_trainer", "paddle_executor")) \
+        if line.startswith(("paddle_trainer", "paddle_executor",
+                            "paddle_compile", "paddle_program",
+                            "paddle_process")) \
                 and "_bucket" not in line:
             print(line)
 
@@ -78,8 +80,21 @@ def main():
         "value"] >= 1
     assert dump["paddle_executor_cache_hits_total"]["samples"][0][
         "value"] >= steps - 1
+    # the compile ledger: the training step's first call booked its
+    # trace, lowering and compile to the step's role (the per-key gauges
+    # paddle_executor_trace_seconds / _compile_seconds are gone)
+    booked = {(s["labels"]["role"], s["labels"]["stage"]): s["value"]
+              for s in dump["paddle_compile_seconds_total"]["samples"]}
+    assert all(booked.get(("train", stage), 0) > 0
+               for stage in ("trace", "lower")), booked
+    assert booked.get(("train", "compile"), 0) + \
+        booked.get(("train", "cache_read"), 0) > 0, booked
+    runs = {s["labels"]["role"]: s["value"] for s in
+            dump["paddle_executor_runs_total"]["samples"]}
+    assert runs["train"] >= steps and runs["startup"] == 1, runs
     names = {e["name"] for e in tracing.events() if e.get("ph") == "X"}
-    assert {"trainStep", "trainOneBatch"} <= names, names
+    assert {"trainStep", "trainOneBatch", "executor:first_call",
+            "executor:call"} <= names, names
     doc = json.load(open(trace_path))
     assert doc["traceEvents"], "empty chrome trace"
     print("TELEMETRY PROBE OK: %d steps, %d trace events, "
