@@ -67,6 +67,15 @@ _FENCED_UPDATES = _metrics.REGISTRY.counter(
     "optimization barrier, so that XLA compiles the update apart from "
     "the op that produced the gradient",
     labelnames=("op",))
+# read once from the module the executor compiled under a strategy's own
+# compiler options (_aot_compile): no other step's text is read
+_ASYNC_COLLECTIVES = _metrics.REGISTRY.gauge(
+    "paddle_executor_async_collectives",
+    "Collectives left in asynchronous form (a start and a done with work "
+    "between them) in the step compiled under the strategy's compiler "
+    "options, by the step's role; 0 where the compiler merged every one "
+    "back into a plain collective",
+    labelnames=("role",))
 # The four spans of a run on counters, from the spans' own clock readings
 # (always on). A compiled step goes by its role, the name of its Program.
 _RUNS = _metrics.REGISTRY.counter(
@@ -155,17 +164,22 @@ class _CacheEntry:
     one role and differ by ``key_id``. ``called`` turns true with the
     first call, the one that traces, lowers and compiles (or reads JAX's
     cache): it runs inside the span ``executor:first_call``, with the
-    compile ledger booking to ``role``."""
+    compile ledger booking to ``role``. ``options`` are the compiler
+    options the strategy gave the step (``DistStrategy.compiler_options``;
+    empty with no strategy): such a step is compiled ahead of time, so
+    that its module's text is there to read."""
 
-    __slots__ = ("fn", "read", "written", "needs_rng", "key_id", "aot",
-                 "aot_failed", "skey_parts", "pkey", "role", "called",
+    __slots__ = ("fn", "read", "written", "needs_rng", "options", "key_id",
+                 "aot", "aot_failed", "skey_parts", "pkey", "role", "called",
                  "_meters", "_generation")
 
-    def __init__(self, fn, read, written, needs_rng, key_id, role):
+    def __init__(self, fn, read, written, needs_rng, key_id, role,
+                 options=None):
         self.fn = fn
         self.read = read
         self.written = written
         self.needs_rng = needs_rng
+        self.options = options or {}
         self.key_id = key_id
         self.aot = None
         self.aot_failed = False
@@ -593,12 +607,12 @@ class Executor:
             self._compiles += 1
             if telemetry and count_cache:
                 _CACHE_MISSES.inc()
-            built = self._build(program, block, feed_sig, fetch_names,
-                                donate_state, check_nan_inf, amp,
-                                nonfinite_guard, ingest_specs, packed_sig,
-                                quant)
+            *built, options = self._build(
+                program, block, feed_sig, fetch_names, donate_state,
+                check_nan_inf, amp, nonfinite_guard, ingest_specs,
+                packed_sig, quant)
             entry = _CacheEntry(*built, key_id="k%d" % next(_KEY_IDS),
-                                role=_role_of(program))
+                                role=_role_of(program), options=options)
             # the process-stable half of the persistent-cache digest
             # (key[2:] drops program uid/version, which the program's
             # serialized content replaces)
@@ -740,6 +754,10 @@ class Executor:
                 float(ca.get("bytes accessed", 0.0)))
         except Exception:
             pass  # cost analysis is best-effort (backend-dependent)
+        if entry.options:
+            from .. import parallel as _parallel
+            _ASYNC_COLLECTIVES.labels(role=entry.role).set(
+                _parallel.async_collectives(compiled.as_text()))
         entry.aot = compiled
 
     def _wants_aot(self, entry):
@@ -747,11 +765,15 @@ class Executor:
         the repo's persistent executable cache is armed (``entry.pkey``,
         set in _prepare only when compile_cache_dir is on, so the
         all-defaults path pays one telemetry flag check a run) or
-        telemetry is, and no attempt failed."""
+        telemetry is, and no attempt failed. Under a strategy: the step
+        was built with compiler options of the strategy's, whose effect
+        is read from the compiled module's text."""
+        if entry.aot is not None or entry.aot_failed:
+            return False
+        if self.strategy is not None:
+            return bool(entry.options)
         from .. import config as _config
-        return entry.aot is None and not entry.aot_failed and \
-            self.strategy is None and \
-            (entry.pkey is not None or bool(_config.get_flag("telemetry")))
+        return entry.pkey is not None or bool(_config.get_flag("telemetry"))
 
     def _make_aot(self, entry, state_rw, state_ro, feed_arrays):
         """The step as a ``jax.stages.Compiled``, deserialized from the
@@ -773,6 +795,10 @@ class Executor:
             try:
                 self._aot_compile(entry, state_rw, state_ro, feed_arrays)
             except Exception:
+                if entry.options:
+                    # the jit call path would compile the same module
+                    # under the same options: nothing to fall back to
+                    raise
                 entry.aot = None
                 entry.aot_failed = True  # jit call path from here on
             else:
@@ -991,7 +1017,17 @@ class Executor:
         if packed_sig is not None:
             donate.append(2)
         jit_kwargs = {"donate_argnums": tuple(donate)} if donate else {}
+        # the strategy says how its step is compiled (the data axis's
+        # all-reduces beside the backward pass); with no strategy, or one
+        # that has nothing to say, the jit call is what it always was. A
+        # step that is fed nothing (a startup program) has no batch, so
+        # no all-reduce to overlap, and is compiled as it always was too
+        options = strategy.compiler_options() \
+            if strategy is not None and feed_sig else {}
+        if options:
+            jit_kwargs["compiler_options"] = options
         # the step goes by its program's role: the HLO module is
         # jit_<role>, the profiler's host trace shows PjitFunction(<role>)
         fn.__name__ = fn.__qualname__ = _role_of(program)
-        return (jax.jit(fn, **jit_kwargs), read_t, written_t, needs_rng)
+        return (jax.jit(fn, **jit_kwargs), read_t, written_t, needs_rng,
+                options)

@@ -1,6 +1,7 @@
 """SPMD data-parallel tests on the 8-device CPU mesh (SURVEY §2.3: replaces
 MultiGradientMachine ring all-reduce / pserver sync / parallel_do)."""
 
+import pytest
 import numpy as np
 
 import jax
@@ -302,3 +303,122 @@ def test_batch_norm_stats_are_global():
                  if k.endswith(".mean")))
     np.testing.assert_allclose(mean_single, mean_par, rtol=1e-4,
                                atol=1e-6)
+
+
+# -- PR 38: the strategy says how its step is compiled ---------------------
+
+def test_compiler_options_are_empty_off_the_tpu_and_off_the_data_axis():
+    """``compiler_options()`` answers by what it can see: nothing for a
+    data axis of 1, for no data axis, beside a model axis, and on CPU
+    devices whatever the axes (an option the CPU compiler does not know
+    is an error there)."""
+    from types import SimpleNamespace
+    from jax.sharding import PartitionSpec as P
+    assert parallel.DataParallel(n_devices=1).compiler_options() == {}
+    assert parallel.DataParallel(n_devices=4).compiler_options() == {}
+    model_only = parallel.DistStrategy(
+        parallel.make_mesh({"model": 4}), param_rules=[("w", P(None, "model"))])
+    assert model_only.data_shards() == 1
+    assert model_only.compiler_options() == {}
+
+    def on_tpus(axes):
+        s = parallel.DistStrategy(parallel.make_mesh(axes))
+        s.mesh = SimpleNamespace(
+            axis_names=s.mesh.axis_names,
+            devices=np.full(s.mesh.devices.shape,
+                            SimpleNamespace(platform="tpu"), dtype=object))
+        return s
+    assert on_tpus({"data": 4}).compiler_options() == \
+        parallel._OVERLAPPED_ALL_REDUCE
+    # a model axis beside the data axis: compiled as ever (PERF.md, PR 38)
+    assert on_tpus({"data": 2, "model": 2}).compiler_options() == {}
+    assert on_tpus({"data": 1, "model": 4}).compiler_options() == {}
+    assert on_tpus({"model": 4}).compiler_options() == {}
+    # a caller's copy: editing it does not edit the next step's
+    on_tpus({"data": 4}).compiler_options().clear()
+    assert parallel._OVERLAPPED_ALL_REDUCE
+
+
+def _gauge(role):
+    from paddle_tpu.core import executor
+    return executor._ASYNC_COLLECTIVES.labels(role=role).value
+
+
+def test_executor_hands_jit_the_strategys_options_and_no_other(monkeypatch):
+    """With no strategy, or one with nothing to say (CPU devices), the
+    executor's ``jit`` call has no ``compiler_options`` and the step is not
+    compiled ahead of time; with options (here one the CPU compiler knows)
+    a fed step is compiled under them, ahead of time, its module's text
+    read once for the gauge, and a step that is fed nothing (startup) is
+    compiled as ever. The results are the same step's."""
+    calls = []
+    real_jit = jax.jit
+
+    def spy(fn, **kwargs):
+        calls.append((fn.__name__, kwargs.get("compiler_options")))
+        return real_jit(fn, **kwargs)
+    monkeypatch.setattr(jax, "jit", spy)
+    xv, yv = _data()
+    main, startup, loss = _build_mlp()
+    main.name = "pr38_step"
+
+    def losses(strategy):
+        exe = ptpu.Executor(strategy=strategy)
+        with ptpu.scope_guard(ptpu.Scope()):
+            exe.run(startup)
+            out = [float(exe.run(main, feed={"x": xv, "y": yv},
+                                 fetch_list=[loss])[0]) for _ in range(3)]
+        return out, {e.role: e for e in exe._cache.values()}
+
+    plain, entries = losses(parallel.DataParallel(n_devices=4))
+    assert [c for c in calls if c[1] is not None] == []
+    assert entries["pr38_step"].options == {}
+    assert entries["pr38_step"].aot is None
+    assert _gauge("pr38_step") == 0     # no text was read
+
+    del calls[:]
+    known = parallel.DataParallel(n_devices=4)
+    known.compiler_options = lambda: {"xla_embed_ir_in_executable": False}
+    under, entries = losses(known)
+    assert ("pr38_step", {"xla_embed_ir_in_executable": False}) in calls
+    assert [c for c in calls if c[0] != "pr38_step" and c[1]] == []
+    step = entries["pr38_step"]
+    assert step.options and step.aot is not None and not step.aot_failed
+    assert not entries[startup.name or "program"].options
+    assert under == plain
+    # the CPU's module holds its all-reduce in no asynchronous form
+    assert _gauge("pr38_step") == 0
+
+
+def test_a_step_that_does_not_compile_under_its_options_raises():
+    """A failed compile under a strategy's options is the caller's to see:
+    the jit call path would compile the same module under the same
+    options, so there is nothing to fall back to (and no text to read)."""
+    xv, yv = _data()
+    main, startup, loss = _build_mlp()
+    strategy = parallel.DataParallel(n_devices=4)
+    strategy.compiler_options = lambda: {"xla_no_such_option_pr38": True}
+    exe = ptpu.Executor(strategy=strategy)
+    with ptpu.scope_guard(ptpu.Scope()):
+        exe.run(startup)        # fed nothing: compiled with no option
+        with pytest.raises(Exception, match="xla_no_such_option_pr38"):
+            exe.run(main, feed={"x": xv, "y": yv}, fetch_list=[loss])
+    (step,) = [e for e in exe._cache.values() if e.options]
+    assert step.aot is None and not step.aot_failed
+
+
+def test_async_collectives_counts_pairs_not_merged_back():
+    """The TPU's asynchronous form alone: a pair merged back into a plain
+    all-reduce and the generic ``async-start`` (prefetches, slices) are
+    not counted."""
+    text = """
+ENTRY %main {
+  %async-collective-start.3 = (bf16[8,8]{1,0}, u32[]) fusion(%p), kind=kCustom, calls=%f.1
+  %fusion.9 = bf16[8,8]{1,0} fusion(%q), kind=kOutput, calls=%f.2
+  %async-collective-done.3 = bf16[8,8]{1,0} fusion(%g), kind=kCustom, calls=%f.3
+  %all-reduce.7 = f32[8]{0} all-reduce(%r), channel_id=2, frontend_attributes={async_collective_name="all-reduce-start.1"}
+  %slice-start.4 = ((f32[8,8]{1,0}), f32[2,8]{1,0}, s32[]) async-start(%u), calls=%s.1
+  ROOT %async-collective-start = (f32[2]{0}, u32[]) fusion(%t), kind=kCustom, calls=%f.4
+}"""
+    assert parallel.async_collectives(text) == 2
+    assert parallel.async_collectives("ENTRY %m {\n  %a = f32[] add(%x, %y)\n}") == 0

@@ -25,8 +25,8 @@ F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """SingleDeviceSharding on one described v5e chip. JAX's persistent
+def described():
+    """The four chips of a described ``v5e:2x2``. JAX's persistent
     compile cache is off for the module: an executable compiled for a
     described device is written to it but cannot be read back without
     the chip."""
@@ -40,9 +40,15 @@ def chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(described):
+    """SingleDeviceSharding on one described v5e chip."""
+    return jax.sharding.SingleDeviceSharding(described[0])
 
 
 def _compile(chip, fn, *shapes):
@@ -486,3 +492,72 @@ def test_adam_compiles_apart_from_the_weight_gradient_matmul(
     monkeypatch.setattr(executor, "_fence_update_grad",
                         lambda op, values: None)
     assert _update_fusions(chip) == ["kOutput"]
+
+
+def _narrow_lm_step(chip, strategy):
+    """(executor's entry, HLO text) of a two-layer GPT-2 block's training
+    step at narrow widths (d 1024, eight heads of 128, FFN 4096: its two
+    matrices' bfloat16 gradients are 8 MiB each, past the 4 MiB under
+    which the strategy's options let all-reduces be combined; B 8 x T 256,
+    amp bfloat16, flash kernels), compiled through the executor's own
+    trace for the described chips: one, or the strategy's mesh."""
+    import numpy as np
+    import paddle_tpu as ptpu
+    from paddle_tpu import layers
+    from paddle_tpu.models.transformer import transformer_lm
+    from benchmarks.harness import lm
+    from benchmarks.sweeps import sizing
+    main, startup = ptpu.Program(), ptpu.Program()
+    with ptpu.unique_name.guard(), ptpu.program_guard(main, startup):
+        toks = layers.data("toks", shape=[256], dtype="int64")
+        lbls = layers.data("lbls", shape=[256], dtype="int64")
+        loss, _ = transformer_lm(toks, lbls, vocab_size=512, d_model=1024,
+                                 num_heads=8, d_ff=4096, num_layers=2)
+        ptpu.optimizer.Adam(learning_rate=1e-4).minimize(
+            loss, startup_program=startup)
+    feed = {"toks": np.zeros((8, 256), "int32"),
+            "lbls": np.zeros((8, 256), "int32")}
+    exe = ptpu.Executor(strategy=strategy)
+    with lm.flags(amp="bfloat16", flash_attention=True,
+                  matmul_precision="BF16_BF16_F32"):
+        _, hlo = sizing._compile(exe, main, feed, [loss],
+                                 sizing._ShapeScope([main, startup]), chip)
+    (entry,) = exe._cache.values()
+    return entry, hlo
+
+
+def _shape_strategy(devices):
+    """DataParallel over the four described chips, placing shapes in
+    place of arrays (a described device holds none)."""
+    from benchmarks.architectures import gpt2_block
+    from benchmarks.sweeps import sizing
+    return sizing._shape_strategy(gpt2_block, None, {"data": 4}, devices)
+
+
+def test_data_parallel_step_keeps_its_all_reduces_asynchronous(
+        described, chip, monkeypatch):
+    """PR 38: under a strategy whose batch is sharded over TPU chips the
+    executor compiles the step with the strategy's compiler options, and
+    the scheduled module keeps gradient all-reduces as pairs of fusions
+    (``async-collective-start`` / ``-done``: the TPU's asynchronous form)
+    with other fusions between a start and its done. With no strategy the
+    same program is compiled with no option and holds no collective."""
+    from paddle_tpu import parallel
+    monkeypatch.setattr(kernel_path, "interpret_mode", lambda: False)
+    strategy = _shape_strategy(described)
+    assert strategy.compiler_options() == parallel._OVERLAPPED_ALL_REDUCE
+    entry, hlo = _narrow_lm_step(chip, strategy)
+    assert entry.options == strategy.compiler_options()
+    # the four FFN matrices' gradients at least; the narrower ones are
+    # combined, and a combined all-reduce is merged back into a plain one
+    assert parallel.async_collectives(hlo) >= 4
+    body = hlo[hlo.index("\nENTRY "):]
+    between = re.findall(
+        r"%async-collective-start(?:\.\d+)? = (.*?)"
+        r"%async-collective-done(?:\.\d+)? = ", body, re.S)
+    assert len(between) >= 4 and all(" fusion(" in b for b in between)
+
+    entry, hlo = _narrow_lm_step(chip, None)
+    assert entry.options == {}
+    assert "all-reduce" not in hlo and "all-gather" not in hlo
+    assert parallel.async_collectives(hlo) == 0
