@@ -17,7 +17,7 @@ _T_IMPORT = _time.perf_counter()    # this import, top to bottom
 
 from .core.framework import (  # noqa: F401
     Program, Variable, Parameter, default_main_program,
-    default_startup_program, program_guard)
+    default_startup_program, program_guard, name_scope)
 from .core.scope import Scope, global_scope, scope_guard  # noqa: F401
 from .core.executor import Executor  # noqa: F401
 from .core.backward import append_backward  # noqa: F401

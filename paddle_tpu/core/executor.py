@@ -436,9 +436,12 @@ def run_block(block, env, trace):
     """Trace every op of ``block`` against ``env`` (name -> traced value).
     Each op is traced under ``jax.named_scope(op.type)`` (the analogue of
     the reference executor's per-op RecordEvent): trace-time only, and an
-    XProf/Perfetto view of a step then groups device ops by Program op."""
+    XProf/Perfetto view of a step then groups device ops by Program op;
+    an op built under ``framework.name_scope`` has its scopes in front."""
     for i, op in enumerate(block.ops):
-        with jax.named_scope(op.type):
+        scope = op.attrs.get("op_namescope")
+        with jax.named_scope("%s/%s" % (scope, op.type) if scope
+                             else op.type):
             if op.type == "vjp_grad":
                 _execute_vjp_grad(op, env, block, trace)
             else:

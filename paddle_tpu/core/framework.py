@@ -16,6 +16,7 @@ Block / Program mirroring a C++ ProgramDesc), re-designed TPU-first:
 """
 
 import contextlib
+import threading
 
 import numpy as np
 
@@ -32,6 +33,7 @@ __all__ = [
     "default_main_program",
     "default_startup_program",
     "program_guard",
+    "name_scope",
     "switch_main_program",
     "switch_startup_program",
     "convert_dtype",
@@ -133,6 +135,8 @@ class Operator:
         self.inputs = {k: list(v) for k, v in (inputs or {}).items()}
         self.outputs = {k: list(v) for k, v in (outputs or {}).items()}
         self.attrs = dict(attrs or {})
+        if getattr(_name_scopes, "open", None):
+            self.attrs["op_namescope"] = "/".join(_name_scopes.open)
 
     def input(self, slot):
         names = self.inputs.get(slot, [])
@@ -332,6 +336,26 @@ def switch_startup_program(program):
         program.name = "startup"
     prev, _startup_program = _startup_program, program
     return prev
+
+
+# the scopes open on the thread that builds ops (``open``: a list)
+_name_scopes = threading.local()
+
+
+@contextlib.contextmanager
+def name_scope(name):
+    """Ops built inside, on this thread, carry the attr ``op_namescope``
+    (the scopes that are open, joined by ``/``; reference parity), and the
+    executor traces such an op under ``jax.named_scope`` of them before its
+    type's: a device trace's ``op_name`` then tells a branch of a block
+    from its neighbours. Ops built outside any scope have no such attr."""
+    if not hasattr(_name_scopes, "open"):
+        _name_scopes.open = []
+    _name_scopes.open.append(name)
+    try:
+        yield
+    finally:
+        _name_scopes.open.pop()
 
 
 @contextlib.contextmanager

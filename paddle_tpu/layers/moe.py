@@ -81,26 +81,31 @@ def swiglu(x, d_ff, prefix, dtype=None, **kwargs):
 
 def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
             route_scale=1.0, expert_offset=0, experts_held=None,
-            dtype=None, std=0.02, scoring="sigmoid", **kwargs):
+            dtype=None, std=0.02, scoring="sigmoid", zero_experts=0,
+            **kwargs):
     """The routed experts of a sparse feed-forward over x [.., d]
     (ops/moe_ops.py ``moe_ffn``): the router ``<prefix>.router.w`` [d, E]
     and the selection bias ``<prefix>.expert_bias`` [E] in float32, the
     held experts ``[expert_offset, expert_offset + experts_held)`` stacked
     as ``<prefix>.experts.gate.w``, ``.up.w`` [E_held, d, d_ff] and
     ``.down.w`` [E_held, d_ff, d] in ``dtype``. ``scoring`` is the
-    router's (``sigmoid``, or ``softmax_topk``: a softmax over the chosen
-    logits). Returns (out float32, counts [E_held] int32)."""
+    router's (``sigmoid``; ``softmax_bias``: a softmax over all outputs;
+    or ``softmax_topk``: a softmax over the chosen logits). With
+    ``zero_experts`` Z the router and the bias are ``E + Z`` wide: the
+    last Z outputs are identity experts. Returns (out float32, counts
+    [E_held] int32), and with ``zero_experts`` a third, the call's
+    identity pairs [1] int32."""
     helper = LayerHelper("moe_ffn", **kwargs)
     d = x.shape[-1]
     held = num_experts if experts_held is None else experts_held
     dtype = dtype or x.dtype
     normal = NormalInitializer(0.0, std)
     router = helper.create_parameter(
-        prefix + ".router.w", shape=[d, num_experts], dtype="float32",
-        default_initializer=normal)
+        prefix + ".router.w", shape=[d, num_experts + zero_experts],
+        dtype="float32", default_initializer=normal)
     bias = helper.create_parameter(
-        prefix + ".expert_bias", shape=[num_experts], dtype="float32",
-        default_initializer=ConstantInitializer(0.0))
+        prefix + ".expert_bias", shape=[num_experts + zero_experts],
+        dtype="float32", default_initializer=ConstantInitializer(0.0))
     stacks = [helper.create_parameter(
         "%s.experts.%s.w" % (prefix, which), shape=shape, dtype=dtype,
         default_initializer=normal)
@@ -114,14 +119,18 @@ def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
              "expert_offset": expert_offset}
     if scoring != "sigmoid":
         attrs["scoring"] = scoring
+    outputs = {"Out": [out.name], "Counts": [counts.name]}
+    if zero_experts:
+        attrs["zero_experts"] = zero_experts
+        zero_pairs = helper.create_tmp_variable("int32", stop_gradient=True)
+        outputs["ZeroPairs"] = [zero_pairs.name]
     helper.append_op(
         type="moe_ffn",
         inputs={"X": [x.name], "RouterW": [router.name],
                 "ExpertBias": [bias.name], "WGate": [stacks[0].name],
                 "WUp": [stacks[1].name], "WDown": [stacks[2].name]},
-        outputs={"Out": [out.name], "Counts": [counts.name]},
-        attrs=attrs)
-    return out, counts
+        outputs=outputs, attrs=attrs)
+    return (out, counts, zero_pairs) if zero_experts else (out, counts)
 
 
 def mla_attention(q, c, k_rope, num_heads, nope_dim, rope_dim, v_dim, scale,

@@ -17,6 +17,18 @@ mixer that is layer by layer a Mamba-2 state-space mixer (``"mamba"``,
   no embedding scale (the ``kimi_k2`` / DeepSeek-V3 family;
   ``benchmarks/reference/kimi_k2.py``).
 
+The layer's own shape is data too. Without ``block`` a layer is one mixer
+and then one feed-forward, each added to the residual stream where it was
+read. With ``block`` (``halves``, ``experts_read``, ``experts_join``) a
+layer is that many **halves**, each an attention and a dense feed-forward
+with their own norms, and ONE expert layer (no shared expert) laid across
+them: it reads the norm before half ``experts_read``'s feed-forward and
+its result joins the residual stream only after half ``experts_join``'s
+(the shortcut-connected MoE of the ``longcat_flash`` family,
+``benchmarks/reference/longcat_flash.py``; its router is ``zero_experts``
+wider than its experts, ``ops/moe_ops.py``). Such a layer has a layer
+cache a half: the session counts attention **sites**, not layers.
+
 :func:`moe_lm` is the whole-sequence forward (its startup program makes the
 weights); :func:`moe_lm_session` builds the paged prefill and decode
 programs through ``transformer.lm_session``, with one kind of layer cache
@@ -37,6 +49,7 @@ the layer caches should be float32 too.
 import math
 
 from .. import layers
+from ..core.framework import name_scope
 from ..layer_helper import LayerHelper
 from ..initializer import NormalInitializer
 from ..param_attr import ParamAttr
@@ -62,7 +75,8 @@ class MoeLM:
                  post_norms=True, latent=None, rope_scaling=None,
                  scoring="sigmoid", qk_norm=True, attn_gate=True,
                  attn_scale=None, residual_scale=None, logit_scale=None,
-                 tie_embeddings=False, mamba=None, shared_d_ff=None):
+                 tie_embeddings=False, mamba=None, shared_d_ff=None,
+                 block=None, zero_experts=0):
         unknown = set(layer_types) - {SLIDING, FULL, MAMBA}
         if unknown:
             raise ValueError("layer_types holds %s: a layer is %r, %r or %r"
@@ -94,7 +108,16 @@ class MoeLM:
         self.attn_scale = attn_scale
         self.residual_scale, self.logit_scale = residual_scale, logit_scale
         self.tie_embeddings = tie_embeddings
-        # expert pairs a row of a step routes, held here or not
+        self.zero_experts = zero_experts
+        self.block = dict(block) if block else None
+        if block and (attention != "latent" or num_dense_layers
+                      or not 0 <= block["experts_read"]
+                      <= block["experts_join"] < block["halves"]):
+            raise ValueError("a block of halves is latent attention's, has "
+                             "an expert layer in every layer and reads it "
+                             "at a half no later than the one it joins")
+        # expert pairs a row of a step routes, held here or not: every
+        # layer but the leading dense ones has one expert layer
         self.pairs_per_row = top_k * (len(self.layer_types)
                                       - num_dense_layers)
         if attention == "latent":
@@ -125,14 +148,17 @@ class MoeLM:
             (num_kv_heads * head_dim, present.index(t))
             for t in self.layer_types]
 
-    def _latent_sizes(self, q_rank, kv_rank, nope_dim, rope_dim, v_dim):
+    def _latent_sizes(self, q_rank, kv_rank, nope_dim, rope_dim, v_dim,
+                      q_scale=None, kv_scale=None):
         """Latent attention's widths, its one kind of layer cache and the
         scale of its scores: ``(nope + rope)^-1/2``, times the square of
         YaRN's ``0.1 mscale_all_dim ln(factor) + 1`` where the positions
-        are scaled."""
+        are scaled. ``q_scale`` / ``kv_scale`` (absent: none) multiply the
+        two latents after their norms."""
         if set(self.layer_types) != {FULL}:
             raise ValueError("latent attention has no window layers")
         self.q_rank, self.kv_rank = q_rank, kv_rank
+        self.q_scale, self.kv_scale = q_scale, kv_scale
         self.nope, self.rope, self.v_dim = nope_dim, rope_dim, v_dim
         m = 1.0
         if self.yarn and self.yarn.get("mscale_all_dim"):
@@ -140,11 +166,13 @@ class MoeLM:
                 math.log(self.yarn["factor"]) + 1.0
         self.attn_scale = (nope_dim + rope_dim) ** -0.5 * m * m
         # a cached row is (c, k_r) and zeros up to whole lane tiles: one
-        # pool a layer, read once a page as key and value
+        # pool an attention site (a layer, or each half of one), read once
+        # a page as key and value
         self.row_width = -(-(kv_rank + rope_dim) // 128) * 128
         self.kinds = (("latent", None),)
         self.cache_pools = ("c",)
-        self.cache_layers = [(self.row_width, 0)] * len(self.layer_types)
+        self.cache_layers = [(self.row_width, 0)] * (
+            len(self.layer_types) * (self.block or {}).get("halves", 1))
         # a prompt's rows attend the prompt's own latents: nothing cached
         # before them is seen
         self.prefill_sees_history = False
@@ -166,18 +194,22 @@ class MoeLM:
         return dict(pos=ctx["pos"] if decode else ctx["pos_idx"],
                     per_row=decode)
 
-    def _latent_attention(self, a, i, ctx):
+    def _latent_attention(self, a, p, site, ctx):
         """a [B, T, d] -> latent attention's output [B, T, H*v]: expanded
         over the rows' own latents (whole sequences, a prefill), absorbed
-        over the layer's paged latent pool (a decode step)."""
-        p = "l%d.attn." % i
+        over the site's paged latent pool (a decode step). ``p`` prefixes
+        the site's parameters, ``site`` is its layer cache's index."""
         nh, nope, rope = self.nh, self.nope, self.rope
         c_q = self._norm(self._linear(a, self.q_rank, p + "q_a"),
                          p + "q_a_norm")
+        if self.q_scale:
+            c_q = layers.scale(c_q, self.q_scale)
         q = self._linear(c_q, nh * (nope + rope), p + "q_b")
         ckr = self._linear(a, self.kv_rank + rope, p + "kv_a")
         c = self._norm(layers.slice(ckr, [2], [0], [self.kv_rank]),
                        p + "kv_a_norm")
+        if self.kv_scale:
+            c = layers.scale(c, self.kv_scale)
         k_r = layers.slice(ckr, [2], [self.kv_rank], [self.kv_rank + rope])
         turn = dict(self._positions(ctx), theta=self.theta, yarn=self.yarn)
         q = layers.rotary_embedding(q, nope + rope,
@@ -188,8 +220,8 @@ class MoeLM:
                       param_attr="moe_lm.%skv_b.w" % p, dtype=self.dtype,
                       std=self.std)
         if ctx is not None:
-            # the token's row into the layer's pool: (c, k_r, zeros)
-            pool, = ctx["caches"][i]
+            # the token's row into the site's pool: (c, k_r, zeros)
+            pool, = ctx["caches"][site]
             row = layers.pad(layers.concat([c, k_r], axis=2),
                              [0, 0, 0, 0, 0,
                               self.row_width - self.kv_rank - rope])
@@ -227,9 +259,9 @@ class MoeLM:
     def _attention(self, a, i, ctx):
         """a [B, T, d] -> the (gated) attention output [B, T, H*D], through
         the layer's paged cache where ``ctx`` has one."""
-        if self.attention == "latent":
-            return self._latent_attention(a, i, ctx)
         p = "l%d.attn." % i
+        if self.attention == "latent":
+            return self._latent_attention(a, p, i, ctx)
         windowed = self.layer_types[i] == SLIDING
         q = self._linear(a, self.nh * self.hd, p + "q")
         k = self._linear(a, self.nkv * self.hd, p + "k")
@@ -293,18 +325,49 @@ class MoeLM:
         p = "moe_lm.l%d.moe" % i
         shared = layers.swiglu(m, self.shared_d_ff, p + ".shared",
                                self.dtype)
-        routed, counts = layers.moe_ffn(
-            m, self.num_experts, self.top_k, self.moe_d_ff, p,
+        routed, counts = self._experts(m, i)
+        return layers.elementwise_add(routed, shared), counts
+
+    def _experts(self, m, i):
+        """-> (the held routed experts' part of layer i's expert layer,
+        its pair counts: the held experts' and, behind them, the identity
+        experts' where the router has any)."""
+        routed, *counts = layers.moe_ffn(
+            m, self.num_experts, self.top_k, self.moe_d_ff,
+            "moe_lm.l%d.moe" % i,
             route_norm=self.route_norm, route_scale=self.route_scale,
             expert_offset=self.expert_offset,
             experts_held=self.experts_held, dtype=self.dtype, std=self.std,
-            scoring=self.scoring)
-        return layers.elementwise_add(routed, shared), counts
+            scoring=self.scoring, zero_experts=self.zero_experts)
+        return routed, (layers.concat(counts, axis=0) if self.zero_experts
+                        else counts[0])
 
     def _residual(self, h, o):
         if self.residual_scale is not None:
             o = layers.scale(o, self.residual_scale)
         return layers.elementwise_add(h, o)
+
+    def _halves(self, h, i, ctx):
+        """Layer i as a block of halves -> (h, the expert layer's counts).
+        The expert layer is the shortcut: read at one half's norm, added
+        after another's feed-forward, so that in a deployment its exchange
+        runs beside the dense work in between."""
+        b = self.block
+        for j in range(b["halves"]):
+            p = "l%d.h%d." % (i, j)
+            o = self._latent_attention(self._norm(h, p + "norm_in"),
+                                       p + "attn.", i * b["halves"] + j, ctx)
+            h = self._residual(h, self._linear(o, self.d, p + "attn.o"))
+            u = self._norm(h, p + "norm_pre_mlp")
+            if j == b["experts_read"]:
+                with name_scope("scmoe_shortcut"):
+                    s, counts = self._experts(u, i)
+            h = self._residual(h, layers.swiglu(
+                u, self.d_ff, "moe_lm.%smlp" % p, self.dtype))
+            if j == b["experts_join"]:
+                with name_scope("scmoe_shortcut"):
+                    h = self._residual(h, s)
+        return h, counts
 
     def hidden(self, tokens, ctx=None):
         """tokens [B, T] -> (h [B, T, d] float32 before the final norm,
@@ -320,6 +383,10 @@ class MoeLM:
             h = layers.scale(h, self.embed_scale)
         all_counts = []
         for i in range(len(self.layer_types)):
+            if self.block:
+                h, counts = self._halves(h, i, ctx)
+                all_counts.append(counts)
+                continue
             a = self._norm(h, "l%d.norm_in" % i)
             if self.layer_types[i] == MAMBA:
                 o = self._mixer(a, i, ctx)

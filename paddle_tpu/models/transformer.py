@@ -303,9 +303,11 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     programs and every argument) with the model behind an object.
     ``model`` offers ``vocab_size``; ``kinds``, its kinds of layer cache
     as (name, window) pairs, the first the one ``num_blocks`` sizes;
-    ``cache_layers``, per layer the width of a cached row and the index
-    of its kind; optionally ``cache_pools``, the pools a layer has (default
-    ``("k", "v")``; a latent kind has one, ``("c",)``, whose row is key and
+    ``cache_layers``, per layer cache (one a layer, or one an attention
+    site where a layer has several: the model reads
+    ``cache_ctx["caches"]`` by the same index) the width of a cached row
+    and the index of its kind; optionally ``cache_pools``, the pools a
+    layer has (default ``("k", "v")``; a latent kind has one, ``("c",)``, whose row is key and
     value at once) and ``prefill_sees_history`` (False: a prefill attends
     its window's own rows only, so neither a shared prefix nor a
     speculative verify can be built on it);
@@ -313,7 +315,9 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     ``prefill_row(tokens, last_pos, cache_ctx)`` -> [1, V];
     ``decode_row(tokens, cache_ctx)`` -> ([slots, V], expert counts or
     None; with counts the model offers ``pairs_per_row``, the expert pairs
-    a row routes in a step, held here or not); ``draft(overrides)`` ->
+    a row routes in a step, held here or not, and where its router has
+    identity experts ``zero_experts``: each layer's counts then end in
+    the step's identity pairs); ``draft(overrides)`` ->
     the speculative draft's model.
     ``kind_blocks`` sizes the pools of the kinds after the first, by
     name; their table feeds are ``gen.ptab.<name>`` / ``gen.dtab.<name>``
@@ -639,6 +643,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         cache_kinds=cache_kinds,
         stats_fetch=None if stats is None else stats.name,
         routed_pairs=None if stats is None else slots * model.pairs_per_row,
+        zero_experts=getattr(model, "zero_experts", 0),
         latent_layers=sum(kinds[k][0] == "latent"
                           for _, k in model.cache_layers),
         state_layers=sum(kinds[k][0] == "state"

@@ -42,6 +42,10 @@ pays three times the products.
   that fall on its experts, which lie first in the sorted order, in passes
   of at most ``SHARE_ROWS`` rows until none is left: its work and its
   temporaries follow the held pairs, and still no pair is dropped.
+  With ``zero_experts`` the router is that much wider than the experts:
+  an output past the last real expert is an **identity expert**, whose
+  pair adds ``w x`` and costs no matmul (``zero_expert_combine``). Every
+  holder adds it for its own rows, so over shares it counts once.
 """
 
 import math
@@ -191,17 +195,19 @@ def route(x, router_w, bias, top_k, route_norm, route_scale,
     """The router of ``moe_ffn``: x [n, d] -> (sel [n, k] expert ids,
     w [n, k] float32 weights), from float32 logits taken at the highest
     precision. ``scoring`` ``sigmoid``: scores are the logits' sigmoid,
-    ``bias`` moves the selection only; ``softmax_topk``: the top k of the
-    logits themselves, weighed by a softmax over the chosen k."""
+    ``bias`` moves the selection only; ``softmax_bias``: the same with a
+    softmax over all outputs for scores; ``softmax_topk``: the top k of
+    the logits themselves, weighed by a softmax over the chosen k."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if scoring == "softmax_topk":
         top, sel = jax.lax.top_k(logits, top_k)
         return sel, jax.nn.softmax(top, axis=-1) * route_scale
-    if scoring != "sigmoid":
-        raise ValueError("scoring is 'sigmoid' or 'softmax_topk', not %r"
-                         % (scoring,))
-    s = jax.nn.sigmoid(logits)
+    if scoring not in ("sigmoid", "softmax_bias"):
+        raise ValueError("scoring is 'sigmoid', 'softmax_bias' or "
+                         "'softmax_topk', not %r" % (scoring,))
+    s = jax.nn.sigmoid(logits) if scoring == "sigmoid" else \
+        jax.nn.softmax(logits, axis=-1)
     _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, sel, axis=1)
     if route_norm:
@@ -242,7 +248,11 @@ def _moe_ffn(ctx):
     where the router is no sigmoid ``scoring`` (:func:`route`).
     Out float32, X's shape: sum over the token's selected experts that
     are held of ``w * (silu(x WGate) * (x WUp)) WDown``. Counts [E_held]
-    int32: the pairs each held expert took in this call."""
+    int32: the pairs each held expert took in this call.
+    With attr ``zero_experts`` Z (absent: 0) RouterW and ExpertBias are
+    ``E + Z`` wide and a selected output ``>= E`` is an identity pair: it
+    adds ``w * x`` to Out in float32, falls in no expert's group, and
+    ZeroPairs [1] int32 counts the call's."""
     x = ctx.input("X")
     wg, wu, wd = ctx.input("WGate"), ctx.input("WUp"), ctx.input("WDown")
     k = ctx.attr("top_k")
@@ -251,15 +261,18 @@ def _moe_ffn(ctx):
     x2 = x.reshape(-1, d)
     n = x2.shape[0]
     router_w = ctx.input("RouterW")
-    if router_w.shape[1] != ctx.attr("num_experts"):
+    zero = ctx.attr("zero_experts") or 0
+    if router_w.shape[1] != ctx.attr("num_experts") + zero:
         raise ValueError("moe_ffn routes over %d experts, RouterW has %d"
-                         % (ctx.attr("num_experts"), router_w.shape[1]))
+                         % (ctx.attr("num_experts") + zero,
+                            router_w.shape[1]))
     sel, w = route(x2, router_w, ctx.input("ExpertBias"), k,
                    ctx.attr("route_norm", True),
                    ctx.attr("route_scale", 1.0),
                    ctx.attr("scoring") or "sigmoid")
     # the n*k pairs sorted by held expert; pairs of experts held elsewhere
-    # sort last, fall in no group and weigh nothing
+    # (and identity pairs, whose ids lie past every real expert's) sort
+    # last, fall in no group and weigh nothing
     local = sel.reshape(-1) - offset
     mine = (local >= 0) & (local < held)
     key = jnp.where(mine, local, held)
@@ -298,4 +311,11 @@ def _moe_ffn(ctx):
 
         out = jax.lax.fori_loop(0, (total + rows - 1) // rows, one_pass,
                                 jnp.zeros((n, d), jnp.float32))
-    return {"Out": out.reshape(x.shape), "Counts": counts}
+    if not zero:
+        return {"Out": out.reshape(x.shape), "Counts": counts}
+    with jax.named_scope("zero_expert_combine"):
+        identity = sel >= ctx.attr("num_experts")
+        out = out + jnp.sum(jnp.where(identity, w, 0.0), axis=1,
+                            keepdims=True) * x2.astype(jnp.float32)
+    return {"Out": out.reshape(x.shape), "Counts": counts,
+            "ZeroPairs": jnp.sum(identity, dtype=jnp.int32).reshape(1)}

@@ -206,8 +206,9 @@ _WINDOW_CONTEXT_TOKENS = _metrics.REGISTRY.counter(
 _LATENT_ROWS_ATTENDED = _metrics.REGISTRY.counter(
     "paddle_generation_latent_rows_attended_total",
     "Cached latent rows attended by decode steps: per step, the sum over "
-    "the slots that advanced and over the layers of a latent kind of "
-    "their context length, the new token included")
+    "the slots that advanced and over the attention sites of a latent "
+    "kind (one a layer, or one a half of a layer of two halves) of their "
+    "context length, the new token included")
 _STATE_ROWS_UPDATED = _metrics.REGISTRY.counter(
     "paddle_generation_state_rows_updated_total",
     "State rows advanced by decode steps: per step, the layers of a state "
@@ -218,6 +219,12 @@ _ROUTED_PAIRS = _metrics.REGISTRY.counter(
     "Token-expert pairs routed by the expert layers of decode steps "
     "(rows x top-k), whether the expert is held here or not: over it, "
     "_expert_assignments_total is the share that fell on held experts")
+_ZERO_EXPERT_PAIRS = _metrics.REGISTRY.counter(
+    "paddle_generation_zero_expert_pairs_total",
+    "Token-expert pairs of decode steps that fell on identity experts "
+    "(router outputs past the real experts: the pair adds w x and costs "
+    "no matmul), every slot's row counted; over _routed_pairs_total the "
+    "share of the routed pairs that do no work anywhere")
 _MOE_LAYER_STEPS = _metrics.REGISTRY.counter(
     "paddle_generation_moe_layer_steps_total",
     "Expert layers run by decode steps (steps x expert layers)")
@@ -359,11 +366,14 @@ class GenerationSpec:
     ``[expert layers, held experts]`` of the decode program, the pairs
     each held expert took in the step, fetched with the step's tokens;
     ``routed_pairs`` is then how many pairs a step routes, held here or
-    not (absent: every expert is held and the counts add up to it).
+    not (absent: every expert is held and the counts add up to it);
+    with ``zero_experts`` (a router that much wider than its experts)
+    the array has one column more, the layer's identity pairs.
 
-    ``latent_layers`` counts the layers whose cache is a latent kind's:
-    one pool a layer, whose row is key and value at once (``cache_vars``
-    names one variable a layer, not a K and a V); its books are the full
+    ``latent_layers`` counts the layer caches of a latent kind, one an
+    attention site (a layer, or each half of a layer that has two): one
+    pool each, whose row is key and value at once (``cache_vars`` names
+    one variable a site, not a K and a V); its books are the full
     kind's. ``state_layers`` counts the layers of a state kind
     (``paged_cache.CacheKind``): three variables a layer, one row a slot.
     ``kind_block_bytes`` says kind by kind what one block (of a state
@@ -378,7 +388,7 @@ class GenerationSpec:
                  "prefix_cache", "copy_program", "copy_feeds",
                  "vocab_size", "policy", "verify_program",
                  "verify_feeds", "verify_fetch", "draft_spec",
-                 "cache_kinds", "stats_fetch", "routed_pairs",
+                 "cache_kinds", "stats_fetch", "routed_pairs", "zero_experts",
                  "latent_layers", "state_layers", "kind_block_bytes")
 
     # a constant, kept because benchmarks/harness/serve.py:71 checks it
@@ -399,6 +409,7 @@ class GenerationSpec:
         kwargs.setdefault("cache_kinds", None)
         kwargs.setdefault("stats_fetch", None)
         kwargs.setdefault("routed_pairs", None)
+        kwargs.setdefault("zero_experts", 0)
         kwargs.setdefault("latent_layers", 0)
         kwargs.setdefault("state_layers", 0)
         for name in self.__slots__:
@@ -1347,7 +1358,11 @@ class GenerationSession:
 
     def _count_experts(self, counts):
         """The routing counters from a step's ``[expert layers, held
-        experts]`` pair counts."""
+        experts]`` pair counts (behind them, where the router has identity
+        experts, the layer's identity pairs)."""
+        if getattr(self.spec, "zero_experts", 0):
+            _ZERO_EXPERT_PAIRS.inc(int(counts[:, -1].sum()))
+            counts = counts[:, :-1]
         held = int(counts.sum())
         _MOE_LAYER_STEPS.inc(counts.shape[0])
         _EXPERTS_TOUCHED.inc(int((counts > 0).sum()))
