@@ -5,7 +5,14 @@ The fused kernel streams K/V from VMEM against one Q block at a time:
 scores, causal mask, softmax, and the P@V contraction all happen
 on-chip, so the [T, T] probability matrix never exists in HBM (the XLA
 fallback in ops/attention_ops.py writes it out between the two
-einsums). Forward and backward are Pallas kernels under
+einsums). Both kernels hand the MXU their operands in the dtype the
+model gives them (bfloat16 under amp; float32 inputs stay float32) and
+round the probabilities to it where they enter a product, as
+``_reference`` does; scores, statistics and sums are float32. A causal
+tile wholly above the diagonal does no arithmetic and fetches nothing:
+its index map stays on a block that is in VMEM already. The forward
+takes tiles of up to 1,024 x 1,024 rows (``_tiles``), the backward of
+512 x 512. Forward and backward are Pallas kernels under
 jax.custom_vjp: the forward under differentiation also keeps the
 log-sum-exp of every query row ([BH, 1, T] float32), and the backward
 (``flash_attention_bwd``) rebuilds the probabilities from it a
@@ -122,7 +129,12 @@ def _body(q_ref, k_ref, v_ref, *refs, segmented, with_lse, scale, causal,
     :func:`_tile_mask`); o_ref; ``with_lse`` (the forward under
     differentiation) lse_ref, the whole [1, 1, T] float32 row of a
     batch-head: the log-sum-exp of every query row, ``m + log l``, which
-    the backward kernel rebuilds the probabilities from; the scratch."""
+    the backward kernel rebuilds the probabilities from; the scratch.
+    A step's cost has a part that goes with ``block_q`` alone (the
+    float32 max, sum and accumulator ``[bq, 128]`` are read, rescaled and
+    written every step), so wide k blocks pay it less often: at
+    ``[64, 2048, 128]`` bfloat16 blocks of (1024, 1024) take 0.87 ms
+    where (256, 512) take 1.77 (my chip runs, PR 41)."""
     sq_ref, sk_ref = refs[:2] if segmented else (None, None)
     o_ref = refs[2 * segmented]
     lse_ref = refs[2 * segmented + 1] if with_lse else None
@@ -137,18 +149,14 @@ def _body(q_ref, k_ref, v_ref, *refs, segmented, with_lse, scale, causal,
 
     @pl.when(_live(qi, ki, block_q, block_k, causal))
     def _step():
-        # narrow (bf16) pools upcast at the contraction, matching the
-        # reference's promotion; identity trace for f32 pools, so the
-        # flag-off program stays byte-identical
-        k_blk = k_ref[0]
-        if k_blk.dtype != jnp.float32:
-            k_blk = k_blk.astype(jnp.float32)
-        v_blk = v_ref[0]
-        if v_blk.dtype != jnp.float32:
-            v_blk = v_blk.astype(jnp.float32)
-        s = jnp.dot(q_ref[0], k_blk.T,
-                    preferred_element_type=jnp.float32,
-                    precision=jax.lax.Precision.DEFAULT) * scale
+        # q, k and v reach the MXU in the one operand dtype _forward
+        # gave them, p rounded to it at P V as in _reference; the
+        # scores, the statistics and the sums are float32. (Float32
+        # operands at Precision.DEFAULT are one bfloat16 pass on the
+        # chip too: a float32 copy of bfloat16 tiles gave the same bits
+        # and the same time, my chip runs, PR 41.)
+        v = v_ref[0]
+        s = _mxu(q_ref[0], k_ref[0], _NT) * scale
         mask = _tile_mask(qi, ki, block_q, block_k, causal, sq_ref, sk_ref)
         if mask is not None:
             s = jnp.where(mask, s, _NEG)
@@ -157,14 +165,16 @@ def _body(q_ref, k_ref, v_ref, *refs, segmented, with_lse, scale, causal,
                             jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, :1])
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)  # kill fully-masked rows
+        if segmented:
+            # a row no key of which attends so far (padding) has
+            # m_new = -1e30 and exp gave 1. Under the causal mask alone
+            # every row has seen key 0 by its first step, m_new is a
+            # score, and exp gave a masked pair exactly 0
+            p = jnp.where(mask, p, 0.0)
         l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=-1,
                                               keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha[:, :1] + jnp.dot(
-            p.astype(v_blk.dtype), v_blk,
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT)
+        acc_ref[:] = acc_ref[:] * alpha[:, :1] + _mxu(
+            p.astype(v.dtype), v, _NN)
         m_ref[:] = m_new
 
     @pl.when(ki == nk - 1)
@@ -210,10 +220,20 @@ def _seg_rows(seg):
     return [seg.reshape(bh, 1, t)] * 2, [_row_spec(t)] * 2
 
 
+def _k_block(i, j, bq, bk, causal):
+    """The k block that grid step (q block ``i``, k block ``j``) of the
+    forward names: ``j`` itself for a live tile; a causal tile wholly
+    above the diagonal (not :func:`_live`) stays on the q block's last
+    live k block, which is in VMEM already, so its step fetches
+    nothing. The mirror of the backward's ``q_of``."""
+    return jnp.minimum(j, (i * bq + bq - 1) // bk) if causal else j
+
+
 def _forward(q, k, v, seg, causal, bq, bk, interpret, with_lse=False):
     """The forward kernel on blocks of ``bq`` query and ``bk`` key rows;
     ``with_lse``: also the [BH, 1, T] float32 log-sum-exp of every query
-    row (``bq`` whole lane tiles then)."""
+    row (``bq`` whole lane tiles then). q, k and v go to the MXU in one
+    operand dtype, the widest of theirs: bfloat16 inputs as they are."""
     from jax.experimental.pallas import tpu as pltpu
     bh, t, d = q.shape
     segs, seg_specs = _seg_rows(seg)
@@ -223,17 +243,18 @@ def _forward(q, k, v, seg, causal, bq, bk, interpret, with_lse=False):
         out_shape = (out_shape,
                      jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
         out_spec = (out_spec, _row_spec(t))
+    mxu = jnp.promote_types(q.dtype, k.dtype)   # one operand dtype
+    q, k, v = (x.astype(mxu) for x in (q, k, v))
+    k_spec = pl.BlockSpec(
+        (1, bk, d), lambda b, i, j: (b, _k_block(i, j, bq, bk, causal), 0))
     return pl.pallas_call(
         functools.partial(_body, segmented=bool(segs), with_lse=with_lse,
                           scale=d ** -0.5, causal=causal, block_q=bq,
                           block_k=bk, nk=t // bk),
         name="flash_attention_fwd" + ("_seg" if segs else ""),
         grid=(bh, t // bq, t // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-        ] + seg_specs,
+        in_specs=[pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                  k_spec, k_spec] + seg_specs,
         out_shape=out_shape, out_specs=out_spec,
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),     # acc
@@ -359,18 +380,32 @@ def _backward(q, k, v, o, lse, seg, do, causal, interpret):
     return dq, dk, dv
 
 
-def _tiles(t, block_q, segmented, lanes=False):
-    """(bq, bk) the forward kernel tiles a length with, or None for a
-    ragged one. ``lanes``: q blocks of whole lane tiles, which the row
+# rows of q and of k a forward tile takes, at most, and the bytes of one
+# operand tile (1,024 rows of a 128-wide float32 head), which keep a wider
+# head's tiles within the VMEM a kernel gets (my chip runs, PR 41:
+# CHANGES.md has the table of pairs)
+_FWD_BLOCK = 1024
+_FWD_TILE_BYTES = 512 << 10
+
+
+def _tiles(q, k, block_q, segmented, lanes=False):
+    """(bq, bk) the forward kernel tiles q, k [BH, T, D] with, or None for
+    a ragged length: the largest aligned divisors of T within
+    ``_FWD_BLOCK`` rows and ``_FWD_TILE_BYTES`` a tile of the operand
+    dtype (``block_q``, where a caller gives one, in place of the q
+    rows). ``lanes``: q blocks of whole lane tiles, which the row
     statistics need (they are stored and sliced along lanes)."""
+    _, t, d = q.shape
+    row = d * jnp.promote_types(q.dtype, k.dtype).itemsize
+    cap = min(_FWD_BLOCK, max(128, _FWD_TILE_BYTES // row))
     align = 128 if segmented else 16
-    bq = _block_size(t, block_q, 128 if lanes else align)
-    bk = _block_size(t, 512, align)
+    bq = _block_size(t, block_q or cap, 128 if lanes else align)
+    bk = _block_size(t, cap, align)
     return (bq, bk) if bq and bk else None
 
 
 def _primal(q, k, v, seg, causal, block_q, interpret):
-    tiles = _tiles(q.shape[1], block_q, seg is not None)
+    tiles = _tiles(q, k, block_q, seg is not None)
     if tiles is None:
         kernel_path.record("flash_attention")
         return _reference(q, k, v, causal, seg)  # ragged: XLA path
@@ -387,7 +422,7 @@ def _flash_fwd(q, k, v, seg, causal, block_q, interpret):
     any other length keeps (q, k, v) alone and differentiates the
     reference."""
     _, t, d = q.shape
-    tiles = _tiles(t, block_q, seg is not None, lanes=True)
+    tiles = _tiles(q, k, block_q, seg is not None, lanes=True)
     if tiles is None or t * d * 4 > _BWD_DQ_BYTES:
         return _primal(q, k, v, seg, causal, block_q, interpret), \
             (q, k, v, None, None, seg)
@@ -792,14 +827,15 @@ def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
 
 
 def flash_attention(q, k, v, causal=False, segment_ids=None,
-                    block_q=256, interpret=None):
+                    block_q=None, interpret=None):
     """q, k, v: [B, H, T, D] (or [BH, T, D]) -> same-shape output.
     Fused Pallas forward and backward. ``segment_ids``:
     [B, T] int32, 0 = padding — a key is attendable iff its id matches
     the query's and is nonzero (one mask covering the padded-batch
     convention AND packed sequences, SURVEY §5.7). Padded query rows
-    yield zeros. ``interpret=None`` auto-selects interpreter mode
-    off-TPU."""
+    yield zeros. ``block_q``: the most query rows a forward tile takes
+    (default: :func:`_tiles`' own). ``interpret=None`` auto-selects
+    interpreter mode off-TPU."""
     if interpret is None:
         interpret = kernel_path.interpret_mode()
     squeeze = q.ndim == 3

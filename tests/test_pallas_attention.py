@@ -4,7 +4,10 @@ which emulates TPU MXU semantics (bf16 multiply passes for f32 dots) —
 tolerances are set for that. The backward is a kernel too
 (``flash_attention_bwd``): its gradients are held to the float32
 reference vjp at the forward's tolerance, and at 1e-5 to a jnp backward
-that rounds where the kernel rounds (``_rounded_backward``)."""
+that rounds where the kernel rounds (``_rounded_backward``). The forward
+takes its operands in their own dtype (bfloat16 to the MXU as bfloat16)
+and is held the same two ways: ``_rounded_forward`` rounds ``p`` to the
+operand dtype at ``P V`` and sums in float32."""
 
 import numpy as np
 import pytest
@@ -28,6 +31,69 @@ STRICT = pltpu.InterpretParams(uninitialized_memory="nan",
                                detect_races=True)
 
 
+def _pair_mask(t, causal, seg):
+    """[1 or BH, T, T] bool: which (query, key) pairs attend."""
+    mask = jnp.ones((1, t, t), bool)
+    if causal:
+        mask = mask & jnp.tril(jnp.ones((t, t), bool))[None]
+    if seg is not None:
+        mask = mask & (seg[:, :, None] == seg[:, None, :]) & \
+            (seg[:, None, :] != 0)
+    return mask
+
+
+def _rounded_forward(q, k, v, causal, seg=None):
+    """(o, lse) of attention over [BH, T, D] by the forward kernel's own
+    mathematics in plain jnp: float32 scores of the operands as given,
+    float32 max, sum and log-sum-exp, ``p`` rounded to the operand dtype
+    where it enters ``P V``, the quotient rounded to the output's. A
+    fully masked row: zeros, and -1e30 for its lse."""
+    dt, f32 = q.dtype, jnp.float32
+    t, scale = q.shape[1], q.shape[-1] ** -0.5
+    q, k, v = (x.astype(f32) for x in (q, k, v))
+    mask = _pair_mask(t, causal, seg)
+    s = jnp.where(mask, jnp.einsum("bqd,bkd->bqk", q, k) * scale, -1e30)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("bqk,bkd->bqd", p.astype(dt).astype(f32), v) / \
+        jnp.maximum(l, 1e-30)
+    lse = m + jnp.log(jnp.maximum(l, 1e-30))
+    return o.astype(dt), jnp.where(l > 0, lse, -1e30)[..., 0]
+
+
+def _segments(seg, t, bh):
+    """The segment-id cases of the kernel tests: ``padding`` (a padded
+    tail in the first batch-head), ``packed`` (two sequences and a padded
+    tail), or none."""
+    if seg == "padding":
+        return (jnp.arange(t)[None, :] <
+                jnp.asarray([t - 200, t])[:, None]).astype(jnp.int32)
+    if seg == "packed":
+        return jnp.broadcast_to(jnp.concatenate([
+            jnp.full((t // 2 - 72,), 1), jnp.full((t // 2,), 2),
+            jnp.zeros((72,))]).astype(jnp.int32), (bh, t))
+    return None
+
+
+# (T, causal, segment ids): one tile; divisor blocks; tiles skipped and
+# tiles the diagonal cuts askew; padding and packed rows, fully masked.
+# The notes are of the backward's tiles (512 rows at most); the forward's
+# test sets its own
+_KERNEL_CASES = [
+    (128, True, None),        # one tile
+    (512, False, None),
+    (768, True, None),        # divisor blocks: 2 x 384 both ways
+    (1024, True, None),       # 2 x 2 tiles, one skipped
+    (1280, False, None),      # bq 256 != bk 320
+    (1280, True, None),       # ... and tiles the diagonal cuts askew
+    (1024, True, "padding"),
+    (1024, False, "padding"),
+    (1280, True, "packed"),   # bq = bk = 256 (whole lane tiles)
+    (1024, False, "packed"),
+]
+
+
 def _rounded_backward(q, k, v, do, causal, seg=None):
     """(dq, dk, dv) of attention over [BH, T, D] by the kernel's own
     mathematics in plain jnp: float32 scores and statistics, ``p`` and
@@ -37,12 +103,7 @@ def _rounded_backward(q, k, v, do, causal, seg=None):
     dt, f32 = q.dtype, jnp.float32
     t, scale = q.shape[1], q.shape[-1] ** -0.5
     q, k, v, do = (x.astype(f32) for x in (q, k, v, do))
-    mask = jnp.ones((1, t, t), bool)
-    if causal:
-        mask = mask & jnp.tril(jnp.ones((t, t), bool))[None]
-    if seg is not None:
-        mask = mask & (seg[:, :, None] == seg[:, None, :]) & \
-            (seg[:, None, :] != 0)
+    mask = _pair_mask(t, causal, seg)
     s = jnp.where(mask, jnp.einsum("bqd,bkd->bqk", q, k) * scale, -1e30)
     lse = jax.nn.logsumexp(s, axis=-1, keepdims=True)
     p = jnp.where(mask, jnp.exp(s - lse), 0.0)
@@ -108,18 +169,7 @@ class TestFlashKernel:
 
     @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
                                            ("bfloat16", 1e-2)])
-    @pytest.mark.parametrize("t,causal,seg", [
-        (128, True, None),        # one tile
-        (512, False, None),
-        (768, True, None),        # divisor blocks: 2 x 384 both ways
-        (1024, True, None),       # 2 x 2 tiles, one skipped
-        (1280, False, None),      # bq 256 != bk 320
-        (1280, True, None),       # ... and tiles the diagonal cuts askew
-        (1024, True, "padding"),
-        (1024, False, "padding"),
-        (1280, True, "packed"),   # bq = bk = 256 (whole lane tiles)
-        (1024, False, "packed"),
-    ])
+    @pytest.mark.parametrize("t,causal,seg", _KERNEL_CASES)
     def test_backward_kernel_matches_rounded_backward(self, t, causal, seg,
                                                       dtype, tol):
         """The kernel's gradients against the same mathematics in jnp,
@@ -130,14 +180,7 @@ class TestFlashKernel:
         rs = np.random.RandomState(t + causal)
         q, k, v, do = (jnp.asarray(rs.randn(bh, t, d), dtype)
                        for _ in range(4))
-        ids = None
-        if seg == "padding":
-            ids = (jnp.arange(t)[None, :] <
-                   jnp.asarray([t - 200, t])[:, None]).astype(jnp.int32)
-        elif seg == "packed":      # two sequences and a padded tail
-            ids = jnp.broadcast_to(jnp.concatenate([
-                jnp.full((t // 2 - 72,), 1), jnp.full((t // 2,), 2),
-                jnp.zeros((72,))]).astype(jnp.int32), (bh, t))
+        ids = _segments(seg, t, bh)
         before = _bwd_paths()
         # [B, H, T, D] with a batch row a batch-head: ids differ by row
         _, vjp = jax.vjp(
@@ -156,6 +199,113 @@ class TestFlashKernel:
             assert pad.any()
             for g in got:
                 assert not np.asarray(g, np.float32)[pad].any()
+
+    @pytest.mark.parametrize("with_lse", [False, True],
+                             ids=["primal", "statistics"])
+    @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                           ("bfloat16", 1e-2)])
+    @pytest.mark.parametrize("rows", [256, 1024])
+    @pytest.mark.parametrize("t,causal,seg", _KERNEL_CASES)
+    def test_forward_kernel_matches_rounded_forward(self, t, causal, seg,
+                                                    dtype, tol, with_lse,
+                                                    rows, monkeypatch):
+        """The forward kernel, alone and as the forward under
+        differentiation (which also writes the rows' log-sum-exp),
+        against the same mathematics in jnp, rounding where the kernel
+        rounds: 1e-5 of the largest output in float32, an ulp's worth in
+        bfloat16; the statistics to 1e-5 in both (the scores of bfloat16
+        operands are the float32 scores of the same numbers). Fully
+        masked rows read zero. ``rows``: the most a tile takes: 1,024, the
+        rule's own (one to four tiles at these lengths), and 256, so that
+        every length has tiles the causal mask skips (their K and V
+        blocks never fetched) and tiles it cuts."""
+        from paddle_tpu.ops import pallas_attention as pa
+        monkeypatch.setattr(pa, "_FWD_BLOCK", rows)
+        bh, d = 2, 32
+        rs = np.random.RandomState(t + causal)
+        q, k, v = (jnp.asarray(rs.randn(bh, t, d), dtype) for _ in range(3))
+        ids = _segments(seg, t, bh)
+        before = kernel_path.counts().get("flash_attention", {})
+        if with_lse:
+            got, res = pa._flash_fwd(q, k, v, ids, causal, None, STRICT)
+            lse = res[4]
+        else:
+            got, lse = pa._primal(q, k, v, ids, causal, None, STRICT), None
+        after = kernel_path.counts()["flash_attention"]
+        assert after.get("interpret", 0) == before.get("interpret", 0) + 1
+        assert after.get("xla", 0) == before.get("xla", 0)
+        want, want_lse = _rounded_forward(q, k, v, causal, ids)
+        assert got.dtype == q.dtype
+        a, b = (np.asarray(x, np.float32) for x in (got, want))
+        assert np.isfinite(a).all()
+        assert np.abs(a - b).max() <= tol * np.abs(b).max()
+        live = np.ones((bh, t), bool) if ids is None else np.asarray(ids) != 0
+        if ids is not None:
+            assert not live.all() and not a[~live].any()
+        if with_lse:
+            assert lse.shape == (bh, 1, t) and lse.dtype == jnp.float32
+            a, b = np.asarray(lse)[:, 0], np.asarray(want_lse)
+            assert np.abs(a - b)[live].max() <= 1e-5 * np.abs(b[live]).max()
+            assert (a[~live] < -1e29).all()
+
+    @pytest.mark.parametrize("with_lse", [False, True],
+                             ids=["primal", "statistics"])
+    def test_bfloat16_operands_reach_the_products_as_they_are(self,
+                                                              with_lse):
+        """The kernel of a bfloat16 call: no ``[bk, d]`` tile is converted
+        to float32, and both products take bfloat16 operands and give
+        float32."""
+        from paddle_tpu.ops import pallas_attention as pa
+        bq, bk, d = 256, 512, 128
+        x = jax.ShapeDtypeStruct((2, 1024, d), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(lambda *a: pa._forward(
+            *a, None, True, bq, bk, True, with_lse=with_lse))(x, x, x)
+
+        def eqns(jaxpr):
+            for e in jaxpr.eqns:
+                yield e
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    yield from eqns(sub)
+
+        kernel = [e for e in eqns(jaxpr.jaxpr)
+                  if e.primitive.name == "pallas_call"]
+        assert len(kernel) == 1
+        body = list(eqns(kernel[0].params["jaxpr"]))
+        upcasts = [e for e in body
+                   if e.primitive.name == "convert_element_type"
+                   and e.params["new_dtype"] == jnp.float32
+                   and e.invars[0].aval.shape in ((bk, d), (bq, d))
+                   and e.invars[0].aval.dtype == jnp.bfloat16]
+        assert not upcasts, upcasts
+        dots = [e for e in body if e.primitive.name == "dot_general"]
+        assert len(dots) == 2
+        for e in dots:
+            assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+            assert e.outvars[0].aval.dtype == jnp.float32
+        assert sorted(e.outvars[0].aval.shape for e in dots) == \
+            [(bq, d), (bq, bk)]
+
+    @pytest.mark.parametrize("t,bq,bk", [(2048, 256, 512), (2048, 512, 512),
+                                         (2048, 512, 1024), (1280, 256, 320),
+                                         (768, 384, 384), (1024, 128, 512)])
+    def test_dead_causal_tiles_stay_on_the_last_live_k_block(self, t, bq,
+                                                             bk):
+        """The K and V index map: a live tile names its own k block; a
+        tile wholly above the diagonal, which ``_live`` skips, names the
+        q block's last live k block (in VMEM already: nothing is
+        fetched); without a causal mask every tile names its own."""
+        from paddle_tpu.ops import pallas_attention as pa
+        dead = 0
+        for i in range(t // bq):
+            live = [j for j in range(t // bk)
+                    if pa._live(i, j, bq, bk, True)]
+            assert live == list(range(len(live)))     # a prefix, never empty
+            for j in range(t // bk):
+                got = int(pa._k_block(i, j, bq, bk, True))
+                assert got == (j if j in live else live[-1])
+                dead += j not in live
+                assert pa._k_block(i, j, bq, bk, False) == j
+        assert dead     # every case has tiles to skip
 
     def test_ragged_length_falls_back_to_reference(self):
         q, k, v = self._data(t=100)  # 100 % 512 != 0
@@ -225,6 +375,31 @@ class TestBlockSelection:
         want = ref(q[0], q[0], q[0], True).reshape(out.shape)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    **TOL)
+
+    @pytest.mark.parametrize("shape,dtype,kw,want", [
+        ((64, 2048, 128), "bfloat16", {}, (1024, 1024)),   # the training cells
+        ((64, 2048, 128), "float32", {}, (1024, 1024)),
+        ((64, 2048, 64), "bfloat16", {}, (1024, 1024)),
+        ((8, 4096, 256), "float32", {}, (512, 512)),       # half the rows
+        ((8, 4096, 256), "bfloat16", {}, (1024, 1024)),
+        ((8, 2048, 512), "float32", {}, (256, 256)),
+        ((2, 1280, 128), "float32", {}, (640, 640)),       # divisors
+        ((2, 1280, 128), "float32", dict(segmented=True), (640, 640)),
+        ((2, 768, 32), "float32", {}, (768, 768)),
+        ((2, 288, 32), "float32", {}, (288, 288)),
+        ((2, 288, 32), "float32", dict(lanes=True), None),
+        ((2, 2048, 128), "bfloat16", dict(block_q=256), (256, 1024)),
+        ((2, 100, 32), "float32", {}, None),               # ragged
+    ])
+    def test_forward_blocks_follow_rows_and_tile_bytes(self, shape, dtype,
+                                                       kw, want):
+        """``_tiles``: the largest aligned divisors of T within 1,024 rows
+        and 512 KiB an operand tile; a caller's ``block_q`` in place of
+        the q rows; None for a ragged length."""
+        from paddle_tpu.ops import pallas_attention as pa
+        x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+        kw = dict(dict(block_q=None, segmented=False), **kw)
+        assert pa._tiles(x, x, **kw) == want
 
     def test_chunked_backward_matches_dense_grads(self):
         """The tiled backward kernel == dense reference grads (T=768:

@@ -100,6 +100,47 @@ def test_flash_attention_compiles(chip, fn, extra, name):
     assert _has_kernel(_compile(chip, fn, _QKV, _QKV, _QKV, *extra), name)
 
 
+# the benchmark's training cells: [64, 2048, 128] bf16 a chip
+_TRAIN_QKV = ((4, 16, 2048, 128), BF16)
+
+
+@pytest.mark.parametrize("qkv", [
+    _TRAIN_QKV,                          # the blocks _tiles picks for T 2,048
+    ((8, 8, 1024, 64), BF16),            # two heads a lane tile
+    ((2, 4, 1280, 128), F32),            # divisor blocks, float32 operands
+], ids=["training_cell", "head_dim_64", "f32_divisor_blocks"])
+@pytest.mark.parametrize("fn,seg,name", [
+    (_flash, False, "flash_attention_fwd"),
+    (_flash_seg, True, "flash_attention_fwd_seg"),
+    (_flash_grad, False, "jvp_flash_attention_fwd_"),
+], ids=["causal", "causal_segment_ids", "custom_vjp_forward"])
+def test_flash_attention_forward_compiles_at(chip, fn, seg, name, qkv):
+    """The forward kernel, alone, segmented and under differentiation (the
+    row statistics written), at the other geometries its callers have."""
+    (b, _, t, _), _ = qkv
+    extra = (((b, t), I32),) if seg else ()
+    # (a function of its own: a trace of ``fn`` that jit kept would leave
+    # the kernel-path counters of a later test of this shape unmoved)
+    assert _has_kernel(_compile(chip, lambda *a: fn(*a), qkv, qkv, qkv,
+                                *extra), name)
+
+
+@pytest.mark.parametrize("fn", [_flash, _flash_grad],
+                         ids=["causal", "custom_vjp_forward"])
+def test_flash_attention_forward_keeps_its_operands_bfloat16(chip, fn):
+    """At the training cells' shape the compiled program around the
+    forward kernel holds no float32 copy of q, k or v and no float32
+    [.., T] array but the rows' statistics [BH, 1, T]: bfloat16 operands
+    reach the kernel as they are."""
+    hlo = _compile(chip, lambda *a: fn(*a), _TRAIN_QKV, _TRAIN_QKV,
+                   _TRAIN_QKV)
+    assert _has_kernel(hlo, "flash_attention_fwd") or \
+        _has_kernel(hlo, "jvp_flash_attention_fwd_")
+    assert not re.search(r"f32\[\d+,(?!1,)\d+,2048\]", hlo)
+    if fn is _flash:
+        assert not re.search(r"f32\[(64|4,16),2048,128\]", hlo)
+
+
 def _bwd_paths(since=None):
     """Traced ``flash_attention_bwd`` sites by path, less those of
     ``since`` (an earlier reading)."""
@@ -109,8 +150,7 @@ def _bwd_paths(since=None):
 
 
 @pytest.mark.parametrize("qkv,extra,name", [
-    # the benchmark's training cells: [64, 2048, 128] bf16 a chip
-    (((4, 16, 2048, 128), BF16), (), "flash_attention_bwd_"),
+    (_TRAIN_QKV, (), "flash_attention_bwd_"),
     (_QKV, (((8, 1024), I32),), "flash_attention_bwd_seg_"),
     # two heads a lane tile: the blocks are whole in their last dim
     (((8, 8, 1024, 64), BF16), (), "flash_attention_bwd_"),

@@ -6,7 +6,7 @@ bench family.
 Usage (on the TPU chip):
   python tools/transformer_mfu_probe.py --mode step [--batch 8 --seqlen 1024]
   python tools/transformer_mfu_probe.py --mode kernel   # fwd and bwd alone
-  python tools/transformer_mfu_probe.py --mode sweep    # fwd block sizes
+  python tools/transformer_mfu_probe.py --mode sweep    # fwd block pairs
 """
 
 import argparse
@@ -95,6 +95,16 @@ def bench_step(batch, seqlen, d=2048, L=12, H=16, vocab=32768,
         return out
 
 
+# (block_q, block_k) of the forward kernel that --mode sweep times; --mode
+# kernel times the pair ``pa._tiles`` picks, which is one of them (my chip
+# runs, PR 41: CHANGES.md has the table)
+_BLOCK_PAIRS = [(256, 512), (512, 512), (256, 1024), (512, 1024),
+                (1024, 512), (1024, 1024)]
+# MXU products a (q-block, k-block) tile: Q K^T and P V forward; the
+# scores, dP, dV, dK and dQ backward
+_PRODUCTS = {"flash_attention_fwd": 2, "flash_attention_bwd": 5}
+
+
 def _live_share(t, bq, bk, causal):
     """Share of the (q-block, k-block) tiles a causal mask leaves."""
     if not causal:
@@ -115,16 +125,18 @@ def _time(fn, *args, n_iter):
     return (time.perf_counter() - t0) / n_iter * 1e3
 
 
-def bench_kernel(block_q, block_k, b=4, h=16, t=2048, dd=128,
+def bench_kernel(block_q=None, block_k=None, b=4, h=16, t=2048, dd=128,
                  causal=True, n_iter=20, bwd=True):
     """The flash kernels alone at the training cells' attention shape
     ([64, 2048, 128] bf16 a chip): the forward under differentiation
-    (``pa._forward`` on blocks of ``block_q`` x ``block_k``, row statistics
-    kept) and, with ``bwd``, the backward kernel (``pa._backward``, its own
-    blocks) on that forward's output. Each is a jitted call on device
+    (``pa._forward`` on blocks of ``block_q`` x ``block_k``, by default those
+    ``pa._tiles`` picks; row statistics kept) and, with ``bwd``, the
+    backward kernel (``pa._backward``, its own blocks) on that forward's
+    output. Each is a jitted call on device
     arrays, timed over ``n_iter`` calls. ``peak_share`` counts every
-    tile's products (2 forward, 5 backward, each 2*T*T*D a batch-head),
-    ``peak_share_live`` only the tiles the causal mask leaves."""
+    tile's products (``products_a_tile``: 2 forward, 5 backward, each
+    2*T*T*D a batch-head), ``peak_share_live`` only the tiles the causal
+    mask leaves."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import pallas_attention as pa
@@ -134,34 +146,33 @@ def bench_kernel(block_q, block_k, b=4, h=16, t=2048, dd=128,
     rs = np.random.RandomState(0)
     q, k, v, do = (jnp.asarray(rs.randn(b * h, t, dd), jnp.bfloat16)
                    for _ in range(4))
+    if block_q is None:
+        block_q, block_k = pa._tiles(q, k, None, False, lanes=True)
     product = 2.0 * t * t * dd * b * h
     out = {"shape": [b * h, t, dd], "causal": causal,
            "device": dev.device_kind}
+
+    def row(kernel, bq, bk, ms):
+        n = _PRODUCTS[kernel]
+        share = n * product / ms / 1e-3 / peak
+        return dict(out, kernel=kernel, block_q=bq, block_k=bk,
+                    ms=round(ms, 3), products_a_tile=n,
+                    peak_share=round(share, 4),
+                    peak_share_live=round(
+                        share * _live_share(t, bq, bk, causal), 4))
     try:
         fwd = jax.jit(lambda *a: pa._forward(
             *a, None, causal, block_q, block_k, False, with_lse=True))
-        ms = _time(fwd, q, k, v, n_iter=n_iter)
-        rows = [dict(out, kernel="flash_attention_fwd", block_q=block_q,
-                     block_k=block_k, ms=round(ms, 3),
-                     peak_share=round(2 * product / ms / 1e-3 / peak, 4),
-                     peak_share_live=round(
-                         2 * product * _live_share(t, block_q, block_k,
-                                                   causal)
-                         / ms / 1e-3 / peak, 4))]
+        rows = [row("flash_attention_fwd", block_q, block_k,
+                    _time(fwd, q, k, v, n_iter=n_iter))]
         if bwd:
             o, lse = fwd(q, k, v)
             ms = _time(jax.jit(lambda *a: pa._backward(
                 *a[:5], None, a[5], causal, False)), q, k, v, o, lse, do,
                 n_iter=n_iter)
-            bq = pa._block_size(t, pa._BWD_BLOCK, 128)
-            bk = pa._block_size(t, pa._BWD_BLOCK)
-            rows.append(dict(
-                out, kernel="flash_attention_bwd", block_q=bq, block_k=bk,
-                ms=round(ms, 3),
-                peak_share=round(5 * product / ms / 1e-3 / peak, 4),
-                peak_share_live=round(
-                    5 * product * _live_share(t, bq, bk, causal)
-                    / ms / 1e-3 / peak, 4)))
+            rows.append(row("flash_attention_bwd",
+                            pa._block_size(t, pa._BWD_BLOCK, 128),
+                            pa._block_size(t, pa._BWD_BLOCK), ms))
         return rows
     except Exception as e:
         return [dict(out, block_q=block_q, block_k=block_k,
@@ -186,13 +197,12 @@ def main():
                      (12, 1024)]:
             print(json.dumps(bench_step(b, t)), flush=True)
     elif args.mode == "kernel":
-        for row in bench_kernel(256, 512):
+        for row in bench_kernel():
             print(json.dumps(row), flush=True)
     elif args.mode == "sweep":
-        for bq in (256, 512, 1024):
-            for bk in (256, 512, 1024):
-                print(json.dumps(bench_kernel(bq, bk, bwd=False)[0]),
-                      flush=True)
+        for bq, bk in _BLOCK_PAIRS:
+            print(json.dumps(bench_kernel(bq, bk, bwd=False)[0]),
+                  flush=True)
 
 
 if __name__ == "__main__":
