@@ -252,6 +252,12 @@ AMP_WHITE = frozenset({
     # carries stay bf16 end-to-end (cast once at the boundary)
     "dynamic_lstm", "dynamic_gru", "attention_gru_decoder",
     "sequence_conv",
+    # the bias-free projection of models/moe_lm.py: exact on a float32
+    # input, one pass on the bfloat16 one it gets here; and the EVA
+    # attention ops (ops/eva_ops.py), whose scores and softmaxes stay
+    # float32 inside
+    "linear", "eva_summaries", "eva_attention",
+    "eva_attention_decode_paged",
 })
 AMP_BLACK = frozenset({
     "cross_entropy", "softmax_with_cross_entropy",

@@ -57,15 +57,21 @@ class UniformInitializer(Initializer):
 
 
 class NormalInitializer(Initializer):
-    def __init__(self, loc=0.0, scale=1.0, seed=0):
+    """``clip`` (in deviations; absent: none) holds the draws within
+    ``loc +- clip * scale``."""
+
+    def __init__(self, loc=0.0, scale=1.0, seed=0, clip=None):
         self.loc, self.scale, self.seed = loc, scale, seed
+        self.clip = clip
 
     def __call__(self, var, block):
+        attrs = {"shape": list(var.shape), "dtype": var.dtype,
+                 "mean": float(self.loc), "std": float(self.scale),
+                 "seed": self.seed}
+        if self.clip:
+            attrs["clip"] = float(self.clip)
         block.append_op("gaussian_random", outputs={"Out": [var.name]},
-                        attrs={"shape": list(var.shape), "dtype": var.dtype,
-                               "mean": float(self.loc),
-                               "std": float(self.scale), "seed": self.seed},
-                        infer_shape=False)
+                        attrs=attrs, infer_shape=False)
 
 
 class XavierInitializer(Initializer):

@@ -8,24 +8,27 @@ from . import nn as _nn
 from . import ops as _ops
 
 __all__ = ["rms_norm", "rotary_embedding", "linear", "swiglu", "moe_ffn",
-           "mla_attention"]
+           "mla_attention", "eva_attention"]
 
 
 def rms_norm(x, epsilon=1e-5, group_size=0, param_attr=None, name=None,
-             **kwargs):
+             offset=0.0, **kwargs):
     """RMSNorm over the last axis of ``x``, or with ``group_size`` over
     each group of that many lanes (one weight vector of ``group_size``
     shared by all groups: a per-head norm of a [.., H*D] projection).
-    Float32 out, float32 weight."""
+    Float32 out, float32 weight. With ``offset`` the gain is ``offset +
+    w`` and ``w`` starts at ``1 - offset``: a unit offset over zeros."""
     helper = LayerHelper("rms_norm", name=name, **kwargs)
     w = helper.create_parameter(
         param_attr, shape=[group_size or x.shape[-1]], dtype="float32",
-        default_initializer=ConstantInitializer(1.0))
+        default_initializer=ConstantInitializer(1.0 - offset))
     out = helper.create_tmp_variable("float32")
+    attrs = {"epsilon": epsilon, "group_size": group_size}
+    if offset:
+        attrs["offset"] = offset
     helper.append_op(type="rms_norm",
                      inputs={"X": [x.name], "Scale": [w.name]},
-                     outputs={"Y": [out.name]},
-                     attrs={"epsilon": epsilon, "group_size": group_size})
+                     outputs={"Y": [out.name]}, attrs=attrs)
     return out
 
 
@@ -162,5 +165,80 @@ def mla_attention(q, c, k_rope, num_heads, nope_dim, rope_dim, v_dim, scale,
             type="mla_attention_decode_paged",
             inputs={"Q": [q.name], "Cache": [cache.name], "Pos": [pos.name],
                     "Table": [table.name], "WUKV": [w.name]},
+            outputs={"Out": [out.name]}, attrs=attrs)
+    return out
+
+
+def eva_attention(q, k, v, num_heads, window, chunk, prefix, dtype=None,
+                  caches=None, tables=None, pos=None, hist=None, length=None,
+                  **kwargs):
+    """EVA attention (ops/eva_ops.py) of rotated queries and keys q, k and
+    values v [B, T, H*D]: a row attends its own aligned ``window`` exactly
+    and one summary for every ``chunk`` positions of the windows before
+    it, pooled with the learned ``<prefix>.mu`` and ``<prefix>.phi`` [H*D]
+    (held in ``dtype``; Normal(0, 1) held within a deviation, times
+    ``D^-1/2``). Over the sequence's own rows; or with ``caches`` ((k, v)
+    of the window pools, (k, v) of the chunk pools) and ``tables`` (the
+    two kinds' table feeds), a prefill (``hist``, ``length``: the rows and
+    the whole chunks' summaries are also written through the tables) or,
+    with ``pos``, a decode step: the row appended, its block's summary
+    written where the block is full, one query a slot over both pools.
+    -> [B, T, H*D] float32."""
+    helper = LayerHelper("eva_attention", **kwargs)
+    dm = q.shape[-1]
+    init = NormalInitializer(0.0, (dm // num_heads) ** -0.5, clip=1.0)
+    mu, phi = (helper.create_parameter(
+        "%s.%s" % (prefix, name), shape=[dm], dtype=dtype or q.dtype,
+        default_initializer=init) for name in ("mu", "phi"))
+    attrs = {"num_heads": num_heads, "window": window, "chunk": chunk}
+    learned = {"Mu": [mu.name], "Phi": [phi.name]}
+    out = helper.create_tmp_variable("float32")
+    decode = pos is not None
+
+    def summaries(rows, dtype):
+        kbar, vbar = (helper.create_tmp_variable(dtype) for _ in "kv")
+        helper.append_op(type="eva_summaries", inputs=dict(learned, **rows),
+                         outputs={"KBar": [kbar.name], "VBar": [vbar.name]},
+                         attrs={"num_heads": num_heads, "chunk": chunk})
+        return kbar, vbar
+
+    def write(pool, new, table, per_chunk):
+        where = {"Pos": [pos.name]} if decode else \
+            {"Hist": [hist.name], "Len": [length.name]}
+        helper.append_op(
+            type="kv_cache_append_paged" if decode
+            else "kv_cache_write_paged",
+            inputs=dict(where, Cache=[pool.name], New=[new.name],
+                        Table=[table.name]),
+            outputs={"Out": [pool.name]},
+            attrs={"chunk": chunk} if per_chunk else {})
+
+    if caches is not None:
+        (ck, cv), (sk, sv) = caches
+        wtab, ctab = tables
+        write(ck, k, wtab, False)
+        write(cv, v, wtab, False)
+    # a decode step pools the block its row just went to, a sequence its
+    # own rows
+    kbar, vbar = summaries(
+        {"CacheK": [ck.name], "CacheV": [cv.name], "Pos": [pos.name],
+         "Table": [wtab.name]}, ck.dtype) if decode else \
+        summaries({"K": [k.name], "V": [v.name]}, k.dtype)
+    if caches is not None:
+        write(sk, kbar, ctab, True)
+        write(sv, vbar, ctab, True)
+    if decode:
+        helper.append_op(
+            type="eva_attention_decode_paged",
+            inputs={"Q": [q.name], "CacheK": [ck.name], "CacheV": [cv.name],
+                    "ChunkK": [sk.name], "ChunkV": [sv.name],
+                    "Pos": [pos.name], "Table": [wtab.name],
+                    "ChunkTable": [ctab.name]},
+            outputs={"Out": [out.name]}, attrs=attrs)
+    else:
+        helper.append_op(
+            type="eva_attention",
+            inputs={"Q": [q.name], "K": [k.name], "V": [v.name],
+                    "KBar": [kbar.name], "VBar": [vbar.name]},
             outputs={"Out": [out.name]}, attrs=attrs)
     return out

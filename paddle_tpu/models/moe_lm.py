@@ -15,7 +15,15 @@ mixer that is layer by layer a Mamba-2 state-space mixer (``"mamba"``,
 * ``latent``: MLA (``ops/mla_ops.py``) with low-rank queries, YaRN rotary
   positions on the rope lanes, RMSNorm before each half only, no gate and
   no embedding scale (the ``kimi_k2`` / DeepSeek-V3 family;
-  ``benchmarks/reference/kimi_k2.py``).
+  ``benchmarks/reference/kimi_k2.py``); or
+* ``eva``: EVA attention (``ops/eva_ops.py``): every head its own KV head,
+  rotary positions in every layer, an exact aligned window and one learned
+  summary for every chunk of the windows before it, in a dense block with
+  RMSNorm before each half only, unit-offset norms (``norm_offset``) and a
+  head of ``pred_heads`` x ``vocab_size`` columns of which the first
+  ``vocab_size`` choose the next token (the ``evabyte`` family;
+  ``benchmarks/reference/evabyte.py``). Served in the operands it is held
+  in: under the ``amp`` flag its products take one pass.
 
 The layer's own shape is data too. Without ``block`` a layer is one mixer
 and then one feed-forward, each added to the residual stream where it was
@@ -39,7 +47,11 @@ model with state-space layers has beside its paged kind a **state kind**:
 a pool of one fixed-size float32 row a slot in each such layer (the scan's
 state, the convolution's last inputs and the tokens absorbed), rewritten
 whole every step; its prefill starts from a zero state, so it takes
-neither a shared prefix nor speculation.
+neither a shared prefix nor speculation. EVA attention has two kinds a
+layer: an **aligned window** kind (the rows of the sequence's current
+window, all freed at the window's edge) and a **chunk kind** (one row for
+every ``chunk`` positions: a chunk's summary, made from the window kind's
+block when its last row is written).
 Matmul weights, the embedding and the head are created and
 held in ``param_dtype``; norms, router and expert bias are float32, and so
 is every activation: the products are exact (ops/moe_ops.py says why), so
@@ -76,13 +88,14 @@ class MoeLM:
                  scoring="sigmoid", qk_norm=True, attn_gate=True,
                  attn_scale=None, residual_scale=None, logit_scale=None,
                  tie_embeddings=False, mamba=None, shared_d_ff=None,
-                 block=None, zero_experts=0):
+                 block=None, zero_experts=0, eva=None, norm_offset=0.0,
+                 pred_heads=1):
         unknown = set(layer_types) - {SLIDING, FULL, MAMBA}
         if unknown:
             raise ValueError("layer_types holds %s: a layer is %r, %r or %r"
                              % (sorted(unknown), SLIDING, FULL, MAMBA))
-        if attention not in ("gqa", "latent"):
-            raise ValueError("attention is 'gqa' or 'latent', not %r"
+        if attention not in ("gqa", "latent", "eva"):
+            raise ValueError("attention is 'gqa', 'latent' or 'eva', not %r"
                              % (attention,))
         if attention == "gqa" and num_heads % num_kv_heads:
             raise ValueError("%d query heads on %d KV heads"
@@ -109,6 +122,7 @@ class MoeLM:
         self.residual_scale, self.logit_scale = residual_scale, logit_scale
         self.tie_embeddings = tie_embeddings
         self.zero_experts = zero_experts
+        self.norm_offset, self.pred_heads = norm_offset, pred_heads
         self.block = dict(block) if block else None
         if block and (attention != "latent" or num_dense_layers
                       or not 0 <= block["experts_read"]
@@ -122,6 +136,9 @@ class MoeLM:
                                       - num_dense_layers)
         if attention == "latent":
             self._latent_sizes(**latent)
+            return
+        if attention == "eva":
+            self._eva_sizes(**eva)
             return
         # the kinds of layer cache, full first where the model has both;
         # per layer the width of a cached row and its kind
@@ -141,8 +158,6 @@ class MoeLM:
             state_row = ((mamba["num_heads"], mamba["head_dim"],
                           mamba["state_dim"]), (mamba["conv_width"], lanes))
             self.kinds += (("state", None),)
-            # a prompt's rows start from a zero state
-            self.prefill_sees_history = False
         self.cache_layers = [
             (state_row, len(present)) if t == MAMBA else
             (num_kv_heads * head_dim, present.index(t))
@@ -173,14 +188,27 @@ class MoeLM:
         self.cache_pools = ("c",)
         self.cache_layers = [(self.row_width, 0)] * (
             len(self.layer_types) * (self.block or {}).get("halves", 1))
-        # a prompt's rows attend the prompt's own latents: nothing cached
-        # before them is seen
-        self.prefill_sees_history = False
+
+    def _eva_sizes(self, window, chunk):
+        """EVA attention's two kinds of layer cache, the window's first: a
+        layer has a site in each (``cache_layers`` 2i and 2i + 1), rows as
+        wide as its keys."""
+        if set(self.layer_types) != {FULL} or self.nh != self.nkv or \
+                window % chunk:
+            raise ValueError("EVA attention is every layer's, gives every "
+                             "head a KV head of its own and has windows of "
+                             "whole chunks")
+        self.eva = dict(window=window, chunk=chunk)
+        self.kinds = (("window", window, dict(aligned=True)),
+                      ("chunk", None, dict(chunk=chunk)))
+        self.cache_layers = [(self.nkv * self.hd, k) for _ in
+                             self.layer_types for k in (0, 1)]
 
     # -- the block ---------------------------------------------------------
     def _norm(self, x, name, group_size=0):
         return layers.rms_norm(x, epsilon=self.eps, group_size=group_size,
-                               param_attr="moe_lm.%s.w" % name)
+                               param_attr="moe_lm.%s.w" % name,
+                               offset=self.norm_offset)
 
     def _linear(self, x, size, name):
         return layers.linear(x, size, "moe_lm.%s.w" % name, self.dtype,
@@ -240,6 +268,28 @@ class MoeLM:
                     table=ctx["table"], **attend)
         return layers.mla_attention(q, c, k_r, block_rows=512, **attend)
 
+    def _eva_attention(self, a, p, i, ctx):
+        """a [B, T, d] -> EVA attention's output [B, T, H*D]: over the
+        rows' own window and summaries (whole sequences, a prefill, which
+        also writes both pools) or over the layer's two paged pools (a
+        decode step)."""
+        rope = dict(self._positions(ctx), head_dim=self.hd, theta=self.theta)
+        q, k, v = (self._linear(a, self.nh * self.hd, p + part)
+                   for part in "qkv")
+        where = {}
+        if ctx is not None:
+            where = dict(caches=ctx["caches"][2 * i:2 * i + 2],
+                         tables=ctx["tables"])
+            if ctx["mode"] == "decode":
+                where["pos"] = ctx["pos"]
+            else:
+                where.update(hist=ctx["hist"], length=ctx["key_length"])
+        return layers.eva_attention(
+            layers.rotary_embedding(q, **rope),
+            layers.rotary_embedding(k, **rope), v, self.nh,
+            prefix="moe_lm." + p[:-1], dtype=self.dtype,
+            **dict(self.eva, **where))
+
     def _mixer(self, a, i, ctx):
         """a [B, T, d] -> the Mamba-2 mixer's output [B, T, d]: whole
         sequences, a prefill into the slot's state row, or a decode step
@@ -262,6 +312,8 @@ class MoeLM:
         p = "l%d.attn." % i
         if self.attention == "latent":
             return self._latent_attention(a, p, i, ctx)
+        if self.attention == "eva":
+            return self._eva_attention(a, p, i, ctx)
         windowed = self.layer_types[i] == SLIDING
         q = self._linear(a, self.nh * self.hd, p + "q")
         k = self._linear(a, self.nkv * self.hd, p + "k")
@@ -412,10 +464,19 @@ class MoeLM:
             logits = layers.linear(h, self.vocab_size, "moe_lm.embed.w",
                                    self.dtype, self.std, transpose_w=True)
         else:
-            logits = self._linear(h, self.vocab_size, "lm_head")
+            logits = self._linear(h, self.vocab_size * self.pred_heads,
+                                  "lm_head")
         if self.logit_scale is not None:
             logits = layers.scale(logits, self.logit_scale)
         return logits
+
+    def _next_token_row(self, logits, rows):
+        """[.., pred_heads * V] -> [rows, V]: the head that predicts the
+        next token, the first of however many the model has."""
+        if self.pred_heads > 1:
+            logits = layers.slice(logits, [len(logits.shape) - 1], [0],
+                                  [self.vocab_size])
+        return layers.reshape(logits, [rows, self.vocab_size])
 
     # -- what lm_session calls ----------------------------------------------
     def logits(self, tokens, cache_ctx=None):
@@ -426,12 +487,11 @@ class MoeLM:
         # the head over every row of the bucket would be P x V logits
         h, _ = self.hidden(tokens, cache_ctx)
         at = layers.gather(layers.transpose(h, [1, 0, 2]), last_pos)
-        return layers.reshape(self._head(at), [1, self.vocab_size])
+        return self._next_token_row(self._head(at), 1)
 
     def decode_row(self, tokens, cache_ctx):
         h, counts = self.hidden(tokens, cache_ctx)
-        row = layers.reshape(self._head(h),
-                             [tokens.shape[0], self.vocab_size])
+        row = self._next_token_row(self._head(h), tokens.shape[0])
         return row, (layers.stack(counts, axis=0) if counts else None)
 
     def draft(self, overrides):
@@ -447,20 +507,21 @@ def moe_lm(tokens, labels, **sizes):
     logits = layers.cast(model.logits(tokens), "float32")
     t = tokens.shape[1]
     tok_loss = layers.softmax_with_cross_entropy(
-        layers.reshape(logits, [-1, model.vocab_size]),
+        model._next_token_row(logits, -1),
         layers.reshape(labels, [-1, 1]))
     return layers.mean(layers.reshape(tok_loss, [-1, t])), logits
 
 
 def moe_lm_session(slots, cache_len, prompt_buckets, block_size, num_blocks,
                    window_num_blocks=None, kv_dtype="float32", bos_id=0,
-                   eos_id=1, cache_ns=None, **sizes):
+                   eos_id=1, cache_ns=None, chunk_num_blocks=None, **sizes):
     """The paged prefill and decode programs of :func:`moe_lm` (a
     ``GenerationSpec``): ``num_blocks`` sizes the first kind of layer
     cache (the full layers', where the model has any), and
     ``window_num_blocks`` the window layers' where it has both; a state
-    kind has one row a slot. Greedy; positions are rotary or none, so a
-    sequence is bounded by ``cache_len`` alone."""
+    kind has one row a slot; ``chunk_num_blocks`` sizes a chunk kind, whose
+    block holds ``block_size`` summaries. Greedy; positions are rotary or
+    none, so a sequence is bounded by ``cache_len`` alone."""
     model = MoeLM(**sizes)
     return lm_session(
         model, max_len=cache_len, slots=slots, cache_len=cache_len,
@@ -468,5 +529,6 @@ def moe_lm_session(slots, cache_len, prompt_buckets, block_size, num_blocks,
         cache_ns=cache_ns, dtype=kv_dtype,
         block_size=block_size, num_blocks=num_blocks, prefix_cache=False,
         decode_policy=None,
-        kind_blocks={"window": window_num_blocks, "state": slots}
+        kind_blocks={"window": window_num_blocks, "state": slots,
+                     "chunk": chunk_num_blocks}
         if len(model.kinds) > 1 else None)

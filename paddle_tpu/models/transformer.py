@@ -302,15 +302,18 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     block: :func:`transformer_lm_session` (whose docstring describes the
     programs and every argument) with the model behind an object.
     ``model`` offers ``vocab_size``; ``kinds``, its kinds of layer cache
-    as (name, window) pairs, the first the one ``num_blocks`` sizes;
+    as (name, window) pairs, the first the one ``num_blocks`` sizes (a
+    third entry, a dict, holds what else ``paged_cache.CacheKind`` takes:
+    ``aligned`` for a window that is freed whole at its edge, ``chunk``
+    for a kind whose row stands for that many positions);
     ``cache_layers``, per layer cache (one a layer, or one an attention
     site where a layer has several: the model reads
     ``cache_ctx["caches"]`` by the same index) the width of a cached row
     and the index of its kind; optionally ``cache_pools``, the pools a
     layer has (default ``("k", "v")``; a latent kind has one, ``("c",)``, whose row is key and
-    value at once) and ``prefill_sees_history`` (False: a prefill attends
-    its window's own rows only, so neither a shared prefix nor a
-    speculative verify can be built on it);
+    value at once); whether a shared prefix or a speculative verify can
+    be built on its prefill goes by its kinds' names
+    (``paged_cache.refuse_sharing``);
     ``logits(tokens, cache_ctx)`` -> [B, T, V];
     ``prefill_row(tokens, last_pos, cache_ctx)`` -> [1, V];
     ``decode_row(tokens, cache_ctx)`` -> ([slots, V], expert counts or
@@ -338,7 +341,9 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         decode_policy = DecodePolicy.from_flags()
     policy = decode_policy
     vocab_size = model.vocab_size
-    kinds = tuple(model.kinds)
+    # (name, window, what else the kind's CacheKind takes)
+    kinds = tuple((k[0], k[1], dict(k[2]) if len(k) > 2 else {})
+                  for k in model.kinds)
     sampled = policy is not None and policy.sampled
     constraint = None if policy is None else policy.constraint
     spec_k = 0 if policy is None else policy.speculate_k
@@ -388,21 +393,11 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     num_blocks = int(num_blocks) or slots * max_blocks
     if prefix_cache is None:
         prefix_cache = bool(_config.get_flag("generation_prefix_cache"))
-    state_kind = any(name == "state" for name, _ in kinds)
-    if state_kind and (spec_k or prefix_cache):
-        raise ValueError(
-            "this model has a state kind of layer cache: a slot's state is "
-            "one row rewritten whole every step, which no prefix can share "
-            "(nothing keeps it as it was at a block's edge) and no "
-            "rejected draft can be rolled back from; it takes neither "
-            "prefix_cache nor speculate_k")
-    if not getattr(model, "prefill_sees_history", True) and (
-            spec_k or prefix_cache):
-        raise ValueError(
-            "this model's prefill attends the window's own rows, not the "
-            "cache: it takes neither prefix_cache nor speculate_k")
+    if spec_k or prefix_cache:
+        from ..serving.paged_cache import refuse_sharing
+        refuse_sharing([name for name, _, _ in kinds])
     rows = [num_blocks] + [int((kind_blocks or {})[name])
-                           for name, _ in kinds[1:]]
+                           for name, _, _ in kinds[1:]]
 
     def layer_pools(width, k):
         """(pool, shape, dtype) of a layer's cache variables."""
@@ -432,7 +427,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         sequence's table is ``max_blocks`` wide, a state kind's one."""
         return [layers.data("%s.%s" % (prefix, name), shape=lead + [
             1 if name == "state" else max_blocks], dtype="int32",
-            append_batch_size=False) for name, _ in kinds[1:]]
+            append_batch_size=False) for name, _, _ in kinds[1:]]
 
     def _policy_epilogue(row, seed=None, step=None, mask=None):
         """row [n, V] -> next token [n] under the resolved policy.
@@ -613,8 +608,8 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
             CacheKind(name, window, rows[k],
                       sum(1 for _, lk in model.cache_layers if lk == k),
                       "gen.ptab.%s" % name if k else "gen.ptab",
-                      "gen.dtab.%s" % name if k else "gen.dtab")
-            for k, (name, window) in enumerate(kinds))
+                      "gen.dtab.%s" % name if k else "gen.dtab", **more)
+            for k, (name, window, more) in enumerate(kinds))
     # what a block (a state kind: a row) holds over each kind's layers
     kind_block_bytes = tuple(
         sum(int(np.prod(shape[1:])) * np.dtype(held).itemsize

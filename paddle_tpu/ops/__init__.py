@@ -21,6 +21,7 @@ from . import (  # noqa: F401
     generation_ops,
     moe_ops,
     mla_ops,
+    eva_ops,
     ssm_ops,
     decoding_ops,
     crf_ctc_ops,

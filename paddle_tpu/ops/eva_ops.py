@@ -1,0 +1,197 @@
+"""EVA attention (Zheng et al., *Efficient Attention via Control
+Variates*, arXiv:2302.04542, as the EvaByte models use it): a query attends
+the keys of **its own aligned window** of ``window`` positions exactly and,
+for every chunk of ``chunk`` positions of the **earlier** windows, ONE
+learned summary key and value, all under one softmax. Per head, with the
+learned ``mu`` and ``phi`` [D]::
+
+    chunk c = positions [C c, C c + C):
+      kbar_c = sum_j softmax_j(mu  . k_j) k_j
+      vbar_c = sum_j softmax_j(phi . k_j) v_j     # weights from the KEYS
+    query i attends  { j : j // W == i // W, j <= i }  and
+                     { c : C (c + 1) <= W (i // W) }
+
+A window is a whole number of chunks, so a summary is of a complete chunk
+and a query never sees one of its own window: within the first ``window``
+positions this is causal softmax attention.
+
+* ``eva_summaries`` — the two poolings, of a sequence's rows (every whole
+  chunk of it) or, for a decode step, of the window pool's block that holds
+  each slot's newest row. The pooling is a function of the chunk's rows
+  alone, so a step that runs twice writes the same summary twice.
+* ``eva_attention`` — whole sequences and a prompt's prefill: 256 queries
+  at a time against their window's rows and the summaries of the
+  windows before it.
+* ``eva_attention_decode_paged`` — one query a slot over two paged pools,
+  the window's rows and the summaries: two walks of
+  ``pallas_attention.decode_attention_paged`` (the window's aligned, the
+  summaries' up to the window's edge), each with its maximum and sum, and
+  one normalisation (``merge_walks``).
+
+Scores, softmaxes and sums are float32 whatever flows in; operands go to
+the MXU as they arrive (bfloat16 under ``amp``), float32 ones at the
+highest precision.
+"""
+
+import jax
+import jax.numpy as jnp
+
+from ..core.registry import register_op
+from .generation_ops import _largest_divisor
+from .pallas_attention import cache_precision
+
+# queries the whole-sequence form takes at a time: float32 scores of
+# [H, 256, window + summaries] are 84 MB at the published sizes
+_BLOCK_ROWS = 256
+
+
+def pool_chunks(k, v, mu, phi):
+    """k, v [.., C, H, D] (a chunk's rows), mu, phi [H, D] ->
+    (kbar, vbar) [.., H, D] float32: the rows' sums under the softmaxes of
+    ``mu . k`` and ``phi . k`` over the chunk, unscaled."""
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+
+    def weights(w):
+        s = jnp.sum(k32 * w.astype(jnp.float32), axis=-1, keepdims=True)
+        return jax.nn.softmax(s, axis=-3)               # over the C rows
+    return (jnp.sum(weights(mu) * k32, axis=-3),
+            jnp.sum(weights(phi) * v32, axis=-3))
+
+
+@register_op("eva_summaries")
+def _eva_summaries(ctx):
+    """Mu, Phi [H*D]; attrs num_heads, chunk. Either K, V [B, T, H*D]
+    (rotated keys, values) -> KBar, VBar [B, ceil(T / chunk), H*D]: one
+    row a chunk (a last, partial chunk is pooled with zero rows: nothing
+    attends it, and a prefill writes whole chunks only). Or CacheK, CacheV
+    [NB, BS, H*D] (the window pools, BS = chunk), Pos [S], Table [S, MB]
+    -> KBar, VBar [S, 1, H*D]: of the block that holds row Pos[s] (what
+    it is worth before the block is full is the writer's to drop). Out in
+    the rows' dtype."""
+    nh, c = ctx.attr("num_heads"), ctx.attr("chunk")
+    mu = ctx.input("Mu").reshape(nh, -1)
+    phi = ctx.input("Phi").reshape(nh, -1)
+    with jax.named_scope("eva.summaries"):
+        if ctx.has_input("K"):
+            k, v = ctx.input("K"), ctx.input("V")
+            b, t, dm = k.shape
+            n = -(-t // c)
+            whole = ((0, 0), (0, n * c - t), (0, 0))
+            kbar, vbar = pool_chunks(
+                jnp.pad(k, whole).reshape(b, n, c, nh, -1),
+                jnp.pad(v, whole).reshape(b, n, c, nh, -1), mu, phi)
+            shape = (b, n, dm)
+        else:
+            ck, cv = ctx.input("CacheK"), ctx.input("CacheV")
+            nb, bs, dm = ck.shape
+            if bs != c:
+                raise ValueError("a block of the window pool is one chunk: "
+                                 "%d rows a block, chunks of %d" % (bs, c))
+            pos = ctx.input("Pos").reshape(-1).astype(jnp.int32)
+            table = ctx.input("Table").astype(jnp.int32)
+            s = pos.shape[0]
+            blk = jnp.clip(table[jnp.arange(s), jnp.clip(
+                pos // bs, 0, table.shape[1] - 1)], 0, nb - 1)
+            k = ck[blk].reshape(s, c, nh, -1)
+            kbar, vbar = pool_chunks(k, cv[blk].reshape(s, c, nh, -1),
+                                     mu, phi)
+            shape = (s, 1, dm)
+    return {"KBar": kbar.reshape(shape).astype(k.dtype),
+            "VBar": vbar.reshape(shape).astype(k.dtype)}
+
+
+def windowed_attention(q, k, v, kbar, vbar, window, chunk, scale, r):
+    """One sequence: q, k, v [T, H, D], kbar, vbar [ceil(T / chunk), H,
+    D] -> [T, H, D] float32. T is at most one window or a whole number of
+    them; ``r`` queries at a time against their window's rows (causal) and the
+    summaries of the windows before it, one softmax over both."""
+    t, nh, hd = q.shape
+    w = min(window, t)
+    prec = cache_precision(k.dtype)
+    cols = jnp.arange(w, dtype=jnp.int32)
+    chunks = jnp.arange(kbar.shape[0], dtype=jnp.int32)
+    offs = jnp.arange(r, dtype=jnp.int32)
+
+    def scores(qb, keys):
+        return jnp.einsum("qhd,khd->hqk", qb, keys, precision=prec,
+                          preferred_element_type=jnp.float32) * scale
+
+    def block(b):
+        lo = b * r // w * w                 # the window's first position
+        qb = jax.lax.dynamic_slice_in_dim(q, b * r, r)
+        kw = jax.lax.dynamic_slice_in_dim(k, lo, w)
+        vw = jax.lax.dynamic_slice_in_dim(v, lo, w)
+        seen = (lo + cols)[None, :] <= (b * r + offs)[:, None]   # [r, w]
+        behind = jnp.broadcast_to((chunks + 1) * chunk <= lo,
+                                  (r, chunks.shape[0]))
+        mask = jnp.concatenate([seen, behind], axis=1)[None]
+        s = jnp.where(mask, jnp.concatenate(
+            [scores(qb, kw), scores(qb, kbar)], axis=-1), -1e30)
+        p = jnp.where(mask, jnp.exp(s - jnp.max(s, -1, keepdims=True)), 0.0)
+        p = (p / jnp.sum(p, -1, keepdims=True)).astype(v.dtype)
+        return jnp.einsum("hqk,khd->qhd", p[..., :w], vw, precision=prec,
+                          preferred_element_type=jnp.float32) + \
+            jnp.einsum("hqk,khd->qhd", p[..., w:], vbar, precision=prec,
+                       preferred_element_type=jnp.float32)
+
+    out = jax.lax.map(block, jnp.arange(t // r, dtype=jnp.int32))
+    return out.reshape(t, nh, hd)
+
+
+@register_op("eva_attention")
+def _eva_attention(ctx):
+    """Q, K, V [B, T, H*D] (rotated), KBar, VBar [B, ceil(T / chunk),
+    H*D] (``eva_summaries`` of the same rows); attrs num_heads, window,
+    chunk. Out [B, T, H*D] float32, scores scaled ``D^-1/2``: row i
+    attends its own window's rows up to itself and the summaries of every
+    chunk of the windows before it. A T that is longer than a window and
+    no whole number of them is padded to one (the padding lies after every
+    real row, so none attends it)."""
+    q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
+    kbar, vbar = ctx.input("KBar"), ctx.input("VBar")
+    nh, w, c = ctx.attr("num_heads"), ctx.attr("window"), ctx.attr("chunk")
+    if w % c:
+        raise ValueError("a window of %d is no whole number of chunks of %d"
+                         % (w, c))
+    b, t, dm = q.shape
+    hd = dm // nh
+    tp = t if t <= w else -(-t // w) * w
+    r = _largest_divisor(min(w, tp), _BLOCK_ROWS)
+
+    def rows(x, n):
+        x = jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0)))
+        return x.reshape(b, n, nh, hd)
+    with jax.named_scope("eva.prefill"):
+        out = jax.vmap(lambda *a: windowed_attention(
+            *a, w, c, hd ** -0.5, r))(
+                rows(q, tp), rows(k, tp), rows(v, tp),
+                rows(kbar, -(-tp // c)), rows(vbar, -(-tp // c)))
+    return {"Out": out[:, :t].reshape(b, t, dm)}
+
+
+@register_op("eva_attention_decode_paged")
+def _eva_attention_decode_paged(ctx):
+    """Q [S, 1, H*D] (rotated), CacheK/CacheV [NB, BS, H*D] (the window
+    pools, the step's row already appended), ChunkK/ChunkV [NBc, BSc, H*D]
+    (the summaries, one row a chunk), Pos [S] (the query's position), Table
+    [S, MB], ChunkTable [S, MBc]; attrs num_heads, window, chunk. Out
+    [S, 1, H*D] float32, scores scaled ``D^-1/2``: slot s's query attends
+    the window pool's rows ``[window (Pos // window), Pos]`` and the chunk
+    pool's rows ``[0, window (Pos // window) / chunk)`` under one softmax.
+    ``flash_attention`` routes both walks to the Pallas kernel; the XLA
+    fallback gathers the same rows."""
+    from .. import config as _config
+    from . import pallas_attention as _pa
+    q = ctx.input("Q")
+    pos = ctx.input("Pos").reshape(-1).astype(jnp.int32)
+    nh, w, c = ctx.attr("num_heads"), ctx.attr("window"), ctx.attr("chunk")
+    walk = _pa.decode_attention_paged if _config.get_flag("flash_attention") \
+        else _pa._decode_paged_reference
+    with jax.named_scope("eva.decode"):
+        exact = walk(q, ctx.input("CacheK"), ctx.input("CacheV"), pos + 1,
+                     ctx.input("Table"), nh, window=w, aligned=True,
+                     stats=True)
+        summed = walk(q, ctx.input("ChunkK"), ctx.input("ChunkV"),
+                      pos // w * (w // c), ctx.input("ChunkTable"), nh,
+                      stats=True)
+        return {"Out": _pa.merge_walks([exact, summed], nh)}

@@ -56,12 +56,15 @@ def _kv_cache_write_paged(ctx):
     written at logical positions Hist+i through Table. Rows at or past
     Len scatter out of bounds and DROP (window padding never lands);
     Out aliases the pool variable, so the donated state update keeps
-    the scatter in place."""
+    the scatter in place. With attr ``chunk`` a row of the pool (and of
+    New) stands for that many positions: Hist and Len, given in
+    positions, count whole chunks."""
     pool = ctx.input("Cache")
     new = ctx.input("New")
     table = ctx.input("Table").reshape(-1).astype(jnp.int32)
-    hist = ctx.input("Hist").reshape(-1)[0].astype(jnp.int32)
-    ln = ctx.input("Len").reshape(-1)[0].astype(jnp.int32)
+    chunk = ctx.attr("chunk") or 1
+    hist = ctx.input("Hist").reshape(-1)[0].astype(jnp.int32) // chunk
+    ln = ctx.input("Len").reshape(-1)[0].astype(jnp.int32) // chunk
     nb, bs, d = pool.shape
     t = new.shape[1]
     idx = jnp.arange(t, dtype=jnp.int32)
@@ -81,15 +84,19 @@ def _kv_cache_append_paged(ctx):
     table-mapped position. A dead table entry (>= NB — how the host
     marks inactive or pool-starved slots) pushes the scatter out of
     bounds, so the write DROPS instead of corrupting a block another
-    sequence owns."""
+    sequence owns. With attr ``chunk`` a row of the pool stands for that
+    many positions: slot s's row is ``Pos[s] // chunk``, and it is
+    written only by the chunk's last position (else the write drops)."""
     pool = ctx.input("Cache")
     new = ctx.input("New")
     pos = ctx.input("Pos").reshape(-1).astype(jnp.int32)
     table = ctx.input("Table").astype(jnp.int32)
     nb, bs, d = pool.shape
     s = new.shape[0]
+    chunk = ctx.attr("chunk") or 1
+    last, pos = pos % chunk == chunk - 1, pos // chunk
     bi = jnp.clip(pos // bs, 0, table.shape[1] - 1)
-    blk = table[jnp.arange(s), bi]
+    blk = jnp.where(last, table[jnp.arange(s), bi], nb)
     rows = blk * bs + pos % bs       # blk >= NB -> out of bounds
     flat = pool.reshape(nb * bs, d)
     flat = flat.at[rows].set(new[:, 0, :].astype(pool.dtype),

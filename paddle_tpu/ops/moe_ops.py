@@ -14,7 +14,10 @@ weight, and the three partial results are added in float32. A decode step,
 which is bound by the bytes of the weights, pays little for it; a prefill
 pays three times the products.
 
-* ``linear`` - ``x @ W`` with no bias, exact, float32 out.
+* ``linear`` - ``x @ W`` with no bias, exact, float32 out. (Under the
+  ``amp`` flag the executor hands it ``x`` in bfloat16: a model with no
+  router, served in the operands it was published in, multiplies in one
+  pass.)
 
 * ``rms_norm`` — ``x * w / sqrt(mean(x^2) + eps)`` over the last axis (or
   over each group of ``group_size`` lanes of it: one head's lanes of a
@@ -83,8 +86,14 @@ def exact_dot(x, w, transposed=False):
     """x [n, d] float32 @ w [d, f] (``transposed``: w [f, d], contracted
     over its second axis where it lies), every product exact and the sums
     in float32: a bfloat16 ``w`` meets x's three pieces as [3n, d] in one
-    pass; any other is multiplied at the highest precision."""
+    pass; any other is multiplied at the highest precision. An ``x`` that
+    arrives in bfloat16 (under ``amp``: a model served in bfloat16
+    operands) is one piece already: one pass, float32 sums."""
     dims = (((1,), (1 if transposed else 0,)), ((), ()))
+    if x.dtype == jnp.bfloat16 and w.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(x, w, dims,
+                                   preferred_element_type=jnp.float32,
+                                   precision=jax.lax.Precision.DEFAULT)
     if w.dtype != jnp.bfloat16:
         return jax.lax.dot_general(x.astype(jnp.float32),
                                    w.astype(jnp.float32), dims,
@@ -125,9 +134,13 @@ def _linear(ctx):
 @register_op("rms_norm")
 def _rms_norm(ctx):
     """X [.., d], Scale [d] or [group_size]; attrs epsilon, group_size (0:
-    the whole last axis). Y float32, X's shape."""
+    the whole last axis) and offset (absent: 0; the gain is ``offset +
+    Scale``, a unit offset for a Scale that starts at zero). Y float32,
+    X's shape."""
     x = ctx.input("X").astype(jnp.float32)
     w = ctx.input("Scale").astype(jnp.float32)
+    if ctx.attr("offset"):
+        w = ctx.attr("offset") + w
     eps = ctx.attr("epsilon", 1e-5)
     group = ctx.attr("group_size", 0)
     shape = x.shape

@@ -478,9 +478,19 @@ def cache_precision(cache_dtype):
         else jax.lax.Precision.DEFAULT
 
 
+def window_edge(lengths, window, aligned):
+    """The first row the query at row ``lengths - 1`` attends: with a
+    sliding window the row ``window - 1`` before it, with an **aligned**
+    one the first row of the query's own block of ``window`` rows."""
+    if aligned:
+        return jnp.maximum(lengths - 1, 0) // window * window
+    return jnp.maximum(lengths - window, 0)
+
+
 def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
                             num_heads, num_kv_heads=None, window=None,
-                            v_width=None, scale=None):
+                            v_width=None, scale=None, aligned=False,
+                            stats=False):
     """Dense XLA single-query attention over a PAGED cache: q [S, 1, H*D]
     (one query token per slot), k/v pools [NB, BS, Hkv*D], lengths [S]
     (live rows per slot), tables [S, MB] block ids mapping slot s's
@@ -491,7 +501,11 @@ def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
     attends KV head ``h // (H / Hkv)``. With ``window``, row j is
     attendable iff ``length - window <= j < length``: the query sits at
     ``length - 1`` and sees itself and the ``window - 1`` rows before it;
-    rows behind the window may sit in blocks the table no longer names.
+    rows behind the window may sit in blocks the table no longer names;
+    an ``aligned`` window starts at a multiple of ``window``
+    (:func:`window_edge`). With ``stats`` the result is float32 and comes
+    with the softmax's maximum and sum, each [S, H, 1]: what a second
+    walk's result is merged by (:func:`merge_walks`).
     With ``v_width`` there is no V pool (``v_pool`` None): the pool holds
     one head whose value is the leading ``v_width`` lanes of its key's
     own row, and the result is [S, 1, H*v_width]. ``scale`` multiplies
@@ -518,7 +532,7 @@ def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
     vh = vh.reshape(s * num_heads, c, vh.shape[-1])
     lens = jnp.broadcast_to(
         jnp.asarray(lengths).reshape(s, 1), (s, num_heads)).reshape(-1)
-    if window is None and not v_width and scale is None:
+    if window is None and not v_width and scale is None and not stats:
         return _decode_reference(qh, kh, vh, lens).reshape(s, 1, dm)
     prec = cache_precision(k_pool.dtype)
     sc = jnp.einsum("bqd,bkd->bqk", qh, kh, precision=prec,
@@ -527,10 +541,34 @@ def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
     cols = jnp.arange(c)[None, None, :]
     mask = cols < lens[:, None, None]
     if window is not None:
-        mask = mask & (cols >= lens[:, None, None] - window)
-    p = jax.nn.softmax(jnp.where(mask, sc, _NEG), axis=-1).astype(q.dtype)
-    return jnp.einsum("bqk,bkd->bqd", p, vh,
-                      precision=prec).reshape(s, 1, -1)
+        mask = mask & (cols >= window_edge(lens, window,
+                                           aligned)[:, None, None])
+    sc = jnp.where(mask, sc, _NEG)
+    if not stats:
+        p = jax.nn.softmax(sc, axis=-1).astype(q.dtype)
+        return jnp.einsum("bqk,bkd->bqd", p, vh,
+                          precision=prec).reshape(s, 1, -1)
+    m = jnp.max(sc, axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(sc - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bqk,bkd->bqd", p.astype(vh.dtype), vh, precision=prec,
+                     preferred_element_type=jnp.float32) \
+        / jnp.maximum(l, 1e-30)
+    return (out.reshape(s, 1, -1), m.reshape(s, num_heads, 1),
+            l.reshape(s, num_heads, 1))
+
+
+def merge_walks(walks, num_heads):
+    """One softmax over the rows of several walks: ``walks`` holds each
+    walk's ``(out [S, 1, H*D], m [S, H, 1], l [S, H, 1])`` as ``stats``
+    returns them. A walk that attended nothing (sum 0) adds nothing."""
+    top = functools.reduce(jnp.maximum, [m for _, m, _ in walks])
+    weights = [l * jnp.exp(m - top) for _, m, l in walks]
+    total = jnp.maximum(sum(weights), 1e-30)
+    s = walks[0][0].shape[0]
+    out = sum(o.reshape(s, num_heads, -1) * w
+              for (o, _, _), w in zip(walks, weights)) / total
+    return out.reshape(s, 1, -1)
 
 
 # VMEM for the paged kernel's page buffers: K and V, each double-buffered
@@ -550,7 +588,8 @@ def _paged_block_pages(block_size, d_model, dtype, max_blocks, buffers=4):
 
 def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
                          block_size, max_blocks, num_blocks, pages,
-                         num_heads, num_kv_heads, window, scale, v_width):
+                         num_heads, num_kv_heads, window, scale, v_width,
+                         aligned, stats):
     """One slot of single-query flash decode THROUGH a block table, all
     heads at once. The pools stay in HBM; the program walks its slot's
     LIVE pages only, ``pages`` of them a compute block, each page
@@ -562,7 +601,11 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
     ``length - window`` and masks that page's rows behind it: pages
     before it are never read (the session has freed them). A slot whose
     first live table entry is dead (>= NB: inactive or starved) has no
-    pages: it fetches nothing and writes zeros.
+    pages: it fetches nothing and writes zeros. An ``aligned`` window's
+    walk starts at the first page of the query's own block of ``window``
+    rows (:func:`window_edge`). With ``stats`` a second output
+    ``[H, 128]`` holds the softmax's maximum in lane 0 and its sum in
+    the others, for :func:`merge_walks`.
 
     With ``v_width`` the pool holds ONE head whose value is the leading
     ``v_width`` lanes of its key's own row (a latent cache): there is no
@@ -578,11 +621,13 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
     The surplus products are free: the kernel runs at the copies'
     speed."""
     from jax.experimental.pallas import tpu as pltpu
-    if v_width:
-        o_ref, kbuf, sem, base_ref = refs
-        vp_ref, vbuf = None, kbuf
-    else:
-        vp_ref, o_ref, kbuf, vbuf, sem, base_ref = refs
+    refs = list(refs)
+    vp_ref = None if v_width else refs.pop(0)
+    o_ref = refs.pop(0)
+    st_ref = refs.pop(0) if stats else None
+    kbuf = refs.pop(0)
+    vbuf = kbuf if v_width else refs.pop(0)
+    sem, base_ref = refs
     bs, mb, nb = block_size, max_blocks, num_blocks
     si, ns = pl.program_id(0), pl.num_programs(0)
     dkv = kbuf.shape[-1]
@@ -597,8 +642,8 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
         """The first page a slot's query can see."""
         if window is None:
             return 0
-        return jnp.minimum(jnp.maximum(lens_ref[slot] - window, 0) // bs,
-                           mb - 1)
+        return jnp.minimum(
+            window_edge(lens_ref[slot], window, aligned) // bs, mb - 1)
 
     def pages_of(slot, first):
         n = jnp.minimum((lens_ref[slot] + bs - 1) // bs, mb) - first
@@ -689,7 +734,7 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
             jnp.int32, s.shape, 1)
         mask = row < length
         if window is not None:
-            mask = mask & (row >= length - window)
+            mask = mask & (row >= window_edge(length, window, aligned))
         s = jnp.where(mask, s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -717,11 +762,15 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
             out = sum(out[:, g * hd:(g + 1) * hd]
                       for g in range(num_kv_heads))
     o_ref[0] = out.astype(o_ref.dtype)
+    if stats:
+        lane = jax.lax.broadcasted_iota(jnp.int32, st_ref.shape[1:], 1)
+        st_ref[0] = jnp.where(lane == 0, m, l)
 
 
 def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
                            num_heads, interpret=None, num_kv_heads=None,
-                           window=None, v_width=None, scale=None):
+                           window=None, v_width=None, scale=None,
+                           aligned=False, stats=False):
     """Single-query flash decode (inference only, no vjp: generation
     never differentiates through the cache) where K/V live in a PAGED
     pool and the kernel streams exactly the live blocks of each
@@ -731,7 +780,11 @@ def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
     with ``num_kv_heads`` heads (default ``num_heads``; fewer: grouped
     queries, head h on KV head ``h // (H / Hkv)``); lengths: [S]; tables:
     [S, MB] int block ids (entries >= NB are dead — clamped, masked by
-    length); ``window``: attend rows ``[length - window, length)`` only.
+    length); ``window``: attend rows ``[length - window, length)`` only,
+    or with ``aligned`` the rows from the last multiple of ``window``
+    below ``length`` on. ``stats``: the result in float32 with the
+    softmax's maximum and sum ``[S, H, 1]`` behind it, so that walks over
+    two pools give one softmax (:func:`merge_walks`).
     ``v_width``: the pool holds one head whose value is the leading
     ``v_width`` lanes of its key's row (a latent cache: ``v_pool`` is
     None, ``num_kv_heads`` 1); a page is read once for both and the
@@ -768,20 +821,20 @@ def decode_attention_paged(q, k_pool, v_pool, lengths, tables,
         kernel_path.record("decode_attention_paged")
         return _decode_paged_reference(q, k_pool, v_pool, lengths,
                                        tables, num_heads, nkv, window,
-                                       v_width, scale)
+                                       v_width, scale, aligned, stats)
     kernel_path.record("decode_attention_paged", interpret)
     pages = _paged_block_pages(bs, dkv, k_pool.dtype, tables.shape[1],
                                2 if v_width else 4)
     return _decode_paged_call(q, k_pool, v_pool, lengths, tables,
                               num_heads, pages, interpret, nkv, window,
                               v_width, hd ** -0.5 if scale is None
-                              else float(scale))
+                              else float(scale), bool(aligned), bool(stats))
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.jit, static_argnums=tuple(range(5, 14)))
 def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
                        pages, interpret, num_kv_heads, window, v_width,
-                       scale):
+                       scale, aligned, stats):
     """The kernel call, under a jit of its own: a model's layers share
     their geometry, so the body is traced once a process and lowered
     once a program, not once a layer."""
@@ -798,14 +851,20 @@ def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
     # head where query heads share one
     rows = (1, dm) if num_kv_heads == num_heads else (num_heads, hd)
     out_rows = (num_heads, v_width) if v_width else rows
+    # behind the result, where asked for, the softmax's maximum and sum
+    out_shapes = [out_rows] + [(num_heads, 128)] * stats
+    out_specs = [pl.BlockSpec((1,) + shape, lambda si, lr, tr: (si, 0, 0))
+                 for shape in out_shapes]
+    out_shape = [jax.ShapeDtypeStruct(
+        (s,) + shape, jnp.float32 if stats else q.dtype)
+        for shape in out_shapes]
     pools = (k_pool,) if v_width else (k_pool, v_pool)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s,),
         in_specs=[pl.BlockSpec((1,) + rows, lambda si, lr, tr: (si, 0, 0))]
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-        out_specs=pl.BlockSpec((1,) + out_rows,
-                               lambda si, lr, tr: (si, 0, 0)),
+        out_specs=out_specs if stats else out_specs[0],
         scratch_shapes=[pltpu.VMEM((2, pages * bs, dkv), pool.dtype)
                         for pool in pools] + [
             pltpu.SemaphoreType.DMA((2, 2)),      # (K|V, buffer)
@@ -815,15 +874,18 @@ def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
         functools.partial(_decode_paged_kernel, block_size=bs,
                           max_blocks=mb, num_blocks=nb, pages=pages,
                           num_heads=num_heads, num_kv_heads=num_kv_heads,
-                          window=window, scale=scale, v_width=v_width),
+                          window=window, scale=scale, v_width=v_width,
+                          aligned=aligned, stats=stats),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s,) + out_rows, q.dtype),
+        out_shape=out_shape if stats else out_shape[0],
         # programs run in order: each hands its successor a block
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="decode_attention_paged",
         interpret=interpret)(lens, tab, q.reshape((s,) + rows), *pools)
-    return out.reshape(s, 1, -1)
+    if not stats:
+        return out.reshape(s, 1, -1)
+    return (out[0].reshape(s, 1, -1), out[1][:, :, :1], out[1][:, :, 1:2])
 
 
 def flash_attention(q, k, v, causal=False, segment_ids=None,

@@ -43,8 +43,10 @@ def _gaussian_random(ctx):
     dtype = convert_dtype(ctx.attr("dtype", "float32"))
     mean = ctx.attr("mean", 0.0)
     std = ctx.attr("std", 1.0)
-    return {"Out": mean + std * jax.random.normal(ctx.rng_key, shape,
-                                                  dtype=dtype)}
+    z = jax.random.normal(ctx.rng_key, shape, dtype=dtype)
+    if ctx.attr("clip"):        # in deviations: a draw past it lies on it
+        z = jnp.clip(z, -ctx.attr("clip"), ctx.attr("clip"))
+    return {"Out": mean + std * z}
 
 
 @register_op("uniform_random", needs_rng=True, skip_eval_shape=True)

@@ -144,7 +144,8 @@ from . import resilience as _sres
 from .batcher import ServingOverloadError, _resolve, _WAIT_ALPHA
 from .decoding.policy import GREEDY_FINGERPRINT, mint_seed
 from .paged_cache import (BLOCK_COWS, SPEC_ROLLBACKS, WINDOW_BLOCKS_FREED,
-                          CacheKind, LayerCache, PoolExhausted, PrefixIndex)
+                          CacheKind, LayerCache, PoolExhausted, PrefixIndex,
+                          refuse_sharing)
 from .resilience import (ReplicaBreaker, ServingDeadlineError,
                          ServingUnavailableError)
 
@@ -209,6 +210,22 @@ _LATENT_ROWS_ATTENDED = _metrics.REGISTRY.counter(
     "the slots that advanced and over the attention sites of a latent "
     "kind (one a layer, or one a half of a layer of two halves) of their "
     "context length, the new token included")
+_EVA_WINDOW_ROWS = _metrics.REGISTRY.counter(
+    "paddle_generation_eva_window_rows_total",
+    "Rows of an aligned window kind attended by decode steps: per step, "
+    "the sum over the slots that advanced and over the kind's layers of "
+    "the rows from the query's own window's first position to the query")
+_EVA_CHUNK_ROWS = _metrics.REGISTRY.counter(
+    "paddle_generation_eva_chunk_rows_total",
+    "Rows of a chunk kind (one summary a chunk of positions) attended by "
+    "decode steps: per step, the sum over the slots that advanced and "
+    "over the kind's layers of the chunks that lie before the query's "
+    "own window")
+_EVA_CHUNKS_WRITTEN = _metrics.REGISTRY.counter(
+    "paddle_generation_eva_chunks_written_total",
+    "Summaries written into a chunk kind's pools, a layer each: by a "
+    "decode step for every slot whose row completed a chunk, by a "
+    "prefill for every whole chunk of its prompt")
 _STATE_ROWS_UPDATED = _metrics.REGISTRY.counter(
     "paddle_generation_state_rows_updated_total",
     "State rows advanced by decode steps: per step, the layers of a state "
@@ -523,26 +540,19 @@ class GenerationSession:
             "full", None, spec.num_blocks, len(spec.cache_vars) // 2,
             None, None),)
         policy = getattr(spec, "policy", None)
-        if any(k.name == "state" for k in kinds) and (
-                spec.prefix_cache or
-                (policy is not None and policy.speculate_k > 0)):
-            raise ValueError(
-                "a spec with a state kind of layer cache takes neither "
-                "prefix_cache nor speculate_k: a slot's state is one row "
-                "rewritten whole every step, which no prefix can share and "
-                "no rejected draft can be rolled back from")
-        if (len(kinds) > 1 or kinds[0].window) and (
-                spec.prefix_cache or
-                (policy is not None and policy.speculate_k > 0)):
-            raise ValueError(
-                "a spec with a window kind of layer cache (or more "
-                "than one kind) takes neither prefix_cache nor "
-                "speculate_k: blocks shared or rolled back behind a "
-                "window are not implemented")
+        if spec.prefix_cache or (policy is not None
+                                 and policy.speculate_k > 0):
+            refuse_sharing(kinds)
         self.kinds = [LayerCache(k, spec.block_size, n, spec.max_blocks)
                       for k in kinds]
         self._more_kinds = tuple(self.kinds[1:])
         self._window_kinds = tuple(k for k in self.kinds if k.window)
+        # an aligned window kind and the chunk kind made from its blocks
+        # (EVA attention): what a step's three eva counters are kept from
+        self._eva = None
+        if any(k.chunk > 1 for k in self.kinds):
+            self._eva = (next(k for k in self.kinds if k.kind.aligned),
+                         next(k for k in self.kinds if k.chunk > 1))
         self._latent_layers = getattr(spec, "latent_layers", 0)
         self._state_layers = getattr(spec, "state_layers", 0)
         self.pool = self.kinds[0].pool
@@ -644,8 +654,8 @@ class GenerationSession:
         consults this during placement so pool pressure parks a
         request instead of turning into an admit exception that would
         charge a healthy session's breaker."""
-        need = -(-min(int(n_tokens), self.max_pos)
-                 // self.spec.block_size)
+        n_tokens = min(int(n_tokens), self.max_pos)
+        need = self.kinds[0].blocks_for(n_tokens)
         avail = self.pool.free_count()
         if self.prefix is not None:
             # a matched prefix ending mid-block copies-on-write one
@@ -661,7 +671,7 @@ class GenerationSession:
         # the other kinds hold the whole history until the prefill has
         # run, then what their window keeps
         return avail >= need and all(
-            k.pool.free_count() >= (1 if k.state else need)
+            k.pool.free_count() >= k.blocks_for(n_tokens)
             for k in self._more_kinds)
 
     def storable(self, n_tokens):
@@ -669,20 +679,22 @@ class GenerationSession:
         ``n_tokens`` history? A pool must have enough blocks
         IN TOTAL — placement must not park a request forever on a
         pool that can never satisfy it, however much retires free."""
-        blocks = -(-int(n_tokens) // self.spec.block_size)
-        return all(self._most_blocks(k, blocks) <= k.pool.num_blocks
+        return all(self._most_blocks(k, int(n_tokens)) <= k.pool.num_blocks
                    for k in self.kinds)
 
-    def _most_blocks(self, kind, blocks):
-        """The most blocks a sequence of ``blocks`` holds in a kind at one
-        time: all of them, a state kind's one row, or with a window those
-        of the longest prompt (a prefill writes all its rows before the
-        window is trimmed) or of the window with the block being written."""
-        if kind.state:
-            return 1
-        if not kind.window:
-            return blocks
+    def _most_blocks(self, kind, n_tokens):
+        """The most blocks a sequence of ``n_tokens`` holds in a kind at
+        one time: all its rows' (a chunk kind has a row a chunk), a state
+        kind's one row, or with a window those of the longest prompt (a
+        prefill writes all its rows before the window is trimmed) or of
+        the window with the block being written; an aligned window is
+        never written behind its edge and holds its own rows at most."""
         bs = self.spec.block_size
+        blocks = kind.blocks_for(n_tokens)
+        if kind.state or not kind.window:
+            return blocks
+        if kind.kind.aligned:
+            return min(-(-n_tokens // bs), kind.window // bs)
         return min(blocks, max(-(-self.spec.prompt_buckets[-1] // bs),
                                kind.window // bs + 2))
 
@@ -937,8 +949,7 @@ class GenerationSession:
                 # the matched prefix ends MID-block: the suffix writes
                 # into that shared block, so diverge onto a copy first
                 self._ensure_writable(table, len(table) - 1)
-            while len(table) * bs < n:
-                table.append(self._alloc_block())
+            self.kinds[0].extend(table, n, slot, self._alloc_block)
             for kind, tbl in zip(self._more_kinds, more):
                 with self._state_bind(kind, slot, True):
                     kind.extend(tbl, n, slot)
@@ -978,11 +989,8 @@ class GenerationSession:
                 seed, cstate)
 
     def _admit_rollback(self, table, more):
-        for block in table:
-            self.pool.decref(block)
-        for kind, tbl in zip(self._more_kinds, more):
-            for block in tbl:
-                kind.pool.decref(block)
+        for kind, tbl in zip(self.kinds, [table] + list(more)):
+            kind.drop(tbl)
 
     def admit_abandon(self, launched):
         """Give back what :meth:`admit_launch` took, for a prefill whose
@@ -1024,6 +1032,9 @@ class GenerationSession:
         _PREFILLS.labels(bucket=bucket).inc()
         _PROMPT_TOKENS.inc(n - matched)
         _PREFILL_PADDED_TOKENS.inc(bucket)
+        if self._eva:
+            chunks = self._eva[1]
+            _EVA_CHUNKS_WRITTEN.inc(chunks.kind.layers * (n // chunks.chunk))
         return slot, first
 
     def step(self):
@@ -1305,7 +1316,18 @@ class GenerationSession:
         if self._window_kinds and advanced.size:
             _WINDOW_CONTEXT_TOKENS.inc(int(sum(
                 k.kind.layers * np.minimum(lens, k.window).sum()
-                for k in self._window_kinds)))
+                for k in self._window_kinds if not k.kind.aligned)))
+        if self._eva and advanced.size:
+            window, chunks = self._eva
+            # each query's own window starts at ``edge``: it attends the
+            # window pool's rows from there on and a summary for every
+            # chunk before; a chunk is summed up by its last position
+            edge = (lens - 1) // window.window * window.window
+            _EVA_WINDOW_ROWS.inc(window.kind.layers * int((lens - edge).sum()))
+            _EVA_CHUNK_ROWS.inc(chunks.kind.layers
+                                * int(edge.sum()) // chunks.chunk)
+            _EVA_CHUNKS_WRITTEN.inc(chunks.kind.layers * int(
+                (lens % chunks.chunk == 0).sum()))
         if self._latent_layers and advanced.size:
             _LATENT_ROWS_ATTENDED.inc(self._latent_layers * int(lens.sum()))
         if self._state_layers and advanced.size:

@@ -51,7 +51,7 @@ from ..observability import metrics as _metrics
 from ..observability import request_trace as _rtrace
 
 __all__ = ["BlockPool", "PrefixIndex", "PoolExhausted", "CacheKind",
-           "LayerCache"]
+           "LayerCache", "refuse_sharing"]
 
 PREFIX_HITS = _metrics.REGISTRY.counter(
     "paddle_generation_prefix_hits_total",
@@ -100,8 +100,51 @@ _POOL_SEQ = itertools.count()
 # layer's state, ops/ssm_ops.py), a sequence's table is the one entry it is
 # bound to from admission to retirement, and the row of a slot is the row of
 # its own index, so that a step updates the pool where it lies.
+# Two more behaviours are fields of their own. A window that is ``aligned``
+# keeps the rows of the sequence's current block of ``window`` positions,
+# from the last multiple of ``window`` on: nothing is freed until a
+# sequence crosses that edge, and then every block behind it at once. And
+# a kind with ``chunk`` > 1 keeps one row for every ``chunk`` positions (a
+# summary of them, written when the chunk's last position is: the kind
+# named ``chunk``, ops/eva_ops.py), so that its table grows a block every
+# ``chunk * block_size`` positions and a sequence of n tokens holds
+# ``n // chunk`` rows.
 CacheKind = collections.namedtuple(
-    "CacheKind", "name window num_blocks layers prefill_table decode_table")
+    "CacheKind", "name window num_blocks layers prefill_table decode_table "
+    "aligned chunk", defaults=(False, 1))
+
+# Why a kind of layer cache takes neither a shared prefix nor speculation:
+# said here once, for ``lm_session`` and ``GenerationSession`` alike.
+_NO_SHARING = {
+    "state": "a slot's state is one row rewritten whole every step, which "
+             "no prefix can share (nothing keeps it as it was at a block's "
+             "edge) and no rejected draft can be rolled back from",
+    "latent": "its prefill expands keys and values from the prompt's own "
+              "latents and attends nothing cached before them",
+    "chunk": "its prefill attends the prompt's own rows and summaries and "
+             "nothing cached before them, and a summary written at a "
+             "chunk's last position cannot be rolled back",
+    "window": "blocks shared or rolled back behind a window are not "
+              "implemented",
+}
+
+
+def refuse_sharing(kinds):
+    """Raise ValueError if a spec with these kinds of layer cache (their
+    names, or ``CacheKind``s) cannot serve ``prefix_cache`` or
+    ``speculate_k``: the message names the kind that refuses and why."""
+    names = [getattr(k, "name", k) for k in kinds]
+    names += ["window" for k in kinds if getattr(k, "window", None)]
+    for name in _NO_SHARING:
+        if name in names:
+            raise ValueError(
+                "a %s kind of layer cache takes neither prefix_cache nor "
+                "speculate_k: %s" % (name, _NO_SHARING[name]))
+    if len(kinds) > 1:
+        raise ValueError(
+            "a spec with more than one kind of layer cache (%s) takes "
+            "neither prefix_cache nor speculate_k: only the first kind's "
+            "blocks are indexed or rolled back" % ", ".join(names))
 
 
 class PoolExhausted(RuntimeError):
@@ -247,11 +290,17 @@ class LayerCache:
     kind marks the entries it has freed dead (the pool's ``num_blocks``,
     what the table feeds hold for rows nobody owns) and remembers per slot
     the first live one, so that a feed row copies the live entries only. A
-    state kind's table is the one row the slot is bound to: its own."""
+    state kind's table is the one row the slot is bound to: its own.
+    An **aligned** window is never written behind its edge: an admission's
+    table starts with dead entries up to it, and ``first_seen`` is that
+    edge's block. A kind whose row is a **chunk** counts its rows as
+    ``n_tokens // chunk`` (its table feeds are as wide as any, and mostly
+    dead)."""
 
     def __init__(self, kind, block_size, slots, max_blocks=None):
         self.kind = kind
         self.window = kind.window
+        self.chunk = kind.chunk
         self.state = kind.name == "state"
         if self.state and kind.num_blocks != slots:
             raise ValueError("a state kind has one row a slot: %d rows, %d "
@@ -263,16 +312,40 @@ class LayerCache:
         self.tables = [[] for _ in range(slots)]
         self.first = np.zeros(slots, np.int64)
 
-    def extend(self, table, n_tokens, slot):
+    def extend(self, table, n_tokens, slot, alloc=None):
         """Append to ``table`` what a sequence of ``n_tokens`` in ``slot``
-        still lacks: fresh blocks, or a state kind's one row, the slot's
-        own. Raises PoolExhausted with the table as far as it got."""
+        still lacks: fresh blocks (from ``alloc``, default the pool's), or
+        a state kind's one row, the slot's own. An empty table of an
+        aligned window starts with dead entries for the blocks behind the
+        window's edge, which nothing will write. Raises PoolExhausted with
+        the table as far as it got."""
         if self.state:
             if not table:
                 table.append(self.pool.take(slot))
             return
-        while len(table) * self.pool.block_size < n_tokens:
-            table.append(self.pool.alloc())
+        if self.kind.aligned and not table:
+            table.extend([self.pool.num_blocks] * int(
+                self.first_seen(n_tokens)))
+        alloc = alloc or self.pool.alloc
+        rows = n_tokens // self.chunk
+        while len(table) * self.pool.block_size < rows:
+            table.append(alloc())
+
+    def blocks_for(self, n_tokens):
+        """The blocks a sequence of ``n_tokens`` takes at its admission."""
+        if self.state:
+            return 1
+        blocks = -(-(n_tokens // self.chunk) // self.pool.block_size)
+        if self.kind.aligned:
+            blocks -= int(self.first_seen(n_tokens))
+        return blocks
+
+    def drop(self, table):
+        """Return the live blocks of a table that belongs to no slot yet
+        (an admission undone)."""
+        for block in table:
+            if block < self.pool.num_blocks:
+                self.pool.decref(block)
 
     def release(self, slot):
         """Return every block the slot still holds."""
@@ -283,21 +356,28 @@ class LayerCache:
 
     def first_seen(self, lengths):
         """The first block the query at position ``lengths`` (the next
-        row written) can see: a window keeps the rows from
-        ``length + 1 - window`` on, the decode kernel's first live page."""
-        return np.maximum(np.asarray(lengths) + 1 - self.window, 0) \
-            // self.pool.block_size
+        row written) can see, the decode kernel's first live page: a
+        window keeps the rows from ``length + 1 - window`` on, an aligned
+        one those from the last multiple of ``window`` at or before the
+        query."""
+        lengths = np.asarray(lengths)
+        edge = lengths // self.window * self.window if self.kind.aligned \
+            else np.maximum(lengths + 1 - self.window, 0)
+        return edge // self.pool.block_size
 
     def trim(self, slot, first):
         """Free the blocks of ``slot`` before block ``first``: those that
         lie wholly behind its window. Returns how many were freed."""
         table, old = self.tables[slot], int(self.first[slot])
         first = min(int(first), len(table))
+        freed = 0
         for j in range(old, first):
-            self.pool.decref(table[j])
-            table[j] = self.pool.num_blocks
+            if table[j] < self.pool.num_blocks:     # never written: dead
+                self.pool.decref(table[j])
+                table[j] = self.pool.num_blocks
+                freed += 1
         self.first[slot] = max(old, first)
-        return max(0, first - old)
+        return freed
 
     def feed_row(self, row, slot):
         """Write the slot's live entries into a table-feed row that is

@@ -883,12 +883,15 @@ def test_a_one_kind_specs_programs_are_the_parents_byte_for_byte():
 
 def test_a_two_kind_specs_programs_are_the_parents_byte_for_byte():
     """This file's model, full and window layers, digested at 119a506,
-    before the dense per-slot layout left ``lm_session``."""
+    before the dense per-slot layout left ``lm_session``. (Digested again
+    in PR 42: ``CacheKind`` took the fields ``aligned`` and ``chunk``,
+    which its ``repr`` in the digest shows; with the kinds written as the
+    parent wrote them the programs still give 9c946466...b1059d.)"""
     spec = moe_lm_session(slots=3, cache_len=32, prompt_buckets=(8, 16),
                           block_size=4, num_blocks=24, window_num_blocks=20,
                           cache_ns="kv", **SIZES)
-    assert _digest(spec) == ("9c9464669cfd22f073cd9c8a1d4243ef"
-                             "4658e61c65d5d0af4eca990686b1059d")
+    assert _digest(spec) == ("bc6009de7e570167352fadb99fdcf2f7"
+                             "5de7307252d03d7b0c86be005bc85038")
 
 
 @pytest.mark.parametrize("policy,digest", [

@@ -230,6 +230,25 @@ def test_decode_attention_paged_compiles_grouped_queries(chip, window):
         "decode_attention_paged")
 
 
+@pytest.mark.parametrize("aligned", [True, False], ids=["window", "chunks"])
+def test_decode_attention_paged_compiles_a_walk_to_be_merged(chip, aligned):
+    """evabyte-serve-offline's two walks a layer (ops/eva_ops.py): 24
+    slots, 32 heads of 128 each on its own KV head, bfloat16 query and
+    pools 4,096 wide, table rows of 768; the window pool's walk aligned,
+    the chunk pool's plain, each with its maximum and sum behind the
+    result (float32, [24, 32, 128] lanes of statistics)."""
+    def fn(q, kp, vp, lens, tables):
+        return pa.decode_attention_paged(
+            q, kp, vp, lens, tables, 32, interpret=False,
+            window=2048 if aligned else None, aligned=aligned, stats=True)
+    pool = ((3072 if aligned else 1152, 16, 4096), BF16)
+    hlo = _compile(chip, fn, ((24, 1, 4096), BF16), pool, pool,
+                   ((24,), I32), ((24, 768), I32))
+    assert _has_kernel(hlo, "decode_attention_paged")
+    assert re.search(r"f32\[24,1,4096\]", hlo) and \
+        re.search(r"f32\[24,32,128\]", hlo), hlo[-2000:]
+
+
 def test_exact_products_keep_their_three_pieces(chip):
     """ops/moe_ops.py exact_dot on the chip's compiler: the float32
     activation reaches the bfloat16 weight as 3 x the rows in one
