@@ -19,9 +19,19 @@ positions this is causal softmax attention.
   chunk of it) or, for a decode step, of the window pool's block that holds
   each slot's newest row. The pooling is a function of the chunk's rows
   alone, so a step that runs twice writes the same summary twice.
-* ``eva_attention`` — whole sequences and a prompt's prefill: 256 queries
-  at a time against their window's rows and the summaries of the
-  windows before it.
+* ``eva_attention`` — whole sequences and a prompt's prefill, as two
+  attentions merged under one softmax, like the decode form: every window
+  is a causal sequence of its own, so the windows go through the flash
+  forward as its batch (``pallas_attention.flash_attention_stats``: the
+  float32 result and each row's log-sum-exp), window ``n`` attends the
+  ``n window / chunk`` summaries before it in plain XLA (no mask: all of
+  them lie behind every row of the window), and ``merge_walks``
+  normalises once (``flash_windowed_attention``). That form is taken
+  where ``flash_attention`` is set and the kernel can tile a window
+  (whole lane tiles of rows); anything else takes ``windowed_attention``,
+  the XLA form: 256 queries at a time against their window's rows and
+  every summary, the causal half and the summaries not yet behind the
+  window computed and masked.
 * ``eva_attention_decode_paged`` — one query a slot over two paged pools,
   the window's rows and the summaries: two walks of
   ``pallas_attention.decode_attention_paged`` (the window's aligned, the
@@ -30,17 +40,21 @@ positions this is causal softmax attention.
 
 Scores, softmaxes and sums are float32 whatever flows in; operands go to
 the MXU as they arrive (bfloat16 under ``amp``), float32 ones at the
-highest precision.
+highest precision, but for the flash forward, which multiplies at the
+MXU's default precision as it does for ``multihead_attention``.
 """
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
+from . import kernel_path, pallas_attention as _pa
 from .generation_ops import _largest_divisor
 from .pallas_attention import cache_precision
 
-# queries the whole-sequence form takes at a time: float32 scores of
+# queries the XLA whole-sequence form takes at a time: float32 scores of
 # [H, 256, window + summaries] are 84 MB at the published sizes
 _BLOCK_ROWS = 256
 
@@ -138,7 +152,67 @@ def windowed_attention(q, k, v, kbar, vbar, window, chunk, scale, r):
     return out.reshape(t, nh, hd)
 
 
-@register_op("eva_attention")
+def flash_windowed_attention(q, k, v, kbar, vbar, window, chunk):
+    """Sequences: q, k, v [B, T, H, D], kbar, vbar [B, ceil(T / chunk), H,
+    D] -> [B, T, H, D] float32, or None where the flash forward cannot
+    tile a window (``flash_attention_stats``). T is at most one window or
+    a whole number of them. What ``windowed_attention`` computes, as two
+    parts under one softmax: the windows, each a causal sequence of the
+    kernel's batch, and for window ``n >= 1`` its rows against the
+    summaries of the windows before it, all of them seen, scores (scaled
+    ``D^-1/2``, as the kernel's) and sums float32, one static step a window
+    (the scores of a step are ``[B, H, window, n window / chunk]``: 100 MB
+    at the last window of 8,192 positions, where all rows against all
+    summaries would be 403)."""
+    b, t, nh, hd = q.shape
+    w = min(window, t)
+    nw = t // w
+
+    def heads_first(x):                 # [B, T, H, D] -> [B nW H, W, D]
+        return x.reshape(b, nw, w, nh, hd).transpose(0, 1, 3, 2, 4) \
+            .reshape(b * nw * nh, w, hd)
+    qh = heads_first(q)
+    part = _pa.flash_attention_stats(qh, heads_first(k), heads_first(v),
+                                     causal=True)
+    if part is None:
+        return None
+    prec = cache_precision(k.dtype)
+    o, lse = (x.reshape(b, nw, nh * w, -1) for x in part)
+    qh = qh.reshape(b, nw, nh, w, hd)
+    rows = b * nh * w       # a row of a head is a walk's slot: [rows, 1, D]
+    out = [o[:, 0]]
+    for n in range(1, nw):              # window n: its n w / chunk summaries
+        kb, vb = kbar[:, :n * w // chunk], vbar[:, :n * w // chunk]
+        s = jnp.einsum("bhqd,bkhd->bhqk", qh[:, n], kb, precision=prec,
+                       preferred_element_type=jnp.float32) * hd ** -0.5
+        m = jnp.max(s, -1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, -1, keepdims=True)
+        summed = jnp.einsum("bhqk,bkhd->bhqd", p.astype(vb.dtype), vb,
+                            precision=prec,
+                            preferred_element_type=jnp.float32) / l
+        exact = lse[:, n].reshape(rows, 1, 1)
+        out.append(_pa.merge_walks(
+            [(o[:, n].reshape(rows, 1, hd), exact, jnp.ones_like(exact)),
+             (summed.reshape(rows, 1, hd), m.reshape(rows, 1, 1),
+              l.reshape(rows, 1, 1))], 1))
+    return jnp.concatenate(
+        [x.reshape(b, nh, w, hd).transpose(0, 2, 1, 3) for x in out], 1)
+
+
+def _eva_attention_shape(op, block):
+    """Out is Q's shape in float32: said, not traced, so that the kernel
+    path counters count the forms that are compiled and a program that is
+    built and never run (a startup's whole-sequence model) counts none."""
+    q, out = (block.var_or_none(n) for n in (op.input("Q"), op.output("Out")))
+    if op.attrs["window"] % op.attrs["chunk"]:
+        raise ValueError("a window of %d is no whole number of chunks of %d"
+                         % (op.attrs["window"], op.attrs["chunk"]))
+    if q is not None and q.shape is not None and out is not None:
+        out.shape, out.dtype = tuple(q.shape), np.dtype("float32")
+
+
+@register_op("eva_attention", infer_shape=_eva_attention_shape)
 def _eva_attention(ctx):
     """Q, K, V [B, T, H*D] (rotated), KBar, VBar [B, ceil(T / chunk),
     H*D] (``eva_summaries`` of the same rows); attrs num_heads, window,
@@ -146,26 +220,33 @@ def _eva_attention(ctx):
     attends its own window's rows up to itself and the summaries of every
     chunk of the windows before it. A T that is longer than a window and
     no whole number of them is padded to one (the padding lies after every
-    real row, so none attends it)."""
+    real row, so none attends it). ``flash_attention`` routes the windows
+    to the Pallas flash forward where it can tile one
+    (``flash_windowed_attention``); the XLA form (``windowed_attention``)
+    computes the same."""
+    from .. import config as _config
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
     kbar, vbar = ctx.input("KBar"), ctx.input("VBar")
     nh, w, c = ctx.attr("num_heads"), ctx.attr("window"), ctx.attr("chunk")
-    if w % c:
-        raise ValueError("a window of %d is no whole number of chunks of %d"
-                         % (w, c))
     b, t, dm = q.shape
     hd = dm // nh
     tp = t if t <= w else -(-t // w) * w
-    r = _largest_divisor(min(w, tp), _BLOCK_ROWS)
 
     def rows(x, n):
         x = jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0)))
         return x.reshape(b, n, nh, hd)
+    args = (rows(q, tp), rows(k, tp), rows(v, tp),
+            rows(kbar, -(-tp // c)), rows(vbar, -(-tp // c)))
     with jax.named_scope("eva.prefill"):
-        out = jax.vmap(lambda *a: windowed_attention(
-            *a, w, c, hd ** -0.5, r))(
-                rows(q, tp), rows(k, tp), rows(v, tp),
-                rows(kbar, -(-tp // c)), rows(vbar, -(-tp // c)))
+        out = None
+        if _config.get_flag("flash_attention"):
+            out = flash_windowed_attention(*args, w, c)
+            if out is None:
+                kernel_path.record("flash_attention")   # armed, not taken
+        if out is None:
+            r = _largest_divisor(min(w, tp), _BLOCK_ROWS)
+            out = jax.vmap(lambda *a: windowed_attention(
+                *a, w, c, hd ** -0.5, r))(*args)
     return {"Out": out[:, :t].reshape(b, t, dm)}
 
 
@@ -181,7 +262,6 @@ def _eva_attention_decode_paged(ctx):
     ``flash_attention`` routes both walks to the Pallas kernel; the XLA
     fallback gathers the same rows."""
     from .. import config as _config
-    from . import pallas_attention as _pa
     q = ctx.input("Q")
     pos = ctx.input("Pos").reshape(-1).astype(jnp.int32)
     nh, w, c = ctx.attr("num_heads"), ctx.attr("window"), ctx.attr("chunk")

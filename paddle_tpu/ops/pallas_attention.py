@@ -229,15 +229,15 @@ def _k_block(i, j, bq, bk, causal):
     return jnp.minimum(j, (i * bq + bq - 1) // bk) if causal else j
 
 
-def _forward(q, k, v, seg, causal, bq, bk, interpret, with_lse=False):
-    """The forward kernel on blocks of ``bq`` query and ``bk`` key rows;
-    ``with_lse``: also the [BH, 1, T] float32 log-sum-exp of every query
-    row (``bq`` whole lane tiles then). q, k and v go to the MXU in one
-    operand dtype, the widest of theirs: bfloat16 inputs as they are."""
+def _forward(q, k, v, seg, causal, bq, bk, interpret, with_lse=False,
+             out_dtype=None):
+    """The forward kernel on ``bq`` x ``bk`` rows, operands in the widest of
+    their dtypes; ``with_lse``: also every query row's log-sum-exp [BH, 1, T]
+    float32 (``bq`` whole lane tiles); ``out_dtype``: o's, default q's."""
     from jax.experimental.pallas import tpu as pltpu
     bh, t, d = q.shape
     segs, seg_specs = _seg_rows(seg)
-    out_shape = jax.ShapeDtypeStruct((bh, t, d), q.dtype)
+    out_shape = jax.ShapeDtypeStruct((bh, t, d), out_dtype or q.dtype)
     out_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
     if with_lse:
         out_shape = (out_shape,
@@ -916,3 +916,23 @@ def flash_attention(q, k, v, causal=False, segment_ids=None,
                  interpret)
     out = out.reshape(b, h, t, d)
     return out[0] if squeeze else out
+
+
+def flash_attention_stats(q, k, v, causal=False, interpret=None):
+    """The flash forward for an op that merges its result with a second
+    attention's under one softmax (inference only, no vjp): q, k, v
+    [BH, T, D] -> ``(o [BH, T, D], lse [BH, 1, T])``, both float32: the
+    kernel's own sums over its normaliser, not rounded to the operands'
+    dtype, and every query row's log-sum-exp, which stands for the part's
+    maximum and sum in :func:`merge_walks` (maximum ``lse``, sum 1). The
+    forward's body as it is; None where :func:`_tiles` finds no blocks of
+    whole lane tiles for T (the caller keeps its XLA form, and counts
+    it)."""
+    if interpret is None:
+        interpret = kernel_path.interpret_mode()
+    tiles = _tiles(q, k, None, False, lanes=True)
+    if tiles is None:
+        return None
+    kernel_path.record("flash_attention", interpret)
+    return _forward(q, k, v, None, causal, *tiles, interpret, with_lse=True,
+                    out_dtype=jnp.float32)

@@ -249,6 +249,87 @@ def test_decode_attention_paged_compiles_a_walk_to_be_merged(chip, aligned):
         re.search(r"f32\[24,32,128\]", hlo), hlo[-2000:]
 
 
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_flash_attention_forward_compiles_with_its_statistics(chip, dtype):
+    """evabyte-serve-offline's prefill (ops/eva_ops.py): the four windows
+    of an 8,192 bucket under 32 heads are 128 causal sequences of 2,048
+    rows; the kernel hands back its float32 sums unrounded and every
+    row's log-sum-exp, in tiles the chip's compiler accepts."""
+    def fn(q, k, v):
+        return pa.flash_attention_stats(q, k, v, causal=True,
+                                        interpret=False)
+    qkv = ((128, 2048, 128), dtype)
+    hlo = _compile(chip, fn, qkv, qkv, qkv)
+    assert _has_kernel(hlo, "flash_attention_fwd")
+    call = re.search(r"%flash_attention_fwd(?:\.\d+)? = [^\n]*", hlo).group()
+    assert "f32[128,2048,128]" in call and "f32[128,1,2048]" in call
+
+
+@pytest.fixture(scope="module")
+def evabyte_prefill(chip):
+    """evabyte-serve-offline's 8,192 bucket as the executor compiles it for
+    the described chip: the configuration at its published widths and its
+    deployment's 24 slots and pools, cut to two layers (every layer
+    compiles alike). -> (memory, HLO), and the kernel paths counted."""
+    import numpy as np
+    import paddle_tpu as ptpu
+    from benchmarks import architectures
+    from benchmarks.harness import common, lm
+    from benchmarks.sweeps import sizing
+    cfg = lm.load_config("evabyte-6.5b-l8")
+    cfg.update(num_hidden_layers=2)
+    arch = architectures.load(cfg)
+    geometry = cfg["deployment"]["serving"]
+    patch = pytest.MonkeyPatch()
+    patch.setattr(kernel_path, "interpret_mode", lambda: False)
+    before = kernel_path.counts()
+    try:
+        with lm.flags(generation_kv_dtype=geometry["kv_dtype"],
+                      matmul_precision="BF16_BF16_F32", **cfg["flags"]):
+            with ptpu.unique_name.guard():
+                startup = arch.serve_startup(cfg, 0)
+            spec = arch.serve_spec(cfg, geometry, (8192,))
+            scope = sizing._ShapeScope([startup], more=spec.cache_vars)
+            feed = {"gen.ptok": np.zeros((1, 8192), "int64"),
+                    "gen.plen": np.ones((1,), "int32"),
+                    "gen.ppos": np.zeros((1,), "int32"),
+                    "gen.phist": np.zeros((1,), "int32"),
+                    "gen.ppix": np.zeros((8192,), "int32")}
+            feed.update({k.prefill_table: np.zeros((spec.max_blocks,), "int32")
+                         for k in spec.cache_kinds})
+            out = sizing._compile(
+                ptpu.Executor(), spec.prefill_programs[8192], feed,
+                [spec.prefill_fetch], scope, chip)
+    finally:
+        patch.undo()
+    return out, common.kernel_paths_since(before)
+
+
+def test_evabyte_prefill_attends_its_windows_through_the_flash_forward(
+        evabyte_prefill):
+    """8,192 positions: one Mosaic call a layer over the 4 x 32 windows
+    (the float32 result and the rows' statistics), counted ``compiled``
+    once a layer, building the programs counted nothing (the op says its
+    output's shape), no ``xla``; the XLA form's scores ``[32, 256, 2048 +
+    512]`` are gone and the summaries' are a window's rows against the
+    128, 256 and 384 summaries before it, never all rows against all
+    (403 MB). The temporaries stay what the pools' scatter makes them
+    (1,150,553,088 B on the parent, my sandbox compile, PR 43), in
+    neither attention."""
+    (mem, hlo), paths = evabyte_prefill
+    calls = re.findall(r"%flash_attention_fwd(?:\.\d+)? = [^\n]*"
+                       r"tpu_custom_call", hlo)
+    assert len(calls) == 2
+    assert all("f32[128,2048,128]" in c and "f32[128,1,2048]" in c
+               for c in calls)
+    assert paths["flash_attention"] == {"compiled": 2}
+    assert not re.search(r"f32\[32,256,\d+\]", hlo)
+    for summaries in (128, 256, 384):
+        assert "f32[32,2048,%d]" % summaries in hlo
+    assert not re.search(r"f32\[(1,)?32,8192,512\]", hlo)
+    assert mem["temp_bytes"] < 1.16e9, mem
+
+
 def test_exact_products_keep_their_three_pieces(chip):
     """ops/moe_ops.py exact_dot on the chip's compiler: the float32
     activation reaches the bfloat16 weight as 3 x the rows in one

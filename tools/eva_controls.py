@@ -11,8 +11,10 @@ little it passes:
   prefill, the decode step): the chunk pool zeroed;
 * ``--control mean``: a chunk is pooled by its mean, not by the two learned
   softmaxes;
-* ``--control unseen``: the decode step walks the window pool alone (the
-  summaries are written and never attended);
+* ``--control unseen``: the decode step walks the window pool alone, and
+  a prefill through the flash forward attends its windows alone (the
+  summaries are written and never merged in: ``merge_walks`` is the one
+  merge of both);
 * ``--control none``: the configuration as it is.
 
     python3 tools/eva_controls.py --control zeroed --seed 4200000011

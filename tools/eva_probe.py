@@ -9,7 +9,12 @@ counts both; PERF.md section 7, PR 42):
   of ``--contexts`` positions a slot: the window pool's rows from the
   window's first position on, a summary for every chunk before it;
 * the prefill form (``eva_summaries`` + ``eva_attention``) over a prompt of
-  each of ``--prompts`` positions.
+  each of ``--prompts`` positions, in both of its forms in one call: the
+  XLA form (``flash_attention`` off) and the flash form (the windows
+  through the flash forward), each beside the path ``flash_attention``
+  counted, and the largest difference between the two results
+  (``--profile 1``: and where the flash form's time goes, operation by
+  operation of a traced call).
 
     python3 tools/eva_probe.py
 
@@ -48,6 +53,32 @@ def _time(fn, args, reps):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / reps * 1e3, out
+
+
+def _profile(fn, args, reps=5, top=16):
+    """The device's operations over ``reps`` traced calls of ``fn``, by
+    self time a call, largest first (the benchmark's own reduction:
+    ``benchmarks/harness/trace_reduce.py``)."""
+    import shutil
+    import jax
+    from benchmarks.harness import trace_reduce
+    out = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".bench_out", "eva_probe", "trace")
+    shutil.rmtree(out, ignore_errors=True)
+    jax.block_until_ready(fn(*args))
+    with jax.profiler.trace(out):
+        for _ in range(reps):
+            last = fn(*args)
+        jax.block_until_ready(last)
+    trace = trace_reduce.load_xplane(trace_reduce.find_xplane(out))
+    ops = trace_reduce._device_ops(
+        trace, rehearsal=jax.default_backend() != "tpu")[0]
+    by_name = {}
+    for name, _, ns in trace_reduce.self_times(
+            trace_reduce._clip(ops, 0, 1 << 62)):
+        by_name[name] = by_name.get(name, 0) + ns
+    return [[name, round(ns / reps / 1e6, 4)] for name, ns in
+            sorted(by_name.items(), key=lambda kv: -kv[1])[:top]]
 
 
 def _shares(ms, flops, nbytes):
@@ -109,9 +140,12 @@ def decode(cfg, arch, slots, contexts, reps, seed):
             **_shares(ms, ops, nbytes))
 
 
-def prefill(cfg, arch, prompts, reps, seed):
+def prefill(cfg, arch, prompts, reps, seed, profile):
     import jax
     import jax.numpy as jnp
+    import paddle_tpu as ptpu
+    from benchmarks.harness import common
+    from paddle_tpu.ops import kernel_path
     nh, d = cfg["num_attention_heads"], cfg["hidden_size"]
     w, c = cfg["window_size"], cfg["chunk_size"]
     rs = np.random.RandomState(seed % (2 ** 31))
@@ -122,15 +156,31 @@ def prefill(cfg, arch, prompts, reps, seed):
         mu, phi = (jnp.asarray(rs.standard_normal(d) * 0.05, bf16)
                    for _ in range(2))
 
-        def call(q, k, v, mu, phi):
+        def call(flash, q, k, v, mu, phi):
+            # the flag is read when the op is traced: a trace a form
+            ptpu.config.set_flags(flash_attention=flash)
             s = _op("eva_summaries", {"num_heads": nh, "chunk": c}, K=k,
                     V=v, Mu=mu, Phi=phi)
             return _op("eva_attention",
                        {"num_heads": nh, "window": w, "chunk": c}, Q=q, K=k,
                        V=v, KBar=s["KBar"], VBar=s["VBar"])["Out"]
-        ms, _ = _time(jax.jit(call), (q, k, v, mu, phi), reps)
         ops, nbytes = arch.eva_prefill_ops_and_bytes(cfg, t, 2)
-        say(what="eva_prefill", tokens=t, **_shares(ms, ops, nbytes))
+        outs = {}
+        for form, flash in (("xla", False), ("flash", True)):
+            before = kernel_path.counts()
+            ms, outs[form] = _time(jax.jit(functools.partial(call, flash)),
+                                   (q, k, v, mu, phi), reps)
+            say(what="eva_prefill", form=form, tokens=t,
+                flash_attention=common.kernel_paths_since(before).get(
+                    "flash_attention", {}), **_shares(ms, ops, nbytes))
+        if profile:
+            say(what="eva_prefill_device_ops_ms", form="flash", tokens=t,
+                ops=_profile(jax.jit(functools.partial(call, True)),
+                             (q, k, v, mu, phi)))
+        ptpu.config.set_flags(flash_attention=True)
+        say(what="eva_prefill_forms", tokens=t, max_abs_diff=float(jnp.max(
+            jnp.abs(outs["flash"] - outs["xla"]))),
+            max_abs=float(jnp.max(jnp.abs(outs["xla"]))))
 
 
 def main(argv=None):
@@ -142,6 +192,9 @@ def main(argv=None):
     ap.add_argument("--prompts", default="2048,4096,8192")
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=4200000021)
+    ap.add_argument("--profile", type=int, default=0,
+                    help="1: also trace the prefill's flash form and print "
+                    "its device operations by time")
     ap.add_argument("--rehearse", type=int, default=0)
     args = ap.parse_args(argv)
     import jax
@@ -160,7 +213,7 @@ def main(argv=None):
     decode(cfg, arch, slots, [int(x) for x in args.contexts.split(",")],
            args.reps, args.seed)
     prefill(cfg, arch, [int(x) for x in args.prompts.split(",")], args.reps,
-            args.seed)
+            args.seed, args.profile)
     return 0
 
 
