@@ -18,7 +18,9 @@ positions this is causal softmax attention.
 * ``eva_summaries`` — the two poolings, of a sequence's rows (every whole
   chunk of it) or, for a decode step, of the window pool's block that holds
   each slot's newest row. The pooling is a function of the chunk's rows
-  alone, so a step that runs twice writes the same summary twice.
+  alone, so a step that runs twice writes the same summary twice. The
+  rows are pooled as they are held, ``[.., chunk, H*D]``, never split into
+  heads (``pool_chunks``).
 * ``eva_attention`` — whole sequences and a prompt's prefill, as two
   attentions merged under one softmax, like the decode form: every window
   is a causal sequence of its own, so the windows go through the flash
@@ -52,6 +54,7 @@ import jax.numpy as jnp
 from ..core.registry import register_op
 from . import kernel_path, pallas_attention as _pa
 from .generation_ops import _largest_divisor
+from .moe_ops import _pieces
 from .pallas_attention import cache_precision
 
 # queries the XLA whole-sequence form takes at a time: float32 scores of
@@ -59,17 +62,49 @@ from .pallas_attention import cache_precision
 _BLOCK_ROWS = 256
 
 
-def pool_chunks(k, v, mu, phi):
-    """k, v [.., C, H, D] (a chunk's rows), mu, phi [H, D] ->
-    (kbar, vbar) [.., H, D] float32: the rows' sums under the softmaxes of
-    ``mu . k`` and ``phi . k`` over the chunk, unscaled."""
-    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+def pool_chunks(k, v, mu, phi, num_heads, chunk):
+    """k, v [.., T, H*D] (rows as they are held, T a whole number of
+    chunks), mu, phi [H*D] -> (kbar, vbar) [.., T / chunk, H*D] float32:
+    per head and chunk the rows' sums under the softmaxes of ``mu . k``
+    and ``phi . k`` over the chunk, unscaled.
 
-    def weights(w):
-        s = jnp.sum(k32 * w.astype(jnp.float32), axis=-1, keepdims=True)
-        return jax.nn.softmax(s, axis=-3)               # over the C rows
-    return (jnp.sum(weights(mu) * k32, axis=-3),
-            jnp.sum(weights(phi) * v32, axis=-3))
+    The rows are never split into heads, nor reshaped before they are
+    read: on the chip ``[.., C, H*D] -> [.., C, H, D]`` moves the tiled
+    pair of dimensions from (rows, lanes) to (heads, lanes), a copy of
+    every row, in float32 once the rows were made float32 for it (2.0 ms a
+    layer at 8,192 positions: PERF.md, PR 44). What is per head goes
+    through the ``[H*D, H]`` indicator of the heads' lanes instead: both
+    poolings' scores are ONE product of the rows with the indicator's
+    columns weighed by ``mu`` and by ``phi`` (``[.., T, 2H]``; the
+    operands as they arrive, float32 ones at the highest precision,
+    float32 sums), the softmaxes run over each chunk's rows, and the
+    weights go back over their head's lanes by the indicator's transpose,
+    exactly: a float32 weight is three bfloat16 pieces that add up to it
+    (``moe_ops._pieces``), stacked along the product's contraction, each
+    times a one. The weighted sum over a chunk's rows is then elementwise
+    on the rows as they lie."""
+    t, dm = k.shape[-2:]
+    chunks = k.shape[:-2] + (t // chunk, chunk)
+    heads = jnp.arange(dm, dtype=jnp.int32)[:, None] // (dm // num_heads) \
+        == jnp.arange(num_heads, dtype=jnp.int32)[None, :]     # [H*D, H]
+    dt = jnp.result_type(k.dtype, mu.dtype)
+    both = jnp.concatenate([heads * mu.astype(dt)[:, None],
+                            heads * phi.astype(dt)[:, None]], axis=1)
+    s = jnp.einsum("...tl,lh->...th", k.astype(dt), both,
+                   precision=cache_precision(dt),
+                   preferred_element_type=jnp.float32)
+    w = jax.nn.softmax(s.reshape(chunks + (2 * num_heads,)), axis=-2) \
+        .reshape(s.shape)                           # over a chunk's rows
+    ones = jnp.tile(heads.T.astype(jnp.bfloat16), (3, 1))      # [3H, H*D]
+
+    def pooled(w, rows):
+        lanes = jnp.einsum("...th,hl->...tl",
+                           jnp.concatenate(_pieces(w), axis=-1), ones,
+                           precision=jax.lax.Precision.DEFAULT,
+                           preferred_element_type=jnp.float32)
+        return jnp.sum((lanes * rows.astype(jnp.float32))
+                       .reshape(chunks + (dm,)), axis=-2)
+    return pooled(w[..., :num_heads], k), pooled(w[..., num_heads:], v)
 
 
 @register_op("eva_summaries")
@@ -83,18 +118,15 @@ def _eva_summaries(ctx):
     it is worth before the block is full is the writer's to drop). Out in
     the rows' dtype."""
     nh, c = ctx.attr("num_heads"), ctx.attr("chunk")
-    mu = ctx.input("Mu").reshape(nh, -1)
-    phi = ctx.input("Phi").reshape(nh, -1)
+    mu, phi = ctx.input("Mu").reshape(-1), ctx.input("Phi").reshape(-1)
     with jax.named_scope("eva.summaries"):
         if ctx.has_input("K"):
             k, v = ctx.input("K"), ctx.input("V")
             b, t, dm = k.shape
             n = -(-t // c)
             whole = ((0, 0), (0, n * c - t), (0, 0))
-            kbar, vbar = pool_chunks(
-                jnp.pad(k, whole).reshape(b, n, c, nh, -1),
-                jnp.pad(v, whole).reshape(b, n, c, nh, -1), mu, phi)
-            shape = (b, n, dm)
+            kbar, vbar = pool_chunks(jnp.pad(k, whole), jnp.pad(v, whole),
+                                     mu, phi, nh, c)
         else:
             ck, cv = ctx.input("CacheK"), ctx.input("CacheV")
             nb, bs, dm = ck.shape
@@ -106,12 +138,9 @@ def _eva_summaries(ctx):
             s = pos.shape[0]
             blk = jnp.clip(table[jnp.arange(s), jnp.clip(
                 pos // bs, 0, table.shape[1] - 1)], 0, nb - 1)
-            k = ck[blk].reshape(s, c, nh, -1)
-            kbar, vbar = pool_chunks(k, cv[blk].reshape(s, c, nh, -1),
-                                     mu, phi)
-            shape = (s, 1, dm)
-    return {"KBar": kbar.reshape(shape).astype(k.dtype),
-            "VBar": vbar.reshape(shape).astype(k.dtype)}
+            k = ck[blk]
+            kbar, vbar = pool_chunks(k, cv[blk], mu, phi, nh, c)
+    return {"KBar": kbar.astype(k.dtype), "VBar": vbar.astype(k.dtype)}
 
 
 def windowed_attention(q, k, v, kbar, vbar, window, chunk, scale, r):

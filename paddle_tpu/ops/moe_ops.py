@@ -26,7 +26,9 @@ pays three times the products.
 * ``rotary_embedding`` — rotary positions on ``[B, T, H*D]`` in the
   half-split pairing (lane ``i`` of a head turns with lane ``i + D/2``),
   positions along the time axis (a prompt window, a training batch) or one
-  per batch row (a decode step); optionally over a range of each head's
+  per batch row (a decode step: its rows come from behind an optimization
+  barrier, so that the projection before it reads its weight where it
+  lies, :func:`_fence_rows`); optionally over a range of each head's
   lanes only, with YaRN-blended frequencies (:func:`rotary_frequencies`).
 * ``moe_ffn`` — route, sort by expert, grouped matmul (exact), weighted
   combine. The grouped matmuls run in ``pallas_moe``'s kernels, which
@@ -57,10 +59,21 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
+from ..observability import metrics as _metrics
 from . import kernel_path, pallas_moe
 
 
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+# trace-time only, like paddle_executor_fenced_updates_total: counts when a
+# step is traced (every compile), never on the steady-state path
+_ROTARY_FENCED = _metrics.REGISTRY.counter(
+    "paddle_rotary_fenced_total",
+    "Rotary turns of a decode step (one position a batch row) traced with "
+    "their input behind an optimization barrier, so that XLA leaves the "
+    "head split out of the projection that made the input and reads that "
+    "projection's weight where it lies",
+    labelnames=("op",))
 
 
 def _pieces(x):
@@ -174,6 +187,24 @@ def rotary_frequencies(rot, theta, yarn=None):
     return freq * (1.0 - ramp) + freq / yarn["factor"] * ramp
 
 
+def _fence_rows(ctx, x):
+    """``x`` [B, 1, H*D], a decode step's rows on their way into a per-head
+    op, behind an optimization barrier (the identity on values).
+
+    Why: the op splits the lanes into heads (``[B, 1, H, D]``), and left
+    alone XLA fuses that split into the result of the projection that made
+    ``x``, wants the projection's weight as ``[H, D, d]`` for it and copies
+    the weight into that layout in every step: 33.5 MB written and read
+    again to spare re-tiling 0.4 MB of rows (PERF.md, PR 44). A step's
+    rows are the slots, always far fewer than a weight's, so the weight's
+    side is never the cheaper one to relay: behind the barrier the product
+    is compiled apart and reads its weight as it lies. Counted where the
+    executor traces a step, not where a program's shapes are inferred."""
+    if ctx.trace is not None:
+        _ROTARY_FENCED.labels(op=ctx.op.type).inc()
+    return jax.lax.optimization_barrier(x)
+
+
 @register_op("rotary_embedding")
 def _rotary_embedding(ctx):
     """X [B, T, H*D], Pos int (optional): [T] positions along the time
@@ -181,8 +212,13 @@ def _rotary_embedding(ctx):
     the positions are 0..T-1. attrs head_dim, theta; where the model has
     them ``lanes`` (lo, hi): the lanes of each head that turn (absent:
     all; the others pass), and ``yarn`` (:func:`rotary_frequencies`).
-    Out: X's shape and dtype, the angles taken in float32."""
+    Out: X's shape and dtype, the angles taken in float32.
+
+    A ``per_row`` turn (a decode step: a row a slot) takes X from behind
+    an optimization barrier (:func:`_fence_rows`)."""
     x = ctx.input("X")
+    if ctx.attr("per_row", False):
+        x = _fence_rows(ctx, x)
     hd = ctx.attr("head_dim")
     theta = ctx.attr("theta", 10000.0)
     lo, hi = ctx.attr("lanes") or (0, hd)

@@ -224,10 +224,11 @@ def test_the_check_sees_a_pooling_by_the_mean(model, monkeypatch):
     """The two softmaxes replaced by the chunk's mean, in the program (the
     prefill's summaries and the decode step's): over ten times the
     tolerance."""
-    def mean(k, v, mu, phi):
-        del mu, phi
-        return (jnp.mean(k.astype(jnp.float32), axis=-3),
-                jnp.mean(v.astype(jnp.float32), axis=-3))
+    def mean(k, v, mu, phi, num_heads, chunk):
+        del mu, phi, num_heads
+        chunks = k.shape[:-2] + (-1, chunk, k.shape[-1])
+        return (jnp.mean(k.astype(jnp.float32).reshape(chunks), axis=-2),
+                jnp.mean(v.astype(jnp.float32).reshape(chunks), axis=-2))
     monkeypatch.setattr(eva_ops, "pool_chunks", mean)
     assert _worst_error(model, lambda sess: None) > 10 * TOL
 
@@ -504,7 +505,9 @@ def test_pool_chunks_is_the_two_softmaxes():
     rs = np.random.RandomState(6)
     k, v = rs.randn(5, C, 4, 16), rs.randn(5, C, 4, 16)
     mu, phi = rs.randn(4, 16), rs.randn(4, 16)
-    kbar, vbar = eva_ops.pool_chunks(k, v, mu, phi)
+    kbar, vbar = (x.reshape(5, 4, 16) for x in eva_ops.pool_chunks(
+        *(jnp.asarray(x.reshape(-1, 64), jnp.float32) for x in (k, v)),
+        *(jnp.asarray(x.reshape(64), jnp.float32) for x in (mu, phi)), 4, C))
     rk, rv = ref.summaries(jnp.asarray(k.reshape(5 * C, 4, 16), jnp.float32),
                            jnp.asarray(v.reshape(5 * C, 4, 16), jnp.float32),
                            jnp.asarray(mu, jnp.float32),
@@ -681,3 +684,102 @@ def test_programs_of_the_three_modes_hold_the_ops(model):
     row = block.var(bench_lm.logits_var(spec.decode_program,
                                         spec.decode_fetch))
     assert tuple(row.shape) == (2, V)
+
+
+# -- a decode step's rotary turns take their rows from behind a fence --------
+
+def _traced(exe, program, feed, fetch, scope):
+    """(barriers in the traced step, rotary turns counted fenced by that
+    trace, the jittable step and its arguments)."""
+    fn, args = exe.as_jax_function(program, feed, fetch, scope=scope)
+    before = _counter("paddle_rotary_fenced_total")
+    jaxpr = str(jax.make_jaxpr(fn)(*args))
+    return (jaxpr.count("optimization_barrier"),
+            _counter("paddle_rotary_fenced_total") - before, fn, args)
+
+
+def _shaped_feed(program, names):
+    block = program.global_block()
+    return {n: np.ones(block.var(n).shape, "int32") for n in names}
+
+
+def test_a_decode_step_is_traced_with_its_rotary_rows_fenced(model,
+                                                             monkeypatch):
+    """The q and the k of each of the three layers go into their rotary
+    turn from behind an ``optimization_barrier`` (so that XLA reads the
+    two projections' weights where they lie: PERF.md, PR 44), the counter
+    moves by as many at the trace, and the step's logits equal, bit for
+    bit, those of the same step traced with the fence patched out, which
+    moves no counter."""
+    sess = _session(model)
+    spec = sess.spec
+    slot, _ = sess.admit(_tokens(9)[:37])
+    feed = sess.step_prepare()[2]
+    name = bench_lm.logits_var(spec.decode_program, spec.decode_fetch)
+    rotary = [op for op in spec.decode_program.global_block().ops
+              if op.type == "rotary_embedding"]
+    assert len(rotary) == 6 and all(op.attrs["per_row"] for op in rotary)
+
+    barriers, counted, fn, args = _traced(
+        sess.exe, spec.decode_program, feed, [name], sess.scope)
+    assert (barriers, counted) == (6, 6)
+    fenced = np.asarray(jax.jit(fn)(*args)[0])
+
+    monkeypatch.setattr(moe_ops, "_fence_rows", lambda ctx, x: x)
+    barriers, counted, fn, args = _traced(
+        sess.exe, spec.decode_program, feed, [name], sess.scope)
+    assert (barriers, counted) == (0, 0)
+    plain = np.asarray(jax.jit(fn)(*args)[0])
+    assert np.abs(plain[slot]).max() > 0.1
+    assert np.array_equal(fenced, plain)
+    sess.close()
+
+
+@pytest.mark.parametrize("what", ["prefill", "whole"])
+def test_only_a_steps_rows_are_fenced(model, what):
+    """A prefill's rotary turns (positions along the time axis) and a
+    whole-sequence program's (no positions fed) are traced as they were:
+    no barrier, no count. There the rows are as many as a weight's."""
+    if what == "prefill":
+        sess = _session(model)
+        spec = sess.spec
+        program = spec.prefill_programs[32]
+        feed = _shaped_feed(program, list(spec.prefill_feeds[:6]) + [
+            k.prefill_table for k in spec.cache_kinds])
+        exe, scope, fetch = sess.exe, sess.scope, [spec.prefill_fetch]
+    else:
+        scope, program, name = model
+        seq = _tokens(1)[None]
+        exe, feed, fetch = ptpu.Executor(), {"toks": seq, "lbls": seq}, [name]
+    rotary = [op for op in program.global_block().ops
+              if op.type == "rotary_embedding"]
+    assert len(rotary) == 6 and not any(op.attrs["per_row"] for op in rotary)
+    assert _traced(exe, program, feed, fetch, scope)[:2] == (0, 0)
+
+
+def test_a_rotary_turn_of_rows_counts_where_a_step_is_traced_only():
+    """A ``per_row`` turn built into a program: inferring its shape (the
+    program's build) counts nothing, the executor's trace counts one, and
+    the turn is the one the time-axis form makes of the same positions."""
+    x = np.random.RandomState(2).randn(3, 1, 32).astype("float32")
+    pos = np.array([5, 0, 17], np.int32)
+    before = _counter("paddle_rotary_fenced_total")
+
+    def build():
+        xv = layers.data("x", shape=[3, 1, 32], append_batch_size=False)
+        pv = layers.data("pos", shape=[3], dtype="int32",
+                         append_batch_size=False)
+        out = layers.rotary_embedding(xv, 8, pos=pv, per_row=True)
+        assert _counter("paddle_rotary_fenced_total") == before
+        return [out]
+    (rows,), _ = _run(build, {"x": x, "pos": pos})
+    assert _counter("paddle_rotary_fenced_total") - before == 1
+
+    def along():
+        xv = layers.data("x", shape=[1, 3, 32], append_batch_size=False)
+        pv = layers.data("pos", shape=[3], dtype="int32",
+                         append_batch_size=False)
+        return [layers.rotary_embedding(xv, 8, pos=pv)]
+    (time,), _ = _run(along, {"x": x.reshape(1, 3, 32), "pos": pos})
+    assert _counter("paddle_rotary_fenced_total") - before == 1
+    assert np.array_equal(rows.reshape(3, 32), time.reshape(3, 32))

@@ -11,6 +11,7 @@ Skipped as a whole where the topology cannot be described (no libtpu).
 
 import os
 import re
+import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
 
@@ -20,6 +21,11 @@ import pytest
 
 from paddle_tpu.ops import kernel_path, pallas_attention as pa
 from paddle_tpu.ops import pallas_conv_bn, quant_ops
+
+sys.path.insert(0, os.path.join(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__))), "tools"))
+
+import hlo_census  # noqa: E402
 
 F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
 
@@ -265,44 +271,37 @@ def test_flash_attention_forward_compiles_with_its_statistics(chip, dtype):
     assert "f32[128,2048,128]" in call and "f32[128,1,2048]" in call
 
 
-@pytest.fixture(scope="module")
-def evabyte_prefill(chip):
-    """evabyte-serve-offline's 8,192 bucket as the executor compiles it for
-    the described chip: the configuration at its published widths and its
-    deployment's 24 slots and pools, cut to two layers (every layer
-    compiles alike). -> (memory, HLO), and the kernel paths counted."""
-    import numpy as np
-    import paddle_tpu as ptpu
-    from benchmarks import architectures
-    from benchmarks.harness import common, lm
-    from benchmarks.sweeps import sizing
-    cfg = lm.load_config("evabyte-6.5b-l8")
-    cfg.update(num_hidden_layers=2)
-    arch = architectures.load(cfg)
-    geometry = cfg["deployment"]["serving"]
+def _evabyte_program(chip, program):
+    """evabyte-serve-offline's decode program or a prefill bucket as the
+    executor compiles it for the described chip (``tools/hlo_census.py``):
+    the configuration at its published widths and its deployment's 24
+    slots and pools, cut to two layers (every layer compiles alike). ->
+    ((memory, HLO), the kernel paths counted, the pools' names, the rotary
+    turns counted fenced)."""
+    from benchmarks.harness import common
     patch = pytest.MonkeyPatch()
     patch.setattr(kernel_path, "interpret_mode", lambda: False)
     before = kernel_path.counts()
     try:
-        with lm.flags(generation_kv_dtype=geometry["kv_dtype"],
-                      matmul_precision="BF16_BF16_F32", **cfg["flags"]):
-            with ptpu.unique_name.guard():
-                startup = arch.serve_startup(cfg, 0)
-            spec = arch.serve_spec(cfg, geometry, (8192,))
-            scope = sizing._ShapeScope([startup], more=spec.cache_vars)
-            feed = {"gen.ptok": np.zeros((1, 8192), "int64"),
-                    "gen.plen": np.ones((1,), "int32"),
-                    "gen.ppos": np.zeros((1,), "int32"),
-                    "gen.phist": np.zeros((1,), "int32"),
-                    "gen.ppix": np.zeros((8192,), "int32")}
-            feed.update({k.prefill_table: np.zeros((spec.max_blocks,), "int32")
-                         for k in spec.cache_kinds})
-            out = sizing._compile(
-                ptpu.Executor(), spec.prefill_programs[8192], feed,
-                [spec.prefill_fetch], scope, chip)
+        mem, hlo, pools, fenced = hlo_census.compile_program(
+            "evabyte-6.5b-l8", 2, program, chip)
     finally:
         patch.undo()
-    return out, common.kernel_paths_since(before)
+    return (mem, hlo), common.kernel_paths_since(before), pools, fenced
+
+
+@pytest.fixture(scope="module")
+def evabyte_prefill(chip):
+    """The 8,192 bucket (feeds ``gen.ptok``, ``.plen``, ``.ppos``,
+    ``.phist``, ``.ppix`` and one ``[768]`` table a cache kind)."""
+    return _evabyte_program(chip, 8192)
+
+
+@pytest.fixture(scope="module")
+def evabyte_decode(chip):
+    """The decode program (feeds ``gen.dtok``, ``gen.dpos`` and one ``[24,
+    768]`` table a cache kind)."""
+    return _evabyte_program(chip, "decode")
 
 
 def test_evabyte_prefill_attends_its_windows_through_the_flash_forward(
@@ -316,7 +315,7 @@ def test_evabyte_prefill_attends_its_windows_through_the_flash_forward(
     (403 MB). The temporaries stay what the pools' scatter makes them
     (1,150,553,088 B on the parent, my sandbox compile, PR 43), in
     neither attention."""
-    (mem, hlo), paths = evabyte_prefill
+    (mem, hlo), paths = evabyte_prefill[:2]
     calls = re.findall(r"%flash_attention_fwd(?:\.\d+)? = [^\n]*"
                        r"tpu_custom_call", hlo)
     assert len(calls) == 2
@@ -328,6 +327,59 @@ def test_evabyte_prefill_attends_its_windows_through_the_flash_forward(
         assert "f32[32,2048,%d]" % summaries in hlo
     assert not re.search(r"f32\[(1,)?32,8192,512\]", hlo)
     assert mem["temp_bytes"] < 1.16e9, mem
+
+
+def test_evabyte_summaries_pool_the_rows_as_they_lie(evabyte_prefill):
+    """PR 44: ``eva_summaries`` never splits the rows into heads, so the
+    8,192 bucket holds no ``copy`` and no stand-alone ``convert`` that
+    makes a float32 array of the rows' size (8,192 x 32 x 128: the parent
+    held two such copies a layer, 134 MB each) under ``eva.summaries``;
+    the scores of both poolings are one product a layer (``[512, 16, 64]``
+    with its softmax in the product's fusion) and the weights' way back
+    over the lanes, the weighted sum over the rows and the cast are one
+    ``kOutput`` fusion a pooling; no rotary turn of a prefill is
+    fenced."""
+    (mem, hlo), _, pools, fenced = evabyte_prefill
+    rows = hlo_census.census(hlo, pools, min_bytes=1 << 20)
+    assert rows and all(r["op"] in hlo_census.OPCODES for r in rows)
+    # under eva.summaries or anywhere else in the bucket
+    assert not [r for r in rows if r["shape"].startswith("f32[")
+                and r["bytes"] >= 8192 * 32 * 128 * 4], rows
+    entry = re.sub(r"\{[\d,]*(?::[^}]*)?\}", "", hlo[hlo.index("\nENTRY "):])
+    products = re.findall(r" = ([^=\n]*) fusion\([^\n]*kind=kOutput[^\n]*"
+                          r"eva\.summaries", entry)
+    assert sum("f32[512,16,64]" in p for p in products) == 2, products
+    assert products.count("bf16[512,4096]") == 4, products
+    assert fenced == 0
+    assert mem["temp_bytes"] < 1.16e9, mem
+
+
+def test_evabyte_decode_step_reads_its_weights_where_they_lie(
+        evabyte_decode):
+    """PR 44: with the rotary turns' rows fenced (``moe_ops._fence_rows``,
+    counted 4: q and k of two layers) the decode step copies no ``[4096,
+    4096]`` projection into another layout (the parent: ``attn.q.w`` and
+    ``attn.k.w`` of every layer, 33.5 MB each, ``temp_bytes``
+    35,844,608) and holds no layout work of a megabyte at all, its
+    pooling of the newest blocks included; the first layer's q, k and v
+    weights are each read by the ``kOutput`` fusion of their product as
+    they lie; two walks a layer as before."""
+    (mem, hlo), paths, pools, fenced = evabyte_decode
+    assert fenced == 4
+    rows = hlo_census.census(hlo, pools)
+    assert not [r for r in rows if r["shape"] == "bf16[4096,4096]"], rows
+    assert not [r for r in rows if r["from"] == "parameter"], rows
+    assert not [r for r in rows if "eva_summaries" in
+                r["op_name"] + r["source"]], rows
+    assert mem["temp_bytes"] < 4 << 20, mem
+    entry = hlo[hlo.index("\nENTRY "):]
+    for part in "qkv":
+        assert re.search(
+            r" fusion\(%%state_ro__moe_lm_l0_attn_%s_w__[^\n]*kind=kOutput"
+            % part, entry), part
+    assert len(re.findall(r"%decode_attention_paged(?:\.\d+)? = [^\n]*"
+                          r"tpu_custom_call", hlo)) == 4
+    assert set(paths["decode_attention_paged"]) == {"compiled"}
 
 
 def test_exact_products_keep_their_three_pieces(chip):

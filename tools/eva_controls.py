@@ -43,22 +43,26 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def _pooled(change):
-    """``eva_ops.pool_chunks`` replaced by ``change(k, v, real)``."""
+    """``eva_ops.pool_chunks`` replaced by ``change(k, v, chunk, real)``."""
     from paddle_tpu.ops import eva_ops
     real = eva_ops.pool_chunks
     return [(eva_ops, "pool_chunks",
-             lambda k, v, mu, phi: change(k, v, lambda: real(k, v, mu, phi)))]
+             lambda k, v, mu, phi, nh, chunk: change(
+                 k, v, chunk, lambda: real(k, v, mu, phi, nh, chunk)))]
 
 
 def _zeroed():
-    return _pooled(lambda k, v, real: tuple(0.0 * x for x in real()))
+    return _pooled(lambda k, v, chunk, real: tuple(0.0 * x for x in real()))
 
 
 def _mean():
     import jax.numpy as jnp
-    return _pooled(lambda k, v, real: (
-        jnp.mean(k.astype(jnp.float32), axis=-3),
-        jnp.mean(v.astype(jnp.float32), axis=-3)))
+
+    def mean(rows, chunk):
+        chunks = rows.shape[:-2] + (-1, chunk, rows.shape[-1])
+        return jnp.mean(rows.astype(jnp.float32).reshape(chunks), axis=-2)
+    return _pooled(lambda k, v, chunk, real: (mean(k, chunk),
+                                              mean(v, chunk)))
 
 
 def _unseen():

@@ -14,7 +14,11 @@ counts both; PERF.md section 7, PR 42):
   through the flash forward), each beside the path ``flash_attention``
   counted, and the largest difference between the two results
   (``--profile 1``: and where the flash form's time goes, operation by
-  operation of a traced call).
+  operation of a traced call);
+* the pooling alone (``eva_summaries``, which the prefill row times with
+  the attention) over the same prompts and in the decode step's form (the
+  newest block of each of ``--slots`` slots through a table), its bytes
+  counted as one read of the bfloat16 rows and one write of the summaries.
 
     python3 tools/eva_probe.py
 
@@ -183,6 +187,51 @@ def prefill(cfg, arch, prompts, reps, seed, profile):
             max_abs=float(jnp.max(jnp.abs(outs["xla"]))))
 
 
+def pooling(cfg, slots, prompts, reps, seed, profile):
+    """``eva_summaries`` alone: a prompt's rows [1, T, H*D] -> a summary a
+    chunk, and the decode step's form (CacheK / CacheV, Pos, Table -> the
+    block that holds each slot's newest row). Bytes: K and V read once, the
+    two summaries written once, at 2 bytes; FLOPs: two scores, two
+    softmaxes' sums and two weighted sums a lane, counted as 8 a lane."""
+    import jax
+    import jax.numpy as jnp
+    nh, d, c = cfg["num_attention_heads"], cfg["hidden_size"], \
+        cfg["chunk_size"]
+    attrs = {"num_heads": nh, "chunk": c}
+    rs = np.random.RandomState(seed % (2 ** 31))
+    bf16 = jnp.bfloat16
+    mu, phi = (jnp.asarray(rs.standard_normal(d) * 0.05, bf16)
+               for _ in range(2))
+
+    def counted(rows):
+        return 8 * rows * d, 2 * 2 * (rows + rows // c) * d
+
+    def summaries(*slots):
+        def call(*arrays):
+            s = _op("eva_summaries", attrs, Mu=mu, Phi=phi,
+                    **dict(zip(slots, arrays)))
+            return s["KBar"], s["VBar"]
+        return jax.jit(call)
+
+    def report(fn, args, rows, **what):
+        ms, _ = _time(fn, args, reps)
+        say(what="eva_pooling", **what, **_shares(ms, *counted(rows)))
+        if profile:
+            say(what="eva_pooling_device_ops_ms", **what,
+                ops=_profile(fn, args))
+    for t in prompts:
+        k, v = (jnp.asarray(rs.standard_normal((1, t, d)) * 0.8, bf16)
+                for _ in range(2))
+        report(summaries("K", "V"), (k, v), t, form="rows", tokens=t)
+    nb = slots * 8
+    ck, cv = (jnp.asarray(rs.standard_normal((nb, c, d)) * 0.8, bf16)
+              for _ in range(2))
+    table = jnp.asarray(rs.permutation(nb).reshape(slots, 8), jnp.int32)
+    pos = jnp.asarray(rs.randint(0, 8 * c, slots), jnp.int32)
+    report(summaries("CacheK", "CacheV", "Pos", "Table"),
+           (ck, cv, pos, table), slots * c, form="blocks", slots=slots)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="evabyte-6.5b-l8")
@@ -193,8 +242,8 @@ def main(argv=None):
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=4200000021)
     ap.add_argument("--profile", type=int, default=0,
-                    help="1: also trace the prefill's flash form and print "
-                    "its device operations by time")
+                    help="1: also trace the prefill's flash form and the "
+                    "pooling and print their device operations by time")
     ap.add_argument("--rehearse", type=int, default=0)
     args = ap.parse_args(argv)
     import jax
@@ -212,8 +261,9 @@ def main(argv=None):
         config=cfg["name"], slots=slots)
     decode(cfg, arch, slots, [int(x) for x in args.contexts.split(",")],
            args.reps, args.seed)
-    prefill(cfg, arch, [int(x) for x in args.prompts.split(",")], args.reps,
-            args.seed, args.profile)
+    prompts = [int(x) for x in args.prompts.split(",")]
+    prefill(cfg, arch, prompts, args.reps, args.seed, args.profile)
+    pooling(cfg, slots, prompts, args.reps, args.seed, args.profile)
     return 0
 
 
