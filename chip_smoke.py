@@ -41,7 +41,7 @@ import numpy as np
 REPO = os.path.dirname(os.path.abspath(__file__))
 SEED = 21
 
-# bench.py:267-270 — the LM the repo trains on the chip
+# the LM the repo trains on the chip
 LM = dict(vocab=32768, d_model=2048, num_heads=16, d_ff=8192,
           num_layers=12)
 
@@ -386,9 +386,8 @@ def phase_serve(vocab, d_model, num_heads, d_ff, num_layers, max_len,
 
 
 def phase_resnet(depth, batch, res, class_dim, steps):
-    """ResNet ImageNet train step (bench.py ``bench_resnet``: Momentum,
-    bf16 amp) — the conv/BN/NCHW lowering, which shares nothing with the
-    LM."""
+    """ResNet ImageNet train step (Momentum, bf16 amp) — the conv/BN/NCHW
+    lowering, which shares nothing with the LM."""
     import jax
     import paddle_tpu as ptpu
     from paddle_tpu import layers

@@ -82,7 +82,7 @@ CACHE_EVICTIONS = _metrics.REGISTRY.counter(
 
 def enable_jax_cache(default_dir):
     """Turn on JAX's OWN persistent compilation cache for an entry
-    point (chip_smoke.py, bench.py) — a mechanism apart from the
+    point (chip_smoke.py, benchmarks/run.py) — a mechanism apart from the
     executable cache this module implements, and the only one that
     covers every jit in the process. It lives at
     ``$JAX_COMPILATION_CACHE_DIR`` when the environment names a place —

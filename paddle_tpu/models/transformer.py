@@ -336,6 +336,7 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     from ..core.framework import Program, program_guard
     from ..serving.generation import GenerationSpec
     from ..serving.decoding import DecodePolicy
+    from ..serving.paged_cache import CacheKind, refuse_sharing
 
     if decode_policy == "flags":
         decode_policy = DecodePolicy.from_flags()
@@ -393,11 +394,18 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     num_blocks = int(num_blocks) or slots * max_blocks
     if prefix_cache is None:
         prefix_cache = bool(_config.get_flag("generation_prefix_cache"))
-    if spec_k or prefix_cache:
-        from ..serving.paged_cache import refuse_sharing
-        refuse_sharing([name for name, _, _ in kinds])
     rows = [num_blocks] + [int((kind_blocks or {})[name])
                            for name, _, _ in kinds[1:]]
+    # the first kind's table feeds go by the bare names, a later kind's
+    # end in the kind's
+    cache_kinds = tuple(
+        CacheKind(name, window, rows[k],
+                  sum(1 for _, lk in model.cache_layers if lk == k),
+                  "gen.ptab.%s" % name if k else "gen.ptab",
+                  "gen.dtab.%s" % name if k else "gen.dtab", **more)
+        for k, (name, window, more) in enumerate(kinds))
+    if spec_k or prefix_cache:
+        refuse_sharing(cache_kinds)
 
     def layer_pools(width, k):
         """(pool, shape, dtype) of a layer's cache variables."""
@@ -421,13 +429,13 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
             stop_gradient=True) for name, shape, held in layer)
             for layer in cache_vars]
 
-    def more_tables(prefix, lead):
-        """The table feeds of the kinds after the first, with the first
-        kind's feed in front: one per kind, as the model indexes them. A
-        sequence's table is ``max_blocks`` wide, a state kind's one."""
-        return [layers.data("%s.%s" % (prefix, name), shape=lead + [
-            1 if name == "state" else max_blocks], dtype="int32",
-            append_batch_size=False) for name, _, _ in kinds[1:]]
+    def table_feeds(names, lead):
+        """One table feed a kind, as the model indexes them. A sequence's
+        table is ``max_blocks`` wide, a state kind's one."""
+        return [layers.data(feed, shape=lead + [
+            1 if kind.name == "state" else max_blocks], dtype="int32",
+            append_batch_size=False)
+            for feed, kind in zip(names, cache_kinds)]
 
     def _policy_epilogue(row, seed=None, step=None, mask=None):
         """row [n, V] -> next token [n] under the resolved policy.
@@ -479,10 +487,9 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
                                 append_batch_size=False)
             ppix = layers.data("gen.ppix", shape=[P], dtype="int32",
                                append_batch_size=False)
-            ptab = layers.data("gen.ptab", shape=[max_blocks],
-                               dtype="int32", append_batch_size=False)
-            cache_ctx = {"mode": "prefill", "caches": None, "table": ptab,
-                         "tables": [ptab] + more_tables("gen.ptab", []),
+            ptabs = table_feeds([k.prefill_table for k in cache_kinds], [])
+            cache_ctx = {"mode": "prefill", "caches": None,
+                         "table": ptabs[0], "tables": ptabs,
                          "hist": phist, "pos_idx": ppix,
                          "key_length": plen, "max_len": max_len}
             pseed, pstep, pmask, prefill_extra = _policy_feeds(
@@ -501,10 +508,9 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
                            append_batch_size=False)
         dpos = layers.data("gen.dpos", shape=[slots], dtype="int32",
                            append_batch_size=False)
-        dtab = layers.data("gen.dtab", shape=[slots, max_blocks],
-                           dtype="int32", append_batch_size=False)
-        cache_ctx = {"mode": "decode", "caches": None, "table": dtab,
-                     "tables": [dtab] + more_tables("gen.dtab", [slots]),
+        dtabs = table_feeds([k.decode_table for k in cache_kinds], [slots])
+        cache_ctx = {"mode": "decode", "caches": None, "table": dtabs[0],
+                     "tables": dtabs,
                      "pos": dpos, "max_len": max_len}
         dseed, dstep, dmask, decode_extra = _policy_feeds(
             "gen.d", slots)
@@ -601,15 +607,6 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
             prefix_cache=prefix_cache, decode_policy=policy,
             kind_blocks=kind_blocks)
 
-    cache_kinds = None
-    if len(kinds) > 1 or kinds[0][1] or kinds[0][0] != "full":
-        from ..serving.paged_cache import CacheKind
-        cache_kinds = tuple(
-            CacheKind(name, window, rows[k],
-                      sum(1 for _, lk in model.cache_layers if lk == k),
-                      "gen.ptab.%s" % name if k else "gen.ptab",
-                      "gen.dtab.%s" % name if k else "gen.dtab", **more)
-            for k, (name, window, more) in enumerate(kinds))
     # what a block (a state kind: a row) holds over each kind's layers
     kind_block_bytes = tuple(
         sum(int(np.prod(shape[1:])) * np.dtype(held).itemsize
@@ -639,8 +636,4 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
         stats_fetch=None if stats is None else stats.name,
         routed_pairs=None if stats is None else slots * model.pairs_per_row,
         zero_experts=getattr(model, "zero_experts", 0),
-        latent_layers=sum(kinds[k][0] == "latent"
-                          for _, k in model.cache_layers),
-        state_layers=sum(kinds[k][0] == "state"
-                         for _, k in model.cache_layers),
         kind_block_bytes=kind_block_bytes)

@@ -243,9 +243,8 @@ class RequestTracer:
     def _record(self, ctx, span_id, parent_id, name, dur_ms, attrs):
         # built lean on purpose: this runs once per lifecycle edge of
         # every SAMPLED request, which at sample_rate=1.0 is the
-        # tracing tax bench.py's tracing_overhead_pct watches. No
-        # rounding, no thread-name resolution — raw floats and the
-        # ident serialize fine.
+        # tracing tax every request pays. No rounding, no thread-name
+        # resolution — raw floats and the ident serialize fine.
         ev = {"trace_id": ctx.trace_id, "span_id": span_id,
               "parent_id": parent_id, "name": name,
               "ts_ms": self._now_ms(),

@@ -91,10 +91,10 @@ one before was uncollected),
 ``_ttft_seconds`` (time to first token), ``_inter_token_seconds``;
 the dispatcher's clock by phase: ``_host_ms_total{phase}``,
 ``_device_wait_ms_total``, and what a step and a prefill worked on:
-``_context_tokens_total``, ``_state_rows_updated_total`` (a state
-kind's rows a step rewrites), ``_prompt_tokens_total``,
+``_context_tokens_total``, ``_prompt_tokens_total``,
 ``_prefill_padded_tokens_total`` (see ``GenerationScheduler``, "The
-dispatcher's clock"); recovery: ``_failover_total``,
+dispatcher's clock"; what a step reads of each kind of layer cache the
+kind counts, serving/paged_cache.py); recovery: ``_failover_total``,
 ``_replayed_tokens_total``, ``_session_rebuilds_total``,
 ``_step_timeouts_total``, ``_failover_recovery_seconds``.
 Shed/deadline events share the serving counters
@@ -144,7 +144,7 @@ from . import resilience as _sres
 from .batcher import ServingOverloadError, _resolve, _WAIT_ALPHA
 from .decoding.policy import GREEDY_FINGERPRINT, mint_seed
 from .paged_cache import (BLOCK_COWS, SPEC_ROLLBACKS, WINDOW_BLOCKS_FREED,
-                          CacheKind, LayerCache, PoolExhausted, PrefixIndex,
+                          LayerCache, PoolExhausted, PrefixIndex,
                           refuse_sharing)
 from .resilience import (ReplicaBreaker, ServingDeadlineError,
                          ServingUnavailableError)
@@ -199,38 +199,6 @@ _CONTEXT_TOKENS = _metrics.REGISTRY.counter(
     "Cached tokens attended by decode steps: per step, the sum over "
     "the slots that advanced of their context length, the new token "
     "included")
-_WINDOW_CONTEXT_TOKENS = _metrics.REGISTRY.counter(
-    "paddle_generation_window_context_tokens_total",
-    "Cached tokens attended by the window layers of decode steps: per "
-    "step, the sum over the slots that advanced and over the window "
-    "layers of min(context length, window)")
-_LATENT_ROWS_ATTENDED = _metrics.REGISTRY.counter(
-    "paddle_generation_latent_rows_attended_total",
-    "Cached latent rows attended by decode steps: per step, the sum over "
-    "the slots that advanced and over the attention sites of a latent "
-    "kind (one a layer, or one a half of a layer of two halves) of their "
-    "context length, the new token included")
-_EVA_WINDOW_ROWS = _metrics.REGISTRY.counter(
-    "paddle_generation_eva_window_rows_total",
-    "Rows of an aligned window kind attended by decode steps: per step, "
-    "the sum over the slots that advanced and over the kind's layers of "
-    "the rows from the query's own window's first position to the query")
-_EVA_CHUNK_ROWS = _metrics.REGISTRY.counter(
-    "paddle_generation_eva_chunk_rows_total",
-    "Rows of a chunk kind (one summary a chunk of positions) attended by "
-    "decode steps: per step, the sum over the slots that advanced and "
-    "over the kind's layers of the chunks that lie before the query's "
-    "own window")
-_EVA_CHUNKS_WRITTEN = _metrics.REGISTRY.counter(
-    "paddle_generation_eva_chunks_written_total",
-    "Summaries written into a chunk kind's pools, a layer each: by a "
-    "decode step for every slot whose row completed a chunk, by a "
-    "prefill for every whole chunk of its prompt")
-_STATE_ROWS_UPDATED = _metrics.REGISTRY.counter(
-    "paddle_generation_state_rows_updated_total",
-    "State rows advanced by decode steps: per step, the layers of a state "
-    "kind times the slots that advanced (each such row is read and "
-    "written whole)")
 _ROUTED_PAIRS = _metrics.REGISTRY.counter(
     "paddle_generation_routed_pairs_total",
     "Token-expert pairs routed by the expert layers of decode steps "
@@ -371,15 +339,18 @@ class GenerationSpec:
       cache names into the scope; under a new namespace those writes
       land on orphaned variables, never on the replacement's state.
 
-    ``cache_kinds`` (optional): the kinds of layer cache the
-    model has, a tuple of ``paged_cache.CacheKind``. Layers of one kind
-    keep the same rows and share a block table: a kind without a
-    ``window`` keeps every block, a window kind frees the blocks that
-    fall wholly behind its window. Each kind has its own pool of
-    ``num_blocks`` and names its own table feeds; the first kind's are
-    ``num_blocks`` and the table feeds of ``prefill_feeds`` /
-    ``decode_feeds``. Absent, the spec has the one kind those fields
-    describe. ``stats_fetch`` (optional) names a small int array
+    ``cache_kinds``: the kinds of layer cache the model has, a tuple of
+    ``paged_cache.CacheKind``, one at least (the GPT-2 block's: one
+    ``full`` kind). Layers of one kind keep the same rows and share a
+    block table: a kind without a ``window`` keeps every block, a window
+    kind frees the blocks that fall wholly behind its window. Each kind
+    has its own pool of ``num_blocks`` and names its own table feeds; the
+    first kind's are ``num_blocks`` and the table feeds of
+    ``prefill_feeds`` / ``decode_feeds``. A latent kind has one pool an
+    attention site (a layer, or each half of a layer that has two), whose
+    row is key and value at once (``cache_vars`` names one variable a
+    site, not a K and a V); a state kind three variables a layer, one row
+    a slot. ``stats_fetch`` (optional) names a small int array
     ``[expert layers, held experts]`` of the decode program, the pairs
     each held expert took in the step, fetched with the step's tokens;
     ``routed_pairs`` is then how many pairs a step routes, held here or
@@ -387,12 +358,6 @@ class GenerationSpec:
     with ``zero_experts`` (a router that much wider than its experts)
     the array has one column more, the layer's identity pairs.
 
-    ``latent_layers`` counts the layer caches of a latent kind, one an
-    attention site (a layer, or each half of a layer that has two): one
-    pool each, whose row is key and value at once (``cache_vars`` names
-    one variable a site, not a K and a V); its books are the full
-    kind's. ``state_layers`` counts the layers of a state kind
-    (``paged_cache.CacheKind``): three variables a layer, one row a slot.
     ``kind_block_bytes`` says kind by kind what one block (of a state
     kind: one row) holds over the kind's layers.
     """
@@ -406,7 +371,7 @@ class GenerationSpec:
                  "vocab_size", "policy", "verify_program",
                  "verify_feeds", "verify_fetch", "draft_spec",
                  "cache_kinds", "stats_fetch", "routed_pairs", "zero_experts",
-                 "latent_layers", "state_layers", "kind_block_bytes")
+                 "kind_block_bytes")
 
     # a constant, kept because benchmarks/harness/serve.py:71 checks it
     paged = True
@@ -423,12 +388,9 @@ class GenerationSpec:
         kwargs.setdefault("verify_feeds", None)
         kwargs.setdefault("verify_fetch", None)
         kwargs.setdefault("draft_spec", None)
-        kwargs.setdefault("cache_kinds", None)
         kwargs.setdefault("stats_fetch", None)
         kwargs.setdefault("routed_pairs", None)
         kwargs.setdefault("zero_experts", 0)
-        kwargs.setdefault("latent_layers", 0)
-        kwargs.setdefault("state_layers", 0)
         for name in self.__slots__:
             setattr(self, name, kwargs.pop(name))
         if kwargs:
@@ -496,9 +458,9 @@ class GenerationSession:
             from . import quant as _quant
             progs = list(spec.prefill_programs.values())
             progs.append(spec.decode_program)
-            if getattr(spec, "verify_program", None) is not None:
+            if spec.verify_program is not None:
                 progs.append(spec.verify_program)
-            dspec = getattr(spec, "draft_spec", None)
+            dspec = spec.draft_spec
             shared_draft = dspec is not None and draft_scope is None
             if shared_draft:
                 progs += list(dspec.prefill_programs.values())
@@ -531,35 +493,27 @@ class GenerationSession:
         self.max_pos = min(spec.cache_len, spec.max_len)
         # -- block-pool state (serving/paged_cache) ----------------------
         # one entry per kind of layer cache (serving/paged_cache.py
-        # LayerCache); a spec that names none has the one kind its own
-        # fields describe, whose pool and tables are ``self.pool`` and
-        # ``self.tables``. What a step does for the other kinds, and for
-        # kinds with a window, it does in loops over these two lists:
-        # both are empty for a one-kind spec
-        kinds = getattr(spec, "cache_kinds", None) or (CacheKind(
-            "full", None, spec.num_blocks, len(spec.cache_vars) // 2,
-            None, None),)
-        policy = getattr(spec, "policy", None)
+        # LayerCache), walked in the spec's order by everything that takes,
+        # feeds, counts or returns blocks
+        policy = spec.policy
         if spec.prefix_cache or (policy is not None
                                  and policy.speculate_k > 0):
-            refuse_sharing(kinds)
-        self.kinds = [LayerCache(k, spec.block_size, n, spec.max_blocks)
-                      for k in kinds]
-        self._more_kinds = tuple(self.kinds[1:])
+            refuse_sharing(spec.cache_kinds)
+        self.kinds = LayerCache.of_kinds(spec.cache_kinds, spec.block_size,
+                                         n, spec.max_blocks)
         self._window_kinds = tuple(k for k in self.kinds if k.window)
-        # an aligned window kind and the chunk kind made from its blocks
-        # (EVA attention): what a step's three eva counters are kept from
-        self._eva = None
-        if any(k.chunk > 1 for k in self.kinds):
-            self._eva = (next(k for k in self.kinds if k.kind.aligned),
-                         next(k for k in self.kinds if k.chunk > 1))
-        self._latent_layers = getattr(spec, "latent_layers", 0)
-        self._state_layers = getattr(spec, "state_layers", 0)
+        # the first kind's pool and its host-side block table per slot
+        # (physical block ids backing logical rows [0, lengths[slot])), by
+        # the names tests and probes read. What a single sharing kind alone
+        # has (``refuse_sharing``) is said of them: a shared prefix with its
+        # copy-on-write and its evicting allocator, and a speculative
+        # round's growth and rollback
         self.pool = self.kinds[0].pool
-        self.prefix = PrefixIndex(self.pool) if spec.prefix_cache else None
-        # host-side block table per slot: physical block ids
-        # backing logical rows [0, lengths[slot])
         self.tables = self.kinds[0].tables
+        self.prefix = None
+        if spec.prefix_cache:
+            self.prefix = PrefixIndex(self.pool)
+            self.kinds[0].alloc = self._alloc_block
         # slots whose next write found no allocatable block this
         # step — excluded from step() results; the scheduler (or
         # generate()) finishes them at their current length
@@ -586,7 +540,7 @@ class GenerationSession:
         # the step's fetches: its tokens, and where the spec names them the
         # expert layers' pair counts beside them
         self._decode_fetches = [spec.decode_fetch]
-        if getattr(spec, "stats_fetch", None) is not None:
+        if spec.stats_fetch is not None:
             self._decode_fetches.append(spec.stats_fetch)
         # -- steps launched and not yet collected (step_launch) ----------
         # oldest first. A step is prepared with at most one of them
@@ -655,24 +609,23 @@ class GenerationSession:
         request instead of turning into an admit exception that would
         charge a healthy session's breaker."""
         n_tokens = min(int(n_tokens), self.max_pos)
-        need = self.kinds[0].blocks_for(n_tokens)
+        # a kind holds the whole history until the prefill has run, then
+        # what its window keeps
+        if self.prefix is None:
+            return all(k.pool.free_count() >= k.blocks_for(n_tokens)
+                       for k in self.kinds)
+        # a matched prefix ending mid-block copies-on-write one
+        # extra block during the admission itself — but never
+        # demand more than the pool HAS: a history that needs
+        # exactly the whole pool can only need the COW block when
+        # something matched, in which case the match freed that
+        # many fresh allocations; capping keeps such a request
+        # admittable instead of parked forever
+        need = min(self.kinds[0].blocks_for(n_tokens) + 1,
+                   self.pool.num_blocks)
         avail = self.pool.free_count()
-        if self.prefix is not None:
-            # a matched prefix ending mid-block copies-on-write one
-            # extra block during the admission itself — but never
-            # demand more than the pool HAS: a history that needs
-            # exactly the whole pool can only need the COW block when
-            # something matched, in which case the match freed that
-            # many fresh allocations; capping keeps such a request
-            # admittable instead of parked forever
-            need = min(need + 1, self.pool.num_blocks)
-            if avail < need:
-                avail += self.prefix.evictable_count()
-        # the other kinds hold the whole history until the prefill has
-        # run, then what their window keeps
-        return avail >= need and all(
-            k.pool.free_count() >= k.blocks_for(n_tokens)
-            for k in self._more_kinds)
+        return avail >= need or \
+            avail + self.prefix.evictable_count() >= need
 
     def storable(self, n_tokens):
         """Static bound: could this session's storage EVER hold an
@@ -829,7 +782,7 @@ class GenerationSession:
                     "closed session leaked %d blocks of kind %s" % (
                         kind.pool.used_count(), kind.kind.name)
                 kind.pool.close()
-            self.kinds, self._more_kinds, self._window_kinds = [], (), ()
+            self.kinds, self._window_kinds = [], ()
             self.pool = None
             self.prefix = None
         claimed = _CACHE_CLAIMS.get(self.scope)
@@ -940,39 +893,31 @@ class GenerationSession:
                 "prompt length %d (unshared suffix %d) exceeds the "
                 "largest prompt bucket %d"
                 % (n, suffix.size, self.spec.prompt_buckets[-1]))
-        table = list(shared)
+        # one table a kind; the first starts with what the prefix shares
+        tables = [[] for _ in self.kinds]
+        tables[0].extend(shared)
         for block in shared:
             self.pool.incref(block)
-        more = [[] for _ in self._more_kinds]
         try:
             if matched % bs:
                 # the matched prefix ends MID-block: the suffix writes
                 # into that shared block, so diverge onto a copy first
-                self._ensure_writable(table, len(table) - 1)
-            self.kinds[0].extend(table, n, slot, self._alloc_block)
-            for kind, tbl in zip(self._more_kinds, more):
-                with self._state_bind(kind, slot, True):
-                    kind.extend(tbl, n, slot)
+                self._ensure_writable(tables[0], len(shared) - 1)
             w = suffix.size
             padded = np.full((1, bucket), self.spec.eos_id, np.int64)
             padded[0, :w] = suffix
             pix = np.clip(matched + np.arange(bucket), 0,
                           self.spec.max_len - 1).astype(np.int32)
-            tab = np.full(self.spec.max_blocks, self.pool.num_blocks,
-                          np.int32)
-            tab[:len(table)] = table
-            f_tok, f_len, f_pos, f_hist, f_pix, f_tab = \
-                self.spec.prefill_feeds[:6]
+            f_tok, f_len, f_pos, f_hist, f_pix = self.spec.prefill_feeds[:5]
             feed = {f_tok: padded,
                     f_len: np.asarray([w], np.int32),
                     f_pos: np.asarray([w - 1], np.int32),
                     f_hist: np.asarray([matched], np.int32),
-                    f_pix: pix,
-                    f_tab: tab}
-            for kind, tbl in zip(self._more_kinds, more):
-                row = np.full(kind.width, kind.pool.num_blocks, np.int32)
-                row[:len(tbl)] = tbl
-                feed[kind.kind.prefill_table] = row
+                    f_pix: pix}
+            for kind, table in zip(self.kinds, tables):
+                with self._state_bind(kind, slot, True):
+                    kind.extend(table, n, slot)
+                feed[kind.kind.prefill_table] = kind.table_row(table)
             # the emitted token's index is the TOTAL length n
             # (= matched + w), prefix sharing included
             self._policy_prefill_feed(feed, n, seed, cstate)
@@ -983,42 +928,39 @@ class GenerationSession:
                     fetch_list=[self.spec.prefill_fetch],
                     scope=self.scope, return_numpy=False)
         except BaseException:
-            self._admit_rollback(table, more)
+            self._admit_rollback(tables)
             raise
-        return (prompt, slot, table, more, bucket, matched, outs,
-                seed, cstate)
+        return (prompt, slot, tables, bucket, matched, outs, seed, cstate)
 
-    def _admit_rollback(self, table, more):
-        for kind, tbl in zip(self.kinds, [table] + list(more)):
-            kind.drop(tbl)
+    def _admit_rollback(self, tables):
+        for kind, table in zip(self.kinds, tables):
+            kind.drop(table)
 
     def admit_abandon(self, launched):
         """Give back what :meth:`admit_launch` took, for a prefill whose
         first token nobody will wait for."""
-        self._admit_rollback(launched[2], launched[3])
+        self._admit_rollback(launched[2])
 
     def admit_collect(self, launched):
         """Phase 2 of an admission: wait for the prefill's first token
         (``session:prefill_wait``) and enter the sequence in the slot's
         books. Returns ``(slot, token)``."""
-        prompt, slot, table, more, bucket, matched, outs, seed, cstate = \
-            launched
+        prompt, slot, tables, bucket, matched, outs, seed, cstate = launched
         try:
             with _tracing.span("session:prefill_wait", round=self.round,
                                slot=slot):
                 first = int(np.asarray(outs[0]).reshape(-1)[0])
         except BaseException:
-            self._admit_rollback(table, more)
+            self._admit_rollback(tables)
             raise
         n = prompt.size
         if self.prefix is not None:
             # publish the prompt's blocks (full chunks + partial
             # tail) — the next admission sharing this prefix, or a
             # PR-9 token replay of it, prefills only its suffix
-            self.prefix.register(prompt, table)
-        self.tables[slot] = table
-        for kind, tbl in zip(self._more_kinds, more):
-            kind.tables[slot] = tbl
+            self.prefix.register(prompt, tables[0])
+        for kind, table in zip(self.kinds, tables):
+            kind.tables[slot] = table
         self.lengths[slot] = n
         self._trim_windows((slot,))
         self.last_token[slot] = first
@@ -1032,9 +974,8 @@ class GenerationSession:
         _PREFILLS.labels(bucket=bucket).inc()
         _PROMPT_TOKENS.inc(n - matched)
         _PREFILL_PADDED_TOKENS.inc(bucket)
-        if self._eva:
-            chunks = self._eva[1]
-            _EVA_CHUNKS_WRITTEN.inc(chunks.kind.layers * (n // chunks.chunk))
+        for kind in self.kinds:
+            kind.count_prefill(n)
         return slot, first
 
     def step(self):
@@ -1127,40 +1068,29 @@ class GenerationSession:
             if self._window_kinds:
                 with _tracing.span("session:window_trim", round=self.round):
                     self._trim_windows(act)
+            live = []
             for s in act:
                 s = int(s)
                 pos = int(self.lengths[s])
-                tbl = self.tables[s]
                 try:
-                    if pos // bs == len(tbl):
-                        tbl.append(self._alloc_block())
-                    else:
+                    if self.prefix is not None and \
+                            pos // bs < len(self.tables[s]):
                         # writing into a block a sharer or the prefix
                         # index also holds: diverge onto a private copy
-                        self._ensure_writable(tbl, pos // bs)
-                    for kind in self._more_kinds:
+                        self._ensure_writable(self.tables[s], pos // bs)
+                    for kind in self.kinds:
                         kind.extend(kind.tables[s], pos + 1, s)
+                    live.append(s)
                 except PoolExhausted:
                     self._starved.add(s)
-            nb = self.pool.num_blocks
-            tab = np.full((self.spec.slots, self.spec.max_blocks), nb,
-                          np.int32)
-            for s in act:
-                s = int(s)
-                if s in self._starved:
-                    continue
-                tbl = self.tables[s]
-                tab[s, :len(tbl)] = tbl
-            f_tok, f_pos, f_tab = self.spec.decode_feeds[:3]
+            f_tok, f_pos = self.spec.decode_feeds[:2]
             feed = {f_tok: self._token_feed(),
-                    f_pos: self.lengths.astype(np.int32),
-                    f_tab: tab}
-            for kind in self._more_kinds:
+                    f_pos: self.lengths.astype(np.int32)}
+            for kind in self.kinds:
                 tab = np.full((self.spec.slots, kind.width),
                               kind.pool.num_blocks, np.int32)
-                for s in act:
-                    if int(s) not in self._starved:
-                        kind.feed_row(tab[s], int(s))
+                for s in live:
+                    kind.feed_row(tab[s], s)
                 feed[kind.kind.decode_table] = tab
             self._policy_decode_feed(feed)
             return (act, frozenset(self._starved), feed)
@@ -1259,10 +1189,7 @@ class GenerationSession:
                 self.pool.truncate_table(tbl, held)
                 self._starved.add(s)
                 continue
-            tab = np.full(self.spec.max_blocks, self.pool.num_blocks,
-                          np.int32)
-            tab[:len(tbl)] = tbl
-            info[s] = (L, tab)
+            info[s] = (L, self.kinds[0].table_row(tbl))
         return {"slots": info, "starved": frozenset(self._starved)}
 
     def step_run(self, prepared, enqueued=None):
@@ -1313,25 +1240,9 @@ class GenerationSession:
             [s for s in act if int(s) not in starved], np.int64)
         self.lengths[advanced] += 1
         lens = self.lengths[advanced]
-        if self._window_kinds and advanced.size:
-            _WINDOW_CONTEXT_TOKENS.inc(int(sum(
-                k.kind.layers * np.minimum(lens, k.window).sum()
-                for k in self._window_kinds if not k.kind.aligned)))
-        if self._eva and advanced.size:
-            window, chunks = self._eva
-            # each query's own window starts at ``edge``: it attends the
-            # window pool's rows from there on and a summary for every
-            # chunk before; a chunk is summed up by its last position
-            edge = (lens - 1) // window.window * window.window
-            _EVA_WINDOW_ROWS.inc(window.kind.layers * int((lens - edge).sum()))
-            _EVA_CHUNK_ROWS.inc(chunks.kind.layers
-                                * int(edge.sum()) // chunks.chunk)
-            _EVA_CHUNKS_WRITTEN.inc(chunks.kind.layers * int(
-                (lens % chunks.chunk == 0).sum()))
-        if self._latent_layers and advanced.size:
-            _LATENT_ROWS_ATTENDED.inc(self._latent_layers * int(lens.sum()))
-        if self._state_layers and advanced.size:
-            _STATE_ROWS_UPDATED.inc(self._state_layers * int(advanced.size))
+        if advanced.size:
+            for kind in self.kinds:
+                kind.count_step(lens)
         flight = _Flight(advanced, self._retires[advanced].copy(), outs,
                          int(lens.sum()))
         self._flights.append(flight)
@@ -1382,14 +1293,14 @@ class GenerationSession:
         """The routing counters from a step's ``[expert layers, held
         experts]`` pair counts (behind them, where the router has identity
         experts, the layer's identity pairs)."""
-        if getattr(self.spec, "zero_experts", 0):
+        if self.spec.zero_experts:
             _ZERO_EXPERT_PAIRS.inc(int(counts[:, -1].sum()))
             counts = counts[:, :-1]
         held = int(counts.sum())
         _MOE_LAYER_STEPS.inc(counts.shape[0])
         _EXPERTS_TOUCHED.inc(int((counts > 0).sum()))
         _EXPERT_ASSIGNMENTS.inc(held)
-        _ROUTED_PAIRS.inc(getattr(self.spec, "routed_pairs", None) or held)
+        _ROUTED_PAIRS.inc(self.spec.routed_pairs or held)
         _EXPERT_MAX_LOAD.inc(int(counts.max(axis=1).sum()))
 
     def _draft_mirror_plain(self, result):

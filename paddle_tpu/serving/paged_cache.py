@@ -38,7 +38,12 @@ Metrics (always-on, the serving discipline):
 ``_prefix_shared_tokens_total`` (prompt tokens NOT re-prefilled),
 ``_kv_block_cows_total`` (copy-on-write block copies),
 ``_kv_pool_evictions_total`` (prefix blocks reclaimed under pressure),
-``_kv_blocks_in_use`` (gauge per pool).
+``_kv_blocks_in_use`` (gauge per pool); and what a decode step reads and
+writes of each kind of layer cache, kept by the kind at the step's launch
+(``LayerCache.count_step``): ``_window_context_tokens_total``,
+``_latent_rows_attended_total``, ``_state_rows_updated_total``,
+``_eva_window_rows_total``, ``_eva_chunk_rows_total``,
+``_eva_chunks_written_total``.
 """
 
 import collections
@@ -85,6 +90,41 @@ WINDOW_BLOCKS_FREED = _metrics.REGISTRY.counter(
     "paddle_generation_kv_window_blocks_freed_total",
     "Blocks a window kind of layer cache returned because every row of "
     "them lay behind the window")
+
+# What a decode step reads and writes of a kind of layer cache, counted by
+# the kind itself (``LayerCache.count_step``) at the step's launch.
+WINDOW_CONTEXT_TOKENS = _metrics.REGISTRY.counter(
+    "paddle_generation_window_context_tokens_total",
+    "Cached tokens attended by the window layers of decode steps: per "
+    "step, the sum over the slots that advanced and over the window "
+    "layers of min(context length, window)")
+LATENT_ROWS_ATTENDED = _metrics.REGISTRY.counter(
+    "paddle_generation_latent_rows_attended_total",
+    "Cached latent rows attended by decode steps: per step, the sum over "
+    "the slots that advanced and over the attention sites of a latent "
+    "kind (one a layer, or one a half of a layer of two halves) of their "
+    "context length, the new token included")
+EVA_WINDOW_ROWS = _metrics.REGISTRY.counter(
+    "paddle_generation_eva_window_rows_total",
+    "Rows of an aligned window kind attended by decode steps: per step, "
+    "the sum over the slots that advanced and over the kind's layers of "
+    "the rows from the query's own window's first position to the query")
+EVA_CHUNK_ROWS = _metrics.REGISTRY.counter(
+    "paddle_generation_eva_chunk_rows_total",
+    "Rows of a chunk kind (one summary a chunk of positions) attended by "
+    "decode steps: per step, the sum over the slots that advanced and "
+    "over the kind's layers of the chunks that lie before the query's "
+    "own window")
+EVA_CHUNKS_WRITTEN = _metrics.REGISTRY.counter(
+    "paddle_generation_eva_chunks_written_total",
+    "Summaries written into a chunk kind's pools, a layer each: by a "
+    "decode step for every slot whose row completed a chunk, by a "
+    "prefill for every whole chunk of its prompt")
+STATE_ROWS_UPDATED = _metrics.REGISTRY.counter(
+    "paddle_generation_state_rows_updated_total",
+    "State rows advanced by decode steps: per step, the layers of a state "
+    "kind times the slots that advanced (each such row is read and "
+    "written whole)")
 
 _POOL_SEQ = itertools.count()
 
@@ -295,7 +335,20 @@ class LayerCache:
     table starts with dead entries up to it, and ``first_seen`` is that
     edge's block. A kind whose row is a **chunk** counts its rows as
     ``n_tokens // chunk`` (its table feeds are as wide as any, and mostly
-    dead)."""
+    dead). What a decode step and a prefill read and write of the kind, the
+    kind counts itself (``count_step``, ``count_prefill``): the session
+    walks its kinds and asks none what it is."""
+
+    @classmethod
+    def of_kinds(cls, kinds, block_size, slots, max_blocks):
+        """The books of a spec's kinds, in its order. A chunk kind is
+        handed the aligned window kind whose blocks its rows are made
+        from: a query's summaries end at that window's edge."""
+        caches = [cls(k, block_size, slots, max_blocks) for k in kinds]
+        for cache in caches:
+            if cache.chunk > 1:
+                cache.made_from = next(c for c in caches if c.kind.aligned)
+        return caches
 
     def __init__(self, kind, block_size, slots, max_blocks=None):
         self.kind = kind
@@ -307,18 +360,22 @@ class LayerCache:
                              "slots" % (kind.num_blocks, slots))
         self.pool = BlockPool(kind.num_blocks, 1 if self.state
                               else block_size, kind.name)
+        # where a fresh block comes from: the pool, unless the session puts
+        # an allocator of its own in its place (one that evicts first)
+        self.alloc = self.pool.alloc
+        self.made_from = None
         # entries of a table feed's row
         self.width = 1 if self.state else max_blocks
         self.tables = [[] for _ in range(slots)]
         self.first = np.zeros(slots, np.int64)
 
-    def extend(self, table, n_tokens, slot, alloc=None):
+    def extend(self, table, n_tokens, slot):
         """Append to ``table`` what a sequence of ``n_tokens`` in ``slot``
-        still lacks: fresh blocks (from ``alloc``, default the pool's), or
-        a state kind's one row, the slot's own. An empty table of an
-        aligned window starts with dead entries for the blocks behind the
-        window's edge, which nothing will write. Raises PoolExhausted with
-        the table as far as it got."""
+        still lacks: fresh blocks (from ``self.alloc``), or a state kind's
+        one row, the slot's own. An empty table of an aligned window
+        starts with dead entries for the blocks behind the window's edge,
+        which nothing will write. Raises PoolExhausted with the table as
+        far as it got."""
         if self.state:
             if not table:
                 table.append(self.pool.take(slot))
@@ -326,10 +383,9 @@ class LayerCache:
         if self.kind.aligned and not table:
             table.extend([self.pool.num_blocks] * int(
                 self.first_seen(n_tokens)))
-        alloc = alloc or self.pool.alloc
         rows = n_tokens // self.chunk
         while len(table) * self.pool.block_size < rows:
-            table.append(alloc())
+            table.append(self.alloc())
 
     def blocks_for(self, n_tokens):
         """The blocks a sequence of ``n_tokens`` takes at its admission."""
@@ -384,6 +440,49 @@ class LayerCache:
         dead everywhere else."""
         table, first = self.tables[slot], int(self.first[slot])
         row[first:len(table)] = table[first:]
+
+    def table_row(self, table):
+        """A table-feed row for ``table``: dead past its end."""
+        row = np.full(self.width, self.pool.num_blocks, np.int32)
+        row[:len(table)] = table
+        return row
+
+    def count_step(self, lengths):
+        """Count what a decode step reads and writes of this kind, over its
+        layers: ``lengths`` are those of the slots the step advances, the
+        new row included."""
+        layers = self.kind.layers
+        if self.state:
+            STATE_ROWS_UPDATED.inc(layers * int(lengths.size))
+        elif self.kind.name == "latent":
+            LATENT_ROWS_ATTENDED.inc(layers * int(lengths.sum()))
+        elif self.kind.aligned:
+            # a query attends its own window's rows, from the edge on
+            EVA_WINDOW_ROWS.inc(layers * int(
+                (lengths - self._edge(lengths, self.window)).sum()))
+        elif self.chunk > 1:
+            # a query attends a summary for every chunk before its own
+            # window's edge; a chunk is summed up by its last position
+            edge = self._edge(lengths, self.made_from.window)
+            EVA_CHUNK_ROWS.inc(layers * int(edge.sum()) // self.chunk)
+            EVA_CHUNKS_WRITTEN.inc(layers * int(
+                (lengths % self.chunk == 0).sum()))
+        elif self.window:
+            WINDOW_CONTEXT_TOKENS.inc(layers * int(
+                np.minimum(lengths, self.window).sum()))
+
+    def count_prefill(self, n_tokens):
+        """Count what the prefill of a prompt of ``n_tokens`` writes of
+        this kind beyond its rows: a chunk kind's summaries."""
+        if self.chunk > 1:
+            EVA_CHUNKS_WRITTEN.inc(self.kind.layers
+                                   * (n_tokens // self.chunk))
+
+    @staticmethod
+    def _edge(lengths, window):
+        """The first position of the aligned window that holds each
+        sequence's newest row."""
+        return (lengths - 1) // window * window
 
     def check_invariant(self, index=None):
         self.pool.check_invariant(

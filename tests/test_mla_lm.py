@@ -723,7 +723,8 @@ def test_the_latent_pools_gauge_goes_by_the_kinds_name(model_scope,
     before = set(gauges())
     sess = _session(scope)
     sess.admit(np.arange(2, 12))         # 10 rows: 3 blocks of 4
-    assert sess.spec.latent_layers == 3
+    assert [(k.name, k.layers) for k in sess.spec.cache_kinds] == [
+        ("latent", 3)]
     assert sess.pool._label.startswith("latent.p")
     assert gauges()[sess.pool._label] == 3.0
     other = _session(scope)              # a second session, a second child

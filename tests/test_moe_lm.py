@@ -855,8 +855,12 @@ def _digest(spec):
     out.append((spec.cache_vars, spec.prefill_feeds, spec.decode_feeds,
                 spec.prefill_fetch, spec.decode_fetch, spec.num_blocks,
                 spec.max_blocks, spec.copy_feeds))
-    more = (spec.verify_feeds, spec.verify_fetch, spec.cache_kinds,
-            spec.stats_fetch)
+    # a spec of the one full kind named no kinds when these were digested
+    kinds = spec.cache_kinds
+    if [(k.name, k.window, k.prefill_table) for k in kinds] == [
+            ("full", None, "gen.ptab")]:
+        kinds = None
+    more = (spec.verify_feeds, spec.verify_fetch, kinds, spec.stats_fetch)
     if any(m is not None for m in more):
         out.append(more)
     return hashlib.sha256(repr(out).encode()).hexdigest()
@@ -873,10 +877,13 @@ def test_a_one_kind_specs_programs_are_the_parents_byte_for_byte():
         cache_ns="kv", decode_policy=None)
     assert _digest(spec) == ("08b806694281316436c1e61cf58a455d"
                              "00eaafe3d70b950487c947a726d05c26")
-    assert spec.cache_kinds is None and spec.stats_fetch is None
+    assert spec.cache_kinds == (CacheKind(
+        "full", None, 24, 2, "gen.ptab", "gen.dtab"),)
+    assert spec.stats_fetch is None
     sess = GenerationSession(spec, scope=ptpu.Scope())
-    assert len(sess.kinds) == 1 and not sess._more_kinds \
-        and not sess._window_kinds
+    assert len(sess.kinds) == 1 and not sess._window_kinds
+    assert sess.pool is sess.kinds[0].pool \
+        and sess.tables is sess.kinds[0].tables
     assert sess._decode_fetches == [spec.decode_fetch]
     sess.close()
 

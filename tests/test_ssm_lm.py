@@ -787,7 +787,6 @@ def test_the_state_kind_is_named_by_the_spec(model_scope, flash_off):
     scope = model_scope[0]
     sess = _session(scope)
     spec = sess.spec
-    assert spec.state_layers == 3 and spec.latent_layers == 0
     assert [(k.name, k.num_blocks, k.layers, k.prefill_table,
              k.decode_table) for k in spec.cache_kinds] == [
         ("full", 24, 1, "gen.ptab", "gen.dtab"),

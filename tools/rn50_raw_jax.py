@@ -1,8 +1,8 @@
 """Raw-JAX ResNet-50 train step: the framework-free upper bound.
 
 Hand-written flax-style RN50 (bf16 activations, f32 params, momentum)
-with no Program/Executor in the loop — if this matches bench.py's
-number, the framework's step IS what XLA delivers for this model on
+with no Program/Executor in the loop — if this matches the number of
+``chip_smoke.py``'s ResNet-50 phase, the framework's step IS what XLA delivers for this model on
 this chip, and the remaining MFU gap is the model's arithmetic
 intensity, not the engine. See PROFILE.md round-4 cap analysis.
 """
