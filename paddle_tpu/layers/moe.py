@@ -7,8 +7,8 @@ from ..initializer import ConstantInitializer, NormalInitializer
 from . import nn as _nn
 from . import ops as _ops
 
-__all__ = ["rms_norm", "rotary_embedding", "linear", "swiglu", "moe_ffn",
-           "mla_attention", "eva_attention"]
+__all__ = ["rms_norm", "rotary_embedding", "linear", "swiglu", "ffn",
+           "moe_ffn", "mla_attention", "eva_attention"]
 
 
 def rms_norm(x, epsilon=1e-5, group_size=0, param_attr=None, name=None,
@@ -76,16 +76,28 @@ def linear(x, size, param_attr, dtype=None, std=0.02, transpose_w=False,
 def swiglu(x, d_ff, prefix, dtype=None, **kwargs):
     """``(silu(x Wg) * (x Wu)) Wd``: parameters ``<prefix>.gate.w``,
     ``.up.w`` [d, d_ff] and ``.down.w`` [d_ff, d]."""
-    gate = linear(x, d_ff, prefix + ".gate.w", dtype, **kwargs)
-    up = linear(x, d_ff, prefix + ".up.w", dtype, **kwargs)
-    inner = _nn.elementwise_mul(_ops.silu(gate, **kwargs), up, **kwargs)
+    return ffn(x, d_ff, prefix, dtype, **kwargs)
+
+
+def ffn(x, d_ff, prefix, dtype=None, act=None, **kwargs):
+    """A dense feed-forward of ``d_ff`` inner lanes: SwiGLU
+    (:func:`swiglu`), or with ``act`` ``relu2`` the two-matrix
+    ``relu(x Wu)^2 Wd``: ``<prefix>.up.w`` [d, d_ff] and ``.down.w``
+    [d_ff, d], no gate."""
+    if act == "relu2":
+        up = linear(x, d_ff, prefix + ".up.w", dtype, **kwargs)
+        inner = _ops.square(_ops.relu(up, **kwargs), **kwargs)
+    else:
+        gate = linear(x, d_ff, prefix + ".gate.w", dtype, **kwargs)
+        up = linear(x, d_ff, prefix + ".up.w", dtype, **kwargs)
+        inner = _nn.elementwise_mul(_ops.silu(gate, **kwargs), up, **kwargs)
     return linear(inner, x.shape[-1], prefix + ".down.w", dtype, **kwargs)
 
 
 def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
             route_scale=1.0, expert_offset=0, experts_held=None,
             dtype=None, std=0.02, scoring="sigmoid", zero_experts=0,
-            **kwargs):
+            act=None, **kwargs):
     """The routed experts of a sparse feed-forward over x [.., d]
     (ops/moe_ops.py ``moe_ffn``): the router ``<prefix>.router.w`` [d, E]
     and the selection bias ``<prefix>.expert_bias`` [E] in float32, the
@@ -95,7 +107,9 @@ def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
     router's (``sigmoid``; ``softmax_bias``: a softmax over all outputs;
     or ``softmax_topk``: a softmax over the chosen logits). With
     ``zero_experts`` Z the router and the bias are ``E + Z`` wide: the
-    last Z outputs are identity experts. Returns (out float32, counts
+    last Z outputs are identity experts. With ``act`` ``relu2`` an expert
+    is two matrices, ``relu(x Wu^T)^2 Wd``: no ``.gate.w``, and ``.up.w``
+    is held [E_held, d_ff, d] as ``.down.w`` is. Returns (out float32, counts
     [E_held] int32), and with ``zero_experts`` a third, the call's
     identity pairs [1] int32."""
     helper = LayerHelper("moe_ffn", **kwargs)
@@ -109,12 +123,15 @@ def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
     bias = helper.create_parameter(
         prefix + ".expert_bias", shape=[num_experts + zero_experts],
         dtype="float32", default_initializer=ConstantInitializer(0.0))
-    stacks = [helper.create_parameter(
+    relu2 = act == "relu2"
+    stacks = {slot: helper.create_parameter(
         "%s.experts.%s.w" % (prefix, which), shape=shape, dtype=dtype,
         default_initializer=normal)
-        for which, shape in (("gate", [held, d, d_ff]),
-                             ("up", [held, d, d_ff]),
-                             ("down", [held, d_ff, d]))]
+        for slot, which, shape in (
+            ("WGate", "gate", [held, d, d_ff]),
+            ("WUp", "up", [held, d_ff, d] if relu2 else [held, d, d_ff]),
+            ("WDown", "down", [held, d_ff, d]))
+        if not (relu2 and which == "gate")}
     out = helper.create_tmp_variable("float32")
     counts = helper.create_tmp_variable("int32", stop_gradient=True)
     attrs = {"num_experts": num_experts, "top_k": top_k,
@@ -122,6 +139,8 @@ def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
              "expert_offset": expert_offset}
     if scoring != "sigmoid":
         attrs["scoring"] = scoring
+    if act:
+        attrs["act"] = act
     outputs = {"Out": [out.name], "Counts": [counts.name]}
     if zero_experts:
         attrs["zero_experts"] = zero_experts
@@ -129,9 +148,9 @@ def moe_ffn(x, num_experts, top_k, d_ff, prefix, route_norm=True,
         outputs["ZeroPairs"] = [zero_pairs.name]
     helper.append_op(
         type="moe_ffn",
-        inputs={"X": [x.name], "RouterW": [router.name],
-                "ExpertBias": [bias.name], "WGate": [stacks[0].name],
-                "WUp": [stacks[1].name], "WDown": [stacks[2].name]},
+        inputs=dict({"X": [x.name], "RouterW": [router.name],
+                     "ExpertBias": [bias.name]},
+                    **{slot: [w.name] for slot, w in stacks.items()}),
         outputs=outputs, attrs=attrs)
     return (out, counts, zero_pairs) if zero_experts else (out, counts)
 

@@ -31,13 +31,15 @@ class _Mamba2Initializer(Initializer):
 
 def mamba2_mixer(x, num_heads, head_dim, state_dim, conv_width, chunk,
                  prefix, epsilon=1e-5, dtype=None, std=0.02, state=None,
-                 table=None, length=None, pos=None, **kwargs):
+                 table=None, length=None, pos=None, n_groups=1, **kwargs):
     """The Mamba-2 mixer over x [B, T, d] -> [B, T, d] float32. Parameters
-    ``<prefix>.in.w`` [d, 2HP + 2N + H] and ``.out.w`` [HP, d] in ``dtype``;
-    ``.conv.w`` [K, HP + 2N], ``.conv.b``, ``.dt_bias``, ``.a_log``, ``.d``
-    [H] and ``.norm.w`` [HP] in float32. ``state`` is the layer's state
-    pool ``(ssm, conv, at)`` with ``table``: and ``length`` for a prompt's
-    prefill into its row, or ``pos`` for a decode step over every row.
+    ``<prefix>.in.w`` [d, 2HP + 2GN + H] and ``.out.w`` [HP, d] in ``dtype``;
+    ``.conv.w`` [K, HP + 2GN], ``.conv.b``, ``.dt_bias``, ``.a_log``, ``.d``
+    [H] and ``.norm.w`` [HP] in float32, for ``n_groups`` G groups of B and
+    C (the gated norm then within each group's HP / G lanes). ``state`` is
+    the layer's state pool ``(ssm, conv, at)`` with ``table``: and
+    ``length`` for a prompt's prefill into its row, or ``pos`` for a decode
+    step over every row.
 
     The convolution's taps start as Mamba-2's do (``nn.Conv1d``'s default:
     uniform within ``1 / sqrt(K)``), not at ``std``: taps of 0.02 leave
@@ -46,7 +48,7 @@ def mamba2_mixer(x, num_heads, head_dim, state_dim, conv_width, chunk,
     can tell a wrong state."""
     helper = LayerHelper("mamba2_mixer", **kwargs)
     d, di = x.shape[-1], num_heads * head_dim
-    lanes = di + 2 * state_dim
+    lanes = di + 2 * n_groups * state_dim
     dtype = dtype or x.dtype
     normal = NormalInitializer(0.0, std)
     tap = conv_width ** -0.5
@@ -83,6 +85,8 @@ def mamba2_mixer(x, num_heads, head_dim, state_dim, conv_width, chunk,
     helper.append_op(
         type=op, inputs={k: [v.name] for k, v in inputs.items()},
         outputs=outputs,
-        attrs={"num_heads": num_heads, "head_dim": head_dim,
-               "state_dim": state_dim, "chunk": chunk, "epsilon": epsilon})
+        attrs=dict({"num_heads": num_heads, "head_dim": head_dim,
+                    "state_dim": state_dim, "chunk": chunk,
+                    "epsilon": epsilon},
+                   **({"n_groups": n_groups} if n_groups > 1 else {})))
     return out

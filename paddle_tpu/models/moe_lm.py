@@ -36,6 +36,14 @@ its result joins the residual stream only after half ``experts_join``'s
 ``benchmarks/reference/longcat_flash.py``; its router is ``zero_experts``
 wider than its experts, ``ops/moe_ops.py``). Such a layer has a layer
 cache a half: the session counts attention **sites**, not layers.
+With ``block`` ``dict(sublayers=1)`` a layer is ONE sublayer, ``h + f(norm
+(h))`` with one norm and one residual sum, where ``f`` is by the layer's
+type a Mamba-2 mixer, an attention or, the fourth entry of
+``layer_types``, ``"experts"``: the expert layer with its shared expert
+alone (the ``nemotron_h`` family, ``benchmarks/reference/nemotron_h.py``,
+whose experts are two matrices, ``expert_act`` ``relu2``, and whose mixer
+has ``n_groups`` groups of B and C). An ``"experts"`` layer has no layer
+cache, so there too a cache goes by its **site**.
 
 :func:`moe_lm` is the whole-sequence forward (its startup program makes the
 weights); :func:`moe_lm_session` builds the paged prefill and decode
@@ -70,6 +78,10 @@ from .transformer import lm_session
 __all__ = ["moe_lm", "moe_lm_session", "MoeLM"]
 
 SLIDING, FULL, MAMBA = "sliding_attention", "full_attention", "mamba"
+EXPERTS = "experts"
+# what a trace's operations of a one-sublayer layer go under, by its type
+_SUBLAYER_SCOPE = {MAMBA: "mamba2_mixer", EXPERTS: "moe_ffn",
+                   FULL: "attention", SLIDING: "attention"}
 
 
 class MoeLM:
@@ -89,11 +101,17 @@ class MoeLM:
                  attn_scale=None, residual_scale=None, logit_scale=None,
                  tie_embeddings=False, mamba=None, shared_d_ff=None,
                  block=None, zero_experts=0, eva=None, norm_offset=0.0,
-                 pred_heads=1):
-        unknown = set(layer_types) - {SLIDING, FULL, MAMBA}
+                 pred_heads=1, expert_act=None):
+        self.block = dict(block) if block else None
+        # a layer of one sublayer, among them the fourth type
+        self.single = (self.block or {}).get("sublayers") == 1
+        unknown = set(layer_types) - {SLIDING, FULL, MAMBA} \
+            - ({EXPERTS} if self.single else set())
         if unknown:
-            raise ValueError("layer_types holds %s: a layer is %r, %r or %r"
-                             % (sorted(unknown), SLIDING, FULL, MAMBA))
+            raise ValueError(
+                "layer_types holds %s: a layer is %r, %r or %r, and in a "
+                "block of one sublayer %r"
+                % (sorted(unknown), SLIDING, FULL, MAMBA, EXPERTS))
         if attention not in ("gqa", "latent", "eva"):
             raise ValueError("attention is 'gqa', 'latent' or 'eva', not %r"
                              % (attention,))
@@ -123,17 +141,24 @@ class MoeLM:
         self.tie_embeddings = tie_embeddings
         self.zero_experts = zero_experts
         self.norm_offset, self.pred_heads = norm_offset, pred_heads
-        self.block = dict(block) if block else None
-        if block and (attention != "latent" or num_dense_layers
-                      or not 0 <= block["experts_read"]
-                      <= block["experts_join"] < block["halves"]):
+        self.expert_act = expert_act
+        if self.single:
+            if attention != "gqa" or num_dense_layers or post_norms:
+                raise ValueError("a block of one sublayer is grouped-query "
+                                 "attention's, has no leading dense layer "
+                                 "and one norm a layer")
+        elif block and (attention != "latent" or num_dense_layers
+                        or not 0 <= block["experts_read"]
+                        <= block["experts_join"] < block["halves"]):
             raise ValueError("a block of halves is latent attention's, has "
                              "an expert layer in every layer and reads it "
                              "at a half no later than the one it joins")
         # expert pairs a row of a step routes, held here or not: every
-        # layer but the leading dense ones has one expert layer
-        self.pairs_per_row = top_k * (len(self.layer_types)
-                                      - num_dense_layers)
+        # layer but the leading dense ones has one expert layer, and of
+        # one-sublayer layers those that are one
+        self.pairs_per_row = top_k * (
+            self.layer_types.count(EXPERTS) if self.single
+            else len(self.layer_types) - num_dense_layers)
         if attention == "latent":
             self._latent_sizes(**latent)
             return
@@ -154,14 +179,17 @@ class MoeLM:
             # row in a layer: the scan's state and the convolution's inputs
             self.mamba = dict(mamba)
             lanes = mamba["num_heads"] * mamba["head_dim"] \
-                + 2 * mamba["state_dim"]
+                + 2 * mamba.get("n_groups", 1) * mamba["state_dim"]
             state_row = ((mamba["num_heads"], mamba["head_dim"],
                           mamba["state_dim"]), (mamba["conv_width"], lanes))
             self.kinds += (("state", None),)
+        # a layer's site among the layer caches: an expert layer has none
+        cached = [i for i, t in enumerate(self.layer_types) if t != EXPERTS]
+        self.site = {i: at for at, i in enumerate(cached)}
         self.cache_layers = [
-            (state_row, len(present)) if t == MAMBA else
-            (num_kv_heads * head_dim, present.index(t))
-            for t in self.layer_types]
+            (state_row, len(present)) if self.layer_types[i] == MAMBA else
+            (num_kv_heads * head_dim, present.index(self.layer_types[i]))
+            for i in cached]
 
     def _latent_sizes(self, q_rank, kv_rank, nope_dim, rope_dim, v_dim,
                       q_scale=None, kv_scale=None):
@@ -296,8 +324,9 @@ class MoeLM:
         over the layer's state pool."""
         where = {}
         if ctx is not None:
-            where = dict(state=ctx["caches"][i],
-                         table=ctx["tables"][self.cache_layers[i][1]])
+            at = self.site[i]
+            where = dict(state=ctx["caches"][at],
+                         table=ctx["tables"][self.cache_layers[at][1]])
             if ctx["mode"] == "decode":
                 where["pos"] = ctx["pos"]
             else:
@@ -343,8 +372,9 @@ class MoeLM:
                 outputs={"Out": [out.name]},
                 attrs=dict(attrs, causal=True, ring_axis=None))
         else:
-            ck, cv = ctx["caches"][i]
-            table = ctx["tables"][self.cache_layers[i][1]]
+            at = self.site[i]
+            ck, cv = ctx["caches"][at]
+            table = ctx["tables"][self.cache_layers[at][1]]
             if ctx["mode"] == "prefill":
                 write, attend = ("kv_cache_write_paged",
                                  "multihead_attention_prefill_paged")
@@ -372,11 +402,11 @@ class MoeLM:
     def _feed_forward(self, m, i):
         """-> (f, the experts' pair counts or None)."""
         if i < self.num_dense_layers:
-            return layers.swiglu(m, self.d_ff, "moe_lm.l%d.mlp" % i,
-                                 self.dtype), None
+            return layers.ffn(m, self.d_ff, "moe_lm.l%d.mlp" % i,
+                              self.dtype, act=self.expert_act), None
         p = "moe_lm.l%d.moe" % i
-        shared = layers.swiglu(m, self.shared_d_ff, p + ".shared",
-                               self.dtype)
+        shared = layers.ffn(m, self.shared_d_ff, p + ".shared", self.dtype,
+                            act=self.expert_act)
         routed, counts = self._experts(m, i)
         return layers.elementwise_add(routed, shared), counts
 
@@ -390,7 +420,8 @@ class MoeLM:
             route_norm=self.route_norm, route_scale=self.route_scale,
             expert_offset=self.expert_offset,
             experts_held=self.experts_held, dtype=self.dtype, std=self.std,
-            scoring=self.scoring, zero_experts=self.zero_experts)
+            scoring=self.scoring, zero_experts=self.zero_experts,
+            act=self.expert_act)
         return routed, (layers.concat(counts, axis=0) if self.zero_experts
                         else counts[0])
 
@@ -421,6 +452,24 @@ class MoeLM:
                     h = self._residual(h, s)
         return h, counts
 
+    def _sublayer(self, h, i, ctx):
+        """Layer i as ONE sublayer -> (``h + f(norm(h))``, an expert
+        layer's counts or None): ``f`` the mixer, the attention with its
+        output projection or the expert layer with its shared expert, by
+        the layer's type, whose name the layer's operations are traced
+        under."""
+        t = self.layer_types[i]
+        with name_scope(_SUBLAYER_SCOPE[t]):
+            a, counts = self._norm(h, "l%d.norm" % i), None
+            if t == EXPERTS:
+                o, counts = self._feed_forward(a, i)
+            elif t == MAMBA:
+                o = self._mixer(a, i, ctx)
+            else:
+                o = self._linear(self._attention(a, i, ctx), self.d,
+                                 "l%d.attn.o" % i)
+            return self._residual(h, o), counts
+
     def hidden(self, tokens, ctx=None):
         """tokens [B, T] -> (h [B, T, d] float32 before the final norm,
         [counts] of the expert layers in order)."""
@@ -436,8 +485,10 @@ class MoeLM:
         all_counts = []
         for i in range(len(self.layer_types)):
             if self.block:
-                h, counts = self._halves(h, i, ctx)
-                all_counts.append(counts)
+                h, counts = (self._sublayer if self.single
+                             else self._halves)(h, i, ctx)
+                if counts is not None:
+                    all_counts.append(counts)
                 continue
             a = self._norm(h, "l%d.norm_in" % i)
             if self.layer_types[i] == MAMBA:

@@ -47,6 +47,10 @@ pays three times the products.
   that fall on its experts, which lie first in the sorted order, in passes
   of at most ``SHARE_ROWS`` rows until none is left: its work and its
   temporaries follow the held pairs, and still no pair is dropped.
+  With attr ``act`` ``relu2`` an expert is **two matrices**,
+  ``relu(x WUp^T)^2 WDown``: no gate, and ``WUp`` held ``[E, f, d]`` as
+  ``WDown`` is (``pallas_moe`` says why), which is what lets a width that
+  is no whole lane tiles take the kernels.
   With ``zero_experts`` the router is that much wider than the experts:
   an output past the last real expert is an **identity expert**, whose
   pair adds ``w x`` and costs no matmul (``zero_expert_combine``). Every
@@ -270,19 +274,26 @@ def route(x, router_w, bias, top_k, route_norm, route_scale,
 SHARE_ROWS = 2048
 
 
-def _expert_rows(xs, wg, wu, wd, counts):
+def _expert_rows(xs, wg, wu, wd, counts, act=None):
     """``(silu(xs WGate) * (xs WUp)) WDown`` of rows sorted by expert,
     ``counts`` [E_held] rows an expert from row 0: xs [r, d] -> [r, d]
-    float32, zeros in the rows past the last expert's. Through
-    ``pallas_moe``'s kernels where they admit the shapes."""
-    if pallas_moe.admits(xs.shape[0], wg):
+    float32, zeros in the rows past the last expert's. With ``act``
+    ``relu2`` ``relu(xs WUp^T)^2 WDown``: no ``wg``, ``wu`` [E, f, d].
+    Through ``pallas_moe``'s kernels where they admit the shapes."""
+    relu2 = act == "relu2"
+    if pallas_moe.admits(xs.shape[0], wu, act):
         interpret = kernel_path.interpret_mode()
         kernel_path.record("moe_grouped_matmul", interpret)
-        ys = pallas_moe.expert_ffn(xs, wg, wu, wd, counts, interpret)
+        ys = pallas_moe.expert_ffn(xs, wg, wu, wd, counts, interpret,
+                                   act=act)
     else:
         kernel_path.record("moe_grouped_matmul")
-        inner = jax.nn.silu(exact_ragged_dot(xs, wg, counts)) * \
-            exact_ragged_dot(xs, wu, counts)
+        if relu2:
+            inner = jnp.square(jax.nn.relu(exact_ragged_dot(
+                xs, jnp.swapaxes(wu, 1, 2), counts)))
+        else:
+            inner = jax.nn.silu(exact_ragged_dot(xs, wg, counts)) * \
+                exact_ragged_dot(xs, wu, counts)
         ys = exact_ragged_dot(inner, wd, counts)        # [r, d] float32
     # rows past the last group are nobody's: whatever they hold, they add 0
     return jnp.where((jnp.arange(xs.shape[0]) < jnp.sum(counts))[:, None],
@@ -298,15 +309,19 @@ def _moe_ffn(ctx):
     Out float32, X's shape: sum over the token's selected experts that
     are held of ``w * (silu(x WGate) * (x WUp)) WDown``. Counts [E_held]
     int32: the pairs each held expert took in this call.
+    With attr ``act`` ``relu2`` (absent: SwiGLU) there is no WGate, WUp is
+    [E_held, f, d] and the expert is ``relu(x WUp^T)^2 WDown``.
     With attr ``zero_experts`` Z (absent: 0) RouterW and ExpertBias are
     ``E + Z`` wide and a selected output ``>= E`` is an identity pair: it
     adds ``w * x`` to Out in float32, falls in no expert's group, and
     ZeroPairs [1] int32 counts the call's."""
     x = ctx.input("X")
-    wg, wu, wd = ctx.input("WGate"), ctx.input("WUp"), ctx.input("WDown")
+    act = ctx.attr("act") or None
+    wg = None if act == "relu2" else ctx.input("WGate")
+    wu, wd = ctx.input("WUp"), ctx.input("WDown")
     k = ctx.attr("top_k")
     offset = ctx.attr("expert_offset", 0)
-    held, d = wg.shape[0], x.shape[-1]
+    held, d = wd.shape[0], x.shape[-1]
     x2 = x.reshape(-1, d)
     n = x2.shape[0]
     router_w = ctx.input("RouterW")
@@ -333,7 +348,7 @@ def _moe_ffn(ctx):
         min(n * k, SHARE_ROWS, pallas_moe.MAX_PAIRS_PER_EXPERT * held)
     if rows == n * k:
         # every pair in one pass
-        ys = _expert_rows(x2[order // k], wg, wu, wd, counts)
+        ys = _expert_rows(x2[order // k], wg, wu, wd, counts, act)
         # back to the pairs' own order, then the weighted sum over a
         # token's k
         y = ys[jnp.argsort(order)] * pair_w[:, None]
@@ -353,7 +368,7 @@ def _moe_ffn(ctx):
             pairs = jax.lax.dynamic_slice(order, (first,), (rows,))
             here = jnp.clip(jnp.minimum(end, first + rows)
                             - jnp.maximum(start, first), 0, rows)
-            ys = _expert_rows(x2[pairs // k], wg, wu, wd, here)
+            ys = _expert_rows(x2[pairs // k], wg, wu, wd, here, act)
             ys = jnp.where((first + jnp.arange(rows) < total)[:, None],
                            ys * pair_w[pairs][:, None], 0.0)
             return out.at[pairs // k].add(ys)

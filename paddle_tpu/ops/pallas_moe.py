@@ -21,6 +21,17 @@ added smallest first. Only the order of the float32 sums over ``d`` may
 differ (on the chip it did not: the results were ``ragged_dot``'s bit
 for bit at every size measured).
 
+An expert's width that is no whole lane tiles (1856 = 14.5 x 128) has no
+column tile, and held ``[d, f]`` XLA lays such a stack out with ``d`` on
+the lanes and copies it whole, every call, into the layout the kernel asks
+for (660 MB a layer at 64 experts of 2688 x 1856: the compiler's own HLO,
+PR 46). So such an expert's first matrix is held **as the down matrix
+lies**, ``[f, d]`` with the model's width on the lanes: nothing is padded
+in the HBM, the whole matrix is one block as tall as the array, and the
+product contracts the lanes of both operands. That is the two-matrix
+form, ``act`` ``relu2``: one stack, held so, the relu squared in the
+epilogue. There is no other knob, and no SwiGLU stack held ``[f, d]``.
+
 Rows are sorted by expert and lie in aligned tiles of ``_ROW_TILE`` rows
 (Mosaic proves no alignment of a data-dependent row start), so a tile
 that two experts share is visited by both, each keeping the other's rows
@@ -51,6 +62,14 @@ _ROW_TILE = 64
 # expert at Trinity-Mini's 2048 x 1024 in bfloat16
 _WEIGHT_TILE_BYTES = 4 << 20
 
+# a matrix whose width is no whole lane tiles is one block whatever it
+# weighs, up to this: 1856 x 2688 in bfloat16 is 9.98 MB, two in flight
+# 20 MB of the chip's 128 MiB of VMEM
+_WHOLE_MATRIX_BYTES = 12 << 20
+
+# the rows of a bfloat16 block come in sublane tiles of this many
+_SUBLANES = 16
+
 
 def _pieces(x):
     """``moe_ops._pieces`` in integer arithmetic, bit for bit: Mosaic
@@ -71,7 +90,9 @@ def _pieces(x):
 def _col_tile(d, f):
     """Columns of a weight block: all ``f`` where ``[d, f]`` bfloat16 fits
     ``_WEIGHT_TILE_BYTES``, else the most whole lane tiles that divide
-    ``f`` and fit."""
+    ``f`` and fit; an ``f`` that is no whole lane tiles is one block."""
+    if f % 128:
+        return f
     return max([t for t in range(128, f + 1, 128)
                 if f % t == 0 and d * t * 2 <= _WEIGHT_TILE_BYTES] or [128])
 
@@ -98,11 +119,14 @@ def work_items(counts, tiles, tm):
 
 
 def _kernel(expert_ref, tile_ref, lo_ref, hi_ref, n_ref, x_ref, *refs,
-            tm):
+            tm, act):
     """One (expert, row tile) step: the tile's rows in three pieces
     against the expert's block of each weight; the rows that are the
     expert's take the result, the others stay. With two weights the
-    result is ``silu(x W0) * (x W1)``."""
+    result is ``silu(x W0) * (x W1)``; with one and ``act`` ``relu2`` it
+    is ``relu(x W0^T)^2``, the block ``[f, d]`` contracted over its
+    lanes."""
+    transposed = act == "relu2"
     del expert_ref                        # the weights' index map reads it
     w_refs, o_ref = refs[:-1], refs[-1]
     i = pl.program_id(1)
@@ -114,29 +138,44 @@ def _kernel(expert_ref, tile_ref, lo_ref, hi_ref, n_ref, x_ref, *refs,
         for w_ref in w_refs:
             # explicit Precision: the executor traces TPU steps under a
             # default Mosaic does not lower
-            y = jnp.dot(p, w_ref[0], preferred_element_type=jnp.float32,
-                        precision=jax.lax.Precision.DEFAULT)
+            y = jax.lax.dot_general(
+                p, w_ref[0], (((1,), (1 if transposed else 0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT)
             ys.append((y[2 * tm:] + y[tm:2 * tm]) + y[:tm])
-        y = jax.nn.silu(ys[0]) * ys[1] if len(ys) == 2 else ys[0]
+        if len(ys) == 2:
+            y = jax.nn.silu(ys[0]) * ys[1]
+        elif transposed:
+            y = jnp.square(jnp.maximum(ys[0], 0.0))
+        else:
+            y = ys[0]
         row = tile_ref[i] * tm + jax.lax.broadcasted_iota(
             jnp.int32, (tm, 1), 0)
         o_ref[...] = jnp.where((row >= lo_ref[i]) & (row < hi_ref[i]), y,
                                o_ref[...])
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def grouped_matmul(xs, ws, items, tm, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def grouped_matmul(xs, ws, items, tm, interpret, act=None):
     """xs [n, d] float32, rows sorted by group, ``n`` whole tiles of
     ``tm``; ws: one or two ``[G, d, f]`` bfloat16; ``items`` from
-    :func:`work_items`. -> [n, f] float32: a row of group g is
-    ``xs @ ws[0][g]``, or ``silu(xs @ ws[0][g]) * (xs @ ws[1][g])``,
-    every product exact. Under a jit of its own: a model's layers share
-    their geometry, so the body is traced once a process."""
+    :func:`work_items`. -> [n, f] float32: a row of group g is ``xs @
+    ws[0][g]``, or ``silu(xs @ ws[0][g]) * (xs @ ws[1][g])``, every
+    product exact. ``act`` ``relu2`` is the first product of a two-matrix
+    expert, and ONE form: ws is one ``[G, f, d]`` stack read as it lies
+    (the module's docstring) and a row is ``relu(xs @ ws[0][g]^T)^2``.
+    Under a jit of its own: a model's layers share their geometry, so the
+    body is traced once a process."""
     from jax.experimental.pallas import tpu as pltpu
+    transposed = act == "relu2"
+    if act not in (None, "relu2") or (transposed and len(ws) != 1):
+        raise ValueError("act is None or 'relu2' over one [G, f, d] stack")
     n, d = xs.shape
-    f = ws[0].shape[2]
+    f = ws[0].shape[1 if transposed else 2]
     tn = _col_tile(d, f)
-    w_spec = pl.BlockSpec((1, d, tn), lambda c, i, e, *_: (e[i], 0, c))
+    w_spec = pl.BlockSpec((1, tn, d), lambda c, i, e, *_: (e[i], c, 0)) \
+        if transposed else \
+        pl.BlockSpec((1, d, tn), lambda c, i, e, *_: (e[i], 0, c))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         # columns outermost: a row tile's visits stay consecutive, so its
@@ -146,7 +185,7 @@ def grouped_matmul(xs, ws, items, tm, interpret):
         + [w_spec] * len(ws),
         out_specs=pl.BlockSpec((tm, tn), lambda c, i, e, t, *_: (t[i], c)))
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm),
+        functools.partial(_kernel, tm=tm, act=act),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, f), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -157,26 +196,39 @@ def grouped_matmul(xs, ws, items, tm, interpret):
         interpret=interpret)(*items, xs, *ws)
 
 
-def admits(n_pairs, w):
+def admits(n_pairs, w, act=None):
     """The static test for taking these kernels, read off the call's
-    shapes: ``n_pairs`` rows over the experts of ``w`` [G, d, f]. The
-    weights are held in bfloat16, ``d`` and ``f`` are whole lane tiles,
-    and an expert has at most ``MAX_PAIRS_PER_EXPERT`` rows in the
-    mean."""
+    shapes: ``n_pairs`` rows over the experts of ``w``, a matrix of the
+    first product, ``[G, d, f]`` or, of a two-matrix expert (``act``
+    ``relu2``), ``[G, f, d]``. The weights are held in bfloat16, the
+    model's width ``d`` is whole lane tiles, the expert's width ``f`` is
+    whole lane tiles too, or (held ``[G, f, d]`` only: the module's
+    docstring) whole sublane tiles of a matrix that is one block, and an
+    expert has at most ``MAX_PAIRS_PER_EXPERT`` rows in the mean."""
     g, d, f = w.shape
-    return (w.dtype == jnp.bfloat16 and d % 128 == 0 and f % 128 == 0
+    transposed = act == "relu2"
+    if transposed:
+        d, f = f, d
+    whole = f % 128 == 0 or (transposed and f % _SUBLANES == 0
+                             and 2 * d * f <= _WHOLE_MATRIX_BYTES)
+    return (w.dtype == jnp.bfloat16 and d % 128 == 0 and whole
             and n_pairs <= MAX_PAIRS_PER_EXPERT * g)
 
 
-def expert_ffn(xs, wg, wu, wd, counts, interpret, tm=_ROW_TILE):
+def expert_ffn(xs, wg, wu, wd, counts, interpret, tm=_ROW_TILE, act=None):
     """``silu(xs WGate) * (xs WUp)`` then ``WDown`` for rows sorted by
     expert, through :func:`grouped_matmul`: xs [n, d] float32, the
     weights stacked ``[G, ..]`` in bfloat16, ``counts`` [G] -> [n, d]
-    float32. ``tm``: rows of a tile (the probe that sized it asks for
+    float32. With ``act`` ``relu2`` there is no ``wg``, ``wu`` is held
+    ``[G, f, d]`` as ``wd`` is, and the inner rows are ``relu(xs
+    WUp^T)^2``. ``tm``: rows of a tile (the probe that sized it asks for
     others)."""
     n = xs.shape[0]
     tiles = -(-n // tm)
     xs = jnp.pad(xs, ((0, tiles * tm - n), (0, 0)))
     items = work_items(counts, tiles, tm)
-    inner = grouped_matmul(xs, (wg, wu), items, tm, interpret)
+    if act == "relu2":
+        inner = grouped_matmul(xs, (wu,), items, tm, interpret, act)
+    else:
+        inner = grouped_matmul(xs, (wg, wu), items, tm, interpret)
     return grouped_matmul(inner, (wd,), items, tm, interpret)[:n]

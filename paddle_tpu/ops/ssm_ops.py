@@ -1,15 +1,17 @@
 """The Mamba-2 mixer (a state-space layer; Dao & Gu 2024, "Transformers are
 SSMs"), twice over as ``mla_ops`` has latent attention twice over. With
-``H`` heads of ``P`` lanes, a state of ``N`` numbers a lane, one group of
-``B`` and ``C``, ``d_inner = H P`` and a depthwise causal convolution of
-width ``K`` over the ``d_inner + 2N`` lanes of ``xBC``:
+``H`` heads of ``P`` lanes, a state of ``N`` numbers a lane, ``G`` groups of
+``B`` and ``C`` (head ``h`` reads group ``h // (H / G)``; one group: every
+head the same), ``d_inner = H P`` and a depthwise causal convolution of
+width ``K`` over the ``d_inner + 2GN`` lanes of ``xBC``:
 
     [z, xBC, dt] = u W_in                     (no bias)
     xBC_t = silu(sum_k w[k] * xBC_{t-K+1+k} + b)      zeros before row 0
     [x, B, C] = split(xBC_t);  dt_t = softplus(dt_t + dt_bias);  A = -exp(A_log)
-    S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] x_t[h] (outer) B_t
-    y_t[h] = S_t[h] C_t + D[h] x_t[h]
-    out = (rmsnorm(y * silu(z)) * w_norm) W_out
+    S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] x_t[h] (outer) B_t[g(h)]
+    y_t[h] = S_t[h] C_t[g(h)] + D[h] x_t[h]
+    out = (rmsnorm(y * silu(z)) * w_norm) W_out     the mean of squares
+                                  within each group's d_inner / G lanes
 
 * ``mamba2_mixer`` — whole rows of sequences from a zero state, in the
   **chunked** form (SSD): inside a chunk of ``chunk`` rows the recurrence
@@ -49,13 +51,29 @@ from .moe_ops import exact_dot
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
+def _groups_of(b, h):
+    """(G, H / G) of B and C ``[.., G, N]`` under ``h`` heads."""
+    g = b.shape[-2]
+    if h % g:
+        raise ValueError("%d heads in %d groups of B and C" % (h, g))
+    return g, h // g
+
+
 def ssd_chunked(x, dt, a, b, c, chunk):
     """The selective scan of one sequence from a zero state, chunked:
     x [T, H, P], dt [T, H] (after softplus; 0 in rows that move nothing),
     a [H] (negative), b, c [T, N]; ``chunk`` divides T. Returns
     (y [T, H, P] without the ``D x`` term, the state after row T-1
-    [H, P, N]). Every exponent is of a number <= 0."""
+    [H, P, N]). Every exponent is of a number <= 0. With b, c [T, G, N]
+    each group's H / G heads scan under their own B and C."""
     t, h, p = x.shape
+    if b.ndim == 3:
+        g, hg = _groups_of(b, h)
+        y, last = jax.vmap(
+            lambda *one: ssd_chunked(*one, chunk), in_axes=(1, 1, 0, 1, 1),
+            out_axes=(1, 0))(x.reshape(t, g, hg, p), dt.reshape(t, g, hg),
+                             a.reshape(g, hg), b, c)
+        return y.reshape(t, h, p), last.reshape(h, p, -1)
     n, q, nc = b.shape[1], chunk, t // chunk
     xc, dtc = x.reshape(nc, q, h, p), dt.reshape(nc, q, h)
     bc, cc = b.reshape(nc, q, n), c.reshape(nc, q, n)
@@ -93,26 +111,51 @@ def ssm_step(ssm, dt, a, x, b, c, fresh):
     [S, N], ``fresh`` [S] bool. -> (the pool with the rows of ``fresh``
     advanced and the others as they were, y [S, H, P] = ``S_new C``
     without the ``D x`` term): elementwise, the pool read once and
-    written once."""
+    written once. With b, c [S, G, N] each group's H / G heads take
+    their own B and C: spread over the heads first (a few MB), so that
+    the update stays the one elementwise pass over the pool (over a
+    leading group axis XLA made it three: a transposed update and a
+    second read for ``y``; PERF.md, PR 46)."""
+    if b.ndim == 3:
+        per_group = _groups_of(b, ssm.shape[1])[1]
+        b, c = (jnp.repeat(v, per_group, axis=1)[:, :, None, :]
+                for v in (b, c))
+    else:
+        b, c = b[:, None, None, :], c[:, None, None, :]
     step = jnp.exp(dt * a)[..., None, None] * ssm + \
-        (dt[..., None] * x)[..., None] * b[:, None, None, :]
+        (dt[..., None] * x)[..., None] * b
     new = jnp.where(fresh[:, None, None, None], step, ssm)
-    return new, jnp.sum(new * c[:, None, None, :], axis=-1)
+    return new, jnp.sum(new * c, axis=-1)
 
 
 def _sizes(ctx):
     return ctx.attr("num_heads"), ctx.attr("head_dim"), ctx.attr("state_dim")
 
 
+def _groups(ctx):
+    return ctx.attr("n_groups") or 1
+
+
 def _split_in(ctx, x2):
-    """x2 [n, d] -> (z [n, HP], xBC [n, HP + 2N], dt [n, H]) = ``x W_in``."""
+    """x2 [n, d] -> (z [n, HP], xBC [n, HP + 2GN], dt [n, H]) = ``x W_in``."""
     h, p, n = _sizes(ctx)
-    di = h * p
+    di, gn = h * p, _groups(ctx) * n
     zxd = exact_dot(x2, ctx.input("WIn"))
-    if zxd.shape[1] != 2 * di + 2 * n + h:
+    if zxd.shape[1] != 2 * di + 2 * gn + h:
         raise ValueError("WIn has %d columns, [z, xBC, dt] needs %d"
-                         % (zxd.shape[1], 2 * di + 2 * n + h))
-    return zxd[:, :di], zxd[:, di:2 * di + 2 * n], zxd[:, 2 * di + 2 * n:]
+                         % (zxd.shape[1], 2 * di + 2 * gn + h))
+    return zxd[:, :di], zxd[:, di:2 * di + 2 * gn], zxd[:, 2 * di + 2 * gn:]
+
+
+def _split_bc(ctx, act, di):
+    """The lanes of the convolved ``xBC`` past ``x`` [.., 2GN] -> (B, C),
+    each [.., N] for one group and [.., G, N] for more."""
+    g, n = _groups(ctx), ctx.attr("state_dim")
+    b, c = act[..., di:di + g * n], act[..., di + g * n:]
+    if g == 1:
+        return b, c
+    return (b.reshape(b.shape[:-1] + (g, n)),
+            c.reshape(c.shape[:-1] + (g, n)))
 
 
 def _dt_a(ctx, dt):
@@ -121,25 +164,31 @@ def _dt_a(ctx, dt):
 
 
 def _gate_and_out(ctx, y, z):
-    """y, z [n, HP] -> ``(rmsnorm(y silu(z)) w_norm) W_out`` [n, d]."""
+    """y, z [n, HP] -> ``(rmsnorm(y silu(z)) w_norm) W_out`` [n, d], the
+    norm's mean of squares within each group's ``HP / G`` lanes."""
     g = y * jax.nn.silu(z)
+    shape = g.shape
+    if _groups(ctx) > 1:
+        g = g.reshape(shape[0], _groups(ctx), -1)
     g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
                           + ctx.attr("epsilon", 1e-5))
-    return exact_dot(g * ctx.input("NormW").astype(jnp.float32),
+    return exact_dot(g.reshape(shape)
+                     * ctx.input("NormW").astype(jnp.float32),
                      ctx.input("WOut"))
 
 
 @register_op("mamba2_mixer")
 def _mamba2_mixer(ctx):
-    """X [B, T, d]; WIn [d, 2HP + 2N + H], ConvW [K, HP + 2N], ConvB,
+    """X [B, T, d]; WIn [d, 2HP + 2GN + H], ConvW [K, HP + 2GN], ConvB,
     DtBias [H], ALog [H], D [H], NormW [HP], WOut [HP, d]; attrs num_heads,
-    head_dim, state_dim, chunk, epsilon. Out [B, T, d] float32: every
+    head_dim, state_dim, chunk, epsilon and, past one group of B and C,
+    n_groups. Out [B, T, d] float32: every
     sequence from a zero state. With a state pool (B = 1: a prefill) also
     Ssm [R, H, P, N], Conv [R, K, HP + 2N], At [R] int32, Len [1] and
     Table [1]: row ``Table[0]`` is left holding the state after row
     ``Len - 1`` (the module's docstring), a dead entry drops the write."""
     x = ctx.input("X").astype(jnp.float32)
-    h, p, n = _sizes(ctx)
+    h, p, _ = _sizes(ctx)
     bsz, t, d = x.shape
     di = h * p
     z, xbc, dt = _split_in(ctx, x.reshape(-1, d))
@@ -162,8 +211,7 @@ def _mamba2_mixer(ctx):
     q = next(r for r in range(min(t, ctx.attr("chunk")), 0, -1)
              if t % r == 0)
     y, last = jax.vmap(lambda x1, dt1, b1, c1: ssd_chunked(
-        x1, dt1, a, b1, c1, q))(xs, dt, act[..., di:di + n],
-                                act[..., di + n:])
+        x1, dt1, a, b1, c1, q))(xs, dt, *_split_bc(ctx, act, di))
     y = y + ctx.input("D").astype(jnp.float32)[:, None] * xs
     out = {"Out": _gate_and_out(ctx, y.reshape(-1, di), z)
            .reshape(bsz, t, d)}
@@ -187,7 +235,7 @@ def _mamba2_mixer_decode(ctx):
     row as stored; a row that is not the slot's to write gives numbers
     nobody reads."""
     x = ctx.input("X").astype(jnp.float32)
-    h, p, n = _sizes(ctx)
+    h, p, _ = _sizes(ctx)
     s, _, d = x.shape
     di = h * p
     ssm, conv, at = ctx.input("Ssm"), ctx.input("Conv"), ctx.input("At")
@@ -208,8 +256,7 @@ def _mamba2_mixer_decode(ctx):
     act = jnp.sum(window * ctx.input("ConvW").astype(jnp.float32), axis=1)
     act = jax.nn.silu(act + ctx.input("ConvB").astype(jnp.float32))
     xs = act[:, :di].reshape(s, h, p)
-    b, c = act[:, di:di + n], act[:, di + n:]
-    new, y = ssm_step(ssm, dt, a, xs, b, c, fresh)
+    new, y = ssm_step(ssm, dt, a, xs, *_split_bc(ctx, act, di), fresh)
     y = y + ctx.input("D").astype(jnp.float32)[:, None] * xs
     return {"Out": _gate_and_out(ctx, y.reshape(s, di), z).reshape(s, 1, d),
             "SsmOut": new, "ConvOut": window,

@@ -645,6 +645,106 @@ def test_granite_prefill_compiles_with_its_chunks_and_its_passes(
     assert "ragged" not in hlo
 
 
+@pytest.fixture(scope="module")
+def nemotron_programs(chip):
+    """nemotron-serve-offline's programs as the executor compiles them, for
+    the described chip: the configuration at its published widths, its
+    deployment's 128 slots and 32,768 blocks, cut to one layer of each
+    letter, ``ME*`` (every layer of a letter compiles alike).
+    -> {"decode" | 2048: (memory, HLO)}, and the kernel paths counted."""
+    import numpy as np
+    import paddle_tpu as ptpu
+    from benchmarks import architectures
+    from benchmarks.harness import lm
+    from benchmarks.sweeps import sizing
+    cfg = lm.load_config("nemotron-3-nano-30b-a3b-l13")
+    cfg.update(num_hidden_layers=3, hybrid_override_pattern="ME*")
+    arch = architectures.load(cfg)
+    geometry = cfg["deployment"]["serving"]
+    patch = pytest.MonkeyPatch()
+    patch.setattr(kernel_path, "interpret_mode", lambda: False)
+    before = {k: dict(v) for k, v in kernel_path.counts().items()}
+    out = {}
+    try:
+        with lm.flags(generation_kv_dtype=geometry["kv_dtype"],
+                      matmul_precision="BF16_BF16_F32", **cfg["flags"]):
+            with ptpu.unique_name.guard():
+                startup = arch.serve_startup(cfg, 0)
+            spec = arch.serve_spec(cfg, geometry, (2048,))
+            scope = sizing._ShapeScope([startup], more=spec.cache_vars)
+            exe = ptpu.Executor()
+            s, mb = spec.slots, spec.max_blocks
+            feed = {"gen.dtok": np.zeros((s, 1), "int64"),
+                    "gen.dpos": np.zeros((s,), "int32"),
+                    "gen.dtab": np.zeros((s, mb), "int32"),
+                    "gen.dtab.state": np.zeros((s, 1), "int32")}
+            out["decode"] = sizing._compile(
+                exe, spec.decode_program, feed,
+                [spec.decode_fetch, spec.stats_fetch], scope, chip)
+            feed = {"gen.ptok": np.zeros((1, 2048), "int64"),
+                    "gen.plen": np.ones((1,), "int32"),
+                    "gen.ppos": np.zeros((1,), "int32"),
+                    "gen.phist": np.zeros((1,), "int32"),
+                    "gen.ppix": np.zeros((2048,), "int32"),
+                    "gen.ptab": np.zeros((mb,), "int32"),
+                    "gen.ptab.state": np.zeros((1,), "int32")}
+            out[2048] = sizing._compile(
+                exe, spec.prefill_programs[2048], feed, [spec.prefill_fetch],
+                scope, chip)
+    finally:
+        patch.undo()
+    after = kernel_path.counts()
+    paths = {k: {p: n - before.get(k, {}).get(p, 0) for p, n in v.items()
+                 if n != before.get(k, {}).get(p, 0)}
+             for k, v in after.items()}
+    return out, {k: v for k, v in paths.items() if v}
+
+
+def test_nemotron_decode_step_streams_its_experts_as_they_lie(
+        nemotron_programs):
+    """128 slots: the held expert layer's two products are two Mosaic
+    calls named ``moe_grouped_matmul`` over the stacks ``[64, 1856, 2688]``
+    as they lie (no copy of either 319 MB stack, which held ``[64, 2688,
+    1856]`` the compiler made every step; no ``ragged_dot`` fallback), the
+    attention layer's paged decode is a Mosaic call, and the mixer's state
+    pool (281 MB) is an argument that the step's output aliases."""
+    (mem, hlo), paths = nemotron_programs[0]["decode"], nemotron_programs[1]
+    stack = 64 * 1856 * 2688 * 2
+    state = 128 * 4 * (64 * 64 * 128 + 4 * 6144)
+    assert mem["alias_bytes"] > state
+    assert mem["temp_bytes"] < stack // 2, mem
+    calls = re.findall(r"%moe_grouped_matmul(?:\.\d+)? = [^\n]*"
+                       r"tpu_custom_call[^\n]*", hlo)
+    assert len(calls) == 2 * 1, hlo[-3000:]
+    assert all("bf16[64,1856,2688]{2,1,0}" in c for c in calls)
+    assert "f32[768,1856]" in calls[0] + calls[1]
+    assert not re.search(r"bf16\[64,2688,1856\]", hlo)
+    assert not re.search(r"= bf16\[64,1856,2688\][^\n]* copy\(", hlo)
+    assert _has_kernel(hlo, "decode_attention_paged")
+    assert "f32[128,64,64,128]" in hlo
+    assert "ragged" not in hlo
+    assert set(paths["moe_grouped_matmul"]) == {"compiled"}
+    assert set(paths["decode_attention_paged"]) == {"compiled"}
+
+
+def test_nemotron_prefill_compiles_with_its_groups_and_its_passes(
+        nemotron_programs):
+    """The 2,048 bucket: 16 chunks of 128 rows, the scores of each of the
+    eight groups, the state written into the slot's row of the pool in
+    place, and the held experts' 6,144 expected pairs taken in passes of
+    ``SHARE_ROWS`` 2,048 rows by the kernel inside a loop."""
+    mem, hlo = nemotron_programs[0][2048]
+    state = 128 * 4 * (64 * 64 * 128 + 4 * 6144)
+    assert mem["alias_bytes"] > state
+    assert mem["temp_bytes"] < 1.0e9, mem
+    assert _has_kernel(hlo, "moe_grouped_matmul")
+    assert "f32[2048,1856]" in hlo and "f32[12288,2688]" not in hlo
+    assert re.search(r"f32\[(8,16|16,8),128,128\]", hlo), \
+        "the groups' chunk scores"
+    assert not re.search(r"= bf16\[64,1856,2688\][^\n]* copy\(", hlo)
+    assert "ragged" not in hlo
+
+
 def _update_fusions(chip):
     """The FFN's down projection at the training cells' shape (8,192 rows
     of 8,192 -> 2,048, amp bfloat16) with its ``vjp_grad`` and ``adam``,

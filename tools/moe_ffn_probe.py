@@ -70,8 +70,73 @@ def _ceiling(xs, ws, items, tm, interpret):
         name="moe_ceiling", interpret=interpret)(*items, xs, *ws)
 
 
+def two_matrix(args, interpret):
+    """The relu2 form: the kernels against ``exact_ragged_dot`` over an up
+    matrix laid ``[d, f]`` (what a holder without the kernels would run),
+    at each number of pairs."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import moe_ops, pallas_moe
+    E, D, F = (int(w) for w in args.widths.split(","))
+    key = jax.random.split(jax.random.PRNGKey(args.seed % (2 ** 31)), 2)
+    wu, wd = (jax.random.normal(k, (E, F, D), jnp.bfloat16) * 0.02
+              for k in key)
+    wu_t = jnp.swapaxes(wu, 1, 2)       # made once, outside the timed call
+    rs = np.random.RandomState(args.seed % (2 ** 31))
+    assert pallas_moe.admits(1, wu, "relu2"), (E, D, F)
+
+    @jax.jit
+    def ragged(xs, counts, wu_t, wd):
+        inner = jnp.square(jax.nn.relu(
+            moe_ops.exact_ragged_dot(xs, wu_t, counts)))
+        return moe_ops.exact_ragged_dot(inner, wd, counts)
+
+    def kernel_of(tm):
+        return jax.jit(lambda xs, counts, wu, wd: pallas_moe.expert_ffn(
+            xs, None, wu, wd, counts, interpret, tm, act="relu2"))
+
+    def timed(fn, *a):
+        t0 = time.perf_counter()
+        out = fn(*a).block_until_ready()
+        first = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        for _ in range(args.reps):
+            out = fn(*a)
+        out.block_until_ready()
+        return out, (time.perf_counter() - t0) / args.reps * 1e3, first
+
+    for n in (int(t) for t in args.pairs.split(",")):
+        c = rs.multinomial(n, np.full(E, 1.0 / E)).astype(np.int32)
+        x = rs.randn(n, D).astype(np.float32)
+        x /= np.sqrt((x ** 2).mean(-1, keepdims=True))
+        xs, counts = jnp.asarray(x), jnp.asarray(c)
+        touched = int((c > 0).sum())
+        bytes_s = touched * 2 * D * F * 2 / HBM_BYTES_PER_S
+        flops_s = n * 2 * 3 * 2 * D * F / PEAK_FLOPS
+        common = dict(form="relu2", pairs=n, pairs_per_expert=n / E,
+                      touched=touched, busiest=int(c.max()))
+        want, ms, first = timed(ragged, xs, counts, wu_t, wd)
+        say(path="ragged_dot", ms=ms, first_call_s=first,
+            bytes_floor_share=bytes_s / ms * 1e3,
+            flops_floor_share=flops_s / ms * 1e3, **common)
+        want = np.asarray(want)
+        scale = float(np.abs(want).max())
+        for tm in (int(t) or pallas_moe._ROW_TILE
+                   for t in args.tm.split(",")):
+            got, ms, first = timed(kernel_of(tm), xs, counts, wu, wd)
+            say(path="pallas_moe", tm=tm, ms=ms, first_call_s=first,
+                bytes_floor_share=bytes_s / ms * 1e3,
+                flops_floor_share=flops_s / ms * 1e3,
+                err_vs_ragged_dot=float(
+                    np.abs(np.asarray(got) - want).max()) / scale, **common)
+    say(summary=True, device=str(jax.devices()[0]), widths=[E, D, F])
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
+    ap.add_argument("--form", default="swiglu", choices=("swiglu", "relu2"))
+    ap.add_argument("--pairs", default="384,5120",
+                    help="relu2: rows of a call, spread over the experts")
     ap.add_argument("--tokens", default="64,256,1024,4096")
     ap.add_argument("--tm", default="0", help="row tiles; 0: the op's own")
     ap.add_argument("--reps", type=int, default=20)
@@ -89,6 +154,8 @@ def main(argv=None):
     interpret = jax.default_backend() != "tpu"
     if interpret and not args.rehearse:
         raise SystemExit("moe_ffn_probe.py measures a chip: no TPU here")
+    if args.form == "relu2":
+        return two_matrix(args, interpret)
     key = jax.random.split(jax.random.PRNGKey(args.seed % (2 ** 31)), 4)
     wg, wu = (jax.random.normal(key[i], (E, D, F), jnp.bfloat16) * 0.02
               for i in (0, 1))
