@@ -86,7 +86,8 @@ Metrics (always-on, like the serving front door):
 ``paddle_generation_requests_total``, ``_tokens_total``,
 ``_prefills_total``, ``_decode_steps_total``,
 ``_decode_steps_ahead_total`` (of them, the steps launched while the
-one before was uncollected),
+one before was uncollected), ``_first_tokens_owed_total`` (the prefills
+whose first token was fetched with a decode step queued behind them),
 ``_retired_total{reason}``, ``_slot_occupancy``,
 ``_ttft_seconds`` (time to first token), ``_inter_token_seconds``;
 the dispatcher's clock by phase: ``_host_ms_total{phase}``,
@@ -184,6 +185,11 @@ _STEPS_AHEAD = _metrics.REGISTRY.counter(
     "Decode steps put on the device's queue while the session's "
     "previous step was still uncollected (over _decode_steps_total: "
     "the share of steps launched one step ahead)")
+_FIRST_TOKENS_OWED = _metrics.REGISTRY.counter(
+    "paddle_generation_first_tokens_owed_total",
+    "Admissions whose first token was fetched with a decode step already "
+    "on the device's queue behind the prefill (over _prefills_total: the "
+    "share of prefills whose host turn ran beside the device)")
 _HOST_MS = _metrics.REGISTRY.counter(
     "paddle_generation_host_ms_total",
     "Dispatcher milliseconds in host turns (a step's tokens on the "
@@ -407,6 +413,27 @@ class GenerationSpec:
                     prog.name = "draft_" + prog.name
 
 
+class _Admission:
+    """A prefill on the device's queue, between ``admit_launch`` and
+    ``admit_collect``: the sequence, the slot and blocks taken for it, and
+    ``outs``, the device arrays it will fetch (its first token). Once
+    ``admit_enter`` has put the slot in the books with that token owed
+    (``entered``), ``published`` holds what the prefix index took of it.
+    The handle is also the *tenancy*: a slot's next admission, of the
+    same request or another, is another handle. ``admit_s`` is its
+    caller's to keep (the scheduler's seconds of ``scheduler:admit``)."""
+
+    __slots__ = ("prompt", "slot", "tables", "bucket", "matched", "outs",
+                 "seed", "cstate", "entered", "published", "admit_s")
+
+    def __init__(self, prompt, slot, tables, bucket, matched, outs, seed,
+                 cstate):
+        self.prompt, self.slot, self.tables = prompt, slot, tables
+        self.bucket, self.matched, self.outs = bucket, matched, outs
+        self.seed, self.cstate = seed, cstate
+        self.entered, self.published, self.admit_s = False, (), 0.0
+
+
 class _Flight:
     """A decode step on the device's queue, between ``step_launch`` and
     ``step_collect``: the slots it advances with their retirement counts
@@ -551,12 +578,16 @@ class GenerationSession:
         # retired since its launch is told from its successor's
         self._flights = collections.deque()
         self._retires = np.zeros(n, np.int64)
+        # slot -> the admission whose first token is owed (admit_enter):
+        # in the books, its prefill launched, the token still on the
+        # device, where the next step's feed takes it (``_place_token``)
+        self._owed = {}
         # the next feed needs no token on the host: what a scheduler
         # reads to work one step ahead (GenerationScheduler, "One step
         # ahead"). A constraint's mask comes from the token itself and a
         # speculative round interleaves host and device
         self.lookahead = not (self.constrained or self.speculative)
-        self._merge_tokens = self._token_dtype = None
+        self._merge_tokens = self._place_token = self._token_dtype = None
         if self.lookahead:
             self._compile_token_merge()
         self.draft = None
@@ -792,6 +823,7 @@ class GenerationSession:
             self.scope.erase(name)
         self._claimed = set()
         self._flights.clear()
+        self._owed.clear()
         self.active[:] = False
 
     # -- decode-policy plumbing ------------------------------------------
@@ -810,10 +842,9 @@ class GenerationSession:
             feed["gen.pmask"] = self._mask_table[
                 c.state_index(state)].reshape(1, -1)
 
-    def _policy_admitted(self, slot, first, seed, cstate):
-        """Record per-slot policy state once an admission emitted its
-        first token, and mirror the admission into the draft."""
-        self.seeds[slot] = int(seed)
+    def _policy_admitted(self, slot, first, cstate):
+        """Record the constraint's per-slot state once an admission
+        emitted its first token."""
         if self.constrained:
             c = self.policy.constraint
             state = c.start if cstate is None else cstate
@@ -859,14 +890,17 @@ class GenerationSession:
         Two phases, like a decode step: :meth:`admit_launch` ends with
         the prefill on the device's queue, :meth:`admit_collect` is the
         wait for its first token and the slot's books. A scheduler that
-        has a decode step queued ahead of the prefill collects that step
-        between the two."""
+        works a step ahead calls :meth:`admit_enter` between the two: the
+        slot is in the books at once with its first token owed, the next
+        decode step takes that token on the device, and the wait comes
+        with that step queued behind the prefill."""
         return self.admit_collect(self.admit_launch(prompt, seed, cstate))
 
     def admit_launch(self, prompt, seed=0, cstate=None):
         """Phase 1 of an admission: the slot, its blocks and the prefill
         call, not waited for. Returns the handle for
-        :meth:`admit_collect`; the slot is not active until then."""
+        :meth:`admit_collect`; the slot is not active until then (or
+        until :meth:`admit_enter`)."""
         prompt = np.asarray(prompt, np.int64).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -930,52 +964,90 @@ class GenerationSession:
         except BaseException:
             self._admit_rollback(tables)
             raise
-        return (prompt, slot, tables, bucket, matched, outs, seed, cstate)
+        return _Admission(prompt, slot, tables, bucket, matched, outs, seed,
+                          cstate)
 
     def _admit_rollback(self, tables):
         for kind, table in zip(self.kinds, tables):
             kind.drop(table)
 
-    def admit_abandon(self, launched):
-        """Give back what :meth:`admit_launch` took, for a prefill whose
-        first token nobody will wait for."""
-        self._admit_rollback(launched[2])
+    def owed(self):
+        """The admissions whose first token is owed, oldest first."""
+        return list(self._owed.values())
 
-    def admit_collect(self, launched):
-        """Phase 2 of an admission: wait for the prefill's first token
-        (``session:prefill_wait``) and enter the sequence in the slot's
-        books. Returns ``(slot, token)``."""
-        prompt, slot, tables, bucket, matched, outs, seed, cstate = launched
-        try:
-            with _tracing.span("session:prefill_wait", round=self.round,
-                               slot=slot):
-                first = int(np.asarray(outs[0]).reshape(-1)[0])
-        except BaseException:
-            self._admit_rollback(tables)
-            raise
-        n = prompt.size
+    def owes(self, slot):
+        """``slot`` is in the books with its first token owed."""
+        return slot in self._owed
+
+    def admit_enter(self, launched):
+        """Enter a launched admission in the slot's books with its first
+        token *owed*: everything that follows from the prompt alone (the
+        tables, the length, the window's trim, the seed, the prefix
+        index's entries, the counts), so that the slot is stepped, and the
+        next admission sees it taken, before the prefill has run. The
+        token stays on the device: ``_token_feed`` hands it to the slot's
+        first decode step there, and :meth:`admit_collect` fetches it for
+        the host, a launch later. Everything this touches (freed window
+        blocks, published prefix blocks) is used by later calls on the
+        device's queue than the prefill. Returns the slot."""
+        slot, prompt, n = launched.slot, launched.prompt, launched.prompt.size
         if self.prefix is not None:
             # publish the prompt's blocks (full chunks + partial
             # tail) — the next admission sharing this prefix, or a
             # PR-9 token replay of it, prefills only its suffix
-            self.prefix.register(prompt, tables[0])
-        for kind, table in zip(self.kinds, tables):
+            launched.published = self.prefix.register(prompt,
+                                                      launched.tables[0])
+        for kind, table in zip(self.kinds, launched.tables):
             kind.tables[slot] = table
         self.lengths[slot] = n
         self._trim_windows((slot,))
-        self.last_token[slot] = first
         self.active[slot] = True
-        self._policy_admitted(slot, first, seed, cstate)
-        self._draft_admit(prompt, slot, first)
+        self.seeds[slot] = int(launched.seed)
         self._starved.discard(slot)
-        self.prefill_log.append((bucket, matched, n - matched))
+        launched.entered = True
+        self._owed[slot] = launched
+        self.prefill_log.append((launched.bucket, launched.matched,
+                                 n - launched.matched))
         if len(self.prefill_log) > 4096:     # keep a list (tests
             del self.prefill_log[:2048]      # slice it), bounded
-        _PREFILLS.labels(bucket=bucket).inc()
-        _PROMPT_TOKENS.inc(n - matched)
-        _PREFILL_PADDED_TOKENS.inc(bucket)
+        _PREFILLS.labels(bucket=launched.bucket).inc()
+        _PROMPT_TOKENS.inc(n - launched.matched)
+        _PREFILL_PADDED_TOKENS.inc(launched.bucket)
         for kind in self.kinds:
             kind.count_prefill(n)
+        return slot
+
+    def admit_collect(self, launched):
+        """Phase 2 of an admission: wait for the prefill's first token
+        (``session:prefill_wait``), enter the sequence in the slot's books
+        unless :meth:`admit_enter` has, and give the host the token.
+        Returns ``(slot, token)``. A prefill that fails here gives back
+        all it took: its blocks, and where the slot was entered the slot
+        and what the prefix index had published of it (a decode step
+        already launched for the slot is told from its next tenant's by
+        the retirement count, like any step's for a slot retired since).
+        An entered admission whose slot was retired meanwhile has nothing
+        left to collect, and says so."""
+        slot, entered = launched.slot, launched.entered
+        if entered and self._owed.get(slot) is not launched:
+            raise RuntimeError("slot %d was retired with this admission's "
+                               "first token owed" % slot)
+        try:
+            with _tracing.span("session:prefill_wait", round=self.round,
+                               slot=slot):
+                first = int(np.asarray(launched.outs[0]).reshape(-1)[0])
+        except BaseException:
+            if entered:
+                self.retire(slot)
+            else:
+                self._admit_rollback(launched.tables)
+            raise
+        if not entered:
+            self.admit_enter(launched)
+        del self._owed[slot]
+        self.last_token[slot] = first
+        self._policy_admitted(slot, first, launched.cstate)
+        self._draft_admit(launched.prompt, slot, first)
         return slot, first
 
     def step(self):
@@ -1012,10 +1084,11 @@ class GenerationSession:
         ``hold`` names active slots that sit this step out: they
         neither write nor advance, like a free slot. A scheduler working
         one step ahead holds the slots whose sequence ends with the step
-        still uncollected. With such a step uncollected
-        (:meth:`step_launch`) the token feed is built on the device from
-        that step's tokens; a slot that did not advance in it is fed the
-        host's ``last_token``.
+        still uncollected, or with its first token still owed. With such
+        a step uncollected (:meth:`step_launch`) or such a token owed
+        (:meth:`admit_enter`) the token feed is built on the device from
+        them; any other slot is fed the host's ``last_token``
+        (:meth:`_token_feed`).
 
         The split is a thread-safety contract, not a convenience: the
         scheduler's step-timeout path runs the device call on a
@@ -1084,7 +1157,7 @@ class GenerationSession:
                 except PoolExhausted:
                     self._starved.add(s)
             f_tok, f_pos = self.spec.decode_feeds[:2]
-            feed = {f_tok: self._token_feed(),
+            feed = {f_tok: self._token_feed(live),
                     f_pos: self.lengths.astype(np.int32)}
             for kind in self.kinds:
                 tab = np.full((self.spec.slots, kind.width),
@@ -1096,42 +1169,72 @@ class GenerationSession:
             return (act, frozenset(self._starved), feed)
 
     def _compile_token_merge(self):
-        """The one device computation a step ahead adds: the uncollected
-        step's tokens, with the host's token where ``from_host`` says so,
-        in the token feed's shape and dtype. Compiled here, with the
-        session, so that no step compiles it."""
+        """The device computations a step ahead adds, in the token feed's
+        shape and dtype: ``_merge_tokens``, the uncollected step's tokens
+        with the host's token where ``from_host`` says so, and
+        ``_place_token``, a feed with one slot's token replaced by a
+        prefill's first token (applied once an owed slot, so that its
+        shape does not depend on how many a step has). Compiled here, with
+        the session, so that no step compiles them."""
         import jax
         import jax.numpy as jnp
         from ..core.framework import convert_dtype
         block = self.spec.decode_program.global_block()
         feed = block.var(self.spec.decode_feeds[0])
         out = block.var(self.spec.decode_fetch)
+        first = next(iter(self.spec.prefill_programs.values())) \
+            .global_block().var(self.spec.prefill_fetch)
         shape, dtype = tuple(feed.shape), convert_dtype(feed.dtype)
         n = self.spec.slots
 
         def merge(tokens, host, from_host):
             return jnp.where(from_host, host,
                              tokens.reshape(n).astype(dtype)).reshape(shape)
+
+        def place(feed, first, slot):
+            return feed.reshape(n).at[slot].set(
+                first.reshape(-1)[0].astype(dtype)).reshape(shape)
         self._token_dtype = dtype
         self._merge_tokens = jax.jit(merge).lower(
             jax.ShapeDtypeStruct(tuple(out.shape), convert_dtype(out.dtype)),
             jax.ShapeDtypeStruct((n,), dtype),
             jax.ShapeDtypeStruct((n,), np.bool_)).compile()
+        self._place_token = jax.jit(place).lower(
+            jax.ShapeDtypeStruct(shape, dtype),
+            jax.ShapeDtypeStruct(tuple(first.shape),
+                                 convert_dtype(first.dtype)),
+            jax.ShapeDtypeStruct((), np.int32)).compile()
 
-    def _token_feed(self):
-        """The step's token feed: the host's ``last_token``, or with a
-        step uncollected that step's tokens merged on the device with the
-        host's for the slots that did not advance in it (admitted since,
-        starved or held in it)."""
-        if not self._flights:
+    def _token_feed(self, act):
+        """The token feed of a step for the slots ``act``, from three
+        sources. The host's ``last_token``; with a step uncollected, that
+        step's tokens for the slots that advanced in it, merged on the
+        device with the host's for those that did not (starved or held in
+        it, or retired since: admitted anew or free); and for a slot whose
+        first token is owed (:meth:`admit_enter`) the prefill's own device
+        array. No token crosses to the host for a feed. An owed token
+        feeds the slot's first step alone: it is fetched
+        (:meth:`admit_collect`) before the slot's second is prepared."""
+        owed = [s for s in act if s in self._owed] if self._owed else ()
+        if not self._flights and not owed:
             return self.last_token.reshape(-1, 1).copy()
-        ahead = self._flights[-1]
-        from_host = np.ones(self.spec.slots, bool)
-        from_host[ahead.advanced[
-            ahead.retires == self._retires[ahead.advanced]]] = False
-        return self._merge_tokens(
-            ahead.outs[0], self.last_token.astype(self._token_dtype),
-            from_host)
+        feed = self.last_token.astype(self._token_dtype)
+        if self._flights:
+            ahead = self._flights[-1]
+            from_host = np.ones(self.spec.slots, bool)
+            from_host[ahead.advanced[
+                ahead.retires == self._retires[ahead.advanced]]] = False
+            feed = self._merge_tokens(ahead.outs[0], feed, from_host)
+        else:
+            feed = feed.reshape(-1, 1)
+        for s in owed:
+            if self.lengths[s] != self._owed[s].prompt.size:
+                raise RuntimeError(
+                    "slot %d was stepped with its first token owed: "
+                    "admit_collect comes before its second step" % s)
+            feed = self._place_token(feed, self._owed[s].outs[0],
+                                     np.int32(s))
+        return feed
 
     def _policy_decode_feed(self, feed):
         """Append the decode-policy feeds to a decode-step feed dict.
@@ -1404,9 +1507,15 @@ class GenerationSession:
         length mask keeps them unattendable meanwhile. Every
         block reference the slot's table held is returned to the pool
         (a block shared with the prefix index survives as cached
-        prompt state; exclusive blocks free immediately)."""
+        prompt state; exclusive blocks free immediately). A slot retired
+        with its first token owed takes back what the prefix index
+        published of its prompt."""
         self.active[slot] = False
         self._retires[slot] += 1
+        launched = self._owed.pop(slot, None)
+        if launched is not None and self.prefix is not None:
+            # its prefill is not known to have run: nobody shares it
+            self.prefix.withdraw(launched.published)
         self.lengths[slot] = 0
         self.last_token[slot] = 0
         self.seeds[slot] = 0
@@ -1455,7 +1564,7 @@ class _GenRequest:
                  "future", "deadline", "t_submit", "tokens", "slot",
                  "session_index", "t_last", "t_queued", "replays",
                  "charged", "failed_on", "last_exc", "ctx",
-                 "on_token", "seed", "tenant", "ahead")
+                 "on_token", "seed", "tenant", "ahead", "admission")
 
     def __init__(self, prompt, max_new, explicit_budget, eos_id,
                  deadline, on_token=None, seed=0, tenant=None):
@@ -1490,6 +1599,9 @@ class _GenRequest:
         # decode steps launched for this request whose tokens are not
         # delivered yet (the dispatcher works one step ahead)
         self.ahead = 0
+        # the session's handle of the admission that put it in its slot:
+        # a launched step delivers to that tenancy and to no later one
+        self.admission = None
         self.slot = None
         self.session_index = None
         self.t_last = None
@@ -1547,15 +1659,17 @@ class _GenRequest:
 
 class _Launched:
     """A session's decode step between the dispatcher's launch and its
-    collect: the requests it steps, by slot, as they stood at the launch
-    (a slot may have been retired since), the slots the pool starved out
-    of it, ``wait()`` that blocks for ``{slot: token}``, and the cached
-    tokens it attends where the launch knows them."""
+    collect: the requests it steps, by slot, with the admission each was
+    in its slot by at the launch (a slot may have been retired since, and
+    taken again, by the same request too), the slots the pool starved
+    out of it, ``wait()`` that blocks for ``{slot: token}``, and the
+    cached tokens it attends where the launch knows them."""
 
-    __slots__ = ("mine", "starved", "wait", "context")
+    __slots__ = ("mine", "admissions", "starved", "wait", "context")
 
     def __init__(self, mine, starved):
         self.mine, self.starved = mine, starved
+        self.admissions = [it.admission for _, it in mine]
         self.wait = self.context = None
 
 
@@ -1606,7 +1720,14 @@ class GenerationScheduler:
     (``GenerationSession.step_launch``). So an iteration of the
     dispatcher on a session is: prepare and launch step n+1, *then*
     collect step n's tokens, deliver them, retire, admit. The device
-    has step n+1 queued while the host does its turn. How far ahead is
+    has step n+1 queued while the host does its turn. A prefill's first
+    token is such a token too: an admission ends with the prefill on the
+    queue and the slot in the books with its first token *owed*
+    (``GenerationSession.admit_enter``), step n+1 is launched behind
+    the prefill and takes the token from the prefill's own device array,
+    and only then, after step n's collect, is the token fetched and the
+    request booked (``scheduler:first_token``): the turn after a prefill
+    runs beside the device like any other. How far ahead is
     read off the session, set by nobody (``_depth``): one step where
     ``session.lookahead`` is true (greedy and sampled policies), none,
     which is launch then collect of the same step, where the next feed
@@ -1614,26 +1735,51 @@ class GenerationScheduler:
     call: constrained decoding, speculative rounds, and
     ``step_timeout_ms`` (the bounded worker).
     ``paddle_generation_decode_steps_ahead_total`` counts the steps
-    launched with their predecessor uncollected. What follows from it:
+    launched with their predecessor uncollected (the step behind a
+    prefill is one), ``paddle_generation_first_tokens_owed_total`` the
+    admissions whose first token was fetched with a decode step queued
+    behind the prefill. At depth 0 an admission is launch, wait, book,
+    with nothing between. What follows from it:
 
-    * A request that the uncollected step ends *by count* (token
-      budget, cache capacity) sits the next step out (``hold``); its
-      Future resolves when the tokens arrive. What ends a request *by
-      value* (EOS, a constraint, a deadline read at delivery) is seen
-      one step late: the slot's one extra step wrote into blocks it
-      owned, and ``_deliver`` discards its result.
+    * A request that what is launched and undelivered ends *by count*
+      (token budget, cache capacity; an owed first token counts as a
+      token launched) sits the next step out (``hold``); its Future
+      resolves when the tokens arrive. What ends a request *by value*
+      (EOS, a constraint, a deadline read at delivery) is seen one step
+      late, at its first token too: the slot's one extra step wrote
+      into blocks it owned, and ``_deliver`` discards its result.
     * Whatever acted "between two steps" first collects and delivers
-      what is launched (``_settle``): the wait for a prefill's first
-      token, ``swap_weights``, the end of ``drain``/``close``/serving
-      out; a rebuild's hand-over and a session failure drop it instead
-      (``drop_flights``): a failed step n surfaces at its collect, the
-      step launched behind it goes with it, and the journals, which
-      hold delivered tokens only, replay bit-identically. An admission
-      into a session always settles that session first, so a launched
-      step never delivers into a slot's next tenant.
+      what is launched (``_settle``: the decode step, then the first
+      tokens owed): ``swap_weights``, the end of
+      ``drain``/``close``/serving out; a rebuild's hand-over (which
+      waits until nothing is active, so no token is owed) and a session
+      failure drop it instead (``drop_flights``): a failed step n
+      surfaces at its collect, the step launched behind it and the
+      admissions whose token is owed go with it (their slots retired,
+      their requests to replay as they came), and the journals, which
+      hold delivered tokens only, replay bit-identically. A prefill
+      that fails at the fetch of its first token with a decode step
+      launched behind it is such a failure (that step took the token on
+      the device): the session takes the slot and what the prefix index
+      had published back (``admit_collect``), the step goes
+      (``drop_flights``), and every request of the session replays.
+      With nothing launched behind it (depth 0; a settle) it is that
+      admission's failure alone, as it was.
+    * A slot's next tenant enters ``_active`` while a step launched for
+      the tenant before may still be uncollected. That step never
+      delivers into the new tenant, be it the same request admitted
+      again (preempted and replayed into the slot it left): ``_collect``
+      hands a result only to the request the step was launched for
+      *by the admission it was launched for* (``_Launched.admissions``:
+      the session's handle is the tenancy), ``step_collect`` and the
+      token feed leave out a slot whose retirement count moved since the
+      launch, and an owed first token is booked before any step
+      launched after its prefill is collected. Which first tokens are
+      owed is the session's book alone (``GenerationSession.owed``).
     * Blocks freed by a retire or a window trim may be reused at once:
       every reuse is a later call on the device's queue than every
-      read or write of them.
+      read or write of them (a prefill's window is trimmed, and its
+      prefix published, with the prefill still queued).
 
     **The dispatcher's clock.** The dispatcher's time is cut, by spans
     (``observability/tracing.py``: in any ``jax.profiler`` trace, no
@@ -1644,24 +1790,25 @@ class GenerationScheduler:
     decode call is on the device's queue. Working a step ahead, that is
     no longer time the device has nothing of the session queued: the
     step launched in the turn before runs beside it, and the turn
-    shows in a token gap only by what it exceeds that step. At depth 0,
-    and in the one turn after each prefill (which is waited for with
-    nothing behind it), the device still idles through it. Its named
+    shows in a token gap only by what it exceeds that step. At depth 0
+    the device still idles through it. Its named
     children are ``scheduler:deliver`` (tokens to requests, finish,
     retire), ``scheduler:admit`` (one per admitted request; ends with
     the prefill's device call ``session:prefill_call`` on the queue)
     and ``scheduler:first_token`` (the wait for it,
-    ``session:prefill_wait``, and the request's entry into the books;
-    both count as ``phase="admit"``), ``session:step_prepare`` and
-    ``session:step_dispatch``; each adds its milliseconds to
+    ``session:prefill_wait``, and the request's entry into the counts;
+    both count as ``phase="admit"``, and ``paddle_request_prefill_ms``
+    is the two of them, not what lies between), ``session:step_prepare``
+    and ``session:step_dispatch``; each adds its milliseconds to
     ``paddle_generation_host_ms_total{phase}``, and ``phase="other"``
     takes the turn less its named children (swap, expiry and queue
     bookkeeping), so the five phases sum to the host turns. A *device
     wait* (``session:step_wait``,
     ``paddle_generation_device_wait_ms_total``) is the dispatcher
-    blocked on the step it collects, booked to that step: a decode step
-    queued ahead of a prefill is collected between ``scheduler:admit``
-    and ``scheduler:first_token``, outside the turn, so its wait is not
+    blocked on the step it collects, booked to that step: working a
+    step ahead, the next step's launch and the collect of the step
+    queued ahead of a prefill lie between ``scheduler:admit`` and
+    ``scheduler:first_token``, the wait outside the turn, so it is not
     read as prefill time. ``paddle_request_decode_step_ms`` observes,
     at each collect, that wait plus the prepare and dispatch seconds
     spent since the last observation (one step ahead: the *next* step's
@@ -2228,14 +2375,16 @@ class GenerationScheduler:
 
     def _admit_item(self, item, si):
         """Admit ``item`` into session ``si``, in the two phases of
-        ``GenerationSession.admit``: ``scheduler:admit`` ends with the
-        prefill on the device's queue, ``scheduler:first_token`` holds
-        the wait for its first token and the request's entry into the
-        books. A decode step of the session that is queued ahead of the
-        prefill is collected and delivered between the two, so its wait
-        is booked to it and its tokens do not wait out the prefill."""
+        ``GenerationSession.admit``. ``scheduler:admit`` ends with the
+        prefill on the device's queue and the request in ``_active``;
+        ``scheduler:first_token`` (``_first_token``) holds the wait for
+        its first token and the request's entry into the counts. Where
+        the session works a step ahead the slot enters the session's
+        books at once with the token *owed* (``admit_enter``), and the
+        wait comes in ``_step_all``, behind the launch of the decode step
+        that takes the token on the device; at depth 0 the next feed
+        needs the token on the host, and the wait follows here."""
         sess = self.sessions[si]
-        replay = bool(item.tokens)
         t_admit0 = time.perf_counter()
         with self._host_phase("scheduler:admit", "admit", session=si):
             wait = t_admit0 - item.t_queued
@@ -2243,47 +2392,72 @@ class GenerationScheduler:
             _rtrace.QUEUE_WAIT_MS.observe(wait * 1e3)
             if item.ctx is not None:
                 _rtrace.event(item.ctx, "queueWait", dur_ms=wait * 1e3,
-                              replay=replay)
+                              replay=bool(item.tokens))
             launched = self._admit_guarded(
                 item, si, lambda: self._prefill_launch(item, si, sess))
-        if launched is None:
-            return
-        prefill_s = time.perf_counter() - t_admit0
-        if self._inflight[si] is not None and not self._settle(si):
-            # the step queued ahead of the prefill failed, and the
-            # session's requests went to replay: this one goes back to
-            # the head of the line as it came
-            sess.admit_abandon(launched)
-            item.failed_on.add(si)
-            self._pending.appendleft(item)
-            return
+            if launched is None:
+                return
+            if item.eos_id is None:
+                item.eos_id = sess.spec.eos_id
+            item.slot, item.ahead = launched.slot, 0
+            item.session_index, item.admission = si, launched
+            self._active[(si, item.slot)] = item
+            self._update_occupancy()
+        launched.admit_s = time.perf_counter() - t_admit0
+        if not self._depth(sess):
+            self._first_token(si, sess, item)
+
+    def _first_tokens(self, si, sess):
+        """Fetch and book the first tokens session ``si`` owes (the
+        session's book: ``GenerationSession.owed``), oldest first: after
+        the launch of the step that takes them on the device, and after
+        the collect of the step that was in flight, whose tokens are
+        older."""
+        for launched in sess.owed():
+            item = self._active.get((si, launched.slot))
+            if item is not None and item.admission is launched:
+                self._first_token(si, sess, item)   # else failed since
+
+    def _first_token(self, si, sess, item):
+        """``scheduler:first_token``: wait for the first token of
+        ``item``'s prefill and enter the request into the counts. A
+        fetch that fails with a decode step launched behind the prefill
+        is the session's failure, like a step's with another behind it:
+        that step took the token on the device."""
+        launched = item.admission
+        replay = bool(item.tokens)
+        behind = self._inflight[si] is not None
+        if behind:
+            # the step uncollected now was launched behind the prefill:
+            # an older one is collected before this fetch
+            _FIRST_TOKENS_OWED.inc()
+        sess.round = self._round
         t_resume = time.perf_counter()
         with self._host_phase("scheduler:first_token", "admit",
                               session=si):
             got = self._admit_guarded(
-                item, si, lambda: sess.admit_collect(launched))
+                item, si, lambda: sess.admit_collect(launched),
+                session_wide=behind)
             if got is None:
                 return
             slot, first = got
             # breaker success is recorded by a surviving STEP, not here:
             # a persistently step-broken session would otherwise launder
             # itself closed through every trial admission it then fails
-            if item.eos_id is None:
-                item.eos_id = sess.spec.eos_id
             now_pc = time.perf_counter()
-            prefill_ms = (prefill_s + now_pc - t_resume) * 1e3
+            prefill_ms = (launched.admit_s + now_pc - t_resume) * 1e3
             _rtrace.PREFILL_MS.observe(prefill_ms)
             if item.ctx is not None:
                 # hist = prefix-cache hit length: tokens served from
                 # shared blocks instead of re-prefilled (0 on a prefix
                 # miss)
-                hist = sess.prefill_log[-1][1] if sess.prefill_log else 0
                 _rtrace.event(item.ctx,
                               "replayAdmit" if replay else "prefill",
                               dur_ms=prefill_ms,
                               session=si, slot=slot,
                               journal_len=int(item.prompt.size)
-                              + len(item.tokens), hist=int(hist))
+                              + len(item.tokens),
+                              hist=int(launched.matched))
             if replay:
                 # the same logical request, resumed — requests_total
                 # must not double-count it; the re-prefilled history is
@@ -2295,12 +2469,8 @@ class GenerationScheduler:
                 _TTFT_SECONDS.observe(now_pc - item.t_submit)
             _TOKENS.inc()  # the prefill produced one NEW token either way
             item.t_last = now_pc
-            item.slot, item.ahead = slot, 0
-            item.session_index = si
             item.tokens.append(first)
             item.notify_token(first)
-            self._active[(si, slot)] = item
-            self._update_occupancy()
             # EOS/budget can end it at token 1; a surviving constrained
             # request may already be in a dead automaton state
             if not self._finish_if_done(item):
@@ -2316,24 +2486,37 @@ class GenerationScheduler:
             c = sess.policy.constraint
             cstate = c.advance_many(c.start, item.tokens)
         sess.round = self._round
-        return sess.admit_launch(item.history(), seed=item.seed,
-                                 cstate=cstate)
+        launched = sess.admit_launch(item.history(), seed=item.seed,
+                                     cstate=cstate)
+        if self._depth(sess):
+            sess.admit_enter(launched)
+        return launched
 
-    def _admit_guarded(self, item, si, call):
+    def _admit_guarded(self, item, si, call, session_wide=False):
         """``call()`` under the request's activated context (it follows
         the admission into the fault hook and the prefill's
         executor.run: deviceCall spans land on this request's trace),
-        or None with the failure handled."""
+        or None with the failure handled: the admission's own, or with
+        ``session_wide`` the session's."""
         try:
             with _rtrace.activate(item.ctx):
                 return call()
-        except ValueError as exc:
-            # a client-shaped prompt (bucket/length) is the request's
-            # fault, not the session's — it must not charge the
-            # breaker and quarantine a healthy session
-            self._resolve_err(item, exc)
         except Exception as exc:
-            self._on_admit_failure(item, si, exc)
+            if session_wide:
+                self._on_session_failure(si, self.sessions[si], exc)
+                return None
+            if self._active.get((si, item.slot)) is item:
+                # the first token's fetch failed: the session gave the
+                # slot back
+                del self._active[(si, item.slot)]
+                self._update_occupancy()
+            if isinstance(exc, ValueError):
+                # a client-shaped prompt (bucket/length) is the request's
+                # fault, not the session's — it must not charge the
+                # breaker and quarantine a healthy session
+                self._resolve_err(item, exc)
+            else:
+                self._on_admit_failure(item, si, exc)
         return None
 
     def _on_admit_failure(self, item, si, exc):
@@ -2592,25 +2775,28 @@ class GenerationScheduler:
         return 1 if sess.lookahead and self.step_timeout is None else 0
 
     def _ends_in_flight(self, sess, it):
-        """The steps launched for ``it`` and not delivered end it by
-        count (token budget, cache capacity): it needs no further one."""
-        return it.ahead and (len(it.tokens) + it.ahead >= it.max_new or
-                             sess.capacity_left(it.slot) <= 0)
+        """The tokens launched for ``it`` and not delivered (decode
+        steps, and a prefill's first token while it is owed) end it by
+        count (token budget, cache capacity): it needs no further step."""
+        ahead = it.ahead + sess.owes(it.slot)
+        return ahead and (len(it.tokens) + ahead >= it.max_new or
+                          sess.capacity_left(it.slot) <= 0)
 
     def _step_all(self):
         """One dispatcher iteration on every session: launch its next
         decode step, then collect and deliver the one launched an
-        iteration earlier — or, at depth 0, the one just launched."""
+        iteration earlier — or, at depth 0, the one just launched —
+        then fetch the first tokens owed by the prefills that the
+        iteration's admissions launched ahead of that next step."""
         for si, sess in enumerate(self.sessions):
             if si in self._rebuilding:
                 continue  # down for reconstruction; nothing is active
             ahead_of = self._inflight[si]
-            mine, hold = self._on_session(si), ()
-            if ahead_of is not None:
-                hold = [slot for slot, it in mine
-                        if self._ends_in_flight(sess, it)]
-                mine = [(slot, it) for slot, it in mine
-                        if slot not in hold]
+            on_session = self._on_session(si)
+            hold = [slot for slot, it in on_session
+                    if self._ends_in_flight(sess, it)]
+            mine = [(slot, it) for slot, it in on_session
+                    if slot not in hold]
             self._t_dispatch = self._t_enqueued = None
             new = None
             if mine:
@@ -2631,14 +2817,15 @@ class GenerationScheduler:
                 self._inflight[si] = new
                 self._turn_open(self._t_enqueued)
                 self._t_enqueued = None
-                continue
-            if ahead_of is None:
-                ahead_of, new = new, None
-            elif new is not None:
-                _STEPS_AHEAD.inc()
-            self._inflight[si] = new
-            if ahead_of is not None:
-                self._collect(si, sess, ahead_of)
+            else:
+                if ahead_of is None:
+                    ahead_of, new = new, None
+                elif new is not None:
+                    _STEPS_AHEAD.inc()
+                self._inflight[si] = new
+                if ahead_of is not None:
+                    self._collect(si, sess, ahead_of)
+            self._first_tokens(si, sess)
 
     def _launch(self, si, sess, mine, hold):
         """Prepare a decode step of ``sess`` for the requests ``mine``
@@ -2688,8 +2875,7 @@ class GenerationScheduler:
         deliver them. One clock reading per boundary: the prepare and
         dispatch seconds no observation holds yet plus this wait are the
         step's time in ``paddle_request_decode_step_ms``, so the
-        observations neither overlap nor leave any of the three out.
-        Returns False when the step failed."""
+        observations neither overlap nor leave any of the three out."""
         if self._t_enqueued is None:
             # nothing was launched in this iteration: the host turn ends
             # where the wait starts
@@ -2706,10 +2892,19 @@ class GenerationScheduler:
         self._turn_open(now_pc)
         if failure is not None:
             self._on_session_failure(si, sess, failure)
-            return False
-        for slot, it in rec.mine:
-            if slot not in rec.starved and \
-                    self._active.get((si, slot)) is it:
+            return
+        # whoever left the slot since the launch gets nothing of the step:
+        # ended by what the tokens of the step before said (EOS, a
+        # deadline), preempted, failed at its first token. The one step
+        # it ran beyond that is discarded, as a speculative round's
+        # tokens past the end are, also where the same request holds the
+        # slot again by a later admission
+        mine = [(slot, it)
+                for (slot, it), adm in zip(rec.mine, rec.admissions)
+                if it.admission is adm and
+                self._active.get((si, slot)) is it]
+        for slot, it in mine:
+            if slot not in rec.starved:
                 it.ahead -= 1
         breaker = self._breakers[si] if self._breakers else None
         if breaker is not None:
@@ -2724,32 +2919,27 @@ class GenerationScheduler:
         _CONTEXT_TOKENS.inc(
             int(sum(sess.lengths[s] for s in toks))
             if rec.context is None else rec.context)
-        _TOKENS.inc(self._deliver(si, sess, rec.mine, toks, now_pc,
-                                  step_ms))
-        return True
+        _TOKENS.inc(self._deliver(si, sess, mine, toks, now_pc, step_ms))
 
     def _settle(self, si):
-        """Collect and deliver the step of session ``si`` that is
-        launched and uncollected, if there is one: what everything that
-        acts between two steps does first (a prefill's wait, a weight
-        swap, a rebuild's hand-over, the end of serving). Returns False
-        when that step failed."""
+        """Collect and deliver what session ``si`` has launched and not
+        collected, the decode step and then the first tokens owed by
+        prefills (launched after it): what everything that acts between
+        two steps does first (a weight swap, the end of serving)."""
         rec, self._inflight[si] = self._inflight[si], None
-        return rec is None or self._collect(si, self.sessions[si], rec)
+        sess = self.sessions[si]
+        if rec is not None:
+            self._collect(si, sess, rec)
+        self._first_tokens(si, sess)
 
     def _deliver(self, si, sess, mine, toks, now_pc, step_ms):
-        """Hand a step's tokens to their requests; finish and retire
-        what ended. Returns the number of tokens delivered."""
+        """Hand a step's tokens to ``mine``, its requests that are still
+        in their slots by the admission it was launched for; finish and
+        retire what ended. Returns the number of tokens delivered."""
         advanced = 0
         with self._host_phase("scheduler:deliver", "deliver",
                               active=len(mine)):
             for slot, it in mine:
-                if self._active.get((si, slot)) is not it:
-                    # ended since the launch, by what the tokens of the
-                    # step before said (EOS, a deadline): the one step
-                    # it ran beyond that is discarded, as a speculative
-                    # round's tokens past the end are below
-                    continue
                 if slot not in toks:
                     # pool exhausted for this sequence (no
                     # allocatable block even after eviction): it

@@ -616,17 +616,18 @@ class PrefixIndex:
         ``table`` (block ids covering positions [0, len(tokens))).
         Chunks already registered are left as-is (the matching path
         shares the very blocks in ``table``); new entries pin their
-        block."""
+        block. Returns the new entries' keys, for :meth:`withdraw`."""
         tokens = np.asarray(tokens, np.int64).reshape(-1)
         bs = self.block_size
         digest = b""
         nfull = tokens.size // bs
+        new = []
         for i in range(min(nfull, len(table))):
             digest = _chain_digest(digest, tokens[i * bs:(i + 1) * bs])
             if digest not in self._full:
                 self._full[digest] = table[i]
                 self.pool.incref(table[i])
-                self._lru[("full", digest)] = True
+                new.append(("full", digest))
             self._touch(("full", digest))
         tail = tuple(int(t) for t in tokens[nfull * bs:])
         if tail and len(table) > nfull:
@@ -634,8 +635,17 @@ class PrefixIndex:
             if tail not in tails:
                 tails[tail] = table[nfull]
                 self.pool.incref(table[nfull])
-                self._lru[("tail", digest, tail)] = True
+                new.append(("tail", digest, tail))
             self._touch(("tail", digest, tail))
+        return new
+
+    def withdraw(self, keys):
+        """Unpin the entries a ``register`` made (its return value) of a
+        prefill that turned out to have failed; those evicted since are
+        gone already."""
+        for key in keys:
+            if key in self._lru:
+                self._drop(key)
 
     # -- eviction --------------------------------------------------------
     def _drop(self, key):

@@ -89,6 +89,19 @@ def _baseline(scope, prompts=PROMPTS, max_new=6, decode_policy=None,
         sched.close()
 
 
+def _arm_mid_decode(site, **spec):
+    """An ``on_token`` observer that arms the fault at the first token
+    it sees. The dispatcher launches the decode step behind a prefill
+    before it fetches the prefill's first token, so a step fault armed
+    before the submit fails requests that have no token yet: they replay
+    as they came, with nothing in their journals. Armed here, the fault
+    finds every request of the iteration with its first token."""
+    def on_token(_token):
+        if not faults.armed(site):
+            faults.arm(site, **spec)
+    return on_token
+
+
 def _sampled_policy():
     from paddle_tpu.serving.decoding import DecodePolicy
     return DecodePolicy(kind="sample", temperature=0.9)
@@ -109,9 +122,10 @@ class TestReplayFailover:
             [_session(scope), _session(scope)], replay_attempts=4,
             breaker_failures=1, breaker_cooldown_ms=60000.0)
         try:
-            faults.arm("generation_step_fail", at=0, times=1)
-            futs = [sched.submit(list(p), max_new_tokens=6, eos_id=-1)
-                    for p in PROMPTS]
+            arm = _arm_mid_decode("generation_step_fail", at=0, times=1)
+            futs = [sched.submit(list(p), max_new_tokens=6, eos_id=-1,
+                                 on_token=arm if i == 0 else None)
+                    for i, p in enumerate(PROMPTS)]
             got = [[int(t) for t in f.result(timeout=60)] for f in futs]
             assert got == want
             assert _counter("paddle_generation_failover_total") > f0
@@ -518,9 +532,10 @@ class TestTracePropagation:
             [_session(scope), _session(scope)], replay_attempts=4,
             breaker_failures=1, breaker_cooldown_ms=60000.0)
         try:
-            faults.arm("generation_step_fail", at=0, times=1)
-            futs = [sched.submit(list(p), max_new_tokens=6, eos_id=-1)
-                    for p in PROMPTS]
+            arm = _arm_mid_decode("generation_step_fail", at=0, times=1)
+            futs = [sched.submit(list(p), max_new_tokens=6, eos_id=-1,
+                                 on_token=arm if i == 0 else None)
+                    for i, p in enumerate(PROMPTS)]
             got = [[int(t) for t in f.result(timeout=60)] for f in futs]
             assert got == want  # tracing armed changes no tokens
         finally:

@@ -1,6 +1,8 @@
 """The dispatcher works one decode step ahead (``GenerationScheduler``,
 "One step ahead"): step n+1 is on the device's queue before step n's
-tokens are fetched, fed by them on the device. What that must not change:
+tokens are fetched, fed by them on the device, and before the first token
+of a prefill launched ahead of it is, which it takes from the prefill's
+own device array (the token is *owed*). What that must not change:
 the tokens of any request, the pool's books, the replay contract, the
 dispatcher's clock. What it must do: engage where the session allows it
 and nowhere else. Everything here runs on the CPU."""
@@ -24,6 +26,8 @@ BOS, EOS = 0, 1
 STEPS = "paddle_generation_decode_steps_total"
 AHEAD = "paddle_generation_decode_steps_ahead_total"
 TOKENS = "paddle_generation_tokens_total"
+OWED = "paddle_generation_first_tokens_owed_total"
+REQUESTS = "paddle_generation_requests_total"
 SAMPLED = DecodePolicy(kind="sample", temperature=1.0)
 
 
@@ -104,12 +108,49 @@ def test_streams_through_the_scheduler_equal_generates(lm_scope, policy):
         got = [[int(t) for t in f.result(timeout=120)] for f in futures]
     c = _delta(_counters(), c0)
     assert got == [out for _, out in want]
-    # it did work ahead, and not on every step: the step after a prefill
-    # has nothing uncollected before it
-    assert 0 < c[AHEAD] < c[STEPS]
+    # it did work ahead, behind prefills too, and not on every step: a
+    # step launched with nothing active before it has no predecessor
+    assert 0 < c[AHEAD] < c[STEPS] and c[OWED] > 0
     sess.check_pool_invariant()
     assert sess.pool.used_count() == 0 and not sess._flights
+    assert not sess._owed
     sess.close()
+
+
+@pytest.mark.parametrize("policy", [None, SAMPLED],
+                         ids=["greedy", "sampled"])
+def test_streams_with_admissions_between_steps_equal_a_depth_0_schedulers(
+        lm_scope, policy):
+    """Seven requests through three slots, by hand: every retirement is
+    followed by an admission whose first token is owed while the next
+    step is launched. The same requests through a scheduler that works
+    no step ahead (launch, wait, book, then step) give the same streams,
+    and owe nothing."""
+    got = {}
+    for depth, kwargs in ((1, {}), (0, {"step_timeout_ms": 60000.0})):
+        sess = _session(lm_scope, policy)
+        sched = GenerationScheduler(sess, deadline_ms=0, autostart=False,
+                                    **kwargs)
+        assert sched._depth(sess) == depth
+        c0 = _counters()
+        futures = [sched.submit(prompt, max_new_tokens=n_new, eos_id=-1,
+                                seed=seed)
+                   for prompt, n_new, seed in _requests()]
+        _drive(sched, futures)
+        c = _delta(_counters(), c0)
+        got[depth] = [[int(t) for t in f.result(1)] for f in futures]
+        # every admission but those into an idle session had a step
+        # launched behind its prefill before its token was fetched
+        assert (0 < c[OWED] <= len(futures)) if depth \
+            else c.get(OWED, 0.0) == 0
+        assert c[REQUESTS] == len(futures)
+        assert c[TOKENS] == sum(n_new for _, n_new, _ in _requests())
+        sess.check_pool_invariant()
+        assert sess.pool.used_count() == 0 and not sess._owed
+        sched.close()
+        sess.close()
+    assert got[1] == got[0]
+    assert [len(g) for g in got[1]] == [n for _, n, _ in _requests()]
 
 
 def test_token_feed_on_the_device_takes_the_hosts_token_for_new_slots(
@@ -134,31 +175,79 @@ def test_token_feed_on_the_device_takes_the_hosts_token_for_new_slots(
     sess.close()
 
 
+class _NoFetch:
+    """``numpy`` with an ``asarray`` that refuses a device array."""
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    @staticmethod
+    def asarray(value, *args, **kwargs):
+        import jax
+        assert not isinstance(value, jax.Array), "device array fetched"
+        return np.asarray(value, *args, **kwargs)
+
+
 def test_a_device_feed_is_not_fetched_by_the_step_that_takes_it(
         lm_scope, monkeypatch):
     """``Executor.run`` must not read a device array it is fed (it did,
     for the dtype: the dispatch of step n+1 then waited for step n, and
     the first chip run of the lookahead read an 11 ms host turn)."""
-    import jax
     from paddle_tpu.core import executor
-
-    class Numpy:
-        def __getattr__(self, name):
-            return getattr(np, name)
-
-        @staticmethod
-        def asarray(value, *args, **kwargs):
-            assert not isinstance(value, jax.Array), "fed array fetched"
-            return np.asarray(value, *args, **kwargs)
     sess = _session(lm_scope)
     slot, _ = sess.admit([BOS, 5, 7])
     flight = sess.step_launch(sess.step_prepare())
     prepared = sess.step_prepare()
-    monkeypatch.setattr(executor, "np", Numpy())
+    monkeypatch.setattr(executor, "np", _NoFetch())
     ahead = sess.step_launch(prepared)
     monkeypatch.undo()
     assert slot in sess.step_collect(flight)
     assert slot in sess.step_collect(ahead)
+    sess.close()
+
+
+@pytest.mark.parametrize("admissions", [1, 2], ids=["one", "two"])
+@pytest.mark.parametrize("uncollected", [True, False],
+                         ids=["behind_a_step", "idle"])
+def test_an_owed_first_token_is_fed_from_the_prefills_device_array(
+        lm_scope, monkeypatch, admissions, uncollected):
+    """Admissions entered with their first token owed, one or two of them
+    between two steps: the next step is prepared and launched with no
+    array fetched, by the session or by the executor, and its feed holds
+    each prefill's token where the host's ``last_token`` knows none."""
+    from paddle_tpu.core import executor
+    from paddle_tpu.serving import generation
+    prompts = [[BOS, 9], [BOS, 4, 8, 3]][:admissions]
+    ref = _session(lm_scope)
+    want = [ref.generate(p, max_new_tokens=3, eos_id=-1) for p in prompts]
+    ref.close()
+    sess = _session(lm_scope)
+    a, _ = sess.admit([BOS, 5, 7])
+    flight = sess.step_launch(sess.step_prepare()) if uncollected else None
+    monkeypatch.setattr(executor, "np", _NoFetch())
+    monkeypatch.setattr(generation, "np", _NoFetch())
+    launched, slots = [], []
+    for p in prompts:       # back to back, neither prefill waited for
+        launched.append(sess.admit_launch(p))
+        slots.append(sess.admit_enter(launched[-1]))
+    assert sorted(sess._owed) == slots and sess.active[slots].all()
+    prepared = sess.step_prepare()
+    feed = prepared[2][sess.spec.decode_feeds[0]]
+    assert not isinstance(feed, np.ndarray)          # built on the device
+    ahead = sess.step_launch(prepared)
+    monkeypatch.undo()
+    assert (sess.last_token[slots] == 0).all()       # not on the host yet
+    tok_a = sess.step_collect(flight)[a] if uncollected \
+        else int(sess.last_token[a])
+    firsts = [sess.admit_collect(one)[1] for one in launched]
+    assert not sess._owed
+    assert np.asarray(feed).reshape(-1)[[a] + slots].tolist() == \
+        [tok_a] + firsts
+    out = sess.step_collect(ahead)
+    seconds = [out[s] for s in slots]
+    third = sess.step()
+    thirds = [third[s] for s in slots]
+    assert [list(t) for t in zip(firsts, seconds, thirds)] == want
     sess.close()
 
 
@@ -231,6 +320,57 @@ def test_an_eos_seen_one_step_late_discards_exactly_one_result(lm_scope):
     sess.close()
 
 
+@pytest.mark.parametrize("ends", ["eos", "max_new"])
+def test_a_request_that_its_first_token_ends_leaves_nothing_to_the_next(
+        lm_scope, ends):
+    """B decodes in slot 0 throughout. A is admitted into slot 1 and its
+    first token, owed while the next step is launched, ends it: by value
+    (the EOS) one step has run for it, whose result is discarded; by count
+    (a budget of one) it sat that step out. C takes slot 1 next, with the
+    step that A was or was not in still uncollected, and gets its own
+    tokens, none of A's."""
+    sess = _session(lm_scope, slots=2)
+    pa, pb, pc = [BOS, 5, 7], [BOS, 9], [BOS, 4, 8, 3]
+    first_a = sess.generate(pa, max_new_tokens=1, eos_id=-1)[0]
+    want_b = sess.generate(pb, max_new_tokens=9, eos_id=-1)
+    want_c = sess.generate(pc, max_new_tokens=4, eos_id=-1)
+    holds, prepare = [], sess.step_prepare
+    sess.step_prepare = lambda hold=(): (holds.append(list(hold)),
+                                         prepare(hold))[1]
+    sched = GenerationScheduler(sess, deadline_ms=0, autostart=False)
+    c0 = _counters()
+    fb = sched.submit(pb, max_new_tokens=9, eos_id=-1)
+    _place_all(sched)
+    sched._step_all()
+    sched._step_all()                    # B has a step uncollected
+    fa = sched.submit(pa, eos_id=first_a) if ends == "eos" \
+        else sched.submit(pa, max_new_tokens=1, eos_id=-1)
+    fc = sched.submit(pc, max_new_tokens=4, eos_id=-1)
+    _place_all(sched)                    # A into slot 1; C waits for it
+    item_a = sched._active[(0, 1)]
+    assert sess.owes(1) and not item_a.tokens
+    n_holds = len(holds)
+    sched._step_all()
+    # the step launched behind A's prefill: A in it, or held out of it
+    assert holds[n_holds] == ([] if ends == "eos" else [1])
+    assert fa.done() and (0, 1) not in sched._active
+    assert item_a.ahead == (1 if ends == "eos" else 0)
+    _drive(sched, [fb, fc])
+    c = _delta(_counters(), c0)
+    assert [int(t) for t in fa.result(1)] == \
+        ([] if ends == "eos" else [first_a])
+    assert [int(t) for t in fb.result(1)] == want_b
+    assert [int(t) for t in fc.result(1)] == want_c
+    assert sched._active == {} and c[REQUESTS] == 3
+    # A's token (its EOS counts as decoded), B's and C's: the result of
+    # the step A ran beyond its end is in no count
+    assert c[TOKENS] == 1 + 9 + 4
+    sess.check_pool_invariant()
+    assert sess.pool.used_count() == 0 and not sess._flights
+    sched.close()
+    sess.close()
+
+
 # -- failure, and everything that acted between two steps ---------------------
 
 def test_a_step_that_fails_with_another_queued_behind_it_replays(lm_scope):
@@ -267,28 +407,193 @@ def test_a_step_that_fails_with_another_queued_behind_it_replays(lm_scope):
     good.close()
 
 
+@pytest.mark.parametrize("sessions", [2, 1], ids=["two", "one_session"])
+@pytest.mark.parametrize("fails", ["admit_fail", "fetch", "step"])
+def test_a_prefill_that_fails_with_a_step_queued_behind_it_replays(
+        lm_scope, fails, sessions):
+    """A request joins two that are decoding on the first session, and
+    its admission fails. At the launch (the ``generation_admit_fail``
+    site) it fails alone: nothing of it is in any book. At the fetch of
+    its first token the slot is in the books and a decode step that took
+    the token on the device is queued behind the prefill: the session's
+    failure, the step dropped with it, the prefix index gives back what
+    it had published, the request replays as it came and the two others
+    from their journals. Or the step queued ahead of the prefill fails at
+    its collect: the prefill and the step behind it go with it. Every
+    stream is what ``generate`` gives and the counts conserve, also where
+    there is one session alone and every request replays into the slots
+    it left."""
+    from paddle_tpu.resilience import faults
+    ref = _session(lm_scope, SAMPLED, prefix_cache=True)
+    reqs = _requests(seed=23, n=3)
+    want = [ref.generate(p, max_new_tokens=n_new + 4, eos_id=-1, seed=seed)
+            for p, n_new, seed in reqs]
+    ref.close()
+    bad = _session(lm_scope, SAMPLED, prefix_cache=True)
+    good = _session(lm_scope, SAMPLED, prefix_cache=True) \
+        if sessions == 2 else bad
+    seen, collect = [], bad.admit_collect
+
+    def failing(launched):
+        seen.append((bad.owes(launched.slot), len(bad._flights)))
+        if fails == "fetch" and len(seen) == 3:
+            launched.outs = [_Unfetchable()]
+        return collect(launched)
+    bad.admit_collect = failing
+    step_collect = bad.step_collect
+
+    def failing_step(flight):
+        if fails == "step" and bad.owed() and "step" not in seen:
+            seen.append("step")
+            seen.append(len(bad._flights))
+            raise RuntimeError("injected: the device lost the step")
+        return step_collect(flight)
+    bad.step_collect = failing_step
+    sched = GenerationScheduler([bad, good][:sessions], deadline_ms=0,
+                                replay_attempts=2, autostart=False)
+    c0 = _counters()
+    futures = [sched.submit(p, max_new_tokens=n_new + 4, eos_id=-1,
+                            seed=seed) for p, n_new, seed in reqs[:2]]
+    _place_all(sched)
+    sched._step_all()
+    sched._step_all()                    # two decoding, a step uncollected
+    journals = sum(len(it.tokens) for it in sched._active.values())
+    if fails == "admit_fail":
+        faults.arm("generation_admit_fail", at=0, times=1)
+    try:
+        p, n_new, seed = reqs[2]
+        futures.append(sched.submit(p, max_new_tokens=n_new + 4, eos_id=-1,
+                                    seed=seed))
+        _drive(sched, futures)
+    finally:
+        faults.disarm()
+    c = _delta(_counters(), c0)
+    assert [[int(t) for t in f.result(1)] for f in futures] == want
+    if fails == "fetch":
+        # the failing fetch found the slot entered and a step behind it
+        # (the one ahead of the prefill had been collected: a token more
+        # in each of the two journals)
+        assert seen[2] == (True, 1)
+        journals += 2
+    elif fails == "step":
+        # the failing collect had the prefill and a second step behind it
+        assert seen[2:4] == ["step", 2]
+    # the third request replayed alone and had no slot yet, or all three
+    moved = 1 if fails == "admit_fail" else 3
+    if sessions == 2:
+        assert len(good.prefill_log) == moved
+        # nothing of the failed prefill stays published where it failed
+        assert bad.prefix.peek(reqs[2][0]) == 0
+    assert good.prefix.peek(reqs[2][0]) == len(reqs[2][0])
+    assert c[REQUESTS] == 3
+    assert c[TOKENS] == sum(len(w) for w in want)
+    assert c["paddle_generation_failover_total"] == moved
+    # the owed request's journal was empty; the two others held the
+    # tokens delivered before the failure
+    assert c.get("paddle_generation_replayed_tokens_total", 0.0) == \
+        (0 if fails == "admit_fail" else journals)
+    retired = {k: v for k, v in c.items() if v and k.startswith(
+        "paddle_generation_retired_total")}
+    want_retired = {"paddle_generation_retired_total{reason=max_tokens}": 3.0}
+    if fails != "admit_fail":
+        want_retired["paddle_generation_retired_total{reason=failover}"] = 3.0
+    assert retired == want_retired
+    for sess in {bad, good}:
+        sess.check_pool_invariant()
+        assert not sess._flights and not sess.owed()
+        assert not sess.active.any()
+        # what is in use is what the prefix index keeps, and nothing of
+        # the prefill that failed at its fetch
+        assert sess.pool.used_count() == len(sess.prefix.pinned_blocks())
+    assert sched._active == {} and sched._inflight == [None] * sessions
+    sched.close()
+    bad.close()
+    if good is not bad:
+        good.close()
+
+
+def test_a_step_launched_for_an_earlier_tenancy_gives_the_same_request_nothing(
+        lm_scope):
+    """One session, so a request that leaves its slot with a step launched
+    for it (preempted for want of a block, or failed at its first token)
+    replays into the slot it left, and is ``_active`` there again when that
+    step is collected. The step was launched for the admission before:
+    its result goes to nobody, the request's count of launched steps is
+    not touched, and its stream is ``generate``'s."""
+    from paddle_tpu.serving.paged_cache import PoolExhausted
+    sess = _session(lm_scope, SAMPLED, slots=2)
+    pa, pb = [BOS, 5, 7], [BOS, 9, 3, 4]
+    want_a = sess.generate(pa, max_new_tokens=8, eos_id=-1, seed=5)
+    want_b = sess.generate(pb, max_new_tokens=8, eos_id=-1, seed=6)
+    sched = GenerationScheduler(sess, deadline_ms=0, replay_attempts=2,
+                                autostart=False)
+    fa = sched.submit(pa, max_new_tokens=8, eos_id=-1, seed=5)
+    fb = sched.submit(pb, max_new_tokens=8, eos_id=-1, seed=6)
+    _place_all(sched)
+    sched._step_all()
+    sched._step_all()                    # both decoding, a step uncollected
+    b = sched._active[(0, 1)]
+    stale, first = sched._inflight[0], b.admission
+    assert (1, b) in stale.mine and b.ahead == 1
+    # what ``_deliver`` does to a request that the pool starved
+    sess.retire(1)
+    del sched._active[(0, 1)]
+    sched._requeue_for_replay([b], PoolExhausted("injected"))
+    had = list(b.tokens)
+    _place_all(sched)                    # B again, into the slot it left
+    assert sched._active[(0, 1)] is b and b.admission is not first
+    assert b.ahead == 0 and sess.owes(1)
+    sched._step_all()                    # launches B's step, collects stale
+    assert sched._active[(0, 1)] is b and b.ahead == 1
+    assert b.tokens == had + [want_b[len(had)]]      # its first token alone
+    _drive(sched, [fa, fb])
+    assert [int(t) for t in fa.result(1)] == want_a
+    assert [int(t) for t in fb.result(1)] == want_b
+    assert b.replays == 1
+    sess.check_pool_invariant()
+    assert sess.pool.used_count() == 0 and not sess._flights
+    sched.close()
+    sess.close()
+
+
+class _Unfetchable:
+    """Stands in for a prefill's device array whose fetch fails."""
+
+    def __array__(self, *args, **kwargs):
+        raise RuntimeError("injected: the prefill's token did not arrive")
+
+
+@pytest.mark.parametrize("owed", [False, True], ids=["a_step", "a_token"])
 @pytest.mark.parametrize("how", ["swap_weights", "drain", "close"])
-def test_what_is_in_flight_is_collected_first(lm_scope, how):
+def test_what_is_in_flight_is_collected_first(lm_scope, how, owed):
+    """What is launched and uncollected when something acts between two
+    steps: a decode step, or (nothing stepped since the admission) a
+    prefill whose first token is owed."""
     sess = _session(lm_scope)
     want = sess.generate([BOS, 5, 7], max_new_tokens=6, eos_id=-1)
     sched = GenerationScheduler(sess, deadline_ms=0, autostart=False)
     f = sched.submit([BOS, 5, 7], max_new_tokens=6, eos_id=-1)
     _place_all(sched)
-    sched._step_all()
     item = next(iter(sched._active.values()))
-    assert sched._inflight[0] is not None and len(item.tokens) == 1
+    if owed:
+        assert sess.owed() == [item.admission] and not item.tokens
+    else:
+        sched._step_all()
+        assert sched._inflight[0] is not None and len(item.tokens) == 1
+    had = len(item.tokens)
     if how == "swap_weights":
         name = sorted(n for n in lm_scope.var_names()
                       if n not in sess._claimed)[0]
         sched.swap_weights({name: np.asarray(lm_scope.find_var(name))})
-        assert len(item.tokens) == 2
+        assert len(item.tokens) == had + 1
     elif how == "drain":
         sched.drain()
         assert [int(t) for t in f.result(1)] == want
     else:
         sched.close()
-        assert len(item.tokens) == 2
+        assert len(item.tokens) == had + 1
     assert sched._inflight == [None] and not sess._flights
+    assert not sess.owed()
     assert item.tokens == want[:len(item.tokens)]
     sched.close()
     sess.close()
@@ -323,6 +628,42 @@ def test_sessions_that_need_the_token_on_the_host_count_no_step_ahead(
     c = _delta(_counters(), c0)
     assert all(len(o) == 6 for o in outs)
     assert c[STEPS] > 0 and c.get(AHEAD, 0.0) == 0
+    assert c.get(OWED, 0.0) == 0
+    sess.close()
+
+
+@pytest.mark.parametrize("make", [_constrained, _speculative,
+                                  _step_bounded])
+def test_sessions_that_need_the_token_on_the_host_keep_the_old_order(
+        lm_scope, make):
+    """Launch, wait, book, and only then a step: an admission into a
+    session at depth 0 is never entered with its token owed, and no step
+    is prepared between its two phases."""
+    sess, kwargs = make(lm_scope)
+    calls = []
+    for name in ("admit_launch", "admit_enter", "admit_collect",
+                 "step_prepare"):
+        def logged(*args, _name=name, _call=getattr(sess, name), **kw):
+            calls.append((_name, len(sess._owed)))
+            return _call(*args, **kw)
+        setattr(sess, name, logged)
+    sched = GenerationScheduler(sess, deadline_ms=0, autostart=False,
+                                **kwargs)
+    fa = sched.submit([BOS, 5, 7], max_new_tokens=5, eos_id=-1)
+    _place_all(sched)
+    sched._step_all()
+    fb = sched.submit([BOS, 5], max_new_tokens=3, eos_id=-1)
+    _drive(sched, [fa, fb])
+    assert len(fa.result(1)) == 5 and len(fb.result(1)) == 3
+    names = [name for name, _ in calls]
+    assert names.count("admit_launch") == 2
+    for i, name in enumerate(names):
+        if name == "admit_launch":
+            # the scheduler's own call, then the one inside admit_collect
+            assert names[i + 1] == "admit_collect"
+    # the only entry is admit_collect's own, after its fetch
+    assert all(owed == 0 for name, owed in calls if name != "admit_enter")
+    sched.close()
     sess.close()
 
 
@@ -353,7 +694,7 @@ def test_the_clock_stays_inside_the_dispatchers_wall_time(lm_scope):
     tracing.clear()
     c = _delta(_counters(), c0)
     sess.close()
-    assert c[AHEAD] > 0
+    assert c[AHEAD] > 0 and c[OWED] > 0
     wall_ms = (max(e["ts"] + e["dur"] for e in events) -
                min(e["ts"] for e in events)) / 1e3
     observed = c["paddle_request_decode_step_ms:sum"] + \
@@ -377,6 +718,50 @@ def test_the_clock_stays_inside_the_dispatchers_wall_time(lm_scope):
     # that step's wait
     firsts = [e for e in events if e["name"] == "session:prefill_wait"]
     assert len(firsts) == len(_requests())
+    # each inside a first_token span, which a host turn holds: the wait
+    # for an owed token is admit time of a turn, like every other
+    for name, parent in (("session:prefill_wait", "scheduler:first_token"),
+                         ("scheduler:first_token", "scheduler:host_turn")):
+        for e in (e for e in events if e["name"] == name):
+            assert sum(p["ts"] - 0.5 <= e["ts"] and e["ts"] + e["dur"] <=
+                       p["ts"] + p["dur"] + 0.5 for p in events
+                       if p["name"] == parent) == 1
+
+
+def test_the_step_behind_a_prefill_counts_as_a_step_ahead(lm_scope):
+    """One iteration of the dispatcher with a step uncollected and a
+    request waiting: the prefill's call, the next step's dispatch, the
+    uncollected step's wait, and only then the wait for the prefill's
+    first token. The step launched behind the prefill counts as a step
+    ahead, and the admission as one whose token was owed."""
+    sess = _session(lm_scope)
+    sess.generate([BOS, 9], max_new_tokens=2, eos_id=-1)       # compile
+    sched = GenerationScheduler(sess, deadline_ms=0, autostart=False)
+    fa = sched.submit([BOS, 5, 7], max_new_tokens=6, eos_id=-1)
+    _place_all(sched)
+    sched._step_all()
+    fb = sched.submit([BOS, 9], max_new_tokens=4, eos_id=-1)
+    c0 = _counters()
+    tracing.start(clear=True)
+    try:
+        _place_all(sched)
+        sched._step_all()
+    finally:
+        tracing.stop()
+    order = [e["name"] for e in sorted(
+        (e for e in tracing.events() if e["ph"] == "X"),
+        key=lambda e: e["ts"]) if e["name"] in (
+            "session:prefill_call", "session:step_dispatch",
+            "session:step_wait", "session:prefill_wait")]
+    tracing.clear()
+    c = _delta(_counters(), c0)
+    assert order == ["session:prefill_call", "session:step_dispatch",
+                     "session:step_wait", "session:prefill_wait"]
+    assert c[AHEAD] == 1 and c[OWED] == 1 and c[STEPS] == 1
+    assert c["paddle_generation_prefills_total{bucket=4}"] == 1
+    _drive(sched, [fa, fb])
+    sched.close()
+    sess.close()
 
 
 def test_parking_the_dispatcher_in_its_observer_leaves_one_step_queued(

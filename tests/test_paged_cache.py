@@ -760,9 +760,15 @@ class TestPagedFailover:
             replay_attempts=2)
         try:
             # persistent step fault on session 0: the request admits
-            # there (lowest index), fails, and must replay onto 1
-            faults.arm("generation_step_fail", at=0, times=None)
-            fut = sched.submit(prompt, max_new_tokens=6, eos_id=-1)
+            # there (lowest index), fails, and must replay onto 1. Armed
+            # at its first token, which is fetched behind the first
+            # step's launch: a step failing before that would find no
+            # token in the journal, and nothing of it to share
+            def arm(_token):
+                if not faults.armed("generation_step_fail"):
+                    faults.arm("generation_step_fail", at=0, times=None)
+            fut = sched.submit(prompt, max_new_tokens=6, eos_id=-1,
+                               on_token=arm)
             got = [int(t) for t in fut.result(timeout=120)]
         finally:
             faults.disarm()
