@@ -478,13 +478,31 @@ def cache_precision(cache_dtype):
         else jax.lax.Precision.DEFAULT
 
 
-def window_edge(lengths, window, aligned):
+def window_edge(lengths, window, aligned, xp=jnp):
     """The first row the query at row ``lengths - 1`` attends: with a
     sliding window the row ``window - 1`` before it, with an **aligned**
-    one the first row of the query's own block of ``window`` rows."""
+    one the first row of the query's own block of ``window`` rows. ``xp``:
+    ``numpy`` for the host's books."""
     if aligned:
-        return jnp.maximum(lengths - 1, 0) // window * window
-    return jnp.maximum(lengths - window, 0)
+        return xp.maximum(lengths - 1, 0) // window * window
+    return xp.maximum(lengths - window, 0)
+
+
+def paged_walk(lengths, block_size, max_blocks, window=None, aligned=False,
+               xp=jnp):
+    """The pages of a table row that the paged decode kernel's walk takes
+    for a sequence of ``lengths`` rows: the first, which holds the first
+    row the query sees, and how many, up to the page of its newest row and
+    never past the row's ``max_blocks`` entries. The kernel walks by it and
+    the session's books count by it (``paged_cache.LayerCache``, with
+    ``xp=numpy``)."""
+    first = 0
+    if window is not None:
+        first = xp.minimum(
+            window_edge(lengths, window, aligned, xp) // block_size,
+            max_blocks - 1)
+    return first, xp.minimum(
+        (lengths + block_size - 1) // block_size, max_blocks) - first
 
 
 def _decode_paged_reference(q, k_pool, v_pool, lengths, tables,
@@ -575,15 +593,26 @@ def merge_walks(walks, num_heads):
 _PAGED_BUFFER_BYTES = 2 << 20
 
 
+def _pages_in_buffers(layer_block_bytes, max_blocks):
+    """Pages the paged decode kernel's buffers hold: they keep, twice
+    over and within ``_PAGED_BUFFER_BYTES``, what one block holds in one
+    layer (a page of K and one of V, or a latent pool's one page); never
+    more than a table row has."""
+    return int(max(1, min(max_blocks,
+                          _PAGED_BUFFER_BYTES // (2 * layer_block_bytes))))
+
+
 def _paged_block_pages(block_size, d_model, dtype, max_blocks, buffers=4):
     """Pages (pool blocks) the paged decode kernel fetches and attends
-    at once: what the page buffers (K and V twice over, or with one pool
-    for both its rows twice over) hold within ``_PAGED_BUFFER_BYTES``,
-    and no more than a table row has. 8 pages (128 rows) for bf16 blocks
-    of 16 x 2048."""
+    at once, a block of its walk: what the page buffers (K and V twice
+    over, or with one pool for both its rows twice over) hold within
+    ``_PAGED_BUFFER_BYTES``, and no more than a table row has. 8 pages
+    (128 rows) for bf16 blocks of 16 x 2048. It is also how many copies a
+    pool the kernel awaits at once where a block is full, and what the
+    session's books count a walk's blocks by
+    (``paged_cache.LayerCache.walk_pages``)."""
     page = block_size * d_model * jnp.dtype(dtype).itemsize
-    return int(max(1, min(max_blocks,
-                          _PAGED_BUFFER_BYTES // (buffers * page))))
+    return _pages_in_buffers(buffers // 2 * page, max_blocks)
 
 
 def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
@@ -597,7 +626,19 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
     buffers while the block before it is attended. The last step of a
     slot starts the first block of the next one, so consecutive
     programs overlap too; ``base_ref`` carries which buffer that block
-    went to. With a ``window`` the walk starts at the page that holds row
+    went to. A block's copies start in a loop over its live pages. A
+    block all of whose ``pages`` pages are live (every block of a walk
+    but its last) is awaited once a pool, through a descriptor that names
+    the whole buffer half: a DMA semaphore counts bytes. Only a walk's
+    last block, where it is partial, is awaited page by page; which of
+    the two is read from the slot's length, nothing else. The compiler's
+    bounds checks of the copies are off (they were most of what a start
+    cost, :func:`_decode_paged_call`): EVERY INDEX OF THIS KERNEL IS KEPT
+    IN RANGE BY HAND, a table entry by ``clip`` into the pool, a page's
+    place in its buffer half by the loop's bound, a table row's entries by
+    :func:`paged_walk`'s ``max_blocks``, and one added later has to be
+    too (``tests/test_paged_cache.py`` feeds the kernel hostile books).
+    With a ``window`` the walk starts at the page that holds row
     ``length - window`` and masks that page's rows behind it: pages
     before it are never read (the session has freed them). A slot whose
     first live table entry is dead (>= NB: inactive or starved) has no
@@ -638,24 +679,20 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
     mxu = jnp.promote_types(q_ref.dtype, kbuf.dtype)
     prec = cache_precision(kbuf.dtype)
 
-    def first_of(slot):
-        """The first page a slot's query can see."""
-        if window is None:
-            return 0
-        return jnp.minimum(
-            window_edge(lens_ref[slot], window, aligned) // bs, mb - 1)
-
-    def pages_of(slot, first):
-        n = jnp.minimum((lens_ref[slot] + bs - 1) // bs, mb) - first
-        return jnp.where(tab_ref[slot * mb + first] < nb, n, 0)
+    def walk_of(slot):
+        """The first page a slot's query can see and how many pages its
+        walk takes (:func:`paged_walk`): none where the first is dead."""
+        first, n = paged_walk(lens_ref[slot], bs, mb, window, aligned)
+        return first, jnp.where(tab_ref[slot * mb + first] < nb, n, 0)
 
     def live_pages(n_pages, blk):
         return jnp.clip(n_pages - blk * pages, 0, pages)
 
     def for_live_pages(slot, first, n_pages, blk, buf, act):
-        """``act`` on the K and V copies of block ``blk``'s live pages
-        (a copy is waited for through a descriptor equal to the one
-        that started it)."""
+        """``act`` on the K and V copies of block ``blk``'s live pages.
+        The compiler's bounds checks are off (:func:`_decode_paged_call`):
+        the ``clip`` is what keeps a page in the pool, ``i < pages`` the
+        destination in its buffer half."""
         def one(i, _):
             page = jnp.clip(
                 tab_ref[slot * mb + first + blk * pages + i], 0, nb - 1)
@@ -671,19 +708,45 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
         for_live_pages(slot, first, n_pages, blk, buf,
                        lambda c: c.start())
 
+    def wait(blk, buf):
+        """Wait for this slot's block ``blk``. A DMA semaphore counts
+        bytes, so a full block is awaited ONCE a pool, through a
+        descriptor that names the whole buffer half (the bytes of its
+        ``pages`` copies). A partial block waits page by page, through
+        descriptors equal to those that started its copies, and the
+        pages it did not fetch are zeroed."""
+        live = live_pages(n_pages, blk)
+
+        @pl.when(live == pages)
+        def _():
+            for pool, half in enumerate(
+                    (kbuf,) if vp_ref is None else (kbuf, vbuf)):
+                pltpu.make_async_copy(half.at[buf], half.at[buf],
+                                      sem.at[pool, buf]).wait()
+
+        @pl.when(live < pages)
+        def _():
+            for_live_pages(si, first, n_pages, blk, buf,
+                           lambda c: c.wait())
+            # pages of the last block that were not fetched hold
+            # whatever VMEM held. The mask covers their scores; V's rows
+            # go to zero (0 x NaN is NaN)
+            def zero_page(i, _):
+                vbuf[buf, pl.ds(pl.multiple_of(i * bs, bs), bs), :] = \
+                    jnp.zeros((bs, dkv), vbuf.dtype)
+            jax.lax.fori_loop(live, pages, zero_page, None)
+
     @pl.when(si == 0)
     def _():
         base_ref[0] = 0
 
     base = base_ref[0]          # the buffer of this slot's first block
-    first = first_of(si)
-    n_pages = pages_of(si, first)
+    first, n_pages = walk_of(si)
     n_blocks = (n_pages + pages - 1) // pages
-    prev = jnp.maximum(si - 1, 0)
-    started = (si > 0) & (pages_of(prev, first_of(prev)) > 0)
+    started = (si > 0) & (walk_of(jnp.maximum(si - 1, 0))[1] > 0)
     nxt = jnp.minimum(si + 1, ns - 1)
-    nxt_first = first_of(nxt)
-    nxt_pages = jnp.where(si + 1 < ns, pages_of(nxt, nxt_first), 0)
+    nxt_first, nxt_pages = walk_of(nxt)
+    nxt_pages = jnp.where(si + 1 < ns, nxt_pages, 0)
 
     @pl.when((n_blocks > 0) & jnp.logical_not(started))
     def _():
@@ -714,14 +777,7 @@ def _decode_paged_kernel(lens_ref, tab_ref, q_ref, kp_ref, *refs,
         def _():
             start(nxt, nxt_first, nxt_pages, 0, 1 - buf)
 
-        for_live_pages(si, first, n_pages, b, buf, lambda c: c.wait())
-        # pages of the last block that were not fetched hold whatever
-        # VMEM held. The mask covers their scores; V's rows go to zero
-        # (0 x NaN is NaN)
-        def zero_page(i, _):
-            vbuf[buf, pl.ds(pl.multiple_of(i * bs, bs), bs), :] = \
-                jnp.zeros((bs, dkv), vbuf.dtype)
-        jax.lax.fori_loop(live_pages(n_pages, b), pages, zero_page, None)
+        wait(b, buf)
         # explicit Precision, as in _body. A query wider than the pool
         # (f32 on bf16 blocks) upcasts the block, the reference's
         # promotion; equal dtypes go to the MXU as they are, float32
@@ -878,9 +934,12 @@ def _decode_paged_call(q, k_pool, v_pool, lengths, tables, num_heads,
                           aligned=aligned, stats=stats),
         grid_spec=grid_spec,
         out_shape=out_shape if stats else out_shape[0],
-        # programs run in order: each hands its successor a block
+        # programs run in order: each hands its successor a block. The
+        # compiler's own checks of every copy's two ends (two chains of
+        # scalar code before each start: most of what a start cost) are
+        # off: the kernel keeps its indices in range itself, and says how
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True),
         name="decode_attention_paged",
         interpret=interpret)(lens, tab, q.reshape((s,) + rows), *pools)
     if not stats:

@@ -527,7 +527,8 @@ class GenerationSession:
                                  and policy.speculate_k > 0):
             refuse_sharing(spec.cache_kinds)
         self.kinds = LayerCache.of_kinds(spec.cache_kinds, spec.block_size,
-                                         n, spec.max_blocks)
+                                         n, spec.max_blocks,
+                                         spec.kind_block_bytes)
         self._window_kinds = tuple(k for k in self.kinds if k.window)
         # the first kind's pool and its host-side block table per slot
         # (physical block ids backing logical rows [0, lengths[slot])), by

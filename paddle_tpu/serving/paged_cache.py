@@ -43,7 +43,8 @@ writes of each kind of layer cache, kept by the kind at the step's launch
 (``LayerCache.count_step``): ``_window_context_tokens_total``,
 ``_latent_rows_attended_total``, ``_state_rows_updated_total``,
 ``_eva_window_rows_total``, ``_eva_chunk_rows_total``,
-``_eva_chunks_written_total``.
+``_eva_chunks_written_total``, and ``_paged_blocks_total{kind, fill}``, the
+blocks of pages its walks take.
 """
 
 import collections
@@ -54,6 +55,7 @@ import numpy as np
 
 from ..observability import metrics as _metrics
 from ..observability import request_trace as _rtrace
+from ..ops.pallas_attention import _pages_in_buffers, paged_walk
 
 __all__ = ["BlockPool", "PrefixIndex", "PoolExhausted", "CacheKind",
            "LayerCache", "refuse_sharing"]
@@ -125,6 +127,15 @@ STATE_ROWS_UPDATED = _metrics.REGISTRY.counter(
     "State rows advanced by decode steps: per step, the layers of a state "
     "kind times the slots that advanced (each such row is read and "
     "written whole)")
+
+PAGED_BLOCKS = _metrics.REGISTRY.counter(
+    "paddle_generation_paged_blocks_total",
+    "Blocks of pages the paged decode kernel's walks take in decode steps "
+    "(a block: as many pages of a pool as the kernel's buffers hold, "
+    "fetched and attended together): per step, the sum over the slots that "
+    "advanced and over the kind's layers. fill=full: every page of the "
+    "block is live and its copies are awaited once a pool; fill=partial: "
+    "a walk's last block, awaited page by page", labelnames=("kind", "fill"))
 
 _POOL_SEQ = itertools.count()
 
@@ -340,17 +351,20 @@ class LayerCache:
     walks its kinds and asks none what it is."""
 
     @classmethod
-    def of_kinds(cls, kinds, block_size, slots, max_blocks):
-        """The books of a spec's kinds, in its order. A chunk kind is
-        handed the aligned window kind whose blocks its rows are made
-        from: a query's summaries end at that window's edge."""
-        caches = [cls(k, block_size, slots, max_blocks) for k in kinds]
+    def of_kinds(cls, kinds, block_size, slots, max_blocks, block_bytes):
+        """The books of a spec's kinds, in its order (``block_bytes``: what
+        a block of each holds over its layers, the spec's
+        ``kind_block_bytes``). A chunk kind is handed the aligned window
+        kind whose blocks its rows are made from: a query's summaries end
+        at that window's edge."""
+        caches = [cls(k, block_size, slots, max_blocks, size)
+                  for k, size in zip(kinds, block_bytes)]
         for cache in caches:
             if cache.chunk > 1:
                 cache.made_from = next(c for c in caches if c.kind.aligned)
         return caches
 
-    def __init__(self, kind, block_size, slots, max_blocks=None):
+    def __init__(self, kind, block_size, slots, max_blocks, block_bytes):
         self.kind = kind
         self.window = kind.window
         self.chunk = kind.chunk
@@ -368,6 +382,12 @@ class LayerCache:
         self.width = 1 if self.state else max_blocks
         self.tables = [[] for _ in range(slots)]
         self.first = np.zeros(slots, np.int64)
+        # pages the decode kernel fetches and attends at once of this
+        # kind's pools, the kernel's own count from what a block holds
+        # over the kind's layers (``block_bytes``); a state kind is not
+        # walked
+        self.walk_pages = 0 if self.state else _pages_in_buffers(
+            block_bytes // kind.layers, max_blocks)
 
     def extend(self, table, n_tokens, slot):
         """Append to ``table`` what a sequence of ``n_tokens`` in ``slot``
@@ -454,7 +474,9 @@ class LayerCache:
         layers = self.kind.layers
         if self.state:
             STATE_ROWS_UPDATED.inc(layers * int(lengths.size))
-        elif self.kind.name == "latent":
+            return
+        self._count_walk_blocks(lengths)
+        if self.kind.name == "latent":
             LATENT_ROWS_ATTENDED.inc(layers * int(lengths.sum()))
         elif self.kind.aligned:
             # a query attends its own window's rows, from the edge on
@@ -470,6 +492,22 @@ class LayerCache:
         elif self.window:
             WINDOW_CONTEXT_TOKENS.inc(layers * int(
                 np.minimum(lengths, self.window).sum()))
+
+    def _count_walk_blocks(self, lengths):
+        """The blocks of ``walk_pages`` pages the decode kernel's walk of
+        this kind takes for sequences of ``lengths`` rows, full ones and
+        each walk's last where it is partial, by the kernel's own
+        arithmetic (``pallas_attention.paged_walk``). A chunk kind's walk
+        is handed the summaries before its window's edge as its length."""
+        if self.chunk > 1:
+            lengths = self._edge(lengths, self.made_from.window) \
+                // self.chunk
+        _, n_pages = paged_walk(lengths, self.pool.block_size, self.width,
+                                self.window, self.kind.aligned, np)
+        for fill, blocks in (("full", n_pages // self.walk_pages),
+                             ("partial", n_pages % self.walk_pages > 0)):
+            PAGED_BLOCKS.labels(kind=self.kind.name, fill=fill).inc(
+                self.kind.layers * int(blocks.sum()))
 
     def count_prefill(self, n_tokens):
         """Count what the prefill of a prompt of ``n_tokens`` writes of

@@ -364,10 +364,11 @@ def test_the_books_over_five_windows(model):
 
 def test_layer_cache_of_an_aligned_window_and_of_a_chunk_kind():
     aligned = LayerCache(CacheKind("window", 32, 16, 3, None, None,
-                                   aligned=True), 4, 2, 48)
-    sliding = LayerCache(CacheKind("window", 32, 16, 3, None, None), 4, 2, 48)
+                                   aligned=True), 4, 2, 48, 3 << 10)
+    sliding = LayerCache(CacheKind("window", 32, 16, 3, None, None), 4, 2, 48,
+                         3 << 10)
     chunks = LayerCache(CacheKind("chunk", None, 8, 3, None, None, chunk=4),
-                        4, 2, 48)
+                        4, 2, 48, 3 << 10)
     lengths = np.array([0, 31, 32, 33, 63, 64, 100])
     assert list(aligned.first_seen(lengths)) == [0, 0, 8, 8, 8, 16, 24]
     assert list(sliding.first_seen(lengths)) == [0, 0, 0, 0, 8, 8, 17]

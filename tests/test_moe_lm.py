@@ -581,7 +581,8 @@ def test_prefill_in_row_blocks_against_plain_attention(window):
 # -- the cache kinds ---------------------------------------------------------
 
 def test_layer_cache_frees_blocks_wholly_behind_the_window():
-    kind = LayerCache(CacheKind("window", 8, 12, 2, "p", "d"), 4, 2)
+    kind = LayerCache(CacheKind("window", 8, 12, 2, "p", "d"), 4, 2, 6,
+                      2 << 10)
     kind.tables[0] = [kind.pool.alloc() for _ in range(5)]   # rows 0..19
     assert list(kind.first_seen([9, 11, 19])) == [0, 1, 3]
     assert kind.trim(0, 0) == 0           # next query 9 sees rows 2..9

@@ -311,6 +311,52 @@ def _kernel_cases():
     return [pytest.param(*c[1:], id=c[0]) for c in cases]
 
 
+def _block_cases():
+    """(id, pages a block, lengths, dead slots, the walk's keywords) of the
+    blocks' two paths: blocks of ``_BS`` rows, ``P`` pages a block."""
+    P, R = 3, 3 * _BS           # pages and rows of a block
+    cases = [
+        # one full block a slot and no partial one: each hands its
+        # successor a full first block
+        ("a_block", P, [R, R, R], (), {}),
+        ("a_block_and_a_page", P, [R + 1, R + _BS, R + 1], (), {}),
+        ("two_blocks", P, [2 * R, 2 * R - _BS + 1, 2 * R], (), {}),
+        ("three_blocks_and_one", P, [3 * R, R, 3 * R + 2, 1], (), {}),
+        ("one_page", P, [1, _BS, 2], (), {}),
+        ("none", P, [R, 2 * R], (0, 1), {}),
+        # a starved slot between two live ones: the first block of the
+        # slot behind it, a full one, is started by that slot itself
+        ("starved_between", P, [2 * R, 7, R + 2, 2 * R], (1,), {}),
+        ("dead_first_and_last", P, [5, R, R + 1, 9], (0, 3), {}),
+        # the window's edge in the first page of a full block (rows 13-22
+        # of 23: pages 3, 4, 5), of a partial one, and behind two blocks
+        ("window_edge_in_a_full_block", P, [23, 24, 21], (),
+         {"window": 10}),
+        ("window_of_two_blocks", P, [2 * R + 9, 50, 7], (),
+         {"window": 2 * R - 2}),
+        # an aligned window's walk and a plain one with their statistics:
+        # two and three full blocks, and the rows just past them
+        ("aligned_two_blocks", P, [4 * R, 2 * R, 4 * R + 1], (),
+         {"window": 2 * R, "aligned": True, "stats": True}),
+        ("aligned_three_blocks", P, [6 * R, 3 * R, 3 * R + 2], (),
+         {"window": 3 * R, "aligned": True, "stats": True}),
+        ("stats_two_and_three_blocks", P, [2 * R, 3 * R, 3 * R + 5, 0],
+         (3,), {"stats": True}),
+        # four query heads on two KV heads
+        ("grouped_queries", P, [R, 2 * R + 1, 3, 2 * R], (),
+         {"num_kv_heads": 2}),
+        ("grouped_queries_window", P, [23, 2 * R + 9, R], (),
+         {"num_kv_heads": 2, "window": 10}),
+        # a latent pool (the value is the head of the key's row), blocks of
+        # 13 pages: one, one and a page, two, less than one
+        ("latent_13_pages", 13, [13 * _BS, 13 * _BS + 1, 26 * _BS, 3], (),
+         {"num_kv_heads": 1, "v_width": 16, "scale": 0.2}),
+        ("latent_starved", 13, [26 * _BS, 9, 13 * _BS], (1,),
+         {"num_kv_heads": 1, "v_width": 16, "scale": 0.2}),
+    ]
+    return [pytest.param(*c[1:], id=c[0]) for c in cases]
+
+
 def _strict_interpreter():
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.InterpretParams(uninitialized_memory="nan",
@@ -379,6 +425,147 @@ class TestPagedDecodeKernel:
             jnp.asarray(lengths), jnp.asarray(tables), _H)
         np.testing.assert_allclose(out, np.asarray(ref, np.float32),
                                    atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("interpret", [True, _strict_interpreter],
+                             ids=["interpreter", "strict"])
+    @pytest.mark.parametrize("pool", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("pages,lengths,dead,kw", _block_cases())
+    def test_full_blocks_and_partial_ones_walk_by_their_own_paths(
+            self, monkeypatch, pages, lengths, dead, kw, pool, interpret):
+        """A block all of whose ``pages`` pages are live is awaited once a
+        pool, through the buffer half's own descriptor; a walk's last,
+        partial block waits page by page; a slot hands its successor's
+        first block over and that slot awaits it by either. The
+        lengths put both paths and their seams side by side, under a
+        window, an aligned window, with the statistics, on a latent pool
+        and under grouped queries. Every block no table names, the pages
+        behind a window and the strict interpreter's unfilled VMEM hold
+        NaN: a page awaited but never started, or started and never
+        awaited (its bytes left on the semaphore for the next block's
+        wait), shows as a NaN or as a race."""
+        from paddle_tpu.ops import pallas_attention as pa
+        if interpret is not True:
+            interpret = interpret()
+        H, HD, BS = 4, 8, _BS
+        nkv = kw.get("num_kv_heads", H)
+        width = 24 if kw.get("v_width") else nkv * HD
+        mb = max(-(-max(lengths) // BS), pages) + 1
+        monkeypatch.setattr(
+            pa, "_PAGED_BUFFER_BYTES",
+            (2 if kw.get("v_width") else 4) * pages * BS * width
+            * jnp.dtype(pool).itemsize)
+        rs = np.random.RandomState(1)
+        S = len(lengths)
+        lens = np.asarray(lengths, np.int32)
+        nb = S * mb + 3
+        tables = np.full((S, mb), nb + 7, np.int32)
+        pools = [np.full((nb, BS, width), np.nan, "float32")
+                 for _ in range(1 if kw.get("v_width") else 2)]
+        free = iter(rs.permutation(nb))
+        window = kw.get("window")
+        for s in range(S):
+            if s in dead:
+                continue
+            edge = 0 if window is None else int(pa.window_edge(
+                lens[s], window, kw.get("aligned", False)))
+            for j in range(edge // BS, -(-int(lens[s]) // BS)):
+                tables[s, j] = b = next(free)
+                for held in pools:
+                    held[b] = rs.randn(BS, width)
+        # a walk to be merged runs on a query of the pools' own dtype (the
+        # reference rounds its weights to it): bfloat16's own rounding
+        query = pool if kw.get("stats") else "float32"
+        tol = 2e-2 if query == "bfloat16" else 1e-5
+        q = jnp.asarray(rs.randn(S, 1, H * width // nkv), query)
+        args = (jnp.asarray(lens), jnp.asarray(tables), H)
+        got = pa.decode_attention_paged(
+            q, *[jnp.asarray(x, pool) for x in pools],
+            *([None] if len(pools) == 1 else []), *args,
+            interpret=interpret, **kw)
+        want = pa._decode_paged_reference(
+            q, *[jnp.asarray(np.nan_to_num(x), pool) for x in pools],
+            *([None] if len(pools) == 1 else []), *args, **kw)
+        live = [s for s in range(S) if s not in dead]
+        for g, w in zip(*([got, want] if kw.get("stats")
+                          else [[got], [want]])):
+            g = np.asarray(g, np.float32)
+            assert np.isfinite(g).all()
+            np.testing.assert_allclose(
+                g[live], np.asarray(w, np.float32)[live], atol=tol, rtol=tol)
+        out = np.asarray(got[0] if kw.get("stats") else got)
+        assert (out[list(dead)] == 0).all()
+
+    @pytest.mark.parametrize("kw", [
+        {}, {"window": 10}, {"window": 18, "aligned": True, "stats": True},
+        {"num_kv_heads": 1, "v_width": 16, "scale": 0.2}],
+        ids=["plain", "window", "aligned_stats", "latent"])
+    @pytest.mark.parametrize("hostile", [
+        "negative_entries", "entries_past_the_pool", "length_past_the_table",
+        "negative_length"])
+    def test_hostile_books_stay_inside_the_pool_and_the_buffers(
+            self, monkeypatch, hostile, kw):
+        """The kernel's copies run with the compiler's bounds checks off,
+        so its own arithmetic is all that keeps an index in range. Books no
+        session writes (negative table entries and entries past the pool in
+        a live slot's later pages, lengths past their table rows, a negative
+        one) beside honest slots: the strict interpreter raises on a
+        read outside the pool, the table or a buffer, every result is
+        finite, the honest slots' and the one with clipped entries are the
+        reference's, which clips the same."""
+        from paddle_tpu.ops import pallas_attention as pa
+        H, HD, BS, P, mb = 4, 8, _BS, 3, 8
+        nkv = kw.get("num_kv_heads", H)
+        width = 24 if kw.get("v_width") else nkv * HD
+        monkeypatch.setattr(
+            pa, "_PAGED_BUFFER_BYTES",
+            (2 if kw.get("v_width") else 4) * P * BS * width * 4)
+        rs = np.random.RandomState(2)
+        nb = 3 * mb
+        lens = np.asarray([2 * P * BS + 3, 2 * P * BS + 1, P * BS], np.int32)
+        tables = rs.permutation(nb).reshape(3, mb).astype(np.int32)
+        tables[0, -(-int(lens[0]) // BS):] = nb + 7
+        tables[2, P:] = nb + 7
+        # behind the slot's first page, which says whether it lives
+        later = slice(int(pa.paged_walk(
+            lens[1], BS, mb, kw.get("window"), kw.get("aligned", False),
+            np)[0]) + 1, None, 2)
+        if hostile == "negative_entries":
+            tables[1, later] = [-1, -nb - 1, -(2 ** 31), -7][
+                :len(tables[1, later])]
+        elif hostile == "entries_past_the_pool":
+            tables[1, later] = [nb, nb + 1, 2 ** 31 - 1, 4 * nb][
+                :len(tables[1, later])]
+        elif hostile == "length_past_the_table":
+            # the last slot's too: behind its row the table ends
+            lens[1:] = mb * BS + 3 * P * BS + 1
+        else:
+            lens[1] = -5
+        pools = [rs.randn(nb, BS, width).astype("float32")
+                 for _ in range(1 if kw.get("v_width") else 2)]
+        q = jnp.asarray(rs.randn(3, 1, H * width // nkv), "float32")
+        args = (*([None] if len(pools) == 1 else []), jnp.asarray(lens),
+                jnp.asarray(tables), H)
+        got = pa.decode_attention_paged(
+            q, *map(jnp.asarray, pools), *args,
+            interpret=_strict_interpreter(), **kw)
+        want = pa._decode_paged_reference(
+            q, *map(jnp.asarray, pools), *args, **kw)
+        # a slot with no rows: the kernel writes zeros, the reference
+        # attends clamped rows nobody reads. A length past the table: the
+        # walk ends with the row's last entry, and the mask, which trusts
+        # the length, lets the unfetched pages' stale rows in: nothing
+        # foreign is read, and the slot's result is nobody's
+        live = {"negative_length": [0, 2], "length_past_the_table": [0]}.get(
+            hostile, [0, 1, 2])
+        for g, w in zip(*([got, want] if kw.get("stats")
+                          else [[got], [want]])):
+            g = np.asarray(g)
+            assert np.isfinite(g).all()
+            np.testing.assert_allclose(g[live], np.asarray(w)[live],
+                                       atol=1e-5, rtol=1e-5)
+        if hostile == "negative_length":
+            assert (np.asarray(got[0] if kw.get("stats") else got)[1]
+                    == 0).all()
 
     @pytest.mark.parametrize("dtype,max_blocks,pages", [
         ("bfloat16", 128, 8), ("float32", 128, 4), ("bfloat16", 5, 5)])

@@ -9,6 +9,7 @@ cannot see tiling or VMEM refusals; this file can, at no chip time.
 Skipped as a whole where the topology cannot be described (no libtpu).
 """
 
+import functools
 import os
 import re
 import sys
@@ -147,12 +148,16 @@ def test_flash_attention_forward_keeps_its_operands_bfloat16(chip, fn):
         assert not re.search(r"f32\[(64|4,16),2048,128\]", hlo)
 
 
-def _bwd_paths(since=None):
-    """Traced ``flash_attention_bwd`` sites by path, less those of
-    ``since`` (an earlier reading)."""
-    now = kernel_path.counts().get("flash_attention_bwd", {})
+def _paths(kernel, since=None):
+    """Traced sites of ``kernel`` by path, less those of ``since`` (an
+    earlier reading)."""
+    now = kernel_path.counts().get(kernel, {})
     return {p: n - (since or {}).get(p, 0) for p, n in now.items()
             if n != (since or {}).get(p, 0)}
+
+
+_bwd_paths = functools.partial(_paths, "flash_attention_bwd")
+_paged_paths = functools.partial(_paths, "decode_attention_paged")
 
 
 @pytest.mark.parametrize("qkv,extra,name", [
@@ -228,12 +233,31 @@ def test_decode_attention_paged_compiles_grouped_queries(chip, window):
     """trinity-serve-offline's two kinds of layer: 64 slots, 32 query
     heads on 4 KV heads of 128, float32 query and pools 512 wide (products
     at the highest precision), table rows of 512; the same kernel, with
-    and without the window in its page walk."""
+    and without the window in its page walk. A block of its walk is 16
+    pages, awaited once a pool where all are live."""
+    before = _paged_paths()
     assert _has_kernel(
         _paged(chip, 32, 128, F32, query=F32, slots=64,
                max_blocks=512, pool_blocks=8704, num_kv_heads=4,
                window=window),
         "decode_attention_paged")
+    assert _paged_paths(before) == {"compiled": 1}
+    assert pa._paged_block_pages(16, 512, F32, 512) == 16
+
+
+def test_decode_attention_paged_compiles_at_nemotrons_geometry(chip):
+    """nemotron-serve-offline's attention layers: 128 slots, 32 query
+    heads on 2 KV heads of 128, float32 pools 256 wide in blocks of 16
+    rows (a page is 16 KB, the smallest served), table rows of 256: a
+    block of the walk is 32 pages of K and 32 of V, 64 copies awaited in
+    two waits."""
+    before = _paged_paths()
+    assert _has_kernel(
+        _paged(chip, 32, 128, F32, query=F32, slots=128,
+               max_blocks=256, pool_blocks=32768, num_kv_heads=2),
+        "decode_attention_paged")
+    assert _paged_paths(before) == {"compiled": 1}
+    assert pa._paged_block_pages(16, 256, F32, 256) == 32
 
 
 @pytest.mark.parametrize("aligned", [True, False], ids=["window", "chunks"])
@@ -248,9 +272,11 @@ def test_decode_attention_paged_compiles_a_walk_to_be_merged(chip, aligned):
             q, kp, vp, lens, tables, 32, interpret=False,
             window=2048 if aligned else None, aligned=aligned, stats=True)
     pool = ((3072 if aligned else 1152, 16, 4096), BF16)
+    before = _paged_paths()
     hlo = _compile(chip, fn, ((24, 1, 4096), BF16), pool, pool,
                    ((24,), I32), ((24, 768), I32))
     assert _has_kernel(hlo, "decode_attention_paged")
+    assert _paged_paths(before) == {"compiled": 1}
     assert re.search(r"f32\[24,1,4096\]", hlo) and \
         re.search(r"f32\[24,32,128\]", hlo), hlo[-2000:]
 
@@ -464,10 +490,12 @@ def test_decode_attention_paged_compiles_on_a_latent_pool(chip, block_size):
         return pa.decode_attention_paged(
             q, pool, None, lens, tables, 64, interpret=False,
             num_kv_heads=1, v_width=512, scale=0.1447)
+    before = _paged_paths()
     hlo = _compile(chip, fn, ((32, 1, 64 * 640), F32),
                    ((32 * max_blocks, block_size, 640), F32), ((32,), I32),
                    ((32, max_blocks), I32))
     assert _has_kernel(hlo, "decode_attention_paged")
+    assert _paged_paths(before) == {"compiled": 1}
     assert re.search(r"f32\[32,64,512\]", hlo), hlo[-2000:]
 
 

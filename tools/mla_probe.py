@@ -2,12 +2,16 @@
 """The kernels of the ``kimi_k2`` decode step alone on the chip, at the
 published widths, against their bytes and operations
 (``benchmarks/architectures/kimi_k2.py`` counts both; PERF.md section 7,
-PR 31):
+PR 31), and beside them ``trinity-mini``'s walk of the same kernel:
 
 * ``decode_attention_paged`` over a paged latent pool (64 query heads on one
   KV head whose value is the leading 512 lanes of its key's 640-lane row,
   float32, products at the highest precision) at ``--slots`` slots and each
   of ``--contexts`` rows a slot, for each of ``--block-sizes``;
+* the same kernel over float32 K and V pools under grouped queries (32
+  heads on 4 KV heads of 128) at ``--gq-slots`` slots and each of
+  ``--gq-contexts`` rows, for each of ``--gq-block-sizes``, over the whole
+  context and over the configuration's sliding window of 2,048;
 * the held experts' three grouped matmuls (``moe_ops._expert_rows``: 12
   experts of 7168 x 2048 in bfloat16, float32 rows in three pieces) for
   each of ``--routed-pairs``: that many pairs are drawn uniformly over 384
@@ -20,12 +24,18 @@ Per line: milliseconds a call (the mean of ``--reps`` calls queued back to
 back), the bytes and FLOPs counted once, the share of the bytes' floor at
 819 GB/s and of the FLOPs' floor at 197 TFLOP/s (counted once: exact
 products take three to six passes), and the largest difference from the
-XLA reference of the same call (for the latent decode, of its first
-slot). Fails off the chip (``--rehearse 1`` runs
-the interpreter at small sizes).
+XLA reference of the same call (for a walk, of its first slot). A walk's
+line also says how many pages it fetched and nanoseconds a page (of K and V
+pools: a pair), and the first two block sizes of a context are solved for
+the part of the time that goes with the pages and the part that goes with
+the rows (``ns_with_a_page``, ``ms_with_the_rows``: the products of
+``streamed_rows`` query rows against every row attended). An empty list
+leaves a part out (``--routed-pairs ""``). Fails off the chip
+(``--rehearse 1`` runs the interpreter at small sizes).
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -61,41 +71,91 @@ def _shares(ms, flops, nbytes):
                 flops / PEAK_FLOPS / (ms / 1e3), 4)}
 
 
-def latent_decode(cfg, arch, slots, contexts, block_sizes, reps, interpret,
-                  seed):
+def _walk(label, slots, contexts, block_sizes, reps, interpret, seed,
+          heads, kv_heads, width, count, window=None, **kw):
+    """One line for each block size and context of a paged walk: ``slots``
+    slots of ``ctx`` rows over pools ``width`` lanes wide (``kv_heads`` 1
+    and a ``v_width`` in ``kw``: one latent pool; else a K and a V pool),
+    ``count(rows)`` its FLOPs and bytes. Then, for each context, the two
+    block sizes' lines solved for the part of the time that goes with the
+    pages fetched and the part that goes with the rows attended."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import pallas_attention as pa
-    nh, rank = cfg["num_attention_heads"], cfg["kv_lora_rank"]
-    width = arch.row_width(cfg)
-    scale = 0.1
     rs = np.random.RandomState(seed % (2 ** 31))
+    kw = dict(kw, num_kv_heads=kv_heads, window=window)
+    lines = {}
     for bs in block_sizes:
         for ctx in contexts:
             mb = -(-ctx // bs)
             nb = slots * mb
-            pool = jnp.asarray(rs.standard_normal((nb, bs, width)) * 0.3,
-                               jnp.float32)
+            pools = [jnp.asarray(rs.standard_normal((nb, bs, width)) * 0.3,
+                                 jnp.float32)
+                     for _ in range(1 if kw.get("v_width") else 2)]
             tables = jnp.asarray(rs.permutation(nb).reshape(slots, mb),
                                  jnp.int32)
             lens = jnp.full((slots,), ctx, jnp.int32)
-            q = jnp.asarray(rs.standard_normal((slots, 1, nh * width)),
-                            jnp.float32)
-            kw = dict(num_kv_heads=1, v_width=rank, scale=scale)
-            kernel = jax.jit(lambda q, p, l, t: pa.decode_attention_paged(
-                q, p, None, l, t, nh, interpret=interpret, **kw))
-            ms, out = _time(kernel, (q, pool, lens, tables), reps)
+            q = jnp.asarray(rs.standard_normal(
+                (slots, 1, heads * width // kv_heads)), jnp.float32)
+
+            def call(fn, q, lens, tables, *pools, **more):
+                return fn(q, pools[0], pools[1] if len(pools) > 1 else None,
+                          lens, tables, heads, **kw, **more)
+            kernel = jax.jit(functools.partial(
+                call, pa.decode_attention_paged, interpret=interpret))
+            ms, out = _time(kernel, (q, lens, tables, *pools), reps)
             # the XLA reference gathers every head's rows: one slot of it
-            want = jax.jit(lambda q, p, l, t: pa._decode_paged_reference(
-                q, p, None, l, t, nh, **kw))(q[:1], pool, lens[:1],
-                                             tables[:1])
-            flops, nbytes = arch.latent_decode_ops_and_bytes(
-                cfg, slots * ctx, 4)
-            say(kernel="decode_attention_paged", pool="latent float32",
-                slots=slots, context=ctx, block_size=bs,
-                page_bytes=bs * width * 4,
+            want = jax.jit(functools.partial(
+                call, pa._decode_paged_reference))(
+                    q[:1], lens[:1], tables[:1], *pools)
+            # what the walk reads: the rows the query can see, and the
+            # pages that hold them
+            seen = min(ctx, window or ctx)
+            pages = slots * (mb - (ctx - seen) // bs)
+            flops, nbytes = count(slots * seen)
+            lines[bs, ctx] = (ms, pages)
+            say(kernel="decode_attention_paged", pool=label, slots=slots,
+                context=ctx, window=window, block_size=bs,
+                page_bytes=bs * width * 4, pages=pages,
+                ns_a_page=round(ms * 1e6 / pages, 2),
                 max_diff_from_reference=float(jnp.abs(out[:1] - want).max()),
                 **_shares(ms, flops, nbytes))
+    if len(block_sizes) < 2:
+        return
+    a, b = block_sizes[:2]
+    for ctx in contexts:
+        (ms_a, pages_a), (ms_b, pages_b) = lines[a, ctx], lines[b, ctx]
+        if pages_a == pages_b:
+            continue
+        # ms = pages x (a page's part) + (the rows' part), the rows' part
+        # the same at both block sizes
+        page_ns = (ms_a - ms_b) * 1e6 / (pages_a - pages_b)
+        say(kernel="decode_attention_paged", pool=label, context=ctx,
+            window=window, block_sizes=[a, b], streamed_rows=heads,
+            ns_with_a_page=round(page_ns, 2),
+            ms_with_the_rows=round(ms_a - page_ns * pages_a / 1e6, 4))
+
+
+def latent_decode(cfg, arch, slots, contexts, block_sizes, reps, interpret,
+                  seed):
+    """The latent walk: 64 heads on one pool whose row is key and value."""
+    _walk("latent float32", slots, contexts, block_sizes, reps, interpret,
+          seed, cfg["num_attention_heads"], 1, arch.row_width(cfg),
+          lambda rows: arch.latent_decode_ops_and_bytes(cfg, rows, 4),
+          v_width=cfg["kv_lora_rank"], scale=0.1)
+
+
+def grouped_decode(cfg, slots, contexts, block_sizes, reps, interpret, seed):
+    """The grouped-query walk of ``trinity-mini``: 32 heads on 4 KV heads
+    of 128, a K and a V pool, over the whole context and over the
+    configuration's sliding window."""
+    heads, kv_heads = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    width = kv_heads * cfg["head_dim"]
+    for window in (None, cfg["sliding_window"]):
+        _walk("grouped-query float32", slots, contexts, block_sizes, reps,
+              interpret, seed, heads, kv_heads, width,
+              lambda rows: (4 * heads * cfg["head_dim"] * rows,
+                            2 * width * 4 * rows), window=window)
 
 
 def grouped_matmul(cfg, arch, routed_pairs, reps, seed):
@@ -155,6 +215,9 @@ def main(argv=None):
     ap.add_argument("--slots", type=int, default=32)
     ap.add_argument("--contexts", default="512,3300,8000")
     ap.add_argument("--block-sizes", default="32,16")
+    ap.add_argument("--gq-slots", type=int, default=64)
+    ap.add_argument("--gq-contexts", default="1024,2048,4096")
+    ap.add_argument("--gq-block-sizes", default="16,32")
     ap.add_argument("--routed-pairs", default="256,32768")
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=3100000001)
@@ -166,17 +229,22 @@ def main(argv=None):
     from paddle_tpu.ops import kernel_path
     cfg = lm.load_config("kimi-k2.7-code-l6")
     arch = architectures.load(cfg)
+    gq_cfg = lm.load_config("trinity-mini-l5")
     on_chip = jax.default_backend() == "tpu"
     if not on_chip and not args.rehearse:
         say(ok=False, why="no TPU backend: %s" % jax.default_backend())
         return 1
     if args.rehearse:
         cfg = arch.tiny(cfg)
+        gq_cfg = architectures.load(gq_cfg).tiny(gq_cfg)
     say(device=jax.devices()[0].device_kind, rehearsal=bool(args.rehearse))
     ints = lambda s: [int(x) for x in s.split(",") if x]     # noqa: E731
     before = kernel_path.counts()
     latent_decode(cfg, arch, args.slots, ints(args.contexts),
                   ints(args.block_sizes), args.reps, not on_chip, args.seed)
+    grouped_decode(gq_cfg, args.gq_slots, ints(args.gq_contexts),
+                   ints(args.gq_block_sizes), args.reps, not on_chip,
+                   args.seed)
     grouped_matmul(cfg, arch, ints(args.routed_pairs), args.reps, args.seed)
     after = kernel_path.counts()
     say(kernel_paths={k: {p: n - before.get(k, {}).get(p, 0)
