@@ -8,7 +8,8 @@ from . import nn as _nn
 from . import ops as _ops
 
 __all__ = ["rms_norm", "rotary_embedding", "linear", "swiglu", "ffn",
-           "moe_ffn", "mla_attention", "eva_attention"]
+           "moe_ffn", "mla_attention", "eva_attention", "bias_add",
+           "diff_attention_queries", "diff_attention_combine"]
 
 
 def rms_norm(x, epsilon=1e-5, group_size=0, param_attr=None, name=None,
@@ -70,6 +71,50 @@ def linear(x, size, param_attr, dtype=None, std=0.02, transpose_w=False,
     helper.append_op(type="linear", inputs={"X": [x.name], "W": [w.name]},
                      outputs={"Out": [out.name]},
                      attrs={"transpose_w": True} if transpose_w else {})
+    return out
+
+
+def bias_add(x, param_attr, std=0.02, **kwargs):
+    """``x + b`` over the last axis, ``b`` float32 drawn Normal(0, std): a
+    projection's bias, which :func:`linear` has none of."""
+    helper = LayerHelper("bias_add", **kwargs)
+    b = helper.create_parameter(
+        param_attr, shape=[x.shape[-1]], dtype="float32",
+        default_initializer=NormalInitializer(0.0, std))
+    return _nn.elementwise_add(x, b, **kwargs)
+
+
+def diff_attention_queries(q, head_dim, **kwargs):
+    """q [.., 2P*D] -> [.., 2P*2D]: a differential pair's two queries as
+    two heads over key rows ``(k1 | k2)`` (ops/attention_ops.py)."""
+    helper = LayerHelper("diff_attention_queries", **kwargs)
+    out = helper.create_tmp_variable(q.dtype)
+    helper.append_op(type="diff_attention_queries", inputs={"Q": [q.name]},
+                     outputs={"Out": [out.name]},
+                     attrs={"head_dim": head_dim})
+    return out
+
+
+def diff_attention_combine(x, head_dim, lambda_init, prefix, epsilon=1e-5,
+                           lambda_std=0.1, **kwargs):
+    """x [.., 2P*2D], each pair's two attentions -> [.., P*2D] float32:
+    ``RMSNorm(x1 - lambda x2) (1 - lambda_init)``. Parameters
+    ``<prefix>.lambda_q1``, ``.lambda_k1``, ``.lambda_q2``, ``.lambda_k2``
+    [D] drawn Normal(0, lambda_std) and ``.subln.w`` [2D] ones, float32."""
+    helper = LayerHelper("diff_attention_combine", **kwargs)
+    inputs = {"X": [x.name]}
+    for slot, name in (("LambdaQ1", "lambda_q1"), ("LambdaK1", "lambda_k1"),
+                       ("LambdaQ2", "lambda_q2"), ("LambdaK2", "lambda_k2")):
+        inputs[slot] = [helper.create_parameter(
+            "%s.%s" % (prefix, name), shape=[head_dim], dtype="float32",
+            default_initializer=NormalInitializer(0.0, lambda_std)).name]
+    inputs["NormW"] = [helper.create_parameter(
+        prefix + ".subln.w", shape=[2 * head_dim], dtype="float32",
+        default_initializer=ConstantInitializer(1.0)).name]
+    out = helper.create_tmp_variable("float32")
+    helper.append_op(type="diff_attention_combine", inputs=inputs,
+                     outputs={"Out": [out.name]},
+                     attrs={"lambda_init": lambda_init, "epsilon": epsilon})
     return out
 
 

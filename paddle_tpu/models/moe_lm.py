@@ -25,6 +25,29 @@ mixer that is layer by layer a Mamba-2 state-space mixer (``"mamba"``,
   ``benchmarks/reference/evabyte.py``). Served in the operands it is held
   in: under the ``amp`` flag its products take one pass.
 
+The seventh family (``phi4flash``, the SambaY decoder-hybrid-decoder;
+``benchmarks/reference/phi4flash.py``) is ``gqa`` again with more of it as
+data, and two layer types that **borrow**: its mixer is Mamba-1's
+(``mamba=dict(scan="s6", ..)``: a decay matrix, ``ops/ssm_ops.py``), its
+attention is **differential** (``differential``: heads in pairs ``(2p,
+2p+1)``, two softmaxes over one value of twice the head's lanes, ``attn1 -
+lambda attn2`` under an RMSNorm over those lanes; held as ordinary grouped
+queries of twice the head's width on half the KV heads, a query padded
+with zeros on the half it does not score, so that the paged kernel serves
+it as it stands and reads a cached page once for both softmaxes), with
+biases on its projections (``attn_bias``), LayerNorm for RMSNorm
+(``norm="layer"``) and no rotary turn anywhere (``window_rotary=False``).
+``"cross_attention"`` is a layer that computes a query only and walks the
+keys and values of layer ``kv_from``: it has no layer cache, writes
+nothing and reads that layer's rows of this very step. ``"gmu"`` is a
+gated memory unit, ``(silu(a W_in) * m) W_out`` with ``m`` the same token's
+scan output of layer ``memory_from`` before its gate: an activation handed
+from one layer to later ones, neither a weight nor a cache. Layers from the
+first that hands something on are traced under ``cross_decoder``, those
+before it under ``self_decoder``; and where the model ends in layers that
+own no cache, a prefill runs them on the prompt's last row alone (nothing
+reads their other rows).
+
 The layer's own shape is data too. Without ``block`` a layer is one mixer
 and then one feed-forward, each added to the residual stream where it was
 read. With ``block`` (``halves``, ``experts_read``, ``experts_join``) a
@@ -66,6 +89,7 @@ is every activation: the products are exact (ops/moe_ops.py says why), so
 the layer caches should be float32 too.
 """
 
+import contextlib
 import math
 
 from .. import layers
@@ -79,6 +103,9 @@ __all__ = ["moe_lm", "moe_lm_session", "MoeLM"]
 
 SLIDING, FULL, MAMBA = "sliding_attention", "full_attention", "mamba"
 EXPERTS = "experts"
+# the two types that borrow: another layer's keys and values, another
+# layer's scan output
+CROSS, GMU = "cross_attention", "gmu"
 # what a trace's operations of a one-sublayer layer go under, by its type
 _SUBLAYER_SCOPE = {MAMBA: "mamba2_mixer", EXPERTS: "moe_ffn",
                    FULL: "attention", SLIDING: "attention"}
@@ -101,17 +128,24 @@ class MoeLM:
                  attn_scale=None, residual_scale=None, logit_scale=None,
                  tie_embeddings=False, mamba=None, shared_d_ff=None,
                  block=None, zero_experts=0, eva=None, norm_offset=0.0,
-                 pred_heads=1, expert_act=None):
+                 pred_heads=1, expert_act=None, norm="rms", attn_bias=False,
+                 differential=False, window_rotary=True, kv_from=None,
+                 memory_from=None):
         self.block = dict(block) if block else None
         # a layer of one sublayer, among them the fourth type
         self.single = (self.block or {}).get("sublayers") == 1
         unknown = set(layer_types) - {SLIDING, FULL, MAMBA} \
-            - ({EXPERTS} if self.single else set())
+            - ({EXPERTS} if self.single else {CROSS, GMU})
         if unknown:
             raise ValueError(
-                "layer_types holds %s: a layer is %r, %r or %r, and in a "
-                "block of one sublayer %r"
-                % (sorted(unknown), SLIDING, FULL, MAMBA, EXPERTS))
+                "layer_types holds %s: a layer is %r, %r or %r, in a "
+                "block of one sublayer also %r, and in a block of a mixer "
+                "and a feed-forward also %r (reading the keys and values "
+                "of layer kv_from) or %r (reading the scan output of layer "
+                "memory_from)" % (sorted(unknown), SLIDING, FULL, MAMBA,
+                                  EXPERTS, CROSS, GMU))
+        self.kv_from, self.memory_from = kv_from, memory_from
+        self._check_borrowed(layer_types, mamba or {}, attention)
         if attention not in ("gqa", "latent", "eva"):
             raise ValueError("attention is 'gqa', 'latent' or 'eva', not %r"
                              % (attention,))
@@ -142,6 +176,15 @@ class MoeLM:
         self.zero_experts = zero_experts
         self.norm_offset, self.pred_heads = norm_offset, pred_heads
         self.expert_act = expert_act
+        self.norm, self.attn_bias = norm, attn_bias
+        self.differential, self.window_rotary = differential, window_rotary
+        if norm not in ("rms", "layer") or (differential and (
+                attention != "gqa" or qk_norm or attn_gate
+                or num_heads % 2 or num_kv_heads % 2)):
+            raise ValueError("norm is 'rms' or 'layer'; differential "
+                             "attention is grouped-query attention's, with "
+                             "heads and KV heads in pairs and neither a "
+                             "norm on q and k nor a gate")
         if self.single:
             if attention != "gqa" or num_dense_layers or post_norms:
                 raise ValueError("a block of one sublayer is grouped-query "
@@ -166,30 +209,87 @@ class MoeLM:
             self._eva_sizes(**eva)
             return
         # the kinds of layer cache, full first where the model has both;
-        # per layer the width of a cached row and its kind
+        # per layer the width of a cached row and its kind. The kind of the
+        # layer whose pool the cross layers walk counts them as borrowers
         present = [t for t in (FULL, SLIDING) if t in self.layer_types]
         self.kinds = tuple(("full", None) if t == FULL else
                            ("window", sliding_window) for t in present)
+        walks = self.layer_types.count(CROSS)
+        if walks:
+            lent = present.index(self.layer_types[kv_from])
+            self.kinds = tuple(
+                kind + (dict(borrowers=walks),) if k == lent else kind
+                for k, kind in enumerate(self.kinds))
         state_row = None
         if MAMBA in self.layer_types:
             if not present:
                 raise ValueError("a model of state-space layers alone has "
                                  "no paged kind for the session to size")
-            # (num_heads, head_dim, state_dim, conv_width, chunk); a slot's
-            # row in a layer: the scan's state and the convolution's inputs
+            # a slot's row in a layer: the scan's state and the
+            # convolution's inputs. Mamba-2: (num_heads, head_dim,
+            # state_dim, conv_width, chunk); Mamba-1 (``scan`` "s6"):
+            # (d_inner, state_dim, conv_width, dt_rank), its convolution
+            # over x alone
             self.mamba = dict(mamba)
-            lanes = mamba["num_heads"] * mamba["head_dim"] \
-                + 2 * mamba.get("n_groups", 1) * mamba["state_dim"]
-            state_row = ((mamba["num_heads"], mamba["head_dim"],
-                          mamba["state_dim"]), (mamba["conv_width"], lanes))
+            if self.mamba.pop("scan", "ssd") == "s6":
+                self.s6 = True
+                state_row = ((mamba["state_dim"], mamba["d_inner"]),
+                             (mamba["conv_width"], mamba["d_inner"]))
+            else:
+                lanes = mamba["num_heads"] * mamba["head_dim"] \
+                    + 2 * mamba.get("n_groups", 1) * mamba["state_dim"]
+                state_row = ((mamba["num_heads"], mamba["head_dim"],
+                              mamba["state_dim"]),
+                             (mamba["conv_width"], lanes))
             self.kinds += (("state", None),)
-        # a layer's site among the layer caches: an expert layer has none
-        cached = [i for i, t in enumerate(self.layer_types) if t != EXPERTS]
+        # a layer's site among the layer caches: an expert layer has none,
+        # nor has a layer that borrows
+        cached = [i for i, t in enumerate(self.layer_types)
+                  if t not in (EXPERTS, CROSS, GMU)]
+        if walks or GMU in self.layer_types:
+            # the cross-decoder: from the first layer that hands something
+            # on; and its tail, the layers after the last that owns a cache
+            self.cross_from = min(
+                i for i in (kv_from, memory_from) if i is not None)
+            self.tail_from = cached[-1] + 1
+            if any(t in (CROSS, GMU)
+                   for t in self.layer_types[:self.tail_from]):
+                raise ValueError(
+                    "a layer that owns a cache follows one that borrows: a "
+                    "prefill runs the borrowing layers on the prompt's "
+                    "last row alone, so they close the model")
         self.site = {i: at for at, i in enumerate(cached)}
         self.cache_layers = [
             (state_row, len(present)) if self.layer_types[i] == MAMBA else
             (num_kv_heads * head_dim, present.index(self.layer_types[i]))
             for i in cached]
+
+    # a model none of whose layers borrows has no cross-decoder, no tail
+    # that a prefill runs on one row, and Mamba-2's scan where it has one
+    s6, cross_from, tail_from = False, None, None
+
+    def _check_borrowed(self, layer_types, mamba, attention):
+        """Refuse a ``kv_from`` / ``memory_from`` that is missing, points
+        at a layer of the wrong type or at one no earlier than a layer that
+        reads it."""
+        for t, what, source, right in (
+                (CROSS, "kv_from", self.kv_from, (FULL, SLIDING)),
+                (GMU, "memory_from", self.memory_from, (MAMBA,))):
+            readers = [i for i, u in enumerate(layer_types) if u == t]
+            if not readers:
+                if source is not None:
+                    raise ValueError("%s is %r and no layer is %r"
+                                     % (what, source, t))
+                continue
+            if attention != "gqa" or source is None or \
+                    not 0 <= source < readers[0] or \
+                    layer_types[source] not in right or \
+                    (t == GMU and mamba.get("scan") != "s6"):
+                raise ValueError(
+                    "a %r layer reads layer %s = %r, which has to be an "
+                    "earlier layer of type %s (grouped-query attention; a "
+                    "memory is a Mamba-1 scan's output)"
+                    % (t, what, source, " or ".join(map(repr, right))))
 
     def _latent_sizes(self, q_rank, kv_rank, nope_dim, rope_dim, v_dim,
                       q_scale=None, kv_scale=None):
@@ -234,13 +334,21 @@ class MoeLM:
 
     # -- the block ---------------------------------------------------------
     def _norm(self, x, name, group_size=0):
+        if self.norm == "layer":
+            return layers.layer_norm(
+                x, begin_norm_axis=len(x.shape) - 1, epsilon=self.eps,
+                param_attr="moe_lm.%s.w" % name,
+                bias_attr="moe_lm.%s.b" % name)
         return layers.rms_norm(x, epsilon=self.eps, group_size=group_size,
                                param_attr="moe_lm.%s.w" % name,
                                offset=self.norm_offset)
 
-    def _linear(self, x, size, name):
-        return layers.linear(x, size, "moe_lm.%s.w" % name, self.dtype,
-                             self.std)
+    def _linear(self, x, size, name, bias=False):
+        y = layers.linear(x, size, "moe_lm.%s.w" % name, self.dtype,
+                          self.std)
+        if bias:
+            y = layers.bias_add(y, "moe_lm.%s.b" % name, self.std)
+        return y
 
     def _positions(self, ctx):
         """The rotary layer's position arguments for a program's mode."""
@@ -318,10 +426,11 @@ class MoeLM:
             prefix="moe_lm." + p[:-1], dtype=self.dtype,
             **dict(self.eva, **where))
 
-    def _mixer(self, a, i, ctx):
-        """a [B, T, d] -> the Mamba-2 mixer's output [B, T, d]: whole
+    def _mixer(self, a, i, ctx, handed=None):
+        """a [B, T, d] -> the state-space mixer's output [B, T, d]: whole
         sequences, a prefill into the slot's state row, or a decode step
-        over the layer's state pool."""
+        over the layer's state pool. Mamba-1's scan output goes into
+        ``handed`` where a later layer reads this one's."""
         where = {}
         if ctx is not None:
             at = self.site[i]
@@ -331,40 +440,73 @@ class MoeLM:
                 where["pos"] = ctx["pos"]
             else:
                 where["length"] = ctx["key_length"]
+        if self.s6:
+            o, m = layers.mamba1_mixer(
+                a, prefix="moe_lm.l%d.mamba" % i, dtype=self.dtype,
+                std=self.std, **dict(self.mamba, **where))
+            if i == self.memory_from:
+                handed["memory"] = m
+            return o
         return layers.mamba2_mixer(
             a, prefix="moe_lm.l%d.mamba" % i, epsilon=self.eps,
             dtype=self.dtype, std=self.std, **dict(self.mamba, **where))
 
-    def _attention(self, a, i, ctx):
+    def _attention(self, a, i, ctx, handed=None):
         """a [B, T, d] -> the (gated) attention output [B, T, H*D], through
-        the layer's paged cache where ``ctx`` has one."""
+        the layer's paged cache where ``ctx`` has one; a cross layer's
+        through the cache of the layer it reads. ``handed`` carries that
+        layer's keys and values to the cross layers of a whole-sequence
+        forward."""
         p = "l%d.attn." % i
         if self.attention == "latent":
             return self._latent_attention(a, p, i, ctx)
         if self.attention == "eva":
             return self._eva_attention(a, p, i, ctx)
-        windowed = self.layer_types[i] == SLIDING
-        q = self._linear(a, self.nh * self.hd, p + "q")
-        k = self._linear(a, self.nkv * self.hd, p + "k")
-        v = self._linear(a, self.nkv * self.hd, p + "v")
+        t = self.layer_types[i]
+        source = self.kv_from if t == CROSS else i
+        windowed = self.layer_types[source] == SLIDING
+        # KV heads as the pools hold them: a differential pair's two keys
+        # are one head of twice the lanes, and so are its two values
+        nkv = self.nkv // 2 if self.differential else self.nkv
+        if t == CROSS:
+            q = self._linear(a, self.nh * self.hd, p + "q", self.attn_bias)
+            k, v = handed["kv"]
+        elif self.differential:
+            # one matrix, as published: q, then k, then v
+            qkv = self._linear(a, (self.nh + 2 * self.nkv) * self.hd,
+                               p + "qkv", self.attn_bias)
+            cuts = [0, self.nh * self.hd, (self.nh + self.nkv) * self.hd,
+                    (self.nh + 2 * self.nkv) * self.hd]
+            q, k, v = (layers.slice(qkv, [2], [lo], [hi])
+                       for lo, hi in zip(cuts, cuts[1:]))
+        else:
+            q = self._linear(a, self.nh * self.hd, p + "q", self.attn_bias)
+            k = self._linear(a, self.nkv * self.hd, p + "k", self.attn_bias)
+            v = self._linear(a, self.nkv * self.hd, p + "v", self.attn_bias)
+        if i == self.kv_from:
+            handed["kv"] = (k, v)
         if self.attn_gate:
             gate = self._linear(a, self.nh * self.hd, p + "gate")
         if self.qk_norm:
             q = self._norm(q, p + "q_norm", self.hd)
             k = self._norm(k, p + "k_norm", self.hd)
-        if windowed:
+        if windowed and self.window_rotary:
             # positions only where the window bounds what they span
             rope = dict(self._positions(ctx), head_dim=self.hd,
                         theta=self.theta)
             q = layers.rotary_embedding(q, **rope)
             k = layers.rotary_embedding(k, **rope)
+        if self.differential:
+            q = layers.diff_attention_queries(q, self.hd)
         helper = LayerHelper("moe_lm_attention")
-        out = helper.create_tmp_variable(v.dtype)
-        attrs = {"num_heads": self.nh, "num_kv_heads": self.nkv}
+        out = helper.create_tmp_variable(q.dtype)
+        attrs = {"num_heads": self.nh, "num_kv_heads": nkv}
         if windowed:
             attrs["window"] = self.window
         if self.attn_scale is not None:
             attrs["scale"] = self.attn_scale
+        elif self.differential:
+            attrs["scale"] = self.hd ** -0.5    # of a head, not of a pair
         if ctx is None:
             helper.append_op(
                 type="multihead_attention",
@@ -372,20 +514,30 @@ class MoeLM:
                 outputs={"Out": [out.name]},
                 attrs=dict(attrs, causal=True, ring_axis=None))
         else:
-            at = self.site[i]
+            at = self.site[source]
             ck, cv = ctx["caches"][at]
             table = ctx["tables"][self.cache_layers[at][1]]
-            if ctx["mode"] == "prefill":
+            if ctx["mode"] == "decode":
+                write, attend = ("kv_cache_append_paged",
+                                 "multihead_attention_decode_paged")
+                where = {"Pos": [ctx["pos"].name], "Table": [table.name]}
+            elif t == CROSS:
+                # a prefill's one row, the prompt's last, against the pool
+                # its source layer has just written: a decode walk of one
+                # slot
+                attend = "multihead_attention_decode_paged"
+                where = {
+                    "Pos": [layers.elementwise_add(
+                        ctx["hist"], ctx["last_pos"]).name],
+                    "Table": [layers.reshape(
+                        table, [1, table.shape[0]]).name]}
+            else:
                 write, attend = ("kv_cache_write_paged",
                                  "multihead_attention_prefill_paged")
                 where = {"Table": [table.name], "Hist": [ctx["hist"].name],
                          "Len": [ctx["key_length"].name]}
                 attrs["block_rows"] = 512
-            else:
-                write, attend = ("kv_cache_append_paged",
-                                 "multihead_attention_decode_paged")
-                where = {"Pos": [ctx["pos"].name], "Table": [table.name]}
-            for cvar, new in ((ck, k), (cv, v)):
+            for cvar, new in ((ck, k), (cv, v)) if t != CROSS else ():
                 helper.append_op(type=write,
                                  inputs=dict(where, Cache=[cvar.name],
                                              New=[new.name]),
@@ -395,9 +547,22 @@ class MoeLM:
                                          CacheK=[ck.name],
                                          CacheV=[cv.name]),
                              outputs={"Out": [out.name]}, attrs=attrs)
+        if self.differential:
+            out = layers.diff_attention_combine(
+                out, self.hd, 0.8 - 0.6 * math.exp(-0.3 * i),
+                "moe_lm." + p[:-1], epsilon=self.eps)
         if not self.attn_gate:
             return out
         return layers.elementwise_mul(out, layers.sigmoid(gate))
+
+    def _gmu(self, a, i, handed):
+        """a [B, T, d] -> the gated memory unit's output [B, T, d]:
+        ``(silu(a W_in) * m) W_out`` on the same rows' scan output of layer
+        ``memory_from``."""
+        m = handed["memory"]
+        g = self._linear(a, m.shape[-1], "l%d.gmu.in" % i)
+        return self._linear(layers.elementwise_mul(layers.silu(g), m),
+                            self.d, "l%d.gmu.out" % i)
 
     def _feed_forward(self, m, i):
         """-> (f, the experts' pair counts or None)."""
@@ -470,9 +635,32 @@ class MoeLM:
                                  "l%d.attn.o" % i)
             return self._residual(h, o), counts
 
+    def _last_row(self, x, last_pos):
+        """x [1, P, w] -> [1, 1, w]: the row at ``last_pos`` [1]."""
+        return layers.gather(layers.transpose(x, [1, 0, 2]), last_pos)
+
+    def _scopes(self, i):
+        """What a trace's operations of layer i's mixer go under, where the
+        model has a cross-decoder: the decoder, and within it the mixer's
+        type."""
+        scopes = contextlib.ExitStack()
+        if self.cross_from is not None:
+            t = self.layer_types[i]
+            scopes.enter_context(name_scope(
+                "self_decoder" if i < self.cross_from else "cross_decoder"))
+            scopes.enter_context(name_scope(
+                {MAMBA: "mamba1_mixer" if self.s6 else "mamba2_mixer",
+                 GMU: "gmu", CROSS: "cross_attention"}.get(
+                     t, "diff_attention" if self.differential
+                     else "attention")))
+        return scopes
+
     def hidden(self, tokens, ctx=None):
         """tokens [B, T] -> (h [B, T, d] float32 before the final norm,
-        [counts] of the expert layers in order)."""
+        [counts] of the expert layers in order). A prefill whose ``ctx``
+        names the prompt's last row (``last_pos``) hands back that row
+        alone, [1, 1, d]: cut out before the layers that own no cache,
+        where the model ends in such layers, else at the end."""
         emb = layers.embedding(
             tokens, size=[self.vocab_size, self.d], dtype=self.dtype,
             param_attr=ParamAttr(
@@ -483,6 +671,9 @@ class MoeLM:
         if self.embed_scale is not None:
             h = layers.scale(h, self.embed_scale)
         all_counts = []
+        # what a layer hands to later ones beside the residual stream
+        handed = {}
+        last_pos = (ctx or {}).get("last_pos")
         for i in range(len(self.layer_types)):
             if self.block:
                 h, counts = (self._sublayer if self.single
@@ -490,12 +681,22 @@ class MoeLM:
                 if counts is not None:
                     all_counts.append(counts)
                 continue
-            a = self._norm(h, "l%d.norm_in" % i)
-            if self.layer_types[i] == MAMBA:
-                o = self._mixer(a, i, ctx)
-            else:
-                o = self._linear(self._attention(a, i, ctx), self.d,
-                                 "l%d.attn.o" % i)
+            if last_pos is not None and i == self.tail_from:
+                h = self._last_row(h, last_pos)
+                if "memory" in handed:
+                    handed["memory"] = self._last_row(handed["memory"],
+                                                      last_pos)
+            t = self.layer_types[i]
+            with self._scopes(i):
+                a = self._norm(h, "l%d.norm_in" % i)
+                if t == MAMBA:
+                    o = self._mixer(a, i, ctx, handed)
+                elif t == GMU:
+                    o = self._gmu(a, i, handed)
+                else:
+                    o = self._linear(self._attention(a, i, ctx, handed),
+                                     self.d, "l%d.attn.o" % i,
+                                     self.attn_bias)
             if self.post_norms:
                 o = self._norm(o, "l%d.norm_post_attn" % i)
             h = self._residual(h, o)
@@ -506,6 +707,8 @@ class MoeLM:
             h = self._residual(h, f)
             if counts is not None:
                 all_counts.append(counts)
+        if last_pos is not None and self.tail_from is None:
+            h = self._last_row(h, last_pos)
         return h, all_counts
 
     def _head(self, h):
@@ -536,8 +739,7 @@ class MoeLM:
     def prefill_row(self, tokens, last_pos, cache_ctx):
         # the last real row before the head: [1,P,d] -> [P,1,d] -> [1,1,d];
         # the head over every row of the bucket would be P x V logits
-        h, _ = self.hidden(tokens, cache_ctx)
-        at = layers.gather(layers.transpose(h, [1, 0, 2]), last_pos)
+        at, _ = self.hidden(tokens, dict(cache_ctx, last_pos=last_pos))
         return self._next_token_row(self._head(at), 1)
 
     def decode_row(self, tokens, cache_ctx):

@@ -305,7 +305,10 @@ def lm_session(model, max_len=16, slots=None, cache_len=None,
     as (name, window) pairs, the first the one ``num_blocks`` sizes (a
     third entry, a dict, holds what else ``paged_cache.CacheKind`` takes:
     ``aligned`` for a window that is freed whole at its edge, ``chunk``
-    for a kind whose row stands for that many positions);
+    for a kind whose row stands for that many positions, ``borrowers`` for
+    the further layers that walk this kind's pools and own none: such a
+    layer is no entry of ``cache_layers`` and reads another's by its
+    index);
     ``cache_layers``, per layer cache (one a layer, or one an attention
     site where a layer has several: the model reads
     ``cache_ctx["caches"]`` by the same index) the width of a cached row

@@ -38,6 +38,51 @@ def _grouped_window_attention(q, k, v, nh, nkv, causal, window, scale=None):
     return jnp.einsum("bkgqc,bckd->bqkgd", p, vh).reshape(b, tq, dm)
 
 
+@register_op("diff_attention_queries")
+def _diff_attention_queries(ctx):
+    """Q [.., 2P*D], the query heads of P differential pairs ``(q1_p,
+    q2_p)`` = heads ``(2p, 2p+1)`` -> Out [.., 2P*2D]: head ``(p, 1)`` is
+    ``(q1_p | 0)`` and ``(p, 2)`` is ``(0 | q2_p)``. Against a key row
+    ``(k1 | k2)`` of 2D lanes the first scores ``q1 . k1`` and the second
+    ``q2 . k2``, so that a pair's two softmaxes are two heads of an
+    ordinary grouped-query attention over KV heads of 2D lanes: the keys
+    and the values ``(v1 | v2)`` lie as the projection wrote them, and a
+    cached page is read once for both."""
+    q = ctx.input("Q")
+    hd = ctx.attr("head_dim")
+    pairs = q.reshape(q.shape[:-1] + (-1, 2, 1, hd))
+    own = jnp.eye(2, dtype=q.dtype)[:, :, None]
+    return {"Out": (pairs * own).reshape(q.shape[:-1] + (-1,))}
+
+
+def diff_lambda(q1, k1, q2, k2, init):
+    """A layer's ``lambda = exp(q1 . k1) - exp(q2 . k2) + lambda_init``
+    from its four learned vectors."""
+    def dot(a, b):
+        return jnp.sum(a.astype(jnp.float32) * b.astype(jnp.float32))
+    return jnp.exp(dot(q1, k1)) - jnp.exp(dot(q2, k2)) + init
+
+
+@register_op("diff_attention_combine")
+def _diff_attention_combine(ctx):
+    """X [.., 2P*W]: heads ``(p, 1)`` and ``(p, 2)`` of W lanes, a pair's
+    two attentions over its one value (Differential Transformer, Ye et al.
+    2024); LambdaQ1, LambdaK1, LambdaQ2, LambdaK2 [D]; NormW [W]; attrs
+    lambda_init, epsilon. Out [.., P*W] float32 =
+    ``RMSNorm_W(x1 - lambda x2; NormW) (1 - lambda_init)`` with ``lambda =
+    exp(q1 . k1) - exp(q2 . k2) + lambda_init``, one number a layer."""
+    x = ctx.input("X").astype(jnp.float32)
+    w = ctx.input("NormW").astype(jnp.float32)
+    init = ctx.attr("lambda_init")
+    lam = diff_lambda(*(ctx.input(slot) for slot in (
+        "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2")), init)
+    pairs = x.reshape(x.shape[:-1] + (-1, 2, w.shape[0]))
+    o = pairs[..., 0, :] - lam * pairs[..., 1, :]
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + ctx.attr("epsilon", 1e-5)) * w * (1.0 - init)
+    return {"Out": o.reshape(x.shape[:-1] + (-1,))}
+
+
 @register_op("multihead_attention")
 def _multihead_attention(ctx):
     """Q,K,V: [B, T, H*D] packed; attrs num_heads, causal; optional

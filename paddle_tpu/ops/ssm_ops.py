@@ -40,6 +40,29 @@ place is written once.
 Products with ``W_in`` and ``W_out`` are exact (``moe_ops.exact_dot``) and
 everything between them is float32 at the highest precision; the state is
 float32. Plain ``jax.numpy``: no kernel of this repo's own.
+
+**The Mamba-1 mixer** (Gu & Dao 2023, the S6 selective scan) is the same
+two ops over again, ``mamba1_mixer`` and ``mamba1_mixer_decode``, and not
+Mamba-2 at other numbers. With ``D`` inner channels, ``N`` numbers of state
+a channel and a step size through a bottleneck of ``R``:
+
+    [x | z] = u W_in                      (a ``linear`` before the op)
+    x_t = silu(sum_k w[k] * x_{t-K+1+k} + b)          over x alone
+    [r | B | C] = x_t [W_r | W_bc];  dt_t = softplus(r W_dt + dt_bias)
+    S_t = exp(dt_t (outer) A) . S_{t-1} + (dt_t x_t) (outer) B_t     A = -exp(A_log)
+    y_t = S_t C_t + D x_t;   out = (y_t silu(z_t)) W_out   (a ``linear`` after)
+
+The decay is a **matrix** ``A [N, D]``, so ``exp(dt_t A)`` is ``[N, D]`` a
+token and no scalar a head factors out of a chunk: the chunked form does not
+apply. A prompt's scan is the recurrence itself, a ``lax.scan`` over the
+rows whose carry is the one state: nothing of ``[T, N, D]`` is ever held.
+The state lies ``[N, D]``, channels along the lanes (the published
+``[D, N]`` would fill 16 of a tile's 128 lanes). B and C are one for all
+channels, there is no norm, and the ops hand back ``y`` itself beside the
+gated ``y silu(z)``: a later layer's gated memory unit reads it. The two
+small products inside the op (``W_r | W_bc`` and ``W_dt``) are exact on the
+float32 ``x``; the large ones are ``linear`` ops and take ``amp``'s one
+pass.
 """
 
 import jax
@@ -263,14 +286,130 @@ def _mamba2_mixer_decode(ctx):
             "AtOut": jnp.where(fresh, pos + 1, at)}
 
 
+def s6_scan(x, dt, a, b, c):
+    """Mamba-1's selective scan of one sequence from a zero state, a row
+    after the other: x, dt [T, D] (dt after softplus; 0 in rows that move
+    nothing), a [N, D] (negative), b, c [T, N] -> (y [T, D] without the
+    ``D x`` term, the state after row T-1 [N, D]). The carry is the one
+    state; eight rows a trip of the loop."""
+    def row(s, inp):
+        x_t, dt_t, b_t, c_t = inp
+        s = jnp.exp(dt_t * a) * s + (dt_t * x_t) * b_t[:, None]
+        return s, jnp.sum(s * c_t[:, None], axis=0)
+
+    last, y = jax.lax.scan(row, jnp.zeros(a.shape, jnp.float32),
+                           (x, dt, b, c), unroll=min(8, x.shape[0]))
+    return y, last
+
+
+def s6_step(ssm, dt, a, x, b, c, fresh):
+    """One step of Mamba-1's recurrence over a whole pool where it lies:
+    ssm [S, N, D], dt, x [S, D], a [N, D], b, c [S, N], ``fresh`` [S] bool
+    -> (the pool with the rows of ``fresh`` advanced, y [S, D] = ``S_new
+    C`` without the ``D x`` term): one elementwise pass, as
+    :func:`ssm_step` is."""
+    step = jnp.exp(dt[:, None, :] * a) * ssm + \
+        (dt * x)[:, None, :] * b[:, :, None]
+    new = jnp.where(fresh[:, None, None], step, ssm)
+    return new, jnp.sum(new * c[:, :, None], axis=1)
+
+
+def _s6_inputs(ctx, x):
+    """The convolved x [.., D] -> (dt [.., D] after softplus, A [N, D],
+    B, C [.., N])."""
+    flat = x.reshape(-1, x.shape[-1])
+    r = exact_dot(flat, ctx.input("WR"))
+    bc = exact_dot(flat, ctx.input("WBC")).reshape(x.shape[:-1] + (-1,))
+    n = bc.shape[-1] // 2
+    dt = exact_dot(r, ctx.input("WDt")).reshape(x.shape)
+    dt, a = _dt_a(ctx, dt)
+    return dt, a, bc[..., :n], bc[..., n:]
+
+
+@register_op("mamba1_mixer")
+def _mamba1_mixer(ctx):
+    """XZ [B, T, 2D] (``u W_in``: x then the gate z); ConvW [K, D], ConvB
+    [D], WR [D, R], WBC [D, 2N], WDt [R, D], DtBias [D], ALog [N, D], D [D].
+    Out [B, T, D] float32 = ``y silu(z)`` and M [B, T, D] = ``y``, the
+    scan's output with its ``D x`` term before the gate: every sequence
+    from a zero state. With a state pool (B = 1: a prefill) also Ssm
+    [R, N, D], Conv [R, K, D], At [R] int32, Len [1] and Table [1], as
+    ``mamba2_mixer`` takes them: row ``Table[0]`` is left holding the state
+    after row ``Len - 1``, a dead entry drops the write."""
+    xz = ctx.input("XZ").astype(jnp.float32)
+    bsz, t, d2 = xz.shape
+    di = d2 // 2
+    raw, z = xz[..., :di], xz[..., di:]
+    conv_w = ctx.input("ConvW").astype(jnp.float32)
+    k = conv_w.shape[0]
+    # K zero rows before the sequence, as the Mamba-2 op pads them
+    raw = jnp.pad(raw, ((0, 0), (k, 0), (0, 0)))
+    x = sum(conv_w[j] * raw[:, j + 1:j + 1 + t] for j in range(k))
+    x = jax.nn.silu(x + ctx.input("ConvB").astype(jnp.float32))
+    dt, a, b, c = _s6_inputs(ctx, x)
+    pooled = ctx.has_input("Ssm")
+    if pooled:
+        if bsz != 1:
+            raise ValueError("a state row takes one sequence, not %d" % bsz)
+        length = ctx.input("Len").reshape(-1)[0].astype(jnp.int32)
+        dt = jnp.where((jnp.arange(t) < length)[None, :, None], dt, 0.0)
+    y, last = jax.vmap(lambda *one: s6_scan(*one[:2], a, *one[2:]))(
+        x, dt, b, c)
+    y = y + ctx.input("D").astype(jnp.float32) * x
+    out = {"Out": y * jax.nn.silu(z), "M": y}
+    if pooled:
+        row = ctx.input("Table").reshape(-1)[0].astype(jnp.int32)
+        tail = jax.lax.dynamic_slice_in_dim(raw[0], length, k, axis=0)
+        out["SsmOut"] = ctx.input("Ssm").at[row].set(last[0], mode="drop")
+        out["ConvOut"] = ctx.input("Conv").at[row].set(tail, mode="drop")
+        out["AtOut"] = ctx.input("At").at[row].set(length, mode="drop")
+    return out
+
+
+@register_op("mamba1_mixer_decode")
+def _mamba1_mixer_decode(ctx):
+    """XZ [S, 1, 2D] and the weights of ``mamba1_mixer``; Ssm [S, N, D],
+    Conv [S, K, D], At [S] int32, Pos [S], Table [S, 1]: the state pool's
+    books as ``mamba2_mixer_decode`` keeps them (a row advances only where
+    ``Table[s] == s`` and ``At[s] == Pos[s]``; a row that already holds
+    ``Pos[s] + 1`` tokens gives its output as stored). Out, M [S, 1, D]."""
+    xz = ctx.input("XZ").astype(jnp.float32)
+    s, _, d2 = xz.shape
+    di = d2 // 2
+    raw, z = xz[:, 0, :di], xz[:, 0, di:]
+    ssm, conv, at = ctx.input("Ssm"), ctx.input("Conv"), ctx.input("At")
+    if ssm.shape[0] != s:
+        raise ValueError("a state pool of %d rows under %d slots: a slot's "
+                         "row is the row of its own index"
+                         % (ssm.shape[0], s))
+    pos = ctx.input("Pos").reshape(-1).astype(jnp.int32)
+    own = ctx.input("Table").reshape(s, -1)[:, 0] == jnp.arange(s)
+    fresh = own & (at == pos)
+    window = jnp.where(fresh[:, None, None],
+                       jnp.concatenate([conv[:, 1:], raw[:, None]], axis=1),
+                       conv)
+    x = jnp.sum(window * ctx.input("ConvW").astype(jnp.float32), axis=1)
+    x = jax.nn.silu(x + ctx.input("ConvB").astype(jnp.float32))
+    dt, a, b, c = _s6_inputs(ctx, x)
+    new, y = s6_step(ssm, dt, a, x, b, c, fresh)
+    y = y + ctx.input("D").astype(jnp.float32) * x
+    return {"Out": (y * jax.nn.silu(z))[:, None], "M": y[:, None],
+            "SsmOut": new, "ConvOut": window,
+            "AtOut": jnp.where(fresh, pos + 1, at)}
+
+
 @register_op("mamba2_param_init")
 def _mamba2_param_init(ctx):
     """U, uniform in [0, 1) -> Mamba-2's published initial values: attr
     ``what`` ``a_log`` gives ``log(A)`` with A uniform in [1, 16];
     ``dt_bias`` gives the inverse softplus of a ``dt`` log-uniform in
-    [1e-3, 1e-1]."""
+    [1e-3, 1e-1]; ``a_log_rows`` gives Mamba-1's ``log(1..N)`` down the
+    rows of U [N, D], the same for every channel (the draw is not read)."""
     u = ctx.input("U").astype(jnp.float32)
     if ctx.attr("what") == "a_log":
         return {"Out": jnp.log(1.0 + 15.0 * u)}
+    if ctx.attr("what") == "a_log_rows":
+        rows = jnp.arange(1, u.shape[0] + 1, dtype=jnp.float32)
+        return {"Out": jnp.broadcast_to(jnp.log(rows)[:, None], u.shape)}
     dt = jnp.maximum(jnp.exp(jnp.log(1e-3) + u * jnp.log(1e2)), 1e-4)
     return {"Out": dt + jnp.log(-jnp.expm1(-dt))}

@@ -43,8 +43,9 @@ writes of each kind of layer cache, kept by the kind at the step's launch
 (``LayerCache.count_step``): ``_window_context_tokens_total``,
 ``_latent_rows_attended_total``, ``_state_rows_updated_total``,
 ``_eva_window_rows_total``, ``_eva_chunk_rows_total``,
-``_eva_chunks_written_total``, and ``_paged_blocks_total{kind, fill}``, the
-blocks of pages its walks take.
+``_eva_chunks_written_total``, ``_borrowed_context_tokens_total`` (the rows
+walked by layers that own no pool, in the pool of a layer that does), and
+``_paged_blocks_total{kind, fill}``, the blocks of pages its walks take.
 """
 
 import collections
@@ -128,6 +129,13 @@ STATE_ROWS_UPDATED = _metrics.REGISTRY.counter(
     "kind times the slots that advanced (each such row is read and "
     "written whole)")
 
+BORROWED_CONTEXT_TOKENS = _metrics.REGISTRY.counter(
+    "paddle_generation_borrowed_context_tokens_total",
+    "Cached rows walked by layers in a pool they do not own (cross "
+    "layers that compute a query only and read another layer's keys and "
+    "values): per step, the sum over the slots that advanced of their "
+    "context length, the new token included, times the kind's borrowers")
+
 PAGED_BLOCKS = _metrics.REGISTRY.counter(
     "paddle_generation_paged_blocks_total",
     "Blocks of pages the paged decode kernel's walks take in decode steps "
@@ -159,10 +167,13 @@ _POOL_SEQ = itertools.count()
 # summary of them, written when the chunk's last position is: the kind
 # named ``chunk``, ops/eva_ops.py), so that its table grows a block every
 # ``chunk * block_size`` positions and a sequence of n tokens holds
-# ``n // chunk`` rows.
+# ``n // chunk`` rows. ``borrowers`` is how many further layers walk the
+# pools of this kind's layers without one of their own (a cross layer reads
+# the keys and values another layer wrote): they hold no block and write no
+# row, and a decode step's walks of the kind are theirs too.
 CacheKind = collections.namedtuple(
     "CacheKind", "name window num_blocks layers prefill_table decode_table "
-    "aligned chunk", defaults=(False, 1))
+    "aligned chunk borrowers", defaults=(False, 1, 0))
 
 # Why a kind of layer cache takes neither a shared prefix nor speculation:
 # said here once, for ``lm_session`` and ``GenerationSession`` alike.
@@ -492,6 +503,10 @@ class LayerCache:
         elif self.window:
             WINDOW_CONTEXT_TOKENS.inc(layers * int(
                 np.minimum(lengths, self.window).sum()))
+        if self.kind.borrowers:
+            BORROWED_CONTEXT_TOKENS.inc(self.kind.borrowers * int(
+                (np.minimum(lengths, self.window) if self.window
+                 else lengths).sum()))
 
     def _count_walk_blocks(self, lengths):
         """The blocks of ``walk_pages`` pages the decode kernel's walk of
@@ -507,7 +522,8 @@ class LayerCache:
         for fill, blocks in (("full", n_pages // self.walk_pages),
                              ("partial", n_pages % self.walk_pages > 0)):
             PAGED_BLOCKS.labels(kind=self.kind.name, fill=fill).inc(
-                self.kind.layers * int(blocks.sum()))
+                (self.kind.layers + self.kind.borrowers)
+                * int(blocks.sum()))
 
     def count_prefill(self, n_tokens):
         """Count what the prefill of a prompt of ``n_tokens`` writes of

@@ -894,12 +894,14 @@ def test_a_two_kind_specs_programs_are_the_parents_byte_for_byte():
     before the dense per-slot layout left ``lm_session``. (Digested again
     in PR 42: ``CacheKind`` took the fields ``aligned`` and ``chunk``,
     which its ``repr`` in the digest shows; with the kinds written as the
-    parent wrote them the programs still give 9c946466...b1059d.)"""
+    parent wrote them the programs still give 9c946466...b1059d. And in PR
+    49, for the field ``borrowers``: written without it the digest is still
+    PR 42's bc6009de...c85038.)"""
     spec = moe_lm_session(slots=3, cache_len=32, prompt_buckets=(8, 16),
                           block_size=4, num_blocks=24, window_num_blocks=20,
                           cache_ns="kv", **SIZES)
-    assert _digest(spec) == ("bc6009de7e570167352fadb99fdcf2f7"
-                             "5de7307252d03d7b0c86be005bc85038")
+    assert _digest(spec) == ("caa58ff4171535fcd14a2c6f2bc38871"
+                             "9aaddb2f92597cc32144270abebe87c2")
 
 
 @pytest.mark.parametrize("policy,digest", [

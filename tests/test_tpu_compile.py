@@ -260,6 +260,25 @@ def test_decode_attention_paged_compiles_at_nemotrons_geometry(chip):
     assert pa._paged_block_pages(16, 256, F32, 256) == 32
 
 
+@pytest.mark.parametrize("slots,window,pool_blocks", [
+    (64, None, 32768), (64, 512, 2560), (1, None, 32768)],
+    ids=["full_and_cross_layers", "window_layers", "a_prefills_cross_walk"])
+def test_decode_attention_paged_compiles_differential_pairs(
+        chip, slots, window, pool_blocks):
+    """phi4flash-serve-offline's walks: a differential pair's two queries
+    as two heads of 128 lanes on the pair's one KV head ``(k1 | k2)``, so
+    40 query heads of 128 on 10 KV heads, float32 query, bfloat16 pools
+    1,280 wide in blocks of 16 rows, table rows of 512: the full layer's
+    and the seven cross layers' walk, the window layers' behind a window of
+    512, and the one-slot walk a prefill's cross layers make."""
+    before = _paged_paths()
+    assert _has_kernel(
+        _paged(chip, 40, 128, BF16, query=F32, slots=slots, max_blocks=512,
+               pool_blocks=pool_blocks, num_kv_heads=10, window=window),
+        "decode_attention_paged")
+    assert _paged_paths(before) == {"compiled": 1}
+
+
 @pytest.mark.parametrize("aligned", [True, False], ids=["window", "chunks"])
 def test_decode_attention_paged_compiles_a_walk_to_be_merged(chip, aligned):
     """evabyte-serve-offline's two walks a layer (ops/eva_ops.py): 24
